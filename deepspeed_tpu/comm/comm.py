@@ -492,9 +492,7 @@ def axis_rank(group: AxisName = "data"):
 
 
 def axis_size(group: AxisName = "data") -> int:
-    from ..utils.jax_compat import axis_size as _axis_size
-
-    return _axis_size(group)
+    return lax.axis_size(group)
 
 
 def barrier(group: AxisName = "data"):

@@ -57,9 +57,7 @@ def compressed_allreduce(x: jnp.ndarray, worker_error: jnp.ndarray,
 
     Returns (allreduced mean, new_worker_error, new_server_error).
     """
-    from ..utils.jax_compat import axis_size
-
-    world = axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     n = x.shape[-1]
     chunk = n // world
     if n % (world * 8):

@@ -43,7 +43,6 @@ from typing import Tuple
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.jax_compat import axis_size as _axis_size
 from .comm import AxisName, all_gather, all_to_all_single
 
 #: default quantization block (values per absmax scale). 256 keeps the
@@ -99,7 +98,7 @@ def quantized_psum(x: jnp.ndarray, axis: AxisName = "model",
     for serving's packed batches, up to ``n``x for a single-token
     offline decode, which is not the path this collective serves).
     """
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     if n == 1:
         return lax.psum(x, axis)
     shape, dtype = x.shape, x.dtype

@@ -158,7 +158,6 @@ class QuantDense(nn.Module):
             from jax.sharding import PartitionSpec as P
 
             from ..comm.quantized import quantized_psum
-            from ..utils.jax_compat import shard_map
 
             # row-parallel seam: x's features and the kernel's K dim (the
             # packed dim for int4) split over `model`; each shard matmuls
@@ -175,9 +174,9 @@ class QuantDense(nn.Module):
                     return quantized_psum(self._matmul(xl, kl, None),
                                           "model", block=block)
 
-                y = shard_map(body, mesh=mesh, in_specs=(xspec, kspec),
-                              out_specs=P(*((None,) * x.ndim)),
-                              check_vma=False)(x, kernel)
+                y = jax.shard_map(body, mesh=mesh, in_specs=(xspec, kspec),
+                                  out_specs=P(*((None,) * x.ndim)),
+                                  check_vma=False)(x, kernel)
             else:
                 sspec = P("model", None) if wscale.shape[0] % mp == 0 \
                     else P(None, None)
@@ -186,10 +185,10 @@ class QuantDense(nn.Module):
                     return quantized_psum(self._matmul(xl, kl, sl),
                                           "model", block=block)
 
-                y = shard_map(body, mesh=mesh,
-                              in_specs=(xspec, kspec, sspec),
-                              out_specs=P(*((None,) * x.ndim)),
-                              check_vma=False)(x, kernel, wscale)
+                y = jax.shard_map(body, mesh=mesh,
+                                  in_specs=(xspec, kspec, sspec),
+                                  out_specs=P(*((None,) * x.ndim)),
+                                  check_vma=False)(x, kernel, wscale)
         if bias is not None:
             y = y + bias
         return y

@@ -127,6 +127,17 @@ class LlamaConfig:
             max_position_embeddings=8192, rope_theta=500000.0), **over})
 
     @staticmethod
+    def mistral_7b(**over):
+        """Mistral-7B-v0.1 as published (``mistralai/Mistral-7B-v0.1``
+        ``config.json``): the dense GQA + sliding-window control that
+        ``chip_smoke.py`` runs at full width with depth cut."""
+        return LlamaConfig(**{**dict(
+            vocab_size=32000, hidden_size=4096, intermediate_size=14336,
+            num_hidden_layers=32, num_attention_heads=32, num_key_value_heads=8,
+            max_position_embeddings=32768, rms_norm_eps=1e-5,
+            rope_theta=10000.0, sliding_window=4096), **over})
+
+    @staticmethod
     def llama_400m(**over):
         """The bench flagship (~400M): shared by bench.py and
         tools/bench_decode.py so both measure the same model."""

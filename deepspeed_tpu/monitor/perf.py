@@ -68,14 +68,21 @@ DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
 def device_peaks(device_kind: Optional[str]
                  ) -> Tuple[Optional[float], Optional[float]]:
     """(peak_flops_per_s, peak_hbm_bytes_per_s) per chip for a
-    ``device.device_kind`` string; (None, None) when unknown (CPU, new
-    hardware) — utilization gauges are then omitted rather than wrong."""
+    ``device.device_kind`` string. A host platform (CPU) has no peak:
+    (None, None), and utilization gauges are omitted rather than wrong. A
+    TPU that is not in the table is an error, not a default — a utilization
+    computed against a guessed or missing peak would read like a result."""
     if not device_kind:
         return (None, None)
     best = None
     for key, peaks in DEVICE_PEAKS.items():
         if key in device_kind and (best is None or len(key) > len(best[0])):
             best = (key, peaks)
+    if best is None and device_kind.upper().startswith("TPU"):
+        raise KeyError(
+            f"no peak FLOP/s / HBM bandwidth known for device_kind "
+            f"{device_kind!r}: add it to monitor/perf.py DEVICE_PEAKS with "
+            f"its source")
     return best[1] if best else (None, None)
 
 

@@ -65,11 +65,6 @@ def _decode_kernel(cidx_ref, q_ref, k_ref, v_ref, *rest,
         q = q_ref[0, 0].astype(jnp.float32)     # [G, D]
         k = k_ref[0, 0].astype(jnp.float32)     # [bk, D]
         v = v_ref[0, 0].astype(jnp.float32)     # [bk, D]
-        if int8:
-            # int8 cache: HBM->VMEM moved half the bytes; dequantize here
-            # with the per-(kv head, position) absmax scales
-            k = k * ks_ref[0, 0][:, None]
-            v = v * vs_ref[0, 0][:, None]
         # the trailing partial block (S % bk) arrives with UNSPECIFIED
         # edge-padding bytes on hardware; scores are masked below (p == 0
         # there) but 0 * NaN would still poison dot(p, v) — zero V's tail
@@ -80,11 +75,17 @@ def _decode_kernel(cidx_ref, q_ref, k_ref, v_ref, *rest,
         v = jnp.where(rows < s_total, v, 0.0)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
+        if int8:
+            # int8 cache: HBM->VMEM moved half the bytes. The per-(kv
+            # head, position) absmax scales ride as [1, bk] ROWS, and
+            # scaling the score / probability columns equals dequantizing
+            # K / V first
+            s = s * ks_ref[0, 0]
         cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + ik * block_k
         valid = (cols <= cidx) & (cols < s_total)
         if window is not None:  # Mistral sliding window: cidx - j < window
             valid = valid & (cidx - cols < window)
-        valid = valid & (mask_ref[0] > 0)[None, :]
+        valid = valid & (mask_ref[0] > 0)
         s = jnp.where(valid, s, NEG_INF)
 
         m_prev = m_scr[:]                        # [G, 1]
@@ -94,6 +95,8 @@ def _decode_kernel(cidx_ref, q_ref, k_ref, v_ref, *rest,
         alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
         p = jnp.where(s == NEG_INF, 0.0, jnp.exp(s - m_new))
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if int8:  # the scale row's edge padding is unspecified too
+            p = jnp.where(cols < s_total, p * vs_ref[0, 0], 0.0)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
         m_scr[:] = m_new
@@ -178,11 +181,15 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     qg = q.reshape(B, Hkv, G, D)
     if key_mask is None:
         key_mask = jnp.ones((B, S), jnp.int32)
-    key_mask = key_mask.astype(jnp.int32)
+    # mask [B, S] and scales [B, Hkv, S] gain a unit second-minor axis: a
+    # (1, bk) block of the bare array breaks Mosaic's block-shape rule, the
+    # same data as a row of [.., 1, S] does not
+    key_mask = key_mask.astype(jnp.int32)[:, None]
     cidx = jnp.asarray(cache_index, jnp.int32).reshape(1)
     scales = []
     if int8:
-        scales = [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        scales = [k_scale.astype(jnp.float32)[:, :, None],
+                  v_scale.astype(jnp.float32)[:, :, None]]
 
     nk = _ceil_div(S, bk)
 
@@ -197,10 +204,10 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         return (b, h, jnp.minimum(ik, cidx_ref[0] // bk), 0)
 
     def mask_idx(b, h, ik, cidx_ref):
-        return (b, jnp.minimum(ik, cidx_ref[0] // bk))
+        return (b, 0, jnp.minimum(ik, cidx_ref[0] // bk))
 
     def scale_idx(b, h, ik, cidx_ref):
-        return (b, h, jnp.minimum(ik, cidx_ref[0] // bk))
+        return (b, h, 0, jnp.minimum(ik, cidx_ref[0] // bk))
 
     in_specs = [
         pl.BlockSpec((1, 1, G, D), lambda b, h, ik, *_: (b, h, 0, 0)),
@@ -208,8 +215,8 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         pl.BlockSpec((1, 1, bk, D), kv_idx),
     ]
     if int8:
-        in_specs += [pl.BlockSpec((1, 1, bk), scale_idx)] * 2
-    in_specs.append(pl.BlockSpec((1, bk), mask_idx))
+        in_specs += [pl.BlockSpec((1, 1, 1, bk), scale_idx)] * 2
+    in_specs.append(pl.BlockSpec((1, 1, bk), mask_idx))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, Hkv, nk),
@@ -281,11 +288,10 @@ def _paged_decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, *rest,
         q = q_ref[0, 0].astype(jnp.float32)      # [G, D]
         k = k_ref[0, 0].astype(jnp.float32)      # [bs, D]
         v = v_ref[0, 0].astype(jnp.float32)      # [bs, D]
-        if int8:
-            k = k * ks_ref[0, 0][:, None]
-            v = v * vs_ref[0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
+        if int8:  # [1, bs] scale rows, as in _decode_kernel
+            s = s * ks_ref[0, 0]
         cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
             + ik * block_size
         valid = cols < clen
@@ -301,6 +307,8 @@ def _paged_decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, *rest,
         alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
         p = jnp.where(s == NEG_INF, 0.0, jnp.exp(s - m_new))
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if int8:
+            p = p * vs_ref[0, 0]
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
         m_scr[:] = m_new
@@ -371,18 +379,13 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         pid = bt_ref[b, jnp.minimum(ik, last)]
         return (jnp.minimum(pid, N - 1), h, 0, 0)
 
-    def scale_idx(b, h, ik, bt_ref, cl_ref):
-        last = jnp.maximum(cl_ref[b] - 1, 0) // bs
-        pid = bt_ref[b, jnp.minimum(ik, last)]
-        return (jnp.minimum(pid, N - 1), h, 0)
-
     in_specs = [
         pl.BlockSpec((1, 1, G, D), lambda b, h, ik, *_: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, bs, D), kv_idx),
         pl.BlockSpec((1, 1, bs, D), kv_idx),
     ]
     if int8:
-        in_specs += [pl.BlockSpec((1, 1, bs), scale_idx)] * 2
+        in_specs += [pl.BlockSpec((1, 1, 1, bs), kv_idx)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, Hkv, nb),
@@ -396,7 +399,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     )
     scales = []
     if int8:
-        scales = [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        scales = [k_scale.astype(jnp.float32)[:, :, None],
+                  v_scale.astype(jnp.float32)[:, :, None]]
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, sm_scale=sm_scale,
                           block_size=bs, window=window, int8=int8),
@@ -453,11 +457,10 @@ def _paged_prefill_kernel(bt_ref, cs_ref, cl_ref, q_ref, k_ref, v_ref, *rest,
         q = q_ref[0, 0].astype(jnp.float32)      # [T*G, D]
         k = k_ref[0, 0].astype(jnp.float32)      # [bs, D]
         v = v_ref[0, 0].astype(jnp.float32)      # [bs, D]
-        if int8:
-            k = k * ks_ref[0, 0][:, None]
-            v = v * vs_ref[0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
+        if int8:  # [1, bs] scale rows, as in _decode_kernel
+            s = s * ks_ref[0, 0]
         # row r is the (r // group)-th chunk token at absolute position
         # start + r // group; chunk-padding rows (position >= clen) end up
         # all-masked — their l stays 0 and _finalize writes zeros
@@ -478,6 +481,8 @@ def _paged_prefill_kernel(bt_ref, cs_ref, cl_ref, q_ref, k_ref, v_ref, *rest,
         alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
         p = jnp.where(s == NEG_INF, 0.0, jnp.exp(s - m_new))
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if int8:
+            p = p * vs_ref[0, 0]
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
         m_scr[:] = m_new
@@ -555,18 +560,13 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         pid = bt_ref[b, jnp.minimum(ik, last)]
         return (jnp.minimum(pid, N - 1), h, 0, 0)
 
-    def scale_idx(b, h, ik, bt_ref, cs_ref, cl_ref):
-        last = jnp.maximum(cl_ref[b] - 1, 0) // bs
-        pid = bt_ref[b, jnp.minimum(ik, last)]
-        return (jnp.minimum(pid, N - 1), h, 0)
-
     in_specs = [
         pl.BlockSpec((1, 1, T * G, D), lambda b, h, ik, *_: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, bs, D), kv_idx),
         pl.BlockSpec((1, 1, bs, D), kv_idx),
     ]
     if int8:
-        in_specs += [pl.BlockSpec((1, 1, bs), scale_idx)] * 2
+        in_specs += [pl.BlockSpec((1, 1, 1, bs), kv_idx)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, Hkv, nb),
@@ -581,7 +581,8 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     )
     scales = []
     if int8:
-        scales = [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        scales = [k_scale.astype(jnp.float32)[:, :, None],
+                  v_scale.astype(jnp.float32)[:, :, None]]
     out = pl.pallas_call(
         functools.partial(_paged_prefill_kernel, sm_scale=sm_scale,
                           block_size=bs, group=G, window=window, int8=int8),

@@ -81,7 +81,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         if window is not None:
             valid = valid & (rows + (tk - tq) - cols < window)
         if has_mask:  # [B, Tk] key-padding mask (left-padded prompts)
-            valid = valid & (kmask_ref[0] > 0)[None, :]
+            valid = valid & (kmask_ref[0] > 0)
         s = jnp.where(valid, s, NEG_INF)
 
         m_prev = m_scr[:]                       # [bq, 1]
@@ -99,11 +99,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        # compact [bq] residual: an earlier version lane-broadcast lse (and
-        # delta) to 128 fp32 columns, which cost 8x a bf16 D=64 q-block of
-        # HBM traffic PER INNER STEP in the backward kernels — the r4
-        # scorecard's flash_bwd_dq deficit in one line
-        lse_ref[0, 0] = (m_scr[:] + jnp.log(l_safe))[:, 0]
+        # compact residual, one fp32 per q row (not lane-broadcast to 128
+        # columns). It is stored as a [1, bq] ROW of [B, H, 1, Tq]: a
+        # (1, bq) block of a bare [B, H, Tq] array breaks Mosaic's
+        # block-shape rule
+        lse_ref[0, 0, 0] = (m_scr[:] + jnp.log(l_safe))[:, 0]
 
 
 def _pad_seq(x, block):
@@ -134,8 +134,9 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     if key_mask is not None:
         km = jnp.pad(key_mask.astype(jnp.int32),
                      ((0, 0), (0, Tk_p - key_mask.shape[1])))
-        mask_args = [km]
-        mask_specs = [pl.BlockSpec((1, bk), lambda b, h, iq, ik: (b, ik))]
+        mask_args = [km[:, None]]
+        mask_specs = [pl.BlockSpec((1, 1, bk),
+                                   lambda b, h, iq, ik: (b, 0, ik))]
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
@@ -151,11 +152,11 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
         ] + mask_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, 1, bq), lambda b, h, iq, ik: (b, h, 0, iq)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Tq_p, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Tq_p), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1, Tq_p), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -164,7 +165,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
         ],
         interpret=interpret,
     )(q, k, v, *mask_args)
-    return out[:, :, :Tq], lse[:, :, :Tq]  # lse: compact [B,H,Tq] fp32
+    return out[:, :, :Tq], lse[:, :, 0, :Tq]  # lse: compact [B,H,Tq] fp32
 
 
 # ---------------------------------------------------------------------------
@@ -195,8 +196,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_s
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]            # compact [bq] residual
-        delta = delta_ref[0, 0][:, None]
+        lse = lse_ref[0, 0, 0][:, None]            # compact [bq] residual
+        delta = delta_ref[0, 0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
         rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + iq * block_q
@@ -244,8 +245,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]            # compact [bq] residual
-        delta = delta_ref[0, 0][:, None]
+        lse = lse_ref[0, 0, 0][:, None]            # compact [bq] residual
+        delta = delta_ref[0, 0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
         rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + iq * block_q
@@ -292,6 +293,7 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
         lse = jnp.pad(lse, ((0, 0), (0, 0), (0, pad_q)))
         delta = jnp.pad(delta, ((0, 0), (0, 0), (0, pad_q)))
     Tq_p, Tk_p = q.shape[2], k.shape[2]
+    lse, delta = lse[:, :, None], delta[:, :, None]
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
@@ -303,8 +305,8 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
             pl.BlockSpec((1, 1, bk, D), lambda b, h, iq, ik: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, iq, ik: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, 1, bq), lambda b, h, iq, ik: (b, h, 0, iq)),
+            pl.BlockSpec((1, 1, 1, bq), lambda b, h, iq, ik: (b, h, 0, iq)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Tq_p, D), q.dtype),
@@ -322,8 +324,8 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
             pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, iq: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, iq: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, bq, D), lambda b, h, ik, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, ik, iq: (b, h, iq)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, ik, iq: (b, h, iq)),
+            pl.BlockSpec((1, 1, 1, bq), lambda b, h, ik, iq: (b, h, 0, iq)),
+            pl.BlockSpec((1, 1, 1, bq), lambda b, h, ik, iq: (b, h, 0, iq)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, iq: (b, h, ik, 0)),

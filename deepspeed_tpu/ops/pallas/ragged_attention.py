@@ -30,12 +30,13 @@ kernels in ``decode_attention.py``:
   absolute position ``chunk_start[r] + t`` and sees kv positions <= that —
   decode (one token at ``clen - 1``) and chunk causality are the SAME rule.
 
-Packed-segment mechanics: q-tiles address the packed token axis through a
-dynamic slice at ``(query_start + tile * q_tile) * G`` (G = query heads per
-kv head), so segments need no tile alignment and decode rows cost ONE
-q-tile, not a padded chunk. Tiles wholly beyond ``query_len`` are skipped
-(compute AND copy). Stores are masked per row, so a partial tail tile
-never clobbers the next segment. The packed axis is padded by one tile so
+Packed-segment mechanics: segments need no tile alignment and decode rows
+cost ONE q-tile, not a padded chunk. Mosaic slices the sublane axis
+dynamically only at tile-aligned starts, so a q-tile loads the aligned span
+of packed rows that covers its ``q_tile * G`` rows (G = query heads per kv
+head) and masks the rows outside them. Tiles wholly beyond ``query_len``
+are skipped (compute AND copy). Stores are masked per row, so a span never
+clobbers a neighbouring segment. The packed axis is padded by one span so
 tail tiles never slice out of bounds.
 
 Parity: ``query_len = [1] * B`` with ``chunk_start = context - 1``
@@ -47,6 +48,7 @@ auto-selects: real kernel on TPU, the XLA reference
 """
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -63,7 +65,8 @@ def _ceil_div(a, b):
 
 def _ragged_kernel(bt_ref, qs_ref, ql_ref, cs_ref, cl_ref, q_ref, k_ref,
                    v_ref, *rest, sm_scale: float, block_size: int,
-                   q_tile: int, group: int, window, int8: bool):
+                   q_tile: int, group: int, align: int, span: int, window,
+                   int8: bool):
     if int8:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -84,6 +87,11 @@ def _ragged_kernel(bt_ref, qs_ref, ql_ref, cs_ref, cl_ref, q_ref, k_ref,
     cs = cs_ref[r]
     clen = cl_ref[r]
     rows0 = (qs + it * q_tile) * group        # tile's packed-row offset
+    # Mosaic loads and stores a dynamic sublane slice only at a start it
+    # can prove tile-aligned, and segments are tightly packed — so the
+    # tile works on the ALIGNED span [a0, a0 + span) that covers its rows
+    # [rows0, rows0 + q_tile * group) and masks the few rows outside them
+    a0 = pl.multiple_of((rows0 // align) * align, align)
     # a tile wholly beyond the row's segment is inert; within it, pages
     # wholly beyond the context are skipped (their index map revisits the
     # last real page, so the DMA is also elided); with a sliding window
@@ -93,6 +101,14 @@ def _ragged_kernel(bt_ref, qs_ref, ql_ref, cs_ref, cl_ref, q_ref, k_ref,
     if window is not None:
         run = run & ((ik + 1) * block_size > cs + it * q_tile - window)
 
+    def span_rows(width):
+        # packed row j of the span -> (is one of this tile's real tokens,
+        # that token's index in the row's segment)
+        row = a0 + jax.lax.broadcasted_iota(jnp.int32, (span, width), 0)
+        tok = row // group - qs
+        mine = (row >= rows0) & (row < rows0 + q_tile * group) & (tok < ql)
+        return mine, tok
+
     @pl.when(ik == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
@@ -101,25 +117,23 @@ def _ragged_kernel(bt_ref, qs_ref, ql_ref, cs_ref, cl_ref, q_ref, k_ref,
 
     @pl.when(run)
     def _body():
-        # [q_tile*G, D] slice of this row's packed segment (dynamic start —
-        # segments are tightly packed, not tile-aligned)
-        q = q_ref[0, pl.ds(rows0, q_tile * group), :].astype(jnp.float32)
+        q = q_ref[0, pl.ds(a0, span), :].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)   # [bs, D]
         v = v_ref[0, 0].astype(jnp.float32)
-        if int8:
-            k = k * ks_ref[0, 0][:, None]
-            v = v * vs_ref[0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-        # local row j is the (it*q_tile + j // G)-th token of the row's
-        # segment, at absolute position chunk_start + that; rows past
-        # query_len end up all-masked (l stays 0, store is masked anyway)
-        tok = it * q_tile + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0) // group
+        if int8:
+            # per-position absmax scales ride as [1, bs] ROWS: scaling the
+            # score / probability columns equals dequantizing K / V first
+            s = s * ks_ref[0, 0]
+        # a token at segment index tok sits at absolute position
+        # chunk_start + tok; rows past query_len (or outside the tile) end
+        # up all-masked (l stays 0, the store is masked anyway)
+        mine, tok = span_rows(block_size)
         q_pos = cs + tok
         cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
             + ik * block_size
-        valid = (cols <= q_pos) & (cols < clen) & (tok < ql)
+        valid = mine & (cols <= q_pos) & (cols < clen)
         if window is not None:
             valid = valid & (q_pos - cols < window)
         s = jnp.where(valid, s, NEG_INF)
@@ -132,6 +146,8 @@ def _ragged_kernel(bt_ref, qs_ref, ql_ref, cs_ref, cl_ref, q_ref, k_ref,
         alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
         p = jnp.where(s == NEG_INF, 0.0, jnp.exp(s - m_new))
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if int8:
+            p = p * vs_ref[0, 0]
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
         m_scr[:] = m_new
@@ -141,13 +157,11 @@ def _ragged_kernel(bt_ref, qs_ref, ql_ref, cs_ref, cl_ref, q_ref, k_ref,
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         out = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        # masked store: a partial tail tile spans into the NEXT row's
-        # packed segment — only this row's real tokens may land
-        cur = o_ref[0, pl.ds(rows0, q_tile * group), :]
-        tok = jax.lax.broadcasted_iota(jnp.int32, (q_tile * group, 1), 0) \
-            // group + it * q_tile
-        o_ref[0, pl.ds(rows0, q_tile * group), :] = \
-            jnp.where(tok < ql, out, cur)
+        # masked store: the span reaches into the neighbouring rows'
+        # packed segments — only this tile's real tokens may land
+        mine, _ = span_rows(1)
+        cur = o_ref[0, pl.ds(a0, span), :]
+        o_ref[0, pl.ds(a0, span), :] = jnp.where(mine, out, cur)
 
 
 def _reference_ragged(q, k_pages, v_pages, block_tables, query_start,
@@ -226,13 +240,17 @@ def ragged_paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     R, nb = block_tables.shape
     q_tile = max(1, min(q_tile, T))
     nt = _ceil_div(T, q_tile)
-    # one spare tile of packed padding: a tail tile starting inside the
-    # last segment may slice up to q_tile - 1 rows past T, and a clamped
-    # (shifted) dynamic slice would hand the masked compute WRONG rows
-    T_pad = (nt + 1) * q_tile
+    # sublane tile of the packed q/out blocks (8 rows of 32 bits) and the
+    # aligned span a q-tile works on (see _ragged_kernel); the packed axis
+    # is padded by one span so the last tile never slices out of bounds
+    # (a clamped, shifted dynamic slice would hand the masked compute
+    # WRONG rows)
+    align = 32 // q.dtype.itemsize
+    span = _ceil_div(q_tile * G + align - math.gcd(G, align), align) * align
+    rows = _ceil_div(T * G + span, align) * align
 
     qg = q.reshape(T, Hkv, G, D).transpose(1, 0, 2, 3).reshape(Hkv, T * G, D)
-    qg = jnp.pad(qg, ((0, 0), (0, (T_pad - T) * G), (0, 0)))
+    qg = jnp.pad(qg, ((0, 0), (0, rows - T * G), (0, 0)))
     bt = jnp.asarray(block_tables, jnp.int32)
     qs = jnp.asarray(query_start, jnp.int32)
     ql = jnp.asarray(query_len, jnp.int32)
@@ -250,42 +268,40 @@ def ragged_paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         pid = bt_ref[r, ikc]
         return (jnp.minimum(pid, N - 1), h, 0, 0)
 
-    def scale_idx(h, r, it, ik, bt_ref, qs_ref, ql_ref, cs_ref, cl_ref):
-        last = jnp.maximum(cl_ref[r] - 1, 0) // bs
-        ikc = jnp.where(it * q_tile < ql_ref[r], jnp.minimum(ik, last), 0)
-        pid = bt_ref[r, ikc]
-        return (jnp.minimum(pid, N - 1), h, 0)
-
     in_specs = [
         # the whole packed q for this kv head stays VMEM-resident across
         # its (r, it, ik) subgrid — the index map moves only with h
-        pl.BlockSpec((1, T_pad * G, D), lambda h, r, it, ik, *_: (h, 0, 0)),
+        pl.BlockSpec((1, rows, D), lambda h, r, it, ik, *_: (h, 0, 0)),
         pl.BlockSpec((1, 1, bs, D), kv_idx),
         pl.BlockSpec((1, 1, bs, D), kv_idx),
     ]
+    scales = []
     if int8:
-        in_specs += [pl.BlockSpec((1, 1, bs), scale_idx)] * 2
+        # [N, Hkv, bs] -> [N, Hkv, 1, bs]: a (1, bs) block of the 3-D
+        # array breaks Mosaic's block-shape rule, the same data as a row
+        # of a 4-D array does not
+        in_specs += [pl.BlockSpec((1, 1, 1, bs), kv_idx)] * 2
+        scales = [k_scale.astype(jnp.float32)[:, :, None],
+                  v_scale.astype(jnp.float32)[:, :, None]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(Hkv, R, nt, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, T_pad * G, D),
+        out_specs=pl.BlockSpec((1, rows, D),
                                lambda h, r, it, ik, *_: (h, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((q_tile * G, 1), jnp.float32),
-            pltpu.VMEM((q_tile * G, 1), jnp.float32),
-            pltpu.VMEM((q_tile * G, D), jnp.float32),
+            pltpu.VMEM((span, 1), jnp.float32),
+            pltpu.VMEM((span, 1), jnp.float32),
+            pltpu.VMEM((span, D), jnp.float32),
         ],
     )
-    scales = []
-    if int8:
-        scales = [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, sm_scale=sm_scale, block_size=bs,
-                          q_tile=q_tile, group=G, window=window, int8=int8),
+                          q_tile=q_tile, group=G, align=align, span=span,
+                          window=window, int8=int8),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Hkv, T_pad * G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((Hkv, rows, D), q.dtype),
         interpret=interpret,
     )(bt, qs, ql, cs, cl, qg, k_pages, v_pages, *scales)
-    return out.reshape(Hkv, T_pad, G, D).transpose(1, 0, 2, 3) \
-        .reshape(T_pad, H, D)[:T]
+    return out[:, :T * G].reshape(Hkv, T, G, D).transpose(1, 0, 2, 3) \
+        .reshape(T, H, D)

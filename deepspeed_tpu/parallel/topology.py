@@ -116,18 +116,14 @@ def build_mesh(topology: Optional[MeshTopology] = None,
     # Auto axis types: the XLA SPMD partitioner owns resharding decisions
     # (our design premise — collectives are inserted by the compiler, not
     # spelled per-op as jax 0.9's Explicit mode would require).
-    # Older jax has no AxisType at all (everything is Auto there already).
-    axis_type = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-    axis_kwargs = {} if axis_type is None else {
-        "axis_types": (axis_type,) * len(MESH_AXES)}
+    axis_types = (jax.sharding.AxisType.Auto,) * len(MESH_AXES)
     if default_devices:
-        # jax.make_mesh lays axes onto the physical ICI topology.
-        try:
-            return jax.make_mesh(sizes, MESH_AXES, **axis_kwargs)
-        except Exception:
-            pass
+        # jax.make_mesh lays axes onto the physical ICI topology; a layout
+        # it cannot make is an error, never a silent reshape of
+        # jax.devices() in enumeration order
+        return jax.make_mesh(sizes, MESH_AXES, axis_types=axis_types)
     mesh_devices = np.asarray(devices).reshape(sizes)
-    return Mesh(mesh_devices, MESH_AXES, **axis_kwargs)
+    return Mesh(mesh_devices, MESH_AXES, axis_types=axis_types)
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,6 @@ from typing import Any, Dict, Iterator, Optional
 
 import jax
 
-from ..utils.jax_compat import shard_map as _compat_shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
@@ -170,10 +169,11 @@ def _pipeline_loss_fn(pipe_module: PipelineModule, mesh, num_microbatches: int,
                 f"global batch {lead} must divide dp*micro_batches = "
                 f"{dp}*{M} (each data shard runs {M} equal microbatches)")
         batch_spec = P(BATCH_AXES)
-        fn = _compat_shard_map(spmd, mesh=mesh, axis_names=frozenset(manual_axes),
-                           in_specs=(pipe_module.in_specs(params), batch_spec,
-                                     batch_spec, P()),
-                           out_specs=P(), check_vma=False)
+        fn = jax.shard_map(
+            spmd, mesh=mesh, axis_names=frozenset(manual_axes),
+            in_specs=(pipe_module.in_specs(params), batch_spec, batch_spec,
+                      P()),
+            out_specs=P(), check_vma=False)
         return fn(params, inputs, labels, rng), ()
 
     loss_fn.casts_params = True  # engine must not pre-cast (see spmd)
@@ -366,7 +366,7 @@ def _pipeline_1f1b_loss_fn(pipe_module: PipelineModule, mesh,
     def run(params, inputs, labels, rng):
         grad_spec = {k: (P("pipe") if k == "stages" else P())
                      for k in params}
-        fn = _compat_shard_map(
+        fn = jax.shard_map(
             spmd, mesh=mesh, axis_names=frozenset(manual_axes),
             in_specs=(pipe_module.in_specs(params), P(BATCH_AXES),
                       P(BATCH_AXES), P()),

@@ -276,10 +276,18 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             params = jax.tree_util.tree_map(jax.device_put, params, self.param_shardings)
             opt_state = jax.jit(self.optimizer.init,
                                 out_shardings=self.opt_shardings)(params)
-        loss_scale = create_loss_scaler(self._config.fp16) if self.fp16_enabled else None
-        self.state = TrainState(step=jnp.zeros([], jnp.int32), params=params,
+        # the scalar leaves are placed on the mesh like every other leaf:
+        # the step's outputs carry the mesh in their type, and an initial
+        # state that does not would give step 0 a cache key (and a compile)
+        # of its own
+        on_mesh = lambda x: jax.device_put(x, self._replicated)
+        loss_scale = jax.tree_util.tree_map(
+            on_mesh, create_loss_scaler(self._config.fp16)) \
+            if self.fp16_enabled else None
+        self.state = TrainState(step=on_mesh(jnp.zeros([], jnp.int32)),
+                                params=params,
                                 opt_state=opt_state, loss_scale=loss_scale,
-                                skipped_steps=jnp.zeros([], jnp.int32))
+                                skipped_steps=on_mesh(jnp.zeros([], jnp.int32)))
         self.state_shardings = TrainState(
             step=self._replicated, params=self.param_shardings,
             opt_state=self.opt_shardings if not self._offload else (),

@@ -43,7 +43,6 @@ from typing import Any, NamedTuple
 
 import jax
 
-from ..utils.jax_compat import shard_map as _compat_shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.flatten_util import ravel_pytree
@@ -261,7 +260,7 @@ def build_onebit_wire(engine, opt_params: dict, kind: str = "onebitadam"):
         ls = state.loss_scale
         lscale = ls.cur_scale if (fp16 and ls is not None) \
             else jnp.float32(1.0)
-        fn = _compat_shard_map(
+        fn = jax.shard_map(
             spmd, mesh=mesh, axis_names=frozenset(axes),
             in_specs=(P(), P(), P(), P(axes), P(axes), P(), P(), P(),
                       P(None, axes), P(), P()),

@@ -30,7 +30,6 @@ scaling), and none of MoQ / PLD / compression-training.
 
 import jax
 
-from ..utils.jax_compat import shard_map as _compat_shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -177,7 +176,7 @@ def build_sparse_dp_step(engine):
     batch_spec = P(None, axes)
 
     def train_step(state, batch, rng):
-        fn = _compat_shard_map(
+        fn = jax.shard_map(
             spmd, mesh=mesh, axis_names=frozenset(axes),
             in_specs=(P(), P(), batch_spec, P()),
             out_specs=(P(), P(), P(), P(), P()),

@@ -132,9 +132,7 @@ def sparse_all_reduce(st: SparseTensor, axis_name="data") -> SparseTensor:
     pads ranks to a common row count before its allgather — here the static
     capacity already makes every rank's slice the same shape).
     """
-    from ..utils.jax_compat import axis_size
-
-    world = axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     # log the PRE-gather per-rank payload — the same convention as the dense
     # helpers (compressed.py:97 logs x.size before pmean), so dense-vs-sparse
     # comms_dict comparisons are apples-to-apples

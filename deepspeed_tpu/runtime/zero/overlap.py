@@ -65,7 +65,6 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...comm import comm as dist
-from ...utils.jax_compat import shard_map as _compat_shard_map
 from .partition import zero1_chunk_sizes, zero1_state_shardings
 
 #: optimizers whose update is elementwise over the flat param space —
@@ -379,7 +378,7 @@ def build_overlap_step(engine):
             return jax.tree_util.tree_map(
                 lambda x: x[None] if getattr(x, "ndim", 0) >= 1 else x, st)
 
-        init_fn = _compat_shard_map(
+        init_fn = jax.shard_map(
             init_spmd, mesh=mesh, axis_names=frozenset(axes),
             in_specs=(repl_spec,), out_specs=opt_specs, check_vma=False)
         opt_state = jax.jit(init_fn)(params0)
@@ -508,7 +507,7 @@ def build_overlap_step(engine):
         ls = state.loss_scale
         lscale = ls.cur_scale if (fp16 and ls is not None) \
             else jnp.float32(1.0)
-        fn = _compat_shard_map(
+        fn = jax.shard_map(
             spmd, mesh=mesh, axis_names=frozenset(axes),
             in_specs=(repl_spec, opt_specs, P(None, axes), repl_spec,
                       repl_spec),

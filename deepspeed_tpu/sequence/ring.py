@@ -16,7 +16,6 @@ strategy of the ring-attention paper without bespoke backward plumbing.
 
 import jax
 
-from ..utils.jax_compat import shard_map as _compat_shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
@@ -85,7 +84,7 @@ def ring_attention(q, k, v, causal: bool = True, mesh=None, axis: str = "seq"):
     # manual only over the ring axis; batch/head dims stay auto-partitioned
     # (specs may only name manual axes)
     spec = P(None, axis)
-    fn = _compat_shard_map(
+    fn = jax.shard_map(
         lambda a, b, c: _ring_local(a, b, c, n_shards=sp, causal=causal, axis=axis),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         axis_names=frozenset({axis}), check_vma=False)

@@ -17,7 +17,6 @@ all_to_alls, which ride ICI. Head count must divide the ``seq`` axis size.
 
 import jax
 
-from ..utils.jax_compat import shard_map as _compat_shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import BATCH_AXES, get_mesh
@@ -56,9 +55,7 @@ def ulysses_attention(q, k, v, causal: bool = False, bias=None,
     # manual, seq/model auto) a sharding constraint may only name the AUTO
     # axes — the manual ones are already per-device. Dropping them keeps the
     # head<->seq reshard meaningful exactly where the partitioner acts.
-    from ..utils.jax_compat import manual_axis_names
-
-    manual = manual_axis_names()
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
 
     def free(axes):
         kept = tuple(a for a in (axes if isinstance(axes, (tuple, list))
@@ -155,9 +152,8 @@ def ulysses_flash_attention(q, k, v, causal: bool = True, mesh=None,
     else:
         spec = P(None, "seq")
         manual = frozenset({"seq"})
-    fn = _compat_shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, axis_names=manual,
-                       check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, axis_names=manual, check_vma=False)
     if not any(isinstance(x, jax.core.Tracer) for x in (q, k, v)):
         return jax.jit(fn)(q, k, v)  # partial-manual needs a jit trace
     return fn(q, k, v)

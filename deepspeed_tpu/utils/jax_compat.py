@@ -1,115 +1,39 @@
-"""Version-tolerant jax shims (this repo targets the jax 0.5+ surface but
-must also run on 0.4.x jaxlibs).
-
-- ``force_cpu_devices(n)``: ``jax.config.update("jax_num_cpu_devices", n)``
-  only exists in newer jax; older jaxlibs spell it as the
-  ``--xla_force_host_platform_device_count`` XLA flag, which must be in the
-  environment before the backend initializes. Test conftest, the launcher
-  worker shim and the benches all funnel through here.
-- ``shard_map(...)``: the 0.5+ top-level ``jax.shard_map`` (``axis_names=``
-  partial-manual, ``check_vma=``) mapped onto 0.4.x's
-  ``jax.experimental.shard_map.shard_map`` (``auto=`` complement,
-  ``check_rep=``).
-- ``manual_axis_names()``: the ``jax.sharding.get_abstract_mesh()``
-  manual-axes probe, empty on jax versions without an abstract-mesh API.
+"""Process-level jax set-up shared by the entry points (tests, CLIs, benches,
+``chip_smoke.py``): which platform the process runs on and where its
+persistent compilation cache lives. Library code never calls these.
 """
 
 import os
 
-
-def _with_device_count_flag(flags: str, n: int) -> str:
-    import re
-
-    flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "", flags)
-    return (flags + f" --xla_force_host_platform_device_count={n}").strip()
+#: the checkout this package was imported from
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def force_cpu_devices(n=8) -> None:
-    """Best effort: make jax run on the CPU platform with ``n`` virtual
-    devices (``n=None``: switch the platform only, leaving any externally
-    configured device count untouched).
+    """Run this process on the CPU platform with ``n`` virtual devices
+    (``n=None``: switch the platform only). Call before the backend
+    initializes — the ``--cpu`` rehearsal switch of the CLIs and the test
+    harness."""
+    import jax
 
-    Sets the env knobs first (they win when jax has not been imported yet),
-    then applies the config-route overrides that also work when jax was
-    pre-imported but the backend is still cold. Safe to call twice.
-    """
-    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
     if n is not None:
-        os.environ["XLA_FLAGS"] = _with_device_count_flag(
-            os.environ.get("XLA_FLAGS", ""), n)
-
-    import jax
-
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except (AttributeError, RuntimeError):
-        pass
-    if n is None:
-        return
-    try:
         jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        pass  # old jax: the XLA_FLAGS route above covers it
-    except RuntimeError:
-        pass  # backend already initialized; nothing more to do
 
 
-def shard_map(f, mesh=None, in_specs=None, out_specs=None, axis_names=None,
-              check_vma=None, **kwargs):
-    """``jax.shard_map`` surface on either jax generation.
+def configure_compile_cache() -> str:
+    """Place jax's persistent compilation cache and return its directory.
 
-    ``axis_names`` (0.5+: the MANUAL axes; everything else stays
-    auto-partitioned) maps to 0.4.x's ``auto=`` complement; ``check_vma``
-    maps to ``check_rep``.
-    """
-    import jax
+    ``JAX_COMPILATION_CACHE_DIR`` wins when set (jax reads it itself, and
+    whoever set it knows where caches survive); otherwise the cache goes to
+    ``<checkout>/.jax_cache`` (git-ignored) — a fixed path, because the path
+    is part of the cache key, and inside the checkout, because that is the
+    one directory a chip call brings along."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        import jax
 
-    if hasattr(jax, "shard_map"):
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    if axis_names is not None and mesh is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            shape = dict(zip(mesh.axis_names, mesh.devices.shape))
-            sharded_auto = [a for a in auto if shape.get(a, 1) > 1]
-            if sharded_auto:
-                # 0.4.x XLA hard-ABORTS (not errors) compiling a
-                # partial-manual program whose auto remainder is actually
-                # sharded — fail loudly in Python instead of killing the
-                # process mid-compile
-                raise NotImplementedError(
-                    f"partial-manual shard_map with sharded auto axes "
-                    f"{sorted(sharded_auto)} requires jax >= 0.5 "
-                    f"(this is jax {__import__('jax').__version__})")
-            kwargs["auto"] = auto
-    if check_vma is not None:
-        kwargs["check_rep"] = bool(check_vma)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def axis_size(axis_name):
-    """``lax.axis_size`` on either jax generation. Inside a (shard_map)
-    traced body; ``lax.psum(1, axis)`` is the classic static-size idiom on
-    jaxes that predate the named accessor."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def manual_axis_names():
-    """Axis names currently under manual (shard_map) control at trace time;
-    empty when this jax has no abstract-mesh introspection."""
-    import jax
-
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return set()
-    return set(getattr(get(), "manual_axes", ()) or ())
+        path = os.path.join(_CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

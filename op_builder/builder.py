@@ -5,12 +5,15 @@ Counterpart of the reference's ``op_builder/builder.py`` (``OpBuilder`` ABC
 ``op_builder/__init__.py:32``). Deliberately much smaller: TPU compute
 kernels are Pallas (JIT by construction), so native builds exist only for
 host-side ops — the SIMD CPU optimizers and the async-IO module. No
-nvcc/hipify machinery; one g++ invocation per op, cached by source mtime.
-Loading returns a ``ctypes.CDLL`` (no pybind11 in this environment).
+nvcc/hipify machinery; one g++ invocation per op, cached by source mtime
+per building machine. Loading returns a ``ctypes.CDLL`` (no pybind11 in this
+environment).
 """
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import sys
 from typing import Dict, List, Optional
@@ -24,7 +27,26 @@ _csrc_candidates = [os.path.join(REPO_ROOT, "deepspeed_tpu", "csrc"),
                     os.path.join(REPO_ROOT, "csrc")]
 CSRC = next((p for p in _csrc_candidates if os.path.isdir(p)),
             _csrc_candidates[0])
-BUILD_DIR = os.path.join(CSRC, "build")
+
+
+def _machine_tag() -> str:
+    """Identity of the CPU the libs are built for: ``-march=native`` code
+    runs only where the building machine's instruction set does, and a
+    checkout's disk can travel to another machine (the chip tool copies
+    it), so every machine builds into — and loads from — its own
+    sub-directory."""
+    features = ""
+    try:
+        with open("/proc/cpuinfo") as f:
+            features = next((line for line in f
+                             if line.startswith(("flags", "Features"))), "")
+    except OSError:
+        pass
+    digest = hashlib.sha1(features.encode()).hexdigest()[:12]
+    return f"{platform.machine()}-{digest}"
+
+
+BUILD_DIR = os.path.join(CSRC, "build", _machine_tag())
 
 
 class OpBuilder:

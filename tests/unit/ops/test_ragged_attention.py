@@ -190,3 +190,32 @@ def test_cpu_fallback_auto_routes_to_reference():
         claimed[c:c + n] = True
     np.testing.assert_allclose(auto[claimed], kern[claimed], rtol=2e-5,
                                atol=2e-5)
+
+
+@pytest.mark.parametrize("heads,dtype,q_tile", [
+    (2, jnp.float32, 8),     # G=1: the span is mostly alignment slack
+    (8, jnp.bfloat16, 8),    # G=4, 16-row bf16 tiles (the Mistral shape)
+    (6, jnp.float32, 4),     # G=3: rows0 shares no factor with the tile
+    (16, jnp.bfloat16, 8),   # G=8
+])
+def test_aligned_span_covers_any_group_and_dtype(heads, dtype, q_tile):
+    """A q-tile works on the ALIGNED span of packed rows around its own
+    (Mosaic slices sublanes dynamically only at tile-aligned starts): for
+    every heads-per-kv-head count and tile height the span's extra rows
+    must neither leak into a neighbouring segment nor drop a real token."""
+    setup = _mixed_setup(np.random.RandomState(29), ROWS, H=heads)
+    pool, q, bt, qs, ql, cs, cl, segs = setup
+    q = q.astype(dtype)
+    args = (q, pool["k"], pool["v"], bt, qs, ql, cs, cl)
+    kern = np.asarray(ragged_paged_attention(
+        *args, q_tile=q_tile, window=6, interpret=True,
+        force_pallas=True).astype(jnp.float32))
+    ref = np.asarray(ragged_paged_attention(*args, window=6)
+                     .astype(jnp.float32))
+    claimed = np.zeros(q.shape[0], bool)
+    for _, c, n in segs:
+        claimed[c:c + n] = True
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2  # bf16 output rounding
+    np.testing.assert_allclose(kern[claimed], ref[claimed], rtol=tol,
+                               atol=tol)
+    assert not np.any(kern[~claimed]), "unclaimed packed rows must be zero"

@@ -1,0 +1,138 @@
+"""The Pallas kernels of the main path must COMPILE for the chip.
+
+Interpret mode (every other kernel test) runs the kernel's Python body and
+says nothing about Mosaic's layout rules: before PR 21 every attention
+kernel here passed its parity tests and was refused by the TPU compiler at
+real widths (block-shape rule, unaligned dynamic sublane slices). The TPU
+compiler is installed with jax and compiles for a chip that is described,
+not attached (on-chip-measurement guide §2) — so each kernel is lowered and
+compiled for a ``v5e:2x2`` topology at ``chip_smoke.py``'s widths (H32 /
+Hkv8 / D128, bf16). Nothing runs: this guards layouts, not results.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
+from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.fused_adam import _run_leaf
+from deepspeed_tpu.ops.pallas.quant_matmul import quant_matmul
+from deepspeed_tpu.ops.pallas.ragged_attention import ragged_paged_attention
+
+H, HKV, D = 32, 8, 128
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one described v5e chip; the persistent compile cache is
+    off around the module (a TPU executable written here could never be
+    read back without a chip, and the next run would warn about it)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _scale_kw(scales):
+    return dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
+
+
+def _flash(bwd, window):
+    q = ((1, 2048, H, D), BF16)
+    fwd = functools.partial(flash_attention, causal=True, interpret=False,
+                            window=window)
+    if not bwd:
+        return fwd, [q, q, q]
+    loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2)), [q, q, q]
+
+
+def _ragged(int8, window):
+    T, N, bs, R, nb = 135, 2048, 16, 8, 64  # the smoke's mixed step
+    pages = ((N, HKV, bs, D), jnp.int8 if int8 else BF16)
+    row = ((R,), jnp.int32)
+    args = [((T, H, D), BF16), pages, pages, ((R, nb), jnp.int32),
+            row, row, row, row]
+    if int8:
+        args += [((N, HKV, bs), jnp.float32)] * 2
+
+    def fn(q, k, v, bt, qs, ql, cs, cl, *scales):
+        return ragged_paged_attention(q, k, v, bt, qs, ql, cs, cl,
+                                      interpret=False, window=window,
+                                      **_scale_kw(scales))
+
+    return fn, args
+
+
+def _decode(int8):
+    B, S = 8, 4096
+    cache = ((B, HKV, S, D), jnp.int8 if int8 else BF16)
+    args = [((B, H, D), BF16), cache, cache, ((), jnp.int32)]
+    if int8:
+        args += [((B, HKV, S), jnp.float32)] * 2
+
+    def fn(q, k, v, idx, *scales):
+        return decode_attention(q, k, v, idx, interpret=False,
+                                **_scale_kw(scales))
+
+    return fn, args
+
+
+def _quant_matmul(mode):
+    K, N = 4096, 14336
+    wq = ((K // 2, N), jnp.uint8) if mode == "int4" else ((K, N), jnp.int8)
+    groups = K // 64 if mode == "int4" else 1
+    fn = functools.partial(quant_matmul, mode=mode, interpret=False)
+    return fn, [((32, K), BF16), wq, ((groups, N), jnp.float32)]
+
+
+def _fused_adam():
+    leaf = ((4096, 14336), jnp.float32)
+    fn = functools.partial(_run_leaf, b1=0.9, b2=0.999, eps=1e-8,
+                           weight_decay=0.1, adam_w_mode=True,
+                           interpret=False)
+    return fn, [leaf] * 4 + [((3,), jnp.float32)]
+
+
+CASES = {
+    "flash_fwd": lambda: _flash(False, None),
+    "flash_fwd_window": lambda: _flash(False, 1024),
+    "flash_fwd_bwd": lambda: _flash(True, None),
+    "flash_fwd_bwd_window": lambda: _flash(True, 1024),
+    "ragged_bf16": lambda: _ragged(False, None),
+    "ragged_bf16_window": lambda: _ragged(False, 4096),
+    "ragged_int8": lambda: _ragged(True, None),
+    "ragged_int8_window": lambda: _ragged(True, 4096),
+    "decode_bf16": lambda: _decode(False),
+    "decode_int8": lambda: _decode(True),
+    "quant_matmul_int8": lambda: _quant_matmul("int8"),
+    "quant_matmul_int4": lambda: _quant_matmul("int4"),
+    "fused_adam_leaf": _fused_adam,
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(chip, name):
+    fn, args = CASES[name]()
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+              for shape, dtype in args]
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "the compiled program holds no Mosaic kernel"

@@ -202,16 +202,15 @@ def test_quantized_psum_matches_psum_within_band():
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.comm import quantized_psum
-    from deepspeed_tpu.utils.jax_compat import shard_map
 
     mesh = build_mesh(data=2, model=4)
     x = np.random.RandomState(0).randn(8, 4, 260).astype(np.float32)
 
     def run(fn):
-        f = jax.jit(shard_map(fn, mesh=mesh,
-                              in_specs=P(None, None, "model"),
-                              out_specs=P(None, None, None),
-                              check_vma=False))
+        f = jax.jit(jax.shard_map(fn, mesh=mesh,
+                                  in_specs=P(None, None, "model"),
+                                  out_specs=P(None, None, None),
+                                  check_vma=False))
         return np.asarray(f(jnp.asarray(x)))
 
     out = run(lambda xl: quantized_psum(xl, "model"))
@@ -224,13 +223,12 @@ def test_quantized_psum_world_one_is_exact():
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.comm import quantized_psum
-    from deepspeed_tpu.utils.jax_compat import shard_map
 
     mesh = build_mesh(data=8, model=1)
     x = np.random.RandomState(1).randn(4, 130).astype(np.float32)
-    f = jax.jit(shard_map(lambda xl: quantized_psum(xl, "model"),
-                          mesh=mesh, in_specs=P(None, "model"),
-                          out_specs=P(None, None), check_vma=False))
+    f = jax.jit(jax.shard_map(lambda xl: quantized_psum(xl, "model"),
+                              mesh=mesh, in_specs=P(None, "model"),
+                              out_specs=P(None, None), check_vma=False))
     assert np.array_equal(np.asarray(f(jnp.asarray(x))), x)
 
 

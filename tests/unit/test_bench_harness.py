@@ -1,6 +1,7 @@
 """The bench harnesses must always emit one parseable JSON summary line on
-stdout with rc=0 — the round-2 perf evidence was lost to an rc=124 timeout
-kill with nothing emitted (VERDICT r2 weak #1)."""
+stdout — the round-2 perf evidence was lost to an rc=124 timeout kill with
+nothing emitted (VERDICT r2 weak #1) — and exit 0 only with a measurement:
+a null ``value`` is a failed run, reported as one."""
 
 import json
 import os
@@ -43,7 +44,7 @@ def test_bench_aborts_on_stray_bench_process():
             [sys.executable, os.path.join(REPO, "bench.py")],
             env={**os.environ, "DS_BENCH_TINY": "1"},
             capture_output=True, text=True, timeout=120, cwd=REPO)
-        assert r.returncode == 0, r.stderr[-2000:]
+        assert r.returncode != 0, "a null value must not exit 0"
         rec = _last_json(r.stdout)
         assert rec["value"] is None
         assert "stray" in rec["error"] and str(stray.pid) in rec["error"]
@@ -103,11 +104,9 @@ def test_bench_decode_tiny_emits_json():
 def test_bench_unreachable_backend_still_emits_json():
     # force the probe at a backend name that CANNOT exist on ANY host
     # (jax rejects unknown platform names at init): the parent must still
-    # exit 0 with a JSON record carrying an explicit error. The headline
-    # value is ALWAYS null on outage (it must reflect a measurement of
-    # this run's code); any resumable chip-window capture
-    # (BENCH_r*_local/_v2.json) rides along as detail.cached_value with
-    # provenance. NOT the tier-1 cpu value, and not "tpu" either (a real
+    # print a JSON record carrying an explicit error and a null value, and
+    # exit non-zero — no earlier run's number rides on this one. NOT the
+    # tier-1 cpu value, and not "tpu" either (a real
     # TPU VM would initialize it): under JAX_PLATFORMS=cpu a warm jax
     # import occasionally beat the 1s probe deadline, bench.py then
     # launched a REAL candidate subprocess, this test's timeout killed
@@ -118,17 +117,11 @@ def test_bench_unreachable_backend_still_emits_json():
         env={**os.environ, "DS_BENCH_PROBE_S": "5",
              "JAX_PLATFORMS": "ds_bench_test_unreachable"},
         capture_output=True, text=True, timeout=120, cwd=REPO)
-    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.returncode != 0, "a null value must not exit 0"
     rec = _last_json(r.stdout)
     assert "backend unavailable" in rec["error"]
     assert rec["value"] is None
-    sys.path.insert(0, REPO)
-    import bench
-    cached = bench._best_window_capture()
-    if cached is not None:
-        assert rec["detail"]["cached_value"] == cached["value"]
-        assert "chip-window capture" in rec["detail"]["source"]
-        assert rec["detail"]["artifact"] == cached["_artifact"]
+    assert "detail" not in rec
 
 
 def test_attack_axis_order_ranks_by_cost_model():

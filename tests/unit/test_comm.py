@@ -1,6 +1,5 @@
 import jax
 
-from deepspeed_tpu.utils.jax_compat import shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,7 +15,7 @@ def mesh(request):
 
 
 def _smap(mesh, fn, in_specs, out_specs):
-    return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
 
 
 def test_all_reduce_sum(mesh):

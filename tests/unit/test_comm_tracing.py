@@ -17,7 +17,6 @@ from deepspeed_tpu import comm
 from deepspeed_tpu.monitor.registry import MetricsRegistry
 from deepspeed_tpu.monitor.tracing import Tracer
 from deepspeed_tpu.parallel import build_mesh
-from deepspeed_tpu.utils.jax_compat import shard_map
 
 
 @pytest.fixture()
@@ -36,8 +35,8 @@ def _mesh():
 
 def _run(body, x):
     mesh = _mesh()
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=P("data"),
-                             out_specs=P("data")))(x)
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                                 out_specs=P("data")))(x)
 
 
 def test_every_collective_emits_span_and_histogram(observer):
@@ -105,8 +104,8 @@ def test_overhead_disabled_vs_enabled(observer):
         return v
 
     mesh = _mesh()
-    wrapped = shard_map(body, mesh=mesh, in_specs=P("data"),
-                        out_specs=P("data"))
+    wrapped = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                            out_specs=P("data"))
     x = jnp.arange(8.0)
 
     def trace_once():

@@ -9,7 +9,6 @@ import pytest
 
 import jax
 
-from deepspeed_tpu.utils.jax_compat import shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -34,7 +33,7 @@ def _run_compressed(xs, werr, serr):
         out, we2, se2 = compressed_allreduce(x[0], we[0], se[0], "data")
         return out[None], we2[None], se2[None]
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         spmd, mesh=mesh, axis_names={"data"},
         in_specs=(P("data"), P("data"), P("data")),
         out_specs=(P("data"), P("data"), P("data"))))

@@ -195,6 +195,9 @@ def test_device_peaks_lookup():
     assert perf.device_peaks("TPU v4") == (275e12, 1228e9)
     assert perf.device_peaks("cpu") == (None, None)
     assert perf.device_peaks(None) == (None, None)
+    # an accelerator the table does not know is an error, never a default
+    with pytest.raises(KeyError, match="TPU v9"):
+        perf.device_peaks("TPU v9")
 
 
 def test_memory_watermarks_graceful_without_allocator_stats():

@@ -3,7 +3,6 @@
 
 import jax
 
-from deepspeed_tpu.utils.jax_compat import shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -79,8 +78,8 @@ class TestSparseTensor:
             st, _ = SparseTensor.from_dense_bounded(x, capacity=3)
             return sparse_all_reduce(st, "data").to_dense()[None]
 
-        out = jax.jit(shard_map(spmd, mesh=mesh,
-                                    in_specs=P("data"), out_specs=P("data")))(dense)
+        out = jax.jit(jax.shard_map(spmd, mesh=mesh, in_specs=P("data"),
+                                    out_specs=P("data")))(dense)
         expect = jnp.mean(dense, axis=0)
         for shard in range(4):
             # atol for float32 reduction-order noise: the sparse psum

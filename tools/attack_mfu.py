@@ -81,12 +81,10 @@ def measure(cfg, state, cap_s):
         return state["results"][k]
     cmd = [sys.executable, os.path.join(REPO, "bench.py"), "--candidate",
            json.dumps(spec_of(cfg))]
-    env = {**os.environ, "JAX_COMPILATION_CACHE_DIR":
-           "/tmp/deepspeed_tpu_jax_bench_cache"}
     t0 = time.time()
     try:
         r = subprocess.run(cmd, capture_output=True, text=True,
-                           timeout=cap_s, cwd=REPO, env=env)
+                           timeout=cap_s, cwd=REPO)
         lines = [ln for ln in r.stdout.splitlines()
                  if ln.strip().startswith("{")]
         rec = json.loads(lines[-1]) if lines else {

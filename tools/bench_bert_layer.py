@@ -95,8 +95,9 @@ def main():
     args = ap.parse_args()
 
     if args.one:
-        os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                              "/tmp/deepspeed_tpu_jax_bench_cache")
+        from deepspeed_tpu.utils.jax_compat import configure_compile_cache
+
+        configure_compile_cache()
         print(json.dumps(run_point(args.one[0], args.one[1], args.tiny)),
               flush=True)
         return

@@ -27,7 +27,6 @@ import time
 
 import numpy as np
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/deepspeed_tpu_jax_bench_cache")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
@@ -40,13 +39,14 @@ def run_point(batch: int, prompt: int, new: int, tiny: bool,
               ep: int = 1) -> dict:
     import jax
 
-    if tiny:
-        # smoke mode must not wait on a real accelerator (env vars cannot
-        # switch platforms here; the config route always works). ep<=1 keeps
-        # the caller's device-count configuration untouched.
-        from deepspeed_tpu.utils.jax_compat import force_cpu_devices
+    from deepspeed_tpu.utils.jax_compat import (configure_compile_cache,
+                                                force_cpu_devices)
 
+    if tiny:
+        # smoke mode must not wait on a real accelerator. ep<=1 keeps the
+        # caller's device-count configuration untouched.
         force_cpu_devices(ep if ep > 1 else None)
+    configure_compile_cache()
 
     import deepspeed_tpu as ds
 

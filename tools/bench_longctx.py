@@ -21,7 +21,6 @@ import time
 
 import numpy as np
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/deepspeed_tpu_jax_bench_cache")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
@@ -62,6 +61,9 @@ def main():
     import jax.numpy as jnp
 
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    from deepspeed_tpu.utils.jax_compat import configure_compile_cache
+
+    configure_compile_cache()
     from deepspeed_tpu.ops.sparse_attention import (
         BigBirdSparsityConfig, BSLongformerSparsityConfig, sparse_attention)
 

@@ -39,8 +39,6 @@ import time
 
 import numpy as np
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      "/tmp/deepspeed_tpu_jax_bench_cache")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
@@ -61,6 +59,10 @@ def bench(batch: int, hidden: int, intermediate: int, experts: int, k: int,
         jax.config.update("jax_platforms", "cpu")
 
     import flax.linen as nn
+
+    from deepspeed_tpu.utils.jax_compat import configure_compile_cache
+
+    configure_compile_cache()
 
     from deepspeed_tpu.models.mixtral import (MixtralConfig,
                                               MixtralSparseMoeBlock)
