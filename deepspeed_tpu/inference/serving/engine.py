@@ -68,6 +68,7 @@ Overload control and fault recovery (the resilience contract):
   and zero pages leak under any injected fault.
 """
 
+import contextlib
 import dataclasses
 import os
 import threading
@@ -977,7 +978,18 @@ class ServingEngine:
         """Admit + prefill new requests, then run ONE ragged decode step
         over every active slot — bounded by deadlines, the step watchdog
         and the logit guard, so one pathological request or one wedged
-        step never takes the engine down."""
+        step never takes the engine down.
+
+        Spans (``monitor/tracing.py``; ``ds.<name>`` on the profiler's
+        clock, docs/observability.md): ``step`` holds ``expire``,
+        ``promote``, ``admit``, then for the unified engine ``plan``,
+        ``pack``, ``dispatch`` (inside ``compile`` when the call carries
+        one), ``fetch``, ``harvest`` and ``bookkeeping``; each carries the
+        step's number."""
+        with self.tracer.span("step", cat="engine", step=self._step_no):
+            self._step()
+
+    def _step(self) -> None:
         # chaos-drill point: DS_FAULT=stall:tag=serving_step wedges the
         # worker here; a bounded stall must leave the queue drainable
         fault_injection.maybe_stall("stall", tag="serving_step",
@@ -994,13 +1006,15 @@ class ServingEngine:
 
         # 1. deadline sweep: queued requests past deadline are shed at the
         # gate; running ones end terminal TIMEOUT, pages back to the pool
-        now = time.perf_counter()
-        self.sched.expire_queued(now)
-        for slot, req in list(self.sched.active()):
-            if req.state is RequestState.RUNNING and req.expired(now):
-                self.sched.timeout(req, "deadline")
-                self._clear_slot_arrays(slot)
-                self.metrics.requests_timeout += 1
+        tr, step_no = self.tracer, self._step_no
+        with tr.span("expire", cat="host", args={"step": step_no}):
+            now = time.perf_counter()
+            self.sched.expire_queued(now)
+            for slot, req in list(self.sched.active()):
+                if req.state is RequestState.RUNNING and req.expired(now):
+                    self.sched.timeout(req, "deadline")
+                    self._clear_slot_arrays(slot)
+                    self.metrics.requests_timeout += 1
         # 1b. wedged-backend gate, BEFORE any device dispatch: while the
         # previously-abandoned (watchdog-tripped) step is still stuck in
         # device compute, neither prefill nor decode may touch the backend
@@ -1011,9 +1025,8 @@ class ServingEngine:
         if self._wedged is not None:
             if self._wedged.is_alive():
                 self.metrics.watchdog_skips += 1
-                if self.tracer.enabled:
-                    self.tracer.instant("watchdog_skip", cat="engine",
-                                        args={"step": self._step_no})
+                tr.instant("watchdog_skip", cat="engine",
+                           args={"step": step_no})
                 time.sleep(min(0.05, self.config.step_watchdog_s))
                 self._account_reaped()
                 # no record_step: a skipped step's sleep in the latency
@@ -1034,49 +1047,53 @@ class ServingEngine:
         # consuming the step's prefill token budget); brownout caps each
         # admission's remaining token budget
         brownout = self.brownout
-        while True:
-            req = self.sched.admit_next()
-            if req is None:
-                break
-            if brownout:
-                capped = len(req.tokens) + self.config.brownout_max_new_tokens
-                if capped < req.max_new_tokens:
-                    req.max_new_tokens = capped
-                    self.metrics.brownout_admissions += 1
-            if req.prefix_len:
-                # prefix-cache hit: these tokens are SERVED without being
-                # recomputed (their pages were acquired, not refilled —
-                # host-tier hits stream up instead of recomputing)
-                self.metrics.prefix_hits += 1
-                self.metrics.cached_prefill_tokens += req.prefix_len
-                self.metrics.prefill_tokens += req.prefix_len
-            if self.host_tier is not None:
-                if req.host_prefix_len:
-                    self.metrics.kv_host_hits += 1
-                    self.metrics.kv_host_hit_tokens += req.host_prefix_len
-                else:
-                    self.metrics.kv_host_misses += 1
-            if req.host_hits:
-                # host-matched pages: start their async device_put NOW so
-                # the transfers overlap everything the packed step does;
-                # the request's own suffix grants wait only on the fold
-                self._schedule_promotions(req)
-            if self._mixed:
-                # unified path: the request's table row is live from
-                # admission (no sentinel rows — its packed segments carry
-                # their own query_len, so an un-granted row is inert) and
-                # its prompt starts consuming the packed step's budget
-                self._write_table_row(req)
-                continue
-            if self._chunk:
-                continue  # prefill runs below, under the step token budget
-            try:
-                self._prefill(req)
-            except BlockPoolError:
-                raise  # accounting invariant broken — never swallow
-            except Exception as e:
-                self._fail_prefill(req, e)
-        self._account_reaped()
+        with tr.span("admit", cat="host", args={"step": step_no}) as admit:
+            admitted = 0
+            while True:
+                req = self.sched.admit_next()
+                if req is None:
+                    break
+                admitted += 1
+                if brownout:
+                    capped = len(req.tokens) + self.config.brownout_max_new_tokens
+                    if capped < req.max_new_tokens:
+                        req.max_new_tokens = capped
+                        self.metrics.brownout_admissions += 1
+                if req.prefix_len:
+                    # prefix-cache hit: these tokens are SERVED without being
+                    # recomputed (their pages were acquired, not refilled —
+                    # host-tier hits stream up instead of recomputing)
+                    self.metrics.prefix_hits += 1
+                    self.metrics.cached_prefill_tokens += req.prefix_len
+                    self.metrics.prefill_tokens += req.prefix_len
+                if self.host_tier is not None:
+                    if req.host_prefix_len:
+                        self.metrics.kv_host_hits += 1
+                        self.metrics.kv_host_hit_tokens += req.host_prefix_len
+                    else:
+                        self.metrics.kv_host_misses += 1
+                if req.host_hits:
+                    # host-matched pages: start their async device_put NOW so
+                    # the transfers overlap everything the packed step does;
+                    # the request's own suffix grants wait only on the fold
+                    self._schedule_promotions(req)
+                if self._mixed:
+                    # unified path: the request's table row is live from
+                    # admission (no sentinel rows — its packed segments carry
+                    # their own query_len, so an un-granted row is inert) and
+                    # its prompt starts consuming the packed step's budget
+                    self._write_table_row(req)
+                    continue
+                if self._chunk:
+                    continue  # prefill runs below, under the step token budget
+                try:
+                    self._prefill(req)
+                except BlockPoolError:
+                    raise  # accounting invariant broken — never swallow
+                except Exception as e:
+                    self._fail_prefill(req, e)
+            self._account_reaped()
+            admit.set(admitted=admitted, queue_depth=self.sched.queue_depth)
         # second pump: a promotion scheduled by THIS step's admission may
         # already be ready — folding it here lets the request take its
         # first suffix grant in the same step. When promotion folds are
@@ -1159,7 +1176,6 @@ class ServingEngine:
                 return self._decode_dispatch(pool, tables, seq_lens,
                                              last_tok, corrupt_j, rng)
 
-            tr = self.tracer
             t_dec = time.perf_counter()
             was_warm = self._decode_warm
             try:
@@ -1167,11 +1183,15 @@ class ServingEngine:
                 # decode invocation contains the XLA compile (often far
                 # beyond any sane step budget) and is never watchdog-judged;
                 # steady-state wedges — the r5 outage class — always are
-                if was_warm:
-                    toks, bad, self.pool = self._guarded(device_step)
-                else:
-                    toks, bad, self.pool = device_step()
-                    self._decode_warm = True
+                with self._compile_span(not was_warm, "decode"), tr.span(
+                        "dispatch", cat="engine", ring="decode_step",
+                        args={"step": step_no, "program": "decode",
+                              "active": len(active)}):
+                    if was_warm:
+                        toks, bad, self.pool = self._guarded(device_step)
+                    else:
+                        toks, bad, self.pool = device_step()
+                        self._decode_warm = True
             except StepWatchdogTimeout as e:
                 log_dist(f"serving: step watchdog tripped: {e}", ranks=[0])
                 self.metrics.watchdog_trips += 1
@@ -1189,18 +1209,14 @@ class ServingEngine:
                 self._flight("watchdog_trip", step=step_no, rids=rids,
                              budget_s=self.config.step_watchdog_s)
             else:
-                t_end = time.perf_counter()
-                if tr.enabled:
-                    tr.complete("decode_step", t_dec, t_end,
-                                cat="engine",
-                                args={"step": step_no,
-                                      "active": len(active)})
                 if was_warm:
                     # first-beat rule for gauges too: the compile-carrying
                     # call's wall time would report a garbage MFU/MBU
-                    self._note_decode_perf(t_end - t_dec, tokens=len(active))
-                toks = np.asarray(toks)
-                bad = np.asarray(bad)
+                    self._note_decode_perf(time.perf_counter() - t_dec,
+                                           tokens=len(active))
+                with tr.span("fetch", cat="host", args={"step": step_no}):
+                    toks = np.asarray(toks)
+                    bad = np.asarray(bad)
                 for slot, req in active:
                     if self.config.logit_guard and bad[slot]:
                         self._quarantine(slot, req, step_no, where="decode")
@@ -1218,9 +1234,12 @@ class ServingEngine:
 
     def _finish_step_bookkeeping(self, t0: float, brownout: bool,
                                  record_latency: bool = True) -> None:
-        if self.tracer.enabled:
-            self.tracer.complete("step", t0, time.perf_counter(),
-                                 cat="engine", args={"step": self._step_no})
+        with self.tracer.span("bookkeeping", cat="host",
+                              args={"step": self._step_no}):
+            self._bookkeeping(t0, brownout, record_latency)
+
+    def _bookkeeping(self, t0: float, brownout: bool,
+                     record_latency: bool) -> None:
         self._step_no += 1
         m = self.metrics
         m.steps += 1
@@ -1445,145 +1464,150 @@ class ServingEngine:
         cfg = self.config
         if self._skip_step_if_wedged(t0, brownout):
             return
+        tr, step_no = self.tracer, self._step_no
+        preempted0 = self.metrics.preemptions
 
-        # prefill grants: round-robin chunk-sized shares of the step's
-        # token budget across mid-prefill residents (admission order);
-        # grants to one request are contiguous, so several rounds simply
-        # extend its packed segment
-        grants = self.sched.plan_prefill_grants(self._chunk_budget,
-                                                self._chunk)
-        # speculation over what the grants left, then page growth sized
-        # to each row's appends (drafts dropped before anyone is evicted)
-        spec_plan = self._plan_speculation(grants)
-        self._grow_decode_pages(spec_plan)
-        # RE-plan grants: growth may have preempted a grantee, and its
-        # share must redistribute to the surviving prefillers instead of
-        # being silently wasted this step. The re-planned total can only
-        # shrink or redistribute (bounded by the same budget and a
-        # smaller owed set), so the packed capacity the speculation plan
-        # was sized against still holds
-        grants = self.sched.plan_prefill_grants(self._chunk_budget,
-                                                self._chunk)
-        for _, req in list(self.sched.active()):
-            if not req.prefilling or req.rid not in grants:
-                continue
-            try:
-                # chaos point: DS_FAULT=flaky_prefill fails ITS request
-                # host-side, before it is packed — everyone else still
-                # rides this step
-                fault_injection.maybe_fail("flaky_prefill",
-                                           exc=RuntimeError,
-                                           tag="serving_prefill",
-                                           step=self._step_no,
-                                           stream=self.fault_stream)
-            except Exception as e:
-                grants.pop(req.rid, None)
-                self._fail_prefill(req, e)
-                continue
-            # COW any chunk-spanned page another sequence still references
-            # (appends into shared pages must be impossible by
-            # construction, not by luck)
-            start, n = req.prefill_done, grants[req.rid]
-            bs = self.block_pool.block_size
-            for idx in range(start // bs, (start + n - 1) // bs + 1):
-                self._ensure_exclusive(req, idx)
-            self._write_table_row(req)
-
-        # pack segments slot-ascending (the ragged kernel's contract) —
-        # decode rows are 1 token (1 + k for a speculating row: the last
-        # committed token plus its drafts, a prefill-like verify segment
-        # starting at seq_len), granted prefill rows up to their grant,
-        # everything else (empty slots, un-granted prefillers) is inert
-        R, T = cfg.max_batch_size, self._mixed_tokens
-        ids = np.zeros((1, T), np.int32)
-        pos = np.full((1, T), -1, np.int32)
-        trow = np.full((1, T), -1, np.int32)
-        row_start = np.zeros((R,), np.int32)
-        row_len = np.zeros((R,), np.int32)
-        row_cs = np.zeros((R,), np.int32)
-        row_cl = np.zeros((R,), np.int32)
-        decodes, prefills = [], []
-        cursor = 0
-        for slot, req in self.sched.active():
-            if req.state is not RequestState.RUNNING:
-                continue
-            if req.prefilling:
-                n = grants.get(req.rid, 0)
-                if not n:
+        with tr.span("plan", cat="host", args={"step": step_no}) as plan:
+            # prefill grants: round-robin chunk-sized shares of the step's
+            # token budget across mid-prefill residents (admission order);
+            # grants to one request are contiguous, so several rounds simply
+            # extend its packed segment
+            grants = self.sched.plan_prefill_grants(self._chunk_budget,
+                                                    self._chunk)
+            # speculation over what the grants left, then page growth sized
+            # to each row's appends (drafts dropped before anyone is evicted)
+            spec_plan = self._plan_speculation(grants)
+            self._grow_decode_pages(spec_plan)
+            # RE-plan grants: growth may have preempted a grantee, and its
+            # share must redistribute to the surviving prefillers instead of
+            # being silently wasted this step. The re-planned total can only
+            # shrink or redistribute (bounded by the same budget and a
+            # smaller owed set), so the packed capacity the speculation plan
+            # was sized against still holds
+            grants = self.sched.plan_prefill_grants(self._chunk_budget,
+                                                    self._chunk)
+            for _, req in list(self.sched.active()):
+                if not req.prefilling or req.rid not in grants:
                     continue
-                start = req.prefill_done
-                ids[0, cursor:cursor + n] = \
-                    req.resume_tokens[start:start + n]
-                pos[0, cursor:cursor + n] = np.arange(start, start + n)
-                trow[0, cursor:cursor + n] = slot
-                row_start[slot], row_len[slot] = cursor, n
-                row_cs[slot], row_cl[slot] = start, start + n
-                prefills.append((slot, req, n,
-                                 start + n >= req.prefill_target))
-                cursor += n
-            else:
-                drafts = spec_plan.get(req.rid) or []
-                n = 1 + len(drafts)
-                ids[0, cursor] = self._last_tok[slot]
-                if drafts:
-                    ids[0, cursor + 1:cursor + n] = drafts
-                pos[0, cursor:cursor + n] = \
-                    np.arange(req.seq_len, req.seq_len + n)
-                trow[0, cursor:cursor + n] = slot
-                row_start[slot], row_len[slot] = cursor, n
-                row_cs[slot], row_cl[slot] = req.seq_len, req.seq_len + n
-                decodes.append((slot, req, drafts))
-                cursor += n
-        assert cursor <= T, f"packed {cursor} tokens into a {T}-token step"
-        if cursor == 0:
-            self._finish_step_bookkeeping(t0, brownout)
-            return
-
-        # corrupt_logits chaos, both tags, as DATA (no recompile): the
-        # serving_step vocabulary pins a decode slot (slot=N, falling back
-        # to the first decode row on a bad/absent pin), serving_prefill
-        # flags the first packed chunk. Each tag is probed only when a
-        # matching row is packed — a bounded (fails=N) spec must spend its
-        # budget on a step it can actually poison
-        corrupt = np.zeros((R,), bool)
-        if decodes:
-            fspec = fault_injection.maybe_flag("corrupt_logits",
-                                               tag="serving_step",
+                try:
+                    # chaos point: DS_FAULT=flaky_prefill fails ITS request
+                    # host-side, before it is packed — everyone else still
+                    # rides this step
+                    fault_injection.maybe_fail("flaky_prefill",
+                                               exc=RuntimeError,
+                                               tag="serving_prefill",
                                                step=self._step_no,
                                                stream=self.fault_stream)
-            if fspec is not None:
-                decode_slots = {s for s, _, _ in decodes}
-                try:
-                    pin = int(fspec.params["slot"])
-                except (KeyError, ValueError):
-                    pin = decodes[0][0]
-                if pin not in decode_slots:
-                    pin = decodes[0][0]
-                corrupt[pin] = True
-        if prefills and fault_injection.maybe_flag(
-                "corrupt_logits", tag="serving_prefill",
-                step=self._step_no,
-                stream=self.fault_stream) is not None:
-            corrupt[prefills[0][0]] = True
+                except Exception as e:
+                    grants.pop(req.rid, None)
+                    self._fail_prefill(req, e)
+                    continue
+                # COW any chunk-spanned page another sequence still references
+                # (appends into shared pages must be impossible by
+                # construction, not by luck)
+                start, n = req.prefill_done, grants[req.rid]
+                bs = self.block_pool.block_size
+                for idx in range(start // bs, (start + n - 1) // bs + 1):
+                    self._ensure_exclusive(req, idx)
+                self._write_table_row(req)
+            plan.set(grants=len(grants),
+                     preempted=self.metrics.preemptions - preempted0)
 
-        # packed width: the full capacity, or — with mixed_step_buckets —
-        # the narrowest compiled bucket that fits this step's packed
-        # tokens (decode-only steps stop paying the full padded batch)
-        W = T
-        if self._bucket_widths is not None:
-            W = next(w for w in self._bucket_widths if w >= cursor)
+        with tr.span("pack", cat="host", args={"step": step_no}):
+            # pack segments slot-ascending (the ragged kernel's contract) —
+            # decode rows are 1 token (1 + k for a speculating row: the last
+            # committed token plus its drafts, a prefill-like verify segment
+            # starting at seq_len), granted prefill rows up to their grant,
+            # everything else (empty slots, un-granted prefillers) is inert
+            R, T = cfg.max_batch_size, self._mixed_tokens
+            ids = np.zeros((1, T), np.int32)
+            pos = np.full((1, T), -1, np.int32)
+            trow = np.full((1, T), -1, np.int32)
+            row_start = np.zeros((R,), np.int32)
+            row_len = np.zeros((R,), np.int32)
+            row_cs = np.zeros((R,), np.int32)
+            row_cl = np.zeros((R,), np.int32)
+            decodes, prefills = [], []
+            cursor = 0
+            for slot, req in self.sched.active():
+                if req.state is not RequestState.RUNNING:
+                    continue
+                if req.prefilling:
+                    n = grants.get(req.rid, 0)
+                    if not n:
+                        continue
+                    start = req.prefill_done
+                    ids[0, cursor:cursor + n] = \
+                        req.resume_tokens[start:start + n]
+                    pos[0, cursor:cursor + n] = np.arange(start, start + n)
+                    trow[0, cursor:cursor + n] = slot
+                    row_start[slot], row_len[slot] = cursor, n
+                    row_cs[slot], row_cl[slot] = start, start + n
+                    prefills.append((slot, req, n,
+                                     start + n >= req.prefill_target))
+                    cursor += n
+                else:
+                    drafts = spec_plan.get(req.rid) or []
+                    n = 1 + len(drafts)
+                    ids[0, cursor] = self._last_tok[slot]
+                    if drafts:
+                        ids[0, cursor + 1:cursor + n] = drafts
+                    pos[0, cursor:cursor + n] = \
+                        np.arange(req.seq_len, req.seq_len + n)
+                    trow[0, cursor:cursor + n] = slot
+                    row_start[slot], row_len[slot] = cursor, n
+                    row_cs[slot], row_cl[slot] = req.seq_len, req.seq_len + n
+                    decodes.append((slot, req, drafts))
+                    cursor += n
+            assert cursor <= T, f"packed {cursor} tokens into a {T}-token step"
+            if cursor == 0:
+                self._finish_step_bookkeeping(t0, brownout)
+                return
 
-        self._rng, rng = jax.random.split(self._rng)
-        step_no = self._step_no
-        # snapshot everything the guarded thread touches on THIS thread
-        # (the watchdog-abandonment rule of the legacy decode step)
-        call_args = (self.engine.params, self.pool,
-                     jnp.asarray(self._tables),
-                     jnp.asarray(ids[:, :W]), jnp.asarray(trow[:, :W]),
-                     jnp.asarray(pos[:, :W]),
-                     jnp.asarray(row_start), jnp.asarray(row_len),
-                     jnp.asarray(row_cs), jnp.asarray(row_cl),
-                     jnp.asarray(corrupt), rng)
+            # corrupt_logits chaos, both tags, as DATA (no recompile): the
+            # serving_step vocabulary pins a decode slot (slot=N, falling back
+            # to the first decode row on a bad/absent pin), serving_prefill
+            # flags the first packed chunk. Each tag is probed only when a
+            # matching row is packed — a bounded (fails=N) spec must spend its
+            # budget on a step it can actually poison
+            corrupt = np.zeros((R,), bool)
+            if decodes:
+                fspec = fault_injection.maybe_flag("corrupt_logits",
+                                                   tag="serving_step",
+                                                   step=self._step_no,
+                                                   stream=self.fault_stream)
+                if fspec is not None:
+                    decode_slots = {s for s, _, _ in decodes}
+                    try:
+                        pin = int(fspec.params["slot"])
+                    except (KeyError, ValueError):
+                        pin = decodes[0][0]
+                    if pin not in decode_slots:
+                        pin = decodes[0][0]
+                    corrupt[pin] = True
+            if prefills and fault_injection.maybe_flag(
+                    "corrupt_logits", tag="serving_prefill",
+                    step=self._step_no,
+                    stream=self.fault_stream) is not None:
+                corrupt[prefills[0][0]] = True
+
+            # packed width: the full capacity, or — with mixed_step_buckets —
+            # the narrowest compiled bucket that fits this step's packed
+            # tokens (decode-only steps stop paying the full padded batch)
+            W = T
+            if self._bucket_widths is not None:
+                W = next(w for w in self._bucket_widths if w >= cursor)
+
+            self._rng, rng = jax.random.split(self._rng)
+            # snapshot everything the guarded thread touches on THIS thread
+            # (the watchdog-abandonment rule of the legacy decode step)
+            call_args = (self.engine.params, self.pool,
+                         jnp.asarray(self._tables),
+                         jnp.asarray(ids[:, :W]), jnp.asarray(trow[:, :W]),
+                         jnp.asarray(pos[:, :W]),
+                         jnp.asarray(row_start), jnp.asarray(row_len),
+                         jnp.asarray(row_cs), jnp.asarray(row_cl),
+                         jnp.asarray(corrupt), rng)
 
         has_prefill = bool(prefills)
 
@@ -1603,19 +1627,33 @@ class ServingEngine:
                                             stream=self.fault_stream)
             return self._mixed_dispatch(call_args, W)
 
-        tr = self.tracer
+        n_decode_packed = sum(1 + len(d) for _, _, d in decodes)
+        n_prefill = cursor - n_decode_packed
+        n_drafted = n_decode_packed - len(decodes)
         t_dev = time.perf_counter()
         # first-beat rule per WIDTH: each bucket's first call carries its
         # own XLA compile and is never watchdog-judged; steady-state
         # wedges always are
         was_warm = W in self._warm_widths
         try:
-            if was_warm:
-                toks, bad, self.pool = self._guarded(device_step)
-            else:
-                toks, bad, self.pool = device_step()
-                self._warm_widths.add(W)
-                self._mixed_warm = True
+            # the one engine span of the unified step, carrying the
+            # per-row decode/prefill/verify token split (what
+            # decode_step + chunked_prefill used to say in two spans)
+            with self._compile_span(not was_warm, self._mixed_name(W)), \
+                    tr.span("dispatch", cat="engine", ring="mixed_step",
+                            args={"step": step_no,
+                                  "decode_tokens": len(decodes),
+                                  "verify_tokens": n_drafted,
+                                  "prefill_tokens": n_prefill,
+                                  "width": W,
+                                  "rows": len(decodes) + len(prefills),
+                                  "context_tokens": int(row_cl.sum())}):
+                if was_warm:
+                    toks, bad, self.pool = self._guarded(device_step)
+                else:
+                    toks, bad, self.pool = device_step()
+                    self._warm_widths.add(W)
+                    self._mixed_warm = True
         except StepWatchdogTimeout as e:
             log_dist(f"serving: step watchdog tripped: {e}", ranks=[0])
             self.metrics.watchdog_trips += 1
@@ -1634,72 +1672,63 @@ class ServingEngine:
                          budget_s=cfg.step_watchdog_s)
         else:
             t_end = time.perf_counter()
-            n_decode_packed = sum(1 + len(d) for _, _, d in decodes)
-            n_prefill = cursor - n_decode_packed
-            n_drafted = n_decode_packed - len(decodes)
-            if tr.enabled:
-                # the one engine span of the unified step, carrying the
-                # per-row decode/prefill/verify token split (what
-                # decode_step + chunked_prefill used to say in two spans)
-                tr.complete("mixed_step", t_dev, t_end, cat="engine",
-                            args={"step": step_no,
-                                  "decode_tokens": len(decodes),
-                                  "verify_tokens": n_drafted,
-                                  "prefill_tokens": n_prefill,
-                                  "width": W,
-                                  "rows": len(decodes) + len(prefills)})
-            toks = np.asarray(toks)
-            bad = np.asarray(bad)
-            committed = 0
-            for slot, req, n, final in prefills:
-                start = req.prefill_done
-                req.prefill_done = start + n
-                req.seq_len = start + n
-                self.metrics.prefill_tokens += n
-                self.metrics.prefill_tokens_computed += n
-                self.metrics.window_tokens += n
-                committed += n
-                # guard EVERY chunk and BEFORE content-indexing: poisoned
-                # KV must never park on the prefix-cache LRU
-                if cfg.logit_guard and bad[slot]:
-                    self._quarantine(slot, req, step_no, where="prefill")
-                    continue
-                self._commit_full_blocks(req)
-                if final:
-                    # last chunk: token one (TTFT ends here) — the row's
-                    # LAST packed position; the slot decodes next step
+            # the host's wait on the device: the tokens come back here
+            with tr.span("fetch", cat="host", args={"step": step_no}):
+                toks = np.asarray(toks)
+                bad = np.asarray(bad)
+            with tr.span("harvest", cat="host",
+                         args={"step": step_no}) as harvest:
+                committed = 0
+                for slot, req, n, final in prefills:
+                    start = req.prefill_done
+                    req.prefill_done = start + n
+                    req.seq_len = start + n
+                    self.metrics.prefill_tokens += n
+                    self.metrics.prefill_tokens_computed += n
+                    self.metrics.window_tokens += n
+                    committed += n
+                    # guard EVERY chunk and BEFORE content-indexing: poisoned
+                    # KV must never park on the prefix-cache LRU
+                    if cfg.logit_guard and bad[slot]:
+                        self._quarantine(slot, req, step_no, where="prefill")
+                        continue
+                    self._commit_full_blocks(req)
+                    if final:
+                        # last chunk: token one (TTFT ends here) — the row's
+                        # LAST packed position; the slot decodes next step
+                        self._seq_lens[slot] = req.seq_len
+                        self._harvest(
+                            req,
+                            int(toks[row_start[slot] + row_len[slot] - 1]))
+                        committed += 1
+                had_verify = False
+                for slot, req, drafts in decodes:
+                    if cfg.logit_guard and bad[slot]:
+                        # one poisoned position anywhere in the row (drafts
+                        # included) fails ITS request; nothing from the row
+                        # commits, so poisoned KV can neither be harvested
+                        # nor content-indexed
+                        self._quarantine(slot, req, step_no, where="decode")
+                        continue
+                    if drafts:
+                        # verify row: greedy accept-prefix over the row's
+                        # k + 1 predictions, rollback past the accepted KV
+                        preds = [int(toks[row_start[slot] + j])
+                                 for j in range(len(drafts) + 1)]
+                        committed += self._commit_verify_row(slot, req,
+                                                             drafts, preds)
+                        had_verify = True
+                        continue
+                    req.seq_len += 1
                     self._seq_lens[slot] = req.seq_len
-                    self._harvest(
-                        req,
-                        int(toks[row_start[slot] + row_len[slot] - 1]))
+                    # a generated token may have just FILLED a page —
+                    # content-index it so identical continuations hit
+                    self._commit_full_blocks(req)
+                    self._harvest(req, int(toks[row_start[slot]]))
                     committed += 1
-            had_verify = False
-            for slot, req, drafts in decodes:
-                if cfg.logit_guard and bad[slot]:
-                    # one poisoned position anywhere in the row (drafts
-                    # included) fails ITS request; nothing from the row
-                    # commits, so poisoned KV can neither be harvested
-                    # nor content-indexed
-                    self._quarantine(slot, req, step_no, where="decode")
-                    continue
-                if drafts:
-                    # verify row: greedy accept-prefix over the row's
-                    # k + 1 predictions, rollback past the accepted KV
-                    preds = [int(toks[row_start[slot] + j])
-                             for j in range(len(drafts) + 1)]
-                    committed += self._commit_verify_row(slot, req,
-                                                         drafts, preds)
-                    had_verify = True
-                    continue
-                req.seq_len += 1
-                self._seq_lens[slot] = req.seq_len
-                # a generated token may have just FILLED a page —
-                # content-index it so identical continuations hit
-                self._commit_full_blocks(req)
-                self._harvest(req, int(toks[row_start[slot]]))
-                committed += 1
-            if had_verify:
-                self.metrics.spec_steps += 1
+                if had_verify:
+                    self.metrics.spec_steps += 1
+                harvest.set(committed=committed)
             if was_warm:
                 # first-beat rule for gauges too (compile wall time would
                 # report garbage utilization). Tokens = what the step
@@ -1852,6 +1881,16 @@ class ServingEngine:
             self.metrics.requests_timeout += len(self.sched.reaped)
             self.sched.reaped.clear()
 
+    def _compile_span(self, compiling: bool, program: str):
+        """A ``compile`` span around a dispatch known to carry an XLA
+        compile (a program's or width's first call), so a trace can put
+        the idle device down to compiling; nothing otherwise."""
+        if not compiling:
+            return contextlib.nullcontext()
+        return self.tracer.span("compile", cat="host",
+                                args={"step": self._step_no,
+                                      "program": program})
+
     def _skip_step_if_wedged(self, t0: float, brownout: bool) -> bool:
         """A watchdog trip EARLIER in this very step (a wedged promotion
         fold) leaves the backend hung: skip the device half entirely —
@@ -1955,6 +1994,12 @@ class ServingEngine:
         q, self._promote_q = self._promote_q, []
         if not q:
             return
+        with self.tracer.span("promote", cat="host",
+                              args={"step": self._step_no,
+                                    "queued": len(q)}):
+            self._fold_promotions(q, wait)
+
+    def _fold_promotions(self, q: List[Any], wait: bool) -> None:
         m = self.metrics
         tr = self.tracer
         still: List[Any] = []
@@ -2271,30 +2316,29 @@ class ServingEngine:
         tokens = req.resume_tokens
         L = len(tokens)
         Tb = next_pow2(max(L, self.config.prefill_bucket_min))
-        self._write_table_row(req)
-        ids = np.zeros((1, Tb), np.int32)
-        ids[0, :L] = tokens
-        fn = self._prefill_fns.get(Tb)
-        if fn is None:
-            fn = self._prefill_fns[Tb] = self._build_prefill(Tb)
-        self._rng, rng = jax.random.split(self._rng)
-        tr = self.tracer
-        t_pf = time.perf_counter() if tr.enabled else 0.0
-        pf_args = (self.engine.params, self.pool,
-                   jnp.asarray(self._tables[req.slot][None]),
-                   jnp.asarray(ids), jnp.asarray([L], np.int32), rng)
         pf_name = f"prefill[{Tb}]"
-        self.perf.observe_call(
-            pf_name,
-            params=self.perf.cached_spec("params", self.engine.params),
-            pool=pf_args[1], table_row=pf_args[2], ids=pf_args[3],
-            length=pf_args[4], rng=rng)
-        tok, bad, self.pool = fn(*pf_args)
-        if self.perf.programs.program(pf_name).cost_pending:
-            self.perf.capture_cost(pf_name, fn, pf_args)
-        if tr.enabled:
-            tr.complete("prefill", t_pf, time.perf_counter(), cat="engine",
-                        args={"rid": req.rid, "tokens": L, "bucket": Tb})
+        with self.tracer.span("dispatch", cat="engine", ring="prefill",
+                              args={"step": self._step_no,
+                                    "program": pf_name, "rid": req.rid,
+                                    "tokens": L, "bucket": Tb}):
+            self._write_table_row(req)
+            ids = np.zeros((1, Tb), np.int32)
+            ids[0, :L] = tokens
+            fn = self._prefill_fns.get(Tb)
+            if fn is None:
+                fn = self._prefill_fns[Tb] = self._build_prefill(Tb)
+            self._rng, rng = jax.random.split(self._rng)
+            pf_args = (self.engine.params, self.pool,
+                       jnp.asarray(self._tables[req.slot][None]),
+                       jnp.asarray(ids), jnp.asarray([L], np.int32), rng)
+            self.perf.observe_call(
+                pf_name,
+                params=self.perf.cached_spec("params", self.engine.params),
+                pool=pf_args[1], table_row=pf_args[2], ids=pf_args[3],
+                length=pf_args[4], rng=rng)
+            tok, bad, self.pool = fn(*pf_args)
+            if self.perf.programs.program(pf_name).cost_pending:
+                self.perf.capture_cost(pf_name, fn, pf_args)
         req.seq_len = L
         req.prefill_done = L
         self._seq_lens[req.slot] = L
@@ -2428,27 +2472,30 @@ class ServingEngine:
         # step watchdog bounds it exactly like decode (a wedged chunk must
         # fail ITS request and keep the engine serving, not hang every
         # tenant); the first call carries the XLA compile and is exempt
-        tr = self.tracer
         t_ck = time.perf_counter()
-        if self._chunked_warm:
-            tok, bad, self.pool = self._guarded(device_call)
-            # warm calls only: the compile-carrying first chunk's wall
-            # time would report a garbage utilization (first-beat rule)
-            self.perf.on_program_step("chunked_prefill",
-                                      time.perf_counter() - t_ck, tokens=n)
-        else:
-            tok, bad, self.pool = device_call()
-            self._chunked_warm = True
-            mcfg = getattr(self.engine.module, "config", None)
-            self.perf.capture_cost(
-                "chunked_prefill", self._chunked_prefill_fn, call_args,
-                fallback=None if mcfg is None else lambda: {
-                    "flops": self._chunk * transformer_flops_per_token(
-                        mcfg, self.config.max_model_len)})
-        if tr.enabled:
-            tr.complete("prefill_chunk", t_ck, time.perf_counter(),
-                        cat="engine",
-                        args={"rid": req.rid, "start": start, "tokens": n})
+        with self._compile_span(not self._chunked_warm, "chunked_prefill"), \
+                self.tracer.span("dispatch", cat="engine",
+                                 ring="prefill_chunk",
+                                 args={"step": self._step_no,
+                                       "program": "chunked_prefill",
+                                       "rid": req.rid, "start": start,
+                                       "tokens": n}):
+            if self._chunked_warm:
+                tok, bad, self.pool = self._guarded(device_call)
+                # warm calls only: the compile-carrying first chunk's wall
+                # time would report a garbage utilization (first-beat rule)
+                self.perf.on_program_step("chunked_prefill",
+                                          time.perf_counter() - t_ck,
+                                          tokens=n)
+            else:
+                tok, bad, self.pool = device_call()
+                self._chunked_warm = True
+                mcfg = getattr(self.engine.module, "config", None)
+                self.perf.capture_cost(
+                    "chunked_prefill", self._chunked_prefill_fn, call_args,
+                    fallback=None if mcfg is None else lambda: {
+                        "flops": self._chunk * transformer_flops_per_token(
+                            mcfg, self.config.max_model_len)})
         req.prefill_done = start + n
         req.seq_len = start + n
         self.metrics.prefill_tokens += n
@@ -2587,36 +2634,45 @@ class ServingEngine:
         R = scfg.max_batch_size
         name = self._mixed_name(t_tokens)
 
-        def mixed_step(params, pool, tables, ids, token_rows, append_pos,
-                       row_start, row_len, chunk_start, context_len,
-                       corrupt, rng):
+        # ds_mixed_step: the XLA module takes the function's name, which
+        # (unlike the scopes inside, which are metadata) is in the compile
+        # cache's key — a cached executable without the names is not reused
+        def ds_mixed_step(params, pool, tables, ids, token_rows, append_pos,
+                          row_start, row_len, chunk_start, context_len,
+                          corrupt, rng):
             # trace-time side effect: runs once per XLA compile
             self.compile_counts["mixed_step"] += 1  # dslint: ignore[trace-closure-state] intentional trace-time compile counter (fires once per XLA compile)
             self.perf.note_compile(name)
             self.tracer.instant("xla_compile", cat="engine",
                                 args={"kind": name})
-            params = self._dequant(params)
-            idx = paged_cache_index(tables, append_pos, context_len,
-                                    chunk_start=chunk_start,
-                                    token_rows=token_rows,
-                                    query_start=row_start,
-                                    query_len=row_len)
-            logits, pool = module.apply({"params": params}, ids, cache=pool,
-                                        cache_index=idx)
-            # multi-position harvest: per-position logits (chaos NaN
-            # applied per flagged row, as DATA) + per-row NaN/Inf flag
-            # OR-reduced over each row's valid tokens — one poisoned
-            # draft position quarantines its request, never the batch
-            lg, bad = harvest_packed_logits(logits, token_rows, R,
-                                            corrupt=corrupt)
-            tok = _sample_logits(lg, rng, scfg.do_sample,
-                                 scfg.temperature, scfg.top_k, scfg.top_p)
-            return tok.astype(jnp.int32), bad, pool
+            # stable trace names: every device operation of the resident
+            # program sits under ds.mixed_step, the model's own ds.* scopes
+            # inside it (docs/observability.md)
+            with jax.named_scope("ds.mixed_step"):
+                params = self._dequant(params)
+                idx = paged_cache_index(tables, append_pos, context_len,
+                                        chunk_start=chunk_start,
+                                        token_rows=token_rows,
+                                        query_start=row_start,
+                                        query_len=row_len)
+                logits, pool = module.apply({"params": params}, ids,
+                                            cache=pool, cache_index=idx)
+                # multi-position harvest: per-position logits (chaos NaN
+                # applied per flagged row, as DATA) + per-row NaN/Inf flag
+                # OR-reduced over each row's valid tokens — one poisoned
+                # draft position quarantines its request, never the batch
+                with jax.named_scope("ds.sample"):
+                    lg, bad = harvest_packed_logits(logits, token_rows, R,
+                                                    corrupt=corrupt)
+                    tok = _sample_logits(lg, rng, scfg.do_sample,
+                                         scfg.temperature, scfg.top_k,
+                                         scfg.top_p)
+                return tok.astype(jnp.int32), bad, pool
 
         # explicit shardings, exactly like the dense engine's generate: TP
         # params keep their NamedShardings, everything else replicates
         r = self.engine._replicated
-        return jax.jit(mixed_step, donate_argnums=self._donate,
+        return jax.jit(ds_mixed_step, donate_argnums=self._donate,
                        in_shardings=(self.engine.param_shardings,)
                        + (r,) * 11,
                        out_shardings=(r, r, r))

@@ -270,6 +270,7 @@ def repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
     return jnp.broadcast_to(x[:, :, :, None, :], (b, t, h, n_rep, d)).reshape(b, t, h * n_rep, d)
 
 
+@jax.named_scope("ds.attention")
 def dot_product_attention(q, k, v, bias=None, causal: bool = False,
                           attention_impl: str = "xla", dropout_rng=None,
                           dropout_rate: float = 0.0, deterministic: bool = True,
@@ -433,6 +434,7 @@ def read_kv_cache(layer_cache, dtype):
     return jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
 
 
+@jax.named_scope("ds.attention")
 def flash_prefill_from_empty(q, k, v, key_mask=None, sm_scale=None,
                              block_q=512, block_k=512, window=None):
     """From-empty cached prefill via the masked flash kernel — the ONE
@@ -451,6 +453,7 @@ def flash_prefill_from_empty(q, k, v, key_mask=None, sm_scale=None,
                            block_k=block_k, window=window)
 
 
+@jax.named_scope("ds.attention")
 def cached_attention_xla(q, layer_cache, cache_index=None, key_mask=None,
                          window=None, scale=None, bias=None):
     """XLA attention over the head-major KV cache with NO cache-sized
@@ -486,6 +489,7 @@ def cached_attention_xla(q, layer_cache, cache_index=None, key_mask=None,
     return jnp.einsum("bhqk,bhkd->bqhd", probs, v)
 
 
+@jax.named_scope("ds.kv_append")
 def update_kv_cache(layer_cache, k, v, cache_index):
     """Append ``[B, T, Hkv, D]`` keys/values at ``cache_index`` (traced ok).
     Only the NEW tokens are transposed into the head-major cache layout
@@ -605,6 +609,7 @@ def is_paged_index(cache_index) -> bool:
     return isinstance(cache_index, dict) and "block_tables" in cache_index
 
 
+@jax.named_scope("ds.kv_append")
 def update_paged_kv_cache(layer_cache, k, v, cache_index):
     """Append fresh ``[B, T, Hkv, D]`` keys/values into the block pool.
 
@@ -680,6 +685,7 @@ def _gather_pages_dense(layer_cache, block_tables, dtype, num_heads):
     return k, v
 
 
+@jax.named_scope("ds.attention")
 def paged_attention_reference(q, layer_cache, block_tables, context_len,
                               window: Optional[int] = None,
                               scale: Optional[float] = None):
@@ -707,6 +713,7 @@ def paged_attention_reference(q, layer_cache, block_tables, context_len,
     return jnp.einsum("bhs,bhsd->bhd", probs, v)
 
 
+@jax.named_scope("ds.attention")
 def paged_prefill_attention_reference(q, layer_cache, block_tables,
                                       append_pos, context_len,
                                       window: Optional[int] = None,
@@ -744,6 +751,7 @@ def paged_prefill_attention_reference(q, layer_cache, block_tables,
     return jnp.einsum("bhqs,bhsd->bqhd", probs, v)
 
 
+@jax.named_scope("ds.attention")
 def ragged_mixed_attention_reference(q, layer_cache, cache_index,
                                      window: Optional[int] = None,
                                      scale: Optional[float] = None):
@@ -968,6 +976,12 @@ def shift_labels(input_ids: jnp.ndarray, ignore_index: int = -100) -> jnp.ndarra
     """HF convention: labels == input_ids; shift left, pad tail with ignore."""
     return jnp.concatenate(
         [input_ids[:, 1:], jnp.full_like(input_ids[:, :1], ignore_index)], axis=1)
+
+
+def head_scope(cache) -> str:
+    """Trace scope of final norm -> head (-> loss): ``ds.lm_head`` on the
+    cached (serving) path, ``ds.lm_head_loss`` where the loss follows."""
+    return "ds.lm_head" if cache is not None else "ds.lm_head_loss"
 
 
 def lm_head_output(parent, cfg, hidden, labels, cache, head_bias=False):

@@ -23,7 +23,8 @@ from jax.sharding import PartitionSpec as P
 
 from .layers import (RMSNorm, apply_rotary,
                      cached_attention_xla, flash_prefill_from_empty,
-                     cross_entropy_loss, lm_head_output, model_dense,
+                     cross_entropy_loss, head_scope, lm_head_output,
+                     model_dense,
                      dot_product_attention, init_kv_cache,
                      init_paged_kv_cache, is_paged_index, key_mask_to_bias,
                      paged_attention_reference,
@@ -166,11 +167,16 @@ class LlamaAttention(nn.Module):
         dense = lambda feats, name, bias=False, row=False: model_dense(
             cfg, feats, name, use_bias=bias, row_parallel=row)
         qb = cfg.attention_qkv_bias
-        q = dense(H * D, "q_proj", qb)(x).reshape(B, T, H, D)
-        k = dense(Hkv * D, "k_proj", qb)(x).reshape(B, T, Hkv, D)
-        v = dense(Hkv * D, "v_proj", qb)(x).reshape(B, T, Hkv, D)
-        q = apply_rotary(q, cos, sin)
-        k = apply_rotary(k, cos, sin)
+        # ds.* scopes: the layer boundaries every model family shares, as
+        # a profiler trace names them (docs/observability.md). ds.attention
+        # holds score-softmax-value only — the Pallas kernels here, their
+        # XLA counterparts in layers.py — so both are measured alike
+        with jax.named_scope("ds.attn_proj"):
+            q = dense(H * D, "q_proj", qb)(x).reshape(B, T, H, D)
+            k = dense(Hkv * D, "k_proj", qb)(x).reshape(B, T, Hkv, D)
+            v = dense(Hkv * D, "v_proj", qb)(x).reshape(B, T, Hkv, D)
+            q = apply_rotary(q, cos, sin)
+            k = apply_rotary(k, cos, sin)
         if layer_cache is not None and is_paged_index(cache_index):
             # paged serving path (inference/serving/): KV appends scatter
             # into the shared block pool through this sequence's block
@@ -188,16 +194,17 @@ class LlamaAttention(nn.Module):
                     from ..ops.pallas.ragged_attention import \
                         ragged_paged_attention
 
-                    out = ragged_paged_attention(
-                        q[0], layer_cache["k"], layer_cache["v"],
-                        cache_index["block_tables"],
-                        cache_index["query_start"],
-                        cache_index["query_len"],
-                        cache_index["chunk_start"],
-                        cache_index["context_len"],
-                        k_scale=layer_cache.get("k_scale"),
-                        v_scale=layer_cache.get("v_scale"),
-                        window=cfg.sliding_window)[None]
+                    with jax.named_scope("ds.attention"):
+                        out = ragged_paged_attention(
+                            q[0], layer_cache["k"], layer_cache["v"],
+                            cache_index["block_tables"],
+                            cache_index["query_start"],
+                            cache_index["query_len"],
+                            cache_index["chunk_start"],
+                            cache_index["context_len"],
+                            k_scale=layer_cache.get("k_scale"),
+                            v_scale=layer_cache.get("v_scale"),
+                            window=cfg.sliding_window)[None]
                 else:
                     out = ragged_mixed_attention_reference(
                         q, layer_cache, cache_index,
@@ -207,13 +214,14 @@ class LlamaAttention(nn.Module):
                     from ..ops.pallas.decode_attention import \
                         paged_decode_attention
 
-                    out = paged_decode_attention(
-                        q[:, 0], layer_cache["k"], layer_cache["v"],
-                        cache_index["block_tables"],
-                        cache_index["context_len"],
-                        k_scale=layer_cache.get("k_scale"),
-                        v_scale=layer_cache.get("v_scale"),
-                        window=cfg.sliding_window)[:, None]
+                    with jax.named_scope("ds.attention"):
+                        out = paged_decode_attention(
+                            q[:, 0], layer_cache["k"], layer_cache["v"],
+                            cache_index["block_tables"],
+                            cache_index["context_len"],
+                            k_scale=layer_cache.get("k_scale"),
+                            v_scale=layer_cache.get("v_scale"),
+                            window=cfg.sliding_window)[:, None]
                 else:
                     out = paged_attention_reference(
                         q[:, 0], layer_cache, cache_index["block_tables"],
@@ -231,14 +239,15 @@ class LlamaAttention(nn.Module):
                     from ..ops.pallas.decode_attention import \
                         paged_prefill_attention
 
-                    out = paged_prefill_attention(
-                        q, layer_cache["k"], layer_cache["v"],
-                        cache_index["block_tables"],
-                        cache_index["chunk_start"],
-                        cache_index["context_len"],
-                        k_scale=layer_cache.get("k_scale"),
-                        v_scale=layer_cache.get("v_scale"),
-                        window=cfg.sliding_window)
+                    with jax.named_scope("ds.attention"):
+                        out = paged_prefill_attention(
+                            q, layer_cache["k"], layer_cache["v"],
+                            cache_index["block_tables"],
+                            cache_index["chunk_start"],
+                            cache_index["context_len"],
+                            k_scale=layer_cache.get("k_scale"),
+                            v_scale=layer_cache.get("v_scale"),
+                            window=cfg.sliding_window)
                 else:
                     out = paged_prefill_attention_reference(
                         q, layer_cache, cache_index["block_tables"],
@@ -275,12 +284,13 @@ class LlamaAttention(nn.Module):
                 # dequantized per block in VMEM (HBM reads stay int8)
                 from ..ops.pallas.decode_attention import decode_attention
 
-                out = decode_attention(q[:, 0], layer_cache["k"],
-                                       layer_cache["v"], cache_index,
-                                       key_mask=mask,
-                                       k_scale=layer_cache.get("k_scale"),
-                                       v_scale=layer_cache.get("v_scale"),
-                                       window=cfg.sliding_window)[:, None]
+                with jax.named_scope("ds.attention"):
+                    out = decode_attention(
+                        q[:, 0], layer_cache["k"], layer_cache["v"],
+                        cache_index, key_mask=mask,
+                        k_scale=layer_cache.get("k_scale"),
+                        v_scale=layer_cache.get("v_scale"),
+                        window=cfg.sliding_window)[:, None]
             elif T > 1 and cfg.prefill_flash_from_empty:
                 # from-empty prefill over the FRESH K/V (== cache attention
                 # when nothing precedes it; see the config flag's contract):
@@ -306,8 +316,10 @@ class LlamaAttention(nn.Module):
                                         flash_block_q=cfg.flash_block_q,
                                         flash_block_k=cfg.flash_block_k,
                                         window=cfg.sliding_window)
-        out = out.reshape(B, T, H * D)
-        return dense(cfg.hidden_size, "o_proj", row=True)(out), layer_cache
+        with jax.named_scope("ds.attn_proj"):
+            out = dense(cfg.hidden_size, "o_proj", row=True)(
+                out.reshape(B, T, H * D))
+        return out, layer_cache
 
 
 class LlamaMLP(nn.Module):
@@ -318,11 +330,13 @@ class LlamaMLP(nn.Module):
         cfg = self.config
         dense = lambda feats, name, row=False: model_dense(
             cfg, feats, name, use_bias=False, row_parallel=row)
-        gate = dense(cfg.intermediate_size, "gate_proj")(x)
-        up = dense(cfg.intermediate_size, "up_proj")(x)
         act = nn.silu if cfg.mlp_activation == "silu" else \
             (lambda g: nn.gelu(g, approximate=True))  # gemma gelu_pytorch_tanh
-        return dense(cfg.hidden_size, "down_proj", row=True)(act(gate) * up)
+        with jax.named_scope("ds.mlp"):
+            gate = dense(cfg.intermediate_size, "gate_proj")(x)
+            up = dense(cfg.intermediate_size, "up_proj")(x)
+            return dense(cfg.hidden_size, "down_proj", row=True)(
+                act(gate) * up)
 
 
 class LlamaBlock(nn.Module):
@@ -376,11 +390,13 @@ class LlamaModel(nn.Module):
         ``p_l = 1 - (l+1)/L * (1 - theta)``, sampled from the ``pld`` rng."""
         cfg = self.config
         B, T = input_ids.shape
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens",
-                     param_dtype=jnp.float32)(input_ids)
-        if cfg.embed_scale is not None:
-            # gemma: hidden states scaled by sqrt(hidden) in the embed dtype
-            x = x * jnp.asarray(cfg.embed_scale, x.dtype)
+        with jax.named_scope("ds.embed"):
+            x = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens",
+                         param_dtype=jnp.float32)(input_ids)
+            if cfg.embed_scale is not None:
+                # gemma: hidden states scaled by sqrt(hidden) in the embed
+                # dtype
+                x = x * jnp.asarray(cfg.embed_scale, x.dtype)
         if positions is None:
             if cache_index is not None and is_paged_index(cache_index):
                 # paged serving: each token's absolute position IS its
@@ -442,7 +458,8 @@ class LlamaModel(nn.Module):
                     new_cache.append(layer_cache)
             if new_cache is not None:
                 cache = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *new_cache)
-        x = RMSNorm(eps=cfg.rms_norm_eps, name="norm")(x)
+        with jax.named_scope(head_scope(cache)):
+            x = RMSNorm(eps=cfg.rms_norm_eps, name="norm")(x)
         return x if cache is None else (x, cache)
 
 
@@ -458,14 +475,15 @@ class LlamaForCausalLM(nn.Module):
                                                pld_theta)
         if cache is not None:
             hidden, cache = hidden
-        logits, loss = lm_head_output(self, cfg, hidden, labels, cache)
-        if cache is not None:
-            return logits, cache
-        if labels is None:
-            return logits
-        if loss is not None:
-            return loss
-        return cross_entropy_loss(logits, shift_labels(labels))
+        with jax.named_scope(head_scope(cache)):
+            logits, loss = lm_head_output(self, cfg, hidden, labels, cache)
+            if cache is not None:
+                return logits, cache
+            if labels is None:
+                return logits
+            if loss is not None:
+                return loss
+            return cross_entropy_loss(logits, shift_labels(labels))
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16):
         """Empty KV cache for incremental decoding."""

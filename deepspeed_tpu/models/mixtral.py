@@ -28,7 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .layers import (RMSNorm, cross_entropy_loss, init_kv_cache,
+from .layers import (RMSNorm, cross_entropy_loss, head_scope, init_kv_cache,
+                     lm_head_output,
                      resolve_remat_policy, rotary_embedding, shift_labels)
 from .llama import LlamaAttention, LlamaConfig
 
@@ -116,6 +117,21 @@ class MixtralConfig(LlamaConfig):
             num_local_experts=4, num_experts_per_tok=2, remat=False), **over})
 
 
+@jax.named_scope("ds.moe_router")
+def _router_stats(onehot, probs, token_mask, tokens):
+    """Per-expert token fraction and mean router probability ``[E]`` of one
+    layer (HF excludes pad tokens via ``attention_mask``)."""
+    routed = jnp.max(onehot, axis=2).astype(jnp.float32)
+    if token_mask is None:
+        denom = float(tokens)
+        return (jnp.sum(routed, axis=(0, 1)) / denom,
+                jnp.sum(probs, axis=(0, 1)) / denom)
+    m = token_mask.astype(jnp.float32)[..., None]        # [B, T, 1]
+    denom = jnp.maximum(jnp.sum(m), 1.0)
+    return (jnp.sum(routed * m, axis=(0, 1)) / denom,
+            jnp.sum(probs * m, axis=(0, 1)) / denom)
+
+
 class MixtralSparseMoeBlock(nn.Module):
     """HF ``MixtralSparseMoeBlock`` semantics. Returns ``(out, frac, prob)``
     where ``frac``/``prob`` are this layer's per-expert token-fraction and
@@ -133,13 +149,16 @@ class MixtralSparseMoeBlock(nn.Module):
         E, K = cfg.num_local_experts, cfg.num_experts_per_tok
         I = cfg.intermediate_size
 
-        router_logits = nn.Dense(E, use_bias=False, name="gate",
-                                 param_dtype=jnp.float32)(x)  # [B, T, E]
-        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-        topk_w, topk_idx = jax.lax.top_k(probs, K)
-        topk_w = topk_w / jnp.sum(topk_w, axis=-1, keepdims=True)
-        # one-hot routing (also feeds the aux-loss stats below)
-        onehot = jax.nn.one_hot(topk_idx, E, dtype=topk_w.dtype)  # [B,T,K,E]
+        with jax.named_scope("ds.moe_router"):
+            router_logits = nn.Dense(E, use_bias=False, name="gate",
+                                     param_dtype=jnp.float32)(x)  # [B, T, E]
+            probs = jax.nn.softmax(router_logits.astype(jnp.float32),
+                                   axis=-1)
+            topk_w, topk_idx = jax.lax.top_k(probs, K)
+            topk_w = topk_w / jnp.sum(topk_w, axis=-1, keepdims=True)
+            # one-hot routing (also feeds the aux-loss stats below)
+            onehot = jax.nn.one_hot(topk_idx, E,
+                                    dtype=topk_w.dtype)  # [B,T,K,E]
 
         # stacked expert SwiGLU: [E, H, I] / [E, I, H], sharded over "expert"
         w1 = self.param("w1", nn.initializers.lecun_normal(), (E, H, I),
@@ -148,57 +167,53 @@ class MixtralSparseMoeBlock(nn.Module):
                         jnp.float32)  # up
         w2 = self.param("w2", nn.initializers.lecun_normal(), (E, I, H),
                         jnp.float32)  # down
-        dt = x.dtype
-        if T == 1 and E > K and not _expert_axis_active():
-            # decode fast path (replicated experts): GATHER only the K
-            # touched experts' weights per token instead of computing all E
-            # — the stacked einsum streams E/K x the weight bytes a decode
-            # step needs (the reference's einsum_sec_sm_ecm / moe_res_matmul
-            # kernels exist for exactly this; tools/bench_moe_decode.py
-            # measures it as gather_speedup_vs_all_e). XLA's gather reads
-            # only the indexed expert rows from HBM.
-            idx = topk_idx[:, 0]                        # [B, K]
-            w1g = jnp.take(w1, idx, axis=0).astype(dt)  # [B, K, H, I]
-            w3g = jnp.take(w3, idx, axis=0).astype(dt)
-            w2g = jnp.take(w2, idx, axis=0).astype(dt)  # [B, K, I, H]
-            xt = x[:, 0]                                # [B, H]
-            hidden = nn.silu(jnp.einsum("bh,bkhi->bki", xt, w1g)) * \
-                jnp.einsum("bh,bkhi->bki", xt, w3g)
-            y = jnp.einsum("bki,bkih->bkh", hidden, w2g)
-            out = jnp.einsum("bk,bkh->bh",
-                             topk_w[:, 0].astype(dt), y)[:, None]
-        else:
-            # dense [B, T, E] combine weights, zero outside the top-k;
-            # the combine joins the expert-axis-gathered tokens in the
-            # final einsum
-            combine = jnp.einsum("btk,btke->bte", topk_w, onehot)
-            combine = _ep_constraint(combine, "data", None, None)
-            # EP layout (GShard-style): tokens all-gather over the expert
-            # axis at entry (B drops to data-only sharding), the [B,T,E,·]
-            # intermediates keep E on the expert axis, and the combine
-            # contraction over E reduce-scatters B back onto (data, expert)
-            xg = _ep_constraint(x, "data", None, None)
-            h = nn.silu(jnp.einsum("bth,ehi->btei", xg, w1.astype(dt))) * \
-                jnp.einsum("bth,ehi->btei", xg, w3.astype(dt))
-            h = _ep_constraint(h, "data", None, "expert", None)
-            y = jnp.einsum("btei,eih->bteh", h, w2.astype(dt))
-            y = _ep_constraint(y, "data", None, "expert", None)
-            out = jnp.einsum("bte,bteh->bth", combine.astype(dt), y)
-            out = _ep_constraint(out, ("data", "expert"), None, None)
-
-        # per-layer masked means (HF excludes pad tokens via attention_mask)
-        if token_mask is None:
-            denom = float(B * T)
-            routed = jnp.max(onehot, axis=2).astype(jnp.float32)
-            frac = jnp.sum(routed, axis=(0, 1)) / denom
-            prob = jnp.sum(probs, axis=(0, 1)) / denom
-        else:
-            m = token_mask.astype(jnp.float32)[..., None]        # [B, T, 1]
-            denom = jnp.maximum(jnp.sum(m), 1.0)
-            routed = jnp.max(onehot, axis=2).astype(jnp.float32)
-            frac = jnp.sum(routed * m, axis=(0, 1)) / denom
-            prob = jnp.sum(probs * m, axis=(0, 1)) / denom
+        out = _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx, onehot)
+        frac, prob = _router_stats(onehot, probs, token_mask, B * T)
         return out, frac, prob
+
+
+@jax.named_scope("ds.moe_experts")
+def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx, onehot):
+    """The stacked expert SwiGLU and the weighted combine: ``[B, T, H]``."""
+    T, dt = x.shape[1], x.dtype
+    E, K = cfg.num_local_experts, cfg.num_experts_per_tok
+    if T == 1 and E > K and not _expert_axis_active():
+        # decode fast path (replicated experts): GATHER only the K
+        # touched experts' weights per token instead of computing all E
+        # — the stacked einsum streams E/K x the weight bytes a decode
+        # step needs (the reference's einsum_sec_sm_ecm / moe_res_matmul
+        # kernels exist for exactly this; tools/bench_moe_decode.py
+        # measures it as gather_speedup_vs_all_e). XLA's gather reads
+        # only the indexed expert rows from HBM.
+        idx = topk_idx[:, 0]                        # [B, K]
+        w1g = jnp.take(w1, idx, axis=0).astype(dt)  # [B, K, H, I]
+        w3g = jnp.take(w3, idx, axis=0).astype(dt)
+        w2g = jnp.take(w2, idx, axis=0).astype(dt)  # [B, K, I, H]
+        xt = x[:, 0]                                # [B, H]
+        hidden = nn.silu(jnp.einsum("bh,bkhi->bki", xt, w1g)) * \
+            jnp.einsum("bh,bkhi->bki", xt, w3g)
+        y = jnp.einsum("bki,bkih->bkh", hidden, w2g)
+        out = jnp.einsum("bk,bkh->bh",
+                         topk_w[:, 0].astype(dt), y)[:, None]
+    else:
+        # dense [B, T, E] combine weights, zero outside the top-k;
+        # the combine joins the expert-axis-gathered tokens in the
+        # final einsum
+        combine = jnp.einsum("btk,btke->bte", topk_w, onehot)
+        combine = _ep_constraint(combine, "data", None, None)
+        # EP layout (GShard-style): tokens all-gather over the expert
+        # axis at entry (B drops to data-only sharding), the [B,T,E,·]
+        # intermediates keep E on the expert axis, and the combine
+        # contraction over E reduce-scatters B back onto (data, expert)
+        xg = _ep_constraint(x, "data", None, None)
+        h = nn.silu(jnp.einsum("bth,ehi->btei", xg, w1.astype(dt))) * \
+            jnp.einsum("bth,ehi->btei", xg, w3.astype(dt))
+        h = _ep_constraint(h, "data", None, "expert", None)
+        y = jnp.einsum("btei,eih->bteh", h, w2.astype(dt))
+        y = _ep_constraint(y, "data", None, "expert", None)
+        out = jnp.einsum("bte,bteh->bth", combine.astype(dt), y)
+        out = _ep_constraint(out, ("data", "expert"), None, None)
+    return out
 
 
 class MixtralBlock(nn.Module):
@@ -238,8 +253,9 @@ class MixtralModel(nn.Module):
                  deterministic=True, cache=None, cache_index=None):
         cfg = self.config
         B, T = input_ids.shape
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens",
-                     param_dtype=jnp.float32)(input_ids)
+        with jax.named_scope("ds.embed"):
+            x = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens",
+                         param_dtype=jnp.float32)(input_ids)
         if positions is None:
             start = 0 if cache_index is None else cache_index
             positions = jnp.broadcast_to(start + jnp.arange(T)[None, :], (B, T))
@@ -287,7 +303,8 @@ class MixtralModel(nn.Module):
             if new_cache is not None:
                 cache = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls),
                                                *new_cache)
-        x = RMSNorm(eps=cfg.rms_norm_eps, name="norm")(x)
+        with jax.named_scope(head_scope(cache)):
+            x = RMSNorm(eps=cfg.rms_norm_eps, name="norm")(x)
         # HF load_balancing_loss_func: means over ALL layers' tokens
         # concatenated (= mean over layers of per-layer masked means), THEN
         # the expert-wise product
@@ -315,15 +332,14 @@ class MixtralForCausalLM(nn.Module):
             hidden, aux, cache = out
         else:
             hidden, aux = out
-        from .layers import lm_head_output
-
-        logits, lm = lm_head_output(self, cfg, hidden, labels, cache)
-        if cache is not None:
-            return logits, cache
-        if labels is None:
-            return logits
-        if lm is None:
-            lm = cross_entropy_loss(logits, shift_labels(labels))
+        with jax.named_scope(head_scope(cache)):
+            logits, lm = lm_head_output(self, cfg, hidden, labels, cache)
+            if cache is not None:
+                return logits, cache
+            if labels is None:
+                return logits
+            if lm is None:
+                lm = cross_entropy_loss(logits, shift_labels(labels))
         return lm + cfg.router_aux_loss_coef * aux
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16):

@@ -9,12 +9,16 @@ Export is Chrome-trace JSON — load a dump straight into Perfetto
 (https://ui.perfetto.dev) or ``chrome://tracing``, or inspect it with
 ``tools/trace_view.py`` (schema validation + per-request phase breakdown).
 
-Cost discipline: a disabled tracer does no work — ``span()`` returns a
-shared singleton context manager and ``instant``/``complete`` return before
-touching the ring, so hot loops guard emission with one attribute check
-(``if tracer.enabled: ...``) and pay **zero allocations** when tracing is
-off. The serving decode step and the training step loop both follow that
-pattern.
+Two sinks, one call: ``span()`` is THE way to open a span. It always enters
+a ``jax.profiler.TraceAnnotation("ds.<name>")`` — a TraceMe, one flag check
+while no profiler records — so a ``/profilez`` capture or a benchmark's
+traced run shows the program's own spans on the profiler's clock beside the
+device operations; with the tracer enabled it also records the ring event.
+This module is the only place that constructs a ``TraceAnnotation``.
+
+Cost discipline: a disabled tracer never touches the ring — ``instant`` and
+``complete`` return first, and a span then costs one small object and the
+TraceMe's flag check (PERF.md has the measured nanoseconds).
 
 The :class:`FlightRecorder` is the post-mortem half: incident triggers
 (watchdog trips, logit quarantines, ``DS_FAULT`` firings, checkpoint-verify
@@ -38,6 +42,8 @@ import threading
 import time
 import weakref
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..utils.logging import logger
 
@@ -85,39 +91,56 @@ def validate_event(ev: Any) -> Optional[str]:
     return None
 
 
-class _NullSpan:
-    """Shared no-op context manager returned by a disabled tracer's
-    ``span()`` — one singleton, zero per-call allocation."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+#: every profiler-side span name starts with this (the ring keeps the bare
+#: names, so old dumps and tools/trace_view.py parse unchanged)
+PROFILER_PREFIX = "ds."
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    """One open span: a profiler annotation ``ds.<name>`` and, with the
+    tracer enabled, a ring event ``ring or name`` (``ring`` keeps a span's
+    older ring spelling where the profiler name is new). ``set()`` adds
+    arguments known only late (a token count, a verdict) before it
+    closes."""
+
+    __slots__ = ("_tracer", "_name", "_ring", "_cat", "_args", "_step",
+                 "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Optional[Dict[str, Any]]):
+                 args: Optional[Dict[str, Any]], ring: Optional[str],
+                 step: Optional[int]):
         self._tracer = tracer
         self._name = name
+        self._ring = ring or name
         self._cat = cat
-        self._args = args
+        # a step span carries its number like every span inside it
+        self._args = args if step is None else {**(args or {}), "step": step}
+        self._step = step
+
+    def set(self, **args) -> "_Span":
+        self._annotation.set_metadata(**args)
+        if self._tracer.enabled:
+            self._args = {**(self._args or {}), **args}
+        return self
 
     def __enter__(self):
+        args = self._args or {}
+        if self._step is None:
+            self._annotation = TraceAnnotation(
+                PROFILER_PREFIX + self._name, **args)
+        else:
+            # a step span: the profiler groups device work under it
+            self._annotation = StepTraceAnnotation(
+                PROFILER_PREFIX + self._name, step_num=self._step, **args)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tracer.complete(self._name, self._t0, time.perf_counter(),
-                              cat=self._cat, args=self._args)
+        t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        self._tracer.complete(self._ring, self._t0, t1, cat=self._cat,
+                              args=self._args)
         return False
 
 
@@ -125,10 +148,11 @@ class Tracer:
     """Thread-safe span/event recorder over a bounded ring buffer.
 
     - ``instant(name)`` — point event;
-    - ``complete(name, start_s, end_s)`` — span with explicit monotonic
-      endpoints (the pattern the hot paths use: measure with two
-      ``perf_counter()`` reads, emit once, allocate nothing when disabled);
-    - ``span(name)`` — context-manager sugar over ``complete``;
+    - ``span(name)`` — context manager: a profiler annotation always, the
+      ring event when enabled; spans nest by containment on their thread;
+    - ``complete(name, start_s, end_s)`` — ring-only span with explicit
+      monotonic endpoints, for spans that do not nest on one thread (the
+      per-request ``phase:*`` and ``request`` spans);
     - ``events()`` / ``to_chrome()`` / ``dump(path)`` — ring snapshot and
       Chrome-trace/Perfetto JSON export.
 
@@ -181,12 +205,11 @@ class Tracer:
         self._append(ev)
 
     def span(self, name: str, cat: str = "",
-             args: Optional[Dict[str, Any]] = None):
-        """Context manager recording a complete span; a disabled tracer
-        returns one shared no-op singleton (no allocation)."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, cat, args)
+             args: Optional[Dict[str, Any]] = None,
+             ring: Optional[str] = None, step: Optional[int] = None):
+        """Open a span: ``with tracer.span("plan", cat="host") as sp: ...;
+        sp.set(grants=3)``. ``step`` makes it a profiler step span."""
+        return _Span(self, name, cat, args, ring, step)
 
     # -- inspection / export -------------------------------------------
 

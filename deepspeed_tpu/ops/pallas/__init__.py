@@ -1,0 +1,18 @@
+"""Pallas kernels. Every ``pl.pallas_call`` here passes one of these fixed
+names, so a profiler trace names the kernel the same way after any refactor
+of the code around it (a trace reader matches the strings; nothing imports
+them from outside this package)."""
+
+FLASH_FWD = "ds_flash_fwd"
+FLASH_BWD_DQ = "ds_flash_bwd_dq"
+FLASH_BWD_DKV = "ds_flash_bwd_dkv"
+RAGGED_PAGED_ATTENTION = "ds_ragged_paged_attention"
+DECODE_ATTENTION = "ds_decode_attention"
+PAGED_DECODE_ATTENTION = "ds_paged_decode_attention"
+PAGED_PREFILL_ATTENTION = "ds_paged_prefill_attention"
+QUANT_MATMUL = "ds_quant_matmul"
+INT8_MATMUL = "ds_int8_matmul"
+FUSED_ADAM = "ds_fused_adam"
+BLOCK_SPARSE_FWD = "ds_block_sparse_fwd"
+BLOCK_SPARSE_BWD_DQ = "ds_block_sparse_bwd_dq"
+BLOCK_SPARSE_BWD_DKV = "ds_block_sparse_bwd_dkv"

@@ -31,6 +31,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import BLOCK_SPARSE_BWD_DKV, BLOCK_SPARSE_BWD_DQ, BLOCK_SPARSE_FWD
+
 NEG_INF = -1e30
 
 
@@ -227,6 +229,7 @@ def _fwd(q, k, v, kv_idx, kv_cnt, sm_scale, causal, bq, bk, interpret):
             jax.ShapeDtypeStruct((B, H, T), jnp.float32),
         ],
         interpret=interpret,
+        name=BLOCK_SPARSE_FWD,
     )(kv_idx, kv_cnt, q, k, v)
     return out, lse
 
@@ -259,6 +262,7 @@ def _bwd(res, g, kv_idx, kv_cnt, q_idx, q_cnt, sm_scale, causal, bq, bk,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
         interpret=interpret,
+        name=BLOCK_SPARSE_BWD_DQ,
     )(kv_idx, kv_cnt, q, k, v, do, lse, delta)
 
     Aq = q_idx.shape[-1]
@@ -297,6 +301,7 @@ def _bwd(res, g, kv_idx, kv_cnt, q_idx, q_cnt, sm_scale, causal, bq, bk,
             jax.ShapeDtypeStruct((B, H, T, D), v.dtype),
         ],
         interpret=interpret,
+        name=BLOCK_SPARSE_BWD_DKV,
     )(q_idx, q_cnt, q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import DECODE_ATTENTION, PAGED_DECODE_ATTENTION, PAGED_PREFILL_ATTENTION
+
 NEG_INF = float("-inf")
 
 
@@ -234,6 +236,7 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
+        name=DECODE_ATTENTION,
     )(cidx, qg, k_cache, v_cache, *scales, key_mask)
     return out.reshape(B, H, D)
 
@@ -407,6 +410,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
+        name=PAGED_DECODE_ATTENTION,
     )(bt, clen, qg, k_pages, v_pages, *scales)
     return out.reshape(B, H, D)
 
@@ -589,6 +593,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, T * G, D), q.dtype),
         interpret=interpret,
+        name=PAGED_PREFILL_ATTENTION,
     )(bt, cs, clen, qg, k_pages, v_pages, *scales)
     return out.reshape(B, Hkv, T, G, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, T, H, D)

@@ -26,6 +26,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD
+
 NEG_INF = -1e30
 
 
@@ -164,6 +166,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
+        name=FLASH_FWD,
     )(q, k, v, *mask_args)
     return out[:, :, :Tq], lse[:, :, 0, :Tq]  # lse: compact [B,H,Tq] fp32
 
@@ -312,6 +315,7 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
         out_shape=jax.ShapeDtypeStruct((B, H, Tq_p, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name=FLASH_BWD_DQ,
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -340,6 +344,7 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((bk, D), jnp.float32),
         ],
         interpret=interpret,
+        name=FLASH_BWD_DKV,
     )(q, k, v, do, lse, delta)
     return dq[:, :, :Tq], dk[:, :, :Tk], dv[:, :, :Tk]
 

@@ -28,6 +28,8 @@ import optax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import FUSED_ADAM
+
 # Each grid step processes one (8, 1024) fp32 tile per operand: 4 inputs +
 # 3 outputs x 32KB = 224KB of VMEM, far under budget, and the last dim is a
 # lane multiple (128) so Mosaic tiles it without relayout.
@@ -82,6 +84,7 @@ def _run_leaf(p, g, m, v, alpha, b1, b2, eps, weight_decay, adam_w_mode, interpr
         out_specs=[spec] * 3,
         out_shape=[jax.ShapeDtypeStruct((rows, 1024), jnp.float32)] * 3,
         interpret=interpret,
+        name=FUSED_ADAM,
     )(alpha, p_, g_, m_, v_)
     unflat = lambda x: x.ravel()[:n].reshape(shape).astype(dtype)
     return unflat(u), unflat(mo), unflat(vo)

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import INT8_MATMUL
+
 
 def quantize_weight_per_col(w: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """[K, N] float -> (int8 [K, N], fp32 scale [N]) with absmax/127 per
@@ -95,5 +97,6 @@ def int8_matmul(x: jnp.ndarray, wq: jnp.ndarray, scale: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((b, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((b, n + pad_n), x.dtype),
         interpret=interpret,
+        name=INT8_MATMUL,
     )(x, wq, scale)
     return out[:, :n]

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import QUANT_MATMUL
+
 #: weight-quantization modes; int4 packs two codes per byte along K
 MODES = ("int8", "int4")
 
@@ -224,5 +226,6 @@ def quant_matmul(x: jnp.ndarray, wq: jnp.ndarray, scale: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((b, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((b, n + pad_n), x.dtype),
         interpret=interpret,
+        name=QUANT_MATMUL,
     )(x, wq, scale)
     return out[:, :n]

@@ -56,6 +56,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import RAGGED_PAGED_ATTENTION
+
 NEG_INF = float("-inf")
 
 
@@ -302,6 +304,7 @@ def ragged_paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Hkv, rows, D), q.dtype),
         interpret=interpret,
+        name=RAGGED_PAGED_ATTENTION,
     )(bt, qs, ql, cs, cl, qg, k_pages, v_pages, *scales)
     return out[:, :T * G].reshape(Hkv, T, G, D).transpose(1, 0, 2, 3) \
         .reshape(T, H, D)

@@ -20,6 +20,7 @@ The reference's micro-step API (``engine(batch)`` → ``engine.backward(loss)``
 ``engine.py:1729,1889``).
 """
 
+import contextlib
 import os
 import time
 from typing import Any, Callable, Dict, Iterator, Optional, Tuple, Union
@@ -635,8 +636,6 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                 # step's intensity (reference engine.py:1620 steps the
                 # compression_scheduler during training)
                 params = compression.apply(params, moq_step)
-            import contextlib
-
             ictx = contextlib.nullcontext()
             if compression is not None and moq_step is not None and \
                     compression.has_activation_methods:
@@ -662,7 +661,11 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             grads, loss = grad_fn(params, batch, rng, scale, pld_theta, moq_step)
             return grads, loss
 
-        def train_step(state: TrainState, batch, rng):
+        # named like the kernels (ds_*): XLA calls the module after the
+        # function, and the module's name — unlike the scopes inside it,
+        # which are metadata — is part of the compile cache's key, so an
+        # executable cached before the scopes existed is never reused
+        def ds_train_step(state: TrainState, batch, rng):
             # trace-time side effect: runs once per XLA compile (the
             # compiled-program registry's compile count)
             self.perf.note_compile("train_step")
@@ -673,50 +676,54 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             moq_step = state.step if (moq is not None or
                                       compression is not None) else None
 
-            if gas > 1:
-                rngs = jax.random.split(rng, gas)
+            # ds.* scopes name the two halves of the fused step in a
+            # profiler trace (docs/observability.md); metadata only
+            with jax.named_scope("ds.loss_and_grad"):
+                if gas > 1:
+                    rngs = jax.random.split(rng, gas)
 
-                def body(acc, xs):
-                    mb, r = xs
-                    g, loss = microbatch_grads(state.params, mb, r, scale,
-                                               pld_theta, moq_step)
-                    acc_g, acc_l = acc
-                    return (jax.tree_util.tree_map(jnp.add, acc_g, g), acc_l + loss), None
+                    def body(acc, xs):
+                        mb, r = xs
+                        g, loss = microbatch_grads(state.params, mb, r, scale,
+                                                   pld_theta, moq_step)
+                        acc_g, acc_l = acc
+                        return (jax.tree_util.tree_map(jnp.add, acc_g, g), acc_l + loss), None
 
-                zero_g = jax.tree_util.tree_map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
-                (sum_g, sum_loss), _ = jax.lax.scan(
-                    body, (zero_g, jnp.float32(0.0)), (batch, rngs))
-                grads = jax.tree_util.tree_map(lambda g: g / gas, sum_g)
-                loss = sum_loss / gas
-            else:
-                squeezed = jax.tree_util.tree_map(lambda x: x[0], batch)
-                grads, loss = microbatch_grads(state.params, squeezed, rng, scale,
-                                               pld_theta, moq_step)
+                    zero_g = jax.tree_util.tree_map(
+                        lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
+                    (sum_g, sum_loss), _ = jax.lax.scan(
+                        body, (zero_g, jnp.float32(0.0)), (batch, rngs))
+                    grads = jax.tree_util.tree_map(lambda g: g / gas, sum_g)
+                    loss = sum_loss / gas
+                else:
+                    squeezed = jax.tree_util.tree_map(lambda x: x[0], batch)
+                    grads, loss = microbatch_grads(state.params, squeezed, rng, scale,
+                                                   pld_theta, moq_step)
 
-            # unscale
-            grads = jax.tree_util.tree_map(lambda g: g / scale, grads)
-            import optax as _optax
+            with jax.named_scope("ds.optimizer"):
+                # unscale
+                grads = jax.tree_util.tree_map(lambda g: g / scale, grads)
+                import optax as _optax
 
-            grad_norm = _optax.global_norm(grads)
+                grad_norm = _optax.global_norm(grads)
 
-            if fp16:
-                overflow = tree_overflow(grads)
-                new_scale = update_scale(state.loss_scale, overflow)
-            else:
-                overflow = jnp.bool_(False)
-                new_scale = state.loss_scale
+                if fp16:
+                    overflow = tree_overflow(grads)
+                    new_scale = update_scale(state.loss_scale, overflow)
+                else:
+                    overflow = jnp.bool_(False)
+                    new_scale = state.loss_scale
 
-            updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-            new_params = jax.tree_util.tree_map(
-                lambda p, u: p + u.astype(p.dtype), state.params, updates)
+                updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
+                new_params = jax.tree_util.tree_map(
+                    lambda p, u: p + u.astype(p.dtype), state.params, updates)
 
-            # skip the whole update on overflow (reference: _take_model_step
-            # engine.py:1889 + CheckOverflow)
-            keep = lambda new, old: jax.tree_util.tree_map(
-                lambda n, o: jnp.where(overflow, o, n), new, old)
-            new_params = keep(new_params, state.params)
-            new_opt = keep(new_opt, state.opt_state)
+                # skip the whole update on overflow (reference: _take_model_step
+                # engine.py:1889 + CheckOverflow)
+                keep = lambda new, old: jax.tree_util.tree_map(
+                    lambda n, o: jnp.where(overflow, o, n), new, old)
+                new_params = keep(new_params, state.params)
+                new_opt = keep(new_opt, state.opt_state)
 
             new_state = state.replace(
                 step=state.step + jnp.where(overflow, 0, 1),
@@ -728,9 +735,9 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             return new_state, (loss, grad_norm), overflow
 
         # raw Python step kept for the flops profiler's jaxpr walk
-        self._train_step_fn = train_step
+        self._train_step_fn = ds_train_step
         return jax.jit(
-            train_step,
+            ds_train_step,
             # batch shardings follow the device_put placement from
             # _shape_batch (per-leaf: token dims ride the seq axis)
             in_shardings=(self.state_shardings, None, self._replicated),
@@ -759,30 +766,31 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
 
         grad_fn = jax.grad(compute_loss, has_aux=True)
 
-        def grad_step(params, batch, rng, scale):
+        def ds_grad_step(params, batch, rng, scale):
             self.perf.note_compile("grad_step")
-            if gas > 1:
-                rngs = jax.random.split(rng, gas)
+            with jax.named_scope("ds.loss_and_grad"):
+                if gas > 1:
+                    rngs = jax.random.split(rng, gas)
 
-                def body(acc, xs):
-                    mb, r = xs
-                    g, loss = grad_fn(params, mb, r, scale)
-                    acc_g, acc_l = acc
-                    return (jax.tree_util.tree_map(jnp.add, acc_g, g),
-                            acc_l + loss), None
+                    def body(acc, xs):
+                        mb, r = xs
+                        g, loss = grad_fn(params, mb, r, scale)
+                        acc_g, acc_l = acc
+                        return (jax.tree_util.tree_map(jnp.add, acc_g, g),
+                                acc_l + loss), None
 
-                zero_g = jax.tree_util.tree_map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
-                (sum_g, sum_loss), _ = jax.lax.scan(
-                    body, (zero_g, jnp.float32(0.0)), (batch, rngs))
-                grads = jax.tree_util.tree_map(lambda g: g / gas, sum_g)
-                loss = sum_loss / gas
-            else:
-                squeezed = jax.tree_util.tree_map(lambda x: x[0], batch)
-                grads, loss = grad_fn(params, squeezed, rng, scale)
+                    zero_g = jax.tree_util.tree_map(
+                        lambda p: jnp.zeros(p.shape, jnp.float32), params)
+                    (sum_g, sum_loss), _ = jax.lax.scan(
+                        body, (zero_g, jnp.float32(0.0)), (batch, rngs))
+                    grads = jax.tree_util.tree_map(lambda g: g / gas, sum_g)
+                    loss = sum_loss / gas
+                else:
+                    squeezed = jax.tree_util.tree_map(lambda x: x[0], batch)
+                    grads, loss = grad_fn(params, squeezed, rng, scale)
             return grads, loss
 
-        return jax.jit(grad_step,
+        return jax.jit(ds_grad_step,
                        in_shardings=(self.param_shardings, None,
                                      self._replicated, self._replicated),
                        out_shardings=(self.param_shardings, self._replicated))
@@ -855,17 +863,26 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         the forward/backward/step loop for the plain engine. Pass either a
         global batch (leading dim = train_batch_size) or an iterator yielding
         microbatches.
+
+        Spans (``monitor/tracing.py``; on the profiler's clock as
+        ``ds.<name>``, docs/observability.md): ``train_batch`` holds
+        ``data_fetch``, ``shape_batch``, ``observe``, ``dispatch`` (inside
+        ``compile`` when the call carries one), ``report`` and
+        ``checkpoint_save``; each carries the step's number.
         """
+        step = self.global_steps
+        with self.tracer.span("train_batch", cat="train", step=step):
+            return self._train_batch(data_iter, batch, step)
+
+    def _train_batch(self, data_iter, batch, step: int):
+        tr = self.tracer
         t_batch0 = time.perf_counter()
         if batch is None:
             if data_iter is None:
                 raise ValueError("train_batch needs a batch or a data iterator")
-            micro = [next(data_iter) for _ in range(self.gradient_accumulation_steps)]
-            batch = {k: np.concatenate([np.asarray(m[k]) for m in micro]) for k in micro[0]}
-            if self.tracer.enabled:
-                self.tracer.complete("data_fetch", t_batch0,
-                                     time.perf_counter(), cat="train",
-                                     args={"step": self.global_steps})
+            with tr.span("data_fetch", cat="train", args={"step": step}):
+                micro = [next(data_iter) for _ in range(self.gradient_accumulation_steps)]
+                batch = {k: np.concatenate([np.asarray(m[k]) for m in micro]) for k in micro[0]}
 
         if self.curriculum_scheduler is not None:
             # truncate token dims to this step's difficulty (reference injects
@@ -915,17 +932,15 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             self.timers("train_batch").start()
         self.tput_timer.start()
 
-        tr = self.tracer
         if self._offload:
-            t_step0 = time.perf_counter() if tr.enabled else 0.0
-            loss = self._offload_train_batch(batch)
-            if tr.enabled:
-                tr.complete("train_step", t_step0, time.perf_counter(),
-                            cat="train", args={"step": self.global_steps,
-                                               "offload": True})
+            with tr.span("dispatch", cat="train", ring="train_step",
+                         args={"step": step, "program": "grad_step",
+                               "offload": True}):
+                loss = self._offload_train_batch(batch)
         else:
-            batch = self._shape_batch(batch)
-            self._rng, step_rng = jax.random.split(self._rng)
+            with tr.span("shape_batch", cat="host", args={"step": step}):
+                batch = self._shape_batch(batch)
+                self._rng, step_rng = jax.random.split(self._rng)
             fp = self._config.flops_profiler
             profiling = (fp.enabled and self.global_steps == fp.profile_step)
             t0 = time.perf_counter() if profiling else None
@@ -935,23 +950,30 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             # state spec is computed once (shapes fixed by construction).
             from ..monitor import perf as _perf
 
-            if self._state_spec is None:
-                self._state_spec = _perf.spec(self.state)
-            self.perf.programs.observe_call(
-                "train_step", {"state": self._state_spec,
-                               "batch": _perf.spec(batch),
-                               "rng": _perf.spec(step_rng)})
-            warm = not self.perf.programs.program("train_step").cost_pending
-            # span covers the fused fwd/bwd/optimizer DISPATCH (XLA runs
-            # the three as one program; wall_clock_breakdown timers remain
-            # the per-phase estimate) — forcing the loss here would fence
-            # the device every step just to trace
-            t_step0 = time.perf_counter() if tr.enabled else 0.0
-            self.state, (loss, self._last_grad_norm), overflow = \
-                self._train_step(self.state, batch, step_rng)
-            if tr.enabled:
-                tr.complete("train_step", t_step0, time.perf_counter(),
-                            cat="train", args={"step": self.global_steps})
+            with tr.span("observe", cat="host", args={"step": step}):
+                if self._state_spec is None:
+                    self._state_spec = _perf.spec(self.state)
+                recompiled = self.perf.programs.observe_call(
+                    "train_step", {"state": self._state_spec,
+                                   "batch": _perf.spec(batch),
+                                   "rng": _perf.spec(step_rng)}) is not None
+                warm = not self.perf.programs.program(
+                    "train_step").cost_pending
+            # the span covers the DISPATCH of the fused fwd/bwd/optimizer
+            # program (its halves are the ds.loss_and_grad / ds.optimizer
+            # scopes of the device trace) — forcing the loss here would
+            # fence the device every step just to trace. A call known to
+            # carry a compile (the first, or one the sentinel flagged)
+            # sits inside a compile span, so an idle device is explained
+            compiling = tr.span(
+                "compile", cat="host",
+                args={"step": step, "program": "train_step"}) \
+                if (recompiled or not warm) else contextlib.nullcontext()
+            with compiling, tr.span(
+                    "dispatch", cat="train", ring="train_step",
+                    args={"step": step, "program": "train_step"}):
+                self.state, (loss, self._last_grad_norm), overflow = \
+                    self._train_step(self.state, batch, step_rng)
             if not warm:
                 # once, after the compile-carrying first call: the cached
                 # lowering yields the cost model without a second trace;
@@ -1003,15 +1025,18 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             if vals["flops_per_sec"]:
                 self.registry.gauge("train_tflops_per_chip").set(
                     vals["flops_per_sec"] / 1e12 / self.perf.n_devices)
-        if tr.enabled:
-            tr.complete("train_batch", t_batch0, time.perf_counter(),
-                        cat="train", args={"step": self.global_steps - 1})
 
-        if self.monitor is not None and self.monitor.enabled:
-            self._write_monitor(loss)
-        if self._config.steps_per_print and \
-                self.global_steps % self._config.steps_per_print == 0:
-            self._report_progress(loss)
+        monitoring = self.monitor is not None and self.monitor.enabled
+        printing = self._config.steps_per_print and \
+            self.global_steps % self._config.steps_per_print == 0
+        if monitoring or printing:
+            # both fetch the loss, so this span is the host's wait on
+            # the device when either is on
+            with tr.span("report", cat="host", args={"step": step}):
+                if monitoring:
+                    self._write_monitor(loss)
+                if printing:
+                    self._report_progress(loss)
         self._last_loss = loss
         return loss
 
@@ -1207,29 +1232,27 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                             skipped_steps=self.get_skipped_steps())
         ft = self._config.fault_tolerance
         t_save0 = time.perf_counter()
-        if self._offload:
-            # host-side fp32 masters + moments live outside TrainState;
-            # written BEFORE the manifest so the save's integrity check
-            # covers them too
-            os.makedirs(save_dir, exist_ok=True)
-            sd = self._host_opt.state_dict()
-            np.savez(os.path.join(save_dir, f"{tag}.host_optimizer.npz"),
-                     step=sd["step"],
-                     **{f"master_{i}": m for i, m in enumerate(sd["master"])},
-                     **{f"moment_{mi}_{li}": buf
-                        for mi, bank in enumerate(sd["moments"])
-                        for li, buf in enumerate(bank)})
-        save_train_state(save_dir, tag, self.state, client_state,
-                         save_latest=save_latest,
-                         save_retries=ft.save_retries if ft.enabled else 0,
-                         retry_backoff_s=ft.save_retry_backoff,
-                         manifest_checksums=ft.manifest_checksums)
         # checkpoint I/O is the step loop's big non-compute latency — a
         # traced run shows exactly which steps paid it
-        if self.tracer.enabled:
-            self.tracer.complete("checkpoint_save", t_save0,
-                                 time.perf_counter(), cat="checkpoint",
-                                 args={"tag": tag})
+        with self.tracer.span("checkpoint_save", cat="checkpoint",
+                              args={"step": self.global_steps, "tag": tag}):
+            if self._offload:
+                # host-side fp32 masters + moments live outside TrainState;
+                # written BEFORE the manifest so the save's integrity check
+                # covers them too
+                os.makedirs(save_dir, exist_ok=True)
+                sd = self._host_opt.state_dict()
+                np.savez(os.path.join(save_dir, f"{tag}.host_optimizer.npz"),
+                         step=sd["step"],
+                         **{f"master_{i}": m for i, m in enumerate(sd["master"])},
+                         **{f"moment_{mi}_{li}": buf
+                            for mi, bank in enumerate(sd["moments"])
+                            for li, buf in enumerate(bank)})
+            save_train_state(save_dir, tag, self.state, client_state,
+                             save_latest=save_latest,
+                             save_retries=ft.save_retries if ft.enabled else 0,
+                             retry_backoff_s=ft.save_retry_backoff,
+                             manifest_checksums=ft.manifest_checksums)
         self.registry.histogram("checkpoint_save_s", lo=1e-3,
                                 hi=4e3).observe(time.perf_counter() - t_save0)
         return True

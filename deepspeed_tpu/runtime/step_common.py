@@ -42,6 +42,7 @@ def scale_local_loss(local_loss, lscale, fp16):
     return lambda p, mb, r: local_loss(p, mb, r) * lscale
 
 
+@jax.named_scope("ds.loss_and_grad")
 def accumulate_local_grads(local_loss, params, batch, rng, gas):
     """(mean loss, mean grads) over ``gas`` microbatches of the LOCAL batch
     (leading dim ``gas``), via ``lax.scan`` — the in-jit GAS boundary

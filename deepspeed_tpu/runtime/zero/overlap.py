@@ -188,6 +188,7 @@ def _embed_chunk(plan: GradBucketPlan, chunk, i, r, like):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("ds.grad_sync")
 def _exchange_flat(plan: GradBucketPlan, g_leaves, axis_tuple,
                    overlap: bool, tag: str = "grad_bucket"):
     """Sum-reduce the local grad leaves across ranks and return the
@@ -219,23 +220,27 @@ def _gather_flat(plan: GradBucketPlan, flat_row, axis_tuple,
     leaves (bucketed start/done pairs, or one monolithic gather)."""
     n = len(like_leaves)
     out: List[Any] = [None] * n
-    if overlap:
-        handles = []
-        for b in range(plan.num_buckets):
-            a, z = plan.bucket_cols(b)
-            handles.append(dist.all_gather_start(
-                flat_row[a:z][None], group=axis_tuple, axis=0, tiled=True,
-                tag=f"{tag}{b}"))
-        for b, idxs in enumerate(plan.buckets):
-            buf = dist.all_gather_done(handles[b])  # [world, C_b]
-            for i, leaf in _unpack(plan, buf, idxs, like_leaves).items():
+    # trace scope: gathering grads back is the second half of the grad
+    # sync; gathering updated shards is the ZeRO param all-gather
+    scope = "ds.param_gather" if tag == "param_bucket" else "ds.grad_sync"
+    with jax.named_scope(scope):
+        if overlap:
+            handles = []
+            for b in range(plan.num_buckets):
+                a, z = plan.bucket_cols(b)
+                handles.append(dist.all_gather_start(
+                    flat_row[a:z][None], group=axis_tuple, axis=0, tiled=True,
+                    tag=f"{tag}{b}"))
+            for b, idxs in enumerate(plan.buckets):
+                buf = dist.all_gather_done(handles[b])  # [world, C_b]
+                for i, leaf in _unpack(plan, buf, idxs, like_leaves).items():
+                    out[i] = leaf
+        else:
+            buf = dist.all_gather(flat_row[None], group=axis_tuple, axis=0,
+                                  tiled=True)
+            for i, leaf in _unpack(plan, buf, tuple(range(n)),
+                                   like_leaves).items():
                 out[i] = leaf
-    else:
-        buf = dist.all_gather(flat_row[None], group=axis_tuple, axis=0,
-                              tiled=True)
-        for i, leaf in _unpack(plan, buf, tuple(range(n)),
-                               like_leaves).items():
-            out[i] = leaf
     return out
 
 
@@ -499,7 +504,7 @@ def build_overlap_step(engine):
                 lambda o, nw: jnp.where(ov, o, nw), opt_state, new_opt)
         return new_params, new_opt, loss, grad_norm, ov
 
-    def train_step(state, batch, rng):
+    def ds_train_step(state, batch, rng):
         # trace-time side effect: the compiled-program registry's
         # compile counter (one resident program is the acceptance bar)
         engine.perf.note_compile("train_step")
@@ -527,4 +532,4 @@ def build_overlap_step(engine):
             skipped_steps=state.skipped_steps + ov.astype(jnp.int32))
         return new_state, (loss, grad_norm), ov
 
-    return opt_state, opt_shardings, train_step
+    return opt_state, opt_shardings, ds_train_step

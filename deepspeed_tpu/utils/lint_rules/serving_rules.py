@@ -34,7 +34,7 @@ from .trace_safety import find_jit_scopes
 
 #: ServingEngine methods where a device sync is the DESIGN (the one
 #: harvest sync per step, and caller-input coercion at submit)
-HOST_SYNC_ALLOW = {"submit", "step", "_step_mixed", "_prefill",
+HOST_SYNC_ALLOW = {"submit", "_step", "_step_mixed", "_prefill",
                    "_prefill_chunk"}
 
 _SYNC_CALLS = {"np.asarray", "np.array", "numpy.asarray", "numpy.array",

@@ -207,20 +207,55 @@ def test_ds_fault_firing_itself_dumps(srv, chaos):
 # 3. disabled tracing = zero work on the hot path
 # ---------------------------------------------------------------------------
 
-def test_disabled_tracer_emits_and_allocates_nothing(srv):
+def test_disabled_tracer_emits_nothing(srv):
     tracer = srv.tracer
     enabled_before = tracer.enabled
     count_before = tracer._count
     try:
         tracer.enabled = False
-        # the disabled span() is one shared singleton: no allocation
-        assert tracer.span("x") is tracer.span("y")
         rid = srv.submit(_prompts(31, 1)[0], max_new_tokens=4)
         _drain(srv)
         assert srv.poll(rid).state == "finished"
         assert tracer._count == count_before  # not one event appended
     finally:
         tracer.enabled = enabled_before
+
+
+# ---------------------------------------------------------------------------
+# 3b. the spans of one step nest and carry one step number
+# ---------------------------------------------------------------------------
+
+#: the host phases of a unified step, in the order step() opens them
+STEP_PHASES = ("expire", "admit", "plan", "pack", "mixed_step", "fetch",
+               "harvest", "bookkeeping")
+
+
+def test_spans_of_one_step_nest_and_share_its_number(srv):
+    srv.tracer.clear()
+    rid = srv.submit(_prompts(41, 1)[0], max_new_tokens=3)
+    _drain(srv)
+    assert srv.poll(rid).state == "finished"
+    spans = [e for e in srv.tracer.events() if e["ph"] == "X"
+             and e["cat"] in ("engine", "host")]
+    steps = [e for e in spans if e["name"] == "step"]
+    assert steps and len({e["args"]["step"] for e in steps}) == len(steps)
+    for step in steps:
+        n = step["args"]["step"]
+        inside = [e for e in spans if e is not step
+                  and e["args"]["step"] == n]
+        # the ring appends a span when it CLOSES: children first, in the
+        # order they ran, the enclosing step last
+        assert tuple(e["name"] for e in inside) == STEP_PHASES
+        lo, hi = step["ts"], step["ts"] + step["dur"]
+        ends = [lo]
+        for e in inside:
+            assert ends[-1] <= e["ts"] and e["ts"] + e["dur"] <= hi
+            ends.append(e["ts"] + e["dur"])
+    dispatch = next(e for e in spans if e["name"] == "mixed_step")
+    assert {"decode_tokens", "verify_tokens", "prefill_tokens", "width",
+            "rows", "context_tokens"} <= set(dispatch["args"])
+    late = next(e for e in spans if e["name"] == "harvest")
+    assert late["args"]["committed"] >= 1     # set before the span closed
 
 
 # ---------------------------------------------------------------------------

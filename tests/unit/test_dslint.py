@@ -226,7 +226,7 @@ class ServingEngine:
     def _grow_pages(self, x):
         return np.asarray(x)
 
-    def step(self, x):
+    def _step(self, x):
         return np.asarray(x)
 """
 

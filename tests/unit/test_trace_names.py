@@ -1,0 +1,233 @@
+"""The names a profiler trace shows are part of the program's interface:
+``ds.*`` scopes inside the jitted steps and the model code, a fixed ``name``
+on every Pallas call. The benchmark's reducer (``benchmark/scope_reduce.py``)
+and ``docs/observability.md`` match these strings, so a rename must fail here.
+
+Scopes are checked in the lowered text of ``train_step`` and ``mixed_step``
+at tiny sizes on the CPU; kernel names in each kernel's lowering for the TPU
+platform (Mosaic's ``kernel_name``), which needs no chip and compiles nothing
+(the block-sparse kernels, which Mosaic's block-shape rule still refuses, in
+their traced program).
+"""
+
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu as ds
+from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
+from deepspeed_tpu.ops import pallas as names
+
+TRAIN_SCOPES = {
+    "llama": ["ds.loss_and_grad", "ds.optimizer", "ds.embed", "ds.attn_proj",
+              "ds.attention", "ds.mlp", "ds.lm_head_loss"],
+    "mixtral": ["ds.loss_and_grad", "ds.optimizer", "ds.embed",
+                "ds.attn_proj", "ds.attention", "ds.moe_router",
+                "ds.moe_experts", "ds.lm_head_loss"],
+}
+SERVE_SCOPES = ["ds.mixed_step", "ds.embed", "ds.attn_proj", "ds.kv_append",
+                "ds.attention", "ds.mlp", "ds.lm_head", "ds.sample"]
+
+
+@pytest.fixture(scope="module")
+def train_text():
+    out = {}
+    for family, model in (
+            ("llama", LlamaForCausalLM(LlamaConfig.tiny(sliding_window=16))),
+            ("mixtral", MixtralForCausalLM(MixtralConfig.tiny(remat=True)))):
+        batch = {"input_ids": np.zeros((8, 32), np.int32),
+                 "labels": np.zeros((8, 32), np.int32)}
+        engine, *_ = ds.initialize(
+            model=model, example_batch={k: v[:1] for k, v in batch.items()},
+            config={"train_batch_size": 8, "bf16": {"enabled": True},
+                    "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})
+        out[family] = engine._train_step.lower(
+            engine.state, engine._shape_batch(batch),
+            jax.random.PRNGKey(0)).as_text(debug_info=True)
+    return out
+
+
+@pytest.mark.parametrize("family,scope", [
+    (f, s) for f, scopes in TRAIN_SCOPES.items() for s in scopes])
+def test_train_step_names_its_scopes(train_text, family, scope):
+    assert re.search(re.escape(scope) + r"\b", train_text[family])
+
+
+def test_jitted_steps_are_named_like_the_kernels(train_text, mixed_text):
+    """The module's name is in the compile cache's key; the scopes inside
+    are metadata and are not. Were the steps still ``train_step`` and
+    ``mixed_step``, an executable cached before the scopes existed would be
+    reused, and a trace of it would show none of them."""
+    for text in train_text.values():
+        assert "module @jit_ds_train_step" in text
+    assert "module @jit_ds_mixed_step" in mixed_text
+
+
+def test_backward_and_recompute_leave_their_marks(train_text):
+    """What ``scope_reduce.phase_of`` tells the phases apart by."""
+    text = train_text["mixtral"]
+    assert "transpose(jvp(" in text
+    assert "rematted_computation" in text
+
+
+@pytest.fixture(scope="module")
+def mixed_text():
+    model = LlamaForCausalLM(LlamaConfig.tiny())
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    srv = ds.init_serving(model, params=params,
+                          config={"dtype": "fp32"},
+                          serving_config=ds.ServingConfig(
+                              max_batch_size=2, num_blocks=16, block_size=8,
+                              max_model_len=64, prefill_chunk_tokens=8))
+    srv.submit(list(range(1, 12)), max_new_tokens=2)
+    srv.run()
+    (width, fn), = srv._mixed_fns.items()
+    R, T = srv.config.max_batch_size, width
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+    args = (srv.engine.params, srv.pool, jnp.asarray(srv._tables),
+            i32(1, T), i32(1, T), i32(1, T), i32(R), i32(R), i32(R), i32(R),
+            jnp.zeros((R,), bool), jax.random.PRNGKey(0))
+    return fn.lower(*args).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", SERVE_SCOPES)
+def test_mixed_step_names_its_scopes(mixed_text, scope):
+    assert re.search(re.escape(scope) + r"\b", mixed_text)
+
+
+# -- kernels -----------------------------------------------------------------
+
+H, HKV, D, BF16 = 8, 2, 128, jnp.bfloat16
+
+
+def _flash(bwd):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    q = ((1, 512, H, D), BF16)
+    fwd = functools.partial(flash_attention, causal=True, interpret=False,
+                            force_pallas=True, window=256)
+    if not bwd:
+        return fwd, [q, q, q]
+    loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2)), [q, q, q]
+
+
+def _ragged():
+    from deepspeed_tpu.ops.pallas.ragged_attention import \
+        ragged_paged_attention
+
+    pages, row = ((64, HKV, 16, D), BF16), ((4,), jnp.int32)
+    fn = functools.partial(ragged_paged_attention, interpret=False,
+                           force_pallas=True)
+    return fn, [((24, H, D), BF16), pages, pages, ((4, 8), jnp.int32),
+                row, row, row, row]
+
+
+def _decode():
+    from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
+
+    cache = ((2, HKV, 512, D), BF16)
+    fn = functools.partial(decode_attention, interpret=False,
+                           force_pallas=True)
+    return fn, [((2, H, D), BF16), cache, cache, ((), jnp.int32)]
+
+
+def _paged(prefill):
+    from deepspeed_tpu.ops.pallas import decode_attention as mod
+
+    pages = ((64, HKV, 16, D), BF16)
+    tables, lens = ((2, 8), jnp.int32), ((2,), jnp.int32)
+    if prefill:
+        fn = functools.partial(mod.paged_prefill_attention, interpret=False,
+                               force_pallas=True)
+        return fn, [((2, 16, H, D), BF16), pages, pages, tables, lens, lens]
+    fn = functools.partial(mod.paged_decode_attention, interpret=False,
+                           force_pallas=True)
+    return fn, [((2, H, D), BF16), pages, pages, tables, lens]
+
+
+def _quant_matmul():
+    from deepspeed_tpu.ops.pallas.quant_matmul import quant_matmul
+
+    fn = functools.partial(quant_matmul, mode="int8", interpret=False)
+    return fn, [((32, 512), BF16), ((512, 512), jnp.int8),
+                ((1, 512), jnp.float32)]
+
+
+def _int8_matmul():
+    from deepspeed_tpu.ops.pallas.int8_matmul import int8_matmul
+
+    fn = functools.partial(int8_matmul, interpret=False)
+    return fn, [((32, 512), BF16), ((512, 512), jnp.int8),
+                ((512,), jnp.float32)]
+
+
+def _fused_adam():
+    from deepspeed_tpu.ops.pallas.fused_adam import _run_leaf
+
+    fn = functools.partial(_run_leaf, b1=0.9, b2=0.999, eps=1e-8,
+                           weight_decay=0.1, adam_w_mode=True,
+                           interpret=False)
+    return fn, [((64, 1024), jnp.float32)] * 4 + [((3,), jnp.float32)]
+
+
+def _block_sparse(bwd):
+    from deepspeed_tpu.ops.pallas.block_sparse_attention import \
+        sparse_attention
+
+    q = ((1, 512, 4, D), BF16)
+    fwd = functools.partial(sparse_attention, causal=True, interpret=True,
+                            force_pallas=True,
+                            layout=np.ones((4, 4, 4), np.int64))
+    if not bwd:
+        return fwd, [q, q, q]
+    loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2)), [q, q, q]
+
+
+KERNELS = {
+    names.FLASH_FWD: ("ds_flash_fwd", lambda: _flash(False)),
+    names.FLASH_BWD_DQ: ("ds_flash_bwd_dq", lambda: _flash(True)),
+    names.FLASH_BWD_DKV: ("ds_flash_bwd_dkv", lambda: _flash(True)),
+    names.RAGGED_PAGED_ATTENTION: ("ds_ragged_paged_attention", _ragged),
+    names.DECODE_ATTENTION: ("ds_decode_attention", _decode),
+    names.PAGED_DECODE_ATTENTION: ("ds_paged_decode_attention",
+                                   lambda: _paged(False)),
+    names.PAGED_PREFILL_ATTENTION: ("ds_paged_prefill_attention",
+                                    lambda: _paged(True)),
+    names.QUANT_MATMUL: ("ds_quant_matmul", _quant_matmul),
+    names.INT8_MATMUL: ("ds_int8_matmul", _int8_matmul),
+    names.FUSED_ADAM: ("ds_fused_adam", _fused_adam),
+    names.BLOCK_SPARSE_FWD: ("ds_block_sparse_fwd",
+                             lambda: _block_sparse(False)),
+    names.BLOCK_SPARSE_BWD_DQ: ("ds_block_sparse_bwd_dq",
+                                lambda: _block_sparse(True)),
+    names.BLOCK_SPARSE_BWD_DKV: ("ds_block_sparse_bwd_dkv",
+                                 lambda: _block_sparse(True)),
+}
+
+
+def test_every_kernel_name_is_listed():
+    constants = {v for k, v in vars(names).items()
+                 if k.isupper() and isinstance(v, str)}
+    assert constants == set(KERNELS)
+
+
+@pytest.mark.parametrize("constant", sorted(KERNELS))
+def test_kernel_lowers_under_its_name(constant):
+    spelled, case = KERNELS[constant]
+    assert constant == spelled      # the reducer matches this spelling
+    fn, args = case()
+    shapes = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in args]
+    if "block_sparse" in spelled:
+        assert re.search(spelled + r"\b", str(jax.make_jaxpr(fn)(*shapes)))
+        return
+    text = jax.jit(fn).trace(*shapes).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert f'kernel_name = "{spelled}"' in text

@@ -93,12 +93,12 @@ def test_capacity_validated():
 # disabled tracer: zero work
 # ---------------------------------------------------------------------------
 
-def test_disabled_tracer_allocates_nothing():
+def test_disabled_tracer_writes_nothing():
     tr = Tracer(capacity=8, enabled=False)
-    # span() hands back ONE shared singleton — no per-call allocation
-    assert tr.span("a") is tr.span("b")
-    with tr.span("a"):
-        pass
+    # a span still opens (its profiler annotation is live either way) and
+    # takes late arguments, but nothing reaches the ring
+    with tr.span("a", args={"step": 1}) as sp:
+        sp.set(tokens=3)
     tr.instant("x", args={"big": list(range(10))})
     tr.complete("y", 0.0, 1.0)
     assert len(tr) == 0 and tr._count == 0
@@ -112,6 +112,86 @@ def test_span_records_complete_event():
     assert ev["name"] == "op" and ev["ph"] == "X" and ev["dur"] >= 0
     assert ev["args"] == {"rid": "r1"} and ev["cat"] == "test"
     assert validate_event(ev) is None
+
+
+@pytest.mark.parametrize("kwargs,late,want_name,want_args", [
+    (dict(name="plan", cat="host", args={"step": 3}), {},
+     "plan", {"step": 3}),
+    (dict(name="plan", cat="host", args={"step": 3}), {"grants": 2},
+     "plan", {"step": 3, "grants": 2}),
+    (dict(name="dispatch", cat="engine", ring="mixed_step"), {"rows": 4},
+     "mixed_step", {"rows": 4}),
+    (dict(name="train_batch", cat="train", step=7), {},
+     "train_batch", {"step": 7}),
+    (dict(name="bare"), {}, "bare", None),
+])
+def test_span_writes_the_event_complete_would(kwargs, late, want_name,
+                                              want_args):
+    """``span`` and ``complete`` leave the same ring event: schema, name
+    (``ring`` keeps an older spelling), category, arguments, late ones
+    included."""
+    tr, ref = Tracer(capacity=8), Tracer(capacity=8)
+    with tr.span(**kwargs) as sp:
+        if late:
+            sp.set(**late)
+    (ev,) = tr.events()
+    ref.complete(want_name, ev["ts"] / 1e6, (ev["ts"] + ev["dur"]) / 1e6,
+                 cat=kwargs.get("cat", ""), args=want_args)
+    (want,) = ref.events()
+    assert validate_event(ev) is None
+    assert {k: v for k, v in ev.items() if k != "dur"} == \
+        {k: v for k, v in want.items() if k != "dur"}
+    assert ev["dur"] == pytest.approx(want["dur"], abs=1e-3)
+
+
+def test_nested_spans_close_inner_first_and_contain():
+    tr = Tracer(capacity=8)
+    with tr.span("step", cat="engine", step=1):
+        with tr.span("plan", cat="host", args={"step": 1}):
+            pass
+        with tr.span("pack", cat="host", args={"step": 1}):
+            pass
+    plan, pack, step = tr.events()
+    assert [e["name"] for e in (plan, pack, step)] == ["plan", "pack", "step"]
+    assert step["ts"] <= plan["ts"] <= plan["ts"] + plan["dur"] <= pack["ts"]
+    assert pack["ts"] + pack["dur"] <= step["ts"] + step["dur"]
+
+
+def test_span_reaches_the_profiler_whatever_the_tracer(tmp_path):
+    """The second sink: with a profiler recording, every span is a
+    ``ds.<name>`` event on its clock, arguments as stats, late ones
+    included — tracer enabled or not."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for tr in (Tracer(capacity=8, enabled=False), Tracer(capacity=8)):
+            with tr.span("step", cat="engine", step=5):
+                with tr.span("dispatch", ring="mixed_step",
+                             args={"step": 5, "enabled": int(tr.enabled)}
+                             ) as sp:
+                    sp.set(rows=3)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    found = [(e.name, dict(e.stats), e.start_ns, e.duration_ns)
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("ds.")]
+    steps = [f for f in found if f[0] == "ds.step"]
+    inner = [f for f in found if f[0] == "ds.dispatch"]
+    assert len(steps) == len(inner) == 2       # never the ring's spelling
+    assert sorted(f[1]["enabled"] for f in inner) == [0, 1]
+    for (_, stats, s, d), (_, outer, s0, d0) in zip(inner, steps):
+        assert stats["step"] == 5 and stats["rows"] == 3
+        assert outer["step_num"] == 5
+        assert s0 <= s and s + d <= s0 + d0
 
 
 # ---------------------------------------------------------------------------
