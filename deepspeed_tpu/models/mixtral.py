@@ -8,19 +8,27 @@ of the selected experts' SwiGLU outputs (plus the Switch load-balancing aux
 loss scaled by ``router_aux_loss_coef`` during training).
 
 TPU-native dispatch: expert weights live STACKED ``[E, ...]`` and shard over
-the ``expert`` mesh axis; every expert's matmuls run on its own shard with
-tokens broadcast, and the top-k-masked combine is the cross-expert psum the
-partitioner inserts. This is exact (no capacity drops — decisive for HF
-logits parity) at the cost of dense E-way MLP FLOPs; for capacity-based
-all_to_all dispatch at training scale use ``deepspeed_tpu.moe.MoE`` (GShard
-gating, reference ``sharded_moe.py``) — the reference makes the same
-split between its inference MoE kernels (``moe_res_matmul``) and its
-training-time gated dispatch.
+the ``expert`` mesh axis. For ``T > 1`` the layer is a dropless, token-sorted
+grouped matmul (``_routed_experts``): the ``N*K`` (token, expert) pairs are
+stably sorted by expert, each expert's run of rows multiplies its own
+weights through ``jax.lax.ragged_dot`` (XLA:TPU's grouped-matmul kernel),
+and each token's K rows are weighted and summed back in float32. Group
+sizes are DATA, so no routing recompiles, nothing is dropped (decisive for
+HF logits parity) or padded to a capacity, and the arithmetic follows the
+rows routed: K/E of what computing every expert for every token costs. Under
+an ``expert`` mesh axis the same function runs inside a ``shard_map`` that
+moves TOKENS and never weights: tokens all-gather over ``expert`` on entry,
+each chip computes the pairs routed to its ``E/ep`` experts, and the
+partial outputs ``psum_scatter`` back onto the batch layout. ``T == 1``
+with replicated experts keeps the weight-gather decode path. For
+capacity-based all_to_all dispatch use ``deepspeed_tpu.moe.MoE`` (GShard
+gating, reference ``sharded_moe.py``).
 
 Attention/rotary/cache machinery is shared with ``models/llama.py``.
 """
 
 import dataclasses
+import math
 from typing import Optional
 
 import flax.linen as nn
@@ -28,6 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..parallel.topology import BATCH_AXES, get_mesh, tokens_replicated
 from .layers import (RMSNorm, cross_entropy_loss, head_scope, init_kv_cache,
                      lm_head_output,
                      resolve_remat_policy, rotary_embedding, shift_labels)
@@ -38,59 +47,11 @@ def _expert_axis_active() -> bool:
     """True when the active mesh shards the ``expert`` axis (>1): the
     gather decode path would pull sharded expert rows cross-device, so it
     only engages with replicated experts."""
-    from ..parallel.topology import get_mesh
-
     mesh = get_mesh()
     if mesh is None:
         return False
     return dict(zip(mesh.axis_names,
                     mesh.devices.shape)).get("expert", 1) > 1
-
-
-def _ep_constraint(t, *spec):
-    """Pin a MoE-internal tensor's sharding (axes present in the active mesh
-    only; no-op off-mesh). Without these pins the partitioner must invent a
-    layout for the [B,T,E,·] intermediates — the batch arrives sharded over
-    (data, expert) while the stacked expert weights shard E over expert, and
-    XLA's guess triggered an 'involuntary full rematerialization' warning
-    (a replicate-then-repartition perf cliff) in the r3 multichip dryrun.
-
-    TPU-only (override: ``DS_EP_CONSTRAINTS=1``): the entry pin makes the
-    partitioner all-gather tokens over the expert axis inside the layer
-    scan, which the XLA:CPU thunk runtime cannot execute (its collective
-    rendezvous aborts — same environmental limit as ``__graft_entry__``
-    section 2d). On CPU meshes use the engine's
-    ``{"moe": {"replicate_tokens": true}}`` layout instead, which needs no
-    in-layer batch reshard (tokens already replicated over the expert axis;
-    the only in-layer collective is the combine psum)."""
-    import os
-
-    from ..parallel.topology import get_mesh, tokens_replicated
-
-    if tokens_replicated():
-        # the engine chose the data-only token layout — these (data, expert)
-        # entry/exit pins would reintroduce the per-layer batch reshard the
-        # flag exists to avoid
-        return t
-    if jax.default_backend() != "tpu" and not os.environ.get("DS_EP_CONSTRAINTS"):
-        return t
-    mesh = get_mesh()
-    if mesh is None:
-        return t
-    shape = dict(zip(mesh.axis_names, mesh.devices.shape))
-
-    def keep(ax):
-        if ax is None:
-            return None
-        axes = ax if isinstance(ax, tuple) else (ax,)
-        kept = tuple(a for a in axes if shape.get(a, 1) > 1)
-        return kept or None
-
-    spec = [keep(s) for s in spec]
-    if all(s is None for s in spec):
-        return t
-    return jax.lax.with_sharding_constraint(
-        t, jax.sharding.NamedSharding(mesh, P(*spec)))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -167,24 +128,160 @@ class MixtralSparseMoeBlock(nn.Module):
                         jnp.float32)  # up
         w2 = self.param("w2", nn.initializers.lecun_normal(), (E, I, H),
                         jnp.float32)  # down
-        out = _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx, onehot)
+        out, rows = _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx)
+        if rows is not None:
+            # (token, expert) pairs each expert computed this call, [E]:
+            # free unless the caller asks for the collection
+            self.sow("intermediates", "expert_rows", rows)
         frac, prob = _router_stats(onehot, probs, token_mask, B * T)
         return out, frac, prob
 
 
+def _grouped_dot(lhs, rhs, group_sizes):
+    """``lhs [M, A] x rhs [G, A, B] -> [M, B]``: rows of group ``g`` (runs
+    of ``group_sizes[g]`` sorted rows) times ``rhs[g]``, through
+    ``jax.lax.ragged_dot`` — XLA:TPU's own grouped-matmul kernel, float32
+    accumulation. Only this layout reaches the kernel: asked to contract
+    ``rhs``'s last dim instead, the compiler falls back to a masked dense
+    product that costs 12-14 ms where the kernel takes 8 (PERF.md), so a
+    transposed weight is transposed first.
+
+    Rows past the last group belong to no group: the definition makes their
+    result zero, the TPU leaves it as it finds it (NaN at Mixtral's sizes),
+    so it is SELECTED to zero there — a multiply would keep a NaN."""
+    valid = jnp.arange(lhs.shape[0]) < jnp.sum(group_sizes)
+    return jnp.where(valid[:, None],
+                     jax.lax.ragged_dot(lhs, rhs, group_sizes), 0)
+
+
+def _grouped_outer(lhs, rhs, group_sizes):
+    """``lhs [M, A], rhs [M, B] -> [G, A, B]``: ``lhs[rows of g]^T @
+    rhs[rows of g]`` for every group — a stacked weight's gradient."""
+    dims = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    return jax.lax.ragged_dot_general(lhs, rhs, group_sizes, dims)
+
+
+@jax.custom_vjp
+def _sorted_experts(x, w1, w3, w2, topk_w, order, inv, group_sizes):
+    """The grouped SwiGLU over sorted rows and the weighted combine.
+
+    ``x [N, H]`` tokens; ``w1``/``w3 [G, H, I]``, ``w2 [G, I, H]`` in the
+    compute dtype; ``topk_w [K, N]`` float32. Sorted row ``r`` holds pair
+    ``order[r]`` (pair ``k * N + n`` is token ``n``'s ``k``-th choice),
+    the ``sum(group_sizes)`` pairs of these experts first; ``inv [K * N]``
+    is each pair's row. Returns ``[N, H]``: each token's K rows times their
+    routing weights, summed in float32; pairs of no group add zero.
+
+    The backward pass is written out. Both permutations stay gathers (by
+    ``order`` and by ``inv``) where autodiff would scatter-add ``[M, H]``
+    rows. And the routing weights' gradient ``<y_row, g_row>`` is taken as
+    ``<h_row, (g @ w2^T)_row>``, a by-product of ``dh``: the down
+    projection is no residual, so ``jax.checkpoint`` replays two products,
+    not three (11 a step, 9 without it)."""
+    return _sorted_experts_fwd(x, w1, w3, w2, topk_w, order, inv,
+                               group_sizes)[0]
+
+
+def _sorted_experts_fwd(x, w1, w3, w2, topk_w, order, inv, group_sizes):
+    K, N = topk_w.shape
+    with jax.named_scope("moe_dispatch"):
+        xs = x[order % N]
+    with jax.named_scope("moe_gmm"):
+        h1 = _grouped_dot(xs, w1, group_sizes)
+        h3 = _grouped_dot(xs, w3, group_sizes)
+        y = _grouped_dot(nn.silu(h1) * h3, w2, group_sizes)
+    with jax.named_scope("moe_combine"):
+        y = y[inv].reshape(K, N, -1).astype(jnp.float32)
+        out = jnp.sum(y * topk_w[:, :, None], axis=0).astype(x.dtype)
+    return out, (xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3)
+
+
+def _sorted_experts_bwd(res, g):
+    xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3 = res
+    K, N = topk_w.shape
+    dt, f32 = xs.dtype, jnp.float32
+    with jax.named_scope("moe_combine"):
+        g_row = g[order % N]                 # each row's cotangent, before
+        w_row = topk_w.reshape(K * N)[order][:, None]  # its routing weight
+    with jax.named_scope("moe_gmm"):
+        t = _grouped_dot(g_row, jnp.swapaxes(w2, 1, 2),
+                         group_sizes).astype(f32)    # g @ w2^T
+        a1, a3 = h1.astype(f32), h3.astype(f32)
+        sig = jax.nn.sigmoid(a1)
+        h = a1 * sig * a3
+        d_w_row = jnp.sum(h * t, axis=-1)
+        dh = t * w_row
+        dh1 = (dh * a3 * sig * (1 + a1 * (1 - sig))).astype(dt)
+        dh3 = (dh * a1 * sig).astype(dt)
+        dxs = _grouped_dot(dh1, jnp.swapaxes(w1, 1, 2), group_sizes) + \
+            _grouped_dot(dh3, jnp.swapaxes(w3, 1, 2), group_sizes)
+        dw1 = _grouped_outer(xs, dh1, group_sizes)
+        dw3 = _grouped_outer(xs, dh3, group_sizes)
+        dw2 = _grouped_outer((h * w_row).astype(dt), g_row, group_sizes)
+    with jax.named_scope("moe_dispatch"):
+        dx = dxs[inv].reshape(K, N, -1).sum(axis=0)
+        d_topk_w = d_w_row[inv].reshape(K, N)
+    return dx, dw1, dw3, dw2, d_topk_w, None, None, None
+
+
+_sorted_experts.defvjp(_sorted_experts_fwd, _sorted_experts_bwd)
+
+
+def _routed_experts(x, w1, w2, w3, topk_w, topk_idx, first):
+    """Dropless grouped SwiGLU of tokens ``x [N, H]`` through the experts
+    ``first .. first + G`` whose weights are ``w1``/``w3 [G, H, I]`` and
+    ``w2 [G, I, H]``: ``(out [N, H], group_sizes [G])``. Pairs routed to
+    other experts contribute zero (another shard computes them).
+
+    The sorted row buffer is the static worst case, ``N*K`` rows (every
+    pair on these experts); ``group_sizes`` is data, so the products follow
+    the rows routed here and no routing recompiles or drops a token."""
+    N, K = topk_idx.shape
+    G, M, dt = w1.shape[0], N * K, x.dtype
+    with jax.named_scope("moe_dispatch"):
+        # choice-major pair ids (k * N + n): [M, H] folds to [K, N, H]
+        # without the relayout a [N, K, H] fold costs on the TPU
+        local = topk_idx.T.reshape(M) - first
+        key = jnp.where((local >= 0) & (local < G), local, G)  # others last
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)  # row -> pair
+        inv = jnp.zeros((M,), jnp.int32).at[order].set(
+            jnp.arange(M, dtype=jnp.int32), unique_indices=True)  # pair -> row
+        group_sizes = jnp.sum(key[:, None] == jnp.arange(G)[None, :],
+                              axis=0, dtype=jnp.int32)
+    out = _sorted_experts(x, w1.astype(dt), w3.astype(dt), w2.astype(dt),
+                          topk_w.T.astype(jnp.float32), order, inv,
+                          group_sizes)
+    return out, group_sizes
+
+
+def _token_axes(size, batch):
+    """The mesh axes the ``[B, T, H]`` tokens shard ``B`` over inside the
+    expert layer: the engine's batch layout (``("data", "expert")``,
+    ``("data",)`` under ``moe.replicate_tokens``) as far as it divides."""
+    axes = ("data",) if tokens_replicated() else BATCH_AXES
+    axes = tuple(a for a in axes if size.get(a, 1) > 1)
+    while axes and batch % math.prod(size[a] for a in axes):
+        axes = axes[:-1]
+    return axes
+
+
 @jax.named_scope("ds.moe_experts")
-def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx, onehot):
-    """The stacked expert SwiGLU and the weighted combine: ``[B, T, H]``."""
-    T, dt = x.shape[1], x.dtype
+def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx):
+    """The stacked expert SwiGLU and the weighted combine: ``(out [B, T, H],
+    rows)``, ``rows [E]`` the (token, expert) pairs each expert computed
+    (None on the decode path, which sorts nothing)."""
+    B, T, H = x.shape
+    dt = x.dtype
     E, K = cfg.num_local_experts, cfg.num_experts_per_tok
     if T == 1 and E > K and not _expert_axis_active():
         # decode fast path (replicated experts): GATHER only the K
         # touched experts' weights per token instead of computing all E
-        # — the stacked einsum streams E/K x the weight bytes a decode
-        # step needs (the reference's einsum_sec_sm_ecm / moe_res_matmul
-        # kernels exist for exactly this; tools/bench_moe_decode.py
-        # measures it as gather_speedup_vs_all_e). XLA's gather reads
-        # only the indexed expert rows from HBM.
+        # — a decode step is bound by the weight bytes it streams (the
+        # reference's einsum_sec_sm_ecm / moe_res_matmul kernels exist
+        # for exactly this; tools/bench_moe_decode.py measures it).
+        # XLA's gather reads only the indexed expert rows from HBM.
         idx = topk_idx[:, 0]                        # [B, K]
         w1g = jnp.take(w1, idx, axis=0).astype(dt)  # [B, K, H, I]
         w3g = jnp.take(w3, idx, axis=0).astype(dt)
@@ -195,25 +292,55 @@ def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx, onehot):
         y = jnp.einsum("bki,bkih->bkh", hidden, w2g)
         out = jnp.einsum("bk,bkh->bh",
                          topk_w[:, 0].astype(dt), y)[:, None]
-    else:
-        # dense [B, T, E] combine weights, zero outside the top-k;
-        # the combine joins the expert-axis-gathered tokens in the
-        # final einsum
-        combine = jnp.einsum("btk,btke->bte", topk_w, onehot)
-        combine = _ep_constraint(combine, "data", None, None)
-        # EP layout (GShard-style): tokens all-gather over the expert
-        # axis at entry (B drops to data-only sharding), the [B,T,E,·]
-        # intermediates keep E on the expert axis, and the combine
-        # contraction over E reduce-scatters B back onto (data, expert)
-        xg = _ep_constraint(x, "data", None, None)
-        h = nn.silu(jnp.einsum("bth,ehi->btei", xg, w1.astype(dt))) * \
-            jnp.einsum("bth,ehi->btei", xg, w3.astype(dt))
-        h = _ep_constraint(h, "data", None, "expert", None)
-        y = jnp.einsum("btei,eih->bteh", h, w2.astype(dt))
-        y = _ep_constraint(y, "data", None, "expert", None)
-        out = jnp.einsum("bte,bteh->bth", combine.astype(dt), y)
-        out = _ep_constraint(out, ("data", "expert"), None, None)
-    return out
+        return out, None
+
+    def experts(x, w1, w2, w3, topk_w, topk_idx, first=0):
+        out, rows = _routed_experts(
+            x.reshape(-1, H), w1, w2, w3, topk_w.reshape(-1, K),
+            topk_idx.reshape(-1, K), first)
+        return out.reshape(x.shape), rows
+
+    mesh = get_mesh()
+    size = {} if mesh is None else dict(zip(mesh.axis_names,
+                                            mesh.devices.shape))
+    if size.get("expert", 1) == 1:
+        return experts(x, w1, w2, w3, topk_w, topk_idx)
+
+    # expert parallel: tokens move, weights never do. Tokens all-gather
+    # over `expert` on entry (or are already whole on it), each shard
+    # computes the pairs routed to its own experts, and the partial outputs
+    # sum back onto the batch layout.
+    batch = _token_axes(size, B)
+    gathered = "expert" in batch
+    others = tuple(a for a in batch if a != "expert")
+
+    def shard(x, w1, w2, w3, topk_w, topk_idx):
+        # the hand-written backward passes return cotangents that vary over
+        # every axis the rows do; an input that enters whole on one of them
+        # is marked varying there, or its cotangent misses the sum over it
+        if gathered:
+            x, topk_w, topk_idx = (
+                jax.lax.all_gather(t, "expert", axis=0, tiled=True)
+                for t in (x, topk_w, topk_idx))
+        else:
+            x, topk_w = jax.lax.pcast((x, topk_w), "expert", to="varying")
+        if others:
+            w1, w2, w3 = jax.lax.pcast((w1, w2, w3), others, to="varying")
+        out, rows = experts(x, w1, w2, w3, topk_w, topk_idx,
+                            jax.lax.axis_index("expert") * w1.shape[0])
+        if gathered:
+            out = jax.lax.psum_scatter(out, "expert", scatter_dimension=0,
+                                       tiled=True)
+        else:
+            out = jax.lax.psum(out, "expert")
+        return out, (jax.lax.psum(rows, others) if others else rows)
+
+    tokens = P(batch or None, None, None)
+    stacked = P("expert", None, None)
+    return jax.shard_map(
+        shard, mesh=mesh,
+        in_specs=(tokens, stacked, stacked, stacked, tokens, tokens),
+        out_specs=(tokens, P("expert")))(x, w1, w2, w3, topk_w, topk_idx)
 
 
 class MixtralBlock(nn.Module):
@@ -279,7 +406,8 @@ class MixtralModel(nn.Module):
             if cfg.remat and cache is None:
                 block_cls = nn.remat(_ScanBlock, prevent_cse=False,
                                      policy=remat_policy)
-            scan = nn.scan(block_cls, variable_axes={"params": 0},
+            scan = nn.scan(block_cls,
+                           variable_axes={"params": 0, "intermediates": 0},
                            split_rngs={"params": True, "dropout": True},
                            length=cfg.num_hidden_layers, metadata_params={})
             (x, *_, frac_sum, prob_sum), cache = scan(cfg, name="layers")(

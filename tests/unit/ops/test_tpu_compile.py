@@ -1,4 +1,5 @@
-"""The Pallas kernels of the main path must COMPILE for the chip.
+"""The Pallas kernels of the main path, and Mixtral's expert layer under
+``expert=4``, must COMPILE for the chip.
 
 Interpret mode (every other kernel test) runs the kernel's Python body and
 says nothing about Mosaic's layout rules: before PR 21 every attention
@@ -11,7 +12,9 @@ Hkv8 / D128, bf16). Nothing runs: this guards layouts, not results.
 """
 
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,10 +32,10 @@ BF16 = jnp.bfloat16
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """Sharding on one described v5e chip; the persistent compile cache is
-    off around the module (a TPU executable written here could never be
-    read back without a chip, and the next run would warn about it)."""
+def topo():
+    """A described ``v5e:2x2``; the persistent compile cache is off around
+    the module (a TPU executable written here could never be read back
+    without a chip, and the next run would warn about it)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -45,9 +48,15 @@ def chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """Sharding on one described v5e chip."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _scale_kw(scales):
@@ -136,3 +145,64 @@ def test_kernel_compiles_for_v5e(chip, name):
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         "the compiled program holds no Mosaic kernel"
+
+
+# -- Mixtral's expert layer across the four chips ---------------------------
+
+_COLLECTIVE = re.compile(
+    r"= (\w+)\[([\d,]*)\]\S* (all-gather|all-reduce|reduce-scatter|"
+    r"all-to-all|collective-permute)(?:-start)?\(")
+_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+
+
+def test_mixtral_expert_layer_moves_tokens_not_weights_on_v5e_2x2(topo):
+    """The MoE layer at Mixtral-8x7B widths (4 x 4096 tokens, 8 experts of
+    14336, top-2), forward and backward, compiles for ``v5e:2x2`` under
+    ``expert=4``. In the optimized HLO the products are XLA's own grouped
+    matmuls (no ``pallas_call`` of ours in the lowering), and every
+    collective is at most token-sized (B*T*H*4 bytes) and never shaped like
+    an expert weight — what ledger PR 25's breakdown is pinned against."""
+    import deepspeed_tpu.models.mixtral as mx
+    from deepspeed_tpu.models import MixtralConfig
+    from deepspeed_tpu.parallel import build_mesh
+    from deepspeed_tpu.parallel.topology import set_mesh
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    B, T, HID, INTER, E, K = 4, 4096, 4096, 14336, 8, 2
+    cfg = MixtralConfig.mixtral_8x7b(num_hidden_layers=1)
+    mesh = build_mesh(expert=4, devices=topo.devices)
+    set_mesh(mesh)  # the conftest fixture clears it
+    tokens = NamedSharding(mesh, P(("data", "expert")))
+    stacked = NamedSharding(mesh, P("expert"))
+
+    def struct(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    args = (struct((B, T, HID), BF16, tokens),
+            struct((E, HID, INTER), jnp.float32, stacked),
+            struct((E, INTER, HID), jnp.float32, stacked),
+            struct((E, HID, INTER), jnp.float32, stacked),
+            struct((B, T, K), jnp.float32, tokens),
+            struct((B, T, K), jnp.int32, tokens))
+
+    def loss(x, w1, w2, w3, topk_w, topk_idx):
+        out, rows = mx._expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx)
+        return jnp.sum(out.astype(jnp.float32) ** 2), rows
+
+    lowered = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(*args)
+    assert "pallas" not in lowered.as_text().lower()
+    hlo = lowered.compile().as_text()
+    assert len(re.findall(r"%ragged-dot\S* = ", hlo)) >= 9, \
+        "three products forward, three dx, three dw"
+    found = [m.groups() for m in map(_COLLECTIVE.search, hlo.splitlines())
+             if m]
+    assert {kind for _, _, kind in found} >= {"all-gather"}
+    token_bytes = B * T * HID * 4
+    per_chip = E // 4
+    for dtype, dims, kind in found:
+        dims = [int(d) for d in dims.split(",") if d]
+        assert math.prod(dims) * _BYTES.get(dtype, 8) <= 1.01 * token_bytes, \
+            (kind, dtype, dims)
+        assert not ({HID, INTER} <= set(dims)
+                    and dims[0] in (per_chip, E)), (kind, dtype, dims)

@@ -211,41 +211,54 @@ def test_replicate_tokens_ep_layout_trains():
     assert "REPLICATE-OK" in r.stdout
 
 
-def test_ep_constraints_compile_on_cpu():
-    """The TPU E+D layout pins (gather tokens over `expert` at MoE entry,
-    reduce-scatter at exit) must at least LOWER + PARTITION cleanly; only
-    execution is TPU-gated (the CPU thunk rendezvous limitation). Compiling
-    with DS_EP_CONSTRAINTS=1 proves the sharding annotations are valid and
-    that the partitioner places an explicit all-gather instead of the
-    'involuntary full rematerialization' fallback."""
-    import os
-    from unittest import mock
+def test_ep_layout_moves_tokens_not_weights_on_cpu():
+    """The expert-parallel layer under the engine's (data, expert) batch
+    layout: the train step compiles with the token all-gather over `expert`
+    on entry that the layer's ``shard_map`` states, and no collective in the
+    partitioned program has an expert weight's shape — tokens move, weights
+    and their gradients never do (PERF.md, PR 25's lines)."""
+    import re
 
     import deepspeed_tpu as ds
     from deepspeed_tpu.models import MixtralConfig, MixtralForCausalLM
     from deepspeed_tpu.parallel import build_mesh
 
-    with mock.patch.dict(os.environ, {"DS_EP_CONSTRAINTS": "1"}):
-        cfg = MixtralConfig.tiny()
-        model = MixtralForCausalLM(cfg)
-        rs = np.random.RandomState(0)
-        batch = {"input_ids": rs.randint(0, cfg.vocab_size, (8, 16)),
-                 "labels": rs.randint(0, cfg.vocab_size, (8, 16))}
-        mesh = build_mesh(data=2, expert=4)
-        engine, *_ = ds.initialize(
-            model=model,
-            config={"train_batch_size": 8,
-                    "optimizer": {"type": "AdamW", "params": {"lr": 3e-3}},
-                    "steps_per_print": 0},
-            example_batch={k: v[:1] for k, v in batch.items()}, mesh=mesh,
-            partition_rules=MixtralForCausalLM.partition_rules(cfg))
-        compiled = engine._train_step.lower(
-            engine.state,
-            {"input_ids": batch["input_ids"].reshape(1, 8, 16),
-             "labels": batch["labels"].reshape(1, 8, 16)},
-            jax.random.PRNGKey(0)).compile()
-        hlo = compiled.as_text()
-        assert "all-gather" in hlo  # the explicit entry gather is placed
+    cfg = MixtralConfig.tiny()
+    model = MixtralForCausalLM(cfg)
+    rs = np.random.RandomState(0)
+    batch = {"input_ids": rs.randint(0, cfg.vocab_size, (8, 16)),
+             "labels": rs.randint(0, cfg.vocab_size, (8, 16))}
+    mesh = build_mesh(data=2, expert=4)
+    engine, *_ = ds.initialize(
+        model=model,
+        config={"train_batch_size": 8,
+                "optimizer": {"type": "AdamW", "params": {"lr": 3e-3}},
+                "steps_per_print": 0},
+        example_batch={k: v[:1] for k, v in batch.items()}, mesh=mesh,
+        partition_rules=MixtralForCausalLM.partition_rules(cfg))
+    compiled = engine._train_step.lower(
+        engine.state,
+        {"input_ids": batch["input_ids"].reshape(1, 8, 16),
+         "labels": batch["labels"].reshape(1, 8, 16)},
+        jax.random.PRNGKey(0)).compile()
+    hlo = compiled.as_text()
+    assert "all-gather" in hlo  # the explicit entry gather is placed
+    H, I = cfg.hidden_size, cfg.intermediate_size
+    collectives = [ln for ln in hlo.splitlines() if re.search(
+        r"= \S+ (all-gather|all-reduce|reduce-scatter|all-to-all|"
+        r"collective-permute)(-start)?\(", ln)]
+    assert collectives
+    # the expert axis never carries a weight: a collective with a
+    # [.., H, I] operand runs in groups of 2, the data axis alone (a weight
+    # gradient's sum over its data replicas), never of 4 or of all 8
+    for ln in collectives:
+        if re.search(rf"\[(\d+,)*({H},{I}|{I},{H})\]", ln):
+            iota = re.search(r"replica_groups=\[\d+,(\d+)\]", ln)
+            listed = re.search(r"replica_groups=\{\{([\d,]+)\}", ln)
+            assert iota or listed, ln
+            group = int(iota.group(1)) if iota \
+                else len(listed.group(1).split(","))
+            assert group == 2, ln
 
 
 def test_ep_inference_parity_and_expert_placement():
@@ -335,11 +348,11 @@ def test_decode_gather_path_computes_only_touched_experts():
         "gather decode path did not engage (all-E intermediate present)"
 
     orig = mx._expert_axis_active
-    mx._expert_axis_active = lambda: True  # force the all-E dense branch
+    mx._expert_axis_active = lambda: True  # sharded experts: grouped path
     try:
-        assert has_all_e_intermediate(
-            jax.make_jaxpr(lambda p, t, c: step(p, t, c))(params, tok,
-                                                          cache))
+        grouped = str(jax.make_jaxpr(lambda p, t, c: step(p, t, c))(
+            params, tok, cache))
+        assert "ragged_dot" in grouped and not has_all_e_intermediate(grouped)
         out_d, _ = step(params, tok, cache)
     finally:
         mx._expert_axis_active = orig
@@ -376,3 +389,150 @@ def test_inference_engine_registers_explicit_mesh_globally():
     # the decode-layout check now sees the expert axis → gather fast path
     # stays OFF for sharded experts
     assert mx._expert_axis_active()
+
+
+# -- the grouped expert layer against the all-E formula (the oracle) --------
+
+def _all_e_oracle(x, w1, w2, w3, topk_w, topk_idx):
+    """What the layer computed before it sorted tokens: every expert for
+    every token, masked by the dense combine weights."""
+    E = w1.shape[0]
+    combine = jnp.einsum("nk,nke->ne", topk_w,
+                         jax.nn.one_hot(topk_idx, E, dtype=topk_w.dtype))
+    h = jax.nn.silu(jnp.einsum("nh,ehi->nei", x, w1)) * \
+        jnp.einsum("nh,ehi->nei", x, w3)
+    return jnp.einsum("ne,neh->nh", combine, jnp.einsum("nei,eih->neh", h, w2))
+
+
+def _routing(kind, N, E, K, rs):
+    """Top-K expert ids ``[N, K]`` (distinct per token) of one routing
+    pattern, and its normalised weights."""
+    if kind == "uniform":
+        idx = np.stack([rs.permutation(E)[:K] for _ in range(N)])
+    elif kind == "skewed":      # nearly every token takes expert 0 first
+        idx = np.stack([rs.permutation(E)[:K] for _ in range(N)])
+        hot = rs.rand(N) < 0.9
+        idx[hot, 1] = np.where(idx[hot, 1] == 0, idx[hot, 0], idx[hot, 1])
+        idx[hot, 0] = 0
+    else:                       # "starved": expert 3 receives no token
+        others = np.array([e for e in range(E) if e != 3])
+        idx = np.stack([rs.permutation(others)[:K] for _ in range(N)])
+    w = rs.rand(N, K).astype(np.float32) + 0.1
+    return jnp.asarray(idx, jnp.int32), jnp.asarray(w / w.sum(-1, keepdims=True))
+
+
+def _moe_case(kind, B=8, T=6, H=16, I=24, E=8, K=2, seed=0):
+    rs = np.random.RandomState(seed)
+    x = jnp.asarray(rs.randn(B, T, H), jnp.float32)
+    w1, w3 = (jnp.asarray(rs.randn(E, H, I) * 0.3, jnp.float32)
+              for _ in range(2))
+    w2 = jnp.asarray(rs.randn(E, I, H) * 0.3, jnp.float32)
+    idx, w = _routing(kind, B * T, E, K, rs)
+    return x, w1, w2, w3, w.reshape(B, T, K), idx.reshape(B, T, K)
+
+
+def _moe_loss(fn):
+    """A scalar of the layer's output whose gradient reaches every input."""
+    def loss(x, w1, w2, w3, topk_w, topk_idx):
+        out = fn(x, w1, w2, w3, topk_w, topk_idx)
+        return jnp.sum(out * jnp.cos(jnp.arange(out.size).reshape(out.shape)))
+    return loss
+
+
+def _grouped(cfg):
+    import deepspeed_tpu.models.mixtral as mx
+
+    return lambda *a: mx._expert_mlp(cfg, *a)[0]
+
+
+def _oracle3d(x, w1, w2, w3, topk_w, topk_idx):
+    K = topk_idx.shape[-1]
+    return _all_e_oracle(x.reshape(-1, x.shape[-1]), w1, w2, w3,
+                         topk_w.reshape(-1, K),
+                         topk_idx.reshape(-1, K)).reshape(x.shape)
+
+
+_MOE_CFG = dict(num_local_experts=8, num_experts_per_tok=2)
+
+
+@pytest.mark.parametrize("kind", ["uniform", "skewed", "starved"])
+def test_grouped_experts_match_all_e_oracle(kind):
+    """Outputs and the gradients w.r.t. x, w1, w2, w3 and the routing
+    weights of the token-sorted grouped layer equal the all-E formula's;
+    group sizes count exactly the routed pairs."""
+    import deepspeed_tpu.models.mixtral as mx
+    from deepspeed_tpu.models import MixtralConfig
+
+    cfg = MixtralConfig.tiny(**_MOE_CFG)
+    args = _moe_case(kind)
+    out, rows = mx._expert_mlp(cfg, *args)
+    np.testing.assert_allclose(out, _oracle3d(*args), rtol=2e-5, atol=2e-5)
+    counts = np.bincount(np.asarray(args[5]).ravel(), minlength=8)
+    np.testing.assert_array_equal(np.asarray(rows), counts)
+    assert int(rows.sum()) == args[5].size
+    if kind == "starved":
+        assert int(rows[3]) == 0
+    got = jax.grad(_moe_loss(_grouped(cfg)), argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(_moe_loss(_oracle3d), argnums=(0, 1, 2, 3, 4))(*args)
+    for name, g, w in zip(("x", "w1", "w2", "w3", "topk_w"), got, want):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("data", [1, 2], ids=["expert4", "data2xexpert4"])
+@pytest.mark.parametrize("replicate", [False, True],
+                         ids=["gathered", "replicate_tokens"])
+@pytest.mark.parametrize("kind", ["uniform", "skewed", "starved"])
+def test_grouped_experts_under_expert_mesh_match_unmeshed(kind, replicate,
+                                                          data):
+    """The same layer inside the ``shard_map`` over a CPU `expert=4` mesh
+    (alone, and under `data=2`) — tokens all-gathered over `expert` (the
+    engine's batch layout) or already whole on it
+    (``moe.replicate_tokens``) — gives the unmeshed outputs, gradients and
+    group sizes. Forward parity alone misses an input that enters whole on
+    an axis and was not marked varying: its cotangent loses the sum over
+    the shards (x and the routing weights over `expert`, the expert weights
+    over `data`)."""
+    import deepspeed_tpu.models.mixtral as mx
+    from deepspeed_tpu.models import MixtralConfig
+    from deepspeed_tpu.parallel import build_mesh
+    from deepspeed_tpu.parallel.topology import (set_mesh,
+                                                 set_token_replication)
+
+    cfg = MixtralConfig.tiny(**_MOE_CFG)
+    args = _moe_case(kind, seed=1)
+    want_out, want_rows = mx._expert_mlp(cfg, *args)
+    want = jax.grad(_moe_loss(_grouped(cfg)), argnums=(0, 1, 2, 3, 4))(*args)
+
+    set_mesh(build_mesh(data=data, expert=4,
+                        devices=jax.devices()[:4 * data]))
+    set_token_replication(replicate)
+    hlo = jax.jit(lambda *a: mx._expert_mlp(cfg, *a)).lower(*args).as_text()
+    assert ("all_gather" in hlo) == (not replicate)
+    out, rows = jax.jit(lambda *a: mx._expert_mlp(cfg, *a))(*args)
+    np.testing.assert_allclose(out, want_out, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(want_rows))
+    got = jax.jit(jax.grad(_moe_loss(_grouped(cfg)),
+                           argnums=(0, 1, 2, 3, 4)))(*args)
+    for name, g, w in zip(("x", "w1", "w2", "w3", "topk_w"), got, want):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+def test_expert_rows_are_sown_for_callers_that_ask():
+    """``expert_rows`` in flax's ``intermediates``: one ``[E]`` vector per
+    layer (stacked by the layer scan), each summing to the routed pairs;
+    a plain ``apply`` returns logits alone."""
+    from deepspeed_tpu.models import MixtralConfig, MixtralForCausalLM
+
+    cfg = MixtralConfig.tiny()
+    model = MixtralForCausalLM(cfg)
+    ids = jnp.asarray(np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (2, 8)))
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    logits, aux = model.apply({"params": params}, ids,
+                              mutable=["intermediates"])
+    (rows,) = jax.tree_util.tree_leaves(aux["intermediates"])
+    assert rows.shape == (cfg.num_hidden_layers, cfg.num_local_experts)
+    np.testing.assert_array_equal(
+        np.asarray(rows.sum(-1)), ids.size * cfg.num_experts_per_tok)
+    plain = model.apply({"params": params}, ids)
+    np.testing.assert_allclose(plain, logits, rtol=1e-6, atol=1e-6)

@@ -106,8 +106,9 @@ def bench(batch: int, hidden: int, intermediate: int, experts: int, k: int,
     import deepspeed_tpu.models.mixtral as mx
 
     def moe_dense_fn(p, x):
-        # force the all-E stacked-einsum branch (what a no-gather
-        # implementation pays); the shipped decode path is moe_fn
+        # force the branch sharded experts take (the token-sorted
+        # grouped matmul: what decode pays without the weight gather);
+        # the shipped replicated-experts decode path is moe_fn
         orig = mx._expert_axis_active
         mx._expert_axis_active = lambda: True
         try:
