@@ -51,6 +51,10 @@ class LlamaConfig:
     sliding_window: Optional[int] = None
     #: Qwen2-style: biases on q/k/v projections (o/mlp stay bias-free)
     attention_qkv_bias: bool = False
+    #: OLMoE-style: RMSNorm over the WHOLE projected query and key (widths
+    #: Hq*D and Hkv*D, scales ``q_norm``/``k_norm``), before the split into
+    #: heads and before RoPE. Off: no parameter exists
+    qk_norm: bool = False
     #: Gemma-style knobs: explicit head_dim (H*D need not equal hidden),
     #: gelu-tanh MLP activation, sqrt(hidden) embedding scaling
     head_dim_override: Optional[int] = None
@@ -172,8 +176,13 @@ class LlamaAttention(nn.Module):
         # holds score-softmax-value only — the Pallas kernels here, their
         # XLA counterparts in layers.py — so both are measured alike
         with jax.named_scope("ds.attn_proj"):
-            q = dense(H * D, "q_proj", qb)(x).reshape(B, T, H, D)
-            k = dense(Hkv * D, "k_proj", qb)(x).reshape(B, T, Hkv, D)
+            norm = (lambda t, name: RMSNorm(eps=cfg.rms_norm_eps,
+                                            name=name)(t)) \
+                if cfg.qk_norm else (lambda t, name: t)
+            q = norm(dense(H * D, "q_proj", qb)(x),
+                     "q_norm").reshape(B, T, H, D)
+            k = norm(dense(Hkv * D, "k_proj", qb)(x),
+                     "k_norm").reshape(B, T, Hkv, D)
             v = dense(Hkv * D, "v_proj", qb)(x).reshape(B, T, Hkv, D)
             q = apply_rotary(q, cos, sin)
             k = apply_rotary(k, cos, sin)
@@ -514,6 +523,8 @@ class LlamaForCausalLM(nn.Module):
             (r"(q_proj|k_proj|v_proj|gate_proj|up_proj)/kernel", P(*L, None, "model")),
             (r"(o_proj|down_proj)/kernel", P(*L, "model", None)),
             (r"lm_head/kernel", P(None, "model")),
+            # qk_norm's scales lie along the q/k kernels' sharded columns
+            (r"(q_norm|k_norm)/scale", P(*L, "model")),
         ]
         if getattr(config, "quantize_weights", None):
             # quantized-weight scales ride as sibling [G, N] leaves:

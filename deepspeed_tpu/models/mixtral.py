@@ -59,6 +59,21 @@ class MixtralConfig(LlamaConfig):
     num_local_experts: int = 8
     num_experts_per_tok: int = 2
     router_aux_loss_coef: float = 0.02
+    #: renormalise each token's top-k router probabilities to sum to 1
+    #: (Mixtral); False combines with the raw softmax values (OLMoE)
+    norm_topk_prob: bool = True
+    #: lecun-normal initial scale of the stacked expert kernels over each
+    #: expert's own fan-in (what an ``nn.Dense`` of one expert's shape
+    #: gets). False counts the expert axis into the fan, as Mixtral's trees
+    #: have always been seeded: every kernel sqrt(E) smaller and the layer's
+    #: output E**1.5 smaller, 512x at 64 experts — too small for any logit
+    #: to tell a right expert layer from a wrong one
+    per_expert_init: bool = False
+    #: the training call returns ``(loss, {"moe_rows_max_over_mean",
+    #: "moe_rows_min_over_mean"})``: the busiest and the idlest expert's
+    #: (token, expert) pairs over the mean, pairs summed over layers, which
+    #: the train engine publishes as registry gauges
+    report_expert_load: bool = False
 
     @staticmethod
     def mixtral_8x7b(**over):
@@ -67,6 +82,21 @@ class MixtralConfig(LlamaConfig):
             num_hidden_layers=32, num_attention_heads=32,
             num_key_value_heads=8, max_position_embeddings=32768,
             rope_theta=1e6, num_local_experts=8, num_experts_per_tok=2),
+            **over})
+
+    @staticmethod
+    def olmoe_1b_7b(**over):
+        """OLMoE-1B-7B (``allenai/OLMoE-1B-7B-0125-Instruct`` ``config.json``
+        and ``modeling_olmoe.py``): 64 experts of 1024, top-8 combined with
+        the un-normalised softmax values, MHA with RMSNorm on the whole
+        projected query and key."""
+        return MixtralConfig(**{**dict(
+            vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+            num_hidden_layers=16, num_attention_heads=16,
+            num_key_value_heads=16, max_position_embeddings=4096,
+            rope_theta=1e4, num_local_experts=64, num_experts_per_tok=8,
+            router_aux_loss_coef=0.01, norm_topk_prob=False, qk_norm=True,
+            per_expert_init=True),
             **over})
 
     @staticmethod
@@ -116,18 +146,18 @@ class MixtralSparseMoeBlock(nn.Module):
             probs = jax.nn.softmax(router_logits.astype(jnp.float32),
                                    axis=-1)
             topk_w, topk_idx = jax.lax.top_k(probs, K)
-            topk_w = topk_w / jnp.sum(topk_w, axis=-1, keepdims=True)
+            if cfg.norm_topk_prob:
+                topk_w = topk_w / jnp.sum(topk_w, axis=-1, keepdims=True)
             # one-hot routing (also feeds the aux-loss stats below)
             onehot = jax.nn.one_hot(topk_idx, E,
                                     dtype=topk_w.dtype)  # [B,T,K,E]
 
         # stacked expert SwiGLU: [E, H, I] / [E, I, H], sharded over "expert"
-        w1 = self.param("w1", nn.initializers.lecun_normal(), (E, H, I),
-                        jnp.float32)  # gate
-        w3 = self.param("w3", nn.initializers.lecun_normal(), (E, H, I),
-                        jnp.float32)  # up
-        w2 = self.param("w2", nn.initializers.lecun_normal(), (E, I, H),
-                        jnp.float32)  # down
+        init = nn.initializers.lecun_normal(
+            batch_axis=(0,) if cfg.per_expert_init else ())
+        w1 = self.param("w1", init, (E, H, I), jnp.float32)  # gate
+        w3 = self.param("w3", init, (E, H, I), jnp.float32)  # up
+        w2 = self.param("w2", init, (E, I, H), jnp.float32)  # down
         out, rows = _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx)
         if rows is not None:
             # (token, expert) pairs each expert computed this call, [E]:
@@ -438,7 +468,8 @@ class MixtralModel(nn.Module):
         # the expert-wise product
         L = cfg.num_hidden_layers
         aux = E * jnp.sum((frac_sum / L) * (prob_sum / L))
-        return (x, aux) if cache is None else (x, aux, cache)
+        # frac_sum: each expert's share of the tokens, summed over layers
+        return (x, aux, frac_sum) if cache is None else (x, aux, cache)
 
 
 class MixtralForCausalLM(nn.Module):
@@ -453,13 +484,12 @@ class MixtralForCausalLM(nn.Module):
                  attention_mask=None, deterministic=True, cache=None,
                  cache_index=None):
         cfg = self.config
-        out = MixtralModel(cfg, name="model")(
+        # the third is the updated cache, or without one the experts' load
+        hidden, aux, load = MixtralModel(cfg, name="model")(
             input_ids, positions, attention_mask, deterministic, cache,
             cache_index)
         if cache is not None:
-            hidden, aux, cache = out
-        else:
-            hidden, aux = out
+            cache = load
         with jax.named_scope(head_scope(cache)):
             logits, lm = lm_head_output(self, cfg, hidden, labels, cache)
             if cache is not None:
@@ -468,7 +498,12 @@ class MixtralForCausalLM(nn.Module):
                 return logits
             if lm is None:
                 lm = cross_entropy_loss(logits, shift_labels(labels))
-        return lm + cfg.router_aux_loss_coef * aux
+        loss = lm + cfg.router_aux_loss_coef * aux
+        if not cfg.report_expert_load:
+            return loss
+        load = load / jnp.mean(load)
+        return loss, {"moe_rows_max_over_mean": jnp.max(load),
+                      "moe_rows_min_over_mean": jnp.min(load)}
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16):
         cfg = self.config
@@ -487,4 +522,5 @@ class MixtralForCausalLM(nn.Module):
             (r"o_proj/kernel", P(*L, "model", None)),
             (r"block_sparse_moe/(w1|w2|w3)", P(*L, "expert", None, None)),
             (r"lm_head/kernel", P(None, "model")),
+            (r"(q_norm|k_norm)/scale", P(*L, "model")),
         ]

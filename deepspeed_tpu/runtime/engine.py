@@ -59,6 +59,18 @@ def load_config_dict(config):
     return config
 
 
+def _named_scalars(aux) -> Dict[str, jax.Array]:
+    """The scalars a training call names beside its loss, ``(loss, {name:
+    scalar})``: they leave the fused step with the loss and become registry
+    gauges of those names wherever the host fetches it (``models/mixtral.py``
+    reports its experts' load so). Any other aux output is dropped."""
+    aux = aux[0] if isinstance(aux, tuple) and len(aux) == 1 else aux
+    if not isinstance(aux, dict):
+        return {}
+    return {k: v for k, v in aux.items()
+            if k != "loss" and isinstance(v, jax.Array) and v.ndim == 0}
+
+
 def _flat_name(kp) -> str:
     return "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in kp)
 
@@ -412,7 +424,7 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                 step_fn,
                 in_shardings=(self.state_shardings, None, self._replicated),
                 out_shardings=(self.state_shardings,
-                               (self._replicated, self._replicated),
+                               (self._replicated,) * 3,
                                self._replicated),
                 donate_argnums=(0,))
         elif self._config.sparse_gradients_enabled:
@@ -428,7 +440,7 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                 step_fn,
                 in_shardings=(self.state_shardings, None, self._replicated),
                 out_shardings=(self.state_shardings,
-                               (self._replicated, self._replicated),
+                               (self._replicated,) * 3,
                                self._replicated),
                 donate_argnums=(0,))
         else:
@@ -497,6 +509,9 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         # micro-step parity API state
         self._pending_microbatches = []
         self._last_loss = None
+        #: the last fused step's named scalars (``_named_scalars``), on the
+        #: device until ``_publish_named_scalars`` fetches them
+        self._step_scalars = {}
 
         # ---- elastic-agent contract (elasticity/elastic_agent.py) ------
         # under the agent, auto-save periodically into its checkpoint dir
@@ -653,13 +668,11 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                                                    pld_theta=pld_theta)
                 else:
                     loss, aux = self._default_loss(params, batch, rng)
-            return (loss.astype(jnp.float32) * scale, loss)
+            return (loss.astype(jnp.float32) * scale,
+                    (loss, _named_scalars(aux)))
 
-        grad_fn = jax.grad(compute_loss, has_aux=True)
-
-        def microbatch_grads(params, batch, rng, scale, pld_theta, moq_step):
-            grads, loss = grad_fn(params, batch, rng, scale, pld_theta, moq_step)
-            return grads, loss
+        # grads, (loss, named scalars)
+        microbatch_grads = jax.grad(compute_loss, has_aux=True)
 
         # named like the kernels (ds_*): XLA calls the module after the
         # function, and the module's name — unlike the scopes inside it,
@@ -684,21 +697,23 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
 
                     def body(acc, xs):
                         mb, r = xs
-                        g, loss = microbatch_grads(state.params, mb, r, scale,
-                                                   pld_theta, moq_step)
+                        g, (loss, named) = microbatch_grads(
+                            state.params, mb, r, scale, pld_theta, moq_step)
                         acc_g, acc_l = acc
-                        return (jax.tree_util.tree_map(jnp.add, acc_g, g), acc_l + loss), None
+                        return (jax.tree_util.tree_map(jnp.add, acc_g, g), acc_l + loss), named
 
                     zero_g = jax.tree_util.tree_map(
                         lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
-                    (sum_g, sum_loss), _ = jax.lax.scan(
+                    (sum_g, sum_loss), named = jax.lax.scan(
                         body, (zero_g, jnp.float32(0.0)), (batch, rngs))
                     grads = jax.tree_util.tree_map(lambda g: g / gas, sum_g)
                     loss = sum_loss / gas
+                    named = jax.tree_util.tree_map(lambda v: v.mean(0), named)
                 else:
                     squeezed = jax.tree_util.tree_map(lambda x: x[0], batch)
-                    grads, loss = microbatch_grads(state.params, squeezed, rng, scale,
-                                                   pld_theta, moq_step)
+                    grads, (loss, named) = microbatch_grads(
+                        state.params, squeezed, rng, scale, pld_theta,
+                        moq_step)
 
             with jax.named_scope("ds.optimizer"):
                 # unscale
@@ -732,7 +747,7 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                 loss_scale=new_scale,
                 skipped_steps=state.skipped_steps + jnp.where(overflow, 1, 0),
             )
-            return new_state, (loss, grad_norm), overflow
+            return new_state, (loss, grad_norm, named), overflow
 
         # raw Python step kept for the flops profiler's jaxpr walk
         self._train_step_fn = ds_train_step
@@ -742,7 +757,7 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             # _shape_batch (per-leaf: token dims ride the seq axis)
             in_shardings=(self.state_shardings, None, self._replicated),
             out_shardings=(self.state_shardings,
-                           (self._replicated, self._replicated),
+                           (self._replicated,) * 3,
                            self._replicated),
             donate_argnums=(0,),
         )
@@ -972,9 +987,14 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             with compiling, tr.span(
                     "dispatch", cat="train", ring="train_step",
                     args={"step": step, "program": "train_step"}):
-                self.state, (loss, self._last_grad_norm), overflow = \
+                self.state, (loss, self._last_grad_norm,
+                             self._step_scalars), overflow = \
                     self._train_step(self.state, batch, step_rng)
             if not warm:
+                # the host has just waited seconds for the compile: one
+                # step's wait is the cheapest fetch there is
+                self._publish_named_scalars()
+
                 # once, after the compile-carrying first call: the cached
                 # lowering yields the cost model without a second trace;
                 # the jaxpr-walk flops profiler is the fallback
@@ -1033,6 +1053,7 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             # both fetch the loss, so this span is the host's wait on
             # the device when either is on
             with tr.span("report", cat="host", args={"step": step}):
+                self._publish_named_scalars()
                 if monitoring:
                     self._write_monitor(loss)
                 if printing:
@@ -1191,6 +1212,12 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
     # ------------------------------------------------------------------
     # monitoring
     # ------------------------------------------------------------------
+
+    def _publish_named_scalars(self):
+        """Registry gauges of the last step's named scalars. It FETCHES, so
+        it is called only where the host waits for the device anyway."""
+        for name, value in self._step_scalars.items():
+            self.registry.gauge(name).set(float(value))
 
     def _write_monitor(self, loss):
         events = [

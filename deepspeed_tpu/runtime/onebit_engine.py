@@ -280,6 +280,6 @@ def build_onebit_wire(engine, opt_params: dict, kind: str = "onebitadam"):
             opt_state=OneBitWireState(mu2, nu2, werr2, serr2, vint2, vcnt2),
             loss_scale=new_ls,
             skipped_steps=state.skipped_steps + ov.astype(jnp.int32))
-        return new_state, (loss, grad_norm), ov
+        return new_state, (loss, grad_norm, {}), ov
 
     return opt_state, opt_shardings, train_step

@@ -96,7 +96,7 @@ def probe_dense_sparse_leaves(engine, sparse_names: set) -> set:
 def build_sparse_dp_step(engine):
     """Returns (sparse_leaf_names, train_step_fn) with the engine's compiled
     step contract: ``train_step(state, batch, rng) -> (state, (loss,
-    grad_norm), overflow)``."""
+    grad_norm, named scalars), overflow)``."""
     mesh = engine.mesh
     shape = dict(zip(mesh.axis_names, mesh.devices.shape))
     if shape.get("model", 1) != 1 or shape.get("seq", 1) != 1 or \
@@ -187,6 +187,6 @@ def build_sparse_dp_step(engine):
             step=state.step + jnp.where(overflow, 0, 1),
             params=new_params, opt_state=new_opt,
             skipped_steps=state.skipped_steps + jnp.where(overflow, 1, 0))
-        return new_state, (loss, grad_norm), overflow
+        return new_state, (loss, grad_norm, {}), overflow
 
     return sparse_names, train_step

@@ -530,6 +530,6 @@ def build_overlap_step(engine):
             step=jnp.where(ov, state.step, count), params=new_params,
             opt_state=new_opt, loss_scale=new_ls,
             skipped_steps=state.skipped_steps + ov.astype(jnp.int32))
-        return new_state, (loss, grad_norm), ov
+        return new_state, (loss, grad_norm, {}), ov
 
     return opt_state, opt_shardings, ds_train_step

@@ -63,8 +63,8 @@ def _scale_kw(scales):
     return dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
 
 
-def _flash(bwd, window):
-    q = ((1, 2048, H, D), BF16)
+def _flash(bwd, window, shape=(1, 2048, H, D)):
+    q = (shape, BF16)
     fwd = functools.partial(flash_attention, causal=True, interpret=False,
                             window=window)
     if not bwd:
@@ -125,6 +125,8 @@ CASES = {
     "flash_fwd_window": lambda: _flash(False, 1024),
     "flash_fwd_bwd": lambda: _flash(True, None),
     "flash_fwd_bwd_window": lambda: _flash(True, 1024),
+    # olmoe-1b-7b.train.4k: two 4096-token sequences, 16 heads (MHA)
+    "flash_fwd_bwd_olmoe": lambda: _flash(True, None, (2, 4096, 16, D)),
     "ragged_bf16": lambda: _ragged(False, None),
     "ragged_bf16_window": lambda: _ragged(False, 4096),
     "ragged_int8": lambda: _ragged(True, None),
@@ -145,6 +147,56 @@ def test_kernel_compiles_for_v5e(chip, name):
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         "the compiled program holds no Mosaic kernel"
+
+
+# -- the expert layer on one chip, at OLMoE's shapes -------------------------
+
+def test_olmoe_expert_layer_reaches_the_grouped_kernel_on_one_v5e(chip):
+    """``models/mixtral.py``'s layer with NO expert axis at OLMoE-1B-7B's
+    widths (2 x 4096 tokens, 64 experts of 1024, top-8: a 65,536-row buffer
+    in 64 groups of about 1,024 rows), forward and backward, compiles for
+    one v5e, and all nine products (three forward, three dx, three dw) are
+    XLA:TPU's grouped-matmul kernel, ``ragged-dot-*`` custom calls: a masked
+    dense fallback (``convolution``) would cost 64/8 times the rows. What
+    ``kernel.moe_gmm.roofline_share`` charges a call is these shapes."""
+    import deepspeed_tpu.models.mixtral as mx
+    from deepspeed_tpu.models import MixtralConfig
+
+    B, T, HID, INTER, E, K = 2, 4096, 2048, 1024, 64, 8
+    cfg = MixtralConfig.olmoe_1b_7b(num_hidden_layers=1)
+    assert (cfg.hidden_size, cfg.intermediate_size, cfg.num_local_experts,
+            cfg.num_experts_per_tok) == (HID, INTER, E, K)
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    args = (struct((B, T, HID), BF16),
+            struct((E, HID, INTER), jnp.float32),
+            struct((E, INTER, HID), jnp.float32),
+            struct((E, HID, INTER), jnp.float32),
+            struct((B, T, K), jnp.float32), struct((B, T, K), jnp.int32))
+
+    def loss(x, w1, w2, w3, topk_w, topk_idx):
+        out, rows = mx._expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx)
+        return jnp.sum(out.astype(jnp.float32) ** 2), rows
+
+    hlo = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(
+            *args).compile().as_text()
+    products = re.findall(r"%(ragged-dot-none\S*) = (\w+)\[([\d,]*)\]", hlo)
+    assert len(products) == 9, products
+    # what kernel.moe_gmm.roofline_share's byte count takes each result for
+    assert {dtype for _, dtype, _ in products} == {"bf16"}
+    shapes = sorted(dims for _, _, dims in products)
+    rows = B * T * K
+    assert shapes.count(f"{rows},{INTER}") == 3      # gate, up, g @ w2^T
+    assert shapes.count(f"{rows},{HID}") == 3        # down, two dx
+    assert shapes.count(f"{E},{HID},{INTER}") + \
+        shapes.count(f"{E},{INTER},{HID}") == 3      # dw1, dw3, dw2
+    assert all("tpu_custom_call" in line for line in hlo.splitlines()
+               if re.search(r"%ragged-dot-none\S* = ", line))
+    assert "convolution" not in "".join(
+        line for line in hlo.splitlines() if "moe_gmm" in line)
 
 
 # -- Mixtral's expert layer across the four chips ---------------------------

@@ -455,16 +455,21 @@ def _oracle3d(x, w1, w2, w3, topk_w, topk_idx):
 _MOE_CFG = dict(num_local_experts=8, num_experts_per_tok=2)
 
 
+@pytest.mark.parametrize("top_k", [2, 4], ids=["top2", "top4_unnormalised"])
 @pytest.mark.parametrize("kind", ["uniform", "skewed", "starved"])
-def test_grouped_experts_match_all_e_oracle(kind):
+def test_grouped_experts_match_all_e_oracle(kind, top_k):
     """Outputs and the gradients w.r.t. x, w1, w2, w3 and the routing
     weights of the token-sorted grouped layer equal the all-E formula's;
-    group sizes count exactly the routed pairs."""
+    group sizes count exactly the routed pairs. Top-4 of 8 with weights
+    that sum to about 1/8 is OLMoE's shape of the one-device layer, whose
+    gradients the chip's ``correct`` does not see."""
     import deepspeed_tpu.models.mixtral as mx
     from deepspeed_tpu.models import MixtralConfig
 
-    cfg = MixtralConfig.tiny(**_MOE_CFG)
-    args = _moe_case(kind)
+    cfg = MixtralConfig.tiny(**{**_MOE_CFG, "num_experts_per_tok": top_k})
+    args = _moe_case(kind, K=top_k)
+    if top_k == 4:
+        args = args[:4] + (args[4] / 8,) + args[5:]
     out, rows = mx._expert_mlp(cfg, *args)
     np.testing.assert_allclose(out, _oracle3d(*args), rtol=2e-5, atol=2e-5)
     counts = np.bincount(np.asarray(args[5]).ravel(), minlength=8)

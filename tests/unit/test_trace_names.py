@@ -68,6 +68,44 @@ def test_jitted_steps_are_named_like_the_kernels(train_text, mixed_text):
     assert "module @jit_ds_mixed_step" in mixed_text
 
 
+@pytest.mark.parametrize("family,over,gauges", [
+    ("mixtral", {"report_expert_load": True},
+     ["moe_rows_max_over_mean", "moe_rows_min_over_mean"]),
+    ("mixtral", {}, []),
+    ("llama", {}, [])], ids=["mixtral_reporting", "mixtral", "llama"])
+def test_moe_load_gauges_are_published_by_name(family, over, gauges):
+    """The train engine's registry names how evenly the router spread the
+    step's (token, expert) pairs (docs/observability.md), where the model is
+    configured to report it: the training call names the two scalars beside
+    its loss, the fused step hands them back, and the engine sets gauges of
+    those names after the compile-carrying first step, where the host has
+    waited anyway. Nothing else publishes anything."""
+    cfg = {"llama": LlamaConfig.tiny, "mixtral": MixtralConfig.tiny}[family](
+        **({"num_local_experts": 8, "remat": True} if family == "mixtral"
+           else {}), **over)
+    model = {"llama": LlamaForCausalLM, "mixtral": MixtralForCausalLM}[
+        family](cfg)
+    ids = np.random.RandomState(0).randint(0, 128, (8, 32)).astype(np.int32)
+    batch = {"input_ids": ids, "labels": ids}
+    engine, *_ = ds.initialize(
+        model=model, example_batch={k: v[:1] for k, v in batch.items()},
+        config={"train_batch_size": 8, "steps_per_print": 0,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})
+    params = jax.tree_util.tree_map(np.asarray, engine.state.params)
+    engine.train_batch(batch=batch)
+    found = engine.registry.snapshot()
+    assert sorted(k for k in found if "moe_rows" in k) == gauges
+    if gauges:
+        _, sown = model.apply({"params": params}, **batch,
+                              mutable=["intermediates"])
+        rows = np.sum([np.asarray(v).reshape(-1, 8).sum(0) for v in
+                       jax.tree_util.tree_leaves(sown)], 0)  # [E]
+        assert rows.sum() == 2 * ids.size * 2    # layers x tokens x top-2
+        assert found[gauges[0]] == pytest.approx(rows.max() / rows.mean())
+        assert found[gauges[1]] == pytest.approx(rows.min() / rows.mean())
+        assert found[gauges[1]] <= 1.0 <= found[gauges[0]]
+
+
 def test_backward_and_recompute_leave_their_marks(train_text):
     """What ``scope_reduce.phase_of`` tells the phases apart by."""
     text = train_text["mixtral"]
@@ -231,3 +269,29 @@ def test_kernel_lowers_under_its_name(constant):
     text = jax.jit(fn).trace(*shapes).lower(
         lowering_platforms=("tpu",)).as_text()
     assert f'kernel_name = "{spelled}"' in text
+
+
+@pytest.mark.parametrize("gas", [1, 2])
+def test_named_scalars_of_any_model_become_gauges(gas):
+    """The contract Mixtral's load report rides: a training call that
+    returns ``(loss, {name: scalar})`` gets registry gauges of those names,
+    the mean over micro-batches; what is no scalar is dropped."""
+    import flax.linen as nn
+
+    class Named(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            loss = jnp.mean(nn.Dense(1)(x) ** 2)
+            return loss, {"probe_first_feature": jnp.mean(x[:, 0]),
+                          "not_a_scalar": x[0]}
+
+    x = np.arange(32 * gas, dtype=np.float32).reshape(8 * gas, 4)
+    engine, *_ = ds.initialize(
+        model=Named(), example_batch={"x": x[:1]},
+        config={"train_batch_size": 8 * gas, "steps_per_print": 0,
+                "gradient_accumulation_steps": gas,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})
+    engine.train_batch(batch={"x": x})
+    found = engine.registry.snapshot()
+    assert found["probe_first_feature"] == pytest.approx(x[:, 0].mean())
+    assert "not_a_scalar" not in found
