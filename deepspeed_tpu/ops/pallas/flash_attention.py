@@ -6,11 +6,18 @@ TPU-native replacement for the reference's fused attention CUDA kernels
 attention that never materializes the [T, T] score matrix in HBM.
 
 Layout: inputs are [B, T, H, D] (model convention); kernels operate on
-[B, H, T, D]. The kv-block grid dimension is innermost, so the per-q-block
-running max / sum / accumulator live in VMEM scratch across sequential grid
-steps (standard TPU flash pattern). Backward uses the saved logsumexp and
-recomputes P per tile: one kernel for dQ (loop over kv), one for dK/dV
-(loop over q).
+[B, H, T, D]. The grid is ``(B, H, tiles)``: its last dimension walks a
+STATIC TILE TABLE (``_tile_table``) that holds only the (q-tile, kv-tile)
+pairs the causal rule and the window keep, built in numpy at trace time
+from the shapes and handed to the kernel by scalar prefetch; the index maps
+read it, so a tile the mask rules out is neither a grid step nor a fetch.
+The table is ordered by q row with keys ascending (forward, dQ) or by kv
+row with queries ascending (dK/dV), so the per-row running max / sum /
+accumulator live in VMEM scratch across consecutive grid steps (standard
+TPU flash pattern) and are initialised / written on the row's first / last
+entry. A tile wholly inside the mask skips the iota / compare / where.
+Backward uses the saved logsumexp and recomputes P per tile: one kernel for
+dQ (loop over kv), one for dK/dV (loop over q).
 
 On non-TPU backends the public entry falls back to reference einsum math so
 the same model code runs everywhere (tests use the fallback + interpret
@@ -36,55 +43,130 @@ def _ceil_div(a, b):
 
 
 # ---------------------------------------------------------------------------
+# the tile table
+# ---------------------------------------------------------------------------
+
+# flag word of a table entry. A tile runs the body without the in-tile mask
+# (_INSIDE: every entry visible), with it (_CUT: the diagonal, the window's
+# edge or a ragged tail crosses it), or not at all (neither: a placeholder)
+_FIRST, _LAST, _INSIDE, _CUT = 1, 2, 4, 8
+
+
+def _tile_table(tq, tk, block_q, block_k, causal, window, by_kv=False):
+    """The tiles the mask keeps, as int32 rows ``[q tile, kv tile, flags]``.
+
+    Causality is bottom-right aligned (offset = tk - tq), matching the decode
+    convention and the einsum fallback's tril(k=Tk-Tq). Inside one tile
+    ``d = row + (tk - tq) - col`` takes every integer of ``[dmin, dmax]``
+    over its REAL rows and columns; the mask keeps ``d >= 0`` (causal) and
+    ``d < window``, so a tile holds a visible entry iff the two ranges meet
+    and is wholly visible iff one lies in the other (and it has no padded
+    tail). Entries are ordered by q row, keys ascending (``by_kv``: by kv
+    row, queries ascending); ``_FIRST`` / ``_LAST`` mark a row's ends.
+
+    Every row of output tiles owns at least one entry, or its block would be
+    neither initialised nor written: a q row that sees no key (causal with
+    tk < tq) and a kv row no query sees (a window with tk > tq) keep one
+    placeholder that runs no body — the in-tile mask could not empty it
+    (``exp(NEG_INF - NEG_INF)`` is 1) — so its outputs are zeros.
+    """
+    off = tk - tq
+    r0 = (np.arange(_ceil_div(tq, block_q)) * block_q)[:, None]
+    c0 = (np.arange(_ceil_div(tk, block_k)) * block_k)[None, :]
+    r1 = np.minimum(r0 + block_q, tq) - 1
+    c1 = np.minimum(c0 + block_k, tk) - 1
+    dmin, dmax = r0 + off - c1, r1 + off - c0
+    keep = np.ones(dmin.shape, bool)
+    inside = (r0 + block_q <= tq) & (c0 + block_k <= tk)
+    if causal:
+        keep &= dmax >= 0
+        inside &= dmin >= 0
+    if window is not None:
+        keep &= dmin < window
+        inside &= dmax < window
+    flags = np.where(inside, _INSIDE, _CUT) * keep
+    if by_kv:
+        keep, flags = keep.T, flags.T
+    keep[~keep.any(axis=1), 0] = True           # placeholders: flags 0
+    outer, inner = np.nonzero(keep)             # row-major: the order above
+    first = np.r_[True, outer[1:] != outer[:-1]]
+    last = np.r_[first[1:], True]
+    word = flags[outer, inner] | first * _FIRST | last * _LAST
+    iq, ik = (inner, outer) if by_kv else (outer, inner)
+    return np.stack([iq, ik, word]).astype(np.int32)
+
+
+# index maps over the grid (b, h, t) and the prefetched table
+def _q_tile(b, h, t, iq_of, ik_of, flags_of):
+    return (b, h, iq_of[t], 0)
+
+
+def _q_row(b, h, t, iq_of, ik_of, flags_of):
+    return (b, h, 0, iq_of[t])
+
+
+def _kv_tile(rep=1):
+    """GQA: q head h reads kv head h // rep."""
+    def index_map(b, h, t, iq_of, ik_of, flags_of):
+        return (b, h // rep, ik_of[t], 0)
+    return index_map
+
+
+def _on_tile(flags, body):
+    """Run ``body(cut)`` as the table entry's flag word says."""
+    pl.when(flags & _INSIDE != 0)(functools.partial(body, False))
+    pl.when(flags & _CUT != 0)(functools.partial(body, True))
+
+
+def _tile_valid(iq, ik, block_q, block_k, tq, tk, causal, window):
+    """In-tile mask of a cut tile, and its global row numbers."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + iq * block_q
+    cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + ik * block_k
+    # ragged tails: padded kv columns/q rows contribute nothing
+    valid = (cols < tk) & (rows < tq)
+    if causal:
+        valid = valid & (rows + (tk - tq) >= cols)
+    if window is not None:
+        valid = valid & (rows + (tk - tq) - cols < window)
+    return valid, rows
+
+
+# ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
+def _fwd_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, *rest,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
                 tq: int, tk: int, window, has_mask: bool = False):
     if has_mask:
         kmask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+    t = pl.program_id(2)
+    iq, ik, flags = iq_of[t], ik_of[t], flags_of[t]
 
-    @pl.when(ik == 0)
+    @pl.when(flags & _FIRST != 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal: skip fully-masked kv blocks (top-right triangle). Causality is
-    # bottom-right aligned (offset = tk - tq), matching the decode convention
-    # and the einsum fallback's tril(k=Tk-Tq).
-    run = True
-    if causal:
-        run = ik * block_k <= iq * block_q + block_q - 1 + (tk - tq)
-    if window is not None:
-        # kv block wholly below the sliding window of every q row: skip
-        run = run & (ik * block_k + block_k - 1 + window >
-                     iq * block_q + (tk - tq))
-
-    @pl.when(run)
-    def _body():
+    def _body(cut):
         q = q_ref[0, 0].astype(jnp.float32)  # [bq, D]
         k = k_ref[0, 0].astype(jnp.float32)  # [bk, D]
         v = v_ref[0, 0].astype(jnp.float32)  # [bk, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-        rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + iq * block_q
-        cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + ik * block_k
-        # ragged tails: padded kv columns/q rows contribute nothing
-        valid = (cols < tk) & (rows < tq)
-        if causal:
-            valid = valid & (rows + (tk - tq) >= cols)
-        if window is not None:
-            valid = valid & (rows + (tk - tq) - cols < window)
+        valid = None
+        if cut:
+            valid, _ = _tile_valid(iq, ik, block_q, block_k, tq, tk, causal,
+                                   window)
         if has_mask:  # [B, Tk] key-padding mask (left-padded prompts)
-            valid = valid & (kmask_ref[0] > 0)
-        s = jnp.where(valid, s, NEG_INF)
+            real = kmask_ref[0] > 0
+            valid = real if valid is None else valid & real
+        if valid is not None:
+            s = jnp.where(valid, s, NEG_INF)
 
         m_prev = m_scr[:]                       # [bq, 1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -96,7 +178,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
             p, v, preferred_element_type=jnp.float32)
         m_scr[:] = m_new
 
-    @pl.when(ik == nk - 1)
+    _on_tile(flags, _body)
+
+    @pl.when(flags & _LAST != 0)
     def _finalize():
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -129,7 +213,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     # pad to block multiples; kernels mask with the ORIGINAL lengths
     q, k, v = _pad_seq(q, bq), _pad_seq(k, bk), _pad_seq(v, bk)
     Tq_p, Tk_p = q.shape[2], k.shape[2]
-    grid = (B, H, Tq_p // bq, Tk_p // bk)
+    table = _tile_table(Tq, Tk, bq, bk, causal, window)
 
     mask_args = []
     mask_specs = []
@@ -137,37 +221,39 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
         km = jnp.pad(key_mask.astype(jnp.int32),
                      ((0, 0), (0, Tk_p - key_mask.shape[1])))
         mask_args = [km[:, None]]
-        mask_specs = [pl.BlockSpec((1, 1, bk),
-                                   lambda b, h, iq, ik: (b, 0, ik))]
+        mask_specs = [pl.BlockSpec(
+            (1, 1, bk), lambda b, h, t, iq_of, ik_of, flags_of:
+            (b, 0, ik_of[t]))]
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=bq, block_k=bk, tq=Tq, tk=Tk,
                           window=window, has_mask=key_mask is not None),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bk, D),
-                         lambda b, h, iq, ik: (b, h // rep, ik, 0)),
-            pl.BlockSpec((1, 1, bk, D),
-                         lambda b, h, iq, ik: (b, h // rep, ik, 0)),
-        ] + mask_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, iq, ik: (b, h, 0, iq)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, H, table.shape[1]),
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, D), _q_tile),
+                pl.BlockSpec((1, 1, bk, D), _kv_tile(rep)),
+                pl.BlockSpec((1, 1, bk, D), _kv_tile(rep)),
+            ] + mask_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, bq, D), _q_tile),
+                pl.BlockSpec((1, 1, 1, bq), _q_row),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, D), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Tq_p, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, 1, Tq_p), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
         interpret=interpret,
         name=FLASH_FWD,
-    )(q, k, v, *mask_args)
+    )(*table, q, k, v, *mask_args)
     return out[:, :, :Tq], lse[:, :, 0, :Tq]  # lse: compact [B,H,Tq] fp32
 
 
@@ -176,25 +262,18 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *,
+def _bwd_dq_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, do_ref,
+                   lse_ref, delta_ref, dq_ref, dq_scr, *,
                    sm_scale: float, causal: bool, block_q: int, block_k: int,
                    tq: int, tk: int, window):
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+    t = pl.program_id(2)
+    iq, ik, flags = iq_of[t], ik_of[t], flags_of[t]
 
-    @pl.when(ik == 0)
+    @pl.when(flags & _FIRST != 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    run = True
-    if causal:
-        run = ik * block_k <= iq * block_q + block_q - 1 + (tk - tq)
-    if window is not None:
-        run = run & (ik * block_k + block_k - 1 + window >
-                     iq * block_q + (tk - tq))
-
-    @pl.when(run)
-    def _body():
+    def _body(cut):
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
@@ -203,47 +282,36 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_s
         delta = delta_ref[0, 0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-        rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + iq * block_q
-        cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + ik * block_k
-        valid = (cols < tk) & (rows < tq)
-        if causal:
-            valid = valid & (rows + (tk - tq) >= cols)
-        if window is not None:
-            valid = valid & (rows + (tk - tq) - cols < window)
-        s = jnp.where(valid, s, NEG_INF)
+        if cut:
+            valid, _ = _tile_valid(iq, ik, block_q, block_k, tq, tk, causal,
+                                   window)
+            s = jnp.where(valid, s, NEG_INF)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
         dq_scr[:] += sm_scale * jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
 
-    @pl.when(ik == nk - 1)
+    _on_tile(flags, _body)
+
+    @pl.when(flags & _LAST != 0)
     def _finalize():
         dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                    dk_scr, dv_scr, *, sm_scale: float, causal: bool, block_q: int,
+def _bwd_dkv_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, do_ref,
+                    lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
+                    sm_scale: float, causal: bool, block_q: int,
                     block_k: int, tq: int, tk: int, window):
-    ik, iq = pl.program_id(2), pl.program_id(3)
-    nq = pl.num_programs(3)
+    t = pl.program_id(2)
+    iq, ik, flags = iq_of[t], ik_of[t], flags_of[t]
 
-    @pl.when(iq == 0)
+    @pl.when(flags & _FIRST != 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    run = True
-    if causal:
-        # q block fully above the diagonal contributes nothing to this kv block
-        run = iq * block_q + block_q - 1 + (tk - tq) >= ik * block_k
-    if window is not None:
-        # q block whose window lies wholly past this kv block: skip
-        run = run & (ik * block_k + block_k - 1 + window >
-                     iq * block_q + (tk - tq))
-
-    @pl.when(run)
-    def _body():
+    def _body(cut):
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
@@ -252,17 +320,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         delta = delta_ref[0, 0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-        rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + iq * block_q
-        cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + ik * block_k
-        # ragged tails: padded q rows AND padded kv cols must contribute zero
-        valid = (cols < tk) & (rows < tq)
-        if causal:
-            valid = valid & (rows + (tk - tq) >= cols)
-        if window is not None:
-            valid = valid & (rows + (tk - tq) - cols < window)
-        s = jnp.where(valid, s, NEG_INF)
+        if cut:
+            valid, rows = _tile_valid(iq, ik, block_q, block_k, tq, tk,
+                                      causal, window)
+            s = jnp.where(valid, s, NEG_INF)
         p = jnp.exp(s - lse)                    # [bq, bk]
-        p = jnp.where(rows < tq, p, 0.0)
+        if cut:  # padded q rows (lse 0) must contribute zero
+            p = jnp.where(rows < tq, p, 0.0)
         dv_scr[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
@@ -271,7 +335,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         dk_scr[:] += sm_scale * jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    @pl.when(iq == nq - 1)
+    _on_tile(flags, _body)
+
+    @pl.when(flags & _LAST != 0)
     def _finalize():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
@@ -298,54 +364,48 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
     Tq_p, Tk_p = q.shape[2], k.shape[2]
     lse, delta = lse[:, :, None], delta[:, :, None]
 
+    q_spec = pl.BlockSpec((1, 1, bq, D), _q_tile)
+    kv_spec = pl.BlockSpec((1, 1, bk, D), _kv_tile())
+    row_spec = pl.BlockSpec((1, 1, 1, bq), _q_row)
+    in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+    kernel_kw = dict(sm_scale=sm_scale, causal=causal, block_q=bq, block_k=bk,
+                     tq=Tq, tk=Tk, window=window)
+
+    by_q = _tile_table(Tq, Tk, bq, bk, causal, window)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=bq, block_k=bk, tq=Tq, tk=Tk,
-                          window=window),
-        grid=(B, H, Tq_p // bq, Tk_p // bk),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, iq, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, iq, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, iq, ik: (b, h, 0, iq)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, iq, ik: (b, h, 0, iq)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+        functools.partial(_bwd_dq_kernel, **kernel_kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, H, by_q.shape[1]),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, H, Tq_p, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
         name=FLASH_BWD_DQ,
-    )(q, k, v, do, lse, delta)
+    )(*by_q, q, k, v, do, lse, delta)
 
+    by_kv = _tile_table(Tq, Tk, bq, bk, causal, window, by_kv=True)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=bq, block_k=bk, tq=Tq, tk=Tk,
-                          window=window),
-        grid=(B, H, Tk_p // bk, Tq_p // bq),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, ik, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, iq: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, iq: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, ik, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, ik, iq: (b, h, 0, iq)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, ik, iq: (b, h, 0, iq)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, iq: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, iq: (b, h, ik, 0)),
-        ],
+        functools.partial(_bwd_dkv_kernel, **kernel_kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, H, by_kv.shape[1]),
+            in_specs=in_specs,
+            out_specs=[kv_spec, kv_spec],
+            scratch_shapes=[
+                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Tk_p, D), k.dtype),
             jax.ShapeDtypeStruct((B, H, Tk_p, D), v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
-        ],
         interpret=interpret,
         name=FLASH_BWD_DKV,
-    )(q, k, v, do, lse, delta)
+    )(*by_kv, q, k, v, do, lse, delta)
     return dq[:, :, :Tq], dk[:, :, :Tk], dv[:, :, :Tk]
 
 

@@ -73,6 +73,16 @@ def _flash(bwd, window, shape=(1, 2048, H, D)):
     return jax.grad(loss, argnums=(0, 1, 2)), [q, q, q]
 
 
+def _flash_key_mask():
+    """Serving prefill (``layers.flash_prefill_from_empty``): forward only,
+    kv heads un-repeated, a [B, Tk] key-padding mask."""
+    B, T = 2, 2048
+    fn = functools.partial(flash_attention, causal=True, interpret=False)
+    return (lambda q, k, v, mask: fn(q, k, v, key_mask=mask),
+            [((B, T, H, D), BF16), ((B, T, HKV, D), BF16),
+             ((B, T, HKV, D), BF16), ((B, T), jnp.int32)])
+
+
 def _ragged(int8, window):
     T, N, bs, R, nb = 135, 2048, 16, 8, 64  # the smoke's mixed step
     pages = ((N, HKV, bs, D), jnp.int8 if int8 else BF16)
@@ -127,6 +137,9 @@ CASES = {
     "flash_fwd_bwd_window": lambda: _flash(True, 1024),
     # olmoe-1b-7b.train.4k: two 4096-token sequences, 16 heads (MHA)
     "flash_fwd_bwd_olmoe": lambda: _flash(True, None, (2, 4096, 16, D)),
+    # mistral-7b.train.8k: one 8192-token sequence, the 4096 window binds
+    "flash_fwd_bwd_train8k": lambda: _flash(True, 4096, (1, 8192, H, D)),
+    "flash_fwd_key_mask_gqa": _flash_key_mask,
     "ragged_bf16": lambda: _ragged(False, None),
     "ragged_bf16_window": lambda: _ragged(False, 4096),
     "ragged_int8": lambda: _ragged(True, None),

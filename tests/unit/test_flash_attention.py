@@ -180,3 +180,122 @@ def test_key_mask_path_gqa_native_kv_heads():
                                key_mask=mask)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# -- the static tile table ----------------------------------------------------
+
+
+def _dense_tiles(tq, tk, bq, bk, causal, window):
+    """[nq, nk] "any entry visible" and "every entry visible" of the dense
+    mask, padded tails counting as not visible."""
+    d = np.arange(tq)[:, None] + (tk - tq) - np.arange(tk)[None, :]
+    mask = np.ones((tq, tk), bool)
+    if causal:
+        mask &= d >= 0
+    if window is not None:
+        mask &= d < window
+    nq, nk = -(-tq // bq), -(-tk // bk)
+    padded = np.zeros((nq * bq, nk * bk), bool)
+    padded[:tq, :tk] = mask
+    tiles = padded.reshape(nq, bq, nk, bk)
+    return tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+
+
+@pytest.mark.parametrize("tq,tk,bq,bk,causal,window", [
+    (256, 256, 64, 64, True, None),      # the plain triangle
+    (256, 256, 64, 64, False, None),     # the full rectangle
+    (256, 256, 64, 64, True, 128),       # window of two blocks
+    (256, 256, 64, 64, True, 16),        # window smaller than a block
+    (256, 256, 32, 64, True, 100),       # bq != bk, window off the grid
+    (96, 96, 64, 64, True, None),        # ragged tails
+    (100, 200, 32, 64, True, 48),        # ragged, tq < tk, window
+    (32, 128, 16, 32, True, 16),         # kv rows no query sees
+    (96, 32, 32, 32, True, None),        # q rows that see no key
+    (128, 64, 32, 32, True, 8),          # tq > tk with a window
+    (200, 100, 64, 32, False, 24),       # a window without causality
+    (1, 128, 1, 64, True, None),         # the decode shape
+])
+def test_tile_table_is_the_dense_mask_by_tile(tq, tk, bq, bk, causal, window):
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        _CUT, _FIRST, _INSIDE, _LAST, _tile_table)
+
+    some, every = _dense_tiles(tq, tk, bq, bk, causal, window)
+    for by_kv in (False, True):
+        iq, ik, flags = _tile_table(tq, tk, bq, bk, causal, window, by_kv)
+        assert iq.dtype == ik.dtype == flags.dtype == np.int32
+        outer, inner = (ik, iq) if by_kv else (iq, ik)
+        # ordered by output row, the inner index ascending: the kernels'
+        # accumulation order
+        order = list(zip(outer.tolist(), inner.tolist()))
+        assert order == sorted(set(order))
+        # every row of output tiles is initialised and written exactly once
+        n_outer = some.shape[1] if by_kv else some.shape[0]
+        assert sorted(set(outer.tolist())) == list(range(n_outer))
+        first = np.r_[True, outer[1:] != outer[:-1]]
+        last = np.r_[first[1:], True]
+        assert ((flags & _FIRST != 0) == first).all()
+        assert ((flags & _LAST != 0) == last).all()
+        # the entries that run are the tiles with a visible entry, and those
+        # that skip the in-tile mask are the wholly visible ones
+        runs = flags & (_INSIDE | _CUT) != 0
+        got = np.zeros_like(some)
+        got[iq[runs], ik[runs]] = True
+        assert (got == some).all()
+        assert ((flags & _INSIDE != 0) == every[iq, ik]).all()
+        assert not (flags & _INSIDE != 0)[~runs].any()
+        assert ((flags & _INSIDE != 0) & (flags & _CUT != 0)).sum() == 0
+        # a placeholder only where the row has nothing to run
+        rows_that_run = set(outer[runs].tolist())
+        assert all(o not in rows_that_run for o in outer[~runs].tolist())
+
+
+@pytest.mark.parametrize("cell,t,window,rect,kept,inside", [
+    ("mistral-7b.train.8k", 8192, 4096, 256, 108, 84),
+    ("olmoe-1b-7b.train.4k", 4096, None, 64, 36, 28),
+])
+def test_tile_table_counts_of_the_benchmark_cells(cell, t, window, rect, kept,
+                                                  inside):
+    """The grid's "hit share" is a function of the shapes alone: 108 of 256
+    tiles (84 of them mask-free) at train.8k, 36 of 64 (28) at OLMoE's 4k."""
+    from deepspeed_tpu.ops.pallas.flash_attention import _INSIDE, _tile_table
+
+    assert (t // 512) ** 2 == rect
+    for by_kv in (False, True):
+        flags = _tile_table(t, t, 512, 512, True, window, by_kv)[2]
+        assert flags.shape == (kept,)
+        assert int((flags & _INSIDE != 0).sum()) == inside
+
+
+@pytest.mark.parametrize("tq,tk,bq,bk,window,first_real_row", [
+    (32, 128, 16, 32, 16, 0),     # window with cross length: kv tiles 0, 1
+                                  # are seen by no query
+    (96, 32, 32, 32, None, 64),   # tk < tq: q tiles 0, 1 see no key
+])
+def test_flash_placeholder_rows_forward_and_gradients(tq, tk, bq, bk, window,
+                                                      first_real_row):
+    """A row of output tiles with nothing to run is still written (zeros).
+    Query rows that see no key are degenerate in the reference (a uniform
+    softmax over masked keys), so parity covers the rows that see one."""
+    q, _, _ = _qkv(1, tq, 2, 64, seed=5)
+    _, k, v = _qkv(1, tk, 2, 64, seed=6)
+    r = first_real_row
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                               interpret=True, force_pallas=True,
+                               window=window)
+
+    def ref(q, k, v):
+        return _reference_attention(q, k, v, True, 1.0 / 8.0, window=window)
+
+    out = flash(q, k, v)
+    np.testing.assert_allclose(np.asarray(out[:, r:]),
+                               np.asarray(ref(q, k, v)[:, r:]),
+                               atol=2e-5, rtol=2e-5)
+    assert not np.asarray(out[:, :r]).any()
+    loss = lambda f: lambda q, k, v: jnp.sum(f(q, k, v)[:, r:] ** 2)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=5e-5, err_msg=f"d{name}")
