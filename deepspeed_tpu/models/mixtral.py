@@ -8,7 +8,8 @@ of the selected experts' SwiGLU outputs (plus the Switch load-balancing aux
 loss scaled by ``router_aux_loss_coef`` during training).
 
 TPU-native dispatch: expert weights live STACKED ``[E, ...]`` and shard over
-the ``expert`` mesh axis. For ``T > 1`` the layer is a dropless, token-sorted
+the ``expert`` mesh axis (which of their dims it shards: ``expert_layout``,
+the one place that says). For ``T > 1`` the layer is a dropless, token-sorted
 grouped matmul (``_routed_experts``): the ``N*K`` (token, expert) pairs are
 stably sorted by expert, each expert's run of rows multiplies its own
 weights through ``jax.lax.ragged_dot`` (XLA:TPU's grouped-matmul kernel),
@@ -18,16 +19,18 @@ HF logits parity) or padded to a capacity, and the arithmetic follows the
 rows routed: K/E of what computing every expert for every token costs. Under
 an ``expert`` mesh axis the same function runs inside a ``shard_map`` that
 moves TOKENS and never weights: tokens all-gather over ``expert`` on entry,
-each chip computes the pairs routed to its ``E/ep`` experts, and the
-partial outputs ``psum_scatter`` back onto the batch layout. ``T == 1``
-with replicated experts keeps the weight-gather decode path. For
-capacity-based all_to_all dispatch use ``deepspeed_tpu.moe.MoE`` (GShard
-gating, reference ``sharded_moe.py``).
+each chip computes its share of the layer (``expert_layout``: every routed
+pair over its columns of all experts, or the pairs routed to its ``E/ep``
+whole experts), and the partial outputs ``psum_scatter`` back onto the batch
+layout. ``T == 1`` with replicated experts keeps the weight-gather decode
+path. For capacity-based all_to_all dispatch use ``deepspeed_tpu.moe.MoE``
+(GShard gating, reference ``sharded_moe.py``).
 
 Attention/rotary/cache machinery is shared with ``models/llama.py``.
 """
 
 import dataclasses
+import functools
 import math
 from typing import Optional
 
@@ -37,6 +40,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import BATCH_AXES, get_mesh, tokens_replicated
+from ..utils.logging import log_dist
 from .layers import (RMSNorm, cross_entropy_loss, head_scope, init_kv_cache,
                      lm_head_output,
                      resolve_remat_policy, rotary_embedding, shift_labels)
@@ -52,6 +56,54 @@ def _expert_axis_active() -> bool:
         return False
     return dict(zip(mesh.axis_names,
                     mesh.devices.shape)).get("expert", 1) > 1
+
+
+def _expert_axis_size(mesh) -> int:
+    return 1 if mesh is None else dict(
+        zip(mesh.axis_names, mesh.devices.shape)).get("expert", 1)
+
+
+def expert_layout(E: int, I: int, ep: int) -> str:
+    """What an ``expert`` mesh axis of size ``ep`` shards of the stacked
+    expert weights ``w1``/``w3 [E, H, I]`` and ``w2 [E, I, H]``: the one
+    place that decides (``partition_rules`` and ``_expert_mlp``'s
+    ``shard_map`` both ask here, so the parameters' sharding and the
+    layer's ``in_specs`` cannot disagree).
+
+    ``"columns"``: every chip holds ``I/ep`` of the intermediate columns of
+    ALL ``E`` experts and computes every routed pair at that width, so the
+    chips do identical work whatever the router does. Taken when the slice
+    keeps XLA's grouped-matmul kernel wide — ``I // ep`` at least 1024 and a
+    multiple of the 128 lanes (Mixtral over 4: 3584).
+
+    ``"experts"``: every chip holds ``E/ep`` whole experts and computes the
+    pairs routed to them; the step then follows the busiest chip. Taken
+    otherwise: fine-grained experts (OLMoE over 4: 64 experts of 1024 would
+    leave 256 columns, and 16 experts a chip average the router out by
+    themselves), and the tiny test configurations.
+
+    Same parameters and optimizer state a chip and the same two token-sized
+    collectives either way; no weight moves in either. (``E`` names the
+    case and enters no condition today.)"""
+    cols = I // ep
+    if ep > 1 and I % ep == 0 and cols >= 1024 and cols % 128 == 0:
+        return "columns"
+    return "experts"
+
+
+def _expert_weight_specs(layout: str):
+    """``(spec of w1 and w3 [E, H, I], spec of w2 [E, I, H])``."""
+    if layout == "columns":
+        return P(None, None, "expert"), P(None, "expert", None)
+    return P("expert", None, None), P("expert", None, None)
+
+
+@functools.lru_cache(maxsize=None)
+def _log_expert_layout(layout: str, E: int, I: int, ep: int) -> None:
+    """Once per layout and shape, at trace time."""
+    log_dist(f"moe expert layout: {layout} (E={E}, I/ep={I // ep})"
+             if layout == "columns" else
+             f"moe expert layout: {layout} (E/ep={E // ep}, I={I})", ranks=[0])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,7 +124,10 @@ class MixtralConfig(LlamaConfig):
     #: the training call returns ``(loss, {"moe_rows_max_over_mean",
     #: "moe_rows_min_over_mean"})``: the busiest and the idlest expert's
     #: (token, expert) pairs over the mean, pairs summed over layers, which
-    #: the train engine publishes as registry gauges
+    #: the train engine publishes as registry gauges; under an ``expert``
+    #: mesh axis also ``"moe_chip_rows_max_over_mean"``, the busiest chip's
+    #: pairs over the chips' mean (what the step follows under
+    #: ``expert_layout``'s whole experts, 1 by construction under columns)
     report_expert_load: bool = False
 
     @staticmethod
@@ -153,6 +208,7 @@ class MixtralSparseMoeBlock(nn.Module):
                                     dtype=topk_w.dtype)  # [B,T,K,E]
 
         # stacked expert SwiGLU: [E, H, I] / [E, I, H], sharded over "expert"
+        # (expert_layout)
         init = nn.initializers.lecun_normal(
             batch_axis=(0,) if cfg.per_expert_init else ())
         w1 = self.param("w1", init, (E, H, I), jnp.float32)  # gate
@@ -333,13 +389,18 @@ def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx):
     mesh = get_mesh()
     size = {} if mesh is None else dict(zip(mesh.axis_names,
                                             mesh.devices.shape))
-    if size.get("expert", 1) == 1:
+    ep = size.get("expert", 1)
+    if ep == 1:
         return experts(x, w1, w2, w3, topk_w, topk_idx)
 
     # expert parallel: tokens move, weights never do. Tokens all-gather
     # over `expert` on entry (or are already whole on it), each shard
-    # computes the pairs routed to its own experts, and the partial outputs
-    # sum back onto the batch layout.
+    # computes its share of the layer (expert_layout: every pair over its
+    # columns of all experts, or the pairs routed to its own experts), and
+    # the partial outputs sum back onto the batch layout.
+    layout = expert_layout(E, w1.shape[2], ep)
+    _log_expert_layout(layout, E, w1.shape[2], ep)
+    columns = layout == "columns"
     batch = _token_axes(size, B)
     gathered = "expert" in batch
     others = tuple(a for a in batch if a != "expert")
@@ -356,21 +417,25 @@ def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx):
             x, topk_w = jax.lax.pcast((x, topk_w), "expert", to="varying")
         if others:
             w1, w2, w3 = jax.lax.pcast((w1, w2, w3), others, to="varying")
-        out, rows = experts(x, w1, w2, w3, topk_w, topk_idx,
-                            jax.lax.axis_index("expert") * w1.shape[0])
+        out, rows = experts(
+            x, w1, w2, w3, topk_w, topk_idx,
+            0 if columns else jax.lax.axis_index("expert") * w1.shape[0])
         if gathered:
             out = jax.lax.psum_scatter(out, "expert", scatter_dimension=0,
                                        tiled=True)
+            if columns:     # every chip counted the same E groups: say so
+                rows = jax.lax.pmax(rows, "expert")
         else:
             out = jax.lax.psum(out, "expert")
         return out, (jax.lax.psum(rows, others) if others else rows)
 
     tokens = P(batch or None, None, None)
-    stacked = P("expert", None, None)
+    up, down = _expert_weight_specs(layout)
     return jax.shard_map(
         shard, mesh=mesh,
-        in_specs=(tokens, stacked, stacked, stacked, tokens, tokens),
-        out_specs=(tokens, P("expert")))(x, w1, w2, w3, topk_w, topk_idx)
+        in_specs=(tokens, up, down, up, tokens, tokens),
+        out_specs=(tokens, P() if columns else P("expert")))(
+            x, w1, w2, w3, topk_w, topk_idx)
 
 
 class MixtralBlock(nn.Module):
@@ -502,8 +567,17 @@ class MixtralForCausalLM(nn.Module):
         if not cfg.report_expert_load:
             return loss
         load = load / jnp.mean(load)
-        return loss, {"moe_rows_max_over_mean": jnp.max(load),
-                      "moe_rows_min_over_mean": jnp.min(load)}
+        named = {"moe_rows_max_over_mean": jnp.max(load),
+                 "moe_rows_min_over_mean": jnp.min(load)}
+        ep = _expert_axis_size(get_mesh())
+        if ep > 1:
+            # a chip of the expert axis computes the rows of its whole
+            # experts, or a slice of every row
+            whole = expert_layout(cfg.num_local_experts,
+                                  cfg.intermediate_size, ep) == "experts"
+            named["moe_chip_rows_max_over_mean"] = jnp.max(jnp.mean(
+                load.reshape(ep if whole else 1, -1), axis=1))
+        return loss, named
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16):
         cfg = self.config
@@ -514,13 +588,22 @@ class MixtralForCausalLM(nn.Module):
     @staticmethod
     def partition_rules(config: "MixtralConfig"):
         """TP for attention (Megatron layout) + EP for the stacked expert
-        weights (``expert`` mesh axis on the leading E dim)."""
+        weights: the ``expert`` mesh axis on the dim ``expert_layout`` names
+        for the mesh the rules are resolved on (a spec that is a function of
+        the mesh: ``runtime/zero/partition.py state_shardings``)."""
         L = (None,) if config.scan_layers else ()
+
+        def experts(mesh):
+            return _expert_weight_specs(expert_layout(
+                config.num_local_experts, config.intermediate_size,
+                _expert_axis_size(mesh)))
+
         return [
             (r"embed_tokens/embedding", P("model", None)),
             (r"(q_proj|k_proj|v_proj)/kernel", P(*L, None, "model")),
             (r"o_proj/kernel", P(*L, "model", None)),
-            (r"block_sparse_moe/(w1|w2|w3)", P(*L, "expert", None, None)),
+            (r"block_sparse_moe/(w1|w3)", lambda m: P(*L, *experts(m)[0])),
+            (r"block_sparse_moe/w2", lambda m: P(*L, *experts(m)[1])),
             (r"lm_head/kernel", P(None, "model")),
             (r"(q_norm|k_norm)/scale", P(*L, "model")),
         ]

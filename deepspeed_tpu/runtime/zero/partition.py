@@ -112,14 +112,15 @@ def partition_spec_for_param(
     return _canon(new)
 
 
-def _resolve_base_spec(path: str, shape, rules) -> Optional[PartitionSpec]:
+def _resolve_base_spec(path: str, shape, rules, mesh) -> Optional[PartitionSpec]:
     if rules is None:
         return None
     if callable(rules):
         return rules(path, shape)
     for pattern, spec in rules:
         if re.search(pattern, path):
-            return spec
+            # a layout that follows the mesh's sizes is a function of the mesh
+            return spec(mesh) if callable(spec) else spec
     return None
 
 
@@ -137,7 +138,8 @@ def state_shardings(
 
     - ``params_shapes``: pytree of ``jax.ShapeDtypeStruct`` (or arrays).
     - ``partition_rules``: tensor-parallel rules — list of
-      ``(path_regex, PartitionSpec)`` or callable ``(path, shape) -> spec``.
+      ``(path_regex, PartitionSpec)`` or callable ``(path, shape) -> spec``;
+      a list entry's spec may be a callable ``mesh -> PartitionSpec``.
 
     Returns the params sharding pytree and a function that shards any
     param-shaped pytree (optimizer moments) with stage>=1 policy.
@@ -147,7 +149,7 @@ def state_shardings(
 
     def spec_of(path, leaf, zero_shard, threshold):
         path_s = _path_str(path)
-        base = _resolve_base_spec(path_s, leaf.shape, partition_rules)
+        base = _resolve_base_spec(path_s, leaf.shape, partition_rules, mesh)
         return partition_spec_for_param(
             tuple(leaf.shape), mesh, zero_shard=zero_shard, base_spec=base,
             persistence_threshold=threshold)

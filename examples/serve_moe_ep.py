@@ -2,9 +2,11 @@
 ``deepspeed.init_inference(..., moe related kwargs)`` building expert-parallel
 groups at serve time).
 
-A Mixtral-family model serves with its stacked expert weights sharded
-E/ep_size per device group over the ``expert`` mesh axis — each group holds a
-fraction of the experts instead of a full replica — while attention is
+A Mixtral-family model serves with its stacked expert weights sharded over
+the ``expert`` mesh axis — each device group holds 1/ep_size of them instead
+of a full replica: whole experts, or from 1024 intermediate columns a group
+on (Mixtral-8x7B's widths) a slice of every expert's columns, as
+``deepspeed_tpu.models.mixtral.expert_layout`` says — while attention is
 tensor-parallel over ``model``. Runs anywhere:
 
     # laptop / CI: virtual 8-device CPU mesh (ep=4 x mp=2)
@@ -49,9 +51,12 @@ def main():
     engine = ds.init_inference(model, params=params, dtype="bf16",
                                mp_size=args.mp, ep_size=args.ep)
     w1 = engine.params["model"]["layers"]["block"]["block_sparse_moe"]["w1"]
-    print(f"expert shard spec: {w1.sharding.spec} "
-          f"(E={cfg.num_local_experts}, ep={engine.ep_world_size} -> "
-          f"{cfg.num_local_experts // engine.ep_world_size} experts/group)")
+    from deepspeed_tpu.models.mixtral import expert_layout
+
+    E, inter, ep = (cfg.num_local_experts, cfg.intermediate_size,
+                    engine.ep_world_size)
+    print(f"expert shard spec: {w1.sharding.spec} (E={E}, I={inter}, ep={ep}: "
+          f"layout {expert_layout(E, inter, ep)!r})")
     toks = engine.generate(ids, max_new_tokens=args.max_new_tokens,
                            do_sample=False)
     print("generated:", np.asarray(toks)[:, :8], "...")

@@ -220,35 +220,52 @@ _COLLECTIVE = re.compile(
 _BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
 
 
-def test_mixtral_expert_layer_moves_tokens_not_weights_on_v5e_2x2(topo):
+@pytest.mark.parametrize("preset,layout,B", [
+    ("mixtral_8x7b", "columns", 4), ("olmoe_1b_7b", "experts", 8)])
+def test_mixtral_expert_layer_moves_tokens_not_weights_on_v5e_2x2(
+        topo, preset, layout, B):
     """The MoE layer at Mixtral-8x7B widths (4 x 4096 tokens, 8 experts of
-    14336, top-2), forward and backward, compiles for ``v5e:2x2`` under
-    ``expert=4``. In the optimized HLO the products are XLA's own grouped
-    matmuls (no ``pallas_call`` of ours in the lowering), and every
-    collective is at most token-sized (B*T*H*4 bytes) and never shaped like
-    an expert weight — what ledger PR 25's breakdown is pinned against."""
+    14336, top-2: ``expert_layout`` shards every expert's columns, 3584 a
+    chip) and at OLMoE-1B-7B's (8 x 4096 tokens, 64 experts of 1024, top-8:
+    16 whole experts a chip), forward and backward, compiles for ``v5e:2x2``
+    under ``expert=4`` with the weights sharded as the model's own
+    ``partition_rules`` say. In the optimized HLO the products are XLA's own
+    grouped matmuls (no ``pallas_call`` of ours in the lowering) over 8 and
+    16 groups, and every collective is at most token-sized (B*T*H*4 bytes)
+    and never shaped like an expert weight or a chip's share of one — what
+    ledger PR 25's breakdown is pinned against, and what a disagreement
+    between the rules and the layer's ``in_specs`` would break."""
     import deepspeed_tpu.models.mixtral as mx
-    from deepspeed_tpu.models import MixtralConfig
+    from deepspeed_tpu.models import MixtralConfig, MixtralForCausalLM
     from deepspeed_tpu.parallel import build_mesh
     from deepspeed_tpu.parallel.topology import set_mesh
+    from deepspeed_tpu.runtime.zero.partition import state_shardings
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    B, T, HID, INTER, E, K = 4, 4096, 4096, 14336, 8, 2
-    cfg = MixtralConfig.mixtral_8x7b(num_hidden_layers=1)
+    cfg = getattr(MixtralConfig, preset)(num_hidden_layers=1,
+                                         scan_layers=False)
+    T, HID, INTER, E, K = (4096, cfg.hidden_size, cfg.intermediate_size,
+                           cfg.num_local_experts, cfg.num_experts_per_tok)
+    assert mx.expert_layout(E, INTER, 4) == layout
     mesh = build_mesh(expert=4, devices=topo.devices)
     set_mesh(mesh)  # the conftest fixture clears it
     tokens = NamedSharding(mesh, P(("data", "expert")))
-    stacked = NamedSharding(mesh, P("expert"))
+    shapes = {"w1": (E, HID, INTER), "w2": (E, INTER, HID),
+              "w3": (E, HID, INTER)}
+    placed, _ = state_shardings(
+        {"block_sparse_moe": {n: jax.ShapeDtypeStruct(s, jnp.float32)
+                              for n, s in shapes.items()}},
+        mesh, None, MixtralForCausalLM.partition_rules(cfg))
+    w1, w2, w3 = (jax.ShapeDtypeStruct(
+        shapes[n], jnp.float32, sharding=placed["block_sparse_moe"][n])
+        for n in ("w1", "w2", "w3"))
+    assert "expert" in w1.sharding.spec
 
-    def struct(shape, dtype, sharding):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tokens)
 
-    args = (struct((B, T, HID), BF16, tokens),
-            struct((E, HID, INTER), jnp.float32, stacked),
-            struct((E, INTER, HID), jnp.float32, stacked),
-            struct((E, HID, INTER), jnp.float32, stacked),
-            struct((B, T, K), jnp.float32, tokens),
-            struct((B, T, K), jnp.int32, tokens))
+    args = (struct((B, T, HID), BF16), w1, w2, w3,
+            struct((B, T, K), jnp.float32), struct((B, T, K), jnp.int32))
 
     def loss(x, w1, w2, w3, topk_w, topk_idx):
         out, rows = mx._expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx)
@@ -258,16 +275,22 @@ def test_mixtral_expert_layer_moves_tokens_not_weights_on_v5e_2x2(topo):
         loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(*args)
     assert "pallas" not in lowered.as_text().lower()
     hlo = lowered.compile().as_text()
-    assert len(re.findall(r"%ragged-dot\S* = ", hlo)) >= 9, \
-        "three products forward, three dx, three dw"
+    # three products forward, three dx, three dw: a chip's share of each
+    groups, cols = (E, INTER // 4) if layout == "columns" else (E // 4, INTER)
+    shapes = sorted(dims for _, dims in re.findall(
+        r"%(ragged-dot-none\S*) = \w+\[([\d,]*)\]", hlo))
+    rows = B * T * K
+    assert shapes.count(f"{rows},{cols}") >= 3       # gate, up, g @ w2^T
+    assert shapes.count(f"{rows},{HID}") >= 3        # down, two dx
+    assert shapes.count(f"{groups},{HID},{cols}") + \
+        shapes.count(f"{groups},{cols},{HID}") >= 3  # dw1, dw3, dw2
     found = [m.groups() for m in map(_COLLECTIVE.search, hlo.splitlines())
              if m]
     assert {kind for _, _, kind in found} >= {"all-gather"}
     token_bytes = B * T * HID * 4
-    per_chip = E // 4
     for dtype, dims, kind in found:
         dims = [int(d) for d in dims.split(",") if d]
         assert math.prod(dims) * _BYTES.get(dtype, 8) <= 1.01 * token_bytes, \
             (kind, dtype, dims)
-        assert not ({HID, INTER} <= set(dims)
-                    and dims[0] in (per_chip, E)), (kind, dtype, dims)
+        assert not (HID in dims and {INTER, INTER // 4} & set(dims)
+                    and dims[0] in (E // 4, E)), (kind, dtype, dims)

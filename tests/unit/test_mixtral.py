@@ -6,6 +6,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
 def _tiny_mixtral_hf(seed=0):
@@ -211,19 +212,24 @@ def test_replicate_tokens_ep_layout_trains():
     assert "REPLICATE-OK" in r.stdout
 
 
-def test_ep_layout_moves_tokens_not_weights_on_cpu():
+@pytest.mark.parametrize("layout,inter", [("experts", 64), ("columns", 4096)])
+def test_ep_layout_moves_tokens_not_weights_on_cpu(layout, inter):
     """The expert-parallel layer under the engine's (data, expert) batch
     layout: the train step compiles with the token all-gather over `expert`
     on entry that the layer's ``shard_map`` states, and no collective in the
     partitioned program has an expert weight's shape — tokens move, weights
-    and their gradients never do (PERF.md, PR 25's lines)."""
+    and their gradients never do (PERF.md, PR 25's lines) — under either
+    layout of the expert axis: the parameters' sharding (the engine, from
+    ``partition_rules``) and the layer's ``in_specs`` agree."""
     import re
 
     import deepspeed_tpu as ds
     from deepspeed_tpu.models import MixtralConfig, MixtralForCausalLM
+    from deepspeed_tpu.models.mixtral import expert_layout
     from deepspeed_tpu.parallel import build_mesh
 
-    cfg = MixtralConfig.tiny()
+    cfg = MixtralConfig.tiny(intermediate_size=inter)
+    assert expert_layout(cfg.num_local_experts, inter, 4) == layout
     model = MixtralForCausalLM(cfg)
     rs = np.random.RandomState(0)
     batch = {"input_ids": rs.randint(0, cfg.vocab_size, (8, 16)),
@@ -243,16 +249,25 @@ def test_ep_layout_moves_tokens_not_weights_on_cpu():
         jax.random.PRNGKey(0)).compile()
     hlo = compiled.as_text()
     assert "all-gather" in hlo  # the explicit entry gather is placed
+    blk = engine.param_shardings["model"]["layers"]["block"][
+        "block_sparse_moe"]
+    assert (blk["w1"].spec, blk["w2"].spec, blk["w3"].spec) == {
+        "experts": (P(None, "expert"),) * 3,
+        "columns": (P(None, None, None, "expert"), P(None, None, "expert"),
+                    P(None, None, None, "expert"))}[layout]
     H, I = cfg.hidden_size, cfg.intermediate_size
     collectives = [ln for ln in hlo.splitlines() if re.search(
         r"= \S+ (all-gather|all-reduce|reduce-scatter|all-to-all|"
         r"collective-permute)(-start)?\(", ln)]
     assert collectives
     # the expert axis never carries a weight: a collective with a
-    # [.., H, I] operand runs in groups of 2, the data axis alone (a weight
-    # gradient's sum over its data replicas), never of 4 or of all 8
+    # [.., H, I] operand (under columns also a chip's [.., H, I/4] slice)
+    # runs in groups of 2, the data axis alone (a weight gradient's sum over
+    # its data replicas), never of 4 or of all 8
+    widths = (I, I // 4) if layout == "columns" else (I,)
+    shaped = "|".join(f"{H},{w}|{w},{H}" for w in widths)
     for ln in collectives:
-        if re.search(rf"\[(\d+,)*({H},{I}|{I},{H})\]", ln):
+        if re.search(rf"\[(\d+,)*({shaped})\]", ln):
             iota = re.search(r"replica_groups=\[\d+,(\d+)\]", ln)
             listed = re.search(r"replica_groups=\{\{([\d,]+)\}", ln)
             assert iota or listed, ln
@@ -426,7 +441,9 @@ def _moe_case(kind, B=8, T=6, H=16, I=24, E=8, K=2, seed=0):
     x = jnp.asarray(rs.randn(B, T, H), jnp.float32)
     w1, w3 = (jnp.asarray(rs.randn(E, H, I) * 0.3, jnp.float32)
               for _ in range(2))
-    w2 = jnp.asarray(rs.randn(E, I, H) * 0.3, jnp.float32)
+    # the down projection sums over I: scaled so a wide I gives outputs of
+    # the size the tolerances below were set at (I = 24: times 0.3)
+    w2 = jnp.asarray(rs.randn(E, I, H) * 0.3 * (24 / I) ** 0.5, jnp.float32)
     idx, w = _routing(kind, B * T, E, K, rs)
     return x, w1, w2, w3, w.reshape(B, T, K), idx.reshape(B, T, K)
 
@@ -483,20 +500,34 @@ def test_grouped_experts_match_all_e_oracle(kind, top_k):
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5, err_msg=name)
 
 
+def _shard_map_specs(fn, *args):
+    """``(in_specs, out_specs)`` of the one ``shard_map`` ``fn`` traces."""
+    (eqn,) = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+              if e.primitive.name == "shard_map"]
+    return eqn.params["in_specs"], eqn.params["out_specs"]
+
+
+#: the intermediate size that reaches each layout over expert=4
+#: (``expert_layout``: columns from I // ep = 1024 on)
+_LAYOUT_I = {"experts": 24, "columns": 4096}
+
+
+@pytest.mark.parametrize("layout", ["experts", "columns"])
 @pytest.mark.parametrize("data", [1, 2], ids=["expert4", "data2xexpert4"])
 @pytest.mark.parametrize("replicate", [False, True],
                          ids=["gathered", "replicate_tokens"])
 @pytest.mark.parametrize("kind", ["uniform", "skewed", "starved"])
 def test_grouped_experts_under_expert_mesh_match_unmeshed(kind, replicate,
-                                                          data):
+                                                          data, layout):
     """The same layer inside the ``shard_map`` over a CPU `expert=4` mesh
     (alone, and under `data=2`) — tokens all-gathered over `expert` (the
     engine's batch layout) or already whole on it
     (``moe.replicate_tokens``) — gives the unmeshed outputs, gradients and
-    group sizes. Forward parity alone misses an input that enters whole on
-    an axis and was not marked varying: its cotangent loses the sum over
-    the shards (x and the routing weights over `expert`, the expert weights
-    over `data`)."""
+    group sizes, whether the axis shards whole experts or, from 1024
+    columns a chip on, every expert's intermediate columns. Forward parity
+    alone misses an input that enters whole on an axis and was not marked
+    varying: its cotangent loses the sum over the shards (x and the routing
+    weights over `expert`, the expert weights over `data`)."""
     import deepspeed_tpu.models.mixtral as mx
     from deepspeed_tpu.models import MixtralConfig
     from deepspeed_tpu.parallel import build_mesh
@@ -504,13 +535,20 @@ def test_grouped_experts_under_expert_mesh_match_unmeshed(kind, replicate,
                                                  set_token_replication)
 
     cfg = MixtralConfig.tiny(**_MOE_CFG)
-    args = _moe_case(kind, seed=1)
+    args = _moe_case(kind, seed=1, I=_LAYOUT_I[layout])
     want_out, want_rows = mx._expert_mlp(cfg, *args)
     want = jax.grad(_moe_loss(_grouped(cfg)), argnums=(0, 1, 2, 3, 4))(*args)
 
     set_mesh(build_mesh(data=data, expert=4,
                         devices=jax.devices()[:4 * data]))
     set_token_replication(replicate)
+    assert mx.expert_layout(8, _LAYOUT_I[layout], 4) == layout
+    (_, w1_spec, w2_spec, w3_spec, *_), _ = _shard_map_specs(
+        lambda *a: mx._expert_mlp(cfg, *a), *args)
+    assert (w1_spec, w2_spec, w3_spec) == {
+        "experts": (P("expert", None, None),) * 3,
+        "columns": (P(None, None, "expert"), P(None, "expert", None),
+                    P(None, None, "expert"))}[layout]
     hlo = jax.jit(lambda *a: mx._expert_mlp(cfg, *a)).lower(*args).as_text()
     assert ("all_gather" in hlo) == (not replicate)
     out, rows = jax.jit(lambda *a: mx._expert_mlp(cfg, *a))(*args)
@@ -520,6 +558,110 @@ def test_grouped_experts_under_expert_mesh_match_unmeshed(kind, replicate,
                            argnums=(0, 1, 2, 3, 4)))(*args)
     for name, g, w in zip(("x", "w1", "w2", "w3", "topk_w"), got, want):
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("E,inter,ep,layout", [
+    (8, 14336, 4, "columns"),       # Mixtral-8x7B over four chips: 3584
+    (8, 14336, 8, "columns"),       # 1792
+    (8, 4096, 4, "columns"),        # the least: 1024 columns a chip
+    (8, 14336, 16, "experts"),      # 896 columns: too narrow
+    (8, 4352, 4, "experts"),        # 1088: not a multiple of the 128 lanes
+    (8, 14337, 4, "experts"),       # does not divide
+    (64, 1024, 4, "experts"),       # OLMoE-1B-7B over four chips: 256
+    (8, 14336, 1, "experts"),       # no expert axis: nothing is sharded
+    (4, 64, 4, "experts"),          # MixtralConfig.tiny
+    (8, 24, 4, "experts"),          # the layer tests above
+])
+def test_expert_layout_follows_the_columns_a_chip_would_keep(E, inter, ep,
+                                                             layout):
+    import deepspeed_tpu.models.mixtral as mx
+
+    assert mx.expert_layout(E, inter, ep) == layout
+
+
+def test_partition_rules_and_shard_map_ask_the_one_layout_function(
+        monkeypatch):
+    """What the `expert` axis shards is decided in ``expert_layout`` alone:
+    turned around, both the specs ``partition_rules`` resolves on a mesh
+    and the layer's ``shard_map`` ``in_specs`` follow it, so they cannot
+    disagree; and each asks with the configuration's ``(E, I)`` and the
+    mesh's `expert` size."""
+    import deepspeed_tpu.models.mixtral as mx
+    from deepspeed_tpu.models import MixtralConfig, MixtralForCausalLM
+    from deepspeed_tpu.parallel import build_mesh
+    from deepspeed_tpu.parallel.topology import set_mesh
+    from deepspeed_tpu.runtime.zero.partition import state_shardings
+
+    cfg = MixtralConfig.tiny(**_MOE_CFG, intermediate_size=24)
+    args = _moe_case("uniform")
+    mesh = build_mesh(expert=4, devices=jax.devices()[:4])
+    set_mesh(mesh)
+    shapes = {"block_sparse_moe": {
+        name: jax.ShapeDtypeStruct((2,) + a.shape, a.dtype)    # [L, ...]
+        for name, a in zip(("w1", "w2", "w3"), args[1:4])}}
+
+    def specs():
+        rules, _ = state_shardings(
+            shapes, mesh, None, MixtralForCausalLM.partition_rules(cfg))
+        layer, _ = _shard_map_specs(lambda *a: mx._expert_mlp(cfg, *a), *args)
+        return ([rules["block_sparse_moe"][n].spec
+                 for n in ("w1", "w2", "w3")], list(layer[1:4]))
+
+    asked = []
+    for layout, want in [
+            ("columns", [P(None, None, "expert"), P(None, "expert", None),
+                         P(None, None, "expert")]),
+            ("experts", [P("expert", None, None)] * 3)]:
+        monkeypatch.setattr(
+            mx, "expert_layout",
+            lambda *a, layout=layout: asked.append(a) or layout)
+        params, layer = specs()
+        assert layer == want
+        # the engine's canonical form: the layer axis first, no trailing None
+        assert [tuple(s) for s in params] == [
+            (None,) + tuple(w)[:tuple(w).index("expert") + 1] for w in want]
+    assert set(asked) == {(8, 24, 4)}
+
+
+@pytest.mark.parametrize("layout,inter", [("experts", 64), ("columns", 4096)])
+def test_chip_rows_figure_is_what_the_step_follows(layout, inter):
+    """``moe_chip_rows_max_over_mean`` under `expert=4`: the busiest chip's
+    pairs over the chips' mean where a chip computes its two whole experts'
+    rows, and 1 where every chip computes a slice of every row."""
+    from deepspeed_tpu.models import MixtralConfig, MixtralForCausalLM
+    from deepspeed_tpu.parallel import build_mesh
+    from deepspeed_tpu.parallel.topology import set_mesh
+
+    cfg = MixtralConfig.tiny(num_local_experts=8, intermediate_size=inter,
+                             report_expert_load=True)
+    model = MixtralForCausalLM(cfg)
+    ids = jnp.asarray(np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (4, 16)))
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    mesh = build_mesh(expert=4, devices=jax.devices()[:4])
+    set_mesh(mesh)
+    (_, named), sown = jax.jit(lambda p: model.apply(
+        {"params": p}, ids, labels=ids, mutable=["intermediates"]))(params)
+    rows = np.asarray(jax.tree_util.tree_leaves(sown)[0]).sum(0)    # [E]
+    assert rows.sum() == cfg.num_hidden_layers * ids.size * 2
+    chips = rows.reshape(4, 2).sum(1)
+    assert chips.max() > chips.mean()
+    assert float(named["moe_rows_max_over_mean"]) == pytest.approx(
+        rows.max() / rows.mean())
+    assert float(named["moe_chip_rows_max_over_mean"]) == pytest.approx(
+        chips.max() / chips.mean() if layout == "experts" else 1.0)
+    # and the train engine publishes it by that name (its first step)
+    import deepspeed_tpu as ds
+
+    batch = {"input_ids": np.asarray(ids), "labels": np.asarray(ids)}
+    engine, *_ = ds.initialize(
+        model=model, example_batch={k: v[:1] for k, v in batch.items()},
+        mesh=mesh, partition_rules=MixtralForCausalLM.partition_rules(cfg),
+        config={"train_batch_size": 4, "steps_per_print": 0,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})
+    engine.train_batch(batch=batch)
+    gauge = engine.registry.snapshot()["moe_chip_rows_max_over_mean"]
+    assert gauge == pytest.approx(1.0) if layout == "columns" else gauge > 1
 
 
 def test_expert_rows_are_sown_for_callers_that_ask():
