@@ -1,3 +1,4 @@
+from .deepseek_v3 import DeepseekV3Config, DeepseekV3ForCausalLM  # noqa: F401
 from .gpt2 import GPT2Config, GPT2LMHeadModel  # noqa: F401
 from .llama import LlamaConfig, LlamaForCausalLM  # noqa: F401
 from .mixtral import MixtralConfig, MixtralForCausalLM  # noqa: F401

@@ -6,7 +6,9 @@ TPU-native replacement for the reference's fused attention CUDA kernels
 attention that never materializes the [T, T] score matrix in HBM.
 
 Layout: inputs are [B, T, H, D] (model convention); kernels operate on
-[B, H, T, D]. The grid is ``(B, H, tiles)``: its last dimension walks a
+[B, H, T, D]. Values (and with them the output, its cotangent and dV) may
+have a width ``Dv`` of their own: queries and keys share ``D``, and where
+``Dv == D`` the calls are what they were before the widths were told apart. The grid is ``(B, H, tiles)``: its last dimension walks a
 STATIC TILE TABLE (``_tile_table``) that holds only the (q-tile, kv-tile)
 pairs the causal rule and the window keep, built in numpy at trace time
 from the shapes and handed to the kernel by scalar prefetch; the index maps
@@ -205,6 +207,8 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     Hkv = k.shape[1]
+    Dv = v.shape[3]     # values, the output and its accumulator: their own
+    # width (latent attention: 192-wide queries and keys, 128-wide values)
     if H % Hkv:
         raise ValueError(f"q heads {H} not a multiple of kv heads {Hkv}")
     rep = H // Hkv  # GQA: q head h reads kv head h // rep — no
@@ -235,20 +239,20 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
             in_specs=[
                 pl.BlockSpec((1, 1, bq, D), _q_tile),
                 pl.BlockSpec((1, 1, bk, D), _kv_tile(rep)),
-                pl.BlockSpec((1, 1, bk, D), _kv_tile(rep)),
+                pl.BlockSpec((1, 1, bk, Dv), _kv_tile(rep)),
             ] + mask_specs,
             out_specs=[
-                pl.BlockSpec((1, 1, bq, D), _q_tile),
+                pl.BlockSpec((1, 1, bq, Dv), _q_tile),
                 pl.BlockSpec((1, 1, 1, bq), _q_row),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bq, 1), jnp.float32),
                 pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, D), jnp.float32),
+                pltpu.VMEM((bq, Dv), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Tq_p, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Tq_p, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, 1, Tq_p), jnp.float32),
         ],
         interpret=interpret,
@@ -348,7 +352,7 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
     q, k, v, out, lse = res
     do = g
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Tk, Dv = k.shape[2], v.shape[3]
     bq, bk = min(block_q, Tq), min(block_k, Tk)
 
     # compact [B,H,Tq] residuals (see _fwd_kernel finalize note)
@@ -364,10 +368,14 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
     Tq_p, Tk_p = q.shape[2], k.shape[2]
     lse, delta = lse[:, :, None], delta[:, :, None]
 
+    # q, k and their gradients are D wide; v, the output's cotangent and dv
+    # Dv wide
     q_spec = pl.BlockSpec((1, 1, bq, D), _q_tile)
-    kv_spec = pl.BlockSpec((1, 1, bk, D), _kv_tile())
+    k_spec = pl.BlockSpec((1, 1, bk, D), _kv_tile())
+    v_spec = pl.BlockSpec((1, 1, bk, Dv), _kv_tile())
+    do_spec = pl.BlockSpec((1, 1, bq, Dv), _q_tile)
     row_spec = pl.BlockSpec((1, 1, 1, bq), _q_row)
-    in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+    in_specs = [q_spec, k_spec, v_spec, do_spec, row_spec, row_spec]
     kernel_kw = dict(sm_scale=sm_scale, causal=causal, block_q=bq, block_k=bk,
                      tq=Tq, tk=Tk, window=window)
 
@@ -393,15 +401,15 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
             num_scalar_prefetch=3,
             grid=(B, H, by_kv.shape[1]),
             in_specs=in_specs,
-            out_specs=[kv_spec, kv_spec],
+            out_specs=[k_spec, v_spec],
             scratch_shapes=[
                 pltpu.VMEM((bk, D), jnp.float32),
-                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, Dv), jnp.float32),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Tk_p, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, Tk_p, D), v.dtype),
+            jax.ShapeDtypeStruct((B, H, Tk_p, Dv), v.dtype),
         ],
         interpret=interpret,
         name=FLASH_BWD_DKV,

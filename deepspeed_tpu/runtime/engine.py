@@ -22,6 +22,7 @@ The reference's micro-step API (``engine(batch)`` → ``engine.backward(loss)``
 
 import contextlib
 import os
+import re
 import time
 from typing import Any, Callable, Dict, Iterator, Optional, Tuple, Union
 
@@ -69,6 +70,48 @@ def _named_scalars(aux) -> Dict[str, jax.Array]:
         return {}
     return {k: v for k, v in aux.items()
             if k != "loss" and isinstance(v, jax.Array) and v.ndim == 0}
+
+
+def _param_deltas(aux) -> Dict[str, jax.Array]:
+    """What a training call asks to be ADDED to parameters after the
+    optimizer's update, ``(loss, {"param_deltas": {flat path: delta}})``:
+    buffers that move by a rule of their own and not by a gradient (the
+    DeepSeek-V3 router's selection bias, ``models/deepseek_v3.py``)."""
+    aux = aux[0] if isinstance(aux, tuple) and len(aux) == 1 else aux
+    return dict(aux.get("param_deltas", {})) if isinstance(aux, dict) else {}
+
+
+def _add_param_deltas(params, deltas):
+    found = set()
+
+    def add(kp, p):
+        name = _flat_name(kp)
+        if name not in deltas:
+            return p
+        found.add(name)
+        return p + deltas[name].astype(p.dtype).reshape(p.shape)
+
+    out = jax.tree_util.tree_map_with_path(add, params)
+    if found != set(deltas):
+        raise KeyError(f"param_deltas name no parameter: "
+                       f"{sorted(set(deltas) - found)}")
+    return out
+
+
+def _freeze_updates(patterns):
+    """An optax transformation that zeroes the updates of every parameter
+    whose path matches one of ``patterns``; last in a chain, it undoes the
+    gradient step and the weight decay alike."""
+    import optax
+
+    def update(updates, state, params=None):
+        del params
+        return jax.tree_util.tree_map_with_path(
+            lambda kp, u: jnp.zeros_like(u)
+            if any(re.search(p, _flat_name(kp)) for p in patterns) else u,
+            updates), state
+
+    return optax.GradientTransformation(lambda _: optax.EmptyState(), update)
 
 
 def _flat_name(kp) -> str:
@@ -238,6 +281,11 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         self.optimizer = None if (self._offload or self._onebit_wire
                                   or self._overlap_lane) \
             else self._build_optimizer()
+        if self.optimizer is None and self._frozen_parameters():
+            raise ValueError(
+                "a model with frozen parameters needs the fused train step: "
+                "offload_optimizer, wire-compressed 1-bit training and "
+                "overlap_grad_sync run optimizers of their own")
         if self._config.sparse_gradients_enabled and (self._offload
                                                       or self._onebit_wire
                                                       or self._overlap_lane):
@@ -600,7 +648,20 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         clip = self._config.gradient_clipping
         if clip and clip > 0:
             tx = optax.chain(optax.clip_by_global_norm(clip), tx)
+        frozen = self._frozen_parameters()
+        if frozen:
+            tx = optax.chain(tx, _freeze_updates(frozen))
         return tx
+
+    def _frozen_parameters(self):
+        """Path patterns of parameters the model declares buffers
+        (``type(model).frozen_parameters(config)``): the optimizer's update
+        of them is zeroed, so neither a gradient step nor weight decay
+        moves them. Most models declare none, and nothing is wrapped."""
+        declare = getattr(type(self.module), "frozen_parameters", None)
+        config = getattr(self.module, "config", None)
+        return list(declare(config)) if declare and config is not None \
+            else []
 
     def _build_monitor(self):
         from ..monitor.monitor import MonitorMaster
@@ -669,9 +730,9 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                 else:
                     loss, aux = self._default_loss(params, batch, rng)
             return (loss.astype(jnp.float32) * scale,
-                    (loss, _named_scalars(aux)))
+                    (loss, _named_scalars(aux), _param_deltas(aux)))
 
-        # grads, (loss, named scalars)
+        # grads, (loss, named scalars, parameter deltas)
         microbatch_grads = jax.grad(compute_loss, has_aux=True)
 
         # named like the kernels (ds_*): XLA calls the module after the
@@ -697,21 +758,23 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
 
                     def body(acc, xs):
                         mb, r = xs
-                        g, (loss, named) = microbatch_grads(
+                        g, (loss, named, deltas) = microbatch_grads(
                             state.params, mb, r, scale, pld_theta, moq_step)
                         acc_g, acc_l = acc
-                        return (jax.tree_util.tree_map(jnp.add, acc_g, g), acc_l + loss), named
+                        return (jax.tree_util.tree_map(jnp.add, acc_g, g),
+                                acc_l + loss), (named, deltas)
 
                     zero_g = jax.tree_util.tree_map(
                         lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
-                    (sum_g, sum_loss), named = jax.lax.scan(
+                    (sum_g, sum_loss), (named, deltas) = jax.lax.scan(
                         body, (zero_g, jnp.float32(0.0)), (batch, rngs))
                     grads = jax.tree_util.tree_map(lambda g: g / gas, sum_g)
                     loss = sum_loss / gas
-                    named = jax.tree_util.tree_map(lambda v: v.mean(0), named)
+                    named, deltas = jax.tree_util.tree_map(
+                        lambda v: v.mean(0), (named, deltas))
                 else:
                     squeezed = jax.tree_util.tree_map(lambda x: x[0], batch)
-                    grads, (loss, named) = microbatch_grads(
+                    grads, (loss, named, deltas) = microbatch_grads(
                         state.params, squeezed, rng, scale, pld_theta,
                         moq_step)
 
@@ -732,6 +795,8 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                 updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
                 new_params = jax.tree_util.tree_map(
                     lambda p, u: p + u.astype(p.dtype), state.params, updates)
+                # buffers the model moves by its own rule (mostly none)
+                new_params = _add_param_deltas(new_params, deltas)
 
                 # skip the whole update on overflow (reference: _take_model_step
                 # engine.py:1889 + CheckOverflow)

@@ -63,14 +63,15 @@ def _scale_kw(scales):
     return dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
 
 
-def _flash(bwd, window, shape=(1, 2048, H, D)):
+def _flash(bwd, window, shape=(1, 2048, H, D), v_dim=None):
     q = (shape, BF16)
+    v = (shape[:3] + (v_dim or shape[3],), BF16)
     fwd = functools.partial(flash_attention, causal=True, interpret=False,
                             window=window)
     if not bwd:
-        return fwd, [q, q, q]
+        return fwd, [q, q, v]
     loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
-    return jax.grad(loss, argnums=(0, 1, 2)), [q, q, q]
+    return jax.grad(loss, argnums=(0, 1, 2)), [q, q, v]
 
 
 def _flash_key_mask():
@@ -139,6 +140,10 @@ CASES = {
     "flash_fwd_bwd_olmoe": lambda: _flash(True, None, (2, 4096, 16, D)),
     # mistral-7b.train.8k: one 8192-token sequence, the 4096 window binds
     "flash_fwd_bwd_train8k": lambda: _flash(True, 4096, (1, 8192, H, D)),
+    # kimi-vl-a3b.train.8k: latent attention, queries and keys 128 + 64
+    # wide, values 128 (Mosaic takes the 192-wide block as it is)
+    "flash_fwd_bwd_mla_train8k": lambda: _flash(True, None, (1, 8192, 16, 192),
+                                                v_dim=128),
     "flash_fwd_key_mask_gqa": _flash_key_mask,
     "ragged_bf16": lambda: _ragged(False, None),
     "ragged_bf16_window": lambda: _ragged(False, 4096),

@@ -57,6 +57,39 @@ def test_forbidden_pairs_raise(config, match):
     _try(config, match)
 
 
+@pytest.mark.parametrize("config", [
+    {**OPT, **OFFLOAD}, WIRE,
+    {**OPT, "zero_optimization": {"stage": 1, "overlap_grad_sync": True}}],
+    ids=["offload_optimizer", "onebit_wire", "overlap_grad_sync"])
+def test_frozen_parameters_need_the_fused_step(config):
+    """A model that declares frozen parameters (DeepseekV3's selection bias)
+    is refused by the lanes that run optimizers of their own."""
+    from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3Config,
+                                                  DeepseekV3ForCausalLM)
+
+    cfg = DeepseekV3Config.tiny()
+    ex = {"input_ids": np.zeros((1, 8), np.int32),
+          "labels": np.zeros((1, 8), np.int32)}
+    with pytest.raises(ValueError, match="frozen parameters"):
+        ds.initialize(model=DeepseekV3ForCausalLM(cfg),
+                      config={"train_batch_size": 8, **config},
+                      example_batch=ex)
+
+
+def test_held_share_under_an_expert_axis_is_not_built():
+    from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3Config,
+                                                  DeepseekV3ForCausalLM)
+    from deepspeed_tpu.parallel import build_mesh
+
+    cfg = DeepseekV3Config.tiny()
+    ex = {"input_ids": np.zeros((1, 8), np.int32),
+          "labels": np.zeros((1, 8), np.int32)}
+    with pytest.raises(NotImplementedError, match="expert"):
+        ds.initialize(model=DeepseekV3ForCausalLM(cfg),
+                      config={"train_batch_size": 8, **OPT},
+                      example_batch=ex, mesh=build_mesh(data=2, expert=4))
+
+
 def test_wire_over_model_axis_rejected():
     cfg = LlamaConfig.tiny(remat=False)
     model = LlamaForCausalLM(cfg)

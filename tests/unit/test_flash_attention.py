@@ -299,3 +299,60 @@ def test_flash_placeholder_rows_forward_and_gradients(tq, tk, bq, bk, window,
     for a, b, name in zip(got, want, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
                                    rtol=5e-5, err_msg=f"d{name}")
+
+
+# -- a value width of its own (latent attention) ------------------------------
+
+def _qkv_two_widths(t, h, d, dv, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (1, t, h, d)),
+            jax.random.normal(ks[1], (1, t, h, d)),
+            jax.random.normal(ks[2], (1, t, h, dv)))
+
+
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
+@pytest.mark.parametrize("t,d,dv,window", [
+    (128, 48, 32, None),    # latent attention's ratio, 192 : 128
+    (96, 24, 40, None),     # values the wider; a ragged tail
+    (128, 48, 32, 40),      # under a window too
+], ids=["wide_keys", "wide_values_ragged", "windowed"])
+def test_flash_with_a_value_width_of_its_own(t, d, dv, window, what):
+    """Queries and keys ``d`` wide, values and the output ``dv``: the
+    kernels (interpret mode) against plain attention, the output and all
+    three gradients, at the scale of the query's width."""
+    q, k, v = _qkv_two_widths(t, 2, d, dv, seed=3)
+    scale = d ** -0.5
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, sm_scale=scale, block_q=32, block_k=32,
+        interpret=True, force_pallas=True, window=window)
+    plain = lambda q, k, v: _reference_attention(q, k, v, True, scale,
+                                                 window=window)
+    if what == "out":
+        got, want = flash(q, k, v), plain(q, k, v)
+        assert got.shape == (1, t, 2, dv)
+    else:
+        i = "qkv".index(what[1])
+        w = jax.random.normal(jax.random.PRNGKey(9), (1, t, 2, dv))
+        got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * w), argnums=i)(
+            q, k, v) for f in (flash, plain))
+        assert got.shape == (q, k, v)[i].shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-4,
+                               rtol=5e-4)
+
+
+@pytest.mark.parametrize("window,digest", [(None, "ff8e37f260a11b44"),
+                                           (40, "8dc581f5cdd5e9b4")])
+def test_equal_widths_trace_the_program_they_always_did(window, digest):
+    """Where values are as wide as keys the forward and both backward calls
+    are, equation for equation, what the kernels were before they told the
+    two widths apart: the digest is of the traced program (the three
+    ``pallas_call``s with their block shapes, scratch and kernel bodies) of
+    PR 31's file at these shapes."""
+    import hashlib
+
+    fwd = functools.partial(flash_attention, causal=True, interpret=True,
+                            window=window, block_q=32, block_k=32)
+    loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
+    x = jnp.zeros((1, 96, 2, 16), jnp.float32)
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest

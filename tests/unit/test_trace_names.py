@@ -20,6 +20,8 @@ import pytest
 
 import deepspeed_tpu as ds
 from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3Config,
+                                              DeepseekV3ForCausalLM)
 from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
 from deepspeed_tpu.ops import pallas as names
 
@@ -29,6 +31,11 @@ TRAIN_SCOPES = {
     "mixtral": ["ds.loss_and_grad", "ds.optimizer", "ds.embed",
                 "ds.attn_proj", "ds.attention", "ds.moe_router",
                 "ds.moe_experts", "ds.lm_head_loss"],
+    # ds.mlp is the leading dense layer, ds.moe_shared the shared experts
+    "deepseek_v3": ["ds.loss_and_grad", "ds.optimizer", "ds.embed",
+                    "ds.attn_proj", "ds.attention", "ds.mlp",
+                    "ds.moe_router", "ds.moe_experts", "ds.moe_shared",
+                    "ds.lm_head_loss"],
 }
 SERVE_SCOPES = ["ds.mixed_step", "ds.embed", "ds.attn_proj", "ds.kv_append",
                 "ds.attention", "ds.mlp", "ds.lm_head", "ds.sample"]
@@ -39,7 +46,9 @@ def train_text():
     out = {}
     for family, model in (
             ("llama", LlamaForCausalLM(LlamaConfig.tiny(sliding_window=16))),
-            ("mixtral", MixtralForCausalLM(MixtralConfig.tiny(remat=True)))):
+            ("mixtral", MixtralForCausalLM(MixtralConfig.tiny(remat=True))),
+            ("deepseek_v3", DeepseekV3ForCausalLM(
+                DeepseekV3Config.tiny(remat=True)))):
         batch = {"input_ids": np.zeros((8, 32), np.int32),
                  "labels": np.zeros((8, 32), np.int32)}
         engine, *_ = ds.initialize(
@@ -72,7 +81,13 @@ def test_jitted_steps_are_named_like_the_kernels(train_text, mixed_text):
     ("mixtral", {"report_expert_load": True},
      ["moe_rows_max_over_mean", "moe_rows_min_over_mean"]),
     ("mixtral", {}, []),
-    ("llama", {}, [])], ids=["mixtral_reporting", "mixtral", "llama"])
+    ("llama", {}, []),
+    ("deepseek_v3", {"report_expert_load": True, "router_experts": 16,
+                     "first_expert": 8},
+     ["moe_held_rows_over_expected", "moe_rows_max_over_mean"]),
+    ("deepseek_v3", {}, [])],
+    ids=["mixtral_reporting", "mixtral", "llama", "deepseek_v3_reporting",
+         "deepseek_v3"])
 def test_moe_load_gauges_are_published_by_name(family, over, gauges):
     """The train engine's registry names how evenly the router spread the
     step's (token, expert) pairs (docs/observability.md), where the model is
@@ -80,11 +95,12 @@ def test_moe_load_gauges_are_published_by_name(family, over, gauges):
     its loss, the fused step hands them back, and the engine sets gauges of
     those names after the compile-carrying first step, where the host has
     waited anyway. Nothing else publishes anything."""
-    cfg = {"llama": LlamaConfig.tiny, "mixtral": MixtralConfig.tiny}[family](
+    cfg = {"llama": LlamaConfig.tiny, "mixtral": MixtralConfig.tiny,
+           "deepseek_v3": DeepseekV3Config.tiny}[family](
         **({"num_local_experts": 8, "remat": True} if family == "mixtral"
            else {}), **over)
-    model = {"llama": LlamaForCausalLM, "mixtral": MixtralForCausalLM}[
-        family](cfg)
+    model = {"llama": LlamaForCausalLM, "mixtral": MixtralForCausalLM,
+             "deepseek_v3": DeepseekV3ForCausalLM}[family](cfg)
     ids = np.random.RandomState(0).randint(0, 128, (8, 32)).astype(np.int32)
     batch = {"input_ids": ids, "labels": ids}
     engine, *_ = ds.initialize(
@@ -96,7 +112,11 @@ def test_moe_load_gauges_are_published_by_name(family, over, gauges):
     found = engine.registry.snapshot()
     # (no expert axis: no moe_chip_rows_max_over_mean either)
     assert sorted(k for k in found if k.startswith("moe_")) == gauges
-    if gauges:
+    if family == "deepseek_v3" and gauges:
+        # 8 of the router's 16 experts are held: about half the pairs
+        assert 0.5 < found["moe_held_rows_over_expected"] < 1.5
+        assert found["moe_rows_max_over_mean"] >= 1.0
+    elif gauges:
         _, sown = model.apply({"params": params}, **batch,
                               mutable=["intermediates"])
         rows = np.sum([np.asarray(v).reshape(-1, 8).sum(0) for v in
