@@ -24,12 +24,24 @@ def resolve_remat_policy(name: str):
     """Activation-checkpoint policy by name (shared by all models so the
     accepted strings cannot drift between model files).
 
+    Under EVERY policy the flash kernel's output and log-sum-exp are kept
+    (the values ``ops/pallas/flash_attention.py`` names ``ds_flash_out`` and
+    ``ds_flash_lse``), as the layer's input always is: they cost two bytes a
+    value of ``[B, T, H, Dv]`` plus four of ``[B, H, T]``, only the forward
+    kernel can produce them, and without them the backward's replay runs
+    that whole kernel again to hand them to the backward kernels. Everything
+    else gets the named policy's answer; where no flash kernel ran (the XLA
+    attention path, whose residual is the ``[H, T, T]`` probabilities) no
+    such name exists and the policy is the plain one.
+
     ``offload_dots_no_batch`` is the CPU-activation-checkpointing analog
     (reference ``activation_checkpointing/checkpointing.py:480``
     ``cpu_checkpointing``): non-batched matmul residuals (the
     ``dots_no_batch`` set) are saved to PINNED HOST memory instead of HBM —
     XLA schedules the device↔host copies, replacing the reference's explicit
     ``.cpu()`` round-trips."""
+    from ..ops.pallas import FLASH_LSE, FLASH_OUT  # ops imports this module
+
     policies = {
         "nothing": jax.checkpoint_policies.nothing_saveable,
         "dots": jax.checkpoint_policies.dots_saveable,
@@ -40,7 +52,18 @@ def resolve_remat_policy(name: str):
     }
     if name not in policies:
         raise ValueError(f"unknown remat_policy {name!r}; one of {sorted(policies)}")
-    return policies[name]
+    base = policies[name]
+    flash_named = jax.checkpoint_policies.save_only_these_names(
+        FLASH_OUT, FLASH_LSE)
+
+    # written out, not save_from_both_policies: that helper refuses the
+    # Offloadable / Recompute answers of the offload policy
+    def policy(prim, *args, **params):
+        if flash_named(prim, *args, **params):
+            return True
+        return base(prim, *args, **params)
+
+    return policy
 
 
 class QuantDense(nn.Module):

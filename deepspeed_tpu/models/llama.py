@@ -88,6 +88,8 @@ class LlamaConfig:
     #   "dots_no_batch" - save only non-batch matmuls (middle ground)
     #   "offload_dots_no_batch" - like dots_no_batch but residuals live in
     #                pinned host memory (CPU activation checkpointing)
+    # Every policy also keeps the flash kernel's output and log-sum-exp, so
+    # the replay never calls the forward kernel (layers.resolve_remat_policy)
     remat_policy: str = "nothing"
     #: >0: training loss runs as a remat'd scan over token chunks of this
     #: size — the [tokens, vocab] logits tensor is never materialized

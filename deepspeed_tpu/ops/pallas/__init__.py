@@ -1,11 +1,17 @@
 """Pallas kernels. Every ``pl.pallas_call`` here passes one of these fixed
 names, so a profiler trace names the kernel the same way after any refactor
-of the code around it (a trace reader matches the strings; nothing imports
-them from outside this package)."""
+of the code around it (a trace reader matches the strings; outside this
+package only ``models/layers.py resolve_remat_policy`` imports any: the two
+checkpoint names)."""
 
 FLASH_FWD = "ds_flash_fwd"
 FLASH_BWD_DQ = "ds_flash_bwd_dq"
 FLASH_BWD_DKV = "ds_flash_bwd_dkv"
+# ``checkpoint_name``s of the two values the flash backward reads that only
+# the forward kernel can produce; ``models/layers.resolve_remat_policy`` keeps
+# them under every policy, so a ``jax.checkpoint`` replay holds no forward call
+FLASH_OUT = "ds_flash_out"
+FLASH_LSE = "ds_flash_lse"
 RAGGED_PAGED_ATTENTION = "ds_ragged_paged_attention"
 DECODE_ATTENTION = "ds_decode_attention"
 PAGED_DECODE_ATTENTION = "ds_paged_decode_attention"

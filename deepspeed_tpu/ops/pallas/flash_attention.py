@@ -32,10 +32,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD
+from . import FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD, FLASH_LSE, FLASH_OUT
 
 NEG_INF = -1e30
 
@@ -434,6 +435,12 @@ def _vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
              window=None):
     out, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k,
                           interpret, window)
+    # named so that a remat policy can keep them (resolve_remat_policy does,
+    # under every policy): q, k, v are cheap to rebuild in jax.checkpoint's
+    # replay, these two cost a whole forward kernel call. Outside a
+    # jax.checkpoint the names are the identity.
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse)
 
 

@@ -299,3 +299,65 @@ def test_mixtral_expert_layer_moves_tokens_not_weights_on_v5e_2x2(
             (kind, dtype, dims)
         assert not (HID in dims and {INTER, INTER // 4} & set(dims)
                     and dims[0] in (E // 4, E)), (kind, dtype, dims)
+
+
+# -- what the remat'd train step keeps of the flash kernel -------------------
+
+def _llama_two_layers():
+    from deepspeed_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    cfg = LlamaConfig.tiny(
+        hidden_size=256, intermediate_size=512, num_attention_heads=2,
+        num_key_value_heads=1, vocab_size=512, max_position_embeddings=1024,
+        sliding_window=512, attention_impl="flash")
+    assert cfg.remat and cfg.scan_layers and cfg.remat_policy == "nothing"
+    return LlamaForCausalLM(cfg)
+
+
+def _deepseek_dense_and_two_expert_layers():
+    from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3Config,
+                                                  DeepseekV3ForCausalLM)
+
+    cfg = DeepseekV3Config.tiny(
+        hidden_size=256, intermediate_size=512, moe_intermediate_size=128,
+        num_attention_heads=2, num_key_value_heads=2, vocab_size=512,
+        max_position_embeddings=1024, kv_lora_rank=128, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, attention_impl="flash",
+        remat=True)
+    assert (cfg.first_k_dense_replace, cfg.num_hidden_layers) == (1, 3)
+    assert cfg.scan_layers and cfg.remat_policy == "nothing"
+    return DeepseekV3ForCausalLM(cfg)
+
+
+@pytest.mark.parametrize("build,sites", [
+    (_llama_two_layers, 1),                       # the forward scan's body
+    (_deepseek_dense_and_two_expert_layers, 2),   # + the unrolled dense layer
+], ids=["llama_scan2", "deepseek_v3_dense1_scan2"])
+def test_remat_train_step_runs_the_flash_forward_once_a_layer(
+        chip, monkeypatch, build, sites):
+    """The gradient of a scanned, remat'd model under the DEFAULT policy,
+    compiled for one v5e: ``ds_flash_fwd`` stands once for each place the
+    forward pass calls it and NOT in the backward scan's replay, because
+    every policy keeps the kernel's named output and log-sum-exp
+    (``layers.resolve_remat_policy``). Before PR 34 the replay held a second
+    instance, a third of the attention time of the 8k cells."""
+    import deepspeed_tpu.ops.pallas.flash_attention as fa
+
+    # the models ask jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=False))
+    model = build()
+    T = 1024
+    ids = jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, BF16, sharding=chip),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, T), jnp.int32)))["params"])
+
+    loss = lambda params, ids: model.apply({"params": params}, ids, labels=ids)
+    hlo = jax.jit(jax.grad(loss)).lower(params, ids).compile().as_text()
+    count = lambda kernel: len(re.findall(
+        rf"^\s*%?{kernel}[.\d]* = .*custom-call", hlo, re.M))
+    assert count("ds_flash_fwd") == sites
+    assert count("ds_flash_bwd_dq") == sites
+    assert count("ds_flash_bwd_dkv") == sites

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.models.layers import resolve_remat_policy
 from deepspeed_tpu.ops.pallas.flash_attention import (
     _reference_attention,
     flash_attention,
@@ -340,19 +341,149 @@ def test_flash_with_a_value_width_of_its_own(t, d, dv, window, what):
                                rtol=5e-4)
 
 
-@pytest.mark.parametrize("window,digest", [(None, "ff8e37f260a11b44"),
-                                           (40, "8dc581f5cdd5e9b4")])
+@pytest.mark.parametrize("window,digest", [(None, "d90a97f21432fc45"),
+                                           (40, "5363675f1dd8a059")])
 def test_equal_widths_trace_the_program_they_always_did(window, digest):
     """Where values are as wide as keys the forward and both backward calls
-    are, equation for equation, what the kernels were before they told the
-    two widths apart: the digest is of the traced program (the three
-    ``pallas_call``s with their block shapes, scratch and kernel bodies) of
-    PR 31's file at these shapes."""
+    are, parameter for parameter, what the kernels were before they told the
+    two widths apart: the digest is of the three ``pallas_call``s of the
+    traced gradient (block shapes, grids, scratch and kernel bodies) at these
+    shapes. PRs 31 to 33 pinned the whole traced text (``ff8e37f2…`` /
+    ``8dc581f5…``); PR 34 put two ``checkpoint_name`` equations around the
+    calls, which renames every variable after them, and pins the calls alone:
+    PR 33's file and PR 34's both give these digests."""
     import hashlib
 
     fwd = functools.partial(flash_attention, causal=True, interpret=True,
                             window=window, block_q=32, block_k=32)
     loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
     x = jnp.zeros((1, 96, 2, 16), jnp.float32)
-    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x))
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 3
+    text = "\n".join(str(sorted((k, str(v)) for k, v in e.params.items()))
+                     for e in calls)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+# -- what jax.checkpoint keeps of the kernel ----------------------------------
+
+_SCAN_T, _SCAN_H = 64, 1
+
+
+def _scanned_layers(policy, d, dv, window):
+    """Two layers in a ``lax.scan``, each the kernel (interpret mode) on
+    scaled operands, the body under ``jax.checkpoint`` with ``policy``
+    (``"plain"``: no ``jax.checkpoint`` at all; None: one with no policy).
+    Returns the function of (q, k, v) -> (loss, outputs) and its operands."""
+    t, h = _SCAN_T, _SCAN_H
+    q, k, v = _qkv_two_widths(t, h, d, dv, seed=5)
+    g = jax.random.normal(jax.random.PRNGKey(6), (1, t, h, dv))
+    scales = jnp.asarray([[1.0, 0.5, 1.0], [0.5, 1.0, 2.0]])
+
+    def layer(c, s, q, k, v):
+        o = flash_attention(q * s[0], k * s[1], v * s[2], causal=True,
+                            block_q=32, block_k=32, force_pallas=True,
+                            window=window)
+        return c + o, o
+
+    if policy != "plain":
+        kw = {} if policy is None else {
+            "policy": resolve_remat_policy(policy)}
+        layer = jax.checkpoint(layer, prevent_cse=False, **kw)
+
+    def f(q, k, v):
+        c, outs = jax.lax.scan(lambda c, s: layer(c, s, q, k, v),
+                               jnp.zeros_like(g), scales)
+        return jnp.sum(c * g), outs
+
+    return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True), (q, k, v)
+
+
+@functools.lru_cache(maxsize=None)
+def _unremat_results(d, dv, window):
+    # numpy COPIES: a cached device array (or a zero-copy view of one) would
+    # stay in jax.live_arrays() for the worker's later test files, and
+    # test_engine.py counts them
+    fn, args = _scanned_layers("plain", d, dv, window)
+    return jax.tree_util.tree_map(np.array, jax.jit(fn)(*args))
+
+
+@pytest.mark.parametrize("policy,fwd_calls", [
+    ("nothing", 1), ("dots", 1), ("dots_no_batch", 1),
+    ("offload_dots_no_batch", 1), (None, 2)])
+def test_every_remat_policy_keeps_the_kernels_output_and_lse(policy,
+                                                             fwd_calls):
+    """Under each of ``resolve_remat_policy``'s four names the gradient of a
+    remat'd scan of layers runs the forward kernel ONCE (in the forward
+    scan, which hands the backward scan the stacked output and log-sum-exp
+    as residuals) and the backward's replay not at all; a bare
+    ``jax.checkpoint`` (no policy: ``gpt2.py``, ``pipe/module.py``) keeps
+    neither and replays the call, as always. Output and all three gradients
+    are the un-remat'd function's bit for bit, at equal widths and at latent
+    attention's, with and without a window."""
+    from deepspeed_tpu.ops.pallas import (FLASH_BWD_DKV, FLASH_BWD_DQ,
+                                          FLASH_FWD, FLASH_LSE, FLASH_OUT)
+
+    t, h = _SCAN_T, _SCAN_H
+    for d, dv, window in [(128, 128, None), (128, 128, 40),
+                          (192, 128, None), (192, 128, 40)]:
+        fn, args = _scanned_layers(policy, d, dv, window)
+        jaxpr = jax.make_jaxpr(fn)(*args)
+        text = str(jaxpr)
+        assert text.count(f"name={FLASH_FWD}") == fwd_calls
+        assert text.count(f"name={FLASH_BWD_DQ}") == 1
+        assert text.count(f"name={FLASH_BWD_DKV}") == 1
+        assert FLASH_OUT in text and FLASH_LSE in text
+        forward_scan = next(e for e in jaxpr.jaxpr.eqns
+                            if e.primitive.name == "scan")
+        stacked = [tuple(v.aval.shape) for v in forward_scan.outvars]
+        kept = fwd_calls == 1
+        assert ((2, 1, h, t) in stacked) == kept                # lse
+        # the stacked outputs [2, 1, t, h, dv] are the scan's own result;
+        # the residual is the kernel's layout, heads before positions
+        assert ((2, 1, h, t, dv) in stacked) == kept
+
+        (loss, outs), grads = jax.jit(fn)(*args)
+        (loss0, outs0), grads0 = _unremat_results(d, dv, window)
+        for name, a, b in [("out", outs, outs0), ("loss", loss, loss0),
+                           *zip(("dq", "dk", "dv"), grads, grads0)]:
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(b),
+                err_msg=f"{name} at d={d} dv={dv} window={window}")
+
+
+@pytest.mark.parametrize("policy", ["nothing", "dots", "dots_no_batch",
+                                    "offload_dots_no_batch"])
+def test_remat_policy_answers_as_its_name_but_for_the_two_names(policy):
+    """``resolve_remat_policy(name)`` is the named JAX policy, equation for
+    equation, except that a value carrying one of the kernel's two names is
+    saved on the device (the offload policy's other answers stay
+    ``Offloadable`` / ``Recompute``)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from deepspeed_tpu.ops.pallas import FLASH_LSE, FLASH_OUT
+
+    name_p = jax.make_jaxpr(lambda x: checkpoint_name(x, "n"))(
+        1.0).eqns[0].primitive
+    base = {
+        "nothing": jax.checkpoint_policies.nothing_saveable,
+        "dots": jax.checkpoint_policies.dots_saveable,
+        "dots_no_batch":
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        "offload_dots_no_batch":
+            jax.checkpoint_policies.offload_dot_with_no_batch_dims(
+                "device", "pinned_host"),
+    }[policy]
+    got = resolve_remat_policy(policy)
+    assert got(name_p, name=FLASH_OUT) is True
+    assert got(name_p, name=FLASH_LSE) is True
+    dot = dict(precision=None, preferred_element_type=None)
+    for prim, params in [
+            (name_p, dict(name="some_other_name")),
+            (jax.lax.dot_general_p, dict(
+                dimension_numbers=(((1,), (0,)), ((), ())), **dot)),
+            (jax.lax.dot_general_p, dict(
+                dimension_numbers=(((2,), (1,)), ((0,), (0,))), **dot)),
+            (jax.lax.exp_p, dict(accuracy=None))]:
+        assert repr(got(prim, **params)) == repr(base(prim, **params))

@@ -81,3 +81,49 @@ def test_mixtral_overfits_fixed_batch():
         if loss < 0.2:
             break
     assert loss < 0.2, f"mixtral loss {loss:.4f} after {step + 1} steps"
+
+
+def remat_loss_and_grads(monkeypatch, model_of, ids):
+    """``(loss, grads)`` of ``model_of(remat)`` under ``remat`` True and
+    False at one set of weights, with ``attention_impl="flash"`` forced to
+    the Pallas kernels (interpret mode; on the CPU the public entry would
+    take the einsum reference)."""
+    import functools
+
+    import deepspeed_tpu.ops.pallas.flash_attention as fa
+
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, force_pallas=True))
+    params = model_of(False).init(jax.random.PRNGKey(0), ids)["params"]
+
+    def run(remat):
+        fn = jax.value_and_grad(lambda params: model_of(remat).apply(
+            {"params": params}, ids, labels=ids))
+        assert "name=ds_flash_fwd" in str(jax.make_jaxpr(fn)(params))
+        return jax.jit(fn)(params)
+
+    return run(True), run(False)
+
+
+def assert_same_loss_and_grads(loss, grads, loss0, grads0):
+    # a replay computes what the forward did, in float32 to the last bits; a
+    # wrong kept value (another layer's, an unsliced one) is wrong by far more
+    np.testing.assert_allclose(float(loss), float(loss0), rtol=1e-6)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7),
+        grads, grads0)
+
+
+def test_llama_remat_reads_the_kept_flash_output_and_lse(monkeypatch):
+    """The scanned, remat'd model's backward reads the flash kernel's KEPT
+    output and log-sum-exp (every policy keeps them) and not a replayed
+    call's: loss and every gradient equal the un-remat'd model's."""
+    from deepspeed_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    ids = np.random.RandomState(0).randint(0, 256, (2, 48))
+    model_of = lambda remat: LlamaForCausalLM(LlamaConfig.tiny(
+        remat=remat, attention_impl="flash", sliding_window=20,
+        flash_block_q=16, flash_block_k=16))
+    (loss, grads), (loss0, grads0) = remat_loss_and_grads(
+        monkeypatch, model_of, ids)
+    assert_same_loss_and_grads(loss, grads, loss0, grads0)

@@ -272,10 +272,26 @@ KERNELS = {
 }
 
 
+#: the two values of the flash forward that every remat policy keeps
+#: (``layers.resolve_remat_policy``): ``checkpoint_name``s, not kernels
+CHECKPOINT_NAMES = {names.FLASH_OUT: "ds_flash_out",
+                    names.FLASH_LSE: "ds_flash_lse"}
+
+
 def test_every_kernel_name_is_listed():
     constants = {v for k, v in vars(names).items()
                  if k.isupper() and isinstance(v, str)}
-    assert constants == set(KERNELS)
+    assert constants == set(KERNELS) | set(CHECKPOINT_NAMES)
+
+
+@pytest.mark.parametrize("constant", sorted(CHECKPOINT_NAMES))
+def test_flash_forward_names_what_the_backward_reads(constant):
+    """A remat policy finds the kernel's output and log-sum-exp by these
+    spellings in the differentiated program."""
+    assert constant == CHECKPOINT_NAMES[constant]
+    fn, args = _flash(True)
+    shapes = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in args]
+    assert f"name[name={constant}]" in str(jax.make_jaxpr(fn)(*shapes))
 
 
 @pytest.mark.parametrize("constant", sorted(KERNELS))
