@@ -4,3 +4,4 @@ from .llama import LlamaConfig, LlamaForCausalLM  # noqa: F401
 from .mixtral import MixtralConfig, MixtralForCausalLM  # noqa: F401
 from .transformer import (TransformerConfig, TransformerForMaskedLM,  # noqa: F401
                           TransformerLMHeadModel)
+from .zaya import ZayaConfig, ZayaForCausalLM  # noqa: F401

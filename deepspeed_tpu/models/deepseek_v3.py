@@ -38,7 +38,11 @@ goes on. The weights' normalisation is over all chosen experts, held or not.
 A share trained ALONE gives its router a partial gradient (the absent
 experts' terms are missing) that starves the held experts within tens of
 steps; ``router_trainable=False`` leaves the router's weights to the
-deployment that sees every expert.
+deployment that sees every expert. What a held share needs besides is
+``mixtral.py``'s, one copy for this file and ``zaya.py``: the check of the
+held range and of the mesh (``_check_held_share``), the balancing rule's
+step from a step's choices (``_balancing_delta``), and the two gauges of
+``report_expert_load`` (``_held_load_gauges``).
 
 Training only: the latent paged cache and absorbed decode are not built.
 """
@@ -51,13 +55,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.topology import get_mesh
 from .layers import (RMSNorm, apply_rotary, cross_entropy_loss,
                      dot_product_attention, head_scope, lm_head_output,
                      model_dense, resolve_remat_policy, rotary_embedding,
                      shift_labels)
 from .llama import LlamaConfig
-from .mixtral import _expert_axis_size, _routed_experts
+from .mixtral import (_balancing_delta, _check_held_share, _held_load_gauges,
+                      _routed_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -161,6 +165,8 @@ BIAS = "e_score_correction_bias"
 
 
 def _check(cfg):
+    """What is not built raises before any parameter is made; the held
+    range and the `expert` mesh axis are ``mixtral._check_held_share``'s."""
     if cfg.q_lora_rank is not None:
         raise NotImplementedError("q_lora_rank: the low-rank query path is "
                                   "not built")
@@ -171,14 +177,8 @@ def _check(cfg):
             cfg.topk_method not in ("noaux_tc", "greedy"):
         raise ValueError(f"scoring_func {cfg.scoring_func!r} / topk_method "
                          f"{cfg.topk_method!r}")
-    if not 0 <= cfg.first_expert <= cfg.router_width - cfg.n_routed_experts:
-        raise ValueError(
-            f"experts {cfg.first_expert}..+{cfg.n_routed_experts} are not "
-            f"among the router's {cfg.router_width}")
-    if _expert_axis_size(get_mesh()) > 1:
-        raise NotImplementedError(
-            "a held share under an `expert` mesh axis is not built: give "
-            "each chip its own first_expert on a mesh without that axis")
+    _check_held_share(cfg.first_expert, cfg.n_routed_experts,
+                      cfg.router_width)
 
 
 def _rotate(x, cos, sin, interleave):
@@ -274,7 +274,8 @@ class DeepseekV3MoE(nn.Module):
     """The expert layer at this chip's share: ``(out [B, T, H], rows [G],
     bias_delta [E] or None)``, ``rows`` the (token, expert) pairs each HELD
     expert computed, ``bias_delta`` what the balancing rule adds to the
-    selection bias after this step."""
+    selection bias after this step (the sign rule over ALL ``E`` columns,
+    held or not: the load it levels is the deployment's, not this chip's)."""
 
     config: DeepseekV3Config
 
@@ -296,10 +297,9 @@ class DeepseekV3MoE(nn.Module):
             topk_w, topk_idx = route(cfg, logits, bias)
             delta = None
             if bias is not None and cfg.router_bias_update_rate:
-                load = jnp.zeros((E,), jnp.float32).at[
-                    topk_idx.reshape(-1)].add(1.0)
-                delta = cfg.router_bias_update_rate * jnp.sign(
-                    jnp.mean(load) - load)
+                # handed to the engine as "param_deltas", never a gradient
+                delta = _balancing_delta(topk_idx, E,
+                                         cfg.router_bias_update_rate)
         # each expert's kernels seeded over its own fan-in
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         w1 = self.param("w1", init, (G, H, I), jnp.float32)  # gate
@@ -431,10 +431,7 @@ class DeepseekV3ForCausalLM(nn.Module):
         # the deployment's level load of this chip: its share of the pairs
         expected = layers * input_ids.size * cfg.num_experts_per_tok \
             * cfg.n_routed_experts / cfg.router_width
-        return loss, {
-            **named,
-            "moe_rows_max_over_mean": jnp.max(rows) / jnp.mean(rows),
-            "moe_held_rows_over_expected": jnp.sum(rows) / expected}
+        return loss, {**named, **_held_load_gauges(rows, expected)}
 
     @staticmethod
     def frozen_parameters(config: "DeepseekV3Config"):

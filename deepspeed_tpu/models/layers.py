@@ -1044,3 +1044,56 @@ def lm_head_output(parent, cfg, hidden, labels, cache, head_bias=False):
     return None, chunked_cross_entropy_loss(hidden, w_out,
                                             shift_labels(labels), bias=bias,
                                             chunk=cfg.loss_chunk)
+
+
+# New helpers go BELOW this line: a Mosaic call's payload holds the line numbers
+# of the frames that call it, so code moved above ``dot_product_attention``
+# changes every flash cell's lowered step and compile-cache key (PERF.md
+# section 6, PR 35).
+
+def apply_rotary_interleaved(x, cos, sin):
+    """GPT-J-style rotate_every_two: pairs are (x[2i], x[2i+1]), not the
+    rotate-half (x[i], x[i+D/2]) convention."""
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    cos = cos[:, :, None, :]
+    sin = sin[:, :, None, :]
+    r1 = x1 * cos - x2 * sin
+    r2 = x1 * sin + x2 * cos
+    return jnp.stack([r1, r2], axis=-1).reshape(x.shape)
+
+
+def apply_rotary_partial(x, cos, sin, rotary_dim, style="half"):
+    """Partial rotary: rotate the first ``rotary_dim`` channels."""
+    rot_fn = apply_rotary if style == "half" else apply_rotary_interleaved
+    if rotary_dim >= x.shape[-1]:
+        return rot_fn(x, cos, sin)
+    rot, rest = x[..., :rotary_dim], x[..., rotary_dim:]
+    return jnp.concatenate([rot_fn(rot, cos, sin), rest], axis=-1)
+
+
+def shift_tokens(x: jnp.ndarray) -> jnp.ndarray:
+    """``x [B, T, ...]`` one token back along the sequence: row ``t`` holds
+    ``x[t - 1]`` and row 0 zeros (never a wrapped row)."""
+    return jnp.pad(x[:, :-1], [(0, 0), (1, 0)] + [(0, 0)] * (x.ndim - 2))
+
+
+def causal_conv(x: jnp.ndarray, weight: jnp.ndarray, bias=None) -> jnp.ndarray:
+    """Causal convolution along the sequence, zeros before position 0:
+    ``y[t] = sum_j weight[j] (x) x[t - (taps - 1) + j] + bias``, as
+    shifted multiply-adds (tap ``taps - 1`` meets the current token).
+
+    ``x [B, T, C]``; ``weight [taps, C]`` is depthwise (one factor a
+    channel), ``weight [taps, G, C/G, C/G]`` grouped (one ``in x out``
+    matrix a group of channels and a tap); ``bias [C]``."""
+    B, T, C = x.shape
+    y, xs = 0, x
+    for j in range(weight.shape[0] - 1, -1, -1):
+        if weight.ndim == 2:
+            y = y + xs * weight[j]
+        else:
+            G = weight.shape[1]
+            y = y + jnp.einsum("btgi,gio->btgo", xs.reshape(B, T, G, C // G),
+                               weight[j]).reshape(B, T, C)
+        if j:
+            xs = shift_tokens(xs)
+    return y if bias is None else y + bias

@@ -607,3 +607,36 @@ class MixtralForCausalLM(nn.Module):
             (r"lm_head/kernel", P(None, "model")),
             (r"(q_norm|k_norm)/scale", P(*L, "model")),
         ]
+
+
+# -- one chip's share of a wider router's experts ---------------------------
+# ``deepseek_v3.py`` and ``zaya.py`` hold ``held`` experts, ``first ..`` of
+# the ``experts`` the router scores, and call ``_routed_experts`` with that
+# ``first``. (At the file's end: a Mosaic call's payload holds the line
+# numbers of the frames above it, PERF.md section 6, PR 35.)
+
+def _check_held_share(first, held, experts):
+    if not 0 <= first <= experts - held:
+        raise ValueError(f"experts {first}..+{held} are not among the "
+                         f"router's {experts}")
+    if _expert_axis_size(get_mesh()) > 1:
+        raise NotImplementedError(
+            "a held share under an `expert` mesh axis is not built: give "
+            "each chip its own first_expert on a mesh without that axis")
+
+
+def _balancing_delta(idx, width, rate):
+    """The sign rule of auxiliary-loss-free balancing: what a step that
+    chose the router columns ``idx`` adds to the selection bias ``[width]``
+    — ``-rate`` where the step sent a column more than the mean number of
+    choices, ``+rate`` where fewer."""
+    load = jnp.zeros((width,), jnp.float32).at[idx.reshape(-1)].add(1.0)
+    return rate * jnp.sign(jnp.mean(load) - load)
+
+
+def _held_load_gauges(rows, expected):
+    """The two registry gauges of a held share from ``rows [G]``, the pairs
+    each held expert computed, and ``expected``, the pairs a level load of
+    the deployment would send to all of them."""
+    return {"moe_rows_max_over_mean": jnp.max(rows) / jnp.mean(rows),
+            "moe_held_rows_over_expected": jnp.sum(rows) / expected}

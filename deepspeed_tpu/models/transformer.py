@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .layers import (cache_attention_bias, cached_attention_xla,
+from .layers import (apply_rotary_partial, cache_attention_bias,
+                     cached_attention_xla,
                      flash_prefill_from_empty,
                      cross_entropy_loss,
                      key_mask_to_bias,
@@ -30,7 +31,6 @@ from .layers import (cache_attention_bias, cached_attention_xla,
                      lm_head_output,
                      init_kv_cache, repeat_kv, resolve_remat_policy,
                      rotary_embedding, shift_labels, update_kv_cache)
-from .layers import apply_rotary as _apply_rotary_full
 
 
 @dataclasses.dataclass(frozen=True)
@@ -174,26 +174,6 @@ def _act(name: str):
     }[name]
 
 
-def _apply_rotary_interleaved(x, cos, sin):
-    """GPT-J-style rotate_every_two: pairs are (x[2i], x[2i+1]), not the
-    rotate-half (x[i], x[i+D/2]) convention."""
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    cos = cos[:, :, None, :]
-    sin = sin[:, :, None, :]
-    r1 = x1 * cos - x2 * sin
-    r2 = x1 * sin + x2 * cos
-    return jnp.stack([r1, r2], axis=-1).reshape(x.shape)
-
-
-def _apply_rotary_partial(x, cos, sin, rotary_dim, style="half"):
-    """Partial rotary: rotate the first ``rotary_dim`` channels."""
-    rot_fn = _apply_rotary_full if style == "half" else _apply_rotary_interleaved
-    if rotary_dim >= x.shape[-1]:
-        return rot_fn(x, cos, sin)
-    rot, rest = x[..., :rotary_dim], x[..., rotary_dim:]
-    return jnp.concatenate([rot_fn(rot, cos, sin), rest], axis=-1)
-
-
 class GenericAttention(nn.Module):
     config: TransformerConfig
 
@@ -211,8 +191,8 @@ class GenericAttention(nn.Module):
         k = dense(Hkv * D, "k_proj", ab)(x).reshape(B, T, Hkv, D)
         v = dense(Hkv * D, "v_proj", ab)(x).reshape(B, T, Hkv, D)
         if cfg.pos_embedding == "rope":
-            q = _apply_rotary_partial(q, cos, sin, cfg.rotary_dim, cfg.rope_style)
-            k = _apply_rotary_partial(k, cos, sin, cfg.rotary_dim, cfg.rope_style)
+            q = apply_rotary_partial(q, cos, sin, cfg.rotary_dim, cfg.rope_style)
+            k = apply_rotary_partial(k, cos, sin, cfg.rotary_dim, cfg.rope_style)
         if layer_cache is not None:
             layer_cache = update_kv_cache(layer_cache, k, v, cache_index)
             if cfg.pallas_decode_eligible(T):

@@ -329,10 +329,24 @@ def _deepseek_dense_and_two_expert_layers():
     return DeepseekV3ForCausalLM(cfg)
 
 
+def _zaya_two_layers():
+    from deepspeed_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
+
+    cfg = ZayaConfig.tiny(
+        hidden_size=256, num_attention_heads=4, num_key_value_heads=2,
+        head_dim_override=128, router_hidden_size=64,
+        moe_intermediate_size=128, n_routed_experts=4, router_experts=8,
+        vocab_size=512, max_position_embeddings=1024, num_hidden_layers=2,
+        attention_impl="flash", remat=True)
+    assert cfg.scan_layers and cfg.remat_policy == "nothing"
+    return ZayaForCausalLM(cfg)
+
+
 @pytest.mark.parametrize("build,sites", [
     (_llama_two_layers, 1),                       # the forward scan's body
     (_deepseek_dense_and_two_expert_layers, 2),   # + the unrolled dense layer
-], ids=["llama_scan2", "deepseek_v3_dense1_scan2"])
+    (_zaya_two_layers, 1),       # unit-length operands, 128 of 256 columns
+], ids=["llama_scan2", "deepseek_v3_dense1_scan2", "zaya_scan2"])
 def test_remat_train_step_runs_the_flash_forward_once_a_layer(
         chip, monkeypatch, build, sites):
     """The gradient of a scanned, remat'd model under the DEFAULT policy,
