@@ -41,8 +41,10 @@ steps; ``router_trainable=False`` leaves the router's weights to the
 deployment that sees every expert. What a held share needs besides is
 ``mixtral.py``'s, one copy for this file and ``zaya.py``: the check of the
 held range and of the mesh (``_check_held_share``), the balancing rule's
-step from a step's choices (``_balancing_delta``), and the two gauges of
-``report_expert_load`` (``_held_load_gauges``).
+step from a step's choices (``_balancing_delta``), the two gauges of
+``report_expert_load`` (``_held_load_gauges``), and the compact row buffer a
+share of at most a quarter runs on (``_compact_rows``; its gauge
+``_compact_hit_gauge``).
 
 Training only: the latent paged cache and absorbed decode are not built.
 """
@@ -60,8 +62,8 @@ from .layers import (RMSNorm, apply_rotary, cross_entropy_loss,
                      model_dense, resolve_remat_policy, rotary_embedding,
                      shift_labels)
 from .llama import LlamaConfig
-from .mixtral import (_balancing_delta, _check_held_share, _held_load_gauges,
-                      _routed_experts)
+from .mixtral import (_balancing_delta, _check_held_share,
+                      _compact_hit_gauge, _held_load_gauges, _routed_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,7 +123,10 @@ class DeepseekV3Config(LlamaConfig):
     #: section 6). A deployment's router sees all its experts
     router_trainable: bool = True
     #: the training call returns ``(loss, {"moe_rows_max_over_mean",
-    #: "moe_held_rows_over_expected"})``, registry gauges of the train engine
+    #: "moe_held_rows_over_expected"})``, registry gauges of the train
+    #: engine, and where the held share is small enough for the compact row
+    #: buffer also ``"moe_compact_hit_share"``: the step's expert layers
+    #: whose held pairs fitted it, over all of them
     report_expert_load: bool = False
 
     @property
@@ -308,7 +313,7 @@ class DeepseekV3MoE(nn.Module):
         with jax.named_scope("ds.moe_experts"):
             out, rows = _routed_experts(
                 x.reshape(-1, H), w1, w2, w3, topk_w.reshape(-1, K),
-                topk_idx.reshape(-1, K), cfg.first_expert)
+                topk_idx.reshape(-1, K), cfg.first_expert, E)
         out = out.reshape(B, T, H)
         if cfg.n_shared_experts:
             out = out + _shared_experts(cfg, x)
@@ -341,10 +346,10 @@ class _ScanBlock(nn.Module):
 
     @nn.compact
     def __call__(self, carry, _):
-        x, cos, sin, mask, rows_sum = carry
+        x, cos, sin, mask = carry
         x, rows, delta = DeepseekV3Block(self.config, name="block")(
             x, cos, sin, mask)
-        return (x, cos, sin, mask, rows_sum + rows), delta
+        return (x, cos, sin, mask), (rows, delta)
 
 
 class DeepseekV3Model(nn.Module):
@@ -352,10 +357,10 @@ class DeepseekV3Model(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, positions=None, attention_mask=None):
-        """``(final-normed hidden [B, T, H], rows [G], bias deltas)``:
-        ``rows`` the pairs each held expert computed, summed over the expert
-        layers; the deltas ``{parameter path: [.., E]}`` of the balancing
-        rule, empty where it is off."""
+        """``(final-normed hidden [B, T, H], rows [L, G], bias deltas)``:
+        ``rows`` the pairs each held expert computed in each of the ``L``
+        expert layers; the deltas ``{parameter path: [.., E]}`` of the
+        balancing rule, empty where it is off."""
         cfg = self.config
         _check(cfg)
         B, T = input_ids.shape
@@ -374,7 +379,7 @@ class DeepseekV3Model(nn.Module):
         remat = lambda cls: nn.remat(cls, prevent_cse=False, policy=policy) \
             if cfg.remat else cls
         first = min(cfg.first_k_dense_replace, cfg.num_hidden_layers)
-        rows = jnp.zeros((cfg.n_routed_experts,), jnp.float32)
+        rows = jnp.zeros((0, cfg.n_routed_experts), jnp.float32)
         deltas = {}
         for i in range(first):
             x, _, _ = remat(DeepseekV3Block)(cfg, dense=True,
@@ -385,14 +390,14 @@ class DeepseekV3Model(nn.Module):
                            split_rngs={"params": True, "dropout": True},
                            length=cfg.num_hidden_layers - first,
                            metadata_params={})
-            (x, *_, rows), delta = scan(cfg, name="layers")(
-                (x, cos, sin, mask, rows), None)
+            (x, *_), (rows, delta) = scan(cfg, name="layers")(
+                (x, cos, sin, mask), None)
             deltas[f"{self.name}/layers/block/mlp/{BIAS}"] = delta
         else:
             for i in range(first, cfg.num_hidden_layers):
                 x, r, delta = remat(DeepseekV3Block)(
                     cfg, name=f"layers_{i}")(x, cos, sin, mask)
-                rows = rows + r
+                rows = jnp.concatenate([rows, r[None]])
                 deltas[f"{self.name}/layers_{i}/mlp/{BIAS}"] = delta
         with jax.named_scope(head_scope(None)):
             x = RMSNorm(eps=cfg.rms_norm_eps, name="norm")(x)
@@ -427,11 +432,13 @@ class DeepseekV3ForCausalLM(nn.Module):
         named = {"param_deltas": deltas} if deltas else {}
         if not cfg.report_expert_load:
             return (loss, named) if named else loss
-        layers = max(cfg.num_hidden_layers - cfg.first_k_dense_replace, 1)
+        pairs = input_ids.size * cfg.num_experts_per_tok      # of one layer
         # the deployment's level load of this chip: its share of the pairs
-        expected = layers * input_ids.size * cfg.num_experts_per_tok \
+        expected = max(rows.shape[0], 1) * pairs \
             * cfg.n_routed_experts / cfg.router_width
-        return loss, {**named, **_held_load_gauges(rows, expected)}
+        return loss, {
+            **named, **_held_load_gauges(jnp.sum(rows, axis=0), expected),
+            **_compact_hit_gauge(rows, pairs, cfg.router_width)}
 
     @staticmethod
     def frozen_parameters(config: "DeepseekV3Config"):

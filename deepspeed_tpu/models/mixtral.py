@@ -315,15 +315,15 @@ def _sorted_experts_bwd(res, g):
 _sorted_experts.defvjp(_sorted_experts_fwd, _sorted_experts_bwd)
 
 
-def _routed_experts(x, w1, w2, w3, topk_w, topk_idx, first):
+def _routed_experts(x, w1, w2, w3, topk_w, topk_idx, first, experts=None):
     """Dropless grouped SwiGLU of tokens ``x [N, H]`` through the experts
     ``first .. first + G`` whose weights are ``w1``/``w3 [G, H, I]`` and
     ``w2 [G, I, H]``: ``(out [N, H], group_sizes [G])``. Pairs routed to
     other experts contribute zero (another shard computes them).
 
     The sorted row buffer is the static worst case, ``N*K`` rows (every
-    pair on these experts); ``group_sizes`` is data, so the products follow
-    the rows routed here and no routing recompiles or drops a token."""
+    pair here), or what ``_sorted_experts_for`` makes of ``G`` of a router's
+    ``experts``; ``group_sizes`` is data: no routing recompiles or drops."""
     N, K = topk_idx.shape
     G, M, dt = w1.shape[0], N * K, x.dtype
     with jax.named_scope("moe_dispatch"):
@@ -336,9 +336,9 @@ def _routed_experts(x, w1, w2, w3, topk_w, topk_idx, first):
             jnp.arange(M, dtype=jnp.int32), unique_indices=True)  # pair -> row
         group_sizes = jnp.sum(key[:, None] == jnp.arange(G)[None, :],
                               axis=0, dtype=jnp.int32)
-    out = _sorted_experts(x, w1.astype(dt), w3.astype(dt), w2.astype(dt),
-                          topk_w.T.astype(jnp.float32), order, inv,
-                          group_sizes)
+    out = _sorted_experts_for(M, G, experts)(
+        x, w1.astype(dt), w3.astype(dt), w2.astype(dt),
+        topk_w.T.astype(jnp.float32), order, inv, group_sizes)
     return out, group_sizes
 
 
@@ -640,3 +640,115 @@ def _held_load_gauges(rows, expected):
     the deployment would send to all of them."""
     return {"moe_rows_max_over_mean": jnp.max(rows) / jnp.mean(rows),
             "moe_held_rows_over_expected": jnp.sum(rows) / expected}
+
+
+# -- a small held share's row buffer follows the rows it holds --------------
+# ``_routed_experts``' stable sort puts the held pairs first, so the layer
+# of a caller that holds few of its router's experts runs on the first ``C``
+# sorted rows; a step whose held pairs do not fit takes the ``N*K``-row
+# buffer, in the same executable. Nothing is dropped either way.
+
+#: The compact buffer's rows over a LEVEL load of the held experts (their
+#: share of the router's pairs). 2 from the load the chip shows: a balancing
+#: rule holds the SUM over kimi 8k's five layers near 0.95 of level, but one
+#: layer's load swings about it — through two 51 s windows the busiest layer
+#: stood at 1.4 of level in the mean and 2.3 and 3.1 at most, and 98.9% and
+#: 98.8% of the layer calls fitted (PERF.md section 6, PR 36). Every pass
+#: under the buffer costs by its rows: a margin of 3 would add about 5 ms to
+#: every step to save the 0.8 ms a step that the overflowing calls cost.
+_COMPACT_MARGIN = 2
+
+
+def _compact_rows(pairs, held, experts):
+    """Rows ``C`` of the compact buffer of a layer that holds ``held`` of
+    its router's ``experts`` and sorts ``pairs`` rows — the margin over the
+    level load, in whole 512-row tiles of the grouped kernel — or None where
+    the layer has no compact buffer: no router width given (every row of a
+    Mixtral layer is a real pair) or a share over a quarter (``C`` would
+    pass half the rows: ZAYA's 8 of 17)."""
+    if experts is None:
+        return None
+    rows = -(-_COMPACT_MARGIN * pairs * held // (experts * 512)) * 512
+    return rows if rows <= pairs // 2 else None
+
+
+def _fits(group_sizes, C):
+    """Whether the held pairs of ``group_sizes [..., G]`` fit the compact
+    buffer: its last row stays a row of no group, the zero row that every
+    pair held elsewhere is gathered from."""
+    return jnp.sum(group_sizes, axis=-1) < C
+
+
+def _sorted_experts_for(pairs, held, experts):
+    """``_sorted_experts``, or the same function of the same arguments over
+    the compact buffer ``_compact_rows`` gives."""
+    C = _compact_rows(pairs, held, experts)
+    return _sorted_experts if C is None else \
+        functools.partial(_compact_experts, C)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _compact_experts(C, x, w1, w3, w2, topk_w, order, inv, group_sizes):
+    """``_sorted_experts`` over the first ``C`` sorted rows where the held
+    pairs fit them, over all ``K * N`` rows where they do not: one
+    ``lax.cond`` in the forward pass and one in the backward pass, both
+    INSIDE the ``custom_vjp``, so no ``cond`` is ever differentiated (a
+    ``lax.switch`` differentiated through under ``jax.checkpoint`` returned
+    zero ``dx`` rows on XLA:TPU, PR 26). The residuals have the compact
+    shape; an overflowing step's backward pass computes its own again."""
+    return _compact_experts_fwd(C, x, w1, w3, w2, topk_w, order, inv,
+                                group_sizes)[0]
+
+
+def _compact_index(C, order, inv):
+    """``(order, inv)`` of the compact buffer: its ``C`` rows' pairs, and
+    each pair's row — a pair held elsewhere sorts past row ``C - 1`` and
+    reads that row, which ``_fits`` keeps a row of zeros."""
+    return order[:C], jnp.minimum(inv, C - 1)
+
+
+def _compact_experts_fwd(C, x, w1, w3, w2, topk_w, order, inv, group_sizes):
+    def run(order, inv):
+        out, res = _sorted_experts_fwd(x, w1, w3, w2, topk_w, order, inv,
+                                       group_sizes)
+        return out, res[0], res[-2], res[-1]         # xs, h1, h3
+
+    def full():     # its backward pass reads none of the three
+        out, xs, h1, h3 = run(order, inv)
+        return out, xs[:C], h1[:C], h3[:C]
+
+    out, xs, h1, h3 = jax.lax.cond(
+        _fits(group_sizes, C),
+        lambda: run(*_compact_index(C, order, inv)), full)
+    return out, (x, xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3)
+
+
+def _compact_experts_bwd(C, res, g):
+    x, xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3 = res
+
+    def compact():
+        return _sorted_experts_bwd(
+            (xs, w1, w3, w2, topk_w, *_compact_index(C, order, inv),
+             group_sizes, h1, h3), g)[:5]
+
+    def full():
+        return _sorted_experts_bwd(_sorted_experts_fwd(
+            x, w1, w3, w2, topk_w, order, inv, group_sizes)[1], g)[:5]
+
+    return (*jax.lax.cond(_fits(group_sizes, C), compact, full),
+            None, None, None)
+
+
+_compact_experts.defvjp(_compact_experts_fwd, _compact_experts_bwd)
+
+
+def _compact_hit_gauge(layer_rows, pairs, experts):
+    """The registry gauge ``moe_compact_hit_share`` from ``layer_rows
+    [L, G]``, the pairs each held expert computed in each of a step's ``L``
+    layer calls of ``pairs`` sorted rows: the calls that took the compact
+    buffer over the calls that have one. No gauge where none has."""
+    C = _compact_rows(pairs, layer_rows.shape[-1], experts)
+    if C is None:
+        return {}
+    return {"moe_compact_hit_share":
+            jnp.mean(_fits(layer_rows, C).astype(jnp.float32))}

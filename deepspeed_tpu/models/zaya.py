@@ -331,7 +331,7 @@ class ZayaMoE(nn.Module):
         with jax.named_scope("ds.moe_experts"):
             out, rows = _routed_experts(
                 h.reshape(-1, H), w1, w2, w3, w.reshape(-1, K),
-                idx.reshape(-1, K), cfg.first_expert)
+                idx.reshape(-1, K), cfg.first_expert, cfg.router_width)
         with jax.named_scope("ds.moe_skip"):
             chose = idx == cfg.router_width - 1
             out = out.reshape(B, T, H) + _skip_expert(

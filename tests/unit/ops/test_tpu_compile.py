@@ -11,6 +11,7 @@ compiled for a ``v5e:2x2`` topology at ``chip_smoke.py``'s widths (H32 /
 Hkv8 / D128, bf16). Nothing runs: this guards layouts, not results.
 """
 
+import dataclasses
 import functools
 import math
 import os
@@ -375,3 +376,126 @@ def test_remat_train_step_runs_the_flash_forward_once_a_layer(
     assert count("ds_flash_fwd") == sites
     assert count("ds_flash_bwd_dq") == sites
     assert count("ds_flash_bwd_dkv") == sites
+
+
+# -- a held share's compact row buffer (PR 36) -------------------------------
+
+def _computations(hlo):
+    """``{computation name: its text}`` of a compiled module's text."""
+    parts = re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", hlo)
+    return {re.match(r"(?:ENTRY )?%([\w.\-]+)", p).group(1): p
+            for p in parts if p.startswith(("%", "ENTRY"))}
+
+
+def test_kimi_expert_layers_run_on_the_compact_buffer_on_one_v5e(chip):
+    """Two scanned, remat'd ``DeepseekV3MoE`` layers at kimi 8k's widths
+    (8,192 tokens, top-6 of 64 experts, 8 of 1408 held), forward and
+    backward, compiled for one v5e: every grouped product over the 49,152
+    worst-case rows stands in a ``conditional``'s FALLBACK branch, and the
+    branch beside it holds the same layer over 12,288 rows — three products
+    in the forward scan, two in the replay (the down projection is no
+    residual), six in the backward pass. At the parent all eleven ran over
+    49,152 rows, whatever the load."""
+    from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3Config,
+                                                  DeepseekV3MoE)
+    from deepspeed_tpu.models.layers import resolve_remat_policy
+    import deepspeed_tpu.models.mixtral as mx
+
+    T, HID, INTER, G, K = 8192, 2048, 1408, 8, 6
+    cfg = DeepseekV3Config.kimi_vl_a3b(n_routed_experts=G, router_experts=64)
+    assert (cfg.hidden_size, cfg.moe_intermediate_size,
+            cfg.num_experts_per_tok) == (HID, INTER, K)
+    full, C = T * K, mx._compact_rows(T * K, G, 64)
+    assert (full, C) == (49152, 12288)
+    layer = DeepseekV3MoE(cfg)
+    x = jax.ShapeDtypeStruct((1, T, HID), BF16, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct((2, *s.shape), BF16, sharding=chip),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, T, HID), BF16)))["params"])
+
+    def loss(params, x):
+        body = lambda x, p: (x + layer.apply({"params": p}, x)[0], None)
+        y, _ = jax.lax.scan(jax.checkpoint(
+            body, prevent_cse=False, policy=resolve_remat_policy("nothing")),
+            x, params)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    comps = _computations(hlo)
+    branches = [re.findall(r"%([\w.\-]+)", pair) for pair in re.findall(
+        r"branch_computations=\{([^}]*)\}", hlo)]
+    assert len(branches) == 3 and all(len(b) == 2 for b in branches)
+    fallback = {b[0] for b in branches}     # index 0: the predicate is false
+    compact = {b[1] for b in branches}
+    rows_of = lambda text: [int(dims.split(",")[0]) for dims in re.findall(
+        r"%ragged-dot-none\S* = \w+\[([\d,]*)\]", text)]
+    counts = {"compact": [], "fallback": []}
+    for name, text in comps.items():
+        rows = [r for r in rows_of(text) if r != G]     # not the dw products
+        if name in fallback:
+            assert rows and set(rows) == {full}, (name, rows)
+            counts["fallback"].append(len(rows_of(text)))
+        elif name in compact:
+            assert rows and set(rows) == {C}, (name, rows)
+            counts["compact"].append(len(rows_of(text)))
+        else:
+            assert not rows_of(text), (name, rows_of(text))
+    assert sorted(counts["compact"]) == [2, 3, 6]
+    # an overflowing step's backward pass computes h1 and h3 again
+    assert sorted(counts["fallback"]) == [2, 3, 8]
+
+
+def _mixtral_two_layers():
+    from deepspeed_tpu.models import MixtralConfig, MixtralForCausalLM
+
+    return MixtralForCausalLM(MixtralConfig.tiny(
+        hidden_size=256, intermediate_size=128, num_attention_heads=2,
+        num_key_value_heads=1, vocab_size=512, max_position_embeddings=1024,
+        attention_impl="flash", remat=True))
+
+
+def _deepseek_eighth_share():
+    model = _deepseek_dense_and_two_expert_layers()
+    return type(model)(dataclasses.replace(
+        model.config, router_experts=64, num_experts_per_tok=6))
+
+
+@pytest.mark.parametrize("build,engages", [
+    (_llama_two_layers, False), (_mixtral_two_layers, False),
+    (_zaya_two_layers, False),
+    (_deepseek_dense_and_two_expert_layers, False),     # 8 of 8
+    (_deepseek_eighth_share, True),                     # 8 of 64
+], ids=["llama", "mixtral", "zaya_4_of_9", "deepseek_v3_8_of_8",
+        "deepseek_v3_8_of_64"])
+def test_compact_buffer_changes_no_other_lowered_gradient(
+        chip, monkeypatch, build, engages):
+    """The gradient of each model family lowered for one v5e, flash kernels
+    in, is the same text with the compact buffer's rule switched off — no
+    ``case`` in it — for every family but a DeepSeek-V3 share of at most a
+    quarter. (Against the PARENT the texts were compared once, at one path:
+    PERF.md section 6, PR 36; a Mosaic payload holds its caller's line
+    numbers, so the rule and its functions stand at ``mixtral.py``'s end.)"""
+    import deepspeed_tpu.models.mixtral as mx
+    import deepspeed_tpu.ops.pallas.flash_attention as fa
+
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=False))
+    model = build()
+    T = 1024
+    ids = jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, BF16, sharding=chip),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, T), jnp.int32)))["params"])
+    loss = lambda params, ids: model.apply({"params": params}, ids, labels=ids)
+    texts = []
+    for rule in (mx._compact_rows, lambda *a: None):
+        monkeypatch.setattr(mx, "_compact_rows", rule)
+        # one line lowers both: a Mosaic payload holds this frame's too
+        texts.append(jax.jit(jax.grad(loss)).lower(params, ids).as_text())
+    with_rule, without = texts
+    assert ("stablehlo.case" in with_rule) == engages
+    assert "stablehlo.case" not in without
+    assert (with_rule == without) == (not engages)

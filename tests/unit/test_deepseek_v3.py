@@ -335,6 +335,71 @@ def test_engine_never_moves_the_bias_and_publishes_the_gauges():
     assert 0 < gauges["moe_held_rows_over_expected"] < 4
 
 
+COMPACT = dict(n_routed_experts=4, router_experts=32, first_expert=8,
+               num_experts_per_tok=3, max_position_embeddings=256,
+               report_expert_load=True)
+
+
+@pytest.mark.parametrize("bias,share", [(0.0, 1.0), (10.0, 0.0)],
+                         ids=["level_load", "every_token_on_the_held"])
+def test_training_call_names_the_compact_hit_share(bias, share):
+    """4 of 32 experts held, 2 x 256 tokens, top-3: a 1,536-row buffer whose
+    compact form has 512 rows (``mixtral._compact_rows``). At the seeded
+    router's load (about 192 held pairs a layer) both expert layers take it;
+    with the selection bias sending every choice to the held experts (1,536
+    pairs) both overflow onto the full buffer, and the gauge says so."""
+    cfg = DeepseekV3Config.tiny(**COMPACT)
+    ids = jnp.asarray(np.random.RandomState(2).randint(0, 128, (2, 256)))
+    model, params = _seeded(cfg, 1, ids)
+    held = jnp.zeros((32,)).at[8:12].set(bias)
+    params = jax.tree_util.tree_map_with_path(
+        lambda kp, p: p + held if dsv3.BIAS in str(kp[-1]) else p, params)
+    loss, named = model.apply({"params": params}, ids, labels=ids)
+    assert sorted(named) == ["moe_compact_hit_share",
+                             "moe_held_rows_over_expected",
+                             "moe_rows_max_over_mean"]
+    assert float(named["moe_compact_hit_share"]) == share
+    level = float(named["moe_held_rows_over_expected"])
+    assert 0.5 < level < 1.5 if share else level == pytest.approx(8.0)
+    assert np.isfinite(float(loss))
+
+
+@pytest.mark.parametrize("family", ["deepseek_v3_half", "zaya", "mixtral"])
+def test_no_compact_hit_share_without_a_compact_buffer(family):
+    """Absent, not zero: a share over a quarter, ZAYA's call and Mixtral's
+    have no layer with a compact buffer."""
+    from deepspeed_tpu.models import MixtralConfig, MixtralForCausalLM
+    from deepspeed_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
+
+    model = {
+        "deepseek_v3_half": lambda: DeepseekV3ForCausalLM(
+            DeepseekV3Config.tiny(**{**COMPACT, "router_experts": 8,
+                                     "first_expert": 4})),
+        "zaya": lambda: ZayaForCausalLM(ZayaConfig.tiny(
+            n_routed_experts=4, router_experts=8, report_expert_load=True,
+            max_position_embeddings=256)),
+        "mixtral": lambda: MixtralForCausalLM(MixtralConfig.tiny(
+            report_expert_load=True, max_position_embeddings=256)),
+    }[family]()
+    ids = jnp.asarray(np.random.RandomState(2).randint(0, 128, (2, 256)))
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    _, named = model.apply({"params": params}, ids, labels=ids)
+    assert "moe_rows_max_over_mean" in named
+    assert "moe_compact_hit_share" not in named
+
+
+def test_compact_hit_gauge_by_hand():
+    from deepspeed_tpu.models.mixtral import _compact_hit_gauge
+
+    # 1,536 pairs, 4 of 32 held: 512 compact rows, the last the zero row
+    rows = jnp.asarray([[100.0, 100, 100, 100], [200, 200, 100, 11],
+                        [200, 200, 100, 12], [0, 0, 0, 0]])
+    assert float(_compact_hit_gauge(rows, 1536, 32)[
+        "moe_compact_hit_share"]) == 0.75
+    assert _compact_hit_gauge(rows, 1536, 8) == {}
+    assert _compact_hit_gauge(rows, 1536, None) == {}
+
+
 def test_router_weights_stay_where_they_were_when_not_trainable():
     """``router_trainable=False``: the gate's gradient exists, the optimizer
     never applies it; every other leaf moves."""

@@ -92,9 +92,14 @@ def test_jitted_steps_are_named_like_the_kernels(train_text, mixed_text):
     ("deepseek_v3", {"report_expert_load": True, "router_experts": 16,
                      "first_expert": 8},
      ["moe_held_rows_over_expected", "moe_rows_max_over_mean"]),
+    ("deepseek_v3", {"report_expert_load": True, "n_routed_experts": 4,
+                     "router_experts": 32, "first_expert": 8,
+                     "num_experts_per_tok": 4},
+     ["moe_compact_hit_share", "moe_held_rows_over_expected",
+      "moe_rows_max_over_mean"]),
     ("deepseek_v3", {}, [])],
     ids=["mixtral_reporting", "mixtral", "llama", "deepseek_v3_reporting",
-         "deepseek_v3"])
+         "deepseek_v3_compact", "deepseek_v3"])
 def test_moe_load_gauges_are_published_by_name(family, over, gauges):
     """The train engine's registry names how evenly the router spread the
     step's (token, expert) pairs (docs/observability.md), where the model is
@@ -125,7 +130,11 @@ def test_moe_load_gauges_are_published_by_name(family, over, gauges):
         # 8 of the router's 16 experts are held: about half the pairs (a
         # tiny random top-1 router is far from level: zaya's reads 0.1-2)
         assert 0 < found["moe_held_rows_over_expected"] < 2.2
-        if family == "deepseek_v3":
+        if "moe_compact_hit_share" in gauges:
+            # 4 of 32 held: 256 tokens x top-4 sort 1,024 rows, the compact
+            # buffer has 512, a level load is 128 a layer
+            assert found["moe_compact_hit_share"] == 1.0
+        elif family == "deepseek_v3":
             assert 0.5 < found["moe_held_rows_over_expected"] < 1.5
         else:
             assert 0 <= found["moe_skip_share"] < 1
