@@ -84,7 +84,7 @@ from ...models.layers import harvest_packed_logits, paged_cache_index
 from ...monitor.perf import (PerfAccounting, estimate_decode_step_bytes,
                              estimate_decode_step_flops, param_bytes,
                              transformer_flops_per_token)
-from ...monitor.tracing import FlightRecorder, Tracer, dump_seq
+from ...monitor.tracing import FlightRecorder, Tracer, dump_seq, versioned
 from ...utils import fault_injection
 from ...utils.logging import log_dist
 from ..engine import InferenceEngine, _sample_logits, next_pow2
@@ -2636,7 +2636,8 @@ class ServingEngine:
 
         # ds_mixed_step: the XLA module takes the function's name, which
         # (unlike the scopes inside, which are metadata) is in the compile
-        # cache's key — a cached executable without the names is not reused
+        # cache's key — a cached executable under other names is not reused
+        @versioned
         def ds_mixed_step(params, pool, tables, ids, token_rows, append_pos,
                           row_start, row_len, chunk_start, context_len,
                           corrupt, rng):

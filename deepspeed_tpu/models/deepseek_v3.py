@@ -330,15 +330,24 @@ class DeepseekV3Block(nn.Module):
     @nn.compact
     def __call__(self, x, cos, sin, mask):
         cfg = self.config
-        h = RMSNorm(eps=cfg.rms_norm_eps, name="input_layernorm")(x)
-        x = x + DeepseekV3Attention(cfg, name="self_attn")(h, cos, sin, mask)
-        h = RMSNorm(eps=cfg.rms_norm_eps, name="post_attention_layernorm")(x)
+        # ds.norm / ds.residual as in models/llama.py LlamaBlock
+        with jax.named_scope("ds.norm"):
+            h = RMSNorm(eps=cfg.rms_norm_eps, name="input_layernorm")(x)
+        attn = DeepseekV3Attention(cfg, name="self_attn")(h, cos, sin, mask)
+        with jax.named_scope("ds.residual"):
+            x = x + attn
+        with jax.named_scope("ds.norm"):
+            h = RMSNorm(eps=cfg.rms_norm_eps,
+                        name="post_attention_layernorm")(x)
         if self.dense:
-            return x + _SwiGLU(cfg, cfg.intermediate_size, "ds.mlp",
-                               name="mlp")(h), \
-                jnp.zeros((cfg.n_routed_experts,), jnp.float32), None
-        out, rows, delta = DeepseekV3MoE(cfg, name="mlp")(h)
-        return x + out, rows.astype(jnp.float32), delta
+            out = _SwiGLU(cfg, cfg.intermediate_size, "ds.mlp",
+                          name="mlp")(h)
+            rows, delta = jnp.zeros((cfg.n_routed_experts,), jnp.float32), None
+        else:
+            out, rows, delta = DeepseekV3MoE(cfg, name="mlp")(h)
+        with jax.named_scope("ds.residual"):
+            x = x + out
+        return x, rows.astype(jnp.float32), delta
 
 
 class _ScanBlock(nn.Module):
@@ -381,24 +390,27 @@ class DeepseekV3Model(nn.Module):
         first = min(cfg.first_k_dense_replace, cfg.num_hidden_layers)
         rows = jnp.zeros((0, cfg.n_routed_experts), jnp.float32)
         deltas = {}
-        for i in range(first):
-            x, _, _ = remat(DeepseekV3Block)(cfg, dense=True,
-                                             name=f"layers_{i}")(x, cos, sin,
-                                                                 mask)
-        if cfg.scan_layers and cfg.num_hidden_layers > first:
-            scan = nn.scan(remat(_ScanBlock), variable_axes={"params": 0},
-                           split_rngs={"params": True, "dropout": True},
-                           length=cfg.num_hidden_layers - first,
-                           metadata_params={})
-            (x, *_), (rows, delta) = scan(cfg, name="layers")(
-                (x, cos, sin, mask), None)
-            deltas[f"{self.name}/layers/block/mlp/{BIAS}"] = delta
-        else:
-            for i in range(first, cfg.num_hidden_layers):
-                x, r, delta = remat(DeepseekV3Block)(
-                    cfg, name=f"layers_{i}")(x, cos, sin, mask)
-                rows = jnp.concatenate([rows, r[None]])
-                deltas[f"{self.name}/layers_{i}/mlp/{BIAS}"] = delta
+        # ds.layer_stack: what the loop over the layers costs beyond what
+        # the layers' own scopes name (models/llama.py LlamaModel)
+        with jax.named_scope("ds.layer_stack"):
+            for i in range(first):
+                x, _, _ = remat(DeepseekV3Block)(cfg, dense=True,
+                                                 name=f"layers_{i}")(x, cos, sin,
+                                                                     mask)
+            if cfg.scan_layers and cfg.num_hidden_layers > first:
+                scan = nn.scan(remat(_ScanBlock), variable_axes={"params": 0},
+                               split_rngs={"params": True, "dropout": True},
+                               length=cfg.num_hidden_layers - first,
+                               metadata_params={})
+                (x, *_), (rows, delta) = scan(cfg, name="layers")(
+                    (x, cos, sin, mask), None)
+                deltas[f"{self.name}/layers/block/mlp/{BIAS}"] = delta
+            else:
+                for i in range(first, cfg.num_hidden_layers):
+                    x, r, delta = remat(DeepseekV3Block)(
+                        cfg, name=f"layers_{i}")(x, cos, sin, mask)
+                    rows = jnp.concatenate([rows, r[None]])
+                    deltas[f"{self.name}/layers_{i}/mlp/{BIAS}"] = delta
         with jax.named_scope(head_scope(None)):
             x = RMSNorm(eps=cfg.rms_norm_eps, name="norm")(x)
         return x, rows, {k: jax.lax.stop_gradient(v)

@@ -357,12 +357,21 @@ class LlamaBlock(nn.Module):
     def __call__(self, x, cos, sin, mask, layer_cache=None, cache_index=None,
                  deterministic=True):
         cfg = self.config
-        h = RMSNorm(eps=cfg.rms_norm_eps, name="input_layernorm")(x)
+        # ds.norm / ds.residual name the block's own element-wise passes at
+        # their call sites: a norm inside a projection's or the head's scope
+        # stays that scope's
+        with jax.named_scope("ds.norm"):
+            h = RMSNorm(eps=cfg.rms_norm_eps, name="input_layernorm")(x)
         attn, layer_cache = LlamaAttention(cfg, name="self_attn")(
             h, cos, sin, mask, layer_cache, cache_index, deterministic)
-        x = x + attn
-        h = RMSNorm(eps=cfg.rms_norm_eps, name="post_attention_layernorm")(x)
-        x = x + LlamaMLP(cfg, name="mlp")(h)
+        with jax.named_scope("ds.residual"):
+            x = x + attn
+        with jax.named_scope("ds.norm"):
+            h = RMSNorm(eps=cfg.rms_norm_eps,
+                        name="post_attention_layernorm")(x)
+        out = LlamaMLP(cfg, name="mlp")(h)
+        with jax.named_scope("ds.residual"):
+            x = x + out
         return x, layer_cache
 
 
@@ -440,35 +449,40 @@ class LlamaModel(nn.Module):
                                  0.0).astype(x.dtype)
 
         remat_policy = resolve_remat_policy(cfg.remat_policy)
-        if cfg.scan_layers:
-            block_cls = _ScanBlock
-            if cfg.remat and cache is None:
-                block_cls = nn.remat(
-                    _ScanBlock, static_argnums=(),
-                    prevent_cse=False,
-                    policy=remat_policy)
-            scan = nn.scan(block_cls, variable_axes={"params": 0},
-                           split_rngs={"params": True, "dropout": True},
-                           length=cfg.num_hidden_layers, metadata_params={})
-            (x, *_), cache = scan(cfg, name="layers")(
-                (x, cos, sin, mask, cache_index, deterministic),
-                (cache, pld_gate))
-        else:
-            block_cls = nn.remat(LlamaBlock, prevent_cse=False, policy=remat_policy) \
-                if (cfg.remat and cache is None) else LlamaBlock
-            new_cache = [] if cache is not None else None
-            for i in range(cfg.num_hidden_layers):
-                layer_cache = None if cache is None else \
-                    jax.tree_util.tree_map(lambda c: c[i], cache)
-                x_in = x
-                x, layer_cache = block_cls(cfg, name=f"layers_{i}")(
-                    x, cos, sin, mask, layer_cache, cache_index, deterministic)
-                if pld_gate is not None:
-                    x = x_in + pld_gate[i] * (x - x_in)
+        # ds.layer_stack: what the loop over the layers costs beyond what the
+        # layers' own scopes name — under nn.scan each layer's weights
+        # sliced out of the stacked tree (and again in the backward pass),
+        # its gradients and kept values written back into it
+        with jax.named_scope("ds.layer_stack"):
+            if cfg.scan_layers:
+                block_cls = _ScanBlock
+                if cfg.remat and cache is None:
+                    block_cls = nn.remat(
+                        _ScanBlock, static_argnums=(),
+                        prevent_cse=False,
+                        policy=remat_policy)
+                scan = nn.scan(block_cls, variable_axes={"params": 0},
+                               split_rngs={"params": True, "dropout": True},
+                               length=cfg.num_hidden_layers, metadata_params={})
+                (x, *_), cache = scan(cfg, name="layers")(
+                    (x, cos, sin, mask, cache_index, deterministic),
+                    (cache, pld_gate))
+            else:
+                block_cls = nn.remat(LlamaBlock, prevent_cse=False, policy=remat_policy) \
+                    if (cfg.remat and cache is None) else LlamaBlock
+                new_cache = [] if cache is not None else None
+                for i in range(cfg.num_hidden_layers):
+                    layer_cache = None if cache is None else \
+                        jax.tree_util.tree_map(lambda c: c[i], cache)
+                    x_in = x
+                    x, layer_cache = block_cls(cfg, name=f"layers_{i}")(
+                        x, cos, sin, mask, layer_cache, cache_index, deterministic)
+                    if pld_gate is not None:
+                        x = x_in + pld_gate[i] * (x - x_in)
+                    if new_cache is not None:
+                        new_cache.append(layer_cache)
                 if new_cache is not None:
-                    new_cache.append(layer_cache)
-            if new_cache is not None:
-                cache = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *new_cache)
+                    cache = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *new_cache)
         with jax.named_scope(head_scope(cache)):
             x = RMSNorm(eps=cfg.rms_norm_eps, name="norm")(x)
         return x if cache is None else (x, cache)

@@ -445,14 +445,21 @@ class MixtralBlock(nn.Module):
     def __call__(self, x, cos, sin, mask, token_mask=None, layer_cache=None,
                  cache_index=None, deterministic=True):
         cfg = self.config
-        h = RMSNorm(eps=cfg.rms_norm_eps, name="input_layernorm")(x)
+        # ds.norm / ds.residual as in models/llama.py LlamaBlock
+        with jax.named_scope("ds.norm"):
+            h = RMSNorm(eps=cfg.rms_norm_eps, name="input_layernorm")(x)
         attn, layer_cache = LlamaAttention(cfg, name="self_attn")(
             h, cos, sin, mask, layer_cache, cache_index, deterministic)
-        x = x + attn
-        h = RMSNorm(eps=cfg.rms_norm_eps, name="post_attention_layernorm")(x)
+        with jax.named_scope("ds.residual"):
+            x = x + attn
+        with jax.named_scope("ds.norm"):
+            h = RMSNorm(eps=cfg.rms_norm_eps,
+                        name="post_attention_layernorm")(x)
         moe_out, frac, prob = MixtralSparseMoeBlock(
             cfg, name="block_sparse_moe")(h, token_mask)
-        return x + moe_out, layer_cache, frac, prob
+        with jax.named_scope("ds.residual"):
+            x = x + moe_out
+        return x, layer_cache, frac, prob
 
 
 class _ScanBlock(nn.Module):
@@ -496,36 +503,39 @@ class MixtralModel(nn.Module):
         E = cfg.num_local_experts
         zero_e = jnp.zeros((E,), jnp.float32)
         remat_policy = resolve_remat_policy(cfg.remat_policy)
-        if cfg.scan_layers:
-            block_cls = _ScanBlock
-            if cfg.remat and cache is None:
-                block_cls = nn.remat(_ScanBlock, prevent_cse=False,
-                                     policy=remat_policy)
-            scan = nn.scan(block_cls,
-                           variable_axes={"params": 0, "intermediates": 0},
-                           split_rngs={"params": True, "dropout": True},
-                           length=cfg.num_hidden_layers, metadata_params={})
-            (x, *_, frac_sum, prob_sum), cache = scan(cfg, name="layers")(
-                (x, cos, sin, mask, tok_mask, cache_index, deterministic,
-                 zero_e, zero_e), cache)
-        else:
-            block_cls = nn.remat(MixtralBlock, prevent_cse=False,
-                                 policy=remat_policy) \
-                if (cfg.remat and cache is None) else MixtralBlock
-            frac_sum, prob_sum = zero_e, zero_e
-            new_cache = [] if cache is not None else None
-            for i in range(cfg.num_hidden_layers):
-                layer_cache = None if cache is None else \
-                    jax.tree_util.tree_map(lambda c: c[i], cache)
-                x, layer_cache, frac, prob = block_cls(cfg, name=f"layers_{i}")(
-                    x, cos, sin, mask, tok_mask, layer_cache, cache_index,
-                    deterministic)
-                frac_sum, prob_sum = frac_sum + frac, prob_sum + prob
+        # ds.layer_stack: what the loop over the layers costs beyond what
+        # the layers' own scopes name (models/llama.py LlamaModel)
+        with jax.named_scope("ds.layer_stack"):
+            if cfg.scan_layers:
+                block_cls = _ScanBlock
+                if cfg.remat and cache is None:
+                    block_cls = nn.remat(_ScanBlock, prevent_cse=False,
+                                         policy=remat_policy)
+                scan = nn.scan(block_cls,
+                               variable_axes={"params": 0, "intermediates": 0},
+                               split_rngs={"params": True, "dropout": True},
+                               length=cfg.num_hidden_layers, metadata_params={})
+                (x, *_, frac_sum, prob_sum), cache = scan(cfg, name="layers")(
+                    (x, cos, sin, mask, tok_mask, cache_index, deterministic,
+                     zero_e, zero_e), cache)
+            else:
+                block_cls = nn.remat(MixtralBlock, prevent_cse=False,
+                                     policy=remat_policy) \
+                    if (cfg.remat and cache is None) else MixtralBlock
+                frac_sum, prob_sum = zero_e, zero_e
+                new_cache = [] if cache is not None else None
+                for i in range(cfg.num_hidden_layers):
+                    layer_cache = None if cache is None else \
+                        jax.tree_util.tree_map(lambda c: c[i], cache)
+                    x, layer_cache, frac, prob = block_cls(cfg, name=f"layers_{i}")(
+                        x, cos, sin, mask, tok_mask, layer_cache, cache_index,
+                        deterministic)
+                    frac_sum, prob_sum = frac_sum + frac, prob_sum + prob
+                    if new_cache is not None:
+                        new_cache.append(layer_cache)
                 if new_cache is not None:
-                    new_cache.append(layer_cache)
-            if new_cache is not None:
-                cache = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls),
-                                               *new_cache)
+                    cache = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls),
+                                                   *new_cache)
         with jax.named_scope(head_scope(cache)):
             x = RMSNorm(eps=cfg.rms_norm_eps, name="norm")(x)
         # HF load_balancing_loss_func: means over ALL layers' tokens

@@ -366,13 +366,19 @@ class ZayaBlock(nn.Module):
     @nn.compact
     def __call__(self, x, state, cos, sin, mask):
         cfg = self.config
-        h = RMSNorm(eps=cfg.rms_norm_eps, name="input_layernorm")(x)
-        x = ScaledResidual(name="attn_residual")(
-            x, ZayaAttention(cfg, name="self_attn")(h, cos, sin, mask))
-        h = RMSNorm(eps=cfg.rms_norm_eps, name="post_attention_layernorm")(x)
+        # ds.norm / ds.residual as in models/llama.py LlamaBlock
+        with jax.named_scope("ds.norm"):
+            h = RMSNorm(eps=cfg.rms_norm_eps, name="input_layernorm")(x)
+        attn = ZayaAttention(cfg, name="self_attn")(h, cos, sin, mask)
+        with jax.named_scope("ds.residual"):
+            x = ScaledResidual(name="attn_residual")(x, attn)
+        with jax.named_scope("ds.norm"):
+            h = RMSNorm(eps=cfg.rms_norm_eps,
+                        name="post_attention_layernorm")(x)
         out, state, rows, skipped, delta = ZayaMoE(cfg, name="mlp")(h, state)
-        return ScaledResidual(name="mlp_residual")(x, out), state, rows, \
-            skipped, delta
+        with jax.named_scope("ds.residual"):
+            x = ScaledResidual(name="mlp_residual")(x, out)
+        return x, state, rows, skipped, delta
 
 
 class _ScanBlock(nn.Module):
@@ -419,19 +425,22 @@ class ZayaModel(nn.Module):
         skipped = jnp.float32(0.0)
         deltas = {}
         path = f"mlp/router/{BIAS}"
-        if cfg.scan_layers:
-            scan = nn.scan(remat(_ScanBlock), variable_axes={"params": 0},
-                           split_rngs={"params": True, "dropout": True},
-                           length=cfg.num_hidden_layers, metadata_params={})
-            (x, _, _, _, _, rows, skipped), delta = scan(cfg, name="layers")(
-                (x, state, cos, sin, mask, rows, skipped), None)
-            deltas[f"{self.name}/layers/block/{path}"] = delta
-        else:
-            for i in range(cfg.num_hidden_layers):
-                x, state, r, s, delta = remat(ZayaBlock)(
-                    cfg, name=f"layers_{i}")(x, state, cos, sin, mask)
-                rows, skipped = rows + r, skipped + s
-                deltas[f"{self.name}/layers_{i}/{path}"] = delta
+        # ds.layer_stack: what the loop over the layers costs beyond what
+        # the layers' own scopes name (models/llama.py LlamaModel)
+        with jax.named_scope("ds.layer_stack"):
+            if cfg.scan_layers:
+                scan = nn.scan(remat(_ScanBlock), variable_axes={"params": 0},
+                               split_rngs={"params": True, "dropout": True},
+                               length=cfg.num_hidden_layers, metadata_params={})
+                (x, _, _, _, _, rows, skipped), delta = scan(cfg, name="layers")(
+                    (x, state, cos, sin, mask, rows, skipped), None)
+                deltas[f"{self.name}/layers/block/{path}"] = delta
+            else:
+                for i in range(cfg.num_hidden_layers):
+                    x, state, r, s, delta = remat(ZayaBlock)(
+                        cfg, name=f"layers_{i}")(x, state, cos, sin, mask)
+                    rows, skipped = rows + r, skipped + s
+                    deltas[f"{self.name}/layers_{i}/{path}"] = delta
         with jax.named_scope(head_scope(None)):
             x = RMSNorm(eps=cfg.rms_norm_eps, name="norm")(x)
         return x, rows, skipped, {k: jax.lax.stop_gradient(v)

@@ -95,6 +95,26 @@ def validate_event(ev: Any) -> Optional[str]:
 #: names, so old dumps and tools/trace_view.py parse unchanged)
 PROFILER_PREFIX = "ds."
 
+#: version of the names a trace shows (``ds.*`` scopes and spans). Scopes are
+#: metadata and the persistent compile cache's key strips metadata: an
+#: executable cached before a name existed would be read back without it.
+#: ``versioned`` puts this number into each jitted step's module name, which
+#: the key does hold. Raise it with every added or renamed name
+#: (tests/unit/test_trace_names.py pins it beside a digest of the names).
+NAMES_VERSION = 2
+
+
+def versioned(step):
+    """``step`` named ``<name>_n<NAMES_VERSION>``: ``jax.jit`` calls the XLA
+    module after the function it is given."""
+    step.__name__ = f"{step.__name__}_n{NAMES_VERSION}"
+    return step
+
+
+def profiler_recording() -> bool:
+    """Whether a profiler session would record a span opened now."""
+    return TraceAnnotation.is_enabled()
+
 
 class _Span:
     """One open span: a profiler annotation ``ds.<name>`` and, with the

@@ -20,6 +20,7 @@ The reference's micro-step API (``engine(batch)`` → ``engine.backward(loss)``
 ``engine.py:1729,1889``).
 """
 
+import collections
 import contextlib
 import os
 import re
@@ -33,6 +34,8 @@ from flax import struct
 from jax.sharding import NamedSharding, PartitionSpec
 
 from .. import comm as dist
+from ..monitor.tracing import (ENV_TRACE_DIR, FlightRecorder, Tracer,
+                               profiler_recording, versioned)
 from ..parallel.topology import (BATCH_AXES, SEQ_AXIS, MeshTopology, build_mesh,
                                  get_mesh, set_mesh)
 from ..utils.logging import log_dist, logger
@@ -428,6 +431,8 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                                             PartitionSpec(None, self._batch_axes))
         self._batch_seq_sharding = NamedSharding(
             mesh, PartitionSpec(None, self._batch_axes, SEQ_AXIS))
+        # (every jitted step's module name carries the trace names' version:
+        # ``tracing.versioned``)
         if self._offload:
             self._train_step = None
             self._grad_step = self._compile_grad_step()
@@ -451,7 +456,7 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                 opt_state=ob_shardings)
             self._train_step_fn = step_fn
             self._train_step = jax.jit(
-                step_fn,
+                versioned(step_fn),
                 in_shardings=(self.state_shardings, None, self._replicated),
                 out_shardings=(self.state_shardings, self._replicated,
                                self._replicated),
@@ -469,7 +474,7 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                 opt_state=ov_shardings)
             self._train_step_fn = step_fn
             self._train_step = jax.jit(
-                step_fn,
+                versioned(step_fn),
                 in_shardings=(self.state_shardings, None, self._replicated),
                 out_shardings=(self.state_shardings,
                                (self._replicated,) * 3,
@@ -485,7 +490,7 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             self._train_step_fn = step_fn
             self._sparse_skip_mark = 0  # stall guard, see train_batch
             self._train_step = jax.jit(
-                step_fn,
+                versioned(step_fn),
                 in_shardings=(self.state_shardings, None, self._replicated),
                 out_shardings=(self.state_shardings,
                                (self._replicated,) * 3,
@@ -510,8 +515,6 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         # monitor backend through MonitorMaster.write_registry.
         from ..monitor.perf import PerfAccounting
         from ..monitor.registry import MetricsRegistry
-        from ..monitor.tracing import (ENV_TRACE_DIR, FlightRecorder,
-                                       Tracer)
 
         self.registry = MetricsRegistry()
         self._step_hist = self.registry.histogram("train_batch_s",
@@ -557,9 +560,15 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         # micro-step parity API state
         self._pending_microbatches = []
         self._last_loss = None
-        #: the last fused step's named scalars (``_named_scalars``), on the
-        #: device until ``_publish_named_scalars`` fetches them
-        self._step_scalars = {}
+        #: dispatched steps whose named scalars (``_named_scalars``) nobody
+        #: has fetched yet, oldest first: ``(step, loss, {name: scalar})``,
+        #: all still on the device. ``_drain_counters`` publishes them; a
+        #: step enters only while something listens (``_train_batch``)
+        self._counter_queue = collections.deque()
+        self._counters_dropped = 0
+        #: (host time, step) of the newest publication: two of them give
+        #: the rate at which steps complete (the train_mfu gauge)
+        self._published = None
 
         # ---- elastic-agent contract (elasticity/elastic_agent.py) ------
         # under the agent, auto-save periodically into its checkpoint dir
@@ -699,9 +708,13 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             # shard_map region: casting a TP-sharded param before entering a
             # partial-manual shard_map crashes the XLA SPMD partitioner.
             if not getattr(loss_fn, "casts_params", False):
-                params = jax.tree_util.tree_map(
-                    lambda p: p.astype(compute_dtype)
-                    if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
+                # the master weights read once a step; the backward's cast
+                # of the gradients carries the same name
+                with jax.named_scope("ds.param_cast"):
+                    params = jax.tree_util.tree_map(
+                        lambda p: p.astype(compute_dtype)
+                        if jnp.issubdtype(p.dtype, jnp.floating) else p,
+                        params)
             if moq is not None and moq_step is not None:
                 # MoQ: the COMPUTE weights are fake-quantized on the
                 # progressive schedule; fp32 masters stay full precision
@@ -738,7 +751,9 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         # named like the kernels (ds_*): XLA calls the module after the
         # function, and the module's name — unlike the scopes inside it,
         # which are metadata — is part of the compile cache's key, so an
-        # executable cached before the scopes existed is never reused
+        # executable cached under other names is never reused
+        # (``tracing.versioned`` adds the names' version)
+        @versioned
         def ds_train_step(state: TrainState, batch, rng):
             # trace-time side effect: runs once per XLA compile (the
             # compiled-program registry's compile count)
@@ -846,6 +861,7 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
 
         grad_fn = jax.grad(compute_loss, has_aux=True)
 
+        @versioned
         def ds_grad_step(params, batch, rng, scale):
             self.perf.note_compile("grad_step")
             with jax.named_scope("ds.loss_and_grad"):
@@ -957,6 +973,10 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
     def _train_batch(self, data_iter, batch, step: int):
         tr = self.tracer
         t_batch0 = time.perf_counter()
+        monitoring = self.monitor is not None and self.monitor.enabled
+        printing = self._config.steps_per_print and \
+            (step + 1) % self._config.steps_per_print == 0
+        reporting = bool(monitoring or printing)
         if batch is None:
             if data_iter is None:
                 raise ValueError("train_batch needs a batch or a data iterator")
@@ -1052,14 +1072,19 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             with compiling, tr.span(
                     "dispatch", cat="train", ring="train_step",
                     args={"step": step, "program": "train_step"}):
-                self.state, (loss, self._last_grad_norm,
-                             self._step_scalars), overflow = \
+                self.state, (loss, self._last_grad_norm, named), overflow = \
                     self._train_step(self.state, batch, step_rng)
+            # the step's named scalars wait on the device for a later call
+            # to publish them — only while something listens: the ring, a
+            # monitor, a progress line, or a recording profiler
+            if not warm or reporting or tr.enabled or profiler_recording():
+                self._queue_counters(step, loss, named)
+            if self._counter_queue:
+                # after the dispatch: the device has work again, so the
+                # fetch hides under it. The compile-carrying call has just
+                # waited seconds: one step's wait is the cheapest there is
+                self._drain_counters(wait=not warm)
             if not warm:
-                # the host has just waited seconds for the compile: one
-                # step's wait is the cheapest fetch there is
-                self._publish_named_scalars()
-
                 # once, after the compile-carrying first call: the cached
                 # lowering yields the cost model without a second trace;
                 # the jaxpr-walk flops profiler is the fallback
@@ -1095,30 +1120,13 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         self.tput_timer.stop()
         if self.wall_clock_breakdown:
             self.timers("train_batch").stop()
-        dt_batch = time.perf_counter() - t_batch0
-        self._step_hist.observe(dt_batch)
-        if not self._offload and \
-                self.perf.programs.program("train_step").cost_source \
-                is not None and self.global_steps > 1:
-            # MFU over the train_batch wall clock: in steady state the
-            # async dispatch backpressures on the previous step, so wall
-            # time per batch ≈ device time per step; the compile-carrying
-            # first step is excluded (first-beat rule)
-            vals = self.perf.on_program_step("train_step", dt_batch)
-            if vals["mfu"] is not None:
-                self.registry.gauge("train_mfu").set(vals["mfu"])
-            if vals["flops_per_sec"]:
-                self.registry.gauge("train_tflops_per_chip").set(
-                    vals["flops_per_sec"] / 1e12 / self.perf.n_devices)
+        self._step_hist.observe(time.perf_counter() - t_batch0)
 
-        monitoring = self.monitor is not None and self.monitor.enabled
-        printing = self._config.steps_per_print and \
-            self.global_steps % self._config.steps_per_print == 0
-        if monitoring or printing:
+        if reporting:
             # both fetch the loss, so this span is the host's wait on
             # the device when either is on
             with tr.span("report", cat="host", args={"step": step}):
-                self._publish_named_scalars()
+                self._drain_counters(wait=True)
                 if monitoring:
                     self._write_monitor(loss)
                 if printing:
@@ -1278,11 +1286,66 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
     # monitoring
     # ------------------------------------------------------------------
 
-    def _publish_named_scalars(self):
-        """Registry gauges of the last step's named scalars. It FETCHES, so
-        it is called only where the host waits for the device anyway."""
-        for name, value in self._step_scalars.items():
-            self.registry.gauge(name).set(float(value))
+    #: steps ``_counter_queue`` holds at most; beyond it the oldest goes
+    #: unpublished and the next ``counters`` span says how many did
+    COUNTER_QUEUE_BOUND = 64
+
+    def _queue_counters(self, step, loss, named):
+        queue = self._counter_queue
+        queue.append((step, loss, named))
+        if len(queue) > self.COUNTER_QUEUE_BOUND:
+            queue.popleft()
+            self._counters_dropped += 1
+
+    def _drain_counters(self, wait: bool = False):
+        """Publish the queued steps the device has finished (all of them
+        with ``wait``, which blocks: only where the host waits anyway): one
+        ``device_get`` for their named scalars, then per step a ``counters``
+        span that carries them (``ds.counters`` on the profiler's clock, a
+        ring event) and registry gauges of their names. Steps complete in
+        order, so the first loss that is not ready ends the search."""
+        queue = self._counter_queue
+        n = len(queue)
+        if not wait:
+            n = next((i for i, (_, loss, _) in enumerate(queue)
+                      if not loss.is_ready()), n)
+        if not n:
+            return
+        done = [queue.popleft() for _ in range(n)]
+        values = None
+        for i, (step, _, _) in enumerate(done):
+            args = {"step": step}
+            if self._counters_dropped:
+                args["dropped"] = self._counters_dropped
+                self._counters_dropped = 0
+            with self.tracer.span("counters", cat="train", args=args) as sp:
+                if values is None:      # the first span holds the fetch
+                    values = jax.device_get([named for _, _, named in done])
+                scalars = {k: float(v) for k, v in values[i].items()}
+                sp.set(**scalars)
+                for name, value in scalars.items():
+                    self.registry.gauge(name).set(value)
+        self._publish_step_rate(time.perf_counter(), done[-1][0])
+
+    def _publish_step_rate(self, now: float, step: int):
+        """``train_mfu`` / ``train_tflops_per_chip`` from the cost model
+        over the time a step took: steps published since the last
+        publication ÷ the host time between the two. A publication follows
+        the device (it waits, or finds finished what a fence finished), so
+        the interval is device-paced however far ahead the host dispatches;
+        the wall clock of one ``train_batch`` call is not. The first
+        publication only starts the clock (it follows the compile)."""
+        last, self._published = self._published, (now, step)
+        if last is None or step <= last[1] or \
+                self.perf.programs.program("train_step").cost_source is None:
+            return
+        vals = self.perf.on_program_step(
+            "train_step", (now - last[0]) / (step - last[1]))
+        if vals["mfu"] is not None:
+            self.registry.gauge("train_mfu").set(vals["mfu"])
+        if vals["flops_per_sec"]:
+            self.registry.gauge("train_tflops_per_chip").set(
+                vals["flops_per_sec"] / 1e12 / self.perf.n_devices)
 
     def _write_monitor(self, loss):
         events = [

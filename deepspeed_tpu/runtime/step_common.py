@@ -20,9 +20,10 @@ def make_local_loss(engine):
     compute_dtype = engine.compute_dtype
 
     def local_loss(params, batch, rng):
-        half = jax.tree_util.tree_map(
-            lambda p: p.astype(compute_dtype)
-            if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
+        with jax.named_scope("ds.param_cast"):
+            half = jax.tree_util.tree_map(
+                lambda p: p.astype(compute_dtype)
+                if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
         if loss_fn is not None:
             loss, _ = loss_fn(half, batch, rng)
         else:

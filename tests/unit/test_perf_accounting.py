@@ -231,7 +231,10 @@ def test_training_engine_registers_train_step_with_cost_and_gauges():
         model=SimpleModel(),
         config={"train_batch_size": 16, "gradient_accumulation_steps": 1,
                 "optimizer": {"type": "Adam", "params": {"lr": 1e-2}},
-                "steps_per_print": 0},
+                # a progress line every step: each call publishes its step
+                # (the gauges follow two publications, not one call's wall
+                # clock, which holds no device time while nobody waits)
+                "steps_per_print": 1},
         example_batch=batch_of(2))
     for i in range(3):
         engine.train_batch(batch=batch_of(16, seed=i))

@@ -11,6 +11,9 @@ their traced program).
 """
 
 import functools
+import glob
+import hashlib
+import os
 import re
 
 import jax
@@ -24,6 +27,7 @@ from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3Config,
                                               DeepseekV3ForCausalLM)
 from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
 from deepspeed_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
+from deepspeed_tpu.monitor import tracing
 from deepspeed_tpu.ops import pallas as names
 
 TRAIN_SCOPES = {
@@ -43,6 +47,10 @@ TRAIN_SCOPES = {
              "ds.cca_mix", "ds.attention", "ds.moe_router", "ds.moe_experts",
              "ds.moe_skip", "ds.lm_head_loss"],
 }
+#: what every family names besides: the engine's cast of the master weights,
+#: the loop over the layers, the block's two pre-norms and residual sums
+for _scopes in TRAIN_SCOPES.values():
+    _scopes += ["ds.param_cast", "ds.layer_stack", "ds.norm", "ds.residual"]
 SERVE_SCOPES = ["ds.mixed_step", "ds.embed", "ds.attn_proj", "ds.kv_append",
                 "ds.attention", "ds.mlp", "ds.lm_head", "ds.sample"]
 
@@ -78,10 +86,41 @@ def test_jitted_steps_are_named_like_the_kernels(train_text, mixed_text):
     """The module's name is in the compile cache's key; the scopes inside
     are metadata and are not. Were the steps still ``train_step`` and
     ``mixed_step``, an executable cached before the scopes existed would be
-    reused, and a trace of it would show none of them."""
+    reused, and a trace of it would show none of them; the names' version
+    in the module's name does the same for every later name."""
+    n = tracing.NAMES_VERSION
     for text in train_text.values():
-        assert "module @jit_ds_train_step" in text
-    assert "module @jit_ds_mixed_step" in mixed_text
+        assert f"module @jit_ds_train_step_n{n} " in text
+    assert f"module @jit_ds_mixed_step_n{n} " in mixed_text
+
+
+def trace_names():
+    """Every ``ds.*`` scope and every span name the package spells, sorted:
+    string literals ``"ds.<name>"`` and the first argument of ``.span(``."""
+    scopes, spans = set(), set()
+    root = os.path.dirname(ds.__file__)
+    for path in glob.glob(os.path.join(root, "**", "*.py"), recursive=True):
+        with open(path) as f:
+            text = f.read()
+        scopes |= set(re.findall(r'"(ds\.[a-z_0-9]+)"', text))
+        spans |= set(re.findall(r'\.span\(\s*"([a-z_:]+)"', text))
+    return sorted(scopes), sorted(spans)
+
+
+#: ``tracing.NAMES_VERSION`` beside the digest of the names it stands for
+NAMES_PIN = (2, "cd7023e9d9c6afc8")
+
+
+def test_names_version_is_raised_with_the_names():
+    """The compile cache's key strips the names and keeps the version: a
+    name added or renamed without raising ``tracing.NAMES_VERSION`` would
+    be read back from the cache as the old one. Raise the version, then
+    put it and the new digest here."""
+    scopes, spans = trace_names()
+    assert {"ds.param_cast", "ds.layer_stack", "ds.norm",
+            "ds.residual"} <= set(scopes) and "counters" in spans
+    digest = hashlib.sha256("\n".join(scopes + spans).encode()).hexdigest()
+    assert (tracing.NAMES_VERSION, digest[:16]) == NAMES_PIN
 
 
 @pytest.mark.parametrize("family,over,gauges", [
