@@ -17,7 +17,9 @@ The table is ordered by q row with keys ascending (forward, dQ) or by kv
 row with queries ascending (dK/dV), so the per-row running max / sum /
 accumulator live in VMEM scratch across consecutive grid steps (standard
 TPU flash pattern) and are initialised / written on the row's first / last
-entry. A tile wholly inside the mask skips the iota / compare / where.
+entry. The forward holds a tile keys-by-queries, so those statistics are
+lane-dense rows (``_fwd_kernel``). A tile wholly inside the mask skips the
+iota / compare / where.
 Backward uses the saved logsumexp and recomputes P per tile: one kernel for
 dQ (loop over kv), one for dK/dV (loop over q).
 
@@ -121,10 +123,14 @@ def _on_tile(flags, body):
     pl.when(flags & _CUT != 0)(functools.partial(body, True))
 
 
-def _tile_valid(iq, ik, block_q, block_k, tq, tk, causal, window):
-    """In-tile mask of a cut tile, and its global row numbers."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + iq * block_q
-    cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + ik * block_k
+def _tile_valid(iq, ik, block_q, block_k, tq, tk, causal, window,
+                keys_first=False):
+    """In-tile mask of a cut tile, and its global row numbers: ``[bq, bk]``,
+    or ``[bk, bq]`` for a tile held ``keys_first`` (the forward's)."""
+    shape, q_dim = ((block_k, block_q), 1) if keys_first else \
+        ((block_q, block_k), 0)
+    rows = jax.lax.broadcasted_iota(jnp.int32, shape, q_dim) + iq * block_q
+    cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim) + ik * block_k
     # ragged tails: padded kv columns/q rows contribute nothing
     valid = (cols < tk) & (rows < tq)
     if causal:
@@ -142,6 +148,15 @@ def _tile_valid(iq, ik, block_q, block_k, tq, tk, causal, window):
 def _fwd_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, *rest,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
                 tq: int, tk: int, window, has_mask: bool = False):
+    """The tile is held TRANSPOSED, ``sT [bk, bq]``: keys on the sublanes,
+    queries on the lanes. The statistics are per query, so the running max,
+    the running sum and the rescale factor are lane-dense ``[1, bq]`` rows,
+    ``max`` / ``sum`` over the keys reduce across sublanes (element-wise over
+    the tile's registers), the rows broadcast along sublanes, and the
+    accumulator is ``accT [Dv, bq]``, turned once a row of tiles in
+    ``_finalize``. With queries on the sublanes (``[bq, 1]`` columns, lane
+    reductions, one live lane in 128) a kept 512 x 512 tile took 2.0 us on
+    the v5e where this takes 1.45 (PERF.md section 6, PR 38)."""
     if has_mask:
         kmask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -158,27 +173,31 @@ def _fwd_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, *rest,
     def _body(cut):
         q = q_ref[0, 0].astype(jnp.float32)  # [bq, D]
         k = k_ref[0, 0].astype(jnp.float32)  # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)  # [bk, D]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
+        v = v_ref[0, 0].astype(jnp.float32)  # [bk, Dv]
+        st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * sm_scale
         valid = None
         if cut:
             valid, _ = _tile_valid(iq, ik, block_q, block_k, tq, tk, causal,
-                                   window)
-        if has_mask:  # [B, Tk] key-padding mask (left-padded prompts)
+                                   window, keys_first=True)
+        if has_mask:  # [B, Tk] key-padding mask (left-padded prompts),
+            # here a [bk, 1] column over the sublanes
             real = kmask_ref[0] > 0
             valid = real if valid is None else valid & real
         if valid is not None:
-            s = jnp.where(valid, s, NEG_INF)
+            st = jnp.where(valid, st, NEG_INF)
 
-        m_prev = m_scr[:]                       # [bq, 1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_prev = m_scr[:]                       # [1, bq]
+        m_cur = jnp.max(st, axis=0, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                  # [bq, bk]
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
+        p = jnp.exp(st - m_new)                 # [bk, bq]
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=0, keepdims=True)
+        # vT . pT as a transposed-left product on v as it comes (the kind
+        # dK/dV runs): no second layout of the values outside the kernel
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            v, p, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [Dv, bq]
         m_scr[:] = m_new
 
     _on_tile(flags, _body)
@@ -187,12 +206,12 @@ def _fwd_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, *rest,
     def _finalize():
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        # compact residual, one fp32 per q row (not lane-broadcast to 128
-        # columns). It is stored as a [1, bq] ROW of [B, H, 1, Tq]: a
+        o_ref[0, 0] = (acc_scr[:] / l_safe).T.astype(o_ref.dtype)
+        # compact residual, one fp32 per q row, written from the rows as
+        # they are kept. It is stored as a [1, bq] ROW of [B, H, 1, Tq]: a
         # (1, bq) block of a bare [B, H, Tq] array breaks Mosaic's
         # block-shape rule
-        lse_ref[0, 0, 0] = (m_scr[:] + jnp.log(l_safe))[:, 0]
+        lse_ref[0, 0] = m_scr[:] + jnp.log(l_safe)
 
 
 def _pad_seq(x, block):
@@ -225,10 +244,10 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     if key_mask is not None:
         km = jnp.pad(key_mask.astype(jnp.int32),
                      ((0, 0), (0, Tk_p - key_mask.shape[1])))
-        mask_args = [km[:, None]]
+        mask_args = [km[:, :, None]]
         mask_specs = [pl.BlockSpec(
-            (1, 1, bk), lambda b, h, t, iq_of, ik_of, flags_of:
-            (b, 0, ik_of[t]))]
+            (1, bk, 1), lambda b, h, t, iq_of, ik_of, flags_of:
+            (b, ik_of[t], 0))]
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
@@ -247,9 +266,9 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
                 pl.BlockSpec((1, 1, 1, bq), _q_row),
             ],
             scratch_shapes=[
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, Dv), jnp.float32),
+                pltpu.VMEM((1, bq), jnp.float32),
+                pltpu.VMEM((1, bq), jnp.float32),
+                pltpu.VMEM((Dv, bq), jnp.float32),
             ],
         ),
         out_shape=[

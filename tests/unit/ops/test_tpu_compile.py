@@ -145,6 +145,9 @@ CASES = {
     # wide, values 128 (Mosaic takes the 192-wide block as it is)
     "flash_fwd_bwd_mla_train8k": lambda: _flash(True, None, (1, 8192, 16, 192),
                                                 v_dim=128),
+    # zaya1-8b.train.8k: 8 query heads of 128 (keys and values repeated from
+    # 2), plain causal
+    "flash_fwd_bwd_cca_train8k": lambda: _flash(True, None, (1, 8192, 8, D)),
     "flash_fwd_key_mask_gqa": _flash_key_mask,
     "ragged_bf16": lambda: _ragged(False, None),
     "ragged_bf16_window": lambda: _ragged(False, 4096),

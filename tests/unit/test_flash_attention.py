@@ -341,29 +341,154 @@ def test_flash_with_a_value_width_of_its_own(t, d, dv, window, what):
                                rtol=5e-4)
 
 
-@pytest.mark.parametrize("window,digest", [(None, "d90a97f21432fc45"),
-                                           (40, "5363675f1dd8a059")])
-def test_equal_widths_trace_the_program_they_always_did(window, digest):
-    """Where values are as wide as keys the forward and both backward calls
-    are, parameter for parameter, what the kernels were before they told the
-    two widths apart: the digest is of the three ``pallas_call``s of the
+def _traced_calls(window, bq=32, bk=32, t=96):
+    """The three ``pallas_call`` equations of the traced gradient at equal
+    widths: forward, dQ, dK/dV."""
+    from deepspeed_tpu.ops.pallas import (FLASH_BWD_DKV, FLASH_BWD_DQ,
+                                          FLASH_FWD)
+
+    fwd = functools.partial(flash_attention, causal=True, interpret=True,
+                            window=window, block_q=bq, block_k=bk)
+    loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
+    x = jnp.zeros((1, t, 2, 16), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in calls] == [FLASH_FWD, FLASH_BWD_DQ,
+                                                 FLASH_BWD_DKV]
+    return calls
+
+
+@pytest.mark.parametrize("window,which,digest", [
+    (None, "backward", "975c02806911ef62"), (40, "backward", "6dc6cd441bad9d98"),
+    (None, "forward", "f14f4c937a441b1e"), (40, "forward", "2c3219fb2deb9e1a")])
+def test_equal_widths_trace_the_program_they_always_did(window, which, digest):
+    """Where values are as wide as keys the calls are, parameter for
+    parameter, what they were: each digest is of ``pallas_call``s of the
     traced gradient (block shapes, grids, scratch and kernel bodies) at these
     shapes. PRs 31 to 33 pinned the whole traced text (``ff8e37f2…`` /
     ``8dc581f5…``); PR 34 put two ``checkpoint_name`` equations around the
-    calls, which renames every variable after them, and pins the calls alone:
-    PR 33's file and PR 34's both give these digests."""
+    calls and pinned the three calls by one digest (``d90a97f2…`` /
+    ``5363675f…``: PR 33's file and PR 34's both gave them). PR 38 turned the
+    forward's tile (keys on the sublanes, the statistics as rows), so the
+    pin is split: **the two backward calls keep a digest of their own,
+    computed on PR 37's file** — their bodies, grids and scratch did not
+    move — and the forward has the digest of its new body."""
     import hashlib
 
-    fwd = functools.partial(flash_attention, causal=True, interpret=True,
-                            window=window, block_q=32, block_k=32)
-    loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
-    x = jnp.zeros((1, 96, 2, 16), jnp.float32)
-    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
-    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
-    assert len(calls) == 3
+    calls = _traced_calls(window)
+    calls = calls[:1] if which == "forward" else calls[1:]
     text = "\n".join(str(sorted((k, str(v)) for k, v in e.params.items()))
                      for e in calls)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+def test_forward_statistics_are_rows_not_columns():
+    """The forward call's scratch holds no ``(bq, 1)`` column: the running
+    max and sum are ``(1, bq)`` rows and the accumulator ``(Dv, bq)``, the
+    tile's queries on the lanes (``bq`` 64 against ``bk`` 32 and ``Dv`` 16,
+    so no two of them can be mistaken for each other)."""
+    bq, bk, dv = 64, 32, 16
+    call = _traced_calls(None, bq=bq, bk=bk, t=128)[0]
+    n = call.params["grid_mapping"].num_scratch_operands
+    scratch = [tuple(v.aval.shape) for v in call.params["jaxpr"].invars[-n:]]
+    assert scratch == [(1, bq), (1, bq), (dv, bq)]
+
+
+# -- the forward's orientation: output AND log-sum-exp ------------------------
+
+#: name: tq, tk, heads, kv heads, d, dv, bq, bk, causal, window, left padding
+_ORIENTATION = {
+    "latent_widths": (128, 128, 2, 2, 192, 128, 64, 64, True, None, 0),
+    "bq_under_bk": (128, 128, 2, 2, 32, 32, 32, 64, True, None, 0),
+    "bq_over_bk": (128, 128, 2, 2, 32, 32, 64, 32, True, None, 0),
+    "cross_length": (64, 160, 2, 2, 32, 32, 32, 32, True, None, 0),
+    "ragged_query_tail": (100, 128, 2, 2, 32, 32, 64, 64, True, None, 0),
+    "ragged_key_tail": (64, 100, 2, 2, 32, 32, 32, 64, True, None, 0),
+    "windowed": (128, 128, 2, 2, 32, 32, 32, 32, True, 40, 0),
+    "not_causal_two_widths": (96, 80, 2, 2, 24, 40, 32, 32, False, None, 0),
+    "key_mask_gqa": (48, 48, 8, 2, 16, 16, 16, 16, True, None, 7),
+    "placeholder_rows": (96, 32, 2, 2, 32, 32, 32, 32, True, None, 0),
+}
+
+
+def _plain_attention(q, k, v, scale, causal, window, key_mask):
+    """float32 attention over [B, T, H, D] written out: the output
+    [B, Tq, H, Dv], the log-sum-exp [B, H, Tq] and which query rows see a
+    key at all [B, Tq] (the others are degenerate: excluded)."""
+    rep = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    tq, tk = q.shape[1], k.shape[1]
+    d = jnp.arange(tq)[:, None] + (tk - tq) - jnp.arange(tk)[None, :]
+    mask = jnp.ones((tq, tk), bool)
+    if causal:
+        mask &= d >= 0
+    if window is not None:
+        mask &= d < window
+    mask = jnp.broadcast_to(mask, (q.shape[0], tq, tk))
+    if key_mask is not None:
+        mask &= (key_mask > 0)[:, None, :]
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    logits = jnp.where(mask[:, None], logits, -jnp.inf)
+    seen = mask.any(-1)
+    lse = jax.nn.logsumexp(jnp.where(seen[:, None, :, None], logits, 0.0),
+                           axis=-1)
+    probs = jnp.exp(logits - lse[..., None]) * seen[:, None, :, None]
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v), lse, seen
+
+
+@pytest.mark.parametrize("case,what", [
+    (case, what) for case in sorted(_ORIENTATION)
+    for what in ("out", "lse", "dq", "dk", "dv")
+    # the masked path is forward-only (no custom vjp)
+    if case != "key_mask_gqa" or what in ("out", "lse")])
+def test_forward_orientation_output_lse_and_gradients(case, what):
+    """What turning the forward's tile could break, each against plain
+    attention in interpret mode: the output and the log-sum-exp the kernel
+    writes from its ``[1, bq]`` rows (latent attention's two widths,
+    ``bq != bk``, ``Tq != Tk``, a ragged tail on either side, a window, the
+    key mask as a column over un-repeated kv heads, a row of tiles with
+    nothing to run), and the three gradients the UNCHANGED backward kernels
+    make of the new forward's ``out`` / ``lse``."""
+    from deepspeed_tpu.ops.pallas.flash_attention import _flash_fwd
+
+    tq, tk, h, hkv, d, dv, bq, bk, causal, window, pad = _ORIENTATION[case]
+    ks = jax.random.split(jax.random.PRNGKey(38), 4)
+    q = jax.random.normal(ks[0], (2, tq, h, d))
+    k = jax.random.normal(ks[1], (2, tk, hkv, d))
+    v = jax.random.normal(ks[2], (2, tk, hkv, dv))
+    key_mask = None
+    if case == "key_mask_gqa":
+        key_mask = jnp.ones((2, tk), jnp.int32).at[0, :pad].set(0)
+    scale = d ** -0.5
+    want_out, want_lse, seen = _plain_attention(q, k, v, scale, causal,
+                                                window, key_mask)
+    if what in ("out", "lse"):
+        bhtd = lambda x: jnp.transpose(x, (0, 2, 1, 3))
+        out, lse = _flash_fwd(bhtd(q), bhtd(k), bhtd(v), scale, causal, bq,
+                              bk, True, window, key_mask)
+        assert out.shape == (2, h, tq, dv) and lse.shape == (2, h, tq)
+        assert lse.dtype == jnp.float32
+        got, want = ((bhtd(out), want_out) if what == "out" else
+                     (jnp.moveaxis(lse, 1, 2), jnp.moveaxis(want_lse, 1, 2)))
+        np.testing.assert_allclose(np.asarray(got)[np.asarray(seen)],
+                                   np.asarray(want)[np.asarray(seen)],
+                                   atol=2e-5, rtol=2e-5)
+        if case == "placeholder_rows":      # rows 0..63 see no key: zeros
+            assert not np.asarray(seen)[:, :64].any()
+            assert np.asarray(seen)[:, 64:].all()
+            assert not np.asarray(bhtd(out))[:, :64].any()
+        return
+    w = jax.random.normal(ks[3], (2, tq, h, dv)) * seen[:, :, None, None]
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, sm_scale=scale, block_q=bq, block_k=bk,
+        interpret=True, force_pallas=True, window=window)
+    plain = lambda q, k, v: _plain_attention(q, k, v, scale, causal, window,
+                                             None)[0]
+    i = "qkv".index(what[1])
+    got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * w), argnums=i)(q, k, v)
+                 for f in (flash, plain))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-4,
+                               rtol=5e-4)
 
 
 # -- what jax.checkpoint keeps of the kernel ----------------------------------
