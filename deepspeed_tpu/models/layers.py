@@ -32,7 +32,9 @@ def resolve_remat_policy(name: str):
     that whole kernel again to hand them to the backward kernels. Everything
     else gets the named policy's answer; where no flash kernel ran (the XLA
     attention path, whose residual is the ``[H, T, T]`` probabilities) no
-    such name exists and the policy is the plain one.
+    such name exists and the policy is the plain one. A learned selection's
+    bit-packed mask (``ds_sa_mask``, ``models/indexed_attention.py``) is kept
+    the same way: the replay must see the set the forward pass chose.
 
     ``offload_dots_no_batch`` is the CPU-activation-checkpointing analog
     (reference ``activation_checkpointing/checkpointing.py:480``
@@ -40,7 +42,8 @@ def resolve_remat_policy(name: str):
     ``dots_no_batch`` set) are saved to PINNED HOST memory instead of HBM —
     XLA schedules the device↔host copies, replacing the reference's explicit
     ``.cpu()`` round-trips."""
-    from ..ops.pallas import FLASH_LSE, FLASH_OUT  # ops imports this module
+    from ..ops.pallas import (FLASH_LSE, FLASH_OUT,  # ops imports this module
+                              SA_MASK)
 
     policies = {
         "nothing": jax.checkpoint_policies.nothing_saveable,
@@ -54,7 +57,7 @@ def resolve_remat_policy(name: str):
         raise ValueError(f"unknown remat_policy {name!r}; one of {sorted(policies)}")
     base = policies[name]
     flash_named = jax.checkpoint_policies.save_only_these_names(
-        FLASH_OUT, FLASH_LSE)
+        FLASH_OUT, FLASH_LSE, SA_MASK)
 
     # written out, not save_from_both_policies: that helper refuses the
     # Offloadable / Recompute answers of the offload policy
@@ -307,10 +310,14 @@ def dot_product_attention(q, k, v, bias=None, causal: bool = False,
     moderate T). This mirrors the reference's split between fused CUDA
     softmax kernels and stock torch attention.
 
-    ``causal`` applies bottom-right-aligned causality; ``bias`` carries any
-    additive mask beyond that (e.g. padding). The flash kernel currently
-    supports causality but not an arbitrary bias or dropout — those cases
-    fall back to the XLA path so semantics never silently change.
+    ``causal`` applies bottom-right-aligned causality and ``window`` a
+    sliding window; ``bias`` carries any ADDITIVE mask beyond that (e.g.
+    padding). The flash kernels take causality, a window, a key-padding
+    mask (forward only) and a ``[B, Tq, Tk]`` selection that is data
+    (``flash_attention(mask=...)``, which ``models/indexed_attention.py``
+    calls directly: it needs the log-sum-exp too) — but no additive bias
+    and no dropout: those cases fall back to the XLA path here so semantics
+    never silently change.
     """
     use_dropout = dropout_rate > 0.0 and not deterministic
     if attention_impl == "flash" and bias is None and not use_dropout:

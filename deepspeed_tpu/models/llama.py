@@ -186,9 +186,24 @@ class LlamaAttention(nn.Module):
             k = norm(dense(Hkv * D, "k_proj", qb)(x),
                      "k_norm").reshape(B, T, Hkv, D)
             v = dense(Hkv * D, "v_proj", qb)(x).reshape(B, T, Hkv, D)
+            if getattr(cfg, "qk_norm_per_head", False):
+                # an RMSNorm over each head's D columns (scales [D])
+                q = RMSNorm(eps=cfg.rms_norm_eps, name="q_norm")(q)
+                k = RMSNorm(eps=cfg.rms_norm_eps, name="k_norm")(k)
             q = apply_rotary(q, cos, sin)
             k = apply_rotary(k, cos, sin)
-        if layer_cache is not None and is_paged_index(cache_index):
+        if getattr(cfg, "sa_config", None) is not None:
+            # a learned indexer chooses each query's keys (training only):
+            # no cache, and a third value: what the loss needs of this layer
+            if layer_cache is not None or mask is not None:
+                raise NotImplementedError(
+                    "sa_config is built for training on packed sequences: "
+                    "no cache holds the indexer's keys and no padding mask "
+                    "is composed with the selection")
+            from .indexed_attention import indexed_attention
+
+            out, sa_stats = indexed_attention(cfg, x, q, k, v, cos, sin)
+        elif layer_cache is not None and is_paged_index(cache_index):
             # paged serving path (inference/serving/): KV appends scatter
             # into the shared block pool through this sequence's block
             # table; ragged-ness (per-sequence lengths) lives in the index
@@ -330,6 +345,8 @@ class LlamaAttention(nn.Module):
         with jax.named_scope("ds.attn_proj"):
             out = dense(cfg.hidden_size, "o_proj", row=True)(
                 out.reshape(B, T, H * D))
+        if getattr(cfg, "sa_config", None) is not None:
+            return out, layer_cache, sa_stats
         return out, layer_cache
 
 

@@ -44,6 +44,7 @@ from ..utils.logging import log_dist
 from .layers import (RMSNorm, cross_entropy_loss, head_scope, init_kv_cache,
                      lm_head_output,
                      resolve_remat_policy, rotary_embedding, shift_labels)
+from .indexed_attention import SparseAttentionConfig
 from .llama import LlamaAttention, LlamaConfig
 
 
@@ -129,6 +130,59 @@ class MixtralConfig(LlamaConfig):
     #: pairs over the chips' mean (what the step follows under
     #: ``expert_layout``'s whole experts, 1 by construction under columns)
     report_expert_load: bool = False
+    #: each expert's width where it is not ``intermediate_size`` (configs
+    #: that also publish a dense width under that key)
+    moe_intermediate_size: Optional[int] = None
+    #: ONE CHIP'S SHARE of a layer's experts, as ``deepseek_v3.py`` and
+    #: ``zaya.py`` hold it: ``num_local_experts`` experts are held, the
+    #: router's ``first_expert ..`` of its ``router_experts`` (None: all are
+    #: held). Router, top-k and the weights' normalisation are over all of
+    #: them; the held experts add their part and the rest is left out
+    router_experts: Optional[int] = None
+    first_expert: int = 0
+    #: False: the optimizer never moves ``block_sparse_moe/gate`` (a share
+    #: trained alone teaches its router to starve it, PERF.md section 6)
+    router_trainable: bool = True
+    #: RMSNorm over each head's ``head_dim`` columns of the query and the
+    #: key (scales ``[head_dim]``), before RoPE; ``qk_norm`` is OLMoE's norm
+    #: over the whole projection
+    qk_norm_per_head: bool = False
+    #: a learned indexer chooses each query's keys
+    #: (``models/indexed_attention.py``; a dict of the published keys is
+    #: taken too). The training call adds the indexer's loss and returns
+    #: ``(loss, {"sa_index_loss", ...})``; None: plain causal attention
+    sa_config: Optional[SparseAttentionConfig] = None
+
+    def __post_init__(self):
+        if isinstance(self.sa_config, dict):
+            object.__setattr__(self, "sa_config",
+                               SparseAttentionConfig(**self.sa_config))
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_local_experts
+
+    @staticmethod
+    def keye_vl2_30b_a3b(**over):
+        """The language model of Keye-VL-2.0-30B-A3B (``Kwai-Keye/
+        Keye-VL-2.0-30B-A3B`` ``config.json``, ``model_type`` ``KeyeVL2``):
+        GQA 32 / 4 heads of 128 with a per-head q/k norm, 128 experts of 768
+        with top-8 of a softmax renormalised, no shared expert, and the
+        indexer of ``sa_config`` choosing 2,048 keys a query."""
+        return MixtralConfig(**{**dict(
+            vocab_size=151936, hidden_size=2048, intermediate_size=6144,
+            moe_intermediate_size=768, num_hidden_layers=48,
+            num_attention_heads=32, num_key_value_heads=4,
+            head_dim_override=128, max_position_embeddings=262144,
+            rms_norm_eps=1e-6, rope_theta=1e7, num_local_experts=128,
+            num_experts_per_tok=8, norm_topk_prob=True,
+            router_aux_loss_coef=0.0, qk_norm_per_head=True,
+            per_expert_init=True, sa_config=SparseAttentionConfig()),
+            **over})
 
     @staticmethod
     def mixtral_8x7b(**over):
@@ -179,9 +233,10 @@ def _router_stats(onehot, probs, token_mask, tokens):
 
 
 class MixtralSparseMoeBlock(nn.Module):
-    """HF ``MixtralSparseMoeBlock`` semantics. Returns ``(out, frac, prob)``
-    where ``frac``/``prob`` are this layer's per-expert token-fraction and
-    mean-router-probability vectors ``[E]`` (token-masked), accumulated
+    """HF ``MixtralSparseMoeBlock`` semantics. Returns ``(out, frac, prob,
+    rows)`` where ``frac``/``prob`` are this layer's per-expert token-fraction
+    and mean-router-probability vectors ``[E]`` (token-masked) and ``rows``
+    the pairs each held expert computed (None on the decode path), accumulated
     across layers by the caller — HF's ``load_balancing_loss_func``
     concatenates all layers' tokens BEFORE taking the means, so the product
     must happen at the top, not per layer."""
@@ -192,8 +247,12 @@ class MixtralSparseMoeBlock(nn.Module):
     def __call__(self, x, token_mask=None):
         cfg = self.config
         B, T, H = x.shape
-        E, K = cfg.num_local_experts, cfg.num_experts_per_tok
-        I = cfg.intermediate_size
+        # G of the router's E experts are held (all of them but for a share)
+        G, E, K = cfg.num_local_experts, cfg.router_width, \
+            cfg.num_experts_per_tok
+        I = cfg.expert_width
+        if cfg.router_experts is not None:
+            _check_held_share(cfg.first_expert, G, E)
 
         with jax.named_scope("ds.moe_router"):
             router_logits = nn.Dense(E, use_bias=False, name="gate",
@@ -211,16 +270,16 @@ class MixtralSparseMoeBlock(nn.Module):
         # (expert_layout)
         init = nn.initializers.lecun_normal(
             batch_axis=(0,) if cfg.per_expert_init else ())
-        w1 = self.param("w1", init, (E, H, I), jnp.float32)  # gate
-        w3 = self.param("w3", init, (E, H, I), jnp.float32)  # up
-        w2 = self.param("w2", init, (E, I, H), jnp.float32)  # down
+        w1 = self.param("w1", init, (G, H, I), jnp.float32)  # gate
+        w3 = self.param("w3", init, (G, H, I), jnp.float32)  # up
+        w2 = self.param("w2", init, (G, I, H), jnp.float32)  # down
         out, rows = _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx)
         if rows is not None:
             # (token, expert) pairs each expert computed this call, [E]:
             # free unless the caller asks for the collection
             self.sow("intermediates", "expert_rows", rows)
         frac, prob = _router_stats(onehot, probs, token_mask, B * T)
-        return out, frac, prob
+        return out, frac, prob, rows
 
 
 def _grouped_dot(lhs, rhs, group_sizes):
@@ -361,7 +420,8 @@ def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx):
     B, T, H = x.shape
     dt = x.dtype
     E, K = cfg.num_local_experts, cfg.num_experts_per_tok
-    if T == 1 and E > K and not _expert_axis_active():
+    if T == 1 and E > K and cfg.router_experts is None \
+            and not _expert_axis_active():
         # decode fast path (replicated experts): GATHER only the K
         # touched experts' weights per token instead of computing all E
         # — a decode step is bound by the weight bytes it streams (the
@@ -380,10 +440,10 @@ def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx):
                          topk_w[:, 0].astype(dt), y)[:, None]
         return out, None
 
-    def experts(x, w1, w2, w3, topk_w, topk_idx, first=0):
+    def experts(x, w1, w2, w3, topk_w, topk_idx, first=cfg.first_expert):
         out, rows = _routed_experts(
             x.reshape(-1, H), w1, w2, w3, topk_w.reshape(-1, K),
-            topk_idx.reshape(-1, K), first)
+            topk_idx.reshape(-1, K), first, cfg.router_experts)
         return out.reshape(x.shape), rows
 
     mesh = get_mesh()
@@ -448,18 +508,25 @@ class MixtralBlock(nn.Module):
         # ds.norm / ds.residual as in models/llama.py LlamaBlock
         with jax.named_scope("ds.norm"):
             h = RMSNorm(eps=cfg.rms_norm_eps, name="input_layernorm")(x)
-        attn, layer_cache = LlamaAttention(cfg, name="self_attn")(
+        out = LlamaAttention(cfg, name="self_attn")(
             h, cos, sin, mask, layer_cache, cache_index, deterministic)
+        attn, layer_cache = out[:2]
+        # under sa_config a third value: the selection's statistics
+        extra = dict(out[2]) if cfg.sa_config is not None else {}
         with jax.named_scope("ds.residual"):
             x = x + attn
         with jax.named_scope("ds.norm"):
             h = RMSNorm(eps=cfg.rms_norm_eps,
                         name="post_attention_layernorm")(x)
-        moe_out, frac, prob = MixtralSparseMoeBlock(
+        moe_out, frac, prob, rows = MixtralSparseMoeBlock(
             cfg, name="block_sparse_moe")(h, token_mask)
         with jax.named_scope("ds.residual"):
             x = x + moe_out
-        return x, layer_cache, frac, prob
+        C = _compact_rows(x.shape[0] * x.shape[1] * cfg.num_experts_per_tok,
+                          cfg.num_local_experts, cfg.router_experts)
+        if C is not None:
+            extra["compact_hit"] = _fits(rows, C).astype(jnp.float32)
+        return x, layer_cache, frac, prob, extra
 
 
 class _ScanBlock(nn.Module):
@@ -467,11 +534,14 @@ class _ScanBlock(nn.Module):
 
     @nn.compact
     def __call__(self, carry, layer_cache):
-        x, cos, sin, mask, tok_mask, cache_index, det, frac_sum, prob_sum = carry
-        y, layer_cache, frac, prob = MixtralBlock(self.config, name="block")(
+        (x, cos, sin, mask, tok_mask, cache_index, det, frac_sum, prob_sum,
+         extra_sum) = carry
+        y, layer_cache, frac, prob, extra = MixtralBlock(
+            self.config, name="block")(
             x, cos, sin, mask, tok_mask, layer_cache, cache_index, det)
         return (y, cos, sin, mask, tok_mask, cache_index, det,
-                frac_sum + frac, prob_sum + prob), layer_cache
+                frac_sum + frac, prob_sum + prob,
+                _add_stats(extra_sum, extra)), layer_cache
 
 
 class MixtralModel(nn.Module):
@@ -500,8 +570,12 @@ class MixtralModel(nn.Module):
                 mask = jnp.where(attention_mask[:, None, None, :] > 0, 0.0,
                                  -1e9).astype(jnp.float32)
 
-        E = cfg.num_local_experts
+        E = cfg.router_width
+        training = cache is None
         zero_e = jnp.zeros((E,), jnp.float32)
+        extra_sum = dict.fromkeys(
+            _extra_stats(cfg, B * T * cfg.num_experts_per_tok),
+            jnp.float32(0))
         remat_policy = resolve_remat_policy(cfg.remat_policy)
         # ds.layer_stack: what the loop over the layers costs beyond what
         # the layers' own scopes name (models/llama.py LlamaModel)
@@ -515,9 +589,10 @@ class MixtralModel(nn.Module):
                                variable_axes={"params": 0, "intermediates": 0},
                                split_rngs={"params": True, "dropout": True},
                                length=cfg.num_hidden_layers, metadata_params={})
-                (x, *_, frac_sum, prob_sum), cache = scan(cfg, name="layers")(
-                    (x, cos, sin, mask, tok_mask, cache_index, deterministic,
-                     zero_e, zero_e), cache)
+                (x, *_, frac_sum, prob_sum, extra_sum), cache = \
+                    scan(cfg, name="layers")(
+                        (x, cos, sin, mask, tok_mask, cache_index,
+                         deterministic, zero_e, zero_e, extra_sum), cache)
             else:
                 block_cls = nn.remat(MixtralBlock, prevent_cse=False,
                                      policy=remat_policy) \
@@ -527,10 +602,12 @@ class MixtralModel(nn.Module):
                 for i in range(cfg.num_hidden_layers):
                     layer_cache = None if cache is None else \
                         jax.tree_util.tree_map(lambda c: c[i], cache)
-                    x, layer_cache, frac, prob = block_cls(cfg, name=f"layers_{i}")(
+                    x, layer_cache, frac, prob, extra = block_cls(
+                        cfg, name=f"layers_{i}")(
                         x, cos, sin, mask, tok_mask, layer_cache, cache_index,
                         deterministic)
                     frac_sum, prob_sum = frac_sum + frac, prob_sum + prob
+                    extra_sum = _add_stats(extra_sum, extra)
                     if new_cache is not None:
                         new_cache.append(layer_cache)
                 if new_cache is not None:
@@ -543,8 +620,10 @@ class MixtralModel(nn.Module):
         # the expert-wise product
         L = cfg.num_hidden_layers
         aux = E * jnp.sum((frac_sum / L) * (prob_sum / L))
-        # frac_sum: each expert's share of the tokens, summed over layers
-        return (x, aux, frac_sum) if cache is None else (x, aux, cache)
+        # frac_sum: each expert's share of the tokens, summed over layers;
+        # extra_sum: the layers' _extra_stats, summed too
+        return (x, aux, (frac_sum, extra_sum)) if training \
+            else (x, aux, cache)
 
 
 class MixtralForCausalLM(nn.Module):
@@ -560,11 +639,14 @@ class MixtralForCausalLM(nn.Module):
                  cache_index=None):
         cfg = self.config
         # the third is the updated cache, or without one the experts' load
+        # and the layers' other statistics
         hidden, aux, load = MixtralModel(cfg, name="model")(
             input_ids, positions, attention_mask, deterministic, cache,
             cache_index)
         if cache is not None:
             cache = load
+        else:
+            load, extra = load
         with jax.named_scope(head_scope(cache)):
             logits, lm = lm_head_output(self, cfg, hidden, labels, cache)
             if cache is not None:
@@ -574,6 +656,9 @@ class MixtralForCausalLM(nn.Module):
             if lm is None:
                 lm = cross_entropy_loss(logits, shift_labels(labels))
         loss = lm + cfg.router_aux_loss_coef * aux
+        if extra or cfg.router_experts is not None:
+            return _share_loss_and_gauges(cfg, loss, load, extra,
+                                          input_ids.size)
         if not cfg.report_expert_load:
             return loss
         load = load / jnp.mean(load)
@@ -584,7 +669,7 @@ class MixtralForCausalLM(nn.Module):
             # a chip of the expert axis computes the rows of its whole
             # experts, or a slice of every row
             whole = expert_layout(cfg.num_local_experts,
-                                  cfg.intermediate_size, ep) == "experts"
+                                  cfg.expert_width, ep) == "experts"
             named["moe_chip_rows_max_over_mean"] = jnp.max(jnp.mean(
                 load.reshape(ep if whole else 1, -1), axis=1))
         return loss, named
@@ -605,9 +690,13 @@ class MixtralForCausalLM(nn.Module):
 
         def experts(mesh):
             return _expert_weight_specs(expert_layout(
-                config.num_local_experts, config.intermediate_size,
+                config.num_local_experts, config.expert_width,
                 _expert_axis_size(mesh)))
 
+        # a per-head norm's [head_dim] scales are whole on every chip (and
+        # so is the indexer's k_norm, which the pattern would also catch)
+        qk_norm = [] if config.qk_norm_per_head else [
+            (r"(q_norm|k_norm)/scale", P(*L, "model"))]
         return [
             (r"embed_tokens/embedding", P("model", None)),
             (r"(q_proj|k_proj|v_proj)/kernel", P(*L, None, "model")),
@@ -615,8 +704,15 @@ class MixtralForCausalLM(nn.Module):
             (r"block_sparse_moe/(w1|w3)", lambda m: P(*L, *experts(m)[0])),
             (r"block_sparse_moe/w2", lambda m: P(*L, *experts(m)[1])),
             (r"lm_head/kernel", P(None, "model")),
-            (r"(q_norm|k_norm)/scale", P(*L, "model")),
+            *qk_norm,
         ]
+
+    @staticmethod
+    def frozen_parameters(config: "MixtralConfig"):
+        """Parameter paths the optimizer never moves: the router's weights
+        where ``router_trainable`` is off (a held share trained alone)."""
+        return [] if config.router_trainable \
+            else [r"block_sparse_moe/gate/kernel$"]
 
 
 # -- one chip's share of a wider router's experts ---------------------------
@@ -762,3 +858,49 @@ def _compact_hit_gauge(layer_rows, pairs, experts):
         return {}
     return {"moe_compact_hit_share":
             jnp.mean(_fits(layer_rows, C).astype(jnp.float32))}
+
+
+# -- what a selection or a held share reports, layer by layer ---------------
+
+def _extra_stats(cfg, pairs):
+    """Names of the float32 scalars a layer of ``pairs`` sorted rows hands
+    up beside the router's statistics, summed over the layers: the indexer's
+    loss and (with ``report_expert_load``) the share of causal tiles its
+    selection keeps, from ``LlamaAttention``; whether the held pairs fitted
+    the compact buffer, where the layer has one."""
+    names = []
+    if cfg.sa_config is not None:
+        names.append("sa_index_loss")
+        if cfg.report_expert_load:
+            names.append("sa_kept_tile_share")
+    if _compact_rows(pairs, cfg.num_local_experts,
+                     cfg.router_experts) is not None:
+        names.append("compact_hit")
+    return names
+
+
+def _add_stats(sums, stats):
+    return {k: v + stats[k] for k, v in sums.items()}
+
+
+def _share_loss_and_gauges(cfg, loss, frac_sum, extra, tokens):
+    """The training call's ``(loss, named scalars)`` from the layers'
+    ``_extra_stats`` summed: the indexer's loss (coefficient 1, the
+    published sparse stage) joins the loss and is named beside it; with
+    ``report_expert_load`` the selection's kept tile share and a held
+    share's gauges as ``deepseek_v3.py`` names them."""
+    L, named = cfg.num_hidden_layers, {}
+    if "sa_index_loss" in extra:
+        loss = loss + extra["sa_index_loss"]
+        named["sa_index_loss"] = extra["sa_index_loss"]
+    if not cfg.report_expert_load:
+        return (loss, named) if named else loss
+    if "sa_kept_tile_share" in extra:
+        named["sa_kept_tile_share"] = extra["sa_kept_tile_share"] / L
+    G, first = cfg.num_local_experts, cfg.first_expert
+    pairs = tokens * cfg.num_experts_per_tok              # of one layer
+    rows = frac_sum[first:first + G] * tokens             # summed over layers
+    named.update(_held_load_gauges(rows, L * pairs * G / cfg.router_width))
+    if "compact_hit" in extra:
+        named["moe_compact_hit_share"] = extra["compact_hit"] / L
+    return loss, named

@@ -101,7 +101,7 @@ PROFILER_PREFIX = "ds."
 #: ``versioned`` puts this number into each jitted step's module name, which
 #: the key does hold. Raise it with every added or renamed name
 #: (tests/unit/test_trace_names.py pins it beside a digest of the names).
-NAMES_VERSION = 2
+NAMES_VERSION = 3
 
 
 def versioned(step):

@@ -1,8 +1,8 @@
 """Pallas kernels. Every ``pl.pallas_call`` here passes one of these fixed
 names, so a profiler trace names the kernel the same way after any refactor
 of the code around it (a trace reader matches the strings; outside this
-package only ``models/layers.py resolve_remat_policy`` imports any: the two
-checkpoint names)."""
+package only ``models/layers.py resolve_remat_policy`` and
+``models/indexed_attention.py`` import any: the checkpoint names)."""
 
 FLASH_FWD = "ds_flash_fwd"
 FLASH_BWD_DQ = "ds_flash_bwd_dq"
@@ -12,6 +12,13 @@ FLASH_BWD_DKV = "ds_flash_bwd_dkv"
 # them under every policy, so a ``jax.checkpoint`` replay holds no forward call
 FLASH_OUT = "ds_flash_out"
 FLASH_LSE = "ds_flash_lse"
+# the head-mean attention probabilities of a selection, from the saved
+# log-sum-exp (``sa_probs.py``)
+SA_PROBS = "ds_sa_probs"
+# ``checkpoint_name`` of a learned selection's bit-packed mask
+# (``models/indexed_attention.py``): kept under every remat policy, like the
+# two above, so a replay never selects again
+SA_MASK = "ds_sa_mask"
 RAGGED_PAGED_ATTENTION = "ds_ragged_paged_attention"
 DECODE_ATTENTION = "ds_decode_attention"
 PAGED_DECODE_ATTENTION = "ds_paged_decode_attention"

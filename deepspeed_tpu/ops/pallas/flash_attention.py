@@ -20,6 +20,16 @@ TPU flash pattern) and are initialised / written on the row's first / last
 entry. The forward holds a tile keys-by-queries, so those statistics are
 lane-dense rows (``_fwd_kernel``). A tile wholly inside the mask skips the
 iota / compare / where.
+
+A mask can also be DATA: ``flash_attention(..., mask=[B, Tq, Tk] int8)``, one
+set of visible keys a query shared by the heads (a learned selection,
+``models/indexed_attention.py``). The table then still comes from the causal
+rule and the window — which tiles the data empties is not known at trace time
+— and every kept tile reads its block of the mask in place of the iota rule
+(the mask has to lie inside that rule and give every query a key). That path
+differentiates (its own ``custom_vjp``, the three kernels with the mask as one
+more operand) and hands out the log-sum-exp beside the output. Without a mask
+no operand, branch or table entry differs from what they were.
 Backward uses the saved logsumexp and recomputes P per tile: one kernel for
 dQ (loop over kv), one for dK/dV (loop over q).
 
@@ -57,7 +67,8 @@ def _ceil_div(a, b):
 _FIRST, _LAST, _INSIDE, _CUT = 1, 2, 4, 8
 
 
-def _tile_table(tq, tk, block_q, block_k, causal, window, by_kv=False):
+def _tile_table(tq, tk, block_q, block_k, causal, window, by_kv=False,
+                dense_mask=False):
     """The tiles the mask keeps, as int32 rows ``[q tile, kv tile, flags]``.
 
     Causality is bottom-right aligned (offset = tk - tq), matching the decode
@@ -74,6 +85,9 @@ def _tile_table(tq, tk, block_q, block_k, causal, window, by_kv=False):
     tk < tq) and a kv row no query sees (a window with tk > tq) keep one
     placeholder that runs no body — the in-tile mask could not empty it
     (``exp(NEG_INF - NEG_INF)`` is 1) — so its outputs are zeros.
+
+    ``dense_mask``: a mask that is data rules inside every kept tile, so none
+    is ``_INSIDE``.
     """
     off = tk - tq
     r0 = (np.arange(_ceil_div(tq, block_q)) * block_q)[:, None]
@@ -89,7 +103,7 @@ def _tile_table(tq, tk, block_q, block_k, causal, window, by_kv=False):
     if window is not None:
         keep &= dmin < window
         inside &= dmax < window
-    flags = np.where(inside, _INSIDE, _CUT) * keep
+    flags = np.where(inside & (not dense_mask), _INSIDE, _CUT) * keep
     if by_kv:
         keep, flags = keep.T, flags.T
     keep[~keep.any(axis=1), 0] = True           # placeholders: flags 0
@@ -147,7 +161,8 @@ def _tile_valid(iq, ik, block_q, block_k, tq, tk, causal, window,
 
 def _fwd_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, *rest,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
-                tq: int, tk: int, window, has_mask: bool = False):
+                tq: int, tk: int, window, has_mask: bool = False,
+                dense_mask: bool = False):
     """The tile is held TRANSPOSED, ``sT [bk, bq]``: keys on the sublanes,
     queries on the lanes. The statistics are per query, so the running max,
     the running sum and the rescale factor are lane-dense ``[1, bq]`` rows,
@@ -157,7 +172,7 @@ def _fwd_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, *rest,
     ``_finalize``. With queries on the sublanes (``[bq, 1]`` columns, lane
     reductions, one live lane in 128) a kept 512 x 512 tile took 2.0 us on
     the v5e where this takes 1.45 (PERF.md section 6, PR 38)."""
-    if has_mask:
+    if has_mask or dense_mask:  # one or the other: [bk, 1] or [bk, bq]
         kmask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
@@ -177,7 +192,9 @@ def _fwd_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, *rest,
         st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * sm_scale
         valid = None
-        if cut:
+        if dense_mask:  # the tile's block of the [B, Tk, Tq] mask
+            valid = kmask_ref[0].astype(jnp.int32) != 0
+        elif cut:
             valid, _ = _tile_valid(iq, ik, block_q, block_k, tq, tk, causal,
                                    window, keys_first=True)
         if has_mask:  # [B, Tk] key-padding mask (left-padded prompts),
@@ -222,8 +239,14 @@ def _pad_seq(x, block):
     return x
 
 
+def _pad_mask(mask, rows, cols):
+    return jnp.pad(mask.astype(jnp.int8),
+                   ((0, 0), (0, rows - mask.shape[1]),
+                    (0, cols - mask.shape[2])))
+
+
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-               window=None, key_mask=None):
+               window=None, key_mask=None, mask=None):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     Hkv = k.shape[1]
@@ -237,11 +260,18 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     # pad to block multiples; kernels mask with the ORIGINAL lengths
     q, k, v = _pad_seq(q, bq), _pad_seq(k, bk), _pad_seq(v, bk)
     Tq_p, Tk_p = q.shape[2], k.shape[2]
-    table = _tile_table(Tq, Tk, bq, bk, causal, window)
+    table = _tile_table(Tq, Tk, bq, bk, causal, window,
+                        dense_mask=mask is not None)
 
     mask_args = []
     mask_specs = []
-    if key_mask is not None:
+    if mask is not None:
+        # keys first, as the kernel holds a tile; padding sees nothing
+        mask_args = [_pad_mask(jnp.swapaxes(mask, 1, 2), Tk_p, Tq_p)]
+        mask_specs = [pl.BlockSpec(
+            (1, bk, bq), lambda b, h, t, iq_of, ik_of, flags_of:
+            (b, ik_of[t], iq_of[t]))]
+    elif key_mask is not None:
         km = jnp.pad(key_mask.astype(jnp.int32),
                      ((0, 0), (0, Tk_p - key_mask.shape[1])))
         mask_args = [km[:, :, None]]
@@ -252,7 +282,8 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=bq, block_k=bk, tq=Tq, tk=Tk,
-                          window=window, has_mask=key_mask is not None),
+                          window=window, has_mask=key_mask is not None
+                          and mask is None, dense_mask=mask is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, H, table.shape[1]),
@@ -287,9 +318,10 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
 
 
 def _bwd_dq_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, do_ref,
-                   lse_ref, delta_ref, dq_ref, dq_scr, *,
+                   lse_ref, delta_ref, *rest,
                    sm_scale: float, causal: bool, block_q: int, block_k: int,
-                   tq: int, tk: int, window):
+                   tq: int, tk: int, window, dense_mask: bool = False):
+    mask_ref, dq_ref, dq_scr = rest if dense_mask else (None, *rest)
     t = pl.program_id(2)
     iq, ik, flags = iq_of[t], ik_of[t], flags_of[t]
 
@@ -306,7 +338,9 @@ def _bwd_dq_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, do_ref,
         delta = delta_ref[0, 0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-        if cut:
+        if dense_mask:
+            s = jnp.where(mask_ref[0].astype(jnp.int32) != 0, s, NEG_INF)
+        elif cut:
             valid, _ = _tile_valid(iq, ik, block_q, block_k, tq, tk, causal,
                                    window)
             s = jnp.where(valid, s, NEG_INF)
@@ -324,9 +358,12 @@ def _bwd_dq_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, do_ref,
 
 
 def _bwd_dkv_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, do_ref,
-                    lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
+                    lse_ref, delta_ref, *rest,
                     sm_scale: float, causal: bool, block_q: int,
-                    block_k: int, tq: int, tk: int, window):
+                    block_k: int, tq: int, tk: int, window,
+                    dense_mask: bool = False):
+    mask_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest if dense_mask \
+        else (None, *rest)
     t = pl.program_id(2)
     iq, ik, flags = iq_of[t], ik_of[t], flags_of[t]
 
@@ -344,12 +381,14 @@ def _bwd_dkv_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, do_ref,
         delta = delta_ref[0, 0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-        if cut:
+        if dense_mask:  # its padding empties the padded q rows too
+            s = jnp.where(mask_ref[0].astype(jnp.int32) != 0, s, NEG_INF)
+        elif cut:
             valid, rows = _tile_valid(iq, ik, block_q, block_k, tq, tk,
                                       causal, window)
             s = jnp.where(valid, s, NEG_INF)
         p = jnp.exp(s - lse)                    # [bq, bk]
-        if cut:  # padded q rows (lse 0) must contribute zero
+        if cut and not dense_mask:  # padded q rows (lse 0) must add zero
             p = jnp.where(rows < tq, p, 0.0)
         dv_scr[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
@@ -368,7 +407,7 @@ def _bwd_dkv_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, do_ref,
 
 
 def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
-               window=None):
+               window=None, mask=None):
     q, k, v, out, lse = res
     do = g
     B, H, Tq, D = q.shape
@@ -398,8 +437,16 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
     in_specs = [q_spec, k_spec, v_spec, do_spec, row_spec, row_spec]
     kernel_kw = dict(sm_scale=sm_scale, causal=causal, block_q=bq, block_k=bk,
                      tq=Tq, tk=Tk, window=window)
+    mask_args = []
+    if mask is not None:
+        mask_args = [_pad_mask(mask, Tq_p, Tk_p)]
+        in_specs = in_specs + [pl.BlockSpec(
+            (1, bq, bk), lambda b, h, t, iq_of, ik_of, flags_of:
+            (b, iq_of[t], ik_of[t]))]
+        kernel_kw["dense_mask"] = True
 
-    by_q = _tile_table(Tq, Tk, bq, bk, causal, window)
+    by_q = _tile_table(Tq, Tk, bq, bk, causal, window,
+                       dense_mask=mask is not None)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kernel_kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -412,9 +459,10 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
         out_shape=jax.ShapeDtypeStruct((B, H, Tq_p, D), q.dtype),
         interpret=interpret,
         name=FLASH_BWD_DQ,
-    )(*by_q, q, k, v, do, lse, delta)
+    )(*by_q, q, k, v, do, lse, delta, *mask_args)
 
-    by_kv = _tile_table(Tq, Tk, bq, bk, causal, window, by_kv=True)
+    by_kv = _tile_table(Tq, Tk, bq, bk, causal, window, by_kv=True,
+                        dense_mask=mask is not None)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **kernel_kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -433,7 +481,7 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
         ],
         interpret=interpret,
         name=FLASH_BWD_DKV,
-    )(*by_kv, q, k, v, do, lse, delta)
+    )(*by_kv, q, k, v, do, lse, delta, *mask_args)
     return dq[:, :, :Tq], dk[:, :, :Tk], dv[:, :, :Tk]
 
 
@@ -471,9 +519,40 @@ def _vjp_bwd(sm_scale, causal, block_q, block_k, interpret, window, res, g):
 _flash_attention_bhtd.defvjp(_vjp_fwd, _vjp_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_masked_bhtd(q, k, v, mask, sm_scale, causal, block_q, block_k,
+                       interpret, window=None):
+    """``(out, lse [B, H, Tq])`` under a mask that is data. The log-sum-exp
+    is handed out for readers that detach it (the head-mean probabilities of
+    ``sa_probs.py``): its cotangent is dropped."""
+    return _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
+                      window, mask=mask)
+
+
+def _masked_vjp_fwd(q, k, v, mask, sm_scale, causal, block_q, block_k,
+                    interpret, window=None):
+    out, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k,
+                          interpret, window, mask=mask)
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
+    return (out, lse), (q, k, v, mask, out, lse)
+
+
+def _masked_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, window,
+                    res, g):
+    *res, mask, out, lse = res
+    dq, dk, dv = _flash_bwd((*res, out, lse), g[0], sm_scale, causal,
+                            block_q, block_k, interpret, window, mask=mask)
+    return dq, dk, dv, None
+
+
+_flash_masked_bhtd.defvjp(_masked_vjp_fwd, _masked_vjp_bwd)
+
+
 def _reference_attention(q, k, v, causal, sm_scale, window=None,
-                         key_mask=None):
-    """[B,T,H,D] einsum reference (used on non-TPU backends)."""
+                         key_mask=None, mask=None):
+    """[B,T,H,D] einsum reference (used on non-TPU backends); with ``mask``
+    ``(out, lse [B, H, Tq])`` as the kernels give them."""
     if k.shape[2] != q.shape[2]:
         # GQA (masked fwd-only path accepts un-repeated kv heads): expand
         # consecutively, matching the kernel's h // rep index map
@@ -486,8 +565,8 @@ def _reference_attention(q, k, v, causal, sm_scale, window=None,
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * sm_scale
     Tq, Tk = q.shape[1], k.shape[1]
     if causal:
-        mask = jnp.tril(jnp.ones((Tq, Tk), bool), k=Tk - Tq)
-        logits = jnp.where(mask[None, None], logits, NEG_INF)
+        tril = jnp.tril(jnp.ones((Tq, Tk), bool), k=Tk - Tq)
+        logits = jnp.where(tril[None, None], logits, NEG_INF)
     if window is not None:
         i = jnp.arange(Tq)[:, None]
         j = jnp.arange(Tk)[None, :]
@@ -496,14 +575,19 @@ def _reference_attention(q, k, v, causal, sm_scale, window=None,
     if key_mask is not None:
         logits = jnp.where((key_mask > 0)[:, None, None, :], logits,
                            NEG_INF)
+    if mask is not None:
+        logits = jnp.where((mask != 0)[:, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    if mask is None:
+        return out
+    return out, jax.nn.logsumexp(logits, axis=-1)
 
 
 def flash_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512,
                     interpret: Optional[bool] = None, force_pallas: bool = False,
-                    window: Optional[int] = None, key_mask=None):
+                    window: Optional[int] = None, key_mask=None, mask=None):
     """Flash attention over [B, T, H, D] tensors.
 
     ``interpret=None`` auto-selects: real kernel on TPU, reference math
@@ -515,6 +599,12 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = No
     gradient through it falls to JAX's default AD over the kernel,
     which pallas_call does not support — use the unmasked path (drop
     padding via the loss mask) for training.
+
+    ``mask`` ``[B, Tq, Tk]`` (nonzero = query sees key) is a selection that
+    is data, shared by the heads; it lies inside the causal rule and the
+    window given here (they build the tile table) and gives every query a
+    key. This path differentiates, takes pre-repeated kv heads like the
+    unmasked one, and returns ``(out, lse [B, H, Tq] float32)``.
     """
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
@@ -522,12 +612,20 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = No
         on_tpu = jax.default_backend() == "tpu"
         if not on_tpu and not force_pallas:
             return _reference_attention(q, k, v, causal, sm_scale,
-                                        window=window, key_mask=key_mask)
+                                        window=window, key_mask=key_mask,
+                                        mask=mask)
         interpret = not on_tpu
 
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
+    if mask is not None:
+        if key_mask is not None or k.shape[2] != q.shape[2]:
+            raise ValueError("flash_attention(mask=...) takes pre-repeated "
+                             "kv heads and no key_mask")
+        out, lse = _flash_masked_bhtd(qt, kt, vt, mask, sm_scale, causal,
+                                      block_q, block_k, interpret, window)
+        return jnp.transpose(out, (0, 2, 1, 3)), lse
     if key_mask is not None:
         # fwd-only masked path; GQA rides the kv-head index map (no
         # repeat_kv materialization)

@@ -75,6 +75,27 @@ def _flash(bwd, window, shape=(1, 2048, H, D), v_dim=None):
     return jax.grad(loss, argnums=(0, 1, 2)), [q, q, v]
 
 
+def _flash_sa(bwd, T=16384):
+    """keye-vl2-30b-a3b.train.16k: a [B, T, T] int8 mask that is data,
+    shared by 32 heads of 128; the backward's two kernels read it too."""
+    q = ((1, T, H, D), BF16)
+    fwd = functools.partial(flash_attention, causal=True, interpret=False)
+    args = [q, q, q, ((1, T, T), jnp.int8)]
+    if not bwd:
+        return (lambda q, k, v, m: fwd(q, k, v, mask=m)), args
+    loss = lambda q, k, v, m: fwd(q, k, v, mask=m)[0].astype(
+        jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2)), args
+
+
+def _sa_probs(T=16384):
+    from deepspeed_tpu.ops.pallas.sa_probs import head_mean_probs
+
+    q = ((1, T, H, D), BF16)
+    return (functools.partial(head_mean_probs, interpret=False),
+            [q, q, ((1, H, T), jnp.float32), ((1, T, T), jnp.int8)])
+
+
 def _flash_key_mask():
     """Serving prefill (``layers.flash_prefill_from_empty``): forward only,
     kv heads un-repeated, a [B, Tk] key-padding mask."""
@@ -149,6 +170,9 @@ CASES = {
     # 2), plain causal
     "flash_fwd_bwd_cca_train8k": lambda: _flash(True, None, (1, 8192, 8, D)),
     "flash_fwd_key_mask_gqa": _flash_key_mask,
+    "flash_fwd_sa_train16k": lambda: _flash_sa(False),
+    "flash_fwd_bwd_sa_train16k": lambda: _flash_sa(True),
+    "sa_probs_train16k": _sa_probs,
     "ragged_bf16": lambda: _ragged(False, None),
     "ragged_bf16_window": lambda: _ragged(False, 4096),
     "ragged_int8": lambda: _ragged(True, None),

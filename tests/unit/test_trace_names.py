@@ -46,6 +46,11 @@ TRAIN_SCOPES = {
     "zaya": ["ds.loss_and_grad", "ds.optimizer", "ds.embed", "ds.attn_proj",
              "ds.cca_mix", "ds.attention", "ds.moe_router", "ds.moe_experts",
              "ds.moe_skip", "ds.lm_head_loss"],
+    # a learned selection: the indexer, the exact selection, the indexer's
+    # loss; ds.attention is the core under the selection
+    "keye": ["ds.loss_and_grad", "ds.optimizer", "ds.embed", "ds.attn_proj",
+             "ds.sa_index", "ds.sa_select", "ds.attention", "ds.sa_loss",
+             "ds.moe_router", "ds.moe_experts", "ds.lm_head_loss"],
 }
 #: what every family names besides: the engine's cast of the master weights,
 #: the loop over the layers, the block's two pre-norms and residual sums
@@ -63,7 +68,12 @@ def train_text():
             ("mixtral", MixtralForCausalLM(MixtralConfig.tiny(remat=True))),
             ("deepseek_v3", DeepseekV3ForCausalLM(
                 DeepseekV3Config.tiny(remat=True))),
-            ("zaya", ZayaForCausalLM(ZayaConfig.tiny(remat=True)))):
+            ("zaya", ZayaForCausalLM(ZayaConfig.tiny(remat=True))),
+            ("keye", MixtralForCausalLM(MixtralConfig.tiny(
+                remat=True, router_experts=16, first_expert=4,
+                sa_config=dict(indexer_head_dim=8, indexer_num_heads=2,
+                               q_chunk_size=16, kv_chunk_size=16,
+                               topk=8))))):
         batch = {"input_ids": np.zeros((8, 32), np.int32),
                  "labels": np.zeros((8, 32), np.int32)}
         engine, *_ = ds.initialize(
@@ -108,7 +118,7 @@ def trace_names():
 
 
 #: ``tracing.NAMES_VERSION`` beside the digest of the names it stands for
-NAMES_PIN = (2, "cd7023e9d9c6afc8")
+NAMES_PIN = (3, "2e34e584a4367720")
 
 
 def test_names_version_is_raised_with_the_names():
@@ -117,8 +127,9 @@ def test_names_version_is_raised_with_the_names():
     be read back from the cache as the old one. Raise the version, then
     put it and the new digest here."""
     scopes, spans = trace_names()
-    assert {"ds.param_cast", "ds.layer_stack", "ds.norm",
-            "ds.residual"} <= set(scopes) and "counters" in spans
+    assert {"ds.param_cast", "ds.layer_stack", "ds.norm", "ds.residual",
+            "ds.sa_index", "ds.sa_select", "ds.sa_loss"} <= set(scopes) \
+        and "counters" in spans
     digest = hashlib.sha256("\n".join(scopes + spans).encode()).hexdigest()
     assert (tracing.NAMES_VERSION, digest[:16]) == NAMES_PIN
 
@@ -312,6 +323,22 @@ def _block_sparse(bwd):
     return jax.grad(loss, argnums=(0, 1, 2)), [q, q, q]
 
 
+def _sa_probs():
+    from deepspeed_tpu.ops.pallas.sa_probs import head_mean_probs
+
+    q = ((1, 512, H, D), BF16)
+    fn = functools.partial(head_mean_probs, interpret=False,
+                           force_pallas=True)
+    return fn, [q, q, ((1, H, 512), jnp.float32), ((1, 512, 512), jnp.int8)]
+
+
+def _selection():
+    from deepspeed_tpu.models.indexed_attention import select_mask
+
+    return functools.partial(select_mask, topk=64), \
+        [((1, 512, 512), jnp.float32)]
+
+
 KERNELS = {
     names.FLASH_FWD: ("ds_flash_fwd", lambda: _flash(False)),
     names.FLASH_BWD_DQ: ("ds_flash_bwd_dq", lambda: _flash(True)),
@@ -331,13 +358,16 @@ KERNELS = {
                                 lambda: _block_sparse(True)),
     names.BLOCK_SPARSE_BWD_DKV: ("ds_block_sparse_bwd_dkv",
                                  lambda: _block_sparse(True)),
+    names.SA_PROBS: ("ds_sa_probs", lambda: _sa_probs()),
 }
 
 
-#: the two values of the flash forward that every remat policy keeps
-#: (``layers.resolve_remat_policy``): ``checkpoint_name``s, not kernels
-CHECKPOINT_NAMES = {names.FLASH_OUT: "ds_flash_out",
-                    names.FLASH_LSE: "ds_flash_lse"}
+#: the values every remat policy keeps (``layers.resolve_remat_policy``):
+#: ``checkpoint_name``s, not kernels — the flash forward's two and a learned
+#: selection's bit-packed mask — with a program that names each
+CHECKPOINT_NAMES = {names.FLASH_OUT: ("ds_flash_out", lambda: _flash(True)),
+                    names.FLASH_LSE: ("ds_flash_lse", lambda: _flash(True)),
+                    names.SA_MASK: ("ds_sa_mask", _selection)}
 
 
 def test_every_kernel_name_is_listed():
@@ -348,10 +378,11 @@ def test_every_kernel_name_is_listed():
 
 @pytest.mark.parametrize("constant", sorted(CHECKPOINT_NAMES))
 def test_flash_forward_names_what_the_backward_reads(constant):
-    """A remat policy finds the kernel's output and log-sum-exp by these
-    spellings in the differentiated program."""
-    assert constant == CHECKPOINT_NAMES[constant]
-    fn, args = _flash(True)
+    """A remat policy finds the kernel's output and log-sum-exp, and a
+    selection's mask, by these spellings in the program."""
+    spelled, case = CHECKPOINT_NAMES[constant]
+    assert constant == spelled
+    fn, args = case()
     shapes = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in args]
     assert f"name[name={constant}]" in str(jax.make_jaxpr(fn)(*shapes))
 
