@@ -22,8 +22,10 @@ only and the indexer's the KL term's only (the selection passes none).
 
 Everything here works on ``[B, T, ...]`` in blocks of query rows so that
 16k positions fit: the XLA paths never hold more than ``[heads, block, T]``
-scores. ``attention_impl="flash"`` runs the core through the flash kernels
-under the mask (``ops/pallas/flash_attention.py``) and reads the head-mean
+scores. ``attention_impl="flash"`` takes the index scores and their gradient
+from the kernels of ``ops/pallas/sa_index.py`` (the causal tiles only, a tile
+of products at a time), runs the core through the flash kernels under the
+mask (``ops/pallas/flash_attention.py``) and reads the head-mean
 probabilities from ``ops/pallas/sa_probs.py``, which reuses the saved
 log-sum-exp.
 """
@@ -224,7 +226,16 @@ def indexed_attention(cfg, x, q, k, v, cos, sin):
     with jax.named_scope("ds.sa_index"):
         qi, ki, w = Indexer(cfg, name="indexer")(
             jax.lax.stop_gradient(x), cos, sin)
-        scores = index_scores(qi, ki, w, block)
+        if cfg.attention_impl == "flash":
+            from ..ops.pallas import sa_index
+
+            # tiles above the diagonal stay unwritten: every reader below
+            # applies the causal rule through a ``where``
+            scores = sa_index.index_scores(
+                qi, ki, w, block_q=cfg.flash_block_q,
+                block_k=cfg.flash_block_k)
+        else:
+            scores = index_scores(qi, ki, w, block)
     with jax.named_scope("ds.sa_select"):
         mask = select_mask(scores, sa.topk, block)
         stats = {"sa_kept_tile_share": kept_tile_share(

@@ -15,6 +15,11 @@ FLASH_LSE = "ds_flash_lse"
 # the head-mean attention probabilities of a selection, from the saved
 # log-sum-exp (``sa_probs.py``)
 SA_PROBS = "ds_sa_probs"
+# the indexer's scores of that attention over the causal tiles, and their
+# backward's two kernels (``sa_index.py``)
+SA_INDEX_FWD = "ds_sa_index_fwd"
+SA_INDEX_BWD_DQ = "ds_sa_index_bwd_dq"
+SA_INDEX_BWD_DK = "ds_sa_index_bwd_dk"
 # ``checkpoint_name`` of a learned selection's bit-packed mask
 # (``models/indexed_attention.py``): kept under every remat policy, like the
 # two above, so a replay never selects again

@@ -96,6 +96,21 @@ def _sa_probs(T=16384):
             [q, q, ((1, H, T), jnp.float32), ((1, T, T), jnp.int8)])
 
 
+def _sa_index(bwd, T=16384):
+    """keye-vl2-30b-a3b.train.16k: the indexer's 16 heads of 64 over one
+    shared key, scores of every causal 512 x 512 tile; the backward's two
+    kernels recompute the products from the same three inputs."""
+    from deepspeed_tpu.ops.pallas.sa_index import index_scores
+
+    args = [((1, T, 16, 64), BF16), ((1, T, 64), BF16),
+            ((1, T, 16), jnp.float32)]
+    fwd = functools.partial(index_scores, interpret=False)
+    if not bwd:
+        return fwd, args
+    loss = lambda qi, ki, w: jnp.sum(jnp.tril(fwd(qi, ki, w)[0]))
+    return jax.grad(loss, argnums=(0, 1, 2)), args
+
+
 def _flash_key_mask():
     """Serving prefill (``layers.flash_prefill_from_empty``): forward only,
     kv heads un-repeated, a [B, Tk] key-padding mask."""
@@ -173,6 +188,8 @@ CASES = {
     "flash_fwd_sa_train16k": lambda: _flash_sa(False),
     "flash_fwd_bwd_sa_train16k": lambda: _flash_sa(True),
     "sa_probs_train16k": _sa_probs,
+    "sa_index_train16k": lambda: _sa_index(False),
+    "sa_index_bwd_train16k": lambda: _sa_index(True),
     "ragged_bf16": lambda: _ragged(False, None),
     "ragged_bf16_window": lambda: _ragged(False, 4096),
     "ragged_int8": lambda: _ragged(True, None),

@@ -332,6 +332,18 @@ def _sa_probs():
     return fn, [q, q, ((1, H, 512), jnp.float32), ((1, 512, 512), jnp.int8)]
 
 
+def _sa_index(bwd):
+    from deepspeed_tpu.ops.pallas.sa_index import index_scores
+
+    fwd = functools.partial(index_scores, interpret=False)
+    args = [((1, 512, 2, 64), BF16), ((1, 512, 64), BF16),
+            ((1, 512, 2), jnp.float32)]
+    if not bwd:
+        return fwd, args
+    loss = lambda qi, ki, w: jnp.tril(fwd(qi, ki, w)[0]).sum()
+    return jax.grad(loss, argnums=(0, 1, 2)), args
+
+
 def _selection():
     from deepspeed_tpu.models.indexed_attention import select_mask
 
@@ -359,6 +371,9 @@ KERNELS = {
     names.BLOCK_SPARSE_BWD_DKV: ("ds_block_sparse_bwd_dkv",
                                  lambda: _block_sparse(True)),
     names.SA_PROBS: ("ds_sa_probs", lambda: _sa_probs()),
+    names.SA_INDEX_FWD: ("ds_sa_index_fwd", lambda: _sa_index(False)),
+    names.SA_INDEX_BWD_DQ: ("ds_sa_index_bwd_dq", lambda: _sa_index(True)),
+    names.SA_INDEX_BWD_DK: ("ds_sa_index_bwd_dk", lambda: _sa_index(True)),
 }
 
 
@@ -399,6 +414,47 @@ def test_kernel_lowers_under_its_name(constant):
     text = jax.jit(fn).trace(*shapes).lower(
         lowering_platforms=("tpu",)).as_text()
     assert f'kernel_name = "{spelled}"' in text
+
+
+def test_indexer_kernels_stand_under_their_scope(monkeypatch):
+    """A ``sa_config`` model with ``attention_impl="flash"``, its gradient
+    lowered for the TPU: the scores' forward kernel stands twice under
+    ``ds.sa_index`` (the forward pass and the layer's replay: 8 calls a step
+    at keye 16k's depth 4), each backward kernel once (4), and nowhere else
+    — ``train.sa_index_share`` reads the whole indexer by that scope."""
+    from deepspeed_tpu.ops.pallas import sa_index
+
+    # the model asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(sa_index, "index_scores", functools.partial(
+        sa_index.index_scores, interpret=False))
+    model = MixtralForCausalLM(MixtralConfig.tiny(
+        remat=True, attention_impl="flash", hidden_size=128,
+        num_attention_heads=2, num_key_value_heads=1,
+        max_position_embeddings=512,
+        sa_config=dict(indexer_head_dim=64, indexer_num_heads=2,
+                       q_chunk_size=128, kv_chunk_size=128, topk=64)))
+    ids = jnp.zeros((1, 256), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
+    loss = lambda p, ids: model.apply({"params": p}, ids, labels=ids)[0]
+    text = jax.jit(jax.grad(loss)).trace(params, ids).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    called = re.findall(r'kernel_name = "(ds_sa_index_\w+)"', text)
+    assert sorted(called) == ["ds_sa_index_bwd_dk", "ds_sa_index_bwd_dq",
+                              "ds_sa_index_fwd", "ds_sa_index_fwd"]
+    scoped = re.findall(r"ds\.sa_index/(ds_sa_index_\w+)/pallas_call", text)
+    assert sorted(scoped) == sorted(called)
+    replayed = re.findall(
+        r"rematted_computation/\S*ds\.sa_index/(ds_sa_index_\w+)/", text)
+    assert replayed == ["ds_sa_index_fwd"]
+
+
+def test_no_step_without_the_flash_indexer_holds_its_kernels(train_text):
+    """The other families' steps, and a ``sa_config`` step on the XLA path,
+    carry none of the three kernels: their programs are what they were."""
+    assert set(train_text) == set(TRAIN_SCOPES)
+    for family, text in train_text.items():
+        assert "ds_sa_index" not in text, family
 
 
 @pytest.mark.parametrize("gas", [1, 2])
