@@ -34,3 +34,7 @@ FUSED_ADAM = "ds_fused_adam"
 BLOCK_SPARSE_FWD = "ds_block_sparse_fwd"
 BLOCK_SPARSE_BWD_DQ = "ds_block_sparse_bwd_dq"
 BLOCK_SPARSE_BWD_DKV = "ds_block_sparse_bwd_dkv"
+# the selective scan of a state-space layer, a chunk of the sequence at a
+# time with the state in VMEM, and its backward (``selective_scan.py``)
+SSM_SCAN_FWD = "ds_ssm_scan_fwd"
+SSM_SCAN_BWD = "ds_ssm_scan_bwd"

@@ -111,6 +111,22 @@ def _sa_index(bwd, T=16384):
     return jax.grad(loss, argnums=(0, 1, 2)), args
 
 
+def _ssm_scan(bwd, T=8192, C=5120, N=16):
+    """phi4-mini-flash.train.8k: one Mamba layer's selective scan, 5,120
+    channels of 16 states over 8,192 positions, bf16 ``u`` beside float32
+    ``delta``; the backward recomputes a chunk's states in VMEM."""
+    from deepspeed_tpu.ops.pallas.selective_scan import selective_scan
+
+    args = [((1, T, C), BF16), ((1, T, C), jnp.float32),
+            ((C, N), jnp.float32), ((1, T, N), BF16), ((1, T, N), BF16),
+            ((C,), jnp.float32)]
+    fwd = functools.partial(selective_scan, interpret=False)
+    if not bwd:
+        return fwd, args
+    return jax.grad(lambda *a: jnp.sum(fwd(*a)), argnums=tuple(range(6))), \
+        args
+
+
 def _flash_key_mask():
     """Serving prefill (``layers.flash_prefill_from_empty``): forward only,
     kv heads un-repeated, a [B, Tk] key-padding mask."""
@@ -190,6 +206,8 @@ CASES = {
     "sa_probs_train16k": _sa_probs,
     "sa_index_train16k": lambda: _sa_index(False),
     "sa_index_bwd_train16k": lambda: _sa_index(True),
+    "ssm_scan_train8k": lambda: _ssm_scan(False),
+    "ssm_scan_bwd_train8k": lambda: _ssm_scan(True),
     "ragged_bf16": lambda: _ragged(False, None),
     "ragged_bf16_window": lambda: _ragged(False, 4096),
     "ragged_int8": lambda: _ragged(True, None),
