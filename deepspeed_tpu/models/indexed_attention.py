@@ -49,8 +49,9 @@ _INT_MIN = np.iinfo(np.int32).min
 class SparseAttentionConfig:
     """Keye-VL-2.0's ``sa_config``, key for key. ``q_chunk_size`` /
     ``kv_chunk_size`` are read as tile sizes (the rows a block of the XLA
-    paths holds, the tiles ``sa_kept_tile_share`` counts); the selection is
-    per token."""
+    paths holds, and on those paths the tiles ``sa_kept_tile_share`` counts;
+    under ``attention_impl="flash"`` it counts the kernels' own
+    ``flash_block_q x flash_block_k`` tiles); the selection is per token."""
     indexer_head_dim: int = 64
     indexer_num_heads: int = 16
     indexer_num_kv_heads: int = 1
@@ -173,14 +174,16 @@ def select_mask(scores, topk, block=512):
     return jnp.unpackbits(packed, axis=-1, count=T).astype(jnp.int8)
 
 
-def kept_tile_share(mask, tile=512):
+def kept_tile_share(tiles, block_q=512, block_k=512):
     """Tiles of the causal triangle that hold at least one selected pair,
-    over its tiles: what a tile table built from the mask could skip."""
-    B, T, _ = mask.shape
-    b = _block(T, tile)
-    n = T // b
-    kept = jnp.any(mask.reshape(B, n, b, n, b) != 0, axis=(2, 4))
-    return jnp.sum(kept, dtype=jnp.float32) / (B * n * (n + 1) // 2)
+    over its tiles, from ``tiles [nq, nk]`` bool
+    (``flash_attention.mask_tiles``): the share of the causal entries that the
+    flash kernels' tile table keeps under this mask — the same array builds
+    that table."""
+    nq, nk = tiles.shape
+    causal = np.arange(nk)[None] * block_k < (np.arange(nq)[:, None] + 1) \
+        * block_q
+    return jnp.sum(tiles, dtype=jnp.float32) / causal.sum()
 
 
 def masked_attention_xla(q, k, v, mask, block=512):
@@ -236,12 +239,18 @@ def indexed_attention(cfg, x, q, k, v, cos, sin):
                 block_k=cfg.flash_block_k)
         else:
             scores = index_scores(qi, ki, w, block)
+    from ..ops.pallas.flash_attention import flash_attention, mask_tiles
+
+    flash = cfg.attention_impl == "flash"
+    bq, bk = (cfg.flash_block_q, cfg.flash_block_k) if flash else \
+        (sa.q_chunk_size, sa.kv_chunk_size)
     with jax.named_scope("ds.sa_select"):
         mask = select_mask(scores, sa.topk, block)
-        stats = {"sa_kept_tile_share": kept_tile_share(
-            mask, sa.kv_chunk_size)} if cfg.report_expert_load else {}
-    if cfg.attention_impl == "flash":
-        from ..ops.pallas.flash_attention import flash_attention
+        # the flash kernels' tile tables and the counter read this one array
+        tiles = mask_tiles(mask, bq, bk)
+        stats = {"sa_kept_tile_share": kept_tile_share(tiles, bq, bk)} \
+            if cfg.report_expert_load else {}
+    if flash:
         from ..ops.pallas.sa_probs import head_mean_probs
         from .layers import repeat_kv
 
@@ -249,12 +258,11 @@ def indexed_attention(cfg, x, q, k, v, cos, sin):
         k, v = repeat_kv(k, rep), repeat_kv(v, rep)
         with jax.named_scope("ds.attention"):
             out, lse = flash_attention(
-                q, k, v, causal=True, block_q=cfg.flash_block_q,
-                block_k=cfg.flash_block_k, mask=mask)
+                q, k, v, causal=True, block_q=bq, block_k=bk, mask=mask,
+                tiles=tiles)
         with jax.named_scope("ds.sa_loss"):
             p_hat = head_mean_probs(
-                q, k, lse, mask, block_q=cfg.flash_block_q,
-                block_k=cfg.flash_block_k)
+                q, k, lse, mask, block_q=bq, block_k=bk, tiles=tiles)
     else:
         with jax.named_scope("ds.attention"):
             out, p_hat = masked_attention_xla(q, k, v, mask, block)

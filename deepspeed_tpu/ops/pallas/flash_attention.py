@@ -23,13 +23,13 @@ iota / compare / where.
 
 A mask can also be DATA: ``flash_attention(..., mask=[B, Tq, Tk] int8)``, one
 set of visible keys a query shared by the heads (a learned selection,
-``models/indexed_attention.py``). The table then still comes from the causal
-rule and the window — which tiles the data empties is not known at trace time
-— and every kept tile reads its block of the mask in place of the iota rule
-(the mask has to lie inside that rule and give every query a key). That path
-differentiates (its own ``custom_vjp``, the three kernels with the mask as one
-more operand) and hands out the log-sum-exp beside the output. Without a mask
-no operand, branch or table entry differs from what they were.
+``models/indexed_attention.py``). The table is then DATA too: the rule's
+entries that hold a selected pair (``mask_tiles``), compacted on the device
+(``_mask_tile_table``, at the file's end) and prefetched like the constant,
+the grid's last dimension their traced count; a kept tile reads its block of
+the mask in place of the iota rule. That path differentiates (its own
+``custom_vjp``, the mask one more operand) and hands out the log-sum-exp too.
+Without a mask no operand, branch or table entry differs from what they were.
 Backward uses the saved logsumexp and recomputes P per tile: one kernel for
 dQ (loop over kv), one for dK/dV (loop over q).
 
@@ -246,7 +246,7 @@ def _pad_mask(mask, rows, cols):
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-               window=None, key_mask=None, mask=None):
+               window=None, key_mask=None, mask=None, tiles=None):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     Hkv = k.shape[1]
@@ -260,8 +260,8 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     # pad to block multiples; kernels mask with the ORIGINAL lengths
     q, k, v = _pad_seq(q, bq), _pad_seq(k, bk), _pad_seq(v, bk)
     Tq_p, Tk_p = q.shape[2], k.shape[2]
-    table = _tile_table(Tq, Tk, bq, bk, causal, window,
-                        dense_mask=mask is not None)
+    # numpy constants, or built on the device from the mask's tiles
+    table, steps = _table(tiles, Tq, Tk, bq, bk, causal, window)
 
     mask_args = []
     mask_specs = []
@@ -286,7 +286,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
                           and mask is None, dense_mask=mask is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, H, table.shape[1]),
+            grid=(B, H, steps),
             in_specs=[
                 pl.BlockSpec((1, 1, bq, D), _q_tile),
                 pl.BlockSpec((1, 1, bk, D), _kv_tile(rep)),
@@ -407,7 +407,7 @@ def _bwd_dkv_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, do_ref,
 
 
 def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
-               window=None, mask=None):
+               window=None, mask=None, tiles=None):
     q, k, v, out, lse = res
     do = g
     B, H, Tq, D = q.shape
@@ -445,13 +445,13 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
             (b, iq_of[t], ik_of[t]))]
         kernel_kw["dense_mask"] = True
 
-    by_q = _tile_table(Tq, Tk, bq, bk, causal, window,
-                       dense_mask=mask is not None)
+    # the same entries twice: by q row here, by kv row for dK/dV below
+    by_q, steps = _table(tiles, Tq, Tk, bq, bk, causal, window)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kernel_kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, H, by_q.shape[1]),
+            grid=(B, H, steps),
             in_specs=in_specs,
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
@@ -461,13 +461,13 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
         name=FLASH_BWD_DQ,
     )(*by_q, q, k, v, do, lse, delta, *mask_args)
 
-    by_kv = _tile_table(Tq, Tk, bq, bk, causal, window, by_kv=True,
-                        dense_mask=mask is not None)
+    by_kv, steps = _table(tiles, Tq, Tk, bq, bk, causal, window,
+                          by_kv=True)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **kernel_kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, H, by_kv.shape[1]),
+            grid=(B, H, steps),
             in_specs=in_specs,
             out_specs=[k_spec, v_spec],
             scratch_shapes=[
@@ -519,31 +519,31 @@ def _vjp_bwd(sm_scale, causal, block_q, block_k, interpret, window, res, g):
 _flash_attention_bhtd.defvjp(_vjp_fwd, _vjp_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash_masked_bhtd(q, k, v, mask, sm_scale, causal, block_q, block_k,
-                       interpret, window=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash_masked_bhtd(q, k, v, mask, tiles, sm_scale, causal, block_q,
+                       block_k, interpret, window=None):
     """``(out, lse [B, H, Tq])`` under a mask that is data. The log-sum-exp
     is handed out for readers that detach it (the head-mean probabilities of
     ``sa_probs.py``): its cotangent is dropped."""
     return _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-                      window, mask=mask)
+                      window, mask=mask, tiles=tiles)
 
 
-def _masked_vjp_fwd(q, k, v, mask, sm_scale, causal, block_q, block_k,
+def _masked_vjp_fwd(q, k, v, mask, tiles, sm_scale, causal, block_q, block_k,
                     interpret, window=None):
     out, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k,
-                          interpret, window, mask=mask)
+                          interpret, window, mask=mask, tiles=tiles)
     out = checkpoint_name(out, FLASH_OUT)
     lse = checkpoint_name(lse, FLASH_LSE)
-    return (out, lse), (q, k, v, mask, out, lse)
+    return (out, lse), (q, k, v, mask, tiles, out, lse)
 
 
 def _masked_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, window,
                     res, g):
-    *res, mask, out, lse = res
-    dq, dk, dv = _flash_bwd((*res, out, lse), g[0], sm_scale, causal,
-                            block_q, block_k, interpret, window, mask=mask)
-    return dq, dk, dv, None
+    *res, mask, tiles, out, lse = res
+    dq, dk, dv = _flash_bwd((*res, out, lse), g[0], sm_scale, causal, block_q,
+                            block_k, interpret, window, mask, tiles)
+    return dq, dk, dv, None, None
 
 
 _flash_masked_bhtd.defvjp(_masked_vjp_fwd, _masked_vjp_bwd)
@@ -587,7 +587,8 @@ def _reference_attention(q, k, v, causal, sm_scale, window=None,
 def flash_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512,
                     interpret: Optional[bool] = None, force_pallas: bool = False,
-                    window: Optional[int] = None, key_mask=None, mask=None):
+                    window: Optional[int] = None, key_mask=None, mask=None,
+                    tiles=None):
     """Flash attention over [B, T, H, D] tensors.
 
     ``interpret=None`` auto-selects: real kernel on TPU, reference math
@@ -601,19 +602,17 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = No
     padding via the loss mask) for training.
 
     ``mask`` ``[B, Tq, Tk]`` (nonzero = query sees key) is a selection that
-    is data, shared by the heads; it lies inside the causal rule and the
-    window given here (they build the tile table) and gives every query a
-    key. This path differentiates, takes pre-repeated kv heads like the
-    unmasked one, and returns ``(out, lse [B, H, Tq] float32)``.
+    is data, shared by the heads, inside the causal rule and the window given
+    here, with a key for every query (``tiles``: its ``mask_tiles``, if held).
+    Differentiates; pre-repeated kv heads; ``(out, lse [B, H, Tq] float32)``.
     """
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     if interpret is None:
         on_tpu = jax.default_backend() == "tpu"
         if not on_tpu and not force_pallas:
-            return _reference_attention(q, k, v, causal, sm_scale,
-                                        window=window, key_mask=key_mask,
-                                        mask=mask)
+            return _reference_attention(q, k, v, causal, sm_scale, window=window,
+                                        key_mask=key_mask, mask=mask)
         interpret = not on_tpu
 
     qt = jnp.transpose(q, (0, 2, 1, 3))
@@ -621,9 +620,10 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = No
     vt = jnp.transpose(v, (0, 2, 1, 3))
     if mask is not None:
         if key_mask is not None or k.shape[2] != q.shape[2]:
-            raise ValueError("flash_attention(mask=...) takes pre-repeated "
-                             "kv heads and no key_mask")
-        out, lse = _flash_masked_bhtd(qt, kt, vt, mask, sm_scale, causal,
+            raise ValueError("mask= takes pre-repeated kv heads, no key_mask")
+        if tiles is None:
+            tiles = mask_tiles(mask, block_q, block_k)
+        out, lse = _flash_masked_bhtd(qt, kt, vt, mask, tiles, sm_scale, causal,
                                       block_q, block_k, interpret, window)
         return jnp.transpose(out, (0, 2, 1, 3)), lse
     if key_mask is not None:
@@ -641,3 +641,77 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = No
         out = _flash_attention_bhtd(qt, kt, vt, sm_scale, causal,
                                     block_q, block_k, interpret, window)
     return jnp.transpose(out, (0, 2, 1, 3))
+
+
+# ---------------------------------------------------------------------------
+# the tile table under a mask that is data (below the rest of the file: a
+# kernel's cache key holds the line numbers of the frames that call it)
+# ---------------------------------------------------------------------------
+
+
+def mask_tiles(mask, block_q: int = 512, block_k: int = 512):
+    """bool ``[nq, nk]``: the ``block_q x block_k`` tiles of ``mask
+    [B, Tq, Tk]`` in which some sequence of the batch selects a pair. One
+    pass over the mask; the tile tables of all the kernels under it
+    (``flash_attention(tiles=)``, ``sa_probs.head_mean_probs(tiles=)``) and
+    the counter ``indexed_attention.kept_tile_share`` read this one array.
+
+    The rows of a tile are folded first (an element-wise max and min of int8
+    rows: any nonzero byte counts) and only the ``[nq, Tk]`` that leaves is
+    reduced along the lanes; the barrier keeps XLA from fusing the mask's
+    producer into that fold. At ``[1, 16384, 16384]`` on a v5e: 0.36 ms a
+    call; 2.2 ms where the fold took ``unpackbits`` in with it, 2.0 as one
+    ``any`` over both axes of a tile (PERF.md section 6, PR 42)."""
+    B, Tq, Tk = mask.shape
+    bq, bk = min(block_q, Tq), min(block_k, Tk)
+    nq, nk = _ceil_div(Tq, bq), _ceil_div(Tk, bk)
+    mask = jax.lax.optimization_barrier(_pad_mask(mask, nq * bq, nk * bk))
+    rows = mask.reshape(B, nq, bq, nk * bk)
+    rows = (jnp.max(rows, axis=(0, 2)) != 0) | (jnp.min(rows, axis=(0, 2)) != 0)
+    return jnp.any(rows.reshape(nq, nk, bk), axis=2)
+
+
+def _mask_tile_table(tiles, static, by_kv=False):
+    """``_tile_table``'s rows with the entries ``tiles`` empties taken out,
+    built on the device: ``(int32 [3, n], count)``, the static table's
+    length ``n`` with the ``count`` live entries in front. The kernels' grid
+    walks ``count`` steps (a traced grid dimension): a tile the mask empties
+    is neither a grid step nor a fetch, as one the rule empties.
+
+    The live entries keep the static order (stable), every one ``_CUT`` (the
+    mask's block rules inside it), ``_FIRST`` / ``_LAST`` from its new
+    neighbours. A row of output tiles none of whose entries is kept holds
+    its first one (by kv row: the diagonal) as a placeholder that runs no
+    body, as ``_tile_table`` does for a row the rule empties: its block is
+    still initialised and written, as zeros. Past ``count`` lie the dropped
+    entries, which no grid step reads."""
+    iq, ik, word = static
+    first = word & _FIRST != 0
+    row = np.cumsum(first) - 1                  # of output tiles, per entry
+    start = np.flatnonzero(first)[row]
+    end = np.flatnonzero(word & _LAST != 0)[row]
+    body = jnp.asarray(word & _CUT != 0) & tiles[iq, ik]
+    seen = jnp.cumsum(body, dtype=jnp.int32)
+    empty_row = seen[end] - seen[start] + body[start] == 0
+    keep = body | (jnp.asarray(first) & empty_row)
+    count = jnp.sum(keep, dtype=jnp.int32)
+    at = jnp.argsort(~keep, stable=True)
+    outer = jnp.asarray(ik if by_kv else iq)[at]
+    edge = outer[1:] != outer[:-1]
+    new = body[at] * _CUT \
+        | jnp.pad(edge, (1, 0), constant_values=True) * _FIRST \
+        | (jnp.pad(edge, (0, 1)) | (jnp.arange(at.size) == count - 1)) * _LAST
+    return jnp.stack([jnp.asarray(iq)[at], jnp.asarray(ik)[at],
+                      new]).astype(jnp.int32), count
+
+
+def _table(tiles, tq, tk, block_q, block_k, causal, window, by_kv=False):
+    """The kernels' tile table and the steps their grid walks it:
+    ``_tile_table``'s numpy constant and its length under a rule alone, a
+    device array of the same form and a traced count under a mask with
+    ``tiles``."""
+    static = _tile_table(tq, tk, block_q, block_k, causal, window, by_kv,
+                         dense_mask=tiles is not None)
+    if tiles is None:
+        return static, static.shape[1]
+    return _mask_tile_table(tiles, static, by_kv)

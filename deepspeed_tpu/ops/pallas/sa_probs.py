@@ -6,11 +6,16 @@ pairs, which no flash kernel materialises. This kernel recomputes ``P`` tile
 by tile from the queries, the keys and the forward kernel's SAVED log-sum-exp
 — as ``flash_attention._bwd_dq_kernel`` does — and sums it over the heads: no
 second softmax, no ``[H, T, T]`` tensor. The grid is ``(B, tiles, H)`` over
-the flash kernels' causal tile table with the heads innermost, so a tile of
+the flash kernels' tile table with the heads innermost, so a tile of
 ``p^`` stays in VMEM while its ``H`` terms are added and is written once.
+That table is the one the flash kernels walk under this mask, by q row: the
+causal entries whose tile holds a selected pair
+(``flash_attention.mask_tiles``), compacted on the device, their traced count
+the grid's middle dimension.
 
-Forward only: ``p^`` is detached from its inputs. Tiles the causal rule drops are
-never written; the caller reads ``p^`` under the mask.
+Forward only: ``p^`` is detached from its inputs. Tiles the causal rule drops
+and tiles the selection leaves empty are never written; the caller reads
+``p^`` under the mask.
 """
 
 import functools
@@ -23,7 +28,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import SA_PROBS
-from .flash_attention import NEG_INF, _pad_mask, _pad_seq, _tile_table
+from .flash_attention import NEG_INF, _pad_mask, _pad_seq, _table, mask_tiles
 
 
 def _probs_kernel(iq_of, ik_of, q_ref, k_ref, lse_ref, mask_ref, p_ref, *,
@@ -57,11 +62,12 @@ def _reference(q, k, lse, mask, sm_scale):
 def head_mean_probs(q, k, lse, mask, sm_scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512,
                     interpret: Optional[bool] = None,
-                    force_pallas: bool = False):
+                    force_pallas: bool = False, tiles=None):
     """``p^ [B, T, T]`` float32 from ``q``, ``k`` ``[B, T, H, D]`` (keys
     repeated to the query heads, as the flash training path takes them),
     the flash forward's ``lse [B, H, T]`` and the selection ``mask
-    [B, T, T]``. Entries outside the causal triangle's tiles are undefined.
+    [B, T, T]`` (``tiles``: its ``mask_tiles``, where the caller holds
+    them). Entries outside the tiles the selection keeps are undefined.
     ``interpret=None``: the kernel on a TPU, einsum math elsewhere."""
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
@@ -78,12 +84,16 @@ def head_mean_probs(q, k, lse, mask, sm_scale: Optional[float] = None,
     kt = _pad_seq(jnp.transpose(k, (0, 2, 1, 3)), bk)
     Tq_p, Tk_p = qt.shape[2], kt.shape[2]
     lse = jnp.pad(lse, ((0, 0), (0, 0), (0, Tq_p - T)))[:, :, None]
-    iq_of, ik_of, _ = _tile_table(T, T, bq, bk, True, None)
+    if tiles is None:
+        tiles = mask_tiles(mask, block_q, block_k)
+    # a row's body-less placeholder (a q row without a key) runs like any
+    # tile here and writes the zeros its empty mask block gives
+    (iq_of, ik_of, _), steps = _table(tiles, T, T, bq, bk, True, None)
     out = pl.pallas_call(
         functools.partial(_probs_kernel, sm_scale=sm_scale, heads=H),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, iq_of.shape[0], H),
+            grid=(B, steps, H),
             in_specs=[
                 pl.BlockSpec((1, 1, bq, D),
                              lambda b, t, h, iq_of, ik_of:

@@ -186,15 +186,20 @@ def test_key_mask_path_gqa_native_kv_heads():
 # -- the static tile table ----------------------------------------------------
 
 
-def _dense_tiles(tq, tk, bq, bk, causal, window):
-    """[nq, nk] "any entry visible" and "every entry visible" of the dense
-    mask, padded tails counting as not visible."""
+def _rule_mask(tq, tk, causal, window):
     d = np.arange(tq)[:, None] + (tk - tq) - np.arange(tk)[None, :]
     mask = np.ones((tq, tk), bool)
     if causal:
         mask &= d >= 0
     if window is not None:
         mask &= d < window
+    return mask
+
+
+def _dense_tiles(tq, tk, bq, bk, causal, window):
+    """[nq, nk] "any entry visible" and "every entry visible" of the dense
+    mask, padded tails counting as not visible."""
+    mask = _rule_mask(tq, tk, causal, window)
     nq, nk = -(-tq // bq), -(-tk // bk)
     padded = np.zeros((nq * bq, nk * bk), bool)
     padded[:tq, :tk] = mask
@@ -612,3 +617,199 @@ def test_remat_policy_answers_as_its_name_but_for_the_two_names(policy):
                 dimension_numbers=(((2,), (1,)), ((0,), (0,))), **dot)),
             (jax.lax.exp_p, dict(accuracy=None))]:
         assert repr(got(prim, **params)) == repr(base(prim, **params))
+
+
+# -- the tile table under a mask that is data ---------------------------------
+
+
+@pytest.mark.parametrize("by_kv", [False, True], ids=["by_q", "by_kv"])
+@pytest.mark.parametrize("tq,tk,bq,bk,causal,window", [
+    (256, 256, 64, 64, True, None),      # the plain triangle
+    (256, 256, 32, 64, True, 100),       # bq != bk, a window off the grid
+    (96, 96, 64, 64, True, None),        # ragged tails
+    (100, 200, 32, 64, True, 48),        # ragged, tq < tk, window
+    (32, 128, 16, 32, True, 16),         # kv rows no query sees
+    (96, 32, 32, 32, True, None),        # q rows that see no key
+    (200, 100, 64, 32, False, 24),       # a window without causality
+])
+def test_mask_tile_table_of_the_whole_rule_is_the_static_table(
+        tq, tk, bq, bk, causal, window, by_kv):
+    """A mask that holds every pair of the rule empties no tile: the table
+    built on the device is ``_tile_table``'s, entry for entry, placeholders
+    of the rule included."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        _mask_tile_table, _tile_table, mask_tiles)
+
+    mask = jnp.asarray(_rule_mask(tq, tk, causal, window)[None], jnp.int8)
+    static = _tile_table(tq, tk, bq, bk, causal, window, by_kv,
+                         dense_mask=True)
+    got, count = _mask_tile_table(mask_tiles(mask, bq, bk), static, by_kv)
+    assert got.dtype == jnp.int32 and int(count) == static.shape[1]
+    np.testing.assert_array_equal(np.asarray(got), static)
+
+
+@pytest.mark.parametrize("by_kv", [False, True], ids=["by_q", "by_kv"])
+@pytest.mark.parametrize("seed,share", [(0, 0.7), (1, 0.3), (2, 0.0)])
+def test_mask_tile_table_keeps_the_tiles_the_mask_holds(seed, share, by_kv):
+    """Random tiles of an 8 x 8 triangle: the ``count`` live entries, which
+    are the grid's steps, are the kept tiles in the static order (+ one
+    body-less placeholder for each row of output tiles that kept none),
+    ``_FIRST`` / ``_LAST`` once a row; no grid step reads the tail."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        _CUT, _FIRST, _INSIDE, _LAST, _mask_tile_table, _tile_table)
+
+    n = 8
+    tiles = np.tril(np.random.default_rng(seed).random((n, n)) < share)
+    static = _tile_table(n * 16, n * 16, 16, 16, True, None, by_kv,
+                         dense_mask=True)
+    table, count = _mask_tile_table(jnp.asarray(tiles), static, by_kv)
+    iq, ik, flags = np.asarray(table)
+    outer, inner = (ik, iq) if by_kv else (iq, ik)
+    count = int(count)
+    assert count >= n and flags.shape == static[2].shape
+    iq, ik, flags, outer, inner = (a[:count] for a in
+                                   (iq, ik, flags, outer, inner))
+    order = list(zip(outer.tolist(), inner.tolist()))
+    assert order == sorted(set(order))
+    assert sorted(set(outer.tolist())) == list(range(n))
+    first = np.r_[True, outer[1:] != outer[:-1]]
+    assert ((flags & _FIRST != 0) == first).all()
+    assert ((flags & _LAST != 0) == np.r_[first[1:], True]).all()
+    assert not (flags & _INSIDE).any()
+    body = flags & _CUT != 0
+    got = np.zeros_like(tiles)
+    got[iq[body], ik[body]] = True
+    assert (got == tiles).all()
+    # a placeholder only in a row that kept nothing, and on its first entry
+    # of the static table (by kv row: the diagonal)
+    kept_rows = tiles.any(axis=0 if by_kv else 1)
+    assert sorted(outer[~body].tolist()) == np.flatnonzero(
+        ~kept_rows).tolist()
+    assert (inner[~body] == (outer[~body] if by_kv else 0)).all()
+
+
+_SA_BLOCK = 32
+
+
+def _sa_patterns(case):
+    """``(T, [tiles of each sequence])``: which 32 x 32 tiles of the
+    triangle a sequence selects in."""
+    full = np.tril(np.ones((5, 5), bool))
+    a = full.copy()
+    if case == "first_tiles_of_a_q_row_empty":
+        a[3, :2] = a[4, :3] = False
+    elif case == "empty_diagonal_tile":
+        a[2, 2] = a[4, 4] = False
+    elif case == "kv_row_no_query_selects":
+        a[:, 1] = a[:, 4] = False
+    elif case == "batch_of_two_selections":
+        b = full.copy()
+        a[3, 1] = a[4, 1] = a[4, 2] = a[2, 0] = False
+        b[4, 2] = b[3, 3] = b[2, 0] = b[4, 0] = False
+        return 160, [a, b]
+    elif case == "ragged_length":
+        a[3, 0] = a[4, 1] = a[4, 3] = False
+        return 150, [a, a]
+    return 160, [a]
+
+
+SA_CASES = ("first_tiles_of_a_q_row_empty", "empty_diagonal_tile",
+            "kv_row_no_query_selects", "batch_of_two_selections",
+            "ragged_length")
+
+
+def _sa_case(case):
+    """The kernels (interpret mode) and the einsum reference under one mask:
+    forward, the three gradients and the head-mean probabilities, as numpy
+    arrays (a device array left alive fails ``test_engine.py``'s look at
+    ``jax.live_arrays()`` in the same worker)."""
+    from deepspeed_tpu.ops.pallas import sa_probs
+    from deepspeed_tpu.ops.pallas.flash_attention import mask_tiles
+
+    T, patterns = _sa_patterns(case)
+    B, H, D, blk = len(patterns), 2, 16, _SA_BLOCK
+    rng = np.random.default_rng(7)
+    mask = np.zeros((B, T, T), bool)
+    for b, tiles in enumerate(patterns):
+        big = np.repeat(np.repeat(tiles, blk, 0), blk, 1)[:T, :T]
+        mask[b] = np.tril(rng.random((T, T)) < 0.3) & big
+        for t in np.flatnonzero(~mask[b].any(axis=1)):
+            # a key for every query: the first of its row's first kept tile
+            mask[b, t, np.flatnonzero(tiles[t // blk])[0] * blk] = True
+    union = np.zeros((5, 5), bool)
+    for b in range(B):
+        padded = np.zeros((5 * blk, 5 * blk), bool)
+        padded[:T, :T] = mask[b]
+        union |= padded.reshape(5, blk, 5, blk).any(axis=(1, 3))
+    mask = jnp.asarray(mask, jnp.int8)
+    np.testing.assert_array_equal(np.asarray(mask_tiles(mask, blk, blk)),
+                                  union)
+    q, k, v = _qkv(B, T, H, D, seed=11)
+    weight = jax.random.normal(jax.random.PRNGKey(12), (B, T, H, D))
+    scale = D ** -0.5
+
+    def kernels(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=blk,
+                               block_k=blk, mask=mask, interpret=True)
+
+    def reference(q, k, v):
+        return _reference_attention(q, k, v, True, scale, mask=mask)
+
+    def both(fn):
+        out, lse = fn(q, k, v)
+        dq, dk, dv = jax.grad(lambda *a: (fn(*a)[0] * weight).sum(),
+                              argnums=(0, 1, 2))(q, k, v)
+        return dict(out=out, lse=lse, dq=dq, dk=dk, dv=dv), lse
+
+    got, lse = both(kernels)
+    want, _ = both(reference)
+    p = sa_probs.head_mean_probs(q, k, lse, mask, block_q=blk, block_k=blk,
+                                 interpret=True)
+    got["probs"] = jnp.where(mask != 0, p, 0.0)     # as its caller reads it
+    want["probs"] = jnp.where(
+        mask != 0, sa_probs._reference(q, k, lse, mask, scale), 0.0)
+    as_numpy = lambda d: {k: np.asarray(v) for k, v in d.items()}
+    return as_numpy(got), as_numpy(want), union
+
+
+@pytest.fixture(scope="module")
+def sa_case():
+    """``sa_case(name)``: each case computed once for the module."""
+    done = {}
+    yield lambda case: done.get(case) or done.setdefault(case, _sa_case(case))
+    done.clear()
+
+
+@pytest.mark.parametrize("what", ["out", "lse", "dq", "dk", "dv", "probs"])
+@pytest.mark.parametrize("case", SA_CASES)
+def test_flash_under_a_mask_that_empties_tiles(sa_case, case, what):
+    """The kernels walk a table without the tiles the mask empties — a q
+    row's first tiles, a diagonal tile, a whole kv row, another set for each
+    sequence (the table holds their union), a ragged last tile — and give
+    what the einsum reference gives under the same mask."""
+    got, want, union = sa_case(case)
+    assert not union[np.tril_indices(5)].all()      # the case empties tiles
+    np.testing.assert_allclose(got[what], want[what], rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("what", ["dk", "dv"])
+def test_a_kv_row_no_query_selects_gets_zero_gradients(sa_case, what):
+    """Its diagonal entry stays in the table as a placeholder: the block is
+    initialised and written, zeros and not what the buffer held."""
+    got, _, union = sa_case("kv_row_no_query_selects")
+    for row in np.flatnonzero(~union.any(axis=0)):
+        block = got[what][:, row * _SA_BLOCK:(row + 1) * _SA_BLOCK]
+        assert block.size and not np.asarray(block).any()
+
+
+@pytest.mark.parametrize("value", [1, -1, -128, 64])
+def test_mask_tiles_counts_any_nonzero_byte(value):
+    """The kernels read ``mask != 0``; so do the tiles, whatever the sign."""
+    from deepspeed_tpu.ops.pallas.flash_attention import mask_tiles
+
+    mask = np.zeros((2, 70, 70), np.int8)
+    mask[1, 40, 3] = mask[0, 69, 69] = value
+    want = np.zeros((3, 3), bool)
+    want[1, 0] = want[2, 2] = True
+    np.testing.assert_array_equal(
+        np.asarray(mask_tiles(jnp.asarray(mask), 32, 32)), want)

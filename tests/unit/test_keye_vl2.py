@@ -23,7 +23,8 @@ from deepspeed_tpu.models import indexed_attention as ia
 from deepspeed_tpu.models.layers import repeat_kv
 from deepspeed_tpu.models.mixtral import (MixtralConfig, MixtralForCausalLM,
                                           MixtralSparseMoeBlock)
-from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                     mask_tiles)
 from deepspeed_tpu.ops.pallas.sa_probs import head_mean_probs
 from deepspeed_tpu.parallel import build_mesh, topology
 
@@ -209,7 +210,8 @@ def test_kept_tile_share_counts_causal_tiles():
     mask = np.zeros((1, 64, 64), np.int8)
     mask[0, np.arange(64), np.arange(64)] = 1       # the diagonal's 4 tiles
     mask[0, 63, 0] = 1                              # and one corner tile
-    assert float(ia.kept_tile_share(jnp.asarray(mask), 16)) == 5 / 10
+    tiles = mask_tiles(jnp.asarray(mask), 16, 16)
+    assert float(ia.kept_tile_share(tiles, 16, 16)) == 5 / 10
 
 
 @pytest.fixture(scope="module")
@@ -253,6 +255,79 @@ def test_flash_gradients_under_a_mask_match_the_xla_path(masked_case, arg):
     want = jax.grad(loss(xla), argnums=arg)(q, k, v)
     got = jax.grad(loss(kernels), argnums=arg)(q, k, v)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.fixture(scope="module")
+def emptied_case():
+    """A selection that leaves tiles of the triangle empty, as a trained
+    indexer's does: every query takes its 24 keys among the 20 before it and
+    the sequence's first 8, so of the fifteen 32 x 32 tiles (3, 1), (4, 1)
+    and (4, 2) hold no pair in either sequence."""
+    B, n, H, D, blk = 2, 160, 4, 32, 32
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    q, k, v = (jax.random.normal(key, (B, n, H, D)) for key in ks[:3])
+    t, s = jnp.arange(n)[:, None], jnp.arange(n)[None]
+    scores = jax.random.uniform(ks[3], (B, n, n)) \
+        + 10.0 * ((s >= t - 20) | (s < 8))
+    mask = ia.select_mask(scores, 24, blk)
+    tiles = mask_tiles(mask, blk, blk)
+    out, lse = flash_attention(q, k, v, causal=True, block_q=blk,
+                               block_k=blk, mask=mask, tiles=tiles,
+                               interpret=True)
+    p_hat = head_mean_probs(q, k, lse, mask, block_q=blk, block_k=blk,
+                            tiles=tiles, interpret=True)
+    return dict(q=q, k=k, v=v, scores=scores, mask=mask, tiles=tiles,
+                out=out, p_hat=p_hat, block=blk)
+
+
+def test_the_emptied_case_empties_tiles(emptied_case):
+    tiles = np.asarray(emptied_case["tiles"])
+    want = np.tril(np.ones((5, 5), bool))
+    want[3, 1] = want[4, 1] = want[4, 2] = False
+    np.testing.assert_array_equal(tiles, want)
+    c = emptied_case
+    out_x, p_x = ia.masked_attention_xla(c["q"], c["k"], c["v"], c["mask"],
+                                         c["block"])
+    np.testing.assert_allclose(c["out"], out_x, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(jnp.where(c["mask"] != 0, c["p_hat"], 0), p_x,
+                               rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("what", ["loss", "gradient"])
+def test_index_loss_never_reads_a_tile_the_probabilities_skipped(
+        emptied_case, what):
+    """``ds_sa_probs`` leaves the tiles its table drops unwritten: filled
+    with NaN they change neither the indexer's loss nor its gradient."""
+    c = emptied_case
+    n, blk = c["mask"].shape[1], c["block"]
+    written = jnp.repeat(jnp.repeat(c["tiles"], blk, 0), blk, 1)[:n, :n]
+    assert not bool(written.all())
+    poisoned = jnp.where(written[None], c["p_hat"], jnp.nan)
+    fn = {"loss": ia.index_loss,
+          "gradient": jax.grad(ia.index_loss, argnums=1)}[what]
+    want = fn(c["p_hat"], c["scores"], c["mask"])
+    got = fn(poisoned, c["scores"], c["mask"])
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("by_kv", [False, True], ids=["by_q", "by_kv"])
+def test_the_counter_and_the_tile_table_agree(emptied_case, by_kv):
+    """``sa_kept_tile_share`` x the triangle's tiles is the number of table
+    entries that run a body; the rest of the live entries are body-less
+    placeholders (here none: every row of output tiles keeps its diagonal)."""
+    from deepspeed_tpu.ops.pallas.flash_attention import _CUT, _table
+
+    c = emptied_case
+    n, blk = c["mask"].shape[1], c["block"]
+    table, steps = _table(c["tiles"], n, n, blk, blk, True, None, by_kv)
+    flags = np.asarray(table)[2]
+    share = float(ia.kept_tile_share(c["tiles"], blk, blk))
+    assert share == pytest.approx(12 / 15)
+    assert int((flags[:int(steps)] & _CUT != 0).sum()) == \
+        round(share * 15) == 12
+    assert int(steps) == 12                         # no placeholder row
+    assert flags.shape == (15,)
 
 
 def test_flash_path_of_the_model_is_the_xla_path(share):
