@@ -25,9 +25,12 @@ Everything here works on ``[B, T, ...]`` in blocks of query rows so that
 scores. ``attention_impl="flash"`` takes the index scores and their gradient
 from the kernels of ``ops/pallas/sa_index.py`` (the causal tiles only, a tile
 of products at a time), runs the core through the flash kernels under the
-mask (``ops/pallas/flash_attention.py``) and reads the head-mean
-probabilities from ``ops/pallas/sa_probs.py``, which reuses the saved
-log-sum-exp.
+mask (``ops/pallas/flash_attention.py``) and takes the KL term and the
+scores' gradient from the two kernels of ``ops/pallas/sa_probs.py``, which
+rebuild ``p^`` a tile at a time from the saved log-sum-exp and reduce it
+against the scores there: no ``[T, T]`` pass of the loss runs in XLA.
+``masked_attention_xla`` and ``index_loss`` are the XLA path, and what those
+kernels are tested against.
 """
 
 import dataclasses
@@ -251,7 +254,7 @@ def indexed_attention(cfg, x, q, k, v, cos, sin):
         stats = {"sa_kept_tile_share": kept_tile_share(tiles, bq, bk)} \
             if cfg.report_expert_load else {}
     if flash:
-        from ..ops.pallas.sa_probs import head_mean_probs
+        from ..ops.pallas.sa_probs import index_kl
         from .layers import repeat_kv
 
         rep = q.shape[2] // k.shape[2]
@@ -261,12 +264,12 @@ def indexed_attention(cfg, x, q, k, v, cos, sin):
                 q, k, v, causal=True, block_q=bq, block_k=bk, mask=mask,
                 tiles=tiles)
         with jax.named_scope("ds.sa_loss"):
-            p_hat = head_mean_probs(
-                q, k, lse, mask, block_q=bq, block_k=bk, tiles=tiles)
+            stats["sa_index_loss"] = index_kl(
+                q, k, lse, scores, mask, block_q=bq, block_k=bk, tiles=tiles)
     else:
         with jax.named_scope("ds.attention"):
             out, p_hat = masked_attention_xla(q, k, v, mask, block)
-    with jax.named_scope("ds.sa_loss"):
-        stats["sa_index_loss"] = index_loss(
-            jax.lax.stop_gradient(p_hat), scores, mask)
+        with jax.named_scope("ds.sa_loss"):
+            stats["sa_index_loss"] = index_loss(
+                jax.lax.stop_gradient(p_hat), scores, mask)
     return out, stats

@@ -33,8 +33,8 @@ def resolve_remat_policy(name: str):
     else gets the named policy's answer; where no flash kernel ran (the XLA
     attention path, whose residual is the ``[H, T, T]`` probabilities) no
     such name exists and the policy is the plain one. A learned selection's
-    bit-packed mask (``ds_sa_mask``, ``models/indexed_attention.py``) is kept
-    the same way: the replay must see the set the forward pass chose.
+    bit-packed mask (``ds_sa_mask``: the replay must see the set the forward
+    chose) and its loss's row statistics (``ds_sa_kl_rows``) are kept too.
 
     ``offload_dots_no_batch`` is the CPU-activation-checkpointing analog
     (reference ``activation_checkpointing/checkpointing.py:480``
@@ -43,7 +43,7 @@ def resolve_remat_policy(name: str):
     XLA schedules the device↔host copies, replacing the reference's explicit
     ``.cpu()`` round-trips."""
     from ..ops.pallas import (FLASH_LSE, FLASH_OUT,  # ops imports this module
-                              SA_MASK)
+                              SA_KL_ROWS, SA_MASK)
 
     policies = {
         "nothing": jax.checkpoint_policies.nothing_saveable,
@@ -57,7 +57,7 @@ def resolve_remat_policy(name: str):
         raise ValueError(f"unknown remat_policy {name!r}; one of {sorted(policies)}")
     base = policies[name]
     flash_named = jax.checkpoint_policies.save_only_these_names(
-        FLASH_OUT, FLASH_LSE, SA_MASK)
+        FLASH_OUT, FLASH_LSE, SA_MASK, SA_KL_ROWS)
 
     # written out, not save_from_both_policies: that helper refuses the
     # Offloadable / Recompute answers of the offload policy

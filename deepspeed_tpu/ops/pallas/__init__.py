@@ -12,9 +12,11 @@ FLASH_BWD_DKV = "ds_flash_bwd_dkv"
 # them under every policy, so a ``jax.checkpoint`` replay holds no forward call
 FLASH_OUT = "ds_flash_out"
 FLASH_LSE = "ds_flash_lse"
-# the head-mean attention probabilities of a selection, from the saved
-# log-sum-exp (``sa_probs.py``)
+# the indexer's loss of a selection — the head-mean attention probabilities
+# from the saved log-sum-exp, reduced against the index scores tile by tile —
+# and the scores' gradient (``sa_probs.py``)
 SA_PROBS = "ds_sa_probs"
+SA_PROBS_BWD = "ds_sa_probs_bwd"
 # the indexer's scores of that attention over the causal tiles, and their
 # backward's two kernels (``sa_index.py``)
 SA_INDEX_FWD = "ds_sa_index_fwd"
@@ -24,6 +26,9 @@ SA_INDEX_BWD_DK = "ds_sa_index_bwd_dk"
 # (``models/indexed_attention.py``): kept under every remat policy, like the
 # two above, so a replay never selects again
 SA_MASK = "ds_sa_mask"
+# ``checkpoint_name`` of ``ds_sa_probs``' one output, the loss's row
+# statistics: kept the same way, so a replay holds no ``ds_sa_probs`` call
+SA_KL_ROWS = "ds_sa_kl_rows"
 RAGGED_PAGED_ATTENTION = "ds_ragged_paged_attention"
 DECODE_ATTENTION = "ds_decode_attention"
 PAGED_DECODE_ATTENTION = "ds_paged_decode_attention"

@@ -653,7 +653,7 @@ def mask_tiles(mask, block_q: int = 512, block_k: int = 512):
     """bool ``[nq, nk]``: the ``block_q x block_k`` tiles of ``mask
     [B, Tq, Tk]`` in which some sequence of the batch selects a pair. One
     pass over the mask; the tile tables of all the kernels under it
-    (``flash_attention(tiles=)``, ``sa_probs.head_mean_probs(tiles=)``) and
+    (``flash_attention(tiles=)``, ``sa_probs.index_kl(tiles=)``) and
     the counter ``indexed_attention.kept_tile_share`` read this one array.
 
     The rows of a tile are folded first (an element-wise max and min of int8

@@ -88,12 +88,18 @@ def _flash_sa(bwd, T=16384):
     return jax.grad(loss, argnums=(0, 1, 2)), args
 
 
-def _sa_probs(T=16384):
-    from deepspeed_tpu.ops.pallas.sa_probs import head_mean_probs
+def _sa_probs(bwd, T=16384):
+    """keye-vl2-30b-a3b.train.16k: the indexer's loss from the saved
+    log-sum-exp and the ``[T, T]`` float32 scores, a tile of the head-mean
+    probabilities in VMEM; the backward writes the scores' gradient onto a
+    zeroed buffer."""
+    from deepspeed_tpu.ops.pallas.sa_probs import index_kl
 
     q = ((1, T, H, D), BF16)
-    return (functools.partial(head_mean_probs, interpret=False),
-            [q, q, ((1, H, T), jnp.float32), ((1, T, T), jnp.int8)])
+    fwd = functools.partial(index_kl, interpret=False)
+    args = [q, q, ((1, H, T), jnp.float32), ((1, T, T), jnp.float32),
+            ((1, T, T), jnp.int8)]
+    return (jax.grad(fwd, argnums=3) if bwd else fwd), args
 
 
 def _sa_index(bwd, T=16384):
@@ -203,7 +209,8 @@ CASES = {
     "flash_fwd_key_mask_gqa": _flash_key_mask,
     "flash_fwd_sa_train16k": lambda: _flash_sa(False),
     "flash_fwd_bwd_sa_train16k": lambda: _flash_sa(True),
-    "sa_probs_train16k": _sa_probs,
+    "sa_probs_train16k": lambda: _sa_probs(False),
+    "sa_probs_bwd_train16k": lambda: _sa_probs(True),
     "sa_index_train16k": lambda: _sa_index(False),
     "sa_index_bwd_train16k": lambda: _sa_index(True),
     "ssm_scan_train8k": lambda: _ssm_scan(False),

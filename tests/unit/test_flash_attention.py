@@ -720,7 +720,7 @@ SA_CASES = ("first_tiles_of_a_q_row_empty", "empty_diagonal_tile",
 
 def _sa_case(case):
     """The kernels (interpret mode) and the einsum reference under one mask:
-    forward, the three gradients and the head-mean probabilities, as numpy
+    forward, the three gradients and the indexer's loss with its own, as numpy
     arrays (a device array left alive fails ``test_engine.py``'s look at
     ``jax.live_arrays()`` in the same worker)."""
     from deepspeed_tpu.ops.pallas import sa_probs
@@ -763,11 +763,18 @@ def _sa_case(case):
 
     got, lse = both(kernels)
     want, _ = both(reference)
-    p = sa_probs.head_mean_probs(q, k, lse, mask, block_q=blk, block_k=blk,
-                                 interpret=True)
-    got["probs"] = jnp.where(mask != 0, p, 0.0)     # as its caller reads it
-    want["probs"] = jnp.where(
-        mask != 0, sa_probs._reference(q, k, lse, mask, scale), 0.0)
+    # the indexer's loss under the same mask, from its two kernels and from
+    # the XLA path's formula on the einsum probabilities; the gradient as
+    # that of the rows' summed KL, so that the tolerances below mean something
+    from deepspeed_tpu.models.indexed_attention import index_loss
+
+    scores = 3.0 * jax.random.normal(jax.random.PRNGKey(13), (B, T, T))
+    kl = lambda sc: B * T * sa_probs.index_kl(
+        q, k, lse, sc, mask, block_q=blk, block_k=blk, interpret=True)
+    kl_ref = lambda sc: B * T * index_loss(
+        sa_probs._reference(q, k, lse, mask, scale), sc, mask)
+    got["kl"], got["dscores"] = jax.value_and_grad(kl)(scores)
+    want["kl"], want["dscores"] = jax.value_and_grad(kl_ref)(scores)
     as_numpy = lambda d: {k: np.asarray(v) for k, v in d.items()}
     return as_numpy(got), as_numpy(want), union
 
@@ -780,7 +787,8 @@ def sa_case():
     done.clear()
 
 
-@pytest.mark.parametrize("what", ["out", "lse", "dq", "dk", "dv", "probs"])
+@pytest.mark.parametrize("what", ["out", "lse", "dq", "dk", "dv", "kl",
+                                  "dscores"])
 @pytest.mark.parametrize("case", SA_CASES)
 def test_flash_under_a_mask_that_empties_tiles(sa_case, case, what):
     """The kernels walk a table without the tiles the mask empties — a q

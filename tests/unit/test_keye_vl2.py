@@ -6,8 +6,8 @@ not from the system) at ONE CHIP'S SHARE — logits, both loss terms and the
 gradient of every parameter; which loss term reaches which parameter; the
 first ``topk`` positions against plain causal attention; the exact selection
 against ``lax.top_k`` on rows full of ties; the flash kernels under a mask
-that is data, and the head-mean probabilities, against the XLA path; the
-eight shares adding up to the uncut layer; and what is not built raising."""
+that is data, and the indexer's loss from its two kernels, against the XLA
+path; the eight shares adding up to the uncut layer; and what is not built raising."""
 
 import dataclasses
 
@@ -25,7 +25,7 @@ from deepspeed_tpu.models.mixtral import (MixtralConfig, MixtralForCausalLM,
                                           MixtralSparseMoeBlock)
 from deepspeed_tpu.ops.pallas.flash_attention import (flash_attention,
                                                      mask_tiles)
-from deepspeed_tpu.ops.pallas.sa_probs import head_mean_probs
+from deepspeed_tpu.ops.pallas.sa_probs import index_kl
 from deepspeed_tpu.parallel import build_mesh, topology
 
 REF = common.load_file_module("reference", "keye_vl2")
@@ -232,25 +232,35 @@ def masked_case():
         kk, vv = repeat_kv(k, H // Hkv), repeat_kv(v, H // Hkv)
         out, lse = flash_attention(q, kk, vv, causal=True, block_q=64,
                                    block_k=64, mask=mask, interpret=True)
-        return out, head_mean_probs(q, kk, lse, mask, block_q=64,
-                                    block_k=64, interpret=True)
+        kl = lambda sc: index_kl(q, kk, lse, sc, mask, block_q=64,
+                                 block_k=64, interpret=True)
+        return out, kl
 
-    return q, k, v, mask, weight, xla, kernels
+    return q, k, v, mask, weight, xla, kernels, scores
 
 
 def test_flash_kernels_under_a_mask_match_the_xla_path(masked_case):
-    q, k, v, mask, _, xla, kernels = masked_case
-    (out_x, p_x), (out_k, p_k) = xla(q, k, v), kernels(q, k, v)
+    q, k, v, mask, _, xla, kernels, scores = masked_case
+    (out_x, p_x), (out_k, kl) = xla(q, k, v), kernels(q, k, v)
     np.testing.assert_allclose(out_k, out_x, rtol=1e-5, atol=1e-5)
-    # the head-mean probabilities, read under the mask as their caller does
-    np.testing.assert_allclose(jnp.where(mask != 0, p_k, 0), p_x,
-                               rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(p_x.sum(-1), 1.0, rtol=1e-5)
+    # the indexer's loss: the kernels rebuild the head-mean probabilities
+    # and reduce them against the scores without writing them
+    np.testing.assert_allclose(kl(scores), ia.index_loss(p_x, scores, mask),
+                               rtol=1e-5)
+
+
+def test_index_kl_gradient_matches_the_xla_path(masked_case):
+    q, k, v, mask, _, xla, kernels, scores = masked_case
+    p_x, kl = xla(q, k, v)[1], kernels(q, k, v)[1]
+    want = jax.grad(ia.index_loss, argnums=1)(p_x, scores, mask)
+    np.testing.assert_allclose(jax.grad(kl)(scores), want, rtol=1e-4,
+                               atol=1e-7)
 
 
 @pytest.mark.parametrize("arg", [0, 1, 2])
 def test_flash_gradients_under_a_mask_match_the_xla_path(masked_case, arg):
-    q, k, v, _, weight, xla, kernels = masked_case
+    q, k, v, _, weight, xla, kernels, _ = masked_case
     loss = lambda fn: lambda *a: (fn(*a)[0] * weight).sum()
     want = jax.grad(loss(xla), argnums=arg)(q, k, v)
     got = jax.grad(loss(kernels), argnums=arg)(q, k, v)
@@ -274,10 +284,10 @@ def emptied_case():
     out, lse = flash_attention(q, k, v, causal=True, block_q=blk,
                                block_k=blk, mask=mask, tiles=tiles,
                                interpret=True)
-    p_hat = head_mean_probs(q, k, lse, mask, block_q=blk, block_k=blk,
-                            tiles=tiles, interpret=True)
+    kl = lambda sc: index_kl(q, k, lse, sc, mask, block_q=blk, block_k=blk,
+                             tiles=tiles, interpret=True)
     return dict(q=q, k=k, v=v, scores=scores, mask=mask, tiles=tiles,
-                out=out, p_hat=p_hat, block=blk)
+                out=out, kl=kl, block=blk)
 
 
 def test_the_emptied_case_empties_tiles(emptied_case):
@@ -289,26 +299,93 @@ def test_the_emptied_case_empties_tiles(emptied_case):
     out_x, p_x = ia.masked_attention_xla(c["q"], c["k"], c["v"], c["mask"],
                                          c["block"])
     np.testing.assert_allclose(c["out"], out_x, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(jnp.where(c["mask"] != 0, c["p_hat"], 0), p_x,
-                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        c["kl"](c["scores"]), ia.index_loss(p_x, c["scores"], c["mask"]),
+        rtol=1e-5)
+
+
+def _written(c):
+    """bool ``[n, n]``: the pairs in tiles the kernels' table keeps."""
+    n, blk = c["mask"].shape[1], c["block"]
+    written = jnp.repeat(jnp.repeat(c["tiles"], blk, 0), blk, 1)[:n, :n]
+    assert not bool(written.all())
+    return written
 
 
 @pytest.mark.parametrize("what", ["loss", "gradient"])
 def test_index_loss_never_reads_a_tile_the_probabilities_skipped(
         emptied_case, what):
-    """``ds_sa_probs`` leaves the tiles its table drops unwritten: filled
-    with NaN they change neither the indexer's loss nor its gradient."""
+    """The XLA path's loss reads ``p^`` under the mask alone: filled with NaN
+    in the tiles a tile table drops, it changes neither the indexer's loss
+    nor its gradient."""
     c = emptied_case
-    n, blk = c["mask"].shape[1], c["block"]
-    written = jnp.repeat(jnp.repeat(c["tiles"], blk, 0), blk, 1)[:n, :n]
-    assert not bool(written.all())
-    poisoned = jnp.where(written[None], c["p_hat"], jnp.nan)
+    _, p_hat = ia.masked_attention_xla(c["q"], c["k"], c["v"], c["mask"],
+                                       c["block"])
+    poisoned = jnp.where(_written(c)[None], p_hat, jnp.nan)
     fn = {"loss": ia.index_loss,
           "gradient": jax.grad(ia.index_loss, argnums=1)}[what]
-    want = fn(c["p_hat"], c["scores"], c["mask"])
+    want = fn(p_hat, c["scores"], c["mask"])
     got = fn(poisoned, c["scores"], c["mask"])
     assert bool(jnp.isfinite(got).all())
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_index_kl_gradient_is_zero_where_the_table_drops_a_tile(emptied_case):
+    """``ds_sa_probs_bwd`` walks the kept tiles and its output lies on a
+    zeroed buffer: ``dI`` is EXACTLY zero in the emptied tiles, above the
+    diagonal and at every unselected pair, and the XLA path's elsewhere."""
+    c = emptied_case
+    p_x = ia.masked_attention_xla(c["q"], c["k"], c["v"], c["mask"],
+                                  c["block"])[1]
+    got = jax.grad(c["kl"])(c["scores"])
+    assert bool((jnp.where(_written(c)[None], 0.0, got) == 0).all())
+    assert bool((jnp.where(c["mask"] != 0, 0.0, got) == 0).all())
+    want = jax.grad(ia.index_loss, argnums=1)(p_x, c["scores"], c["mask"])
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-7)
+
+
+@pytest.mark.parametrize("what", ["loss", "gradient"])
+def test_index_kl_never_reads_a_score_above_the_diagonal(emptied_case, what):
+    """``ds_sa_index_fwd`` leaves the tiles above the diagonal unwritten:
+    NaN there (and at every other unselected pair) changes nothing."""
+    c = emptied_case
+    poisoned = jnp.where(c["mask"] != 0, c["scores"], jnp.nan)
+    fn = {"loss": c["kl"], "gradient": jax.grad(c["kl"])}[what]
+    want, got = fn(c["scores"]), fn(poisoned)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_index_kl_cotangent_scales_the_gradient(emptied_case):
+    c = emptied_case
+    want = jax.grad(c["kl"])(c["scores"])
+    got = jax.grad(lambda sc: -2.5 * c["kl"](sc))(c["scores"])
+    np.testing.assert_allclose(got, -2.5 * want, rtol=1e-5, atol=1e-9)
+
+
+def test_index_kl_adds_zero_where_a_probability_is_exactly_zero():
+    """A selected key every head's probability underflows at (its query is
+    far from it): ``p^`` is exactly 0 there, the term adds zero — not
+    ``0 log 0`` — and the key still stands in the scores' softmax."""
+    B, n, H, D, blk = 1, 64, 2, 16, 32
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    k = jax.random.normal(ks[0], (B, n, H, D))
+    q = 40.0 * k                    # every query sees its own key alone
+    scores = jax.random.normal(ks[2], (B, n, n))
+    mask = ia.select_mask(scores, 6, blk)
+    out, lse = flash_attention(q, k, k, causal=True, block_q=blk, block_k=blk,
+                               mask=mask, interpret=True)
+    _, p_x = ia.masked_attention_xla(q, k, k, mask, blk)
+    assert bool(((p_x == 0) & (mask != 0)).any())
+    kl = lambda sc: index_kl(q, k, lse, sc, mask, block_q=blk, block_k=blk,
+                             interpret=True)
+    loss, grad = jax.value_and_grad(kl)(scores)
+    assert bool(jnp.isfinite(loss)) and bool(jnp.isfinite(grad).all())
+    np.testing.assert_allclose(loss, ia.index_loss(p_x, scores, mask),
+                               rtol=1e-5)
+    np.testing.assert_allclose(
+        grad, jax.grad(ia.index_loss, argnums=1)(p_x, scores, mask),
+        rtol=1e-4, atol=1e-7)
 
 
 @pytest.mark.parametrize("by_kv", [False, True], ids=["by_q", "by_kv"])

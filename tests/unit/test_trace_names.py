@@ -336,13 +336,14 @@ def _block_sparse(bwd):
     return jax.grad(loss, argnums=(0, 1, 2)), [q, q, q]
 
 
-def _sa_probs():
-    from deepspeed_tpu.ops.pallas.sa_probs import head_mean_probs
+def _sa_probs(bwd):
+    from deepspeed_tpu.ops.pallas.sa_probs import index_kl
 
     q = ((1, 512, H, D), BF16)
-    fn = functools.partial(head_mean_probs, interpret=False,
-                           force_pallas=True)
-    return fn, [q, q, ((1, H, 512), jnp.float32), ((1, 512, 512), jnp.int8)]
+    fwd = functools.partial(index_kl, interpret=False)
+    args = [q, q, ((1, H, 512), jnp.float32), ((1, 512, 512), jnp.float32),
+            ((1, 512, 512), jnp.int8)]
+    return (jax.grad(fwd, argnums=3) if bwd else fwd), args
 
 
 def _sa_index(bwd):
@@ -395,7 +396,8 @@ KERNELS = {
                                 lambda: _block_sparse(True)),
     names.BLOCK_SPARSE_BWD_DKV: ("ds_block_sparse_bwd_dkv",
                                  lambda: _block_sparse(True)),
-    names.SA_PROBS: ("ds_sa_probs", lambda: _sa_probs()),
+    names.SA_PROBS: ("ds_sa_probs", lambda: _sa_probs(False)),
+    names.SA_PROBS_BWD: ("ds_sa_probs_bwd", lambda: _sa_probs(True)),
     names.SA_INDEX_FWD: ("ds_sa_index_fwd", lambda: _sa_index(False)),
     names.SA_INDEX_BWD_DQ: ("ds_sa_index_bwd_dq", lambda: _sa_index(True)),
     names.SA_INDEX_BWD_DK: ("ds_sa_index_bwd_dk", lambda: _sa_index(True)),
@@ -405,11 +407,14 @@ KERNELS = {
 
 
 #: the values every remat policy keeps (``layers.resolve_remat_policy``):
-#: ``checkpoint_name``s, not kernels — the flash forward's two and a learned
-#: selection's bit-packed mask — with a program that names each
+#: ``checkpoint_name``s, not kernels — the flash forward's two, a learned
+#: selection's bit-packed mask and its loss's row statistics — with a program
+#: that names each
 CHECKPOINT_NAMES = {names.FLASH_OUT: ("ds_flash_out", lambda: _flash(True)),
                     names.FLASH_LSE: ("ds_flash_lse", lambda: _flash(True)),
-                    names.SA_MASK: ("ds_sa_mask", _selection)}
+                    names.SA_MASK: ("ds_sa_mask", _selection),
+                    names.SA_KL_ROWS: ("ds_sa_kl_rows",
+                                       lambda: _sa_probs(True))}
 
 
 def test_every_kernel_name_is_listed():
@@ -443,29 +448,38 @@ def test_kernel_lowers_under_its_name(constant):
     assert f'kernel_name = "{spelled}"' in text
 
 
-def test_indexer_kernels_stand_under_their_scope(monkeypatch):
+@pytest.fixture(scope="module")
+def keye_step_text():
     """A ``sa_config`` model with ``attention_impl="flash"``, its gradient
-    lowered for the TPU: the scores' forward kernel stands twice under
-    ``ds.sa_index`` (the forward pass and the layer's replay: 8 calls a step
-    at keye 16k's depth 4), each backward kernel once (4), and nowhere else
-    — ``train.sa_index_share`` reads the whole indexer by that scope."""
-    from deepspeed_tpu.ops.pallas import sa_index
+    lowered for the TPU, the indexer's kernels as on the chip."""
+    from deepspeed_tpu.ops.pallas import sa_index, sa_probs
 
     # the model asks jax.default_backend(), which is the CPU here
-    monkeypatch.setattr(sa_index, "index_scores", functools.partial(
-        sa_index.index_scores, interpret=False))
-    model = MixtralForCausalLM(MixtralConfig.tiny(
-        remat=True, attention_impl="flash", hidden_size=128,
-        num_attention_heads=2, num_key_value_heads=1,
-        max_position_embeddings=512,
-        sa_config=dict(indexer_head_dim=64, indexer_num_heads=2,
-                       q_chunk_size=128, kv_chunk_size=128, topk=64)))
-    ids = jnp.zeros((1, 256), jnp.int32)
-    params = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
-    loss = lambda p, ids: model.apply({"params": p}, ids, labels=ids)[0]
-    text = jax.jit(jax.grad(loss)).trace(params, ids).lower(
-        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(sa_index, "index_scores", functools.partial(
+            sa_index.index_scores, interpret=False))
+        patch.setattr(sa_probs, "index_kl", functools.partial(
+            sa_probs.index_kl, interpret=False))
+        model = MixtralForCausalLM(MixtralConfig.tiny(
+            remat=True, attention_impl="flash", hidden_size=128,
+            num_attention_heads=2, num_key_value_heads=1,
+            max_position_embeddings=512,
+            sa_config=dict(indexer_head_dim=64, indexer_num_heads=2,
+                           q_chunk_size=128, kv_chunk_size=128, topk=64)))
+        ids = jnp.zeros((1, 256), jnp.int32)
+        params = jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
+        loss = lambda p, ids: model.apply({"params": p}, ids, labels=ids)[0]
+        return jax.jit(jax.grad(loss)).trace(params, ids).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+def test_indexer_kernels_stand_under_their_scope(keye_step_text):
+    """The scores' forward kernel stands twice under ``ds.sa_index`` (the
+    forward pass and the layer's replay: 8 calls a step at keye 16k's depth
+    4), each backward kernel once (4), and nowhere else —
+    ``train.sa_index_share`` reads the whole indexer by that scope."""
+    text = keye_step_text
     called = re.findall(r'kernel_name = "(ds_sa_index_\w+)"', text)
     assert sorted(called) == ["ds_sa_index_bwd_dk", "ds_sa_index_bwd_dq",
                               "ds_sa_index_fwd", "ds_sa_index_fwd"]
@@ -474,6 +488,21 @@ def test_indexer_kernels_stand_under_their_scope(monkeypatch):
     replayed = re.findall(
         r"rematted_computation/\S*ds\.sa_index/(ds_sa_index_\w+)/", text)
     assert replayed == ["ds_sa_index_fwd"]
+
+
+def test_loss_kernels_stand_once_a_layer_and_in_no_replay(keye_step_text):
+    """The indexer's loss is ``ds_sa_probs`` once a layer and
+    ``ds_sa_probs_bwd`` once (4 and 4 calls a step at keye 16k's depth 4),
+    both under ``ds.sa_loss`` and neither inside the layer's replay: every
+    remat policy keeps the forward kernel's one output (``ds_sa_kl_rows``),
+    so ``jax.checkpoint`` drops the call. ``train.sa_loss_share`` reads the
+    loss by that scope, ``kernel.sa_probs.roofline_share`` the first name."""
+    text = keye_step_text
+    called = re.findall(r'kernel_name = "(ds_sa_probs\w*)"', text)
+    assert sorted(called) == ["ds_sa_probs", "ds_sa_probs_bwd"]
+    scoped = re.findall(r"ds\.sa_loss/(ds_sa_probs\w*)/pallas_call", text)
+    assert sorted(scoped) == sorted(called)
+    assert not re.findall(r"rematted_computation/\S*/ds_sa_probs\w*/", text)
 
 
 def test_scan_kernels_stand_alone_under_their_scope(monkeypatch):
@@ -507,10 +536,12 @@ def test_scan_kernels_stand_alone_under_their_scope(monkeypatch):
 
 def test_no_step_without_the_flash_indexer_holds_its_kernels(train_text):
     """The other families' steps, and a ``sa_config`` step on the XLA path,
-    carry none of the three kernels: their programs are what they were."""
+    carry none of the indexer's five kernels: their programs are what they
+    were."""
     assert set(train_text) == set(TRAIN_SCOPES)
     for family, text in train_text.items():
         assert "ds_sa_index" not in text, family
+        assert "ds_sa_probs" not in text, family
 
 
 @pytest.mark.parametrize("gas", [1, 2])
