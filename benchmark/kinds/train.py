@@ -13,7 +13,7 @@ import time
 
 import numpy as np
 
-from benchmark import common, flops, trace_reduce
+from benchmark import common, flops, stats as st, trace_reduce
 from benchmark.traffic import generator
 
 #: deliberately wrong computations the check must refuse (``--control``)
@@ -35,7 +35,7 @@ def build_engine(ctx, sizes, control=None):
     mesh = common.cell_mesh(chips, **wl["engine"].get("parallel", {}))
     config = {**config,
               "train_batch_size": mix["sequences_per_chip"] * chips,
-              "seed": ctx["seed"] % (2 ** 31)}
+              "seed": weight_seed(ctx) % (2 ** 31)}
     example = {k: v[:1] for k, v in generator.packed_batch(
         mix, 0, 0, sizes["vocab_size"], chips).items()}
     engine, _, _, _ = ds.initialize(
@@ -166,12 +166,19 @@ def run(ctx):
         ctx["emit"]({"defect": "compile inside the window",
                      "program": "train_step", "count": compiled})
     rate = clocked * tokens / (w1 - w0)
+    # the clocked groups, so that a stalled run can be told from a slow
+    # program
+    ctx["emit"]({"phase": "window", **st.fence_groups(fence_ms)})
+    tol = ctx["workload"]["check"]
     return {
         "correct": correct,
+        # each number the check compared, beside its limit (the benchmark's
+        # contract: a run that is not correct leaves them in the record)
+        "compared": {k[:-4]: {"value": stats[k[:-4]], "limit": limit}
+                     for k, limit in tol.items() if k.endswith("_tol")},
         "attempted": len(losses),
         "failed": sum(1 for x in losses if not math.isfinite(x)),
-        "end_to_end": {"setup_s": setup_s,
-                       "train_tokens_per_s_per_chip": rate / chips},
+        "end_to_end": {"setup_s": setup_s, rate_metric(ctx): rate / chips},
         "observed": {"kind": "train", "fence_ms": fence_ms,
                      "tokens_per_s": rate, "chips": chips,
                      "flops_per_token": flops.train_flops_per_token(
@@ -180,3 +187,22 @@ def run(ctx):
                      "compiles_in_window": compiled},
         "trace": trace,
     }
+
+
+def weight_seed(ctx):
+    """The seed of the engine's initial weights: the one the cell's file
+    states as ``weight_seed`` (a cell whose step time follows what a seeded,
+    frozen router sends its held experts is measured on ONE set of weights,
+    and ``--seed`` draws the data alone), else the run's own seed. At the
+    file's end: the frames above keep their lines."""
+    seed = ctx["workload"].get("weight_seed")
+    return ctx["seed"] if seed is None else seed
+
+
+def rate_metric(ctx):
+    """The end-to-end name the cell's rate is reported under: the one its
+    file states as ``rate_metric`` (``BENCHMARK.json`` gives a bound a
+    metric, not a cell, so a cell whose rate follows its own training
+    trajectory reports the same quantity under a name with a bound of its
+    own), else ``train_tokens_per_s_per_chip``."""
+    return ctx["workload"].get("rate_metric", "train_tokens_per_s_per_chip")

@@ -153,6 +153,14 @@ def main():
         result["device"].update(busy_s=t["busy_s"], window_s=t["window_s"])
         result["breakdown"] = {"device_ops": t["device_ops"],
                                "idle_gaps": t["idle_gaps"]}
+    if run.get("compared"):
+        # what `correct` compared, each beside its limit: the result's last
+        # key and the last lines on standard error, which the driver keeps
+        # of a run that is not correct
+        result["compared"] = run["compared"]
+        for name, c in run["compared"].items():
+            print(f"benchmark: compared {name} {c['value']!r} "
+                  f"limit {c['limit']!r}", file=sys.stderr)
     if tiny:
         emit({"rehearsal": "passed" if run["correct"] else "failed",
               "would_print": result})

@@ -85,7 +85,12 @@ def flash_sa_bwd(batch, seq_len, q_heads, kv_heads, head_dim, topk):
 def sa_probs(batch, seq_len, q_heads, kv_heads, head_dim, topk, elem=2):
     """``ds_sa_probs``: the scores again (2 x D a selected pair a head), q
     once a query head and k once a key/value head, the log-sum-exp rows, the
-    mask, and the head-mean written in float32 for the selected pairs."""
+    mask, and the head-mean in float32 for the selected pairs. Since PR 44
+    the kernel no longer WRITES that head-mean (it reduces it against the
+    index scores in VMEM to five row statistics); the 4 bytes a selected
+    pair stay in the count, which at these shapes is bound by operations
+    (the least time is ``flops`` / peak with or without them), so no number
+    moves."""
     selected = batch * seq_len * flops.mean_attended_keys(seq_len, topk)
     return {"flops": 2 * head_dim * q_heads * selected,
             "bytes": elem * batch * seq_len * head_dim * (q_heads + kv_heads)

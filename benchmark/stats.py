@@ -16,6 +16,15 @@ def percentile(values, q):
     return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)
 
 
+def fence_groups(fence_ms):
+    """A training window's fenced groups of steps in one line (``fence_ms``:
+    each group's milliseconds a step): a stall is a group or two far above
+    the median among steady ones, a slow program a high median."""
+    return {"groups": len(fence_ms),
+            "fence_ms_p50": percentile(fence_ms, 50),
+            "fence_ms_max": max(fence_ms, default=None)}
+
+
 #: a request in one of these has not failed; every other terminal state
 #: (failed, timeout, cancelled/shed, rejected) has
 LIVE_OR_FINISHED = ("finished", "running", "queued")

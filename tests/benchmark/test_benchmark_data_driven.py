@@ -118,6 +118,27 @@ def test_added_files_are_found_and_run(copy):
     assert "train.step_ms_p50" in metrics
     # trace-sourced metrics have nothing to read on a CPU: left out
     assert "device.idle_share.train" not in metrics
+    # the clocked window's fenced groups, on a line before the result's, in
+    # traced runs as in untraced ones
+    lines = out.stdout.splitlines()
+    at = next(i for i, l in enumerate(lines)
+              if l.startswith('{"phase": "window"'))
+    window = json.loads(lines[at])
+    assert at < len(lines) - 1 and window["groups"] >= 1
+    assert window["fence_ms_max"] >= window["fence_ms_p50"] > 0
+    assert list(window) == ["phase", "groups", "fence_ms_p50", "fence_ms_max"]
+    # what `correct` compared, each number beside its limit: the result's
+    # last key, and lines of standard error after everything the run says
+    result = line["would_print"]
+    assert list(result)[-1] == "compared"
+    assert set(result["compared"]) == {"loss_gap", "logit_rel_l2"}
+    said = [l for l in out.stderr.splitlines()
+            if l.startswith("benchmark: compared ")]
+    assert len(said) == 2
+    for name, c in result["compared"].items():
+        assert 0 <= c["value"] <= c["limit"], (name, c)
+        assert any(l.split()[2] == name and float(l.split()[-1]) == c["limit"]
+                   for l in said)
 
 
 @pytest.mark.parametrize("trace", [0, 1])

@@ -21,13 +21,16 @@ NEW = ("train.mfu.sa_moe", "train.sa_index_share", "train.sa_select_share",
        "train.sa_loss_share", "kernel.flash_sa_fwd.roofline_share",
        "kernel.flash_sa_bwd.roofline_share", "kernel.sa_probs.roofline_share",
        "sa.kept_tile_share")
-SHARED = ("train.step_ms_p50", "device.idle_share.train",
-          "train.attention_share", "train.attn_proj_share",
-          "train.head_loss_share", "train.optimizer_share",
-          "train.recompute_share", "train.host_gap_ms_per_step",
-          "moe.expert_share", "moe.grouped_matmul_share",
-          "moe.compact_hit_share", "moe.rows_max_over_mean",
-          "moe.held_rows_over_expected")
+#: the readers other cells have too, here under the names that move the
+#: cell's own rate metric (``train_tokens_per_s_per_chip.trajectory``)
+SHARED = tuple(f"{name}.trajectory" for name in (
+    "train.step_ms_p50", "device.idle_share.train",
+    "train.attention_share", "train.attn_proj_share",
+    "train.head_loss_share", "train.optimizer_share",
+    "train.recompute_share", "train.host_gap_ms_per_step",
+    "moe.expert_share", "moe.grouped_matmul_share",
+    "moe.compact_hit_share", "moe.rows_max_over_mean",
+    "moe.held_rows_over_expected"))
 SA = {"indexer_head_dim": 64, "indexer_num_heads": 16,
       "indexer_num_kv_heads": 1, "kv_chunk_size": 512, "q_chunk_size": 512,
       "topk": 2048}
@@ -125,6 +128,24 @@ def test_model_is_built_from_the_file_and_the_workload():
         ("packed", 16384, 1)
 
 
+def test_cell_states_its_weights_and_nothing_else_moved():
+    """PR 46: the cell's file states whose weights it is measured on, and
+    that alone: the optimizer, the traffic, the limits of `correct` and the
+    warm-up are the ones PR 39 set; under ``tiny`` the key is null."""
+    wl = common.load_json("workloads", f"{CELL}.json")
+    mix = common.load_json("traffic", "train.16k.json")
+    assert isinstance(wl["weight_seed"], int)
+    assert wl["engine"]["optimizer"] == {"type": "AdamW",
+                                         "params": {"lr": 1e-4}}
+    assert "scheduler" not in wl["engine"]
+    assert set(mix) == {"kind", "what", "seq_len", "sequences_per_chip"}
+    assert wl["tiny"]["weight_seed"] is None
+    assert wl["tiny"]["engine"]["optimizer"]["params"]["lr"] == 1e-3
+    assert (wl["check"]["loss_gap_tol"], wl["check"]["logit_rel_l2_tol"],
+            wl["check"]["probe_positions"], wl["warmup_steps"]) == \
+        (3e-4, 0.13, 256, 3)
+
+
 def test_parameters_by_hand():
     """A layer is 18.9 M of attention, 2.26 M of indexer, 0.26 M of router
     and 16 held experts of 4.7 M; the sliced table and head 77.8 M."""
@@ -208,10 +229,28 @@ def test_engine_matches_reference_on_one_device(seed):
     assert stats["logit_rel_l2"] < 1e-5 and stats["loss_gap"] < 1e-5
 
 
+#: what the flash branch of ``indexed_attention`` takes from a kernel's
+#: module, not from its own file: (wrong computation, module, attribute)
+FLASH_ENTRIES = (("relu_left_out", "sa_index", "index_scores"),
+                 ("head_weights_left_out", "sa_index", "index_scores"),
+                 ("index_loss_left_out", "sa_probs", "index_kl"))
+
+
+def flash_context(seed):
+    """The tiny cell on the branch the chip times: ``attention_impl``
+    "flash", which off a TPU runs the kernels' modules' reference math."""
+    ctx, kind = tiny_context(CELL, seed)
+    ctx["workload"] = {**ctx["workload"], "model": {
+        **ctx["workload"]["model"], "attention_impl": "flash"}}
+    return ctx, kind
+
+
 def wrong_check(seed, name):
     """(verdict, stats) of the cell's check with the system computing
-    ``name`` wrongly, or the reference from float8 weights."""
-    ctx, kind = tiny_context(CELL, seed)
+    ``name`` wrongly, or the reference from float8 weights; ``name@flash``:
+    on the flash branch of ``indexed_attention``."""
+    name, _, impl = name.partition("@")
+    ctx, kind = flash_context(seed) if impl else tiny_context(CELL, seed)
     how = keye_vl2_wrong.reference_from_float8(
         *((4, 3) if name.endswith("e4m3") else (5, 2))) \
         if name.startswith("reference_fp8") else keye_vl2_wrong.wrong(name)
@@ -222,20 +261,66 @@ def wrong_check(seed, name):
 
 @pytest.mark.parametrize("seed", [40, 41])
 @pytest.mark.parametrize("name", [
-    *keye_vl2_wrong.WRONG, "reference_fp8_e4m3", "reference_fp8_e5m2"])
+    *keye_vl2_wrong.WRONG, "reference_fp8_e4m3", "reference_fp8_e5m2",
+    *(f"{name}@flash" for name, _, _ in FLASH_ENTRIES)])
 def test_a_wrong_computation_fails_the_check(seed, name):
     """Each thing of the layer or of the loss left out or replaced, and the
     reference one precision down, is far outside the tolerance: the logits
     refuse what changes the forward pass, the first loss what changes the
-    indexer's term alone."""
+    indexer's term alone. The three the flash branch takes from a kernel's
+    module are refused on that branch too (at PR 44's commit the script
+    patched ``indexed_attention`` alone, and the branch the chip times ran
+    the index scores right under ``relu_left_out``)."""
     ok, stats = wrong_check(seed, name)
     tol = tiny_context(CELL, seed)[0]["workload"]["check"]
     assert not ok
-    if name == "index_loss_left_out":
+    if name.startswith("index_loss_left_out"):
         assert stats["verdicts"]["logit_rel_l2"]
         assert stats["loss_gap"] > 100 * tol["loss_gap_tol"]
     else:
         assert stats["logit_rel_l2"] > 10 * tol["logit_rel_l2_tol"]
+
+
+@pytest.mark.parametrize("seed", [40, 41])
+def test_flash_branch_matches_reference_on_one_device(seed):
+    """Unpatched, the branch the chip times passes the same check."""
+    ctx, kind = flash_context(seed)
+    assert ctx["workload"]["model"]["attention_impl"] == "flash"
+    ok, stats = kind.check(ctx, kind.build_engine(ctx, ctx["sizes"]),
+                           ctx["sizes"])
+    assert ok, stats
+    assert stats["logit_rel_l2"] < 1e-5 and stats["loss_gap"] < 1e-5
+
+
+@pytest.mark.parametrize("name,module,attribute", FLASH_ENTRIES)
+def test_wrong_computation_patches_what_the_flash_branch_calls(
+        name, module, attribute):
+    """By name: the kernel module's entry is among the computation's
+    patches, the flash branch of ``indexed_attention`` is where it is read
+    (at call time, so a patch reaches it), and the replacement takes the
+    arguments that branch passes."""
+    import importlib
+    import inspect
+
+    import deepspeed_tpu.models.indexed_attention as ia
+
+    mod = importlib.import_module(f"deepspeed_tpu.ops.pallas.{module}")
+    patched = {(m, k) for m, make in keye_vl2_wrong.WRONG[name]
+               for k in make(m)}
+    assert (mod, attribute) in patched
+    assert any(m is ia for m, _ in patched)        # the XLA path's as well
+    source = inspect.getsource(ia.indexed_attention)
+    flash = source[source.index('attention_impl == "flash"'):]
+    assert (f"{module}.{attribute}(" in flash
+            or f"{module} import {attribute}" in flash)
+    real = inspect.signature(getattr(mod, attribute))
+    with keye_vl2_wrong.wrong(name):
+        fake = inspect.signature(getattr(mod, attribute))
+    call = {"index_scores": (("qi", "ki", "w"), ("block_q", "block_k")),
+            "index_kl": (("q", "k", "lse", "scores", "mask"),
+                         ("block_q", "block_k", "tiles"))}[attribute]
+    for sig in (real, fake):
+        sig.bind(*call[0], **{k: 0 for k in call[1]})
 
 
 def test_every_wrong_computation_of_the_issue_is_there():
@@ -250,14 +335,20 @@ def test_wrong_computations_leave_the_model_as_it_was():
     import deepspeed_tpu.models.llama as llama
     import deepspeed_tpu.models.mixtral as mixtral
 
+    import deepspeed_tpu.ops.pallas.sa_index as sa_index
+    import deepspeed_tpu.ops.pallas.sa_probs as sa_probs
+
     names = [(ia, "select_mask"), (ia, "index_scores"),
              (ia, "index_loss"),
-             (llama, "RMSNorm"), (mixtral, "_routed_experts")]
+             (llama, "RMSNorm"), (mixtral, "_routed_experts"),
+             (sa_index, "index_scores"), (sa_probs, "index_kl")]
     before = [getattr(m, k) for m, k in names]
     for name in keye_vl2_wrong.WRONG:
         with keye_vl2_wrong.wrong(name):
+            # one thing of the model, on each path that computes it
             assert sum(getattr(m, k) is not v
-                       for (m, k), v in zip(names, before)) == 1
+                       for (m, k), v in zip(names, before)) == \
+                len(keye_vl2_wrong.WRONG[name])
     assert all(getattr(m, k) is v for (m, k), v in zip(names, before))
 
 
@@ -379,8 +470,8 @@ def cell_metrics():
     spans: neither is a share of this cut)."""
     return [m["name"] for m in common.load_benchmark()["per_layer"]
             if CELL in m["workloads"] and m["source"] == "device_trace"
-            and m["name"] not in ("device.idle_share.train",
-                                  "train.host_gap_ms_per_step")]
+            and m["name"] not in ("device.idle_share.train.trajectory",
+                                  "train.host_gap_ms_per_step.trajectory")]
 
 
 @pytest.mark.parametrize("metric", cell_metrics())

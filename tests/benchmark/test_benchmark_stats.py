@@ -68,3 +68,17 @@ def test_a_request_without_first_token_weighs_on_the_tail():
     # it enters at window end - due = 8 s, not left out
     assert max(w["ttft_s"]) == pytest.approx(8.0)
     assert st.percentile(w["ttft_s"], 95) > 6.0
+
+
+@pytest.mark.parametrize("fence_ms,want", [
+    # a steady window; one with a stalled group (PERF.md section 7, 1: one
+    # to four groups at 1.1-7 times the step among steady ones); none
+    ([830.0, 829.5, 831.0, 830.5], (4, 830.25, 831.0)),
+    ([830.0, 829.5, 2400.0, 831.0, 830.5], (5, 830.5, 2400.0)),
+    ([], (0, None, None)),
+])
+def test_fence_groups_line(fence_ms, want):
+    line = st.fence_groups(fence_ms)
+    assert list(line) == ["groups", "fence_ms_p50", "fence_ms_max"]
+    assert (line["groups"], line["fence_ms_p50"], line["fence_ms_max"]) == \
+        (want[0], pytest.approx(want[1]) if want[1] else None, want[2])
