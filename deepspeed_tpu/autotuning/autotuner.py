@@ -216,10 +216,9 @@ class Autotuner:
         """Drive the full MFU lever space (remat policy x flash tiles x
         loss_chunk x micro/gas split x Pallas-Adam x attention impl) with
         the memoized, cost-model-guided coordinate descent of
-        ``mfu_tuner.MFUTuner`` — the search ``tools/attack_mfu.py`` runs
-        against the live chip, exposed as a library API (reference
-        ``tuner/model_based_tuner.py``). Requires the model to be one of
-        this framework's config-dataclass families (``model.config``)."""
+        ``mfu_tuner.MFUTuner`` (reference ``tuner/model_based_tuner.py``).
+        Requires the model to be one of this framework's config-dataclass
+        families (``model.config``)."""
         from .mfu_tuner import MFUTuner
 
         mcfg = getattr(self.model, "config", None)

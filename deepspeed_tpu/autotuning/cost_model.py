@@ -38,9 +38,7 @@ def flatten_config(cfg: Dict, prefix: str = "") -> Dict[str, float]:
 def rank_by_cost_model(measured, cand_feats, min_measured: int = 6):
     """Order candidate indices predicted-best-first, or None when the model
     has too few measurements to rank (callers keep declaration order).
-    ``measured``: [(features, score)]; shared by ``mfu_tuner`` and
-    ``tools/attack_mfu.py`` so the ranking core can't drift between the
-    library search and the on-chip attack."""
+    ``measured``: [(features, score)]; ``mfu_tuner``'s ranking core."""
     if len(measured) < min_measured or len(cand_feats) <= 1:
         return None
     model = RidgeCostModel().fit([m[0] for m in measured],

@@ -9,9 +9,9 @@ move MFU here are *compilation* knobs — remat policy, flash-attention tile
 sizes, chunked-loss size, micro-batch x gradient-accumulation split,
 Pallas-vs-XLA kernels — so candidates rebuild the model config
 (``dataclasses.replace``) and re-jit in-process instead of forking cluster
-jobs. The search is the memoized coordinate descent proven on hardware by
-``tools/attack_mfu.py``, with the ridge cost model supplying the
-predicted-best-first evaluation order and pruning within each axis.
+jobs. The search is a memoized coordinate descent, with the ridge cost
+model supplying the predicted-best-first evaluation order and pruning
+within each axis. No cell runs it (ROADMAP D1b).
 
 Every evaluation is memoized (and persisted to ``results_dir``) so repeated
 calls — or a resumed tuning session — never re-measure a spec.
@@ -27,8 +27,8 @@ import numpy as np
 
 from ..utils.logging import log_dist, logger
 
-#: The full lever space (reference core space analog; tools/attack_mfu.py
-#: walks the same axes on the live chip). ``bg`` is (micro_batch, gas).
+#: The full lever space (reference core space analog). ``bg`` is
+#: (micro_batch, gas).
 LEVER_AXES: Dict[str, List[Any]] = {
     "bg": [(8, 8), (16, 4), (16, 8), (32, 4), (8, 16)],
     "fq": [256, 512, 1024],
@@ -209,8 +209,7 @@ class MFUTuner:
         # both the acceptance threshold (best_rec) and the walk position
         # (cur). Without this a resumed tune starts at the default spec with
         # a warm cost model, can terminate without revisiting the previously
-        # best spec, and overwrites best_mfu.json with a WORSE best
-        # (tools/attack_mfu.py got this fix in r5; this is the library port).
+        # best spec, and overwrites best_mfu.json with a WORSE best.
         best_rec = None
         for rec in self.results.values():
             if rec.get("tokens_per_sec") is not None and (

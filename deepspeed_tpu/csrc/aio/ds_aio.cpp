@@ -143,11 +143,9 @@ class PoolBackend : public DsAioGroupBackend {
 extern "C" {
 
 // backend: 0 = auto, 1 = pool, 2 = io_uring (NULL if unavailable).
-// auto currently resolves to the pool: the AIO_r04.json sweep measured the
-// pool ahead of uring at every point on this host's disk (both saturate the
-// device at their best; callers' num_threads tuning only means something on
-// the pool). Flip auto to prefer uring when a sweep shows it winning on
-// real NVMe.
+// auto currently resolves to the pool: no tools/aio_bench.py sweep on real
+// NVMe has shown uring ahead (callers' num_threads tuning only means
+// something on the pool). Flip auto to prefer uring when one does.
 void* ds_aio_handle_create3(int64_t block_size, int queue_depth,
                             int single_submit, int overlap_events,
                             int num_threads, int use_o_direct, int backend) {

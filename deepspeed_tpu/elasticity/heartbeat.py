@@ -1,8 +1,9 @@
 """Heartbeat protocol between the engine and the elastic agent's watchdog.
 
-The r5 outage record (``TPU_DOWN_r05.log``: 108 consecutive probes wedging
-past their 120s cap) is the failure class the exit-code-only agent cannot
-see: a rank stuck in a collective never exits, so the job stalls forever.
+A wedged device (an outage of the retired backend had 108 probes in a row
+hang past their 120 s cap) is the failure class the exit-code-only agent
+cannot see: a rank stuck in a collective never exits, so the job stalls
+forever.
 
 Protocol: each worker writes ``<checkpoint_dir>/heartbeats/rank_<r>.json``
 (``{"step", "time", "pid"}``) via temp-file + ``os.replace`` at the top of

@@ -356,8 +356,7 @@ def journal_report() -> None:
               f"({st['records_compacted']} compacted, "
               f"{st['torn_tails_truncated']} torn tail(s) truncated), "
               f"last compaction "
-              f"{'never' if age is None else f'{age:.0f}s ago'}"
-              + ("" if st["fsync"] else " [FSYNC OFF — bench probe only]"))
+              f"{'never' if age is None else f'{age:.0f}s ago'}")
 
 
 def fleet_report() -> None:

@@ -162,8 +162,8 @@ class ServingConfig:
     #: chunks packed into a single ragged token batch per step — no
     #: sentinel decode rows, no second resident compile, one device
     #: dispatch per step. False = the LEGACY two-program engine (resident
-    #: decode + chunked prefill / bucketed monolithic prefill), kept only
-    #: so benches and parity tests can A/B against it in the same run.
+    #: decode + chunked prefill / bucketed monolithic prefill), whose only
+    #: users are its parity tests (ROADMAP D2).
     mixed_step: bool = True
     # sampling (static per engine: they shape the compiled programs)
     do_sample: bool = False

@@ -170,16 +170,11 @@ class RequestJournal:
     lock on ``<dir>/LOCK`` enforces the single writer ACROSS processes
     (:class:`JournalLockedError` on an overlapping open)."""
 
-    def __init__(self, journal_dir: str, segment_bytes: int = 1 << 20,
-                 fsync: bool = True):
+    def __init__(self, journal_dir: str, segment_bytes: int = 1 << 20):
         if segment_bytes < 4096:
             raise ValueError("segment_bytes must be >= 4096")
         self.dir = journal_dir
         self.segment_bytes = int(segment_bytes)
-        #: fsync on by default — the durability contract. False exists
-        #: ONLY for the overhead A/B probe in ds_bench; a production
-        #: journal without fsync is not a journal
-        self.fsync = bool(fsync)
         os.makedirs(journal_dir, exist_ok=True)
         # single-writer exclusion ACROSS processes: a POSIX record lock
         # (lockf) on <dir>/LOCK, released by the OS on any death incl.
@@ -311,9 +306,9 @@ class RequestJournal:
         self._active_size = 0
 
     def _append(self, payload: Dict[str, Any], sync: bool = True) -> None:
-        """Append ONE record; with ``sync`` (and :attr:`fsync` on) the
-        bytes are on disk before this returns — the caller sequences
-        this BEFORE the action the record makes durable."""
+        """Append ONE record; with ``sync`` the bytes are on disk before
+        this returns — the caller sequences this BEFORE the action the
+        record makes durable."""
         self._rotate_if_needed()
         fid = payload.get("fid")
         if fid is not None:
@@ -324,7 +319,7 @@ class RequestJournal:
         f = self._open_active()
         f.write(data)
         f.flush()
-        if sync and self.fsync:
+        if sync:
             _datasync(f.fileno())
             self._unsynced = False
         else:
@@ -340,8 +335,7 @@ class RequestJournal:
         No-op when nothing is pending."""
         if self._active is not None and self._unsynced:
             self._active.flush()
-            if self.fsync:
-                _datasync(self._active.fileno())
+            _datasync(self._active.fileno())
             self._unsynced = False
 
     def knows(self, fid: str) -> bool:
@@ -757,7 +751,6 @@ class RequestJournal:
             "last_compaction_age_s":
                 None if self._last_compaction is None
                 else round(time.monotonic() - self._last_compaction, 3),
-            "fsync": self.fsync,
         }
 
     def close(self) -> None:
@@ -784,7 +777,6 @@ def replay_journal(journal_dir: str) -> Dict[str, JournalEntry]:
     j = RequestJournal.__new__(RequestJournal)
     j.dir = journal_dir
     j.segment_bytes = 1 << 20
-    j.fsync = False
     j.appends = 0
     j.compactions = 0
     j.records_compacted = 0
@@ -811,7 +803,6 @@ def replay_scale_state(journal_dir: str) -> Dict[int, Dict[str, Any]]:
     j = RequestJournal.__new__(RequestJournal)
     j.dir = journal_dir
     j.segment_bytes = 1 << 20
-    j.fsync = False
     j.appends = 0
     j.compactions = 0
     j.records_compacted = 0

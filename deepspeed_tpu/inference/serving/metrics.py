@@ -10,7 +10,7 @@ biased p95 toward recent traffic and forgot bursts outright; the
 histograms are O(1) memory under sustained traffic and their quantiles
 cover the whole run. ``snapshot()`` keys are unchanged
 (``ttft_p50_s``/``ttft_p95_s``/``step_p50_s``/``step_p95_s``) so monitor
-wiring and ``ds_bench`` artifacts keep parsing; p99 keys are new.
+wiring keeps parsing; p99 keys are new.
 """
 
 import time

@@ -156,9 +156,6 @@ class RouterConfig:
     journal_dir: Optional[str] = None
     #: journal segment rotation size (bytes)
     journal_segment_bytes: int = 1 << 20
-    #: fsync every journal append (the durability contract). False is
-    #: ONLY for the ds_bench overhead A/B probe
-    journal_fsync: bool = True
     #: compact the journal every N router steps (sealed segments drop
     #: terminal-request records; empty ones are deleted). 0 = manual
     #: ``journal.compact()`` only
@@ -382,8 +379,7 @@ class ServingRouter:
         if self.cfg.journal_dir:
             self.journal = RequestJournal(
                 self.cfg.journal_dir,
-                segment_bytes=self.cfg.journal_segment_bytes,
-                fsync=self.cfg.journal_fsync)
+                segment_bytes=self.cfg.journal_segment_bytes)
         with _live_routers_lock:
             _LIVE_ROUTERS.add(self)
         log_dist(f"ServingRouter: {len(self.replicas)} replicas, "
@@ -633,8 +629,7 @@ class ServingRouter:
             if self.journal is None:
                 self.journal = RequestJournal(
                     journal_dir,
-                    segment_bytes=self.cfg.journal_segment_bytes,
-                    fsync=self.cfg.journal_fsync)
+                    segment_bytes=self.cfg.journal_segment_bytes)
             elif os.path.abspath(self.journal.dir) != \
                     os.path.abspath(journal_dir):
                 raise ValueError(

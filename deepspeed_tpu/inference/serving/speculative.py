@@ -16,8 +16,7 @@ the PLD/"prompt lookup decoding" lineage): match the last n-gram of the
 resident's OWN prompt + generated history against an earlier occurrence
 in that same history and propose the tokens that followed it. Zero
 extra device work, no draft model to load, and it pays exactly on the
-repetitive traffic the prefix cache already proves is common
-(shared-prefix hit rate 0.42-0.47 in SERVING_r08): multi-turn replays,
+repetitive traffic the prefix cache serves: multi-turn replays,
 quote-heavy completions, structured output, greedy repetition loops.
 
 A draft MODEL can slot in later by implementing :class:`Drafter` —

@@ -146,8 +146,8 @@ class LlamaConfig:
 
     @staticmethod
     def llama_400m(**over):
-        """The bench flagship (~400M): shared by bench.py and
-        tools/bench_decode.py so both measure the same model."""
+        """A ~400M preset of no published model, named by the README's
+        example alone (a line removed here moves every frame below it)."""
         return LlamaConfig(**{**dict(
             vocab_size=32000, hidden_size=1024, intermediate_size=2816,
             num_hidden_layers=24, num_attention_heads=16,

@@ -426,7 +426,7 @@ def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx):
         # touched experts' weights per token instead of computing all E
         # — a decode step is bound by the weight bytes it streams (the
         # reference's einsum_sec_sm_ecm / moe_res_matmul kernels exist
-        # for exactly this; tools/bench_moe_decode.py measures it).
+        # for exactly this; no cell measures it yet: ROADMAP W1).
         # XLA's gather reads only the indexed expert rows from HBM.
         idx = topk_idx[:, 0]                        # [B, K]
         w1g = jnp.take(w1, idx, axis=0).astype(dt)  # [B, K, H, I]

@@ -23,14 +23,14 @@ layer makes *performance claims* measurable and defensible:
 - **Device memory watermarks** — ``device.memory_stats()`` live/peak HBM
   bytes, graceful no-op on backends (CPU) that expose none.
 - **Artifact meta stamp** — :func:`perf_meta`: git sha, jax/jaxlib
-  versions, device kind/count, host. Every ``ds_bench`` artifact carries
-  it so ``tools/perfdiff.py`` can refuse apples-to-oranges comparisons.
+  versions, device kind/count, host: what a stored number needs beside
+  it to be compared with another (nothing calls it today: ROADMAP D1e).
 
-A ``ProgramRegistry`` is cheap enough for hot paths: one dict-equality
-check per dispatch (the fingerprints are small flat dicts of strings) —
-the decode step pays ~tens of microseconds against a multi-millisecond
-step, and the tracing-overhead bar in ``SERVING_r*.json`` keeps that
-honest.
+A ``ProgramRegistry`` is meant for hot paths: one dict-equality check
+per dispatch (the fingerprints are small flat dicts of strings) and
+nothing on the device. What that check costs a decode step on the chip
+is not measured: no cell drives the serving path yet (ROADMAP W1), and
+a CPU run gives no time.
 """
 
 import hashlib
@@ -445,8 +445,8 @@ def git_sha(repo_root: Optional[str] = None) -> Optional[str]:
 
 
 def perf_meta() -> Dict[str, Any]:
-    """The provenance block every ``ds_bench`` artifact carries: enough to
-    refuse apples-to-oranges perf comparisons (``tools/perfdiff.py``) and
+    """A provenance block for a stored measurement: enough to refuse a
+    comparison of numbers from two installations or two devices, and
     to answer "what exactly produced this number?" months later."""
     import jax
     import jaxlib

@@ -31,9 +31,9 @@ class AsyncIOHandle:
         self._lib.ds_aio_backend_name.restype = ctypes.c_char_p
         # backend "uring" is the libaio-io_context equivalent (queue_depth
         # kernel-async ops in flight off one driver thread); "pool" is the
-        # pread/pwrite worker pool; "auto" currently resolves to pool (the
-        # AIO_r04 sweep measured pool ahead at every point on this host —
-        # flip when uring wins on real NVMe). O_DIRECT (reference: libaio
+        # pread/pwrite worker pool; "auto" currently resolves to pool (no
+        # ``tools/aio_bench.py`` sweep on real NVMe has shown uring ahead
+        # — flip it when one does). O_DIRECT (reference: libaio
         # O_DIRECT is the default path): aligned chunks bypass the page
         # cache through aligned bounce buffers; filesystems that refuse
         # O_DIRECT degrade to buffered IO.

@@ -533,8 +533,8 @@ class PipelineEngine(DeepSpeedEngine):
         in MEMORY the default ``time_checkpoint_chunk="auto"`` bounds the
         live set to ~2*sqrt(M+S) carries via chunked remat over the time
         scan, approaching 1F1B's warmup+1 bound at one extra forward of
-        recompute (measured: ``tools/pipe_memory.py``, ~60% backward temp
-        reduction vs the plain scan). Opt out with
+        recompute (the temp bytes on the chip are not measured: no cell
+        runs a pipeline, ROADMAP W3). Opt out with
         ``{"pipeline": {"time_checkpoint_chunk": 0}}`` for the GPipe-class
         fill-drain memory profile."""
         return TrainSchedule(self.micro_batches, self.pipe_module.num_stages, stage_id)

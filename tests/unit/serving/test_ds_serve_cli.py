@@ -268,7 +268,7 @@ def test_journal_dir_demo_durable_and_restart_recovers_nothing(tmp_path):
     recs, err = run(3)
     final = recs[-1]
     j = final["fleet"]["journal"]
-    assert j["dir"] == jdir and j["fsync"] is True
+    assert j["dir"] == jdir
     assert j["non_terminal"] == 0          # everything landed terminal
     results = [rec for rec in recs[:-1] if "rid" in rec]
     assert len(results) == 3

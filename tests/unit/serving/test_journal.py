@@ -74,9 +74,13 @@ def fleet(engine, n=2, jdir=None, rcfg_kw=None, **scfg_kw):
 # journal unit: append / replay / rotation / compaction
 # ---------------------------------------------------------------------------
 
-def test_roundtrip_rotation_and_compaction(tmp_path):
+def test_roundtrip_rotation_and_compaction(tmp_path, capsys):
     d = str(tmp_path / "j")
     j = RequestJournal(d, segment_bytes=4096)
+    # ds_report's journal line reads the live journal's status()
+    from deepspeed_tpu.env_report import journal_report
+    journal_report()
+    assert f"request journal: {d}" in capsys.readouterr().out
     for i in range(60):
         j.append_admit(f"r{i}", list(range(30)), 8, eos_token_id=5,
                        priority=i % 3, deadline_wall=None)
@@ -367,7 +371,7 @@ def test_status_safe_against_concurrent_transitions(tmp_path):
     import threading
 
     d = str(tmp_path / "j")
-    j = RequestJournal(d, fsync=False)
+    j = RequestJournal(d)
     stop = threading.Event()
 
     def mutate():

@@ -4,8 +4,8 @@ Acceptance contract of the rewrite: with ``ServingConfig.mixed_step=True``
 (the default) the engine serves every mix — shared-prefix traffic,
 preemption storms, chaos drills — through ONE resident compiled program
 with zero recompiles, token-identical to the legacy two-program engine
-(``mixed_step=False``, kept exactly so these A/Bs and the
-``ds_bench --serving-mixed`` sweep can measure both in the same run)."""
+(``mixed_step=False``, whose only users are these parity tests: ROADMAP
+D2)."""
 
 import numpy as np
 import pytest

@@ -182,7 +182,7 @@ def test_serving_metrics_snapshot_keys_stable():
         m.record_ttft(x)
         m.record_step(x / 10)
     snap = m.snapshot()
-    # the keys monitor wiring and ds_bench artifacts parse — frozen
+    # the keys monitor wiring parses — frozen
     for k in ("ttft_p50_s", "ttft_p95_s", "ttft_p99_s",
               "step_p50_s", "step_p95_s", "step_p99_s"):
         assert k in snap, k
