@@ -1104,3 +1104,46 @@ def causal_conv(x: jnp.ndarray, weight: jnp.ndarray, bias=None) -> jnp.ndarray:
         if j:
             xs = shift_tokens(xs)
     return y if bias is None else y + bias
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max_position_embeddings: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """YaRN's blended rotary frequencies ``[head_dim / 2]`` (float64 numpy)
+    and the corrected range ``(low, high)``: pair ``i`` keeps its frequency
+    ``theta^(-2i/d)`` below ``low`` (it turns more than ``beta_fast`` times
+    over the original length), takes it divided by ``factor`` above ``high``
+    (fewer than ``beta_slow`` turns), and a linear ramp of the two between."""
+    d = head_dim
+    extrap = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def corr(turns):
+        return d * np.log(original_max_position_embeddings
+                          / (2 * np.pi * turns)) / (2 * np.log(theta))
+
+    low = max(int(np.floor(corr(beta_fast))), 0)
+    high = min(int(np.ceil(corr(beta_slow))), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return extrap / factor * ramp + extrap * (1 - ramp), (low, high)
+
+
+def yarn_rotary_embedding(positions: jnp.ndarray, head_dim: int, theta: float,
+                          factor: float,
+                          original_max_position_embeddings: int,
+                          beta_fast: float = 32.0, beta_slow: float = 1.0,
+                          attention_factor: Optional[float] = None,
+                          dtype=jnp.float32):
+    """``rotary_embedding`` under YaRN (``rope_type`` "yarn"): cos/sin
+    ``[B, T, head_dim/2]`` at ``yarn_inv_freq``, BOTH multiplied by
+    ``attention_factor`` (None: ``0.1 ln(factor) + 1``), so rotated queries
+    and keys carry it and their scores its square. The same table at every
+    sequence length: no switch at the original length."""
+    inv_freq, _ = yarn_inv_freq(head_dim, theta, factor,
+                                original_max_position_embeddings, beta_fast,
+                                beta_slow)
+    if attention_factor is None:
+        attention_factor = 0.1 * np.log(factor) + 1.0
+    freqs = positions[..., None].astype(jnp.float32) \
+        * inv_freq.astype(np.float32)[None, None, :]
+    return ((jnp.cos(freqs) * attention_factor).astype(dtype),
+            (jnp.sin(freqs) * attention_factor).astype(dtype))

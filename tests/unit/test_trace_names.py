@@ -25,6 +25,7 @@ import deepspeed_tpu as ds
 from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3Config,
                                               DeepseekV3ForCausalLM)
+from deepspeed_tpu.models.mellum import MellumConfig, MellumForCausalLM
 from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
 from deepspeed_tpu.models.sambay import SambaYConfig, SambaYForCausalLM
 from deepspeed_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
@@ -58,6 +59,12 @@ TRAIN_SCOPES = {
     "sambay": ["ds.loss_and_grad", "ds.optimizer", "ds.embed", "ds.ssm_mix",
                "ds.ssm_scan", "ds.attn_proj", "ds.attention", "ds.da_mix",
                "ds.gmu", "ds.mlp", "ds.lm_head_loss"],
+    # a pattern of layer kinds over Mixtral's block: each block under its
+    # kind's outer scope, the two rotary tables under their own
+    "mellum": ["ds.loss_and_grad", "ds.optimizer", "ds.embed",
+               "ds.rope_tables", "ds.layer_window", "ds.layer_full",
+               "ds.attn_proj", "ds.attention", "ds.moe_router",
+               "ds.moe_experts", "ds.lm_head_loss"],
 }
 #: what every family names besides: the engine's cast of the master weights,
 #: the loop over the layers, the block's two pre-norms and residual sums
@@ -81,7 +88,8 @@ def train_text():
                 sa_config=dict(indexer_head_dim=8, indexer_num_heads=2,
                                q_chunk_size=16, kv_chunk_size=16,
                                topk=8)))),
-            ("sambay", SambaYForCausalLM(SambaYConfig.tiny(remat=True)))):
+            ("sambay", SambaYForCausalLM(SambaYConfig.tiny(remat=True))),
+            ("mellum", MellumForCausalLM(MellumConfig.tiny(remat=True)))):
         batch = {"input_ids": np.zeros((8, 32), np.int32),
                  "labels": np.zeros((8, 32), np.int32)}
         engine, *_ = ds.initialize(
@@ -129,8 +137,10 @@ def trace_names():
 #: (PR 41 added ``ds.ssm_scan``, ``ds.ssm_mix``, ``ds.gmu`` and ``ds.da_mix``
 #: under version 3: they stand only in ``models/sambay.py``'s step, which no
 #: cache held before them, and the six older cells' steps keep their module
-#: name, their lowered text and their cache entries)
-NAMES_PIN = (3, "085e6b5c46c43998")
+#: name, their lowered text and their cache entries; PR 49 added
+#: ``ds.layer_window``, ``ds.layer_full`` and ``ds.rope_tables`` the same way:
+#: they stand only in ``models/mellum.py``'s step)
+NAMES_PIN = (3, "4115774094a4356a")
 
 
 def test_names_version_is_raised_with_the_names():
@@ -141,7 +151,8 @@ def test_names_version_is_raised_with_the_names():
     scopes, spans = trace_names()
     assert {"ds.param_cast", "ds.layer_stack", "ds.norm", "ds.residual",
             "ds.sa_index", "ds.sa_select", "ds.sa_loss", "ds.ssm_scan",
-            "ds.ssm_mix", "ds.gmu", "ds.da_mix"} <= set(scopes) \
+            "ds.ssm_mix", "ds.gmu", "ds.da_mix", "ds.layer_window",
+            "ds.layer_full", "ds.rope_tables"} <= set(scopes) \
         and "counters" in spans
     digest = hashlib.sha256("\n".join(scopes + spans).encode()).hexdigest()
     assert (tracing.NAMES_VERSION, digest[:16]) == NAMES_PIN
