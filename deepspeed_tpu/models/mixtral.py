@@ -12,8 +12,11 @@ the ``expert`` mesh axis (which of their dims it shards: ``expert_layout``,
 the one place that says). For ``T > 1`` the layer is a dropless, token-sorted
 grouped matmul (``_routed_experts``): the ``N*K`` (token, expert) pairs are
 stably sorted by expert, each expert's run of rows multiplies its own
-weights through ``jax.lax.ragged_dot`` (XLA:TPU's grouped-matmul kernel),
-and each token's K rows are weighted and summed back in float32. Group
+weights in a grouped matmul (``_grouped_dot``: on one TPU device the
+small-group kernels of ``ops/pallas/grouped_matmul.py`` where their rule
+has tiles for the shape, ``jax.lax.ragged_dot`` — XLA:TPU's own kernel —
+everywhere else), and each token's K rows are weighted and summed back in
+float32. Group
 sizes are DATA, so no routing recompiles, nothing is dropped (decisive for
 HF logits parity) or padded to a capacity, and the arithmetic follows the
 rows routed: K/E of what computing every expert for every token costs. Under
@@ -39,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops.pallas import grouped_matmul
 from ..parallel.topology import BATCH_AXES, get_mesh, tokens_replicated
 from ..utils.logging import log_dist
 from .layers import (RMSNorm, cross_entropy_loss, head_scope, init_kv_cache,
@@ -282,26 +286,59 @@ class MixtralSparseMoeBlock(nn.Module):
         return out, frac, prob, rows
 
 
-def _grouped_dot(lhs, rhs, group_sizes):
-    """``lhs [M, A] x rhs [G, A, B] -> [M, B]``: rows of group ``g`` (runs
-    of ``group_sizes[g]`` sorted rows) times ``rhs[g]``, through
-    ``jax.lax.ragged_dot`` — XLA:TPU's own grouped-matmul kernel, float32
-    accumulation. Only this layout reaches the kernel: asked to contract
-    ``rhs``'s last dim instead, the compiler falls back to a masked dense
-    product that costs 12-14 ms where the kernel takes 8 (PERF.md), so a
-    transposed weight is transposed first.
+def _grouped_tiles(M, A, B, G, dtype):
+    """``grouped_matmul.plan`` of what this call site can see: the tiles of
+    the small-group kernels, or None where ``jax.lax.ragged_dot`` stays the
+    path (off a TPU, under a mesh of several devices, shapes the rule
+    leaves). Logged once a shape at trace time."""
+    mesh = get_mesh()
+    tiles = grouped_matmul.plan(
+        grouped_matmul.backend(), 1 if mesh is None else mesh.devices.size,
+        M, A, B, G, jnp.dtype(dtype).itemsize, grouped_matmul.device_kind())
+    grouped_matmul.log_plan(M, A, B, G, tiles)
+    return tiles
 
-    Rows past the last group belong to no group: the definition makes their
-    result zero, the TPU leaves it as it finds it (NaN at Mixtral's sizes),
-    so it is SELECTED to zero there — a multiply would keep a NaN."""
-    valid = jnp.arange(lhs.shape[0]) < jnp.sum(group_sizes)
+
+def _grouped_dot(lhs, rhs, group_sizes, transposed=False):
+    """``lhs [M, A] x rhs [G, A, B] -> [M, B]``: rows of group ``g`` (runs
+    of ``group_sizes[g]`` sorted rows) times ``rhs[g]``, float32
+    accumulation; with ``transposed`` ``rhs`` comes as ``[G, B, A]``. Rows
+    past the last group belong to no group and come back zero.
+
+    Where ``_grouped_tiles`` has tiles: ``ds_moe_gmm``
+    (``ops/pallas/grouped_matmul.py``), which contracts a transposed weight
+    as it lies and zeroes the rows of no group itself. Elsewhere
+    ``jax.lax.ragged_dot`` — XLA:TPU's own grouped-matmul kernel. Only
+    ``[M, A] x [G, A, B]`` reaches that kernel: asked to contract ``rhs``'s
+    last dim instead, the compiler falls back to a masked dense product that
+    costs 12-14 ms where the kernel takes 8 (PERF.md), so a transposed
+    weight is transposed first. And the TPU leaves the rows of no group as
+    it finds them (NaN at Mixtral's sizes), so they are SELECTED to zero —
+    a multiply would keep a NaN."""
+    M, A = lhs.shape
+    G, B = rhs.shape[0], rhs.shape[1 if transposed else 2]
+    tiles = _grouped_tiles(M, A, B, G, lhs.dtype)
+    if tiles is not None:
+        return grouped_matmul.gmm(
+            lhs, rhs, group_sizes, rows=tiles.rows, cols=tiles.cols,
+            transpose_rhs=transposed)
+    if transposed:
+        rhs = jnp.swapaxes(rhs, 1, 2)
+    valid = jnp.arange(M) < jnp.sum(group_sizes)
     return jnp.where(valid[:, None],
                      jax.lax.ragged_dot(lhs, rhs, group_sizes), 0)
 
 
 def _grouped_outer(lhs, rhs, group_sizes):
     """``lhs [M, A], rhs [M, B] -> [G, A, B]``: ``lhs[rows of g]^T @
-    rhs[rows of g]`` for every group — a stacked weight's gradient."""
+    rhs[rows of g]`` for every group — a stacked weight's gradient, in the
+    operands' dtype (``ds_moe_gmm_t`` or ``ragged_dot_general``, as
+    ``_grouped_dot`` chooses)."""
+    (M, A), B, G = lhs.shape, rhs.shape[1], group_sizes.shape[0]
+    tiles = _grouped_tiles(M, A, B, G, lhs.dtype)
+    if tiles is not None:
+        return grouped_matmul.tgmm(lhs, rhs, group_sizes, rows=tiles.rows,
+                                   cols=tiles.cols_t)
     dims = jax.lax.RaggedDotDimensionNumbers(
         dot_dimension_numbers=(((0,), (0,)), ((), ())),
         lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
@@ -351,8 +388,8 @@ def _sorted_experts_bwd(res, g):
         g_row = g[order % N]                 # each row's cotangent, before
         w_row = topk_w.reshape(K * N)[order][:, None]  # its routing weight
     with jax.named_scope("moe_gmm"):
-        t = _grouped_dot(g_row, jnp.swapaxes(w2, 1, 2),
-                         group_sizes).astype(f32)    # g @ w2^T
+        t = _grouped_dot(g_row, w2, group_sizes,
+                         transposed=True).astype(f32)    # g @ w2^T
         a1, a3 = h1.astype(f32), h3.astype(f32)
         sig = jax.nn.sigmoid(a1)
         h = a1 * sig * a3
@@ -360,8 +397,8 @@ def _sorted_experts_bwd(res, g):
         dh = t * w_row
         dh1 = (dh * a3 * sig * (1 + a1 * (1 - sig))).astype(dt)
         dh3 = (dh * a1 * sig).astype(dt)
-        dxs = _grouped_dot(dh1, jnp.swapaxes(w1, 1, 2), group_sizes) + \
-            _grouped_dot(dh3, jnp.swapaxes(w3, 1, 2), group_sizes)
+        dxs = _grouped_dot(dh1, w1, group_sizes, transposed=True) + \
+            _grouped_dot(dh3, w3, group_sizes, transposed=True)
         dw1 = _grouped_outer(xs, dh1, group_sizes)
         dw3 = _grouped_outer(xs, dh3, group_sizes)
         dw2 = _grouped_outer((h * w_row).astype(dt), g_row, group_sizes)

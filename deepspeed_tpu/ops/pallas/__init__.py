@@ -43,3 +43,7 @@ BLOCK_SPARSE_BWD_DKV = "ds_block_sparse_bwd_dkv"
 # time with the state in VMEM, and its backward (``selective_scan.py``)
 SSM_SCAN_FWD = "ds_ssm_scan_fwd"
 SSM_SCAN_BWD = "ds_ssm_scan_bwd"
+# the expert layer's grouped products on one chip, for small groups
+# (``grouped_matmul.py``): rows x a group's weight, and the weights' gradient
+MOE_GMM = "ds_moe_gmm"
+MOE_GMM_T = "ds_moe_gmm_t"

@@ -60,6 +60,16 @@ def chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture
+def on_v5e(monkeypatch):
+    """``mixtral._grouped_tiles`` asks the backend and the device kind,
+    which are the CPU's here: answer for the described chip."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "backend", lambda: "tpu")
+    monkeypatch.setattr(gm, "device_kind", lambda: "TPU v5 lite")
+
+
 def _scale_kw(scales):
     return dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
 
@@ -239,14 +249,33 @@ def test_kernel_compiles_for_v5e(chip, name):
 
 # -- the expert layer on one chip, at OLMoE's shapes -------------------------
 
-def test_olmoe_expert_layer_reaches_the_grouped_kernel_on_one_v5e(chip):
+_GMM = re.compile(r"%(ds_moe_gmm(?:_t)?)[.\d]* = (\w+)\[([\d,]*)\]")
+
+
+def _grouped_products(hlo):
+    """``[(kernel, dtype, dims)]`` of the compiled module's grouped products
+    in the small-group kernels; no product may be left in ``ragged-dot`` or
+    in a masked dense fallback (``convolution``)."""
+    assert "ragged-dot" not in hlo
+    assert "convolution" not in "".join(
+        line for line in hlo.splitlines() if "moe_gmm" in line)
+    found = _GMM.findall(hlo)
+    assert all("tpu_custom_call" in line for line in hlo.splitlines()
+               if _GMM.search(line))
+    return found
+
+
+def test_olmoe_expert_layer_reaches_the_grouped_kernel_on_one_v5e(
+        chip, on_v5e):
     """``models/mixtral.py``'s layer with NO expert axis at OLMoE-1B-7B's
     widths (2 x 4096 tokens, 64 experts of 1024, top-8: a 65,536-row buffer
     in 64 groups of about 1,024 rows), forward and backward, compiles for
     one v5e, and all nine products (three forward, three dx, three dw) are
-    XLA:TPU's grouped-matmul kernel, ``ragged-dot-*`` custom calls: a masked
-    dense fallback (``convolution``) would cost 64/8 times the rows. What
-    ``kernel.moe_gmm.roofline_share`` charges a call is these shapes."""
+    the small-group kernels (``ops/pallas/grouped_matmul.py``): six
+    ``ds_moe_gmm``, three ``ds_moe_gmm_t``, none ``ragged-dot-*`` (XLA:TPU's
+    kernel, 36% of the matmul peak at this shape where these read 60%:
+    PERF.md section 6, PR 50) and none a masked dense fallback. What
+    ``kernel.ds_moe_gmm.roofline_share`` charges a call is these shapes."""
     import deepspeed_tpu.models.mixtral as mx
     from deepspeed_tpu.models import MixtralConfig
 
@@ -271,20 +300,105 @@ def test_olmoe_expert_layer_reaches_the_grouped_kernel_on_one_v5e(chip):
     hlo = jax.jit(jax.value_and_grad(
         loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(
             *args).compile().as_text()
-    products = re.findall(r"%(ragged-dot-none\S*) = (\w+)\[([\d,]*)\]", hlo)
+    products = _grouped_products(hlo)
     assert len(products) == 9, products
-    # what kernel.moe_gmm.roofline_share's byte count takes each result for
+    # what the roofline's byte count takes each result for
     assert {dtype for _, dtype, _ in products} == {"bf16"}
-    shapes = sorted(dims for _, _, dims in products)
+    shapes = sorted((kernel, dims) for kernel, _, dims in products)
     rows = B * T * K
-    assert shapes.count(f"{rows},{INTER}") == 3      # gate, up, g @ w2^T
-    assert shapes.count(f"{rows},{HID}") == 3        # down, two dx
-    assert shapes.count(f"{E},{HID},{INTER}") + \
-        shapes.count(f"{E},{INTER},{HID}") == 3      # dw1, dw3, dw2
-    assert all("tpu_custom_call" in line for line in hlo.splitlines()
-               if re.search(r"%ragged-dot-none\S* = ", line))
-    assert "convolution" not in "".join(
-        line for line in hlo.splitlines() if "moe_gmm" in line)
+    assert shapes.count(("ds_moe_gmm", f"{rows},{INTER}")) == 3  # gate, up,
+    assert shapes.count(("ds_moe_gmm", f"{rows},{HID}")) == 3    # g @ w2^T;
+    assert shapes.count(("ds_moe_gmm_t", f"{E},{HID},{INTER}")) == 2  # down,
+    assert shapes.count(("ds_moe_gmm_t", f"{E},{INTER},{HID}")) == 1  # 2 dx
+
+
+#: a held share's layer at its cell's shapes: ``(tokens, top-k, hidden,
+#: intermediate, experts held, experts the router scores, compact rows)``
+HELD_SHARES = {
+    "zaya_8k": (8192, 1, 2048, 2048, 8, 17, None),      # 8 of 17: no compact
+    "mellum2_8k": (8192, 8, 2304, 896, 8, 64, 16384),
+    "keye_16k": (16384, 8, 2048, 768, 16, 128, 32768),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(HELD_SHARES))
+def test_held_share_reaches_the_small_group_kernels_on_one_v5e(
+        chip, on_v5e, cell):
+    """``_routed_experts`` at ZAYA1-8B's, Mellum2's and Keye-VL2's one-chip
+    shares, forward and backward, compiled for one v5e: every grouped
+    product is ``ds_moe_gmm`` / ``ds_moe_gmm_t`` -- nine over the one buffer
+    (ZAYA), or nine over the compact buffer and eleven over the fallback's
+    rows (an overflowing step's backward computes ``h1`` and ``h3`` again),
+    each pair behind its ``conditional``."""
+    import deepspeed_tpu.models.mixtral as mx
+
+    N, K, HID, INTER, G, experts, C = HELD_SHARES[cell]
+    assert mx._compact_rows(N * K, G, experts) == C
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    args = (struct((N, HID), BF16), struct((G, HID, INTER), BF16),
+            struct((G, INTER, HID), BF16), struct((G, HID, INTER), BF16),
+            struct((N, K), jnp.float32), struct((N, K), jnp.int32))
+
+    def loss(x, w1, w2, w3, topk_w, topk_idx):
+        out, rows = mx._routed_experts(x, w1, w2, w3, topk_w, topk_idx, 0,
+                                       experts)
+        return jnp.sum(out.astype(jnp.float32) ** 2), rows
+
+    hlo = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(
+            *args).compile().as_text()
+    products = _grouped_products(hlo)
+    rows = sorted(int(dims.split(",")[0]) for kernel, _, dims in products
+                  if kernel == "ds_moe_gmm")
+    outer = [dims for kernel, _, dims in products if kernel == "ds_moe_gmm_t"]
+    assert set(outer) == {f"{G},{HID},{INTER}", f"{G},{INTER},{HID}"}
+    if C is None:
+        assert rows == 6 * [N * K] and len(outer) == 3
+    else:
+        assert rows == 6 * [C] + 8 * [N * K] and len(outer) == 6
+
+
+def test_a_step_lowers_each_distinct_grouped_kernel_once(on_v5e):
+    """Two expert layers in one step, lowered for the TPU: 22 call sites
+    (three forward, two replayed, three dx and three dw products a layer),
+    and the module holds SEVEN lowerings of a kernel -- ``ds_moe_gmm`` at
+    ``[M, H] x [G, H, I]`` (once more for ``jax.checkpoint``'s replay, whose
+    jaxpr is its own), ``[M, I] x [G, I, H]`` and the two transposed
+    weights of the backward, ``ds_moe_gmm_t`` at the two gradients' shapes
+    -- because the kernels and their visit table are jitted entries: every
+    further site is a call of the function the first one lowered, whatever
+    the number of layers. A ``pallas_call``
+    lowered site by site costs its Mosaic module once a site in every
+    cell's set-up (PR 25 read +16.7% ``setup_s`` that way)."""
+    import deepspeed_tpu.models.mixtral as mx
+
+    N, K, HID, INTER, G = 4096, 2, 256, 512, 8
+    struct = jax.ShapeDtypeStruct
+    args = (struct((N, HID), BF16), struct((2, G, HID, INTER), BF16),
+            struct((2, G, INTER, HID), BF16), struct((2, G, HID, INTER), BF16),
+            struct((N, K), jnp.float32), struct((N, K), jnp.int32))
+
+    @jax.checkpoint
+    def layer(x, w1, w2, w3, topk_w, topk_idx):
+        return x + mx._routed_experts(x, w1, w2, w3, topk_w, topk_idx, 0)[0]
+
+    def loss(x, w1, w2, w3, topk_w, topk_idx):
+        for i in range(2):
+            x = layer(x, w1[i], w2[i], w3[i], topk_w, topk_idx)
+        return jnp.sum(x.astype(jnp.float32) ** 2)
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+    text = grad.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    kernels = re.findall(r'kernel_name = "(ds_moe_gmm\w*)"', text)
+    assert sorted(kernels) == 5 * ["ds_moe_gmm"] + 2 * ["ds_moe_gmm_t"]
+    sites = re.findall(r"call @_(gmm|tgmm|visits)\w*\(", text)
+    assert sorted(sites) == 16 * ["gmm"] + 6 * ["tgmm"] + 22 * ["visits"]
+    # and the visit tables: one for each kernel at the one row count, and
+    # as many again in the replay's jaxpr and the backward's
+    assert len(re.findall(r"func.func private @_visits", text)) == 4
 
 
 # -- Mixtral's expert layer across the four chips ---------------------------
@@ -456,15 +570,18 @@ def _computations(hlo):
             for p in parts if p.startswith(("%", "ENTRY"))}
 
 
-def test_kimi_expert_layers_run_on_the_compact_buffer_on_one_v5e(chip):
+def test_kimi_expert_layers_run_on_the_compact_buffer_on_one_v5e(
+        chip, on_v5e):
     """Two scanned, remat'd ``DeepseekV3MoE`` layers at kimi 8k's widths
     (8,192 tokens, top-6 of 64 experts, 8 of 1408 held), forward and
     backward, compiled for one v5e: every grouped product over the 49,152
     worst-case rows stands in a ``conditional``'s FALLBACK branch, and the
     branch beside it holds the same layer over 12,288 rows — three products
     in the forward scan, two in the replay (the down projection is no
-    residual), six in the backward pass. At the parent all eleven ran over
-    49,152 rows, whatever the load."""
+    residual), six in the backward pass. Before PR 36 all eleven ran over
+    49,152 rows, whatever the load. Since PR 50 every one of them is a
+    small-group kernel, ``ds_moe_gmm`` / ``ds_moe_gmm_t``, and none is
+    XLA:TPU's ``ragged-dot``."""
     from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3Config,
                                                   DeepseekV3MoE)
     from deepspeed_tpu.models.layers import resolve_remat_policy
@@ -498,8 +615,9 @@ def test_kimi_expert_layers_run_on_the_compact_buffer_on_one_v5e(chip):
     assert len(branches) == 3 and all(len(b) == 2 for b in branches)
     fallback = {b[0] for b in branches}     # index 0: the predicate is false
     compact = {b[1] for b in branches}
-    rows_of = lambda text: [int(dims.split(",")[0]) for dims in re.findall(
-        r"%ragged-dot-none\S* = \w+\[([\d,]*)\]", text)]
+    assert len(_grouped_products(hlo)) == 11 + 13
+    rows_of = lambda text: [int(dims.split(",")[0])
+                            for _, _, dims in _GMM.findall(text)]
     counts = {"compact": [], "fallback": []}
     for name, text in comps.items():
         rows = [r for r in rows_of(text) if r != G]     # not the dw products

@@ -381,6 +381,18 @@ def _ssm_scan(bwd):
     return jax.grad(lambda *a: fwd(*a).sum(), argnums=tuple(range(6))), args
 
 
+def _moe_gmm(outer):
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+
+    rows, groups = ((512, 256), BF16), ((4,), jnp.int32)
+    if outer:
+        return functools.partial(gm.tgmm, rows=256, cols=256,
+                                 interpret=False), \
+            [rows, rows, groups]
+    return functools.partial(gm.gmm, rows=256, cols=256, interpret=False), \
+        [rows, ((4, 256, 256), BF16), groups]
+
+
 def _selection():
     from deepspeed_tpu.models.indexed_attention import select_mask
 
@@ -414,6 +426,8 @@ KERNELS = {
     names.SA_INDEX_BWD_DK: ("ds_sa_index_bwd_dk", lambda: _sa_index(True)),
     names.SSM_SCAN_FWD: ("ds_ssm_scan_fwd", lambda: _ssm_scan(False)),
     names.SSM_SCAN_BWD: ("ds_ssm_scan_bwd", lambda: _ssm_scan(True)),
+    names.MOE_GMM: ("ds_moe_gmm", lambda: _moe_gmm(False)),
+    names.MOE_GMM_T: ("ds_moe_gmm_t", lambda: _moe_gmm(True)),
 }
 
 
