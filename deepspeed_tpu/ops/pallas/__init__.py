@@ -5,6 +5,10 @@ package only ``models/layers.py resolve_remat_policy`` and
 ``models/indexed_attention.py`` import any: the checkpoint names)."""
 
 FLASH_FWD = "ds_flash_fwd"
+# the flash backward: all three gradients in one walk by kv row where a
+# head's dQ fits the chip's VMEM (``flash_attention.fused_backward``), the
+# two kernels below where it does not
+FLASH_BWD = "ds_flash_bwd"
 FLASH_BWD_DQ = "ds_flash_bwd_dq"
 FLASH_BWD_DKV = "ds_flash_bwd_dkv"
 # ``checkpoint_name``s of the two values the flash backward reads that only

@@ -30,8 +30,16 @@ the grid's last dimension their traced count; a kept tile reads its block of
 the mask in place of the iota rule. That path differentiates (its own
 ``custom_vjp``, the mask one more operand) and hands out the log-sum-exp too.
 Without a mask no operand, branch or table entry differs from what they were.
-Backward uses the saved logsumexp and recomputes P per tile: one kernel for
-dQ (loop over kv), one for dK/dV (loop over q).
+Backward uses the saved logsumexp and recomputes P per tile, in ONE kernel
+(``ds_flash_bwd``) that walks the table by kv row: a tile's ``s``, ``p``,
+``dp`` and ``ds`` are formed once and feed all three gradients, five
+products a tile. dK and dV accumulate in a kv row's scratch; dQ adds into
+the rows of a float32 buffer that holds a head's WHOLE dQ in VMEM for the
+walk (for one query row the kv tiles still arrive ascending), written back
+once a head. Where that buffer does not fit the chip's VMEM, or the chip is
+not in the table (``fused_backward``: a rule of shape, item size and
+``device_kind``, no option), two kernels run, seven products a tile: one for
+dQ (by q row, loop over kv), one for dK/dV (by kv row, loop over q).
 
 On non-TPU backends the public entry falls back to reference einsum math so
 the same model code runs everywhere (tests use the fallback + interpret
@@ -48,7 +56,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD, FLASH_LSE, FLASH_OUT
+from . import (FLASH_BWD, FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD, FLASH_LSE,
+               FLASH_OUT, grouped_matmul)
 
 NEG_INF = -1e30
 
@@ -361,11 +370,32 @@ def _bwd_dkv_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, *rest,
                     sm_scale: float, causal: bool, block_q: int,
                     block_k: int, tq: int, tk: int, window,
-                    dense_mask: bool = False):
-    mask_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest if dense_mask \
-        else (None, *rest)
+                    dense_mask: bool = False, fused: bool = False):
+    """dK and dV of a kv row from its query tiles and, ``fused``, dQ too
+    (``ds_flash_bwd``): ``dq_scr [Tq_p, D]`` float32 is a head's whole dQ,
+    zeroed at the walk's first step, added into a q tile's rows at a time
+    and written to ``dq_ref``'s ``(1, 1, Tq_p, D)`` block at the last (its
+    index moves with ``(b, h)`` alone: one write-back a head). Rows no kept
+    tile touches stay zero."""
+    mask_ref, dk_ref, dv_ref, *rest = rest if dense_mask else (None, *rest)
+    dq_ref, dk_scr, dv_scr, dq_scr = rest if fused else (None, *rest, None)
     t = pl.program_id(2)
     iq, ik, flags = iq_of[t], ik_of[t], flags_of[t]
+
+    def q_rows(i):
+        return pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+
+    def each_q_tile(fn):  # a loop, not 1,024 unrolled registers of text
+        jax.lax.fori_loop(0, dq_scr.shape[0] // block_q,
+                          lambda i, _: fn(q_rows(i)), None)
+
+    if fused:
+        @pl.when(t == 0)
+        def _zero_dq():
+            def zero(rows):
+                dq_scr[rows, :] = jnp.zeros((block_q, dq_scr.shape[1]),
+                                            jnp.float32)
+            each_q_tile(zero)
 
     @pl.when(flags & _FIRST != 0)
     def _init():
@@ -397,6 +427,9 @@ def _bwd_dkv_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, do_ref,
         ds = p * (dp - delta)                   # [bq, bk]
         dk_scr[:] += sm_scale * jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        if fused:  # the fifth product, on the ds the fourth just read
+            dq_scr[q_rows(iq), :] += sm_scale * jax.lax.dot(
+                ds, k, preferred_element_type=jnp.float32)
 
     _on_tile(flags, _body)
 
@@ -404,6 +437,50 @@ def _bwd_dkv_kernel(iq_of, ik_of, flags_of, q_ref, k_ref, v_ref, do_ref,
     def _finalize():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+
+    if fused:
+        # the table's count is traced under a mask that is data
+        @pl.when(t == pl.num_programs(2) - 1)
+        def _write_dq():
+            def cast(rows):
+                dq_ref[0, 0, rows, :] = dq_scr[rows, :].astype(dq_ref.dtype)
+            each_q_tile(cast)
+
+
+#: the resident dQ -- the float32 buffer and the output block it is written
+#: to, which the pipeline holds twice -- may take this share of a chip's
+#: VMEM (``grouped_matmul._VMEM_BYTES``: the chips the kernels were timed
+#: on). On a v5e a quarter is 32 MiB: 8,192 x 128 in bf16 holds 8, kimi's
+#: 192-wide keys (256 lanes) and keye's 16,384 rows 16 each, and 32,768 x 128
+#: is the longest that fits; 131,072 x 128 would hold the whole VMEM.
+_DQ_VMEM_SHARE = 4
+
+
+def fused_backward(tq_p: int, d: int, itemsize: int,
+                   device_kind: str) -> Optional[int]:
+    """The rule that says which backward runs, a pure function of what
+    ``_flash_bwd`` sees: the bytes of VMEM a head's resident dQ takes
+    (``[tq_p, d]`` in float32 and twice in the output's item size, its lanes
+    padded to whole registers) where ``ds_flash_bwd`` runs, or None where
+    the two kernels stay -- a dQ over the share above, and a chip whose VMEM
+    is not in the table (every platform but a TPU among them: interpret
+    mode on a CPU walks the two kernels unless a test answers for a chip)."""
+    vmem = grouped_matmul._VMEM_BYTES.get(device_kind)
+    resident = tq_p * _ceil_div(d, 128) * 128 * (4 + 2 * itemsize)
+    if vmem is None or resident * _DQ_VMEM_SHARE > vmem:
+        return None
+    return resident
+
+
+def _fused_vmem(resident, bq, bk, d, dv, itemsize, dense_mask):
+    """What a ``ds_flash_bwd`` call holds: the resident dQ; the tile's
+    operand and dK/dV blocks, each twice; dK/dV's float32 scratch and the
+    operands' float32 copies; the four ``[bq, bk]`` float32 products (s, p,
+    dp, ds); the two statistics' rows (eight sublanes each, twice) and the
+    mask's block twice."""
+    tile = (bq + bk) * (d + dv)
+    return resident + tile * (4 * itemsize + 8) + 4 * bq * bk * 4 \
+        + 4 * 8 * bq * 4 + 2 * bq * bk * dense_mask
 
 
 def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
@@ -445,43 +522,46 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
             (b, iq_of[t], ik_of[t]))]
         kernel_kw["dense_mask"] = True
 
-    # the same entries twice: by q row here, by kv row for dK/dV below
-    by_q, steps = _table(tiles, Tq, Tk, bq, bk, causal, window)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **kernel_kw),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, H, steps),
-            in_specs=in_specs,
-            out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, H, Tq_p, D), q.dtype),
-        interpret=interpret,
-        name=FLASH_BWD_DQ,
-    )(*by_q, q, k, v, do, lse, delta, *mask_args)
+    def call(kernel, name, by_kv, out_specs, out_shape, scratch, **kw):
+        table, steps = _table(tiles, Tq, Tk, bq, bk, causal, window, by_kv)
+        return pl.pallas_call(
+            functools.partial(kernel, **kernel_kw),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(B, H, steps),
+                in_specs=in_specs,
+                out_specs=out_specs,
+                scratch_shapes=[pltpu.VMEM(shape, jnp.float32)
+                                for shape in scratch],
+            ),
+            out_shape=out_shape,
+            interpret=interpret,
+            name=name,
+            **kw,
+        )(*table, q, k, v, do, lse, delta, *mask_args)
 
-    by_kv, steps = _table(tiles, Tq, Tk, bq, bk, causal, window,
-                          by_kv=True)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **kernel_kw),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, H, steps),
-            in_specs=in_specs,
-            out_specs=[k_spec, v_spec],
-            scratch_shapes=[
-                pltpu.VMEM((bk, D), jnp.float32),
-                pltpu.VMEM((bk, Dv), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, Tk_p, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, Tk_p, Dv), v.dtype),
-        ],
-        interpret=interpret,
-        name=FLASH_BWD_DKV,
-    )(*by_kv, q, k, v, do, lse, delta, *mask_args)
+    dq_shape = jax.ShapeDtypeStruct((B, H, Tq_p, D), q.dtype)
+    dkv_shapes = [jax.ShapeDtypeStruct((B, H, Tk_p, D), k.dtype),
+                  jax.ShapeDtypeStruct((B, H, Tk_p, Dv), v.dtype)]
+    resident = fused_backward(Tq_p, D, q.dtype.itemsize,
+                              grouped_matmul.device_kind())
+    if resident is not None:
+        held = _fused_vmem(resident, bq, bk, D, Dv, q.dtype.itemsize,
+                           mask is not None)
+        dk, dv, dq = call(
+            functools.partial(_bwd_dkv_kernel, fused=True), FLASH_BWD, True,
+            [k_spec, v_spec, pl.BlockSpec(
+                (1, 1, Tq_p, D),
+                lambda b, h, t, iq_of, ik_of, flags_of: (b, h, 0, 0))],
+            dkv_shapes + [dq_shape], [(bk, D), (bk, Dv), (Tq_p, D)],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=grouped_matmul._vmem_limit(held)))
+    else:
+        # the same entries twice: by q row here, by kv row for dK/dV below
+        dq = call(_bwd_dq_kernel, FLASH_BWD_DQ, False, q_spec, dq_shape,
+                  [(bq, D)])
+        dk, dv = call(_bwd_dkv_kernel, FLASH_BWD_DKV, True, [k_spec, v_spec],
+                      dkv_shapes, [(bk, D), (bk, Dv)])
     return dq[:, :, :Tq], dk[:, :, :Tk], dv[:, :, :Tk]
 
 

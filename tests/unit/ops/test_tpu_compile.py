@@ -62,8 +62,9 @@ def chip(topo):
 
 @pytest.fixture
 def on_v5e(monkeypatch):
-    """``mixtral._grouped_tiles`` asks the backend and the device kind,
-    which are the CPU's here: answer for the described chip."""
+    """``mixtral._grouped_tiles`` asks the backend and the device kind, and
+    ``flash_attention.fused_backward`` the device kind, which are the CPU's
+    here: answer for the described chip."""
     from deepspeed_tpu.ops.pallas import grouped_matmul as gm
 
     monkeypatch.setattr(gm, "backend", lambda: "tpu")
@@ -87,7 +88,7 @@ def _flash(bwd, window, shape=(1, 2048, H, D), v_dim=None):
 
 def _flash_sa(bwd, T=16384):
     """keye-vl2-30b-a3b.train.16k: a [B, T, T] int8 mask that is data,
-    shared by 32 heads of 128; the backward's two kernels read it too."""
+    shared by 32 heads of 128; the backward reads it too."""
     q = ((1, T, H, D), BF16)
     fwd = functools.partial(flash_attention, causal=True, interpret=False)
     args = [q, q, q, ((1, T, T), jnp.int8)]
@@ -235,6 +236,47 @@ CASES = {
     "quant_matmul_int4": lambda: _quant_matmul("int4"),
     "fused_adam_leaf": _fused_adam,
 }
+
+
+#: a flash call's backward in each flash cell, the longest dQ a v5e holds
+#: and the first it does not
+FLASH_BWD = {
+    "olmoe_4k": lambda: _flash(True, None, (2, 4096, 16, D)),
+    "train8k": lambda: _flash(True, 4096, (1, 8192, H, D)),
+    "mla_train8k": lambda: _flash(True, None, (1, 8192, 16, 192), v_dim=128),
+    "cca_train8k": lambda: _flash(True, None, (1, 8192, 8, D)),
+    "sa_train16k": lambda: _flash_sa(True),
+    # phi4-mini-flash.train.8k: a pair's two 64-wide heads, values side by
+    # side; its 512 window and the full layers
+    "da_window_train8k": lambda: _flash(True, 512, (1, 8192, 20, 64),
+                                        v_dim=128),
+    "da_train8k": lambda: _flash(True, None, (1, 8192, 20, 64), v_dim=128),
+    # mellum2-12b-a2.5b.train.8k: three 1,024-window layers a period
+    "swa_train8k": lambda: _flash(True, 1024, (1, 8192, H, D)),
+    "longest_32k": lambda: _flash(True, None, (1, 32768, 2, D)),
+    # 64 MiB resident, twice the share: the two kernels
+    "over_the_share_64k": lambda: _flash(True, None, (1, 65536, 1, D)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FLASH_BWD))
+def test_flash_backward_is_one_kernel_on_a_v5e(chip, on_v5e, name):
+    """Where the rule answers for the described chip, every flash cell's
+    backward compiles as ``ds_flash_bwd`` alone -- the head's float32 dQ
+    ``[Tq, D]`` and its output block resident, 8 to 32 MiB, under the
+    ``vmem_limit_bytes`` the call sets -- and a dQ over the share as the two
+    kernels. (``test_kernel_compiles_for_v5e``'s ``flash_fwd_bwd*`` cases,
+    which ask for this CPU's kind, keep compiling the two kernels at the
+    cells' shapes, as a chip the rule does not know would.)"""
+    fn, args = FLASH_BWD[name]()
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+              for shape, dtype in args]
+    hlo = jax.jit(fn).lower(*shapes).compile().as_text()
+    called = re.findall(
+        r"^\s*%?\w*(ds_flash_[a-z_]*[a-z])[_.\d]* = .*custom-call", hlo, re.M)
+    backward = ["ds_flash_bwd_dkv", "ds_flash_bwd_dq"] \
+        if name == "over_the_share_64k" else ["ds_flash_bwd"]
+    assert sorted(called) == backward + ["ds_flash_fwd"]
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
@@ -532,13 +574,14 @@ def _zaya_two_layers():
     (_zaya_two_layers, 1),       # unit-length operands, 128 of 256 columns
 ], ids=["llama_scan2", "deepseek_v3_dense1_scan2", "zaya_scan2"])
 def test_remat_train_step_runs_the_flash_forward_once_a_layer(
-        chip, monkeypatch, build, sites):
+        chip, on_v5e, monkeypatch, build, sites):
     """The gradient of a scanned, remat'd model under the DEFAULT policy,
     compiled for one v5e: ``ds_flash_fwd`` stands once for each place the
     forward pass calls it and NOT in the backward scan's replay, because
     every policy keeps the kernel's named output and log-sum-exp
     (``layers.resolve_remat_policy``). Before PR 34 the replay held a second
-    instance, a third of the attention time of the 8k cells."""
+    instance, a third of the attention time of the 8k cells. The backward of
+    each call is ONE kernel, ``ds_flash_bwd``, on this chip."""
     import deepspeed_tpu.ops.pallas.flash_attention as fa
 
     # the models ask jax.default_backend(), which is the CPU here
@@ -557,8 +600,8 @@ def test_remat_train_step_runs_the_flash_forward_once_a_layer(
     count = lambda kernel: len(re.findall(
         rf"^\s*%?{kernel}[.\d]* = .*custom-call", hlo, re.M))
     assert count("ds_flash_fwd") == sites
-    assert count("ds_flash_bwd_dq") == sites
-    assert count("ds_flash_bwd_dkv") == sites
+    assert count("ds_flash_bwd") == sites
+    assert count("ds_flash_bwd_dq") == count("ds_flash_bwd_dkv") == 0
 
 
 # -- a held share's compact row buffer (PR 36) -------------------------------

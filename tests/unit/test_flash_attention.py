@@ -718,16 +718,10 @@ SA_CASES = ("first_tiles_of_a_q_row_empty", "empty_diagonal_tile",
             "ragged_length")
 
 
-def _sa_case(case):
-    """The kernels (interpret mode) and the einsum reference under one mask:
-    forward, the three gradients and the indexer's loss with its own, as numpy
-    arrays (a device array left alive fails ``test_engine.py``'s look at
-    ``jax.live_arrays()`` in the same worker)."""
-    from deepspeed_tpu.ops.pallas import sa_probs
-    from deepspeed_tpu.ops.pallas.flash_attention import mask_tiles
-
+def _sa_mask(case):
+    """``(mask [B, T, T] int8, the 5 x 5 tiles it selects in)`` of a case."""
     T, patterns = _sa_patterns(case)
-    B, H, D, blk = len(patterns), 2, 16, _SA_BLOCK
+    B, blk = len(patterns), _SA_BLOCK
     rng = np.random.default_rng(7)
     mask = np.zeros((B, T, T), bool)
     for b, tiles in enumerate(patterns):
@@ -741,7 +735,19 @@ def _sa_case(case):
         padded = np.zeros((5 * blk, 5 * blk), bool)
         padded[:T, :T] = mask[b]
         union |= padded.reshape(5, blk, 5, blk).any(axis=(1, 3))
-    mask = jnp.asarray(mask, jnp.int8)
+    return jnp.asarray(mask, jnp.int8), union
+
+
+def _sa_case(case):
+    """The kernels (interpret mode) and the einsum reference under one mask:
+    forward, the three gradients and the indexer's loss with its own, as numpy
+    arrays (a device array left alive fails ``test_engine.py``'s look at
+    ``jax.live_arrays()`` in the same worker)."""
+    from deepspeed_tpu.ops.pallas import sa_probs
+    from deepspeed_tpu.ops.pallas.flash_attention import mask_tiles
+
+    mask, union = _sa_mask(case)
+    (B, T, _), H, D, blk = mask.shape, 2, 16, _SA_BLOCK
     np.testing.assert_array_equal(np.asarray(mask_tiles(mask, blk, blk)),
                                   union)
     q, k, v = _qkv(B, T, H, D, seed=11)
@@ -821,3 +827,193 @@ def test_mask_tiles_counts_any_nonzero_byte(value):
     want[1, 0] = want[2, 2] = True
     np.testing.assert_array_equal(
         np.asarray(mask_tiles(jnp.asarray(mask), 32, 32)), want)
+
+
+# -- the backward as ONE kernel: a head's dQ resident in VMEM -----------------
+
+V5E = "TPU v5 lite"
+
+#: the cells' shapes cut small -- name: tq, tk, d, dv, bq, bk, causal, window,
+#: first query row that sees a key, a mask that is data (an ``_sa_mask`` case)
+_FUSED = {
+    "causal": (128, 128, 32, 32, 32, 32, True, None, 0, None),
+    # mellum2's three-tile walk: 1,024 over 512-row tiles
+    "window_two_tiles": (160, 160, 32, 32, 32, 32, True, 64, 0, None),
+    # kv tiles 0 and 1 are seen by no query: placeholders by kv row
+    "window_unseen_kv_rows": (32, 128, 16, 16, 16, 32, True, 16, 0, None),
+    "tk_over_tq": (64, 160, 32, 32, 32, 32, True, None, 0, None),
+    # q tiles 0 and 1 see no key: no entry touches their dQ rows
+    "tk_under_tq": (96, 32, 32, 32, 32, 32, True, None, 64, None),
+    "ragged_tails": (100, 150, 32, 32, 32, 64, True, None, 0, None),
+    "not_causal_ragged": (70, 90, 16, 16, 32, 32, False, None, 0, None),
+    "latent_widths": (128, 128, 192, 128, 64, 64, True, None, 0, None),
+    "narrow_keys_window": (128, 128, 64, 128, 32, 32, True, 32, 0, None),
+    "mask_empty_diagonal_tile": (160, 160, 16, 16, 32, 32, True, None, 0,
+                                 "empty_diagonal_tile"),
+    "mask_kv_row_unselected": (160, 160, 16, 16, 32, 32, True, None, 0,
+                               "kv_row_no_query_selects"),
+    "mask_batch_ragged": (150, 150, 16, 16, 32, 32, True, None, 0,
+                          "ragged_length"),
+}
+
+
+def _fused_case(name):
+    """dq, dk, dv of one shape three ways -- the one kernel (the rule answers
+    for a v5e), the two kernels (it answers for this CPU), the einsum
+    reference -- and the backward kernels each traced gradient names."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+
+    tq, tk, d, dv, bq, bk, causal, window, r, sa = _FUSED[name]
+    mask = None if sa is None else _sa_mask(sa)[0]
+    B = 1 if mask is None else mask.shape[0]
+    ks = jax.random.split(jax.random.PRNGKey(21), 4)
+    q = jax.random.normal(ks[0], (B, tq, 2, d))
+    k = jax.random.normal(ks[1], (B, tk, 2, d))
+    v = jax.random.normal(ks[2], (B, tk, 2, dv))
+    w = jax.random.normal(ks[3], (B, tq, 2, dv))
+    scale = d ** -0.5
+    first = lambda out: out if mask is None else out[0]
+
+    def kernels(q, k, v):
+        return first(flash_attention(
+            q, k, v, causal=causal, sm_scale=scale, block_q=bq, block_k=bk,
+            interpret=True, force_pallas=True, window=window, mask=mask))
+
+    def reference(q, k, v):
+        return first(_reference_attention(q, k, v, causal, scale,
+                                          window=window, mask=mask))
+
+    def grads(fn):
+        grad = jax.grad(lambda *a: jnp.sum((fn(*a) * w)[:, r:]),
+                        argnums=(0, 1, 2))
+        called = [e.params["name"] for e in
+                  jax.make_jaxpr(grad)(q, k, v).jaxpr.eqns
+                  if e.primitive.name == "pallas_call"]
+        return dict(zip(("dq", "dk", "dv"), map(np.asarray, grad(q, k, v)))), \
+            [n for n in called if "bwd" in n]
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(gm, "device_kind", lambda: V5E)
+        fused, fused_calls = grads(kernels)
+        if mask is not None:    # the walk is shorter than the rule's table
+            static = fa._tile_table(tq, tk, bq, bk, causal, window,
+                                    by_kv=True, dense_mask=True)
+            count = fa._mask_tile_table(fa.mask_tiles(mask, bq, bk), static,
+                                        by_kv=True)[1]
+            assert int(count) < static.shape[1]
+    two, two_calls = grads(kernels)
+    assert fused_calls == ["ds_flash_bwd"]
+    assert two_calls == ["ds_flash_bwd_dq", "ds_flash_bwd_dkv"]
+    return fused, two, grads(reference)[0]
+
+
+@pytest.fixture(scope="module")
+def fused_case():
+    done = {}
+    yield lambda name: done.get(name) or done.setdefault(name,
+                                                          _fused_case(name))
+    done.clear()
+
+
+@pytest.mark.parametrize("what", ["dq", "dk", "dv"])
+@pytest.mark.parametrize("case", sorted(_FUSED))
+def test_fused_backward_is_the_two_kernels_and_the_reference(fused_case, case,
+                                                             what):
+    """``ds_flash_bwd`` (interpret mode) gives the two kernels' gradients
+    BIT FOR BIT -- a tile's ``s``, ``p``, ``dp``, ``ds`` are the same
+    values, dK/dV accumulate as they did, and a query row's kv tiles still
+    arrive ascending, so dQ's float32 sum has today's order -- and the
+    einsum reference's to its tolerance; rows of dQ no kept tile touches
+    are zeros."""
+    fused, two, want = fused_case(case)
+    np.testing.assert_array_equal(fused[what], two[what])
+    np.testing.assert_allclose(fused[what], want[what], atol=5e-4, rtol=5e-4)
+    r = _FUSED[case][8]
+    if what == "dq" and r:
+        assert not fused["dq"][:, :r].any()
+
+
+#: (Tq padded, D) of a flash call in each of the benchmark's cells
+#: (``mixtral-8x7b.train.ep4`` runs XLA attention; its shape, were it asked)
+_CELL_DQ = {
+    "mistral-7b.train.8k": (8192, 128), "olmoe-1b-7b.train.4k": (4096, 128),
+    "kimi-vl-a3b.train.8k": (8192, 192), "zaya1-8b.train.8k": (8192, 128),
+    "keye-vl2-30b-a3b.train.16k": (16384, 128),
+    "phi4-mini-flash.train.8k": (8192, 64),
+    "mellum2-12b-a2.5b.train.8k": (8192, 128),
+    "mixtral-8x7b.train.ep4": (4096, 128),
+    "the longest that fits": (32768, 128),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_DQ))
+def test_every_cells_dq_is_resident_on_a_v5e(cell):
+    """The float32 buffer and the bf16 output block twice, lanes padded to
+    whole registers (phi4's 64-wide rows take 128, kimi's 192 take 256): 4
+    to 16 MiB in the cells, within the quarter of the v5e's 128 the rule
+    allows; 32,768 x 128 fills it."""
+    from deepspeed_tpu.ops.pallas.flash_attention import fused_backward
+
+    tq, d = _CELL_DQ[cell]
+    assert fused_backward(tq, d, 2, V5E) == \
+        tq * -(-d // 128) * 128 * (4 + 2 * 2)
+    assert fused_backward(tq, d, 2, V5E) <= 32 << 20
+
+
+@pytest.mark.parametrize("tq,d,itemsize,kind", [
+    (131072, 128, 2, V5E),      # 64 MB of float32 alone
+    (65536, 128, 2, V5E),       # twice the share
+    (32768, 128, 4, V5E),       # float32 operands: 48 MiB
+    (8192, 128, 2, "TPU v4"),   # a chip nobody timed: not in the table
+    (8192, 128, 2, "cpu"),      # interpret mode, the CPU
+    (8192, 128, 2, "NVIDIA H100"),
+], ids=["128k", "64k", "32k_float32", "tpu_v4", "cpu", "gpu"])
+def test_a_dq_that_does_not_fit_or_an_unknown_chip_keeps_two_kernels(
+        tq, d, itemsize, kind):
+    from deepspeed_tpu.ops.pallas.flash_attention import fused_backward
+
+    assert fused_backward(tq, d, itemsize, kind) is None
+
+
+@pytest.mark.parametrize("cell", ["kimi-vl-a3b.train.8k",
+                                  "keye-vl2-30b-a3b.train.16k",
+                                  "phi4-mini-flash.train.8k"])
+def test_fused_call_asks_for_the_vmem_it_holds(monkeypatch, cell):
+    """The traced ``ds_flash_bwd`` call at a cell's real shape: its resident
+    dQ (scratch + the output block twice) is what the rule counted, and
+    ``vmem_limit_bytes`` covers every block twice, the scratch and the
+    tile's four float32 products -- with half as much again, inside the
+    cap."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "device_kind", lambda: V5E)
+    tq, d = _CELL_DQ[cell]
+    masked = cell.startswith("keye")
+    x = jax.ShapeDtypeStruct((1, tq, 2, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, tq, 2, 128), jnp.bfloat16)
+    args = (x, x, v) + masked * (jax.ShapeDtypeStruct((1, tq, tq), jnp.int8),)
+
+    def loss(q, k, v, mask=None):
+        out = flash_attention(q, k, v, causal=True, interpret=False,
+                              mask=mask)
+        return (out if mask is None else out[0]).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*args)
+    call, = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"
+             and e.params["name"] == "ds_flash_bwd"]
+    lanes = lambda shape: shape[:-1] + (-(-shape[-1] // 128) * 128,)
+    size = lambda shape, dtype: int(np.prod(lanes(tuple(
+        shape)))) * np.dtype(dtype).itemsize
+    mapping = call.params["grid_mapping"]
+    blocks = [size(m.transformed_block_aval.shape, m.array_aval.dtype)
+              for m in mapping.block_mappings]
+    scratch = [size(v.aval.shape, v.aval.dtype) for v in
+               call.params["jaxpr"].invars[-mapping.num_scratch_operands:]]
+    resident = fa.fused_backward(tq, d, 2, V5E)
+    assert scratch[-1] + 2 * blocks[-1] == resident
+    limit = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    held = 2 * sum(blocks) + sum(scratch) + 4 * 512 * 512 * 4
+    assert held <= fa._fused_vmem(resident, 512, 512, d, 128, 2, masked)
+    assert held * 3 // 2 <= limit <= gm._VMEM_CAP

@@ -262,7 +262,15 @@ def test_mixed_step_names_its_scopes(mixed_text, scope):
 H, HKV, D, BF16 = 8, 2, 128, jnp.bfloat16
 
 
-def _flash(bwd):
+def _on_v5e(patch):
+    """``flash_attention.fused_backward`` asks the device kind, which is the
+    CPU's here: answer for the chip, where a head's dQ stays in VMEM."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul
+
+    patch.setattr(grouped_matmul, "device_kind", lambda: "TPU v5 lite")
+
+
+def _flash(bwd, fused=False):
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
     q = ((1, 512, H, D), BF16)
@@ -271,7 +279,15 @@ def _flash(bwd):
     if not bwd:
         return fwd, [q, q, q]
     loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
-    return jax.grad(loss, argnums=(0, 1, 2)), [q, q, q]
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    if not fused:   # the two kernels: a dQ too long for VMEM, an unknown chip
+        return grad, [q, q, q]
+
+    def traced_for_v5e(*args):
+        with pytest.MonkeyPatch.context() as patch:
+            _on_v5e(patch)
+            return grad(*args)
+    return traced_for_v5e, [q, q, q]
 
 
 def _ragged():
@@ -402,6 +418,7 @@ def _selection():
 
 KERNELS = {
     names.FLASH_FWD: ("ds_flash_fwd", lambda: _flash(False)),
+    names.FLASH_BWD: ("ds_flash_bwd", lambda: _flash(True, fused=True)),
     names.FLASH_BWD_DQ: ("ds_flash_bwd_dq", lambda: _flash(True)),
     names.FLASH_BWD_DKV: ("ds_flash_bwd_dkv", lambda: _flash(True)),
     names.RAGGED_PAGED_ATTENTION: ("ds_ragged_paged_attention", _ragged),
@@ -513,6 +530,42 @@ def test_indexer_kernels_stand_under_their_scope(keye_step_text):
     replayed = re.findall(
         r"rematted_computation/\S*ds\.sa_index/(ds_sa_index_\w+)/", text)
     assert replayed == ["ds_sa_index_fwd"]
+
+
+@pytest.mark.parametrize("on_v5e,backward", [
+    (True, ["ds_flash_bwd"]),
+    (False, ["ds_flash_bwd_dkv", "ds_flash_bwd_dq"])],
+    ids=["one_kernel", "two_kernels"])
+def test_flash_backward_stands_under_its_scope(on_v5e, backward):
+    """A remat'd step with ``attention_impl="flash"``, lowered for the TPU.
+    Where the rule answers for a v5e a flash call's backward is ONE kernel,
+    ``ds_flash_bwd``, where it answers for a chip it does not know the two
+    it was: under ``ds.attention``, beside one ``ds_flash_fwd``, and none
+    of them in the replay."""
+    import deepspeed_tpu.ops.pallas.flash_attention as fa
+
+    with pytest.MonkeyPatch.context() as patch:
+        if on_v5e:
+            _on_v5e(patch)
+        # the model asks jax.default_backend(), which is the CPU here
+        patch.setattr(fa, "flash_attention", functools.partial(
+            fa.flash_attention, interpret=False))
+        model = LlamaForCausalLM(LlamaConfig.tiny(
+            remat=True, attention_impl="flash", hidden_size=256,
+            num_attention_heads=2, num_key_value_heads=1,
+            max_position_embeddings=512))
+        ids = jnp.zeros((1, 256), jnp.int32)
+        params = jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
+        loss = lambda p, ids: model.apply({"params": p}, ids, labels=ids)
+        text = jax.jit(jax.grad(loss)).trace(params, ids).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    called = re.findall(r'kernel_name = "(ds_flash_\w+)"', text)
+    assert sorted(called) == backward + ["ds_flash_fwd"]
+    scoped = re.findall(r'"(\S*)ds\.attention/(ds_flash_\w+)/pallas_call',
+                        text)
+    assert sorted(k for _, k in scoped) == sorted(called)
+    assert not any("rematted_computation" in path for path, _ in scoped)
 
 
 def test_loss_kernels_stand_once_a_layer_and_in_no_replay(keye_step_text):
