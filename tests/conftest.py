@@ -52,3 +52,25 @@ def mesh8():
 
 def pytest_report_header(config):
     return f"jax {jax.__version__} | devices: {jax.device_count()} ({jax.devices()[0].platform})"
+
+
+#: Standing tests that assert a state of ``BENCHMARK.json`` which a later
+#: cell ended: mellum2's entries LAST, ``train.full_layer_share`` listing
+#: mellum2 alone. PR 52 appended a cell; their file lies under the
+#: benchmark's ``paths``, which only a ``benchmark`` PR may edit (ROADMAP M1:
+#: look-ups by name). Expected failures until then, STRICT: the repair makes
+#: them pass, which fails the run until the entry here goes with it.
+OVERTAKEN_BY_A_LATER_CELL = {
+    "tests/benchmark/test_benchmark_mellum2.py::"
+    "test_the_benchmark_gained_one_configuration_one_cell_and_five_metrics",
+    "tests/benchmark/test_benchmark_mellum2.py::"
+    "test_cost_readers_know_their_own_cells",
+}
+
+
+def pytest_collection_modifyitems(items):
+    for item in items:
+        if item.nodeid in OVERTAKEN_BY_A_LATER_CELL:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, reason="asserts mellum2's entries are the "
+                "benchmark's last: ROADMAP M1"))

@@ -27,6 +27,8 @@ from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3Config,
                                               DeepseekV3ForCausalLM)
 from deepspeed_tpu.models.mellum import MellumConfig, MellumForCausalLM
 from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
+from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                             Qwen3NextForCausalLM)
 from deepspeed_tpu.models.sambay import SambaYConfig, SambaYForCausalLM
 from deepspeed_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
 from deepspeed_tpu.monitor import tracing
@@ -65,6 +67,15 @@ TRAIN_SCOPES = {
                "ds.rope_tables", "ds.layer_window", "ds.layer_full",
                "ds.attn_proj", "ds.attention", "ds.moe_router",
                "ds.moe_experts", "ds.lm_head_loss"],
+    # two mixers a period over Mixtral's expert layer: each block under its
+    # kind's outer scope; ds.gdn_rule is the chunked delta rule alone,
+    # ds.gdn_mix the rest of a delta-rule mixer, ds.attn_gate the full
+    # layer's output gate, ds.moe_shared the gated shared expert
+    "qwen3_next": ["ds.loss_and_grad", "ds.optimizer", "ds.embed",
+                   "ds.layer_gdn", "ds.layer_full", "ds.attn_proj",
+                   "ds.gdn_mix", "ds.gdn_rule", "ds.attention",
+                   "ds.attn_gate", "ds.moe_router", "ds.moe_experts",
+                   "ds.moe_shared", "ds.lm_head_loss"],
 }
 #: what every family names besides: the engine's cast of the master weights,
 #: the loop over the layers, the block's two pre-norms and residual sums
@@ -89,7 +100,9 @@ def train_text():
                                q_chunk_size=16, kv_chunk_size=16,
                                topk=8)))),
             ("sambay", SambaYForCausalLM(SambaYConfig.tiny(remat=True))),
-            ("mellum", MellumForCausalLM(MellumConfig.tiny(remat=True)))):
+            ("mellum", MellumForCausalLM(MellumConfig.tiny(remat=True))),
+            ("qwen3_next", Qwen3NextForCausalLM(Qwen3NextConfig.tiny(
+                remat=True)))):
         batch = {"input_ids": np.zeros((8, 32), np.int32),
                  "labels": np.zeros((8, 32), np.int32)}
         engine, *_ = ds.initialize(
@@ -139,8 +152,10 @@ def trace_names():
 #: cache held before them, and the six older cells' steps keep their module
 #: name, their lowered text and their cache entries; PR 49 added
 #: ``ds.layer_window``, ``ds.layer_full`` and ``ds.rope_tables`` the same way:
-#: they stand only in ``models/mellum.py``'s step)
-NAMES_PIN = (3, "4115774094a4356a")
+#: they stand only in ``models/mellum.py``'s step; PR 52 added
+#: ``ds.layer_gdn``, ``ds.gdn_mix``, ``ds.gdn_rule`` and ``ds.attn_gate``,
+#: which stand only in ``models/qwen3_next.py``'s step)
+NAMES_PIN = (3, "ea6d11f7d29360d3")
 
 
 def test_names_version_is_raised_with_the_names():
@@ -152,7 +167,8 @@ def test_names_version_is_raised_with_the_names():
     assert {"ds.param_cast", "ds.layer_stack", "ds.norm", "ds.residual",
             "ds.sa_index", "ds.sa_select", "ds.sa_loss", "ds.ssm_scan",
             "ds.ssm_mix", "ds.gmu", "ds.da_mix", "ds.layer_window",
-            "ds.layer_full", "ds.rope_tables"} <= set(scopes) \
+            "ds.layer_full", "ds.rope_tables", "ds.layer_gdn", "ds.gdn_mix",
+            "ds.gdn_rule", "ds.attn_gate"} <= set(scopes) \
         and "counters" in spans
     digest = hashlib.sha256("\n".join(scopes + spans).encode()).hexdigest()
     assert (tracing.NAMES_VERSION, digest[:16]) == NAMES_PIN
@@ -622,17 +638,39 @@ def test_no_step_without_the_flash_indexer_holds_its_kernels(train_text):
         assert "ds_sa_probs" not in text, family
 
 
+def test_no_other_familys_step_holds_the_delta_rules_names(train_text):
+    """``ds.layer_gdn``, ``ds.gdn_mix``, ``ds.gdn_rule`` and ``ds.attn_gate``
+    stand in ``models/qwen3_next.py``'s step alone: the other families'
+    programs are what they were, and ``NAMES_VERSION`` stays."""
+    for family, text in train_text.items():
+        found = set(re.findall(r"ds\.(?:layer_gdn|gdn_[a-z]+|attn_gate)\b",
+                               text))
+        assert found == ({"ds.layer_gdn", "ds.gdn_mix", "ds.gdn_rule",
+                          "ds.attn_gate"} if family == "qwen3_next"
+                         else set()), family
+
+
 @pytest.mark.parametrize("gas", [1, 2])
 def test_named_scalars_of_any_model_become_gauges(gas):
     """The contract Mixtral's load report rides: a training call that
     returns ``(loss, {name: scalar})`` gets registry gauges of those names,
-    the mean over micro-batches; what is no scalar is dropped."""
+    the mean over micro-batches; what is no scalar is dropped.
+
+    The head is three wide, a shape of this test's own: with ``Dense(1)``
+    its init programs were, byte for byte, those of
+    ``test_step_counters.py``'s model, so the two files -- on two workers
+    under ``--dist loadfile`` -- read and wrote the SAME entries of the
+    persistent compile cache (``tests/conftest.py`` caches every compile;
+    jax's ``put`` is a plain ``write_bytes``, so a reader can meet a
+    half-written entry). That shared state is the one thing this case had
+    in common with a neighbour when it failed in the driver's run of PR 51's
+    tree and passed in the builder's."""
     import flax.linen as nn
 
     class Named(nn.Module):
         @nn.compact
         def __call__(self, x):
-            loss = jnp.mean(nn.Dense(1)(x) ** 2)
+            loss = jnp.mean(nn.Dense(3)(x) ** 2)
             return loss, {"probe_first_feature": jnp.mean(x[:, 0]),
                           "not_a_scalar": x[0]}
 
