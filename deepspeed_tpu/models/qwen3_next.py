@@ -30,8 +30,15 @@ dt_bias)`` a value head in float32; ``q <- q / |q| / sqrt(dk)``, ``k <- k /
     S <- exp(g_t) S;  d_t = beta_t (v_t - S^T k_t);  S <- S + k_t d_t^T;
     o_t = S^T q_t
 
-(``gated_delta_rule``, in chunks). Then ``o_t <- w * rms_norm(o_t) * SiLU(z_t)``
-a head (plain scale seeded at 1, the norm before the gate) and ``W_out``.
+(``gated_delta_rule``, in chunks, under ``ds.gdn_rule``: on one TPU device at
+heads of whole lanes XLA builds each chunk's running decays, its
+strictly-lower table and that table's inverse, and the two kernels of
+``ops/pallas/gdn_rule.py`` do everything else with the chunk's other tables
+and the head's state in VMEM; elsewhere -- a CPU, a mesh of several devices,
+the tiny sizes -- XLA alone, ``_rule_xla``: a triangular solve and a
+rematerialised scan over chunk boundaries). Then ``o_t <- w * rms_norm(o_t) *
+SiLU(z_t)`` a head (plain scale seeded at 1, the norm before the gate) and
+``W_out``.
 
 *MoE*: ``mixtral.MixtralSparseMoeBlock`` as it is -- softmax router over
 ``router_experts``, top-k renormalised, the HELD experts
@@ -54,6 +61,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas import gdn_rule, grouped_matmul
+from ..parallel.topology import get_mesh
 from .layers import (apply_rotary_partial, causal_conv, cross_entropy_loss,
                      dot_product_attention, head_scope, model_dense, repeat_kv,
                      resolve_remat_policy, rotary_embedding, shift_labels)
@@ -139,6 +148,17 @@ def _unit_lower_solve(a, rhs):
         a, rhs, left_side=True, lower=True, unit_diagonal=True)
 
 
+def _rule_tiling(dk, dv, chunk, dtype):
+    """``gdn_rule.plan`` of what this call site can see: the kernels' tiling,
+    or None where the rule stays in XLA (off a TPU, under a mesh of several
+    devices, widths the kernels leave)."""
+    mesh = get_mesh()
+    return gdn_rule.plan(
+        grouped_matmul.backend(), 1 if mesh is None else mesh.devices.size,
+        dk, dv, chunk, jnp.dtype(dtype).itemsize,
+        grouped_matmul.device_kind())
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk=64):
     """The recurrence of the module's docstring over ``T`` positions, in
     chunks of ``chunk``: ``q, k [B, T, H, dk]`` (normalised, ``q`` scaled),
@@ -153,11 +173,43 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64):
     gamma_j), j <= i) D`` and ``S' = exp(gamma_C) S + (exp(gamma_C - gamma)
     K)^T D``. Every exponent is a difference that is ``<= 0``: nothing
     divides by a decay (``exp(-gamma)`` overflows from 88 nats, and a chunk
-    may hold 1,300). What does not need ``S`` is computed for all chunks at
-    once; a ``lax.scan`` over the chunks carries ``S`` in float32, its body
-    rematerialised, so the backward pass keeps the boundary states only.
-    A ragged tail is padded with ``k = v = q = 0``, ``beta = 0``, ``g = 0``:
-    no update, no decay, no output."""
+    may hold 1,300). A ragged tail is padded with ``k = v = q = 0``, ``beta
+    = 0``, ``g = 0``: no update, no decay, no output.
+
+    Where ``_rule_tiling`` has a tiling (one TPU device, heads of whole
+    lanes) XLA builds ``gamma``, ``A`` and the inverse ``(I + A)^-1`` and
+    the two kernels of ``ops/pallas/gdn_rule.py`` do the rest a chunk at a
+    time in VMEM, the state beside it; elsewhere ``_rule_xla``."""
+    B, T, H, dk = q.shape
+    dv, C, f32 = v.shape[-1], chunk, jnp.float32
+    tiling = _rule_tiling(dk, dv, C, q.dtype)
+    if tiling is None:
+        return _rule_xla(q, k, v, g, beta, C)
+    pad = (-T) % C
+    n = (T + pad) // C
+    seq = lambda x: jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+    fold = lambda x: seq(x.astype(f32)).reshape(B, n, C, H).transpose(
+        0, 3, 1, 2)                                      # [B, H, n, C]
+    q, k, v, beta = seq(q), seq(k), seq(v), fold(beta)
+    gamma = jnp.cumsum(fold(g), axis=-1)
+    i = jnp.arange(C)
+    below = jnp.exp(jnp.where(i[:, None] > i[None, :],
+                              gamma[..., :, None] - gamma[..., None, :],
+                              -jnp.inf))
+    kc = k.reshape(B, n, C, H, dk)
+    a = beta[..., None] * below * jnp.einsum(
+        "bnihd,bnjhd->bhnij", kc, kc, preferred_element_type=f32)
+    inverse = _unit_lower_solve(a, jnp.broadcast_to(jnp.eye(C, dtype=f32),
+                                                    a.shape))
+    o = gdn_rule.chunk_rule(q, k, v, gamma, beta, inverse, tiling)
+    return o[:, :T], jnp.max(-gamma[..., -1])
+
+
+def _rule_xla(q, k, v, g, beta, chunk):
+    """``gated_delta_rule`` in XLA alone: what does not need ``S`` is
+    computed for all chunks at once (the solve's two right-hand sides among
+    it); a ``lax.scan`` over the chunks carries ``S`` in float32, its body
+    rematerialised, so the backward pass keeps the boundary states only."""
     B, T, H, dk = q.shape
     dv, C, f32 = v.shape[-1], chunk, jnp.float32
     pad = (-T) % C

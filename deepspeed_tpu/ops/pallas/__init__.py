@@ -51,3 +51,7 @@ SSM_SCAN_BWD = "ds_ssm_scan_bwd"
 # (``grouped_matmul.py``): rows x a group's weight, and the weights' gradient
 MOE_GMM = "ds_moe_gmm"
 MOE_GMM_T = "ds_moe_gmm_t"
+# the chunked gated delta rule of a linear-attention layer, a chunk's tables
+# and a head's matrix state in VMEM, and its backward (``gdn_rule.py``)
+GDN_RULE_FWD = "ds_gdn_rule_fwd"
+GDN_RULE_BWD = "ds_gdn_rule_bwd"

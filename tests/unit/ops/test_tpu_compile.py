@@ -144,6 +144,25 @@ def _ssm_scan(bwd, T=8192, C=5120, N=16):
         args
 
 
+def _gdn_rule(bwd, T=8192, heads=32, d=128, chunk=64):
+    """qwen3-next-80b-a3b.train.8k: one delta-rule layer's chunked rule, 32
+    value heads of 128 x 128 over 128 chunks of 64, bf16 q, k, v beside the
+    float32 running decays, ``beta`` and chunk inverses XLA hands over; the
+    backward rebuilds a chunk in VMEM from its saved boundary state."""
+    from deepspeed_tpu.ops.pallas import gdn_rule
+
+    seq = ((1, T, heads, d), BF16)
+    row = ((1, heads, T // chunk, chunk), jnp.float32)
+    args = [seq, seq, seq, row, row, (row[0] + (chunk,), jnp.float32)]
+    fwd = functools.partial(gdn_rule.chunk_rule,
+                            tiling=gdn_rule.plan("tpu", 1, d, d, chunk),
+                            interpret=False)
+    if not bwd:
+        return fwd, args
+    return jax.grad(lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)),
+                    argnums=tuple(range(6))), args
+
+
 def _flash_key_mask():
     """Serving prefill (``layers.flash_prefill_from_empty``): forward only,
     kv heads un-repeated, a [B, Tk] key-padding mask."""
@@ -226,6 +245,8 @@ CASES = {
     "sa_index_bwd_train16k": lambda: _sa_index(True),
     "ssm_scan_train8k": lambda: _ssm_scan(False),
     "ssm_scan_bwd_train8k": lambda: _ssm_scan(True),
+    "gdn_rule_train8k": lambda: _gdn_rule(False),
+    "gdn_rule_bwd_train8k": lambda: _gdn_rule(True),
     "ragged_bf16": lambda: _ragged(False, None),
     "ragged_bf16_window": lambda: _ragged(False, 4096),
     "ragged_int8": lambda: _ragged(True, None),
@@ -287,6 +308,42 @@ def test_kernel_compiles_for_v5e(chip, name):
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         "the compiled program holds no Mosaic kernel"
+
+
+def test_delta_rule_layer_is_two_kernels_and_no_loop_on_one_v5e(chip, on_v5e):
+    """``models/qwen3_next.py``'s delta-rule mixer at the published widths
+    (hidden 2,048, 16 key and 32 value heads of 128, chunks of 64) over
+    qwen3-next 8k's 8,192 tokens, its gradient compiled for one v5e with the
+    chooser answered for it: the rule is ONE ``ds_gdn_rule_fwd`` and ONE
+    ``ds_gdn_rule_bwd``, and neither the lowered nor the compiled program
+    holds a loop -- no scan over the 128 chunk boundaries is left; the
+    chunks' tables and their inverse stay XLA's."""
+    from benchmark import common
+    from deepspeed_tpu.models import qwen3_next as qn
+
+    config = common.load_json("configs", "qwen3-next-80b-a3b.json")
+    cfg, _ = common.build_model(config, common.sizes_of(config, "train"))
+    assert (cfg.linear_num_key_heads, cfg.linear_num_value_heads,
+            cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+            cfg.gdn_chunk) == (16, 32, 128, 128, 64)
+    mixer = qn.GatedDeltaNet(cfg)
+    x = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), BF16, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, BF16, sharding=chip),
+        jax.eval_shape(lambda: mixer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 64, cfg.hidden_size),
+                                             BF16)))["params"])
+    loss = lambda p, x: jnp.sum(
+        mixer.apply({"params": p}, x)[0].astype(jnp.float32))
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x)
+    text = lowered.as_text()
+    assert sorted(re.findall(r'kernel_name = "(ds_\w+)"', text)) == [
+        "ds_gdn_rule_bwd", "ds_gdn_rule_fwd"]
+    assert "stablehlo.while" not in text
+    hlo = lowered.compile().as_text()
+    assert sorted(re.findall(r"%(ds_gdn_rule_\w+?)[.\d]* = ", hlo)) == [
+        "ds_gdn_rule_bwd", "ds_gdn_rule_fwd"]
+    assert not re.search(r" while\(", hlo)
 
 
 # -- the expert layer on one chip, at OLMoE's shapes -------------------------

@@ -425,6 +425,19 @@ def _moe_gmm(outer):
         [rows, ((4, 256, 256), BF16), groups]
 
 
+def _gdn_rule(bwd):
+    from deepspeed_tpu.ops.pallas import gdn_rule
+
+    seq, row = ((1, 256, 2, D), BF16), ((1, 2, 4, 64), jnp.float32)
+    args = [seq, seq, seq, row, row, ((1, 2, 4, 64, 64), jnp.float32)]
+    fwd = functools.partial(gdn_rule.chunk_rule,
+                            tiling=gdn_rule.Tiling(2, 2), interpret=False)
+    if not bwd:
+        return fwd, args
+    return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                    argnums=tuple(range(6))), args
+
+
 def _selection():
     from deepspeed_tpu.models.indexed_attention import select_mask
 
@@ -461,6 +474,8 @@ KERNELS = {
     names.SSM_SCAN_BWD: ("ds_ssm_scan_bwd", lambda: _ssm_scan(True)),
     names.MOE_GMM: ("ds_moe_gmm", lambda: _moe_gmm(False)),
     names.MOE_GMM_T: ("ds_moe_gmm_t", lambda: _moe_gmm(True)),
+    names.GDN_RULE_FWD: ("ds_gdn_rule_fwd", lambda: _gdn_rule(False)),
+    names.GDN_RULE_BWD: ("ds_gdn_rule_bwd", lambda: _gdn_rule(True)),
 }
 
 
@@ -626,6 +641,44 @@ def test_scan_kernels_stand_alone_under_their_scope(monkeypatch):
     replayed = re.findall(
         r"rematted_computation/\S*ds\.ssm_scan/(ds_\w+)/", text)
     assert replayed == 2 * ["ds_ssm_scan_fwd"]
+
+
+def test_delta_rule_kernels_stand_alone_under_their_scope(monkeypatch):
+    """One period of ``models/qwen3_next.py`` at heads of 128 x 128 with the
+    chooser answered for one v5e, its remat'ed gradient lowered for the TPU:
+    the two kernels are called under ``ds.gdn_rule`` and nowhere else -- the
+    forward twice a delta-rule layer (the forward pass and the block's
+    replay: 6 calls a step at qwen3-next 8k's one period), the backward once
+    (3): ``train.gdn_rule_share`` reads the rule,
+    XLA's tables and inverse with it, by that scope. No loop over chunk
+    boundaries is left (the layers are unrolled here: no scan of periods
+    either)."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul
+
+    # the chooser asks the backend and the device kind, the CPU's here
+    monkeypatch.setattr(grouped_matmul, "backend", lambda: "tpu")
+    _on_v5e(monkeypatch)
+    model = Qwen3NextForCausalLM(Qwen3NextConfig.tiny(
+        remat=True, scan_layers=False, num_hidden_layers=4,
+        linear_key_head_dim=128, linear_value_head_dim=128, gdn_chunk=64))
+    ids = jnp.zeros((1, 256), jnp.int32)
+    # the step's bf16 compute copy: the kernels take two-byte operands
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, BF16), jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), ids))["params"])
+    loss = lambda p, ids: model.apply({"params": p}, ids, labels=ids)
+    text = jax.jit(jax.grad(loss)).trace(params, ids).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    sites = re.findall(r'"(\S*)ds\.gdn_rule/jit\(_rule_(fwd|bwd)\)"', text)
+    assert sorted(k for _, k in sites) == 3 * ["bwd"] + 6 * ["fwd"]
+    assert len(re.findall(r"call @_rule_(?:fwd|bwd)", text)) == len(sites)
+    assert sorted(k for path, k in sites
+                  if "rematted_computation" in path) == 3 * ["fwd"]
+    # the entries are jitted: the module holds each kernel once (the forward
+    # once more for the replay, whose jaxpr is its own), whatever the layers
+    lowered = re.findall(r'kernel_name = "(ds_\w+)"', text)
+    assert sorted(lowered) == ["ds_gdn_rule_bwd"] + 2 * ["ds_gdn_rule_fwd"]
+    assert "stablehlo.while" not in text
 
 
 def test_no_step_without_the_flash_indexer_holds_its_kernels(train_text):
