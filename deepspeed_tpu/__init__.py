@@ -6,11 +6,22 @@ Public API parity with ``deepspeed/__init__.py``: ``initialize`` (:51),
 namespaces (``comm``, ``zero``, ``moe``, ``ops``...).
 """
 
-from .version import __version__  # noqa: F401
+import time as _time
 
-from . import comm  # noqa: F401
-from . import parallel  # noqa: F401
-from .utils.logging import log_dist, logger  # noqa: F401
+T_IMPORT = _time.perf_counter()
+
+from .version import __version__  # noqa: E402,F401
+
+from . import comm  # noqa: E402,F401
+from . import parallel  # noqa: E402,F401
+from .utils.logging import log_dist, logger  # noqa: E402,F401
+
+#: seconds of the imports on the way to a training engine: this package's
+#: root import (jax's own where nothing imported it before; it began at
+#: ``T_IMPORT`` on the ``perf_counter`` clock) and, on the first
+#: ``initialize``, the lazy ``runtime.engine`` import — the engine's set-up
+#: record reports their sum as ``import_s``
+IMPORT_SECONDS = {"package": _time.perf_counter() - T_IMPORT}
 
 
 def initialize(*args, **kwargs):
@@ -19,8 +30,10 @@ def initialize(*args, **kwargs):
     Reference: ``deepspeed/__init__.py:51`` — returns
     ``(engine, optimizer, dataloader, lr_scheduler)``.
     """
+    t0 = _time.perf_counter()
     from .runtime.engine import initialize as _initialize
 
+    IMPORT_SECONDS.setdefault("engine", _time.perf_counter() - t0)
     return _initialize(*args, **kwargs)
 
 

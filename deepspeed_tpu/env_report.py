@@ -231,13 +231,15 @@ def perf_report() -> None:
     if not rows:
         print("compiled programs: none resident in this process")
         return
+    from deepspeed_tpu.monitor.export import LEDGER_HEADER, ledger_columns
+
     print(f"{'program':<34}{'fingerprint':<13}{'compiles':>9}"
-          f"{'recompiles':>11}{'calls':>7}  flops/call")
+          f"{'recompiles':>11}{'calls':>7}{LEDGER_HEADER}  flops/call")
     for r in rows:
         flops = "n/a" if r["flops"] is None else f"{r['flops']:.3e}"
         print(f"{r['name']:<34}{str(r['fingerprint']):<13}"
               f"{r['compiles']:>9}{r['recompiles']:>11}{r['calls']:>7}"
-              f"  {flops} ({r['cost_source'] or '-'})")
+              f"{ledger_columns(r)}  {flops} ({r['cost_source'] or '-'})")
 
 
 def speculation_report() -> None:

@@ -442,6 +442,20 @@ def serving_metrics_text(srv) -> str:
     return render_prometheus(registry=srv.metrics.registry, scalars=scalars)
 
 
+#: the compile ledger's columns of a program row (monitor/perf.py
+#: CompileLedger.program): seconds traced, lowered and in the backend, and
+#: whether the persistent cache served every compile — a cold start reads
+#: "miss" and a backend_s of minutes, a warm one "hit" and a cache read
+LEDGER_HEADER = f"{'trace_s':>9}{'lower_s':>9}{'backend_s':>11}{'cache':>7}"
+
+
+def ledger_columns(row) -> str:
+    cells = ["-" if row.get(k) is None else f"{row[k]:.2f}"
+             for k in ("trace_s", "lower_s", "backend_s")]
+    cells.append({True: "hit", False: "miss", None: "-"}[row.get("cache_hit")])
+    return "".join(f"{c:>{w}}" for c, w in zip(cells, (9, 9, 11, 7)))
+
+
 def serving_statusz(srv) -> str:
     """The human-readable /statusz page of a serving engine: resident
     compiled-program table, recompile counts, HBM watermarks, and the
@@ -458,11 +472,11 @@ def serving_statusz(srv) -> str:
         lines.append("hbm: no allocator stats on this backend")
     lines.append("")
     lines.append(f"{'program':<28}{'fingerprint':<13}{'compiles':>9}"
-                 f"{'recompiles':>11}{'calls':>7}")
+                 f"{'recompiles':>11}{'calls':>7}{LEDGER_HEADER}")
     for row in perf.get("programs", []):
         lines.append(f"{row['name']:<28}{str(row['fingerprint']):<13}"
                      f"{row['compiles']:>9}{row['recompiles']:>11}"
-                     f"{row['calls']:>7}")
+                     f"{row['calls']:>7}{ledger_columns(row)}")
     lines.append("")
     lines.append(f"compile_counts: {json.dumps(perf.get('compile_counts'))}")
     lines.append("")

@@ -1,5 +1,6 @@
 """Performance accounting: compiled-program registry, recompile sentinel,
-cost-model FLOPs/bytes, MFU/MBU, HBM watermarks, and the artifact meta stamp.
+cost-model FLOPs/bytes, MFU/MBU, HBM watermarks, and the compile ledger with
+the engine's set-up record.
 
 PR 5 made *events* observable (spans, flight dumps, metrics registry); this
 layer makes *performance claims* measurable and defensible:
@@ -22,9 +23,13 @@ layer makes *performance claims* measurable and defensible:
   compute-bound) and **MBU + tokens/sec/chip** (decode: bandwidth-bound).
 - **Device memory watermarks** — ``device.memory_stats()`` live/peak HBM
   bytes, graceful no-op on backends (CPU) that expose none.
-- **Artifact meta stamp** — :func:`perf_meta`: git sha, jax/jaxlib
-  versions, device kind/count, host: what a stored number needs beside
-  it to be compared with another (nothing calls it today: ROADMAP D1e).
+- **Compile ledger + set-up record** — one set of ``jax.monitoring``
+  listeners a process sums what jax itself times of every compile (trace,
+  lowering, backend compile or persistent-cache read, cache hits and
+  misses) per function and per open set-up span; a registry row says
+  whether its program came from the cache and what it cost, and
+  :class:`SetupRecord` is the flat record an engine publishes as
+  ``ds.setup``. A listener runs only where something compiles.
 
 A ``ProgramRegistry`` is meant for hot paths: one dict-equality check
 per dispatch (the fingerprints are small flat dicts of strings) and
@@ -33,10 +38,9 @@ is not measured: no cell drives the serving path yet (ROADMAP W1), and
 a CPU run gives no time.
 """
 
+import contextlib
 import hashlib
-import os
-import socket
-import subprocess
+import re
 import threading
 import time
 import weakref
@@ -206,6 +210,8 @@ class ProgramRegistry:
         #: uncontended acquire per dispatch — noise against the
         #: fingerprint compare the dispatch already pays)
         self.programs: Dict[str, CompiledProgram] = {}  # dslint: guarded-by=_lock
+        #: what each program's compiles cost (listening from here on)
+        self.ledger = compile_ledger()
         with _live_lock:
             _live_registries.add(self)
 
@@ -282,7 +288,7 @@ class ProgramRegistry:
             items = list(self.programs.items())
         rows = []
         for name, prog in sorted(items):
-            row = prog.row()
+            row = {**prog.row(), **self.ledger.program(name)}
             if self.scope:
                 row["name"] = f"{self.scope}/{name}"
             rows.append(row)
@@ -428,47 +434,230 @@ def hbm_watermarks() -> Tuple[Optional[int], Optional[int]]:
 
 
 # ---------------------------------------------------------------------------
-# artifact meta stamp
+# compile ledger: what jax says each compile cost, and the set-up record
 # ---------------------------------------------------------------------------
 
-def git_sha(repo_root: Optional[str] = None) -> Optional[str]:
-    root = repo_root or os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    try:
-        out = subprocess.run(["git", "rev-parse", "--short", "HEAD"],
-                             cwd=root, capture_output=True, text=True,
-                             timeout=10)
-        sha = out.stdout.strip()
-        return sha or None
-    except Exception:
-        return None
+#: ``jax.monitoring`` duration events -> the ledger's column of seconds.
+#: jax fires them only where something traces, lowers, compiles or reads
+#: the persistent cache: never in a warm step.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_DURATION_EVENTS = {
+    _TRACE_EVENT: "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    # compile_or_get_cached as a whole: a cache read is inside it
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_s",
+}
+#: counting events -> the ledger's column of counts (a miss is counted
+#: where jax WRITES the entry: a compile under
+#: ``jax_persistent_cache_min_compile_time_secs`` is neither)
+_COUNT_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+#: the columns a set-up record carries, inside the program's spans and
+#: ``outside`` them; ``programs`` counts backend compiles-or-reads
+LEDGER_COLUMNS = ("trace_s", "lower_s", "backend_s", "cache_read_s",
+                  "cache_hits", "cache_misses", "programs")
+_CACHE_COLUMNS = ("cache_read_s", "cache_hits", "cache_misses")
+_FUN_WRAPPED = re.compile(r"(?:jit|pmap)\((.*)\)")
+_FUN_AFFIXES = re.compile(r"^(ds_)?|(_n\d+)?$")
 
 
-def perf_meta() -> Dict[str, Any]:
-    """A provenance block for a stored measurement: enough to refuse a
-    comparison of numbers from two installations or two devices, and
-    to answer "what exactly produced this number?" months later."""
-    import jax
-    import jaxlib
+def _fun_key(fun_name: str) -> str:
+    """``jit(ds_train_step_n3)`` (what jax calls the lowered and compiled
+    module), ``ds_train_step_n3`` (the traced function) and the registry's
+    ``train_step`` are one program: the key drops jax's wrapper, the
+    ``ds_`` of a named step and ``tracing.versioned``'s suffix; a bucketed
+    program's ``[width]`` goes too, so the buckets of one function share
+    its row."""
+    wrapped = _FUN_WRAPPED.fullmatch(fun_name)
+    name = wrapped.group(1) if wrapped else fun_name
+    return _FUN_AFFIXES.sub("", re.sub(r"\[.*\]$", "", name))
 
-    meta: Dict[str, Any] = {
-        "schema": 1,
-        "git_sha": git_sha(),
-        "jax": jax.__version__,
-        "jaxlib": jaxlib.__version__,
-        "host": socket.gethostname(),
-        "wall_time": time.strftime("%Y-%m-%dT%H:%M:%S"),
-    }
-    try:
-        devs = jax.devices()
-        meta["platform"] = devs[0].platform
-        meta["device_kind"] = devs[0].device_kind
-        meta["device_count"] = len(devs)
-    except Exception as e:
-        meta["platform"] = f"unavailable ({type(e).__name__})"
-        meta["device_kind"] = None
-        meta["device_count"] = 0
-    return meta
+
+class CompileLedger:
+    """Sums of what ``jax.monitoring`` reports about compiles, per function
+    and per open set-up span. One a process (:func:`compile_ledger`): jax's
+    listeners are process-wide and cannot be taken back one by one.
+
+    An event is charged to the innermost set-up span open on its thread
+    (:meth:`charging` names it in a thread-local) or, with none
+    open, to ``outside`` — a benchmark's reference program compiles in the
+    engine's process and must not read as the engine's. The cache's events
+    carry no function name; they fire inside ``backend_compile_duration``,
+    which does, so they wait in the thread-local until it closes. Traces
+    nest (a jitted step traces every jitted function it calls, and each
+    reports its own, inclusive, duration): a function's row keeps its
+    inclusive time, a span's sum takes the outermost traces alone — jax
+    reports a trace's start as a scalar, which is how the depth is known."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._tls = threading.local()
+        #: listener invocations and the seconds spent inside them: what
+        #: the instrumentation costs, and a test's proof that a warm call
+        #: fires none
+        self.calls = 0  # dslint: guarded-by=_lock
+        self.listener_s = 0.0  # dslint: guarded-by=_lock
+        self.by_fun: Dict[str, Dict[str, float]] = {}  # dslint: guarded-by=_lock
+        self.outside: Dict[str, float] = {}  # dslint: guarded-by=_lock
+
+    def listen(self) -> "CompileLedger":
+        import jax.monitoring as monitoring
+
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+        monitoring.register_scalar_listener(self._on_scalar)
+        return self
+
+    @contextlib.contextmanager
+    def charging(self, sums: Dict[str, Dict[str, float]], span: str):
+        """While open, this thread's compiles go to ``sums[span]``; the
+        span that was charged before comes back after."""
+        tls = self._tls
+        before = getattr(tls, "charge", None)
+        tls.charge = (sums, span)
+        try:
+            yield
+        finally:
+            tls.charge = before
+
+    def _on_scalar(self, event: str, value: float, **kw) -> None:
+        # a trace starts: one deeper (the other starts are not needed)
+        tls = self._tls
+        if event == _TRACE_EVENT:
+            tls.depth = getattr(tls, "depth", 0) + 1
+        self._charge(None, 0, None)
+
+    def _on_duration(self, event: str, duration: float, **kw) -> None:
+        self._charge(_DURATION_EVENTS.get(event), duration,
+                     kw.get("fun_name"))
+
+    def _on_event(self, event: str, **kw) -> None:
+        self._charge(_COUNT_EVENTS.get(event), 1, None)
+
+    def _charge(self, column: Optional[str], value: float,
+                fun_name: Optional[str]) -> None:
+        t0 = time.perf_counter()
+        tls = self._tls
+        with self._lock:
+            self.calls += 1
+            nested = False
+            if column == "trace_s":
+                # a trace that began before the ledger listened has no
+                # start on record: never below the outermost
+                tls.depth = max(getattr(tls, "depth", 0) - 1, 0)
+                nested = tls.depth > 0
+            if column is not None:
+                sums, span = getattr(tls, "charge", None) or (None, None)
+                row = self.outside if sums is None \
+                    else sums.setdefault(span, {})
+                if not nested:
+                    row[column] = row.get(column, 0) + value
+                pending = tls.__dict__.setdefault("pending", {})
+                if fun_name is None:
+                    pending[column] = pending.get(column, 0) + value
+                else:
+                    fun = self.by_fun.setdefault(_fun_key(fun_name), {})
+                    fun[column] = fun.get(column, 0) + value
+                    if column == "backend_s":
+                        row["programs"] = row.get("programs", 0) + 1
+                        fun["programs"] = fun.get("programs", 0) + 1
+                        for k in _CACHE_COLUMNS:
+                            if k in pending:
+                                fun[k] = fun.get(k, 0) + pending.pop(k)
+            self.listener_s += time.perf_counter() - t0
+
+    def program(self, name: str) -> Dict[str, Any]:
+        """What the ledger knows of the registry's program ``name``:
+        seconds traced, lowered and in the backend (compile or cache
+        read), and whether every one of its compiles came from the
+        persistent cache (None: it never asked the cache, or never
+        compiled since the ledger listens)."""
+        with self._lock:
+            fun = dict(self.by_fun.get(_fun_key(name), {}))
+        asked = fun.get("cache_hits", 0) + fun.get("cache_misses", 0)
+        return {"trace_s": fun.get("trace_s"), "lower_s": fun.get("lower_s"),
+                "backend_s": fun.get("backend_s"),
+                "cache_hit": fun.get("cache_misses", 0) == 0
+                if asked else None}
+
+    def snapshot(self) -> Dict[str, Any]:
+        with self._lock:
+            return {"calls": self.calls, "listener_s": self.listener_s,
+                    "outside": dict(self.outside),
+                    "by_fun": {k: dict(v) for k, v in self.by_fun.items()}}
+
+
+_ledger_lock = threading.Lock()
+_ledger: Optional[CompileLedger] = None  # dslint: guarded-by=_ledger_lock
+
+
+def compile_ledger() -> CompileLedger:
+    """The process's ledger; the first call registers its listeners."""
+    global _ledger
+    with _ledger_lock:
+        if _ledger is None:
+            _ledger = CompileLedger().listen()
+        return _ledger
+
+
+class SetupRecord:
+    """One engine's set-up as flat numbers: the seconds of each named part
+    and the ledger's sums under each of its set-up spans. Kept whether or
+    not the tracer's ring is enabled: a span reads the clock twice anyway
+    (``tracing._Span.seconds``). Set-up ends where the first step returns
+    (:meth:`close`): from there on the record stands — a recompile the
+    sentinel flags, or something the caller compiles hundreds of steps
+    later, is not set-up."""
+
+    PARTS = ("import", "pre_init", "init", "init_shapes", "init_params",
+             "init_opt_state", "init_step", "first_step", "first_dispatch",
+             "first_wait", "cost_capture")
+
+    def __init__(self):
+        self.ledger = compile_ledger()
+        self.seconds: Dict[str, float] = {}
+        self.sums: Dict[str, Dict[str, float]] = {}
+        self._closed: Optional[Dict[str, float]] = None
+
+    @contextlib.contextmanager
+    def span(self, span, part: Optional[str] = None):
+        """Run ``span`` (an unopened ``Tracer.span``) as a set-up span:
+        compiles on this thread are charged to its name while it is the
+        innermost, and its seconds become the record's ``part`` (its own
+        name unless given). After :meth:`close` it is the span alone."""
+        if self._closed is not None:
+            with span:
+                yield span
+            return
+        with span, self.ledger.charging(self.sums, span.name):
+            yield span
+        self.seconds[part or span.name] = span.seconds
+
+    @property
+    def first_step_done(self) -> bool:
+        return "first_step" in self.seconds
+
+    def close(self) -> None:
+        self._closed = self._numbers()
+
+    def _numbers(self) -> Dict[str, float]:
+        out = {f"{k}_s": self.seconds.get(k, 0.0) for k in self.PARTS}
+        outside = self.ledger.snapshot()["outside"]
+        for k in LEDGER_COLUMNS:
+            out[k] = sum(row.get(k, 0) for row in self.sums.values())
+        for k in LEDGER_COLUMNS:
+            out[f"outside_{k}"] = outside.get(k, 0)
+        return out
+
+    def record(self, steps_before: int) -> Dict[str, float]:
+        """The flat record (docs/observability.md has each key): parts the
+        engine never ran read 0; ``outside_*`` is the process's sum where
+        set-up ended."""
+        numbers = self._numbers() if self._closed is None else self._closed
+        return {**numbers, "steps_before": steps_before}
 
 
 # ---------------------------------------------------------------------------

@@ -124,7 +124,7 @@ class _Span:
     closes."""
 
     __slots__ = ("_tracer", "_name", "_ring", "_cat", "_args", "_step",
-                 "_t0", "_annotation")
+                 "_t0", "_t1", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Optional[Dict[str, Any]], ring: Optional[str],
@@ -157,11 +157,22 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
+        t1 = self._t1 = time.perf_counter()
         self._annotation.__exit__(*exc)
         self._tracer.complete(self._ring, self._t0, t1, cat=self._cat,
                               args=self._args)
         return False
+
+    @property
+    def name(self) -> str:
+        return self._name
+
+    @property
+    def seconds(self) -> float:
+        """What the closed span took, from the two clock readings it makes
+        anyway — there whether or not the ring is enabled (the engine's
+        set-up record, ``monitor/perf.py SetupRecord``)."""
+        return self._t1 - self._t0
 
 
 class Tracer:

@@ -33,6 +33,7 @@ import numpy as np
 from flax import struct
 from jax.sharding import NamedSharding, PartitionSpec
 
+from .. import IMPORT_SECONDS, T_IMPORT
 from .. import comm as dist
 from ..monitor.tracing import (ENV_TRACE_DIR, FlightRecorder, Tracer,
                                profiler_recording, versioned)
@@ -229,6 +230,84 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         self.micro_batch_size = self._config.train_micro_batch_size_per_gpu
         self.gradient_accumulation_steps = self._config.gradient_accumulation_steps
 
+        # ---- tracing / flight recorder / metrics registry --------------
+        # span timelines for the step loop + post-mortem dumps on DS_FAULT
+        # firings and checkpoint-verify failures; armed by the config
+        # block or the DS_TRACE_DIR env var (monitor/tracing.py). The
+        # registry's log-bucket step-latency histogram flows to every
+        # monitor backend through MonitorMaster.write_registry. The tracer
+        # stands first (it needs only the config): everything the
+        # constructor does after it runs under the ``init`` span.
+        from ..monitor.perf import PerfAccounting, SetupRecord
+        from ..monitor.registry import MetricsRegistry
+
+        tcfg = self._config.tracing
+        trace_dir = tcfg.dir or os.environ.get(ENV_TRACE_DIR)
+        self.tracer = Tracer(capacity=tcfg.capacity,
+                             enabled=bool(tcfg.enabled or trace_dir))
+        self.registry = MetricsRegistry()
+        self._step_hist = self.registry.histogram("train_batch_s",
+                                                  lo=1e-4, hi=4e3)
+        #: performance accounting (monitor/perf.py): the compiled train
+        #: step registers an argument fingerprint (recompile sentinel —
+        #: curriculum/data shape drift shows up as a NAMED alarm, not a
+        #: mystery stall) and captures cost-model FLOPs once, yielding the
+        #: train_mfu / train_tflops_per_chip gauges in the registry.
+        self.perf = PerfAccounting(
+            tracer=self.tracer, metrics=self.registry, scope="train",
+            n_devices=int(np.prod(self.mesh.devices.shape)))
+        #: state fingerprint computed once: the TrainState's shapes are
+        #: fixed by construction (replace() preserves them) while its
+        #: object identity changes every step — re-walking a large param
+        #: tree per step would tax the hot loop for a spec that cannot
+        #: change. Batch + rng stay fingerprinted per call.
+        self._state_spec: Optional[str] = None
+        if self.tracer.enabled and tcfg.comm:
+            # per-collective observability (comm/comm.py): every
+            # all_reduce/all_gather/... staged by the train step emits a
+            # comm:<op> span + a comm_op_s{op,dtype,bytes_bucket}
+            # histogram — the per-op comm mix trace_view --summary and
+            # ds_report aggregate (process-global; last armed engine wins)
+            from ..comm.comm import configure_comm_tracing
+
+            configure_comm_tracing(tracer=self.tracer,
+                                   registry=self.registry)
+        self.flight = None
+        if trace_dir:
+            self.flight = FlightRecorder(
+                trace_dir, self.tracer, last_n=tcfg.flight_events,
+                metrics_fn=lambda: {"global_steps": self.global_steps,
+                                    **self.registry.snapshot()})
+            self.flight.arm_faults()
+
+        #: set-up as numbers (monitor/perf.py SetupRecord): each set-up
+        #: span's seconds and the compiles jax reports under it, published
+        #: as ``ds.setup`` and the ``setup_*`` gauges (``_publish_setup``)
+        self.setup = SetupRecord()
+        # the imports on the way here, and from the package's import to
+        # here less them: the caller's own work before the engine and,
+        # where it starts the backend there (jax has no event for that),
+        # backend start
+        imports = sum(IMPORT_SECONDS.values())
+        self.setup.seconds.update({
+            "import": imports,
+            "pre_init": time.perf_counter() - T_IMPORT - imports})
+        #: whether a profiler session that recorded the last step has the
+        #: record already
+        self._setup_published = False
+        with self.setup.span(self.tracer.span("init", cat="setup")):
+            self._construct(model, model_parameters, example_batch,
+                            partition_rules, rng)
+
+    def _construct(self, model, model_parameters, example_batch,
+                   partition_rules, rng):
+        """The rest of the constructor, under the ``init`` span; its
+        set-up spans are the children ``init_shapes``, ``init_params``,
+        ``init_opt_state`` and ``init_step``. None of them fences: a span
+        that ends on a dispatch is host time, and the device's time lands
+        in the first wait after it."""
+        mesh, setup, tr = self.mesh, self.setup, self.tracer
+
         # ---- precision --------------------------------------------------
         self.compute_dtype = {"bf16": jnp.bfloat16, "fp16": jnp.float16,
                               "fp32": jnp.float32}[self._config.precision]
@@ -238,24 +317,25 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         # ---- rng / params ----------------------------------------------
         self._rng = rng if rng is not None else jax.random.PRNGKey(self._config.seed)
         self.example_batch = example_batch
-        params = model_parameters
-        init_fn = init_rngs = None
-        if params is None and model is not None and example_batch is not None:
-            # Sharded-at-birth init (the real ``zero.Init``): derive shardings
-            # from abstract shapes first, then materialize under jit with
-            # ``out_shardings`` so no leaf is ever fully resident on one
-            # device (reference: ``partition_parameters.py:537`` exists to
-            # avoid exactly that replicated birth).
-            init_fn, init_args = self._make_init_fn(example_batch)
-            params_shapes = jax.eval_shape(init_fn, *init_args)
-        elif params is not None:
-            params = jax.tree_util.tree_map(
-                lambda p: jnp.asarray(p, jnp.float32)
-                if jnp.issubdtype(jnp.asarray(p).dtype, jnp.floating)
-                else jnp.asarray(p), params)
-            params_shapes = jax.eval_shape(lambda: params)
-        else:
-            raise ValueError("Provide model_parameters, or model + example_batch to init")
+        with setup.span(tr.span("init_shapes", cat="setup")):
+            params = model_parameters
+            init_fn = init_rngs = None
+            if params is None and model is not None and example_batch is not None:
+                # Sharded-at-birth init (the real ``zero.Init``): derive shardings
+                # from abstract shapes first, then materialize under jit with
+                # ``out_shardings`` so no leaf is ever fully resident on one
+                # device (reference: ``partition_parameters.py:537`` exists to
+                # avoid exactly that replicated birth).
+                init_fn, init_args = self._make_init_fn(example_batch)
+                params_shapes = jax.eval_shape(init_fn, *init_args)
+            elif params is not None:
+                params = jax.tree_util.tree_map(
+                    lambda p: jnp.asarray(p, jnp.float32)
+                    if jnp.issubdtype(jnp.asarray(p).dtype, jnp.floating)
+                    else jnp.asarray(p), params)
+                params_shapes = jax.eval_shape(lambda: params)
+            else:
+                raise ValueError("Provide model_parameters, or model + example_batch to init")
 
         # ---- optimizer --------------------------------------------------
         self.lr_scheduler = self._build_lr_scheduler()
@@ -304,42 +384,46 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         #: (init under jit with out_shardings) rather than placed post-hoc.
         self.params_born_sharded = params is None
         if params is None:
-            params = jax.jit(init_fn, out_shardings=self.param_shardings)(*init_args)
-        if self._offload or self._onebit_wire or self._overlap_lane:
-            self.opt_shardings = ()
-        else:
-            opt_shapes = jax.eval_shape(self.optimizer.init, params_shapes)
-            self.opt_shardings = shard_opt(opt_shapes)
-        self._replicated = NamedSharding(mesh, PartitionSpec())
+            # trace, compile or cache read, enqueue: the device's time is
+            # in the first wait after it
+            with setup.span(tr.span("init_params", cat="setup")):
+                params = jax.jit(init_fn, out_shardings=self.param_shardings)(*init_args)
+        with setup.span(tr.span("init_opt_state", cat="setup")):
+            if self._offload or self._onebit_wire or self._overlap_lane:
+                self.opt_shardings = ()
+            else:
+                opt_shapes = jax.eval_shape(self.optimizer.init, params_shapes)
+                self.opt_shardings = shard_opt(opt_shapes)
+            self._replicated = NamedSharding(mesh, PartitionSpec())
 
-        # ---- build + place state ---------------------------------------
-        if self._offload:
-            # host owns fp32 master + moments; device holds bf16 weights only
-            from .zero.offload import HostOffloadOptimizer
+            # ---- build + place state ---------------------------------------
+            if self._offload:
+                # host owns fp32 master + moments; device holds bf16 weights only
+                from .zero.offload import HostOffloadOptimizer
 
-            opt_cfg = self._config.optimizer
-            self._host_opt = HostOffloadOptimizer(
-                params,
-                opt_cfg.type if opt_cfg else "AdamW",
-                opt_cfg.params if opt_cfg else {},
-                self._config.zero_config.offload_optimizer,
-                gradient_clipping=self._config.gradient_clipping,
-                lr_scheduler=self.lr_scheduler)
-            params = jax.tree_util.tree_map(
-                lambda p, s: jax.device_put(
-                    p.astype(self.compute_dtype)
-                    if jnp.issubdtype(p.dtype, jnp.floating) else p, s),
-                params, self.param_shardings)
-            opt_state = ()
-        elif self._onebit_wire or self._overlap_lane:
-            self._host_opt = None
-            params = jax.tree_util.tree_map(jax.device_put, params, self.param_shardings)
-            opt_state = ()  # built by the lane builder below (needs params)
-        else:
-            self._host_opt = None
-            params = jax.tree_util.tree_map(jax.device_put, params, self.param_shardings)
-            opt_state = jax.jit(self.optimizer.init,
-                                out_shardings=self.opt_shardings)(params)
+                opt_cfg = self._config.optimizer
+                self._host_opt = HostOffloadOptimizer(
+                    params,
+                    opt_cfg.type if opt_cfg else "AdamW",
+                    opt_cfg.params if opt_cfg else {},
+                    self._config.zero_config.offload_optimizer,
+                    gradient_clipping=self._config.gradient_clipping,
+                    lr_scheduler=self.lr_scheduler)
+                params = jax.tree_util.tree_map(
+                    lambda p, s: jax.device_put(
+                        p.astype(self.compute_dtype)
+                        if jnp.issubdtype(p.dtype, jnp.floating) else p, s),
+                    params, self.param_shardings)
+                opt_state = ()
+            elif self._onebit_wire or self._overlap_lane:
+                self._host_opt = None
+                params = jax.tree_util.tree_map(jax.device_put, params, self.param_shardings)
+                opt_state = ()  # built by the lane builder below (needs params)
+            else:
+                self._host_opt = None
+                params = jax.tree_util.tree_map(jax.device_put, params, self.param_shardings)
+                opt_state = jax.jit(self.optimizer.init,
+                                    out_shardings=self.opt_shardings)(params)
         # the scalar leaves are placed on the mesh like every other leaf:
         # the step's outputs carry the mesh in their type, and an initial
         # state that does not would give step 0 a cache key (and a compile)
@@ -431,73 +515,74 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                                             PartitionSpec(None, self._batch_axes))
         self._batch_seq_sharding = NamedSharding(
             mesh, PartitionSpec(None, self._batch_axes, SEQ_AXIS))
-        # (every jitted step's module name carries the trace names' version:
-        # ``tracing.versioned``)
-        if self._offload:
-            self._train_step = None
-            self._grad_step = self._compile_grad_step()
-        elif self._onebit_wire:
-            from .onebit_engine import build_onebit_wire
+        with setup.span(tr.span("init_step", cat="setup")):
+            # (every jitted step's module name carries the trace names' version:
+            # ``tracing.versioned``)
+            if self._offload:
+                self._train_step = None
+                self._grad_step = self._compile_grad_step()
+            elif self._onebit_wire:
+                from .onebit_engine import build_onebit_wire
 
-            if self._moq is not None or self._pld is not None or \
-                    self._compression is not None:
-                raise ValueError(
-                    "compressed 1-bit training does not compose with "
-                    "quantize_training (MoQ), progressive_layer_drop, or "
-                    "compression_training; disable those blocks or use the "
-                    "optax 1-bit optimizers (no comm_backend_name)")
+                if self._moq is not None or self._pld is not None or \
+                        self._compression is not None:
+                    raise ValueError(
+                        "compressed 1-bit training does not compose with "
+                        "quantize_training (MoQ), progressive_layer_drop, or "
+                        "compression_training; disable those blocks or use the "
+                        "optax 1-bit optimizers (no comm_backend_name)")
 
-            opt_state, ob_shardings, step_fn = build_onebit_wire(
-                self, dict(opt_cfg.params or {}), kind=opt_cfg.type.lower())
-            self.opt_shardings = ob_shardings
-            self.state = self.state.replace(opt_state=jax.device_put(
-                opt_state, ob_shardings))
-            self.state_shardings = self.state_shardings.replace(
-                opt_state=ob_shardings)
-            self._train_step_fn = step_fn
-            self._train_step = jax.jit(
-                versioned(step_fn),
-                in_shardings=(self.state_shardings, None, self._replicated),
-                out_shardings=(self.state_shardings, self._replicated,
-                               self._replicated),
-                donate_argnums=(0,))
-        elif self._overlap_lane:
-            # bucketed per-layer grad reduce-scatter overlap + data-axis
-            # sharded optimizer step (runtime/zero/overlap.py)
-            from .zero.overlap import build_overlap_step
+                opt_state, ob_shardings, step_fn = build_onebit_wire(
+                    self, dict(opt_cfg.params or {}), kind=opt_cfg.type.lower())
+                self.opt_shardings = ob_shardings
+                self.state = self.state.replace(opt_state=jax.device_put(
+                    opt_state, ob_shardings))
+                self.state_shardings = self.state_shardings.replace(
+                    opt_state=ob_shardings)
+                self._train_step_fn = step_fn
+                self._train_step = jax.jit(
+                    versioned(step_fn),
+                    in_shardings=(self.state_shardings, None, self._replicated),
+                    out_shardings=(self.state_shardings, self._replicated,
+                                   self._replicated),
+                    donate_argnums=(0,))
+            elif self._overlap_lane:
+                # bucketed per-layer grad reduce-scatter overlap + data-axis
+                # sharded optimizer step (runtime/zero/overlap.py)
+                from .zero.overlap import build_overlap_step
 
-            opt_state, ov_shardings, step_fn = build_overlap_step(self)
-            self.opt_shardings = ov_shardings
-            self.state = self.state.replace(opt_state=jax.device_put(
-                opt_state, ov_shardings))
-            self.state_shardings = self.state_shardings.replace(
-                opt_state=ov_shardings)
-            self._train_step_fn = step_fn
-            self._train_step = jax.jit(
-                versioned(step_fn),
-                in_shardings=(self.state_shardings, None, self._replicated),
-                out_shardings=(self.state_shardings,
-                               (self._replicated,) * 3,
-                               self._replicated),
-                donate_argnums=(0,))
-        elif self._config.sparse_gradients_enabled:
-            # explicit sparse-gradient DP exchange (runtime/sparse_engine.py;
-            # reference sparse_allreduce path, engine.py:2286-2301)
-            from .sparse_engine import build_sparse_dp_step
+                opt_state, ov_shardings, step_fn = build_overlap_step(self)
+                self.opt_shardings = ov_shardings
+                self.state = self.state.replace(opt_state=jax.device_put(
+                    opt_state, ov_shardings))
+                self.state_shardings = self.state_shardings.replace(
+                    opt_state=ov_shardings)
+                self._train_step_fn = step_fn
+                self._train_step = jax.jit(
+                    versioned(step_fn),
+                    in_shardings=(self.state_shardings, None, self._replicated),
+                    out_shardings=(self.state_shardings,
+                                   (self._replicated,) * 3,
+                                   self._replicated),
+                    donate_argnums=(0,))
+            elif self._config.sparse_gradients_enabled:
+                # explicit sparse-gradient DP exchange (runtime/sparse_engine.py;
+                # reference sparse_allreduce path, engine.py:2286-2301)
+                from .sparse_engine import build_sparse_dp_step
 
-            self.sparse_tensor_module_names, step_fn = \
-                build_sparse_dp_step(self)
-            self._train_step_fn = step_fn
-            self._sparse_skip_mark = 0  # stall guard, see train_batch
-            self._train_step = jax.jit(
-                versioned(step_fn),
-                in_shardings=(self.state_shardings, None, self._replicated),
-                out_shardings=(self.state_shardings,
-                               (self._replicated,) * 3,
-                               self._replicated),
-                donate_argnums=(0,))
-        else:
-            self._train_step = self._compile_train_step()
+                self.sparse_tensor_module_names, step_fn = \
+                    build_sparse_dp_step(self)
+                self._train_step_fn = step_fn
+                self._sparse_skip_mark = 0  # stall guard, see train_batch
+                self._train_step = jax.jit(
+                    versioned(step_fn),
+                    in_shardings=(self.state_shardings, None, self._replicated),
+                    out_shardings=(self.state_shardings,
+                                   (self._replicated,) * 3,
+                                   self._replicated),
+                    donate_argnums=(0,))
+            else:
+                self._train_step = self._compile_train_step()
         self._eval_step = None
 
         # ---- timers / monitor ------------------------------------------
@@ -506,56 +591,6 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                                           steps_per_output=self._config.steps_per_print)
         self.monitor = self._build_monitor()
         self.wall_clock_breakdown = self._config.wall_clock_breakdown
-
-        # ---- tracing / flight recorder / metrics registry --------------
-        # span timelines for the step loop + post-mortem dumps on DS_FAULT
-        # firings and checkpoint-verify failures; armed by the config
-        # block or the DS_TRACE_DIR env var (monitor/tracing.py). The
-        # registry's log-bucket step-latency histogram flows to every
-        # monitor backend through MonitorMaster.write_registry.
-        from ..monitor.perf import PerfAccounting
-        from ..monitor.registry import MetricsRegistry
-
-        self.registry = MetricsRegistry()
-        self._step_hist = self.registry.histogram("train_batch_s",
-                                                  lo=1e-4, hi=4e3)
-        #: performance accounting (monitor/perf.py): the compiled train
-        #: step registers an argument fingerprint (recompile sentinel —
-        #: curriculum/data shape drift shows up as a NAMED alarm, not a
-        #: mystery stall) and captures cost-model FLOPs once, yielding the
-        #: train_mfu / train_tflops_per_chip gauges in the registry.
-        self.perf = PerfAccounting(
-            tracer=None,  # set below once the tracer exists
-            metrics=self.registry, scope="train",
-            n_devices=int(np.prod(self.mesh.devices.shape)))
-        #: state fingerprint computed once: the TrainState's shapes are
-        #: fixed by construction (replace() preserves them) while its
-        #: object identity changes every step — re-walking a large param
-        #: tree per step would tax the hot loop for a spec that cannot
-        #: change. Batch + rng stay fingerprinted per call.
-        self._state_spec: Optional[str] = None
-        tcfg = self._config.tracing
-        trace_dir = tcfg.dir or os.environ.get(ENV_TRACE_DIR)
-        self.tracer = Tracer(capacity=tcfg.capacity,
-                             enabled=bool(tcfg.enabled or trace_dir))
-        self.perf.programs.tracer = self.tracer
-        if self.tracer.enabled and tcfg.comm:
-            # per-collective observability (comm/comm.py): every
-            # all_reduce/all_gather/... staged by the train step emits a
-            # comm:<op> span + a comm_op_s{op,dtype,bytes_bucket}
-            # histogram — the per-op comm mix trace_view --summary and
-            # ds_report aggregate (process-global; last armed engine wins)
-            from ..comm.comm import configure_comm_tracing
-
-            configure_comm_tracing(tracer=self.tracer,
-                                   registry=self.registry)
-        self.flight = None
-        if trace_dir:
-            self.flight = FlightRecorder(
-                trace_dir, self.tracer, last_n=tcfg.flight_events,
-                metrics_fn=lambda: {"global_steps": self.global_steps,
-                                    **self.registry.snapshot()})
-            self.flight.arm_faults()
 
         # micro-step parity API state
         self._pending_microbatches = []
@@ -971,8 +1006,12 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             return self._train_batch(data_iter, batch, step)
 
     def _train_batch(self, data_iter, batch, step: int):
-        tr = self.tracer
+        tr, setup = self.tracer, self.setup
         t_batch0 = time.perf_counter()
+        # the first call carries the compile: its parts go into the set-up
+        # record; a profiler session's first call publishes the record
+        first = not setup.first_step_done
+        recording = profiler_recording()
         monitoring = self.monitor is not None and self.monitor.enabled
         printing = self._config.steps_per_print and \
             (step + 1) % self._config.steps_per_print == 0
@@ -1033,10 +1072,14 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         self.tput_timer.start()
 
         if self._offload:
-            with tr.span("dispatch", cat="train", ring="train_step",
-                         args={"step": step, "program": "grad_step",
-                               "offload": True}):
+            dispatch = tr.span("dispatch", cat="train", ring="train_step",
+                               args={"step": step, "program": "grad_step",
+                                     "offload": True})
+            with setup.span(dispatch, part="first_dispatch") \
+                    if first else dispatch:
                 loss = self._offload_train_batch(batch)
+            if first:
+                setup.seconds["first_step"] = time.perf_counter() - t_batch0
         else:
             with tr.span("shape_batch", cat="host", args={"step": step}):
                 batch = self._shape_batch(batch)
@@ -1065,9 +1108,10 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             # fence the device every step just to trace. A call known to
             # carry a compile (the first, or one the sentinel flagged)
             # sits inside a compile span, so an idle device is explained
-            compiling = tr.span(
+            compiling = setup.span(tr.span(
                 "compile", cat="host",
-                args={"step": step, "program": "train_step"}) \
+                args={"step": step, "program": "train_step"}),
+                part="first_dispatch") \
                 if (recompiled or not warm) else contextlib.nullcontext()
             with compiling, tr.span(
                     "dispatch", cat="train", ring="train_step",
@@ -1077,21 +1121,27 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             # the step's named scalars wait on the device for a later call
             # to publish them — only while something listens: the ring, a
             # monitor, a progress line, or a recording profiler
-            if not warm or reporting or tr.enabled or profiler_recording():
+            if not warm or reporting or tr.enabled or recording:
                 self._queue_counters(step, loss, named)
             if self._counter_queue:
                 # after the dispatch: the device has work again, so the
                 # fetch hides under it. The compile-carrying call has just
                 # waited seconds: one step's wait is the cheapest there is
+                # (the first step's execution, where the step names scalars
+                # to fetch: the set-up record's first_wait_s)
                 self._drain_counters(wait=not warm)
+            if first:
+                setup.seconds["first_step"] = time.perf_counter() - t_batch0
             if not warm:
                 # once, after the compile-carrying first call: the cached
                 # lowering yields the cost model without a second trace;
                 # the jaxpr-walk flops profiler is the fallback
-                self.perf.capture_cost(
-                    "train_step", self._train_step,
-                    (self.state, batch, step_rng),
-                    fallback=self._train_flops_estimate(batch, step_rng))
+                with setup.span(tr.span("cost_capture", cat="host",
+                                        args={"step": step})):
+                    self.perf.capture_cost(
+                        "train_step", self._train_step,
+                        (self.state, batch, step_rng),
+                        fallback=self._train_flops_estimate(batch, step_rng))
             if profiling:
                 float(loss)  # device fence so the measured latency is real
                 self._print_flops_profile(batch, step_rng,
@@ -1131,6 +1181,9 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                     self._write_monitor(loss)
                 if printing:
                     self._report_progress(loss)
+        if first or (recording and not self._setup_published):
+            self._publish_setup(step, first)
+        self._setup_published = recording
         self._last_loss = loss
         return loss
 
@@ -1325,6 +1378,8 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
                 sp.set(**scalars)
                 for name, value in scalars.items():
                     self.registry.gauge(name).set(value)
+            if i == 0 and not self.setup.first_step_done:
+                self.setup.seconds["first_wait"] = sp.seconds
         self._publish_step_rate(time.perf_counter(), done[-1][0])
 
     def _publish_step_rate(self, now: float, step: int):
@@ -1506,6 +1561,24 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         if hasattr(self, "_sparse_skip_mark"):
             self._sparse_skip_mark = self.get_skipped_steps()
         return load_dir, client_state
+
+    def _publish_setup(self, step: int, first: bool) -> None:
+        """Publish the set-up record (``monitor/perf.py SetupRecord``): the
+        ``setup_*`` registry gauges, and one ``setup`` span that carries
+        every number — ``ds.setup`` on the profiler's clock inside
+        ``ds.train_batch``, where a traced run and a ``/profilez`` capture
+        find it (the benchmark's ``setup.*`` readers read it there), and a
+        ring event. Once after the first step, which ends set-up (the record
+        stands from there on), and on the first step of every profiler
+        session. At the class's end: the frames of the step's call sites
+        keep their lines."""
+        if first:
+            self.setup.close()
+        record = self.setup.record(step)
+        for name, value in record.items():
+            self.registry.gauge(f"setup_{name}").set(value)
+        with self.tracer.span("setup", cat="setup", args=record):
+            pass
 
 
 class _LazyLoss:

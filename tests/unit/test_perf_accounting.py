@@ -1,7 +1,7 @@
 """Performance accounting (``monitor/perf.py``): fingerprints, the
 recompile sentinel, cost-model capture, MFU arithmetic, hand-rolled
-transformer estimates, device peaks, watermarks, and the artifact meta
-stamp.
+transformer estimates, device peaks and watermarks (the compile ledger and
+the set-up record: ``test_setup_record.py``).
 
 FLOPs pinning strategy: the 5% hand-computed bar runs against programs
 whose FLOPs are EXACTLY countable by hand (matmul chains — XLA's cost
@@ -208,16 +208,6 @@ def test_memory_watermarks_graceful_without_allocator_stats():
         acc = perf.PerfAccounting(scope="t")
         assert acc.memory_watermarks() == (None, None)
         assert acc._mem_capable is False  # probed once, then free
-
-
-def test_perf_meta_carries_provenance():
-    meta = perf.perf_meta()
-    for key in ("schema", "git_sha", "jax", "jaxlib", "host", "platform",
-                "device_kind", "device_count", "wall_time"):
-        assert key in meta, key
-    assert meta["jax"] == jax.__version__
-    assert meta["device_count"] >= 1
-    assert isinstance(meta["git_sha"], str) and meta["git_sha"]
 
 
 # ---------------------------------------------------------------------------
