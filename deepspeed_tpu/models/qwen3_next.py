@@ -38,7 +38,15 @@ and the head's state in VMEM; elsewhere -- a CPU, a mesh of several devices,
 the tiny sizes -- XLA alone, ``_rule_xla``: a triangular solve and a
 rematerialised scan over chunk boundaries). Then ``o_t <- w * rms_norm(o_t) *
 SiLU(z_t)`` a head (plain scale seeded at 1, the norm before the gate) and
-``W_out``.
+``W_out``. What stands AROUND the rule under ``ds.gdn_mix`` -- the
+convolution, its activation and the unit length of q and k ahead of it, the
+gated norm after it -- runs, where ``_mix_tiling`` has a tiling (one TPU
+device, heads of whole lanes, two-byte operands), as the four kernels of
+``ops/pallas/gdn_mix.py``: they read ``W_qkvz u``'s published columns in place
+and write q, k, v in the rows-of-time layout the rule's kernels take, so no
+array is split, regrouped, repeated or concatenated; elsewhere as XLA's
+fusions, ``_premix_xla`` and ``_gate_xla``. ``beta`` and ``g`` (``[B, T, Hv]``)
+stay XLA's on both paths.
 
 *MoE*: ``mixtral.MixtralSparseMoeBlock`` as it is -- softmax router over
 ``router_experts``, top-k renormalised, the HELD experts
@@ -61,7 +69,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.pallas import gdn_rule, grouped_matmul
+from ..ops.pallas import gdn_mix, gdn_rule, grouped_matmul
 from ..parallel.topology import get_mesh
 from .layers import (apply_rotary_partial, causal_conv, cross_entropy_loss,
                      dot_product_attention, head_scope, model_dense, repeat_kv,
@@ -279,39 +287,80 @@ class GatedDeltaNet(nn.Module):
                                "in_proj_qkvz")(x)
             ba = model_dense(cfg, 2 * Hv, "in_proj_ba")(x)
         with jax.named_scope("ds.gdn_mix"):
-            # the published layout: a key head's q, k, its r values, its r z
-            q, k, v, z = jnp.split(
-                qkvz.reshape(B, T, Hk, 2 * dk + 2 * r * dv),
-                (dk, 2 * dk, 2 * dk + r * dv), axis=-1)
             b, a = jnp.split(ba.reshape(B, T, Hk, 2 * r), 2, axis=-1)
             taps = self.param("conv1d", nn.initializers.lecun_normal(
                 in_axis=0, out_axis=1, batch_axis=()),
                 (cfg.linear_conv_kernel_dim, 2 * Hk * dk + Hv * dv), f32)
-            mixed = jnp.concatenate([t.reshape(B, T, -1) for t in (q, k, v)],
-                                    axis=-1)
-            mixed = _conv_act(causal_conv(mixed, taps.astype(x.dtype)))
-            q, k, v = jnp.split(mixed, (Hk * dk, 2 * Hk * dk), axis=-1)
+            heads = gdn_mix.Heads(Hk, dk, r, dv, cfg.linear_conv_kernel_dim)
+            tiling = _mix_tiling(heads, x.dtype)
+            # _conv_act, _unit_length: looked up here, at trace time
+            if tiling is None:
+                q, k, v, z = _premix_xla(qkvz, taps, heads, _conv_act,
+                                         _unit_length)
+            else:
+                q, k, v, z = gdn_mix.premix(qkvz, taps, heads, _conv_act,
+                                            _unit_length, tiling)
             a_log = self.param("A_log", _a_log_init, (Hv,), f32)
             dt_bias = self.param("dt_bias", nn.initializers.ones, (Hv,), f32)
             beta = _beta(b.reshape(B, T, Hv).astype(f32))
             g = _log_decay(a_log, a.reshape(B, T, Hv).astype(f32), dt_bias)
-            q, k = (_unit_length(t.reshape(B, T, Hk, dk)) for t in (q, k))
-            q = repeat_kv((q * dk ** -0.5).astype(x.dtype), r)
-            k = repeat_kv(k.astype(x.dtype), r)
         with jax.named_scope("ds.gdn_rule"):
-            o, decay = gated_delta_rule(q, k, v.reshape(B, T, Hv, dv), g,
-                                        beta, cfg.gdn_chunk)
+            o, decay = gated_delta_rule(
+                q.reshape(B, T, Hv, dk), k.reshape(B, T, Hv, dk),
+                v.reshape(B, T, Hv, dv), g, beta, cfg.gdn_chunk)
         with jax.named_scope("ds.gdn_mix"):
-            o32 = o.astype(f32)
             scale = self.param("norm_scale", nn.initializers.ones, (dv,), f32)
-            o32 = o32 * jax.lax.rsqrt(jnp.mean(o32 * o32, -1, keepdims=True)
-                                      + cfg.rms_norm_eps) * scale
-            o = (o32 * nn.silu(z.reshape(B, T, Hv, dv).astype(f32))).astype(
-                x.dtype)
+            o = o.reshape(B, T, Hv * dv)
+            if tiling is None:
+                o = _gate_xla(o, z, scale, cfg.rms_norm_eps, dv)
+            else:       # z: premix's handle; the kernel reads qkvz in place
+                o = gdn_mix.gate(o, z, qkvz, scale, cfg.rms_norm_eps, heads,
+                                 tiling)
         with jax.named_scope("ds.attn_proj"):
             out = model_dense(cfg, cfg.hidden_size, "out_proj",
-                              row_parallel=True)(o.reshape(B, T, Hv * dv))
+                              row_parallel=True)(o)
         return out, jax.lax.stop_gradient(decay)
+
+
+def _mix_tiling(heads, dtype):
+    """``gdn_mix.plan`` of what this call site can see: the tiling of the
+    kernels around the rule, or None where that work stays in XLA (off a
+    TPU, under a mesh of several devices, widths the kernels leave)."""
+    mesh = get_mesh()
+    return gdn_mix.plan(
+        grouped_matmul.backend(), 1 if mesh is None else mesh.devices.size,
+        heads, jnp.dtype(dtype).itemsize, grouped_matmul.device_kind())
+
+
+def _premix_xla(qkvz, taps, heads, conv_act, unit_length):
+    """The mixer between ``in_proj_qkvz`` and the rule in XLA: ``(q, k [B,
+    T, Hv dk], v, z [B, T, Hv dv])`` from the published layout (a key head's
+    q, k, its r values, its r z) -- what ``gdn_mix.premix`` computes in one
+    kernel (its fourth output stands for z there)."""
+    B, T, _ = qkvz.shape
+    Hk, dk, r, dv, _ = heads
+    q, k, v, z = jnp.split(
+        qkvz.reshape(B, T, Hk, 2 * dk + 2 * r * dv),
+        (dk, 2 * dk, 2 * dk + r * dv), axis=-1)
+    mixed = jnp.concatenate([t.reshape(B, T, -1) for t in (q, k, v)], axis=-1)
+    mixed = conv_act(causal_conv(mixed, taps.astype(qkvz.dtype)))
+    q, k, v = jnp.split(mixed, (Hk * dk, 2 * Hk * dk), axis=-1)
+    q, k = (unit_length(t.reshape(B, T, Hk, dk)) for t in (q, k))
+    q = repeat_kv((q * dk ** -0.5).astype(qkvz.dtype), r)
+    k = repeat_kv(k.astype(qkvz.dtype), r)
+    flat = lambda t: t.reshape(B, T, -1)
+    return flat(q), flat(k), v, flat(z)
+
+
+def _gate_xla(o, z, scale, eps, dv):
+    """``scale * rms_norm(o) * silu(z)`` a value head of ``dv`` columns in
+    float32, in o's type (``gdn_mix.gate`` in one kernel)."""
+    B, T, _ = o.shape
+    o32 = o.reshape(B, T, -1, dv).astype(jnp.float32)
+    o32 = o32 * jax.lax.rsqrt(jnp.mean(o32 * o32, -1, keepdims=True)
+                              + eps) * scale
+    return (o32 * nn.silu(z.reshape(B, T, -1, dv).astype(jnp.float32))
+            ).astype(o.dtype).reshape(B, T, -1)
 
 
 # the mixers' small formulas by name (tests/benchmark/qwen3_next_wrong.py

@@ -55,3 +55,11 @@ MOE_GMM_T = "ds_moe_gmm_t"
 # and a head's matrix state in VMEM, and its backward (``gdn_rule.py``)
 GDN_RULE_FWD = "ds_gdn_rule_fwd"
 GDN_RULE_BWD = "ds_gdn_rule_bwd"
+# what stands around that rule in the layer's mixer (``gdn_mix.py``): the
+# convolution, activation and unit length of q, k, v read in place from the
+# projection's published columns, and the output's gated norm; a backward
+# each (the prefix is not the rule's: a trace reader counts ``ds_gdn_rule_``)
+GDN_PREMIX_FWD = "ds_gdn_premix_fwd"
+GDN_PREMIX_BWD = "ds_gdn_premix_bwd"
+GDN_GATE_FWD = "ds_gdn_gate_fwd"
+GDN_GATE_BWD = "ds_gdn_gate_bwd"

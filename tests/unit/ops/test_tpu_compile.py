@@ -163,6 +163,35 @@ def _gdn_rule(bwd, T=8192, heads=32, d=128, chunk=64):
                     argnums=tuple(range(6))), args
 
 
+def _gdn_mix(kernel, T=8192):
+    """qwen3-next-80b-a3b.train.8k: what stands around the rule in one
+    delta-rule layer, 16 key heads of 128 + 128 + 256 + 256 columns read in
+    place from ``in_proj_qkvz``'s ``[1, 8192, 12288]`` output, four taps, at
+    the tiling the chooser gives the cell; each of the four kernels alone,
+    behind its jitted entry."""
+    from deepspeed_tpu.models import qwen3_next as qn
+    from deepspeed_tpu.ops.pallas import gdn_mix
+
+    heads = gdn_mix.Heads(16, D, 2, D, 4)
+    rows = gdn_mix.plan("tpu", 1, heads).rows
+    premix = gdn_mix._Static(heads, rows, False, qn._conv_act,
+                             qn._unit_length)
+    gate = gdn_mix._Static(heads, rows, False, eps=1e-6)
+    qkvz = ((1, T, 16 * 6 * D), BF16)
+    head = ((1, T, 32 * D), BF16)
+    taps, scale = ((4, 16 * 4 * D), jnp.float32), ((1, D), jnp.float32)
+    return {
+        "premix_fwd": (lambda *a: gdn_mix._premix_fwd(*a, premix),
+                       [qkvz, taps]),
+        "premix_bwd": (lambda *a: gdn_mix._premix_bwd(*a, premix),
+                       [qkvz, taps, head, head, head, head]),
+        "gate_fwd": (lambda *a: gdn_mix._gate_fwd(*a, gate),
+                     [head, qkvz, scale]),
+        "gate_bwd": (lambda *a: gdn_mix._gate_bwd(*a, gate),
+                     [head, head, qkvz, scale]),
+    }[kernel]
+
+
 def _flash_key_mask():
     """Serving prefill (``layers.flash_prefill_from_empty``): forward only,
     kv heads un-repeated, a [B, Tk] key-padding mask."""
@@ -247,6 +276,10 @@ CASES = {
     "ssm_scan_bwd_train8k": lambda: _ssm_scan(True),
     "gdn_rule_train8k": lambda: _gdn_rule(False),
     "gdn_rule_bwd_train8k": lambda: _gdn_rule(True),
+    "gdn_premix_train8k": lambda: _gdn_mix("premix_fwd"),
+    "gdn_premix_bwd_train8k": lambda: _gdn_mix("premix_bwd"),
+    "gdn_gate_train8k": lambda: _gdn_mix("gate_fwd"),
+    "gdn_gate_bwd_train8k": lambda: _gdn_mix("gate_bwd"),
     "ragged_bf16": lambda: _ragged(False, None),
     "ragged_bf16_window": lambda: _ragged(False, 4096),
     "ragged_int8": lambda: _ragged(True, None),
@@ -314,10 +347,15 @@ def test_delta_rule_layer_is_two_kernels_and_no_loop_on_one_v5e(chip, on_v5e):
     """``models/qwen3_next.py``'s delta-rule mixer at the published widths
     (hidden 2,048, 16 key and 32 value heads of 128, chunks of 64) over
     qwen3-next 8k's 8,192 tokens, its gradient compiled for one v5e with the
-    chooser answered for it: the rule is ONE ``ds_gdn_rule_fwd`` and ONE
+    choosers answered for it: the rule is ONE ``ds_gdn_rule_fwd`` and ONE
     ``ds_gdn_rule_bwd``, and neither the lowered nor the compiled program
     holds a loop -- no scan over the 128 chunk boundaries is left; the
-    chunks' tables and their inverse stay XLA's."""
+    chunks' tables and their inverse stay XLA's. Around the rule (PR 55) the
+    mixer is the four kernels of ``ops/pallas/gdn_mix.py`` -- six kernels
+    in all -- which read ``in_proj_qkvz``'s columns in place: under
+    ``ds.gdn_mix`` the compiled program copies no ``[8192, 8192]`` and no
+    ``[8192, 12288]`` bf16 array (the regrouping of the published layout,
+    and the concatenate that rebuilt its gradient, are gone)."""
     from benchmark import common
     from deepspeed_tpu.models import qwen3_next as qn
 
@@ -337,13 +375,22 @@ def test_delta_rule_layer_is_two_kernels_and_no_loop_on_one_v5e(chip, on_v5e):
         mixer.apply({"params": p}, x)[0].astype(jnp.float32))
     lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x)
     text = lowered.as_text()
-    assert sorted(re.findall(r'kernel_name = "(ds_\w+)"', text)) == [
-        "ds_gdn_rule_bwd", "ds_gdn_rule_fwd"]
+    six = ["ds_gdn_gate_bwd", "ds_gdn_gate_fwd", "ds_gdn_premix_bwd",
+           "ds_gdn_premix_fwd", "ds_gdn_rule_bwd", "ds_gdn_rule_fwd"]
+    assert sorted(re.findall(r'kernel_name = "(ds_\w+)"', text)) == six
     assert "stablehlo.while" not in text
     hlo = lowered.compile().as_text()
     assert sorted(re.findall(r"%(ds_gdn_rule_\w+?)[.\d]* = ", hlo)) == [
         "ds_gdn_rule_bwd", "ds_gdn_rule_fwd"]
+    assert sorted(re.findall(r"%(ds_gdn_\w+?)[.\d]* = ", hlo)) == six
     assert not re.search(r" while\(", hlo)
+    under_mix = [line for line in hlo.splitlines() if "ds.gdn_mix" in line]
+    assert under_mix
+    # whatever under the scope results in an array of that size is a kernel
+    whole = re.compile(r"= \(?bf16\[(?:1,)?8192,(?:8192|12288)\]")
+    assert {re.search(r"%(\w+?)[.\d]* = ", line).group(1)
+            for line in under_mix if whole.search(line)
+            and " get-tuple-element(" not in line} == {"ds_gdn_premix_bwd"}
 
 
 # -- the expert layer on one chip, at OLMoE's shapes -------------------------

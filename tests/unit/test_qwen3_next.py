@@ -1,6 +1,7 @@
 """``models/qwen3_next.py`` against the plain reference
 (``benchmark/reference/qwen3_next.py``) at tiny sizes in float32 on the CPU:
-logits, loss and every parameter's gradient; the chunked gated delta rule
+logits, loss and every parameter's gradient (the delta-rule mixer in XLA and
+through ``ops/pallas/gdn_mix.py``'s kernels); the chunked gated delta rule
 against the token-by-token recurrence (chunks that do and do not divide the
 length, decays of 20 nats a token, forward and backward); the gated attention
 against a masked softmax with 64 of 256 columns rotated; the sixteen shares of
@@ -53,7 +54,19 @@ def case():
 
 # -- the model against the reference ------------------------------------------
 
-def test_logits_and_loss_are_the_references(case):
+@pytest.fixture(params=["xla", "kernels"])
+def mixer_path(request, monkeypatch):
+    """The delta-rule mixer around its rule as the CPU runs it (XLA), and
+    with ``ops/pallas/gdn_mix.py``'s kernels forced on (interpret mode: the
+    chooser answered with a tiling, three tiles over the 37 positions)."""
+    if request.param == "kernels":
+        from deepspeed_tpu.ops.pallas import gdn_mix
+
+        monkeypatch.setattr(qn, "_mix_tiling", lambda *a: gdn_mix.Tiling(16))
+    return request.param
+
+
+def test_logits_and_loss_are_the_references(case, mixer_path):
     cfg, model, ids, params = case
     with HIGHEST:
         logits = model.apply({"params": params}, ids)
@@ -71,7 +84,7 @@ def test_logits_and_loss_are_the_references(case):
                              "moe_rows_max_over_mean"]
 
 
-def test_every_parameters_gradient_is_the_references(case):
+def test_every_parameters_gradient_is_the_references(case, mixer_path):
     """Each kind by name: ``A_log``, ``dt_bias``, the convolution, both
     gates (the full layer's inside ``q_proj``, the shared expert's), the
     zero-centred weights, the delta rule's plain output scale, the frozen

@@ -445,6 +445,28 @@ def _gdn_rule(bwd):
                     argnums=tuple(range(6))), args
 
 
+def _gdn_mix(gate, bwd):
+    """The mixer around the rule at two key heads of 128 + 128 + 256 + 256
+    columns: ``premix`` (with ``gate`` reading its handle, so that ``dz``
+    has somewhere to come from) or ``gate`` alone."""
+    from deepspeed_tpu.models import qwen3_next as qn
+    from deepspeed_tpu.ops.pallas import gdn_mix
+
+    heads, tiling = gdn_mix.Heads(2, D, 2, D, 4), gdn_mix.Tiling(128)
+    args = [((1, 256, 2 * 6 * D), BF16), ((4, 2 * 4 * D), jnp.float32),
+            ((D,), jnp.float32)]
+
+    def fwd(qkvz, taps, scale):
+        q, k, v, z = gdn_mix.premix(qkvz, taps, heads, qn._conv_act,
+                                    qn._unit_length, tiling, interpret=False)
+        if not gate:
+            return q.astype(jnp.float32).sum() + (k + v).astype(
+                jnp.float32).sum()
+        return gdn_mix.gate(v, z, qkvz, scale, 1e-6, heads, tiling,
+                            interpret=False).astype(jnp.float32).sum()
+    return (jax.grad(fwd, argnums=(0, 1, 2)) if bwd else fwd), args
+
+
 def _selection():
     from deepspeed_tpu.models.indexed_attention import select_mask
 
@@ -483,6 +505,12 @@ KERNELS = {
     names.MOE_GMM_T: ("ds_moe_gmm_t", lambda: _moe_gmm(True)),
     names.GDN_RULE_FWD: ("ds_gdn_rule_fwd", lambda: _gdn_rule(False)),
     names.GDN_RULE_BWD: ("ds_gdn_rule_bwd", lambda: _gdn_rule(True)),
+    names.GDN_PREMIX_FWD: ("ds_gdn_premix_fwd",
+                           lambda: _gdn_mix(False, False)),
+    names.GDN_PREMIX_BWD: ("ds_gdn_premix_bwd",
+                           lambda: _gdn_mix(False, True)),
+    names.GDN_GATE_FWD: ("ds_gdn_gate_fwd", lambda: _gdn_mix(True, False)),
+    names.GDN_GATE_BWD: ("ds_gdn_gate_bwd", lambda: _gdn_mix(True, True)),
 }
 
 
@@ -684,8 +712,25 @@ def test_delta_rule_kernels_stand_alone_under_their_scope(monkeypatch):
     # the entries are jitted: the module holds each kernel once (the forward
     # once more for the replay, whose jaxpr is its own), whatever the layers
     lowered = re.findall(r'kernel_name = "(ds_\w+)"', text)
-    assert sorted(lowered) == ["ds_gdn_rule_bwd"] + 2 * ["ds_gdn_rule_fwd"]
+    assert sorted(n for n in lowered if n.startswith("ds_gdn_rule_")) == [
+        "ds_gdn_rule_bwd"] + 2 * ["ds_gdn_rule_fwd"]
     assert "stablehlo.while" not in text
+    # what stands around the rule (``ops/pallas/gdn_mix.py``, PR 55): one
+    # kernel before it and one after it, under ``ds.gdn_mix`` and nowhere
+    # else, the forwards twice a layer and the backwards once --
+    # ``train.gdn_mix_share`` reads them by that scope
+    mix = re.findall(
+        r'"(\S*)ds\.gdn_mix/jit\(_(premix|gate)_(fwd|bwd)\)"', text)
+    assert sorted(f"{k}_{d}" for _, k, d in mix) == sorted(
+        3 * ["premix_bwd", "gate_bwd"] + 6 * ["premix_fwd", "gate_fwd"])
+    assert len(re.findall(r"call @_(?:premix|gate)_(?:fwd|bwd)", text)) \
+        == len(mix)
+    assert sorted(f"{k}_{d}" for path, k, d in mix
+                  if "rematted_computation" in path) == sorted(
+        3 * ["premix_fwd", "gate_fwd"])
+    assert sorted(n for n in lowered if not n.startswith("ds_gdn_rule_")) \
+        == sorted(["ds_gdn_premix_bwd", "ds_gdn_gate_bwd"]
+                  + 2 * ["ds_gdn_premix_fwd", "ds_gdn_gate_fwd"])
 
 
 def test_no_step_without_the_flash_indexer_holds_its_kernels(train_text):
