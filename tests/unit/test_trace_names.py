@@ -27,6 +27,7 @@ from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3Config,
                                               DeepseekV3ForCausalLM)
 from deepspeed_tpu.models.mellum import MellumConfig, MellumForCausalLM
 from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
+from deepspeed_tpu.models.ouro import OuroConfig, OuroForCausalLM
 from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
                                              Qwen3NextForCausalLM)
 from deepspeed_tpu.models.sambay import SambaYConfig, SambaYForCausalLM
@@ -76,6 +77,12 @@ TRAIN_SCOPES = {
                    "ds.gdn_mix", "ds.gdn_rule", "ds.attention",
                    "ds.attn_gate", "ds.moe_router", "ds.moe_experts",
                    "ds.moe_shared", "ds.lm_head_loss"],
+    # one stack run four times over shared weights: ds.loop_stack is the
+    # loop over the passes beyond the passes' own scopes, ds.exit_gate the
+    # gate after every pass and the mixing of the passes' losses
+    "ouro": ["ds.loss_and_grad", "ds.optimizer", "ds.embed",
+             "ds.loop_stack", "ds.attn_proj", "ds.attention", "ds.mlp",
+             "ds.lm_head_loss", "ds.exit_gate"],
 }
 #: what every family names besides: the engine's cast of the master weights,
 #: the loop over the layers, the block's two pre-norms and residual sums
@@ -102,7 +109,9 @@ def train_text():
             ("sambay", SambaYForCausalLM(SambaYConfig.tiny(remat=True))),
             ("mellum", MellumForCausalLM(MellumConfig.tiny(remat=True))),
             ("qwen3_next", Qwen3NextForCausalLM(Qwen3NextConfig.tiny(
-                remat=True)))):
+                remat=True))),
+            ("ouro", OuroForCausalLM(OuroConfig.tiny(remat=True,
+                                                     loss_chunk=64)))):
         batch = {"input_ids": np.zeros((8, 32), np.int32),
                  "labels": np.zeros((8, 32), np.int32)}
         engine, *_ = ds.initialize(
@@ -159,8 +168,9 @@ def trace_names():
 #: ``init_opt_state``, ``init_step``, ``cost_capture`` and ``setup``: a host
 #: span stands in no lowered step, so no cell's module name, lowered text or
 #: cache entry changes, and a trace taken before them lacks only events no
-#: reader of that time asked for)
-NAMES_PIN = (3, "c2575eb26d7f63c9")
+#: reader of that time asked for; PR 56 added ``ds.loop_stack`` and
+#: ``ds.exit_gate``, which stand only in ``models/ouro.py``'s step)
+NAMES_PIN = (3, "476bbf73e3960e1c")
 
 
 def test_names_version_is_raised_with_the_names():
@@ -173,7 +183,8 @@ def test_names_version_is_raised_with_the_names():
             "ds.sa_index", "ds.sa_select", "ds.sa_loss", "ds.ssm_scan",
             "ds.ssm_mix", "ds.gmu", "ds.da_mix", "ds.layer_window",
             "ds.layer_full", "ds.rope_tables", "ds.layer_gdn", "ds.gdn_mix",
-            "ds.gdn_rule", "ds.attn_gate"} <= set(scopes) \
+            "ds.gdn_rule", "ds.attn_gate", "ds.loop_stack",
+            "ds.exit_gate"} <= set(scopes) \
         and {"counters", "init", "init_shapes", "init_params",
              "init_opt_state", "init_step", "cost_capture",
              "setup"} <= set(spans)
@@ -755,21 +766,49 @@ def test_no_other_familys_step_holds_the_delta_rules_names(train_text):
                          else set()), family
 
 
+def test_no_other_familys_step_holds_the_loops_names(train_text):
+    """``ds.loop_stack`` and ``ds.exit_gate`` stand in ``models/ouro.py``'s
+    step alone: the other families' programs are what they were, and
+    ``NAMES_VERSION`` stays."""
+    for family, text in train_text.items():
+        found = set(re.findall(r"ds\.(?:loop_stack|exit_gate)\b", text))
+        assert found == ({"ds.loop_stack", "ds.exit_gate"}
+                         if family == "ouro" else set()), family
+
+
+@pytest.fixture
+def own_compile_cache(tmp_path):
+    """A persistent compile cache of the test's own, empty: whatever it
+    runs was compiled by this process on this machine."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    shared = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_compilation_cache_dir", shared)
+    compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize("gas", [1, 2])
-def test_named_scalars_of_any_model_become_gauges(gas):
+def test_named_scalars_of_any_model_become_gauges(gas, own_compile_cache):
     """The contract Mixtral's load report rides: a training call that
     returns ``(loss, {name: scalar})`` gets registry gauges of those names,
     the mean over micro-batches; what is no scalar is dropped.
 
-    The head is three wide, a shape of this test's own: with ``Dense(1)``
-    its init programs were, byte for byte, those of
-    ``test_step_counters.py``'s model, so the two files -- on two workers
-    under ``--dist loadfile`` -- read and wrote the SAME entries of the
-    persistent compile cache (``tests/conftest.py`` caches every compile;
-    jax's ``put`` is a plain ``write_bytes``, so a reader can meet a
-    half-written entry). That shared state is the one thing this case had
-    in common with a neighbour when it failed in the driver's run of PR 51's
-    tree and passed in the builder's."""
+    The case failed in the driver's runs of PR 51's and PR 55's trees and
+    in no builder's (PR 56: alone, beside ``test_step_counters.py`` on two
+    workers, and again on an empty cache directory: 3 x green). What it
+    shared with its neighbours was ``tests/conftest.py``'s ONE persistent
+    compile cache, six workers on a directory: with ``Dense(1)`` its init
+    programs were, byte for byte, ``test_step_counters.py``'s (PR 51 made the
+    head three wide for that). A half-written entry cannot be the cause --
+    jax catches a failed read, warns and compiles -- but an entry another
+    machine wrote can be read whole and run: this sandbox's XLA:CPU loader
+    warns of exactly that ("compile machine features ... vs host machine
+    features"). So the case compiles its own programs into a directory of
+    its own (``own_compile_cache``) and shares nothing; should it fail
+    again, the cache is not why."""
     import flax.linen as nn
 
     class Named(nn.Module):
