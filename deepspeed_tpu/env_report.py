@@ -231,7 +231,8 @@ def perf_report() -> None:
     if not rows:
         print("compiled programs: none resident in this process")
         return
-    from deepspeed_tpu.monitor.export import LEDGER_HEADER, ledger_columns
+    from deepspeed_tpu.monitor.export import (LEDGER_HEADER, ledger_columns,
+                                              memory_line)
 
     print(f"{'program':<34}{'fingerprint':<13}{'compiles':>9}"
           f"{'recompiles':>11}{'calls':>7}{LEDGER_HEADER}  flops/call")
@@ -240,6 +241,9 @@ def perf_report() -> None:
         print(f"{r['name']:<34}{str(r['fingerprint']):<13}"
               f"{r['compiles']:>9}{r['recompiles']:>11}{r['calls']:>7}"
               f"{ledger_columns(r)}  {flops} ({r['cost_source'] or '-'})")
+        line = memory_line(r)
+        if line:
+            print(line)
 
 
 def speculation_report() -> None:

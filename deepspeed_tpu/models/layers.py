@@ -20,28 +20,28 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def resolve_remat_policy(name: str):
+def resolve_remat_policy(name: str, offered=()):
     """Activation-checkpoint policy by name (shared by all models so the
     accepted strings cannot drift between model files).
 
     Under EVERY policy the flash kernel's output and log-sum-exp are kept
-    (the values ``ops/pallas/flash_attention.py`` names ``ds_flash_out`` and
-    ``ds_flash_lse``), as the layer's input always is: they cost two bytes a
-    value of ``[B, T, H, Dv]`` plus four of ``[B, H, T]``, only the forward
-    kernel can produce them, and without them the backward's replay runs
-    that whole kernel again to hand them to the backward kernels. Everything
-    else gets the named policy's answer; where no flash kernel ran (the XLA
-    attention path, whose residual is the ``[H, T, T]`` probabilities) no
-    such name exists and the policy is the plain one. A learned selection's
-    bit-packed mask (``ds_sa_mask``: the replay must see the set the forward
-    chose) and its loss's row statistics (``ds_sa_kl_rows``) are kept too.
+    (``ds_flash_out``, ``ds_flash_lse``), as the layer's input always is:
+    64 MB a layer at 8k that only the forward kernel can produce, so the
+    replay never runs that kernel; a learned selection's bit-packed mask
+    (``ds_sa_mask``: the replay must see the set the forward chose) and its
+    loss's row statistics (``ds_sa_kl_rows``) the same. Everything else gets
+    the named policy's answer -- but for what the model file OFFERS:
+    ``offered`` is its ordered ``[(checkpoint_name, bytes over every layer
+    application)]``, costliest replay first, and ``keep_for_room`` (this
+    file's end) keeps as many as the budget the ENGINE states for the trace
+    has room for (``remat_room``: the device's free memory before the step
+    is built; the engine checks the compiled step and takes the choice back
+    where it was wrong). Nothing is set by hand. With no budget -- a bare
+    ``model.apply``, a CPU, a mesh of several devices -- nothing offered is
+    kept, the names are the identity and the policy is the plain one.
 
-    ``offload_dots_no_batch`` is the CPU-activation-checkpointing analog
-    (reference ``activation_checkpointing/checkpointing.py:480``
-    ``cpu_checkpointing``): non-batched matmul residuals (the
-    ``dots_no_batch`` set) are saved to PINNED HOST memory instead of HBM —
-    XLA schedules the device↔host copies, replacing the reference's explicit
-    ``.cpu()`` round-trips."""
+    ``offload_dots_no_batch`` is the CPU-activation-checkpointing analog:
+    its ``dots_no_batch`` residuals go to PINNED HOST memory, not HBM."""
     from ..ops.pallas import (FLASH_LSE, FLASH_OUT,  # ops imports this module
                               SA_KL_ROWS, SA_MASK)
 
@@ -57,7 +57,7 @@ def resolve_remat_policy(name: str):
         raise ValueError(f"unknown remat_policy {name!r}; one of {sorted(policies)}")
     base = policies[name]
     flash_named = jax.checkpoint_policies.save_only_these_names(
-        FLASH_OUT, FLASH_LSE, SA_MASK, SA_KL_ROWS)
+        FLASH_OUT, FLASH_LSE, SA_MASK, SA_KL_ROWS, *keep_for_room(offered))
 
     # written out, not save_from_both_policies: that helper refuses the
     # Offloadable / Recompute answers of the offload policy
@@ -1147,3 +1147,79 @@ def yarn_rotary_embedding(positions: jnp.ndarray, head_dim: int, theta: float,
         * inv_freq.astype(np.float32)[None, None, :]
     return ((jnp.cos(freqs) * attention_factor).astype(dtype),
             (jnp.sin(freqs) * attention_factor).astype(dtype))
+
+
+# -- what a remat'ed block keeps beyond its policy ---------------------------
+# at the file's end, its two imports too: a line added above moves the frames
+# of every flash kernel's call sites, which its compile-cache key holds
+
+import contextlib  # noqa: E402
+import threading  # noqa: E402
+
+from jax.ad_checkpoint import checkpoint_name  # noqa: E402
+
+#: the rule's constants (PERF.md section 3 has the readings they were fixed
+#: on; they are not options). A named byte costs the compiled step up to
+#: ``REMAT_FACTOR`` bytes of temp (the kept value, stacked over the layers,
+#: beside the layer's own copy of it while the stack is written); the step
+#: plans with ``REMAT_SHARE`` of the device's memory, less what is in use
+#: before it is built; and a compiled step whose footprint stands over
+#: ``REMAT_MARGIN`` of the device's memory is built again with nothing kept
+REMAT_FACTOR = 2
+REMAT_SHARE = 0.75
+REMAT_MARGIN = 0.95
+
+
+class _Room(threading.local):
+    """The budget stated for the trace open on this thread, and what the
+    rule kept under it ``{name: bytes}``."""
+
+    budget = 0
+    kept = None
+
+
+_room = _Room()
+
+
+@contextlib.contextmanager
+def remat_room(budget: int):
+    """State ``budget`` bytes of free device memory for every trace made
+    inside (the engine's, around the lowering of its train step); yields
+    ``{name: bytes}``, which fills as block wrappers ask ``keep_for_room``.
+    Outside any such context the budget is 0."""
+    before = _room.budget, _room.kept
+    _room.budget, _room.kept = max(int(budget), 0), {}
+    try:
+        yield _room.kept
+    finally:
+        _room.budget, _room.kept = before
+
+
+def keep_for_room(offered) -> Tuple[str, ...]:
+    """The names of ``offered`` a remat policy keeps: ``offered`` is ONE
+    model's ``[(checkpoint_name, bytes over all its layer applications)]``
+    in the model's order, walked once -- a name is kept (for all its layer
+    applications, or for none) while ``REMAT_FACTOR`` times the bytes kept
+    so far, it among them, stay inside the budget ``remat_room`` states for
+    this trace (0 outside one). A name that does not fit is passed over and
+    the walk goes on: a later, smaller one may."""
+    kept, total = [], 0
+    for name, nbytes in offered:
+        if REMAT_FACTOR * (total + nbytes) <= _room.budget:
+            kept.append(name)
+            total += nbytes
+            _room.kept[name] = nbytes
+    return tuple(kept)
+
+
+def name_if_kept(x, name: str):
+    """``checkpoint_name(x, name)`` where the rule kept ``name`` for the
+    trace open on this thread, else ``x`` itself: a step that keeps nothing
+    holds no ``name`` equation, so it lowers to the very text it had before
+    any name was offered (a ``name`` lowers to nothing, but it moves the
+    numbers XLA's private functions are called by, which the compile
+    cache's key holds). For a module's own body, traced anew under every
+    trace -- not for a function jitted by itself, whose trace is cached."""
+    if _room.kept and name in _room.kept:
+        return checkpoint_name(x, name)
+    return x

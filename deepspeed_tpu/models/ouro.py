@@ -42,7 +42,8 @@ import jax.numpy as jnp
 
 from .layers import (RMSNorm, head_scope, resolve_remat_policy,
                      rotary_embedding, shift_labels)
-from .llama import (LlamaAttention, LlamaConfig, LlamaForCausalLM, LlamaMLP)
+from .llama import (LlamaAttention, LlamaConfig, LlamaForCausalLM, LlamaMLP,
+                    remat_offers)
 
 IGNORE = -100
 
@@ -228,16 +229,20 @@ class _Pass(nn.Module):
     with the sandwich block), the final norm, and what is read off the normed
     state: ``(the stream as the layers left it, its final norm h_t, (each
     token's loss under the head, the gate's logit))`` -- without labels no
-    loss (the caller asks ``head`` for the last state's logits)."""
+    loss (the caller asks ``head`` for the last state's logits).
+    ``offered``: what the blocks name, its bytes over every layer of every
+    pass (``llama.remat_offers``), for the remat policy to choose from."""
 
     config: OuroConfig
+    offered: tuple = ()
 
     def setup(self):
         cfg = self.config
         block = _ScanBlock
         if cfg.remat:
             block = nn.remat(_ScanBlock, prevent_cse=False,
-                             policy=resolve_remat_policy(cfg.remat_policy))
+                             policy=resolve_remat_policy(cfg.remat_policy,
+                                                         self.offered))
         self.layers = nn.scan(
             block, variable_axes={"params": 0}, split_rngs={"params": True},
             in_axes=(nn.broadcast,) * 3, length=cfg.num_hidden_layers,
@@ -312,7 +317,9 @@ class OuroForCausalLM(nn.Module):
             attention_mask[:, None, None, :] > 0, 0.0, -1e9).astype(
                 jnp.float32)
         shifted = None if labels is None else shift_labels(labels)
-        one_pass = _Pass(cfg, name="loop")
+        one_pass = _Pass(cfg, remat_offers(
+            cfg, x, cfg.num_hidden_layers * cfg.total_ut_steps)
+            if cfg.remat else (), name="loop")
         # ds.loop_stack: what the loop over the passes costs beyond the
         # passes' own scopes -- the R readings' stacking, the sums of the
         # shared weights' R gradients

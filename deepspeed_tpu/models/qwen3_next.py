@@ -63,17 +63,20 @@ lowered step).
 """
 
 import dataclasses
+import functools
 from typing import Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.pallas import gdn_mix, gdn_rule, grouped_matmul
+from ..ops.pallas import (REMAT_GDN_MIX, REMAT_GDN_QKVZ, REMAT_GDN_RULE,
+                          gdn_mix, gdn_rule, grouped_matmul)
 from ..parallel.topology import get_mesh
 from .layers import (apply_rotary_partial, causal_conv, cross_entropy_loss,
-                     dot_product_attention, head_scope, model_dense, repeat_kv,
-                     resolve_remat_policy, rotary_embedding, shift_labels)
+                     dot_product_attention, head_scope, model_dense,
+                     name_if_kept, repeat_kv, resolve_remat_policy,
+                     rotary_embedding, shift_labels)
 from .mixtral import (MixtralConfig, MixtralForCausalLM, MixtralSparseMoeBlock,
                       _add_stats, _compact_rows, _extra_stats, _fits,
                       _share_loss_and_gauges)
@@ -156,6 +159,39 @@ def _unit_lower_solve(a, rhs):
         a, rhs, left_side=True, lower=True, unit_diagonal=True)
 
 
+def _named_inverse(a):
+    """``(I + a)^-1`` for ``a [..., C, C]`` strictly lower triangular, named
+    with the kernel's o and boundary states (``gdn_rule._vjp_fwd``): a
+    policy that keeps the three replays neither the kernel nor this solve."""
+    eye = jnp.eye(a.shape[-1], dtype=a.dtype)
+    return name_if_kept(_unit_lower_solve(a, jnp.broadcast_to(eye, a.shape)),
+                        REMAT_GDN_RULE)
+
+
+_chunk_inverse = jax.custom_jvp(_named_inverse)
+
+
+@_chunk_inverse.defjvp
+def _chunk_inverse_jvp(primals, tangents):
+    """``d X = -X da X`` over the strictly lower part of ``da``, reading the
+    NAMED ``X``: two products at the highest precision and no solve. jax's
+    own rule for the solve is ``-(I + a)^-1 da X`` with one more solve, and
+    closes over the solve's RAW output, which no name reaches -- the
+    backward would ask the replay for it, and the replay would run the
+    solve to give it; and a backward that holds ``X`` needs no substitution
+    to apply ``(I + a)^-1`` (on the chip a solve is the rule's largest
+    operation, 2.7 ms a layer, and its transposed twin in the backward cost
+    as much once no replay shared its inverted blocks). The solve and its
+    name stand HERE, in the differentiated program's own equations, where a
+    remat policy sees them (a call to ``_chunk_inverse`` would hide both
+    inside one opaque equation)."""
+    (a,), (da,) = primals, tangents
+    inverse = _named_inverse(a)
+    product = functools.partial(jnp.matmul,
+                                precision=jax.lax.Precision.HIGHEST)
+    return inverse, -product(product(inverse, jnp.tril(da, -1)), inverse)
+
+
 def _rule_tiling(dk, dv, chunk, dtype):
     """``gdn_rule.plan`` of what this call site can see: the kernels' tiling,
     or None where the rule stays in XLA (off a TPU, under a mesh of several
@@ -207,9 +243,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64):
     kc = k.reshape(B, n, C, H, dk)
     a = beta[..., None] * below * jnp.einsum(
         "bnihd,bnjhd->bhnij", kc, kc, preferred_element_type=f32)
-    inverse = _unit_lower_solve(a, jnp.broadcast_to(jnp.eye(C, dtype=f32),
-                                                    a.shape))
-    o = gdn_rule.chunk_rule(q, k, v, gamma, beta, inverse, tiling)
+    o = gdn_rule.chunk_rule(q, k, v, gamma, beta, _chunk_inverse(a), tiling)
     return o[:, :T], jnp.max(-gamma[..., -1])
 
 
@@ -283,8 +317,9 @@ class GatedDeltaNet(nn.Module):
             cfg.linear_num_value_heads // cfg.linear_num_key_heads
         f32 = jnp.float32
         with jax.named_scope("ds.attn_proj"):
-            qkvz = model_dense(cfg, 2 * Hk * dk + 2 * Hv * dv,
-                               "in_proj_qkvz")(x)
+            qkvz = name_if_kept(model_dense(
+                cfg, 2 * Hk * dk + 2 * Hv * dv, "in_proj_qkvz")(x),
+                REMAT_GDN_QKVZ)
             ba = model_dense(cfg, 2 * Hv, "in_proj_ba")(x)
         with jax.named_scope("ds.gdn_mix"):
             b, a = jnp.split(ba.reshape(B, T, Hk, 2 * r), 2, axis=-1)
@@ -300,6 +335,7 @@ class GatedDeltaNet(nn.Module):
             else:
                 q, k, v, z = gdn_mix.premix(qkvz, taps, heads, _conv_act,
                                             _unit_length, tiling)
+            q, k, v = (name_if_kept(t, REMAT_GDN_MIX) for t in (q, k, v))
             a_log = self.param("A_log", _a_log_init, (Hv,), f32)
             dt_bias = self.param("dt_bias", nn.initializers.ones, (Hv,), f32)
             beta = _beta(b.reshape(B, T, Hv).astype(f32))
@@ -316,6 +352,7 @@ class GatedDeltaNet(nn.Module):
             else:       # z: premix's handle; the kernel reads qkvz in place
                 o = gdn_mix.gate(o, z, qkvz, scale, cfg.rms_norm_eps, heads,
                                  tiling)
+            o = name_if_kept(o, REMAT_GDN_MIX)
         with jax.named_scope("ds.attn_proj"):
             out = model_dense(cfg, cfg.hidden_size, "out_proj",
                               row_parallel=True)(o)
@@ -513,7 +550,9 @@ class _Period(nn.Module):
         cfg = self.config
         x, frac_sum, prob_sum, extra_sum, decay_max = carry
         block_cls = nn.remat(Qwen3NextBlock, prevent_cse=self.lone,
-                             policy=resolve_remat_policy(cfg.remat_policy)) \
+                             policy=resolve_remat_policy(
+                                 cfg.remat_policy,
+                                 remat_offers(cfg, x, self.kinds))) \
             if cfg.remat else Qwen3NextBlock
         for i, kind in enumerate(self.kinds):
             with jax.named_scope(KIND_SCOPES[kind]):
@@ -615,3 +654,29 @@ class Qwen3NextForCausalLM(nn.Module):
     #: router
     partition_rules = staticmethod(MixtralForCausalLM.partition_rules)
     frozen_parameters = staticmethod(MixtralForCausalLM.frozen_parameters)
+
+
+def remat_offers(cfg, x, kinds):
+    """What a delta-rule layer names, as ``_Period`` offers it to
+    ``layers.resolve_remat_policy``: ``[(name, bytes over the stack's
+    delta-rule layers)]`` for a stream ``x [B, T, hidden]``, costliest replay
+    a byte first -- the rule's output, boundary states and chunk inverse
+    (qwen3-next 8k: 13.4 ms a step for 1.2 GB), ``in_proj_qkvz``'s output
+    (the replay then runs the premix kernel alone to hand the backward its
+    q, k, v), and last what the premix and gate kernels hand on. The rule's
+    values are named only where its kernels run (``_rule_tiling``: one TPU
+    device, which is also where an engine states a budget): nothing is
+    offered elsewhere."""
+    B, T, _ = x.shape
+    Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv, C = cfg.linear_key_head_dim, cfg.linear_value_head_dim, \
+        cfg.gdn_chunk
+    if _rule_tiling(dk, dv, C, x.dtype) is None:
+        return ()
+    layers = cfg.num_hidden_layers // len(kinds) * kinds.count(GDN)
+    item, n = x.dtype.itemsize, -(-T // C)
+    return ((REMAT_GDN_RULE, layers * B * Hv * (
+                n * (C * C + dk * dv) * 4 + T * dv * item)),
+            (REMAT_GDN_QKVZ,
+             layers * B * T * (2 * Hk * dk + 2 * Hv * dv) * item),
+            (REMAT_GDN_MIX, layers * B * T * Hv * (2 * dk + 2 * dv) * item))

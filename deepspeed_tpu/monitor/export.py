@@ -456,6 +456,24 @@ def ledger_columns(row) -> str:
     return "".join(f"{c:>{w}}" for c, w in zip(cells, (9, 9, 11, 7)))
 
 
+def memory_line(row) -> Optional[str]:
+    """One line under a program's row that was built ahead of its first
+    call (the train step, ``runtime/engine.py _fit_train_step``): what its
+    remat policy keeps beyond the policy's own, of which budget, how often
+    the compiled step made the engine take that back, and what
+    ``memory_analysis()`` says the program occupies. None for a row
+    without the numbers."""
+    m = row.get("memory")
+    if not m:
+        return None
+    gb = lambda key: f"{m[key] / 1e9:.2f}"
+    return (f"  {row['name']}: keeps {m['remat_kept_names']} offered values, "
+            f"{gb('remat_kept_bytes')} of {gb('remat_room_bytes')} GB room "
+            f"({m['remat_fallbacks']} taken back); compiled: arguments "
+            f"{gb('step_argument_bytes')} + temp {gb('step_temp_bytes')} GB, "
+            f"peak {gb('step_peak_bytes')} GB")
+
+
 def serving_statusz(srv) -> str:
     """The human-readable /statusz page of a serving engine: resident
     compiled-program table, recompile counts, HBM watermarks, and the
@@ -477,6 +495,9 @@ def serving_statusz(srv) -> str:
         lines.append(f"{row['name']:<28}{str(row['fingerprint']):<13}"
                      f"{row['compiles']:>9}{row['recompiles']:>11}"
                      f"{row['calls']:>7}{ledger_columns(row)}")
+        line = memory_line(row)
+        if line:
+            lines.append(line)
     lines.append("")
     lines.append(f"compile_counts: {json.dumps(perf.get('compile_counts'))}")
     lines.append("")

@@ -154,7 +154,8 @@ class CompiledProgram:
     """One resident jitted program's accounting record."""
 
     __slots__ = ("name", "fingerprint", "compiles", "calls", "recompiles",
-                 "flops", "bytes_accessed", "cost_source", "cost_attempted")
+                 "flops", "bytes_accessed", "cost_source", "cost_attempted",
+                 "memory")
 
     def __init__(self, name: str):
         self.name = name
@@ -169,6 +170,10 @@ class CompiledProgram:
         #: model AND no fallback must pay the lowering walk once, not on
         #: every hot-path dispatch
         self.cost_attempted = False
+        #: ``SetupRecord.COUNTS`` of a train step built ahead of its first
+        #: call: what its remat policy keeps and what the compiled program
+        #: occupies (``export.memory_line`` prints it under the row)
+        self.memory: Optional[Dict[str, int]] = None
 
     @property
     def cost_pending(self) -> bool:
@@ -187,7 +192,7 @@ class CompiledProgram:
                 "compiles": self.compiles, "recompiles": self.recompiles,
                 "calls": self.calls, "flops": self.flops,
                 "bytes_accessed": self.bytes_accessed,
-                "cost_source": self.cost_source}
+                "cost_source": self.cost_source, "memory": self.memory}
 
 
 #: every live ProgramRegistry in the process, for ``ds_report``'s resident
@@ -615,9 +620,19 @@ class SetupRecord:
     PARTS = ("import", "pre_init", "init", "init_shapes", "init_params",
              "init_opt_state", "init_step", "first_step", "first_dispatch",
              "first_wait", "cost_capture")
+    #: what the engine's ahead-of-time build of the train step found
+    #: (``runtime/engine.py _fit_train_step``): the bytes and the count of
+    #: the named values its remat policy keeps beyond the policy's own, the
+    #: budget they were chosen under, how often the compiled step made the
+    #: engine take a choice back, and ``memory_analysis()`` of the compiled
+    #: step (the peak: arguments included; 0 where the backend has none)
+    COUNTS = ("remat_kept_bytes", "remat_kept_names", "remat_room_bytes",
+              "remat_fallbacks", "step_argument_bytes", "step_temp_bytes",
+              "step_peak_bytes")
 
     def __init__(self):
         self.ledger = compile_ledger()
+        self.counts: Dict[str, int] = dict.fromkeys(self.COUNTS, 0)
         self.seconds: Dict[str, float] = {}
         self.sums: Dict[str, Dict[str, float]] = {}
         self._closed: Optional[Dict[str, float]] = None
@@ -645,6 +660,7 @@ class SetupRecord:
 
     def _numbers(self) -> Dict[str, float]:
         out = {f"{k}_s": self.seconds.get(k, 0.0) for k in self.PARTS}
+        out.update(self.counts)
         outside = self.ledger.snapshot()["outside"]
         for k in LEDGER_COLUMNS:
             out[k] = sum(row.get(k, 0) for row in self.sums.values())

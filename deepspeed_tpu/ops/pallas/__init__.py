@@ -1,8 +1,8 @@
 """Pallas kernels. Every ``pl.pallas_call`` here passes one of these fixed
 names, so a profiler trace names the kernel the same way after any refactor
 of the code around it (a trace reader matches the strings; outside this
-package only ``models/layers.py resolve_remat_policy`` and
-``models/indexed_attention.py`` import any: the checkpoint names)."""
+package only ``models/layers.py resolve_remat_policy`` and the model files
+that name a value import any: the checkpoint names)."""
 
 FLASH_FWD = "ds_flash_fwd"
 # the flash backward: all three gradients in one walk by kv row where a
@@ -63,3 +63,18 @@ GDN_PREMIX_FWD = "ds_gdn_premix_fwd"
 GDN_PREMIX_BWD = "ds_gdn_premix_bwd"
 GDN_GATE_FWD = "ds_gdn_gate_fwd"
 GDN_GATE_BWD = "ds_gdn_gate_bwd"
+# ``checkpoint_name``s a remat'ed block OFFERS, costliest replay a byte
+# first: under every policy ``models/layers.keep_for_room`` keeps as many as
+# the engine's budget for the trace has room for, and none without one
+# (``resolve_remat_policy``); elsewhere they are the identity. The MLP's
+# gate and up products, and q, k, v after RoPE (the key/value heads before
+# ``repeat_kv``) -- ``models/llama.py``; ``models/ouro.py`` offers the same
+REMAT_MLP = "ds_mlp_gate_up"
+REMAT_QKV = "ds_attn_qkv"
+# a delta-rule layer's (``models/qwen3_next.py``): the rule's output,
+# boundary states and chunk inverse (the replay then runs neither
+# ``ds_gdn_rule_fwd`` nor the triangular solve), ``in_proj_qkvz``'s output,
+# and what the premix and gate kernels hand on
+REMAT_GDN_RULE = "ds_gdn_rule_kept"
+REMAT_GDN_QKVZ = "ds_gdn_qkvz"
+REMAT_GDN_MIX = "ds_gdn_mix_out"

@@ -50,10 +50,11 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import GDN_RULE_BWD, GDN_RULE_FWD, grouped_matmul
+from . import GDN_RULE_BWD, GDN_RULE_FWD, REMAT_GDN_RULE, grouped_matmul
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -319,6 +320,12 @@ def _rule(q, k, v, gamma, beta, t, tiling, interpret):
 
 def _vjp_fwd(q, k, v, gamma, beta, t, tiling, interpret):
     o, hs = _rule_fwd(q, k, v, gamma, beta, t, tiling, interpret)
+    # named, as the flash forward names its pair, so that a remat policy can
+    # keep them (models/layers.resolve_remat_policy does where the engine
+    # finds room; the caller names t, the chunk inverse, the same): the
+    # replay then calls no forward kernel. Outside a policy: the identity
+    o = checkpoint_name(o, REMAT_GDN_RULE)
+    hs = checkpoint_name(hs, REMAT_GDN_RULE)
     return o, (q, k, v, gamma, beta, t, hs)
 
 

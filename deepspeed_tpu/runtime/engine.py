@@ -295,6 +295,9 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         #: whether a profiler session that recorded the last step has the
         #: record already
         self._setup_published = False
+        #: choices of the remat rule the compiled step made it take back
+        #: (``_fit_train_step``); from the first on the budget is 0
+        self._remat_fallbacks = 0
         with self.setup.span(self.tracer.span("init", cat="setup")):
             self._construct(model, model_parameters, example_batch,
                             partition_rules, rng)
@@ -1116,6 +1119,9 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             with compiling, tr.span(
                     "dispatch", cat="train", ring="train_step",
                     args={"step": step, "program": "train_step"}):
+                if recompiled or not warm:
+                    # the compile, ahead of the call that would carry it
+                    self._fit_train_step(batch, step_rng)
                 self.state, (loss, self._last_grad_norm, named), overflow = \
                     self._train_step(self.state, batch, step_rng)
             # the step's named scalars wait on the device for a later call
@@ -1580,6 +1586,85 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         with self.tracer.span("setup", cat="setup", args=record):
             pass
 
+    def _remat_budget(self) -> Tuple[int, Optional[Tuple[int, int]]]:
+        """``(the bytes the remat rule may plan with, the device's (limit, in
+        use) or None)``: ``layers.REMAT_SHARE`` of the one device's memory
+        less what is in use now, the state resident -- and 0 where that
+        cannot be read (a CPU), under a mesh of several devices (a device's
+        part of a kept value is not what the model file counted), on the
+        one-bit, overlap and sparse lanes (their steps differentiate by their
+        own rules), and once a choice was taken back."""
+        from ..models import layers
+
+        memory = _device_memory(self.mesh.devices.flat[0])
+        if memory is None or self.mesh.devices.size != 1 or \
+                self._remat_fallbacks or self._onebit_wire or \
+                self._overlap_lane or self._config.sparse_gradients_enabled:
+            return 0, memory
+        limit, in_use = memory
+        return max(int(layers.REMAT_SHARE * limit) - in_use, 0), memory
+
+    def _fit_train_step(self, batch, rng) -> None:
+        """Lower and compile the train step for this batch AHEAD of the call
+        (the call then finds the executable: nothing is lowered or compiled
+        twice), its trace under the budget ``_remat_budget`` states -- the
+        model's remat'ed blocks keep, of the values they offer by name, what
+        fits it (``layers.keep_for_room``). Then the check on the compiled
+        program: where something was kept and the step's footprint
+        (``memory_analysis()``'s peak, arguments included) plus what else the
+        process holds on the device stands over ``layers.REMAT_MARGIN`` of
+        the device's memory, or the compiler refused the step for memory,
+        the step is built once more with nothing kept -- the state is not
+        yet donated, no step of this shape has run -- and one line says so.
+        The numbers go to the set-up record (``SetupRecord.COUNTS``)."""
+        from ..models import layers
+
+        def build(budget):
+            with layers.remat_room(budget) as kept:
+                lowered = self._train_step.lower(self.state, batch, rng)
+            try:
+                return dict(kept), lowered.compile().memory_analysis()
+            except jax.errors.JaxRuntimeError as e:
+                if not kept or "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
+                return dict(kept), None
+
+        budget, memory = self._remat_budget()
+        kept, compiled = build(budget)
+        size = lambda name: int(getattr(compiled, name, 0) or 0)
+        if kept:
+            limit, in_use = memory
+            footprint = None if compiled is None else \
+                size("peak_memory_in_bytes") + max(
+                    in_use - size("argument_size_in_bytes"), 0)
+            if footprint is None or footprint > layers.REMAT_MARGIN * limit:
+                logger.warning(
+                    f"train step: kept {sorted(kept)} ({sum(kept.values())} "
+                    f"bytes) for a budget of {budget} bytes, and the compiled "
+                    f"step " + ("was refused for memory" if footprint is None
+                                else f"stands at {footprint} of {limit} "
+                                f"bytes") + ": built again with nothing kept")
+                self._remat_fallbacks += 1
+                self._train_step = self._compile_train_step()
+                kept, compiled = build(0)
+        self.setup.counts.update(
+            remat_kept_bytes=sum(kept.values()), remat_kept_names=len(kept),
+            remat_room_bytes=budget, remat_fallbacks=self._remat_fallbacks,
+            step_argument_bytes=size("argument_size_in_bytes"),
+            step_temp_bytes=size("temp_size_in_bytes"),
+            step_peak_bytes=size("peak_memory_in_bytes"))
+        self.perf.programs.program("train_step").memory = dict(
+            self.setup.counts)
+        if memory is not None:
+            log_dist(
+                f"train step: kept {sorted(kept) or 'nothing'} "
+                f"({sum(kept.values()) / 1e9:.2f} GB of {budget / 1e9:.2f} GB "
+                f"room); compiled: arguments "
+                f"{size('argument_size_in_bytes') / 1e9:.2f} + temp "
+                f"{size('temp_size_in_bytes') / 1e9:.2f} GB, peak "
+                f"{size('peak_memory_in_bytes') / 1e9:.2f} of "
+                f"{memory[0] / 1e9:.2f} GB", ranks=[0])
+
 
 class _LazyLoss:
     """Loss handle returned by the parity ``forward``: forcing it (float/
@@ -1685,3 +1770,15 @@ def initialize(args=None, model=None, optimizer=None, model_parameters=None,
             training_data, batch_size=engine.micro_batch_size * engine.dp_world_size,
             collate_fn=collate_fn)
     return engine, engine, dataloader, engine.lr_scheduler
+
+
+def _device_memory(device) -> Optional[Tuple[int, int]]:
+    """``(bytes_limit, bytes_in_use)`` of the device's allocator, or None
+    where the backend keeps no such numbers (a CPU)."""
+    try:
+        stats = device.memory_stats()
+    except Exception:
+        stats = None
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]), int(stats.get("bytes_in_use", 0))

@@ -833,3 +833,53 @@ def test_compact_buffer_changes_no_other_lowered_gradient(
     assert ("stablehlo.case" in with_rule) == engages
     assert "stablehlo.case" not in without
     assert (with_rule == without) == (not engages)
+
+
+# -- what a remat'ed block keeps where the chip has room (PR 57) -------------
+
+def test_kept_names_cost_train_8ks_step_no_more_than_the_rule_counts(
+        chip, on_v5e, monkeypatch):
+    """A two-layer Llama at train.8k's widths (hidden 4096, 14336 wide MLP,
+    32 / 8 heads of 128, 8,192 tokens, flash kernels in): its gradient
+    compiled for one v5e with both offered names kept holds no gate, up, q, k
+    or v product in the backward scan's replay, and ``memory_analysis()``'s
+    temp stands above the plain step's by no more than ``REMAT_FACTOR`` times
+    the bytes the rule counted -- the reading the factor was fixed on (the
+    engine's whole step on the chip read 1.41 times: PERF.md section 3)."""
+    import deepspeed_tpu.ops.pallas.flash_attention as fa
+    from deepspeed_tpu.models import LlamaConfig, LlamaForCausalLM
+    from deepspeed_tpu.models.layers import REMAT_FACTOR, remat_room
+
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=False))
+    T = 8192
+    ids = jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=chip)
+
+    def compiled(budget):
+        # a model, and so a function, of its own: jax keeps a trace
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=4096, hidden_size=4096, intermediate_size=14336,
+            num_hidden_layers=2, num_attention_heads=H,
+            num_key_value_heads=HKV, max_position_embeddings=T,
+            attention_impl="flash", loss_chunk=1024))
+        params = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, BF16, sharding=chip),
+            jax.eval_shape(lambda: model.init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"])
+        loss = lambda params, ids: model.apply({"params": params}, ids,
+                                               labels=ids)
+        with remat_room(budget) as kept:
+            program = jax.jit(jax.grad(loss)).lower(params, ids).compile()
+        return dict(kept), program
+
+    kept, named = compiled(4 * 10 ** 9)
+    nothing, plain = compiled(0)
+    assert nothing == {}
+    assert kept == {"ds_mlp_gate_up": 2 * 2 * T * 14336 * 2,
+                    "ds_attn_qkv": 2 * T * (H + 2 * HKV) * D * 2}
+    wide = lambda hlo: len(re.findall(
+        r"= bf16\[8192,14336\]\S* (?:convolution|dot)\(", hlo))
+    assert wide(plain.as_text()) - wide(named.as_text()) == 2
+    rise = named.memory_analysis().temp_size_in_bytes \
+        - plain.memory_analysis().temp_size_in_bytes
+    assert 0 < rise <= REMAT_FACTOR * sum(kept.values())

@@ -179,7 +179,7 @@ def test_a_second_fresh_compile_reads_the_persistent_cache(tmp_path):
 
 RECORD_KEYS = (
     [f"{k}_s" for k in perf.SetupRecord.PARTS]
-    + list(perf.LEDGER_COLUMNS)
+    + list(perf.SetupRecord.COUNTS) + list(perf.LEDGER_COLUMNS)
     + [f"outside_{k}" for k in perf.LEDGER_COLUMNS] + ["steps_before"])
 
 
@@ -227,7 +227,7 @@ def test_set_up_spans_stand_in_the_ring(traced_engine):
 
 def test_the_record_adds_up(traced_engine):
     rec = traced_engine.setup.record(7)
-    assert list(rec) == RECORD_KEYS and len(rec) == 26
+    assert list(rec) == RECORD_KEYS and len(rec) == 33
     assert all(isinstance(v, (int, float)) and v >= 0 for v in rec.values())
     assert rec["steps_before"] == 7
     # the imports on the way to an engine were stamped, and what lay

@@ -536,10 +536,78 @@ CHECKPOINT_NAMES = {names.FLASH_OUT: ("ds_flash_out", lambda: _flash(True)),
                                        lambda: _sa_probs(True))}
 
 
+def _offering_llama():
+    from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+    model = LlamaForCausalLM(LlamaConfig.tiny(remat=True))
+    ids = jnp.zeros((1, 16), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
+    return jax.grad(lambda p: model.apply({"params": p}, ids, labels=ids)), \
+        params
+
+
+def _offering_qwen3_next():
+    """The delta rule's kernels as on the chip (the choosers answered with a
+    tiling, the kernels lowered, not interpreted: nothing runs)."""
+    from deepspeed_tpu.models import qwen3_next as qn
+    from deepspeed_tpu.ops.pallas import gdn_mix, gdn_rule
+
+    model = qn.Qwen3NextForCausalLM(qn.Qwen3NextConfig.tiny(
+        num_hidden_layers=4, remat=True))
+    ids = jnp.zeros((1, 32), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
+
+    def grad(p):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(qn, "_rule_tiling",
+                          lambda *a: gdn_rule.Tiling(2, 2))
+            patch.setattr(qn, "_mix_tiling", lambda *a: gdn_mix.Tiling(16))
+            return jax.grad(lambda p: model.apply(
+                {"params": p}, ids, labels=ids))(p)
+    return grad, params
+
+
+#: the values a remat'ed block OFFERS its policy (``layers.keep_for_room``
+#: keeps what the engine's budget has room for): ``checkpoint_name``s that
+#: stand in a program only where they were kept, with a model that offers each
+OFFERED_NAMES = {
+    names.REMAT_MLP: ("ds_mlp_gate_up", _offering_llama),
+    names.REMAT_QKV: ("ds_attn_qkv", _offering_llama),
+    names.REMAT_GDN_RULE: ("ds_gdn_rule_kept", _offering_qwen3_next),
+    names.REMAT_GDN_QKVZ: ("ds_gdn_qkvz", _offering_qwen3_next),
+    names.REMAT_GDN_MIX: ("ds_gdn_mix_out", _offering_qwen3_next),
+}
+
+
 def test_every_kernel_name_is_listed():
     constants = {v for k, v in vars(names).items()
                  if k.isupper() and isinstance(v, str)}
-    assert constants == set(KERNELS) | set(CHECKPOINT_NAMES)
+    assert constants == set(KERNELS) | set(CHECKPOINT_NAMES) \
+        | set(OFFERED_NAMES)
+
+
+@pytest.mark.parametrize("constant", sorted(OFFERED_NAMES))
+def test_offered_name_stands_where_it_is_kept_and_nowhere_else(constant):
+    """Under a budget with room the model's gradient names the value by
+    this spelling (the policy finds it by it); with none the program holds
+    no such equation -- it is the program it was before names were offered
+    (but for the delta rule's kernel, which names its output and boundary
+    states wherever it runs, as the flash forward names its pair: there the
+    name tells nothing apart)."""
+    from deepspeed_tpu.models.layers import remat_room
+
+    spelled, case = OFFERED_NAMES[constant]
+    assert constant == spelled
+    named = lambda text: text.count(f"name[name={constant}]")
+    grad, params = case()
+    without = named(str(jax.make_jaxpr(grad)(params)))
+    assert (without == 0) == (constant != names.REMAT_GDN_RULE)
+    grad, params = case()       # jax keeps a function's trace
+    with remat_room(10 ** 9) as kept:
+        text = str(jax.make_jaxpr(grad)(params))
+    assert constant in kept and named(text) > 0
 
 
 @pytest.mark.parametrize("constant", sorted(CHECKPOINT_NAMES))
