@@ -311,7 +311,7 @@ def dot_product_attention(q, k, v, bias=None, causal: bool = False,
     softmax kernels and stock torch attention.
 
     ``causal`` applies bottom-right-aligned causality and ``window`` a
-    sliding window; ``bias`` carries any ADDITIVE mask beyond that (e.g.
+    sliding window (or a ``BlockDiffusion``); ``bias`` any ADDITIVE mask (e.g.
     padding). The flash kernels take causality, a window, a key-padding
     mask (forward only) and a ``[B, Tq, Tk]`` selection that is data
     (``flash_attention(mask=...)``, which ``models/indexed_attention.py``
@@ -375,15 +375,15 @@ def dot_product_attention(q, k, v, bias=None, causal: bool = False,
     if scale is None:
         scale = 1.0 / np.sqrt(depth)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    if causal:
+    from ..ops.pallas.flash_attention import BlockDiffusion, window_mask
+
+    if causal and not isinstance(window, BlockDiffusion):
         logits = logits + make_causal_mask(q.shape[1], k.shape[1], dtype=jnp.float32,
                                            offset=k.shape[1] - q.shape[1])[None, None]
-    if window is not None:
-        Tq, Tk = q.shape[1], k.shape[1]
-        i = jnp.arange(Tq)[:, None]
-        j = jnp.arange(Tk)[None, :]
-        logits = jnp.where((i + (Tk - Tq) - j < window)[None, None],
-                           logits, -1e9)
+    if window is not None:  # a width, or a rule that stands for causality too
+        logits = jnp.where(
+            window_mask(window, q.shape[1], k.shape[1])[None, None],
+            logits, -1e9)
     if bias is not None:
         logits = logits + bias
     logits = logits.astype(jnp.float32)

@@ -341,7 +341,7 @@ class LlamaAttention(nn.Module):
                                         attention_impl=cfg.attention_impl,
                                         flash_block_q=cfg.flash_block_q,
                                         flash_block_k=cfg.flash_block_k,
-                                        window=cfg.sliding_window)
+                                        window=_window_of(cfg, T))
         with jax.named_scope("ds.attn_proj"):
             out = dense(cfg.hidden_size, "o_proj", row=True)(
                 out.reshape(B, T, H * D))
@@ -603,3 +603,20 @@ def remat_offers(cfg, x, applications: int):
     heads = cfg.num_attention_heads + 2 * cfg.num_key_value_heads
     return ((REMAT_MLP, 2 * cfg.intermediate_size * per_value),
             (REMAT_QKV, heads * cfg.head_dim * per_value))
+
+
+def _window_of(cfg, T: int):
+    """What the uncached attention core takes as ``window=``: the config's
+    ``sliding_window``, or under a config with a ``block_length``
+    (``models/sdar.py``: every sequence its stack sees is ``[x_t ; x_0]``)
+    the ``flash_attention.BlockDiffusion`` of these ``T`` positions' halves,
+    which stands for causality too."""
+    block = getattr(cfg, "block_length", None)
+    if block is None:
+        return cfg.sliding_window
+    from ..ops.pallas import flash_attention
+
+    if T % (2 * block):
+        raise ValueError(f"{T} positions are not a noised and a clean copy "
+                         f"of one sequence in blocks of {block}")
+    return flash_attention.BlockDiffusion(T // 2, block)

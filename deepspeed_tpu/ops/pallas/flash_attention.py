@@ -10,7 +10,7 @@ Layout: inputs are [B, T, H, D] (model convention); kernels operate on
 have a width ``Dv`` of their own: queries and keys share ``D``, and where
 ``Dv == D`` the calls are what they were before the widths were told apart. The grid is ``(B, H, tiles)``: its last dimension walks a
 STATIC TILE TABLE (``_tile_table``) that holds only the (q-tile, kv-tile)
-pairs the causal rule and the window keep, built in numpy at trace time
+pairs the causal rule and the window (or a ``BlockDiffusion``) keep, in numpy
 from the shapes and handed to the kernel by scalar prefetch; the index maps
 read it, so a tile the mask rules out is neither a grid step nor a fetch.
 The table is ordered by q row with keys ascending (forward, dQ) or by kv
@@ -47,7 +47,7 @@ mode for kernel parity).
 """
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -86,18 +86,15 @@ def _tile_table(tq, tk, block_q, block_k, causal, window, by_kv=False,
     over its REAL rows and columns; the mask keeps ``d >= 0`` (causal) and
     ``d < window``, so a tile holds a visible entry iff the two ranges meet
     and is wholly visible iff one lies in the other (and it has no padded
-    tail). Entries are ordered by q row, keys ascending (``by_kv``: by kv
-    row, queries ascending); ``_FIRST`` / ``_LAST`` mark a row's ends.
-
-    Every row of output tiles owns at least one entry, or its block would be
-    neither initialised nor written: a q row that sees no key (causal with
-    tk < tq) and a kv row no query sees (a window with tk > tq) keep one
-    placeholder that runs no body — the in-tile mask could not empty it
-    (``exp(NEG_INF - NEG_INF)`` is 1) — so its outputs are zeros.
-
-    ``dense_mask``: a mask that is data rules inside every kept tile, so none
-    is ``_INSIDE``.
-    """
+    tail). A ``window`` that is a ``BlockDiffusion`` (file's end) is a RULE
+    that stands for both: it says which tiles it keeps and which whole.
+    Entries are ordered by q row, keys ascending (``by_kv``: by kv row,
+    queries ascending); ``_FIRST`` / ``_LAST`` mark a row's ends. Every row
+    of output tiles owns at least one entry, or its block would be neither
+    initialised nor written: a row the mask empties keeps one placeholder
+    that runs no body -- the in-tile mask could not empty it (``exp(NEG_INF
+    - NEG_INF)`` is 1) -- so its outputs are zeros. ``dense_mask``: a mask
+    that is data rules inside every kept tile, so none is ``_INSIDE``."""
     off = tk - tq
     r0 = (np.arange(_ceil_div(tq, block_q)) * block_q)[:, None]
     c0 = (np.arange(_ceil_div(tk, block_k)) * block_k)[None, :]
@@ -106,6 +103,9 @@ def _tile_table(tq, tk, block_q, block_k, causal, window, by_kv=False,
     dmin, dmax = r0 + off - c1, r1 + off - c0
     keep = np.ones(dmin.shape, bool)
     inside = (r0 + block_q <= tq) & (c0 + block_k <= tk)
+    if isinstance(window, BlockDiffusion):
+        some, whole = window.tiles(r0, r1, c0, c1)
+        keep, inside, causal, window = keep & some, inside & whole, False, None
     if causal:
         keep &= dmax >= 0
         inside &= dmin >= 0
@@ -154,13 +154,13 @@ def _tile_valid(iq, ik, block_q, block_k, tq, tk, causal, window,
         ((block_q, block_k), 0)
     rows = jax.lax.broadcasted_iota(jnp.int32, shape, q_dim) + iq * block_q
     cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim) + ik * block_k
-    # ragged tails: padded kv columns/q rows contribute nothing
-    valid = (cols < tk) & (rows < tq)
-    if causal:
+    ruled = _ruled(window, rows, cols)  # None under causal / window alone
+    valid = (cols < tk) & (rows < tq)   # ragged tails contribute nothing
+    if causal and ruled is None:
         valid = valid & (rows + (tk - tq) >= cols)
-    if window is not None:
+    if window is not None and ruled is None:
         valid = valid & (rows + (tk - tq) - cols < window)
-    return valid, rows
+    return (valid if ruled is None else valid & ruled), rows
 
 
 # ---------------------------------------------------------------------------
@@ -644,13 +644,13 @@ def _reference_attention(q, k, v, causal, sm_scale, window=None,
             b, t, hk * rep, d)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * sm_scale
     Tq, Tk = q.shape[1], k.shape[1]
-    if causal:
+    if causal and not isinstance(window, BlockDiffusion):
         tril = jnp.tril(jnp.ones((Tq, Tk), bool), k=Tk - Tq)
         logits = jnp.where(tril[None, None], logits, NEG_INF)
     if window is not None:
-        i = jnp.arange(Tq)[:, None]
-        j = jnp.arange(Tk)[None, :]
-        wmask = (i + (Tk - Tq) - j) < window
+        # a sliding window's width, or a rule that stands for causality
+        # too (``BlockDiffusion``, at the file's end)
+        wmask = window_mask(window, Tq, Tk)
         logits = jnp.where(wmask[None, None], logits, NEG_INF)
     if key_mask is not None:
         logits = jnp.where((key_mask > 0)[:, None, None, :], logits,
@@ -795,3 +795,106 @@ def _table(tiles, tq, tk, block_q, block_k, causal, window, by_kv=False):
     if tiles is None:
         return static, static.shape[1]
     return _mask_tile_table(tiles, static, by_kv)
+
+
+# ---------------------------------------------------------------------------
+# a rule in the window's place. HERE, below the kernels, and not beside
+# ``_tile_table``, for the reason above: a Mosaic payload holds the line AND
+# column of every frame that calls it, so one line more above a kernel (or in
+# a caller, ``llama.LlamaAttention``) re-keys the compiled step of every
+# cell that runs these kernels. ``_tile_table`` and ``_tile_valid`` took the
+# rule within the lines they had; ``tests/unit/test_flash_attention.py``
+# holds the standing cells' tables to their bytes
+# ---------------------------------------------------------------------------
+
+
+class BlockDiffusion(NamedTuple):
+    """Block diffusion's training mask (Arriola et al., ICLR 2025) as a
+    static RULE over ``[x_t ; x_0]``: positions ``0 .. half - 1`` are a noised
+    copy of the sequence, ``half ..`` the clean one, both in blocks of
+    ``block`` tokens. With ``clean(p) = p >= half`` and ``blk(p) = (p - half *
+    clean(p)) // block``, query ``q`` sees key ``j`` iff
+
+    - ``clean(q) == clean(j)`` and ``blk(q) == blk(j)``: a block sees itself,
+      both ways; or
+    - ``j`` is clean and ``blk(q) + clean(q) > blk(j)``: a noised block sees
+      the clean blocks BEFORE it, a clean block those up to its own.
+
+    A clean query never sees a noised key. ``half = 0`` is block-causal
+    attention alone (a prefill over clean ids). Handed to ``flash_attention``
+    (and ``layers.dot_product_attention``) as ``window=``: the slot of the
+    static rule that narrows what a query sees. It stands for causality too:
+    under it ``causal`` is NOT READ, at any of the sites that take the rule
+    (``_tile_table``, ``_tile_valid``, ``_reference_attention``,
+    ``layers.dot_product_attention``), whatever the caller passed. Hashable: a
+    static argument of the kernels' ``custom_vjp`` and of their tile table,
+    never an array -- at 2 x 8,192 positions and blocks of 4 the table keeps
+    288 of 1,024 tiles where a ``[16384, 16384]`` mask would be 268 MB."""
+
+    half: int
+    block: int
+
+    def _split(self, p):
+        clean = p >= self.half
+        return clean, (p - self.half * clean) // self.block
+
+    def sees(self, rows, cols):
+        """The rule on broadcastable position arrays (numpy or traced)."""
+        (cq, bq), (cj, bj) = self._split(rows), self._split(cols)
+        return ((cq == cj) & (bq == bj)) | (cj & (bq + cq > bj))
+
+    def tiles(self, r0, r1, c0, c1):
+        """``(some, whole)``: does the rule keep a pair, and every pair, of
+        the tile of rows ``r0 .. r1`` and columns ``c0 .. c1`` (inclusive;
+        arrays that broadcast)? Exact for any tile: a range of positions
+        splits into its noised and its clean part, each a range of blocks
+        ``[a0, a1]`` against ``[b0, b1]``, under one clause each."""
+        some, whole = False, True
+        for cq in (False, True):
+            (qa, qb), q_has = self._part(r0, r1, cq)
+            for cj in (False, True):
+                (ka, kb), k_has = self._part(c0, c1, cj)
+                if cq == cj:    # same block; and among the clean, earlier
+                    any_ = (qb >= ka) & ((kb >= qa) | cq)
+                    all_ = (qa >= kb) & ((qb <= ka) | cq)
+                else:           # noised sees clean before it; never back
+                    any_, all_ = cj & (qb > ka), cj & (qa > kb)
+                has = q_has & k_has
+                some, whole = some | (has & any_), whole & (~has | all_)
+        return some, whole
+
+    def _part(self, p0, p1, clean):
+        """Block range of the noised (clean) part of ``p0 .. p1``, and
+        whether it holds a position."""
+        lo = np.maximum(p0, self.half) if clean else p0
+        hi = p1 if clean else np.minimum(p1, self.half - 1)
+        return self._split(np.stack([lo, hi]))[1], lo <= hi
+
+
+def _ruled(window, rows, cols):
+    """A cut tile's mask under a rule; None where ``window`` is a width."""
+    if isinstance(window, BlockDiffusion):
+        return window.sees(rows, cols)
+    return None
+
+
+def window_mask(window, tq: int, tk: int):
+    """bool ``[tq, tk]``: the pairs a sliding window's width (inside a causal
+    mask the caller applies) or a ``BlockDiffusion`` keeps -- the einsum paths'
+    dense form of what the kernels read off the tile table."""
+    i, j = jnp.arange(tq)[:, None], jnp.arange(tk)[None, :]
+    if isinstance(window, BlockDiffusion):
+        return window.sees(i, j)
+    return i + (tk - tq) - j < window
+
+
+def rule_tile_share(rule, t: int, block_q: int = 512,
+                    block_k: int = 512) -> float:
+    """The tiles the kernels walk under ``rule`` over ``t`` positions (the
+    forward's table; the backward's holds the same entries) over all
+    ``ceil(t / block)**2``: a constant of the shapes, 288 / 1,024 at 2 x 8,192
+    positions and blocks of 4."""
+    bq, bk = min(block_q, t), min(block_k, t)
+    word = _tile_table(t, t, bq, bk, True, rule)[2]
+    return float(np.sum(word & (_INSIDE | _CUT) != 0)) \
+        / (_ceil_div(t, bq) * _ceil_div(t, bk))

@@ -23,7 +23,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
-from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.flash_attention import (BlockDiffusion,
+                                                     flash_attention)
 from deepspeed_tpu.ops.pallas.fused_adam import _run_leaf
 from deepspeed_tpu.ops.pallas.quant_matmul import quant_matmul
 from deepspeed_tpu.ops.pallas.ragged_attention import ragged_paged_attention
@@ -265,6 +266,10 @@ CASES = {
     # zaya1-8b.train.8k: 8 query heads of 128 (keys and values repeated from
     # 2), plain causal
     "flash_fwd_bwd_cca_train8k": lambda: _flash(True, None, (1, 8192, 8, D)),
+    # sdar-30b-a3b.train.8k: [x_t ; x_0], 2 x 8,192 rows under the block
+    # rule; a cut tile's mask divides row numbers by the block length
+    "flash_fwd_bwd_bd_train8k": lambda: _flash(
+        True, BlockDiffusion(8192, 4), (1, 16384, H, D)),
     "flash_fwd_key_mask_gqa": _flash_key_mask,
     "flash_fwd_sa_train16k": lambda: _flash_sa(False),
     "flash_fwd_bwd_sa_train16k": lambda: _flash_sa(True),
@@ -307,6 +312,9 @@ FLASH_BWD = {
     "da_train8k": lambda: _flash(True, None, (1, 8192, 20, 64), v_dim=128),
     # mellum2-12b-a2.5b.train.8k: three 1,024-window layers a period
     "swa_train8k": lambda: _flash(True, 1024, (1, 8192, H, D)),
+    # sdar-30b-a3b.train.8k: 16,384 rows, 16 MiB resident
+    "bd_train8k": lambda: _flash(True, BlockDiffusion(8192, 4),
+                                 (1, 16384, H, D)),
     "longest_32k": lambda: _flash(True, None, (1, 32768, 2, D)),
     # 64 MiB resident, twice the share: the two kernels
     "over_the_share_64k": lambda: _flash(True, None, (1, 65536, 1, D)),

@@ -11,6 +11,7 @@ import pytest
 
 from deepspeed_tpu.models.layers import resolve_remat_policy
 from deepspeed_tpu.ops.pallas.flash_attention import (
+    BlockDiffusion,
     _reference_attention,
     flash_attention,
 )
@@ -186,7 +187,22 @@ def test_key_mask_path_gqa_native_kv_heads():
 # -- the static tile table ----------------------------------------------------
 
 
+def _block_diffusion_pairs(t, half, block):
+    """The rule as ISSUE 58 states it, pair by pair: ``half(p) = [p >= L]``,
+    ``blk(p) = (p mod L) // B``; with no noised half every position is
+    clean."""
+    clean = [p >= half for p in range(t)]
+    blk = [(p % half if half else p) // block for p in range(t)]
+    return np.array([[
+        (clean[q] == clean[j] and blk[q] == blk[j])
+        or (not clean[q] and clean[j] and blk[q] > blk[j])
+        or (clean[q] and clean[j] and blk[q] >= blk[j])
+        for j in range(t)] for q in range(t)])
+
+
 def _rule_mask(tq, tk, causal, window):
+    if isinstance(window, BlockDiffusion):  # it stands for causality too
+        return _block_diffusion_pairs(tq, window.half, window.block)
     d = np.arange(tq)[:, None] + (tk - tq) - np.arange(tk)[None, :]
     mask = np.ones((tq, tk), bool)
     if causal:
@@ -220,6 +236,14 @@ def _dense_tiles(tq, tk, bq, bk, causal, window):
     (128, 64, 32, 32, True, 8),          # tq > tk with a window
     (200, 100, 64, 32, False, 24),       # a window without causality
     (1, 128, 1, 64, True, None),         # the decode shape
+    # block diffusion over [x_t ; x_0]: blocks inside a tile, a tile, and
+    # (L 1536, B 512, tiles of 128) tiles inside a block
+    *[(2 * L, 2 * L, tile, tile, True, BlockDiffusion(L, B))
+      for L in (512, 1024, 1536) for B in (4, 32, 512)
+      for tile in (128, 512)],
+    (400, 400, 128, 128, True, BlockDiffusion(200, 8)),   # no tile divides L
+    (1000, 1000, 128, 64, True, BlockDiffusion(500, 4)),  # ragged, bq != bk
+    (600, 600, 128, 128, True, BlockDiffusion(0, 4)),     # block-causal alone
 ])
 def test_tile_table_is_the_dense_mask_by_tile(tq, tk, bq, bk, causal, window):
     from deepspeed_tpu.ops.pallas.flash_attention import (
@@ -258,6 +282,10 @@ def test_tile_table_is_the_dense_mask_by_tile(tq, tk, bq, bk, causal, window):
 @pytest.mark.parametrize("cell,t,window,rect,kept,inside", [
     ("mistral-7b.train.8k", 8192, 4096, 256, 108, 84),
     ("olmoe-1b-7b.train.4k", 4096, None, 64, 36, 28),
+    # 2 x 8,192 positions in blocks of 4: the clean-clean quadrant's 136,
+    # noised-clean 136, the noised quadrant's 16 diagonal tiles, clean-noised
+    # none; 48 cut. The causal rule over 16,384 walks 528
+    ("sdar-30b-a3b.train.8k", 16384, BlockDiffusion(8192, 4), 1024, 288, 240),
 ])
 def test_tile_table_counts_of_the_benchmark_cells(cell, t, window, rect, kept,
                                                   inside):
@@ -854,6 +882,14 @@ _FUSED = {
                                "kv_row_no_query_selects"),
     "mask_batch_ragged": (150, 150, 16, 16, 32, 32, True, None, 0,
                           "ragged_length"),
+    # sdar 8k's rule cut small: 2 x 96 positions in blocks of 4, three
+    # tiles a half; a half no tile divides; the label-free forward's
+    "block_diffusion": (192, 192, 32, 32, 32, 32, True,
+                        BlockDiffusion(96, 4), 0, None),
+    "block_diffusion_ragged": (200, 200, 16, 16, 32, 64, True,
+                               BlockDiffusion(100, 4), 0, None),
+    "block_causal": (100, 100, 16, 16, 32, 32, True, BlockDiffusion(0, 4),
+                     0, None),
 }
 
 
@@ -1017,3 +1053,53 @@ def test_fused_call_asks_for_the_vmem_it_holds(monkeypatch, cell):
     held = 2 * sum(blocks) + sum(scratch) + 4 * 512 * 512 * 4
     assert held <= fa._fused_vmem(resident, 512, 512, d, 128, 2, masked)
     assert held * 3 // 2 <= limit <= gm._VMEM_CAP
+
+
+@pytest.mark.parametrize("cell,t,window,dense,by_q,by_kv", [
+    ("mistral-7b.train.8k", 8192, 4096, False,
+     "371cd7f2227cb1fa:108", "34b56f811ee487af:108"),
+    ("olmoe-1b-7b.train.4k", 4096, None, False,
+     "bfb269788521f3a3:36", "0898468a0af1dd09:36"),
+    ("kimi-vl-a3b.train.8k", 8192, None, False,
+     "a89064733947bd2d:136", "efca08a8fd38c77d:136"),
+    ("keye-vl2-30b-a3b.train.16k", 16384, None, True,
+     "8980f0f628858aec:528", "5c191f03a63c97f3:528"),
+    ("phi4-mini-flash.train.8k", 8192, 512, False,
+     "131abd77b9129cb7:31", "f2c99ea089dea4bd:31"),
+    ("mellum2-12b-a2.5b.train.8k", 8192, 1024, False,
+     "22f9e8d4fd9e540e:45", "b58f4fa97d2f99fc:45"),
+])
+def test_tables_of_the_standing_cells_are_the_parents(cell, t, window, dense,
+                                                      by_q, by_kv):
+    """A rule in the window's place (PR 58) moved no entry of a causal or a
+    windowed table: sha256 of the bytes and the length of each standing
+    cell's forward and backward tables, as the parent commit built them
+    (kimi 8k's shape is zaya 8k's, ouro 8k's, qwen3-next 8k's and mellum2's
+    full layer's; olmoe 4k's is ep4's)."""
+    import hashlib
+
+    from deepspeed_tpu.ops.pallas.flash_attention import _tile_table
+
+    for want, kv in ((by_q, False), (by_kv, True)):
+        table = _tile_table(t, t, 512, 512, True, window, kv, dense)
+        assert hashlib.sha256(table.tobytes()).hexdigest()[:16] \
+            + f":{table.shape[1]}" == want
+
+
+@pytest.mark.parametrize("half,t,bq,bk", [(96, 192, 32, 32),
+                                          (100, 200, 32, 64), (0, 100, 32, 32)])
+def test_flash_forward_under_the_block_rule(half, t, bq, bk):
+    rule = BlockDiffusion(half, 4)
+    q, k, v = _qkv(2, t, 2, 32, seed=3)
+    ref = _reference_attention(q, k, v, True, 32 ** -0.5, window=rule)
+    out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                          interpret=True, force_pallas=True, window=rule)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
+                               rtol=2e-5)
+    # the reference itself against the rule pair by pair
+    seen = _block_diffusion_pairs(t, half, 4)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * 32 ** -0.5
+    probs = jax.nn.softmax(jnp.where(seen[None, None], logits, -jnp.inf), -1)
+    np.testing.assert_allclose(
+        np.asarray(ref), np.asarray(jnp.einsum("bhqk,bkhd->bqhd", probs, v)),
+        atol=2e-5, rtol=2e-5)

@@ -31,6 +31,7 @@ from deepspeed_tpu.models.ouro import OuroConfig, OuroForCausalLM
 from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
                                              Qwen3NextForCausalLM)
 from deepspeed_tpu.models.sambay import SambaYConfig, SambaYForCausalLM
+from deepspeed_tpu.models.sdar import SdarConfig, SdarForCausalLM
 from deepspeed_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
 from deepspeed_tpu.monitor import tracing
 from deepspeed_tpu.ops import pallas as names
@@ -83,6 +84,12 @@ TRAIN_SCOPES = {
     "ouro": ["ds.loss_and_grad", "ds.optimizer", "ds.embed",
              "ds.loop_stack", "ds.attn_proj", "ds.attention", "ds.mlp",
              "ds.lm_head_loss", "ds.exit_gate"],
+    # block diffusion's training pass over Mixtral's stack: ds.bd_noise is
+    # the checksum, the draws, the masking and the doubled sequence,
+    # ds.bd_gather the noised half taken before the head
+    "sdar": ["ds.loss_and_grad", "ds.optimizer", "ds.embed", "ds.bd_noise",
+             "ds.attn_proj", "ds.attention", "ds.moe_router",
+             "ds.moe_experts", "ds.bd_gather", "ds.lm_head_loss"],
 }
 #: what every family names besides: the engine's cast of the master weights,
 #: the loop over the layers, the block's two pre-norms and residual sums
@@ -111,7 +118,9 @@ def train_text():
             ("qwen3_next", Qwen3NextForCausalLM(Qwen3NextConfig.tiny(
                 remat=True))),
             ("ouro", OuroForCausalLM(OuroConfig.tiny(remat=True,
-                                                     loss_chunk=64)))):
+                                                     loss_chunk=64))),
+            ("sdar", SdarForCausalLM(SdarConfig.tiny(remat=True,
+                                                     loss_chunk=16)))):
         batch = {"input_ids": np.zeros((8, 32), np.int32),
                  "labels": np.zeros((8, 32), np.int32)}
         engine, *_ = ds.initialize(
@@ -169,8 +178,10 @@ def trace_names():
 #: span stands in no lowered step, so no cell's module name, lowered text or
 #: cache entry changes, and a trace taken before them lacks only events no
 #: reader of that time asked for; PR 56 added ``ds.loop_stack`` and
-#: ``ds.exit_gate``, which stand only in ``models/ouro.py``'s step)
-NAMES_PIN = (3, "476bbf73e3960e1c")
+#: ``ds.exit_gate``, which stand only in ``models/ouro.py``'s step; PR 58
+#: added ``ds.bd_noise`` and ``ds.bd_gather``, which stand only in
+#: ``models/sdar.py``'s step)
+NAMES_PIN = (3, "6988b08f961235e5")
 
 
 def test_names_version_is_raised_with_the_names():
@@ -184,7 +195,7 @@ def test_names_version_is_raised_with_the_names():
             "ds.ssm_mix", "ds.gmu", "ds.da_mix", "ds.layer_window",
             "ds.layer_full", "ds.rope_tables", "ds.layer_gdn", "ds.gdn_mix",
             "ds.gdn_rule", "ds.attn_gate", "ds.loop_stack",
-            "ds.exit_gate"} <= set(scopes) \
+            "ds.exit_gate", "ds.bd_noise", "ds.bd_gather"} <= set(scopes) \
         and {"counters", "init", "init_shapes", "init_params",
              "init_opt_state", "init_step", "cost_capture",
              "setup"} <= set(spans)
@@ -842,6 +853,16 @@ def test_no_other_familys_step_holds_the_loops_names(train_text):
         found = set(re.findall(r"ds\.(?:loop_stack|exit_gate)\b", text))
         assert found == ({"ds.loop_stack", "ds.exit_gate"}
                          if family == "ouro" else set()), family
+
+
+def test_no_other_familys_step_holds_block_diffusions_names(train_text):
+    """``ds.bd_noise`` and ``ds.bd_gather`` stand in ``models/sdar.py``'s
+    step alone: the other families' programs are what they were, and
+    ``NAMES_VERSION`` stays."""
+    for family, text in train_text.items():
+        found = set(re.findall(r"ds\.bd_[a-z]+\b", text))
+        assert found == ({"ds.bd_noise", "ds.bd_gather"}
+                         if family == "sdar" else set()), family
 
 
 @pytest.fixture
