@@ -57,13 +57,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops.pallas import REMAT_ATTN_OUT, REMAT_MLP, REMAT_QKV
 from .layers import (RMSNorm, apply_rotary, cross_entropy_loss,
                      dot_product_attention, head_scope, lm_head_output,
-                     model_dense, resolve_remat_policy, rotary_embedding,
-                     shift_labels)
+                     model_dense, name_if_kept, resolve_remat_policy,
+                     rotary_embedding, shift_labels)
 from .llama import LlamaConfig
 from .mixtral import (_balancing_delta, _check_held_share,
-                      _compact_hit_gauge, _held_load_gauges, _routed_experts)
+                      _compact_hit_gauge, _held_load_gauges, _routed_experts,
+                      expert_offers)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -224,6 +226,9 @@ class DeepseekV3Attention(nn.Module):
             q = jnp.concatenate([q_nope, q_rot], axis=-1)
             k = jnp.concatenate(
                 [k_nope, jnp.broadcast_to(k_rot, (B, T, H, dr))], axis=-1)
+            # as the flash kernel takes them: the replay then runs neither
+            # projection, norm, rotation nor the key's assembly
+            q, k, v = (name_if_kept(t, REMAT_QKV) for t in (q, k, v))
         out = dot_product_attention(
             q, k, v, bias=mask, causal=True,
             attention_impl=cfg.attention_impl,
@@ -262,8 +267,9 @@ class _SwiGLU(nn.Module):
         dense = lambda feats, name, row=False: model_dense(
             self.config, feats, name, row_parallel=row)
         with jax.named_scope(self.trace_scope):
-            gate = dense(self.features, "gate_proj")(x)
-            up = dense(self.features, "up_proj")(x)
+            gate = name_if_kept(dense(self.features, "gate_proj")(x),
+                                REMAT_MLP)
+            up = name_if_kept(dense(self.features, "up_proj")(x), REMAT_MLP)
             return dense(self.config.hidden_size, "down_proj", row=True)(
                 nn.silu(gate) * up)
 
@@ -335,7 +341,7 @@ class DeepseekV3Block(nn.Module):
             h = RMSNorm(eps=cfg.rms_norm_eps, name="input_layernorm")(x)
         attn = DeepseekV3Attention(cfg, name="self_attn")(h, cos, sin, mask)
         with jax.named_scope("ds.residual"):
-            x = x + attn
+            x = x + name_if_kept(attn, REMAT_ATTN_OUT)
         with jax.named_scope("ds.norm"):
             h = RMSNorm(eps=cfg.rms_norm_eps,
                         name="post_attention_layernorm")(x)
@@ -384,10 +390,14 @@ class DeepseekV3Model(nn.Module):
             attention_mask[:, None, None, :] > 0, 0.0, -1e9).astype(
                 jnp.float32)
 
-        policy = resolve_remat_policy(cfg.remat_policy)
-        remat = lambda cls: nn.remat(cls, prevent_cse=False, policy=policy) \
-            if cfg.remat else cls
         first = min(cfg.first_k_dense_replace, cfg.num_hidden_layers)
+        scanned = cfg.scan_layers and cfg.num_hidden_layers > first
+        # the scanned layers offer what they name; an unrolled layer does
+        # not: XLA merges its replay with its forward pass, so its values
+        # are held already (kimi 8k's dense layer shows no replay)
+        remat = lambda cls, offered=(): nn.remat(
+            cls, prevent_cse=False, policy=resolve_remat_policy(
+                cfg.remat_policy, offered)) if cfg.remat else cls
         rows = jnp.zeros((0, cfg.n_routed_experts), jnp.float32)
         deltas = {}
         # ds.layer_stack: what the loop over the layers costs beyond what
@@ -397,8 +407,10 @@ class DeepseekV3Model(nn.Module):
                 x, _, _ = remat(DeepseekV3Block)(cfg, dense=True,
                                                  name=f"layers_{i}")(x, cos, sin,
                                                                      mask)
-            if cfg.scan_layers and cfg.num_hidden_layers > first:
-                scan = nn.scan(remat(_ScanBlock), variable_axes={"params": 0},
+            if scanned:
+                scan = nn.scan(remat(_ScanBlock, remat_offers(
+                    cfg, x, cfg.num_hidden_layers - first)),
+                               variable_axes={"params": 0},
                                split_rngs={"params": True, "dropout": True},
                                length=cfg.num_hidden_layers - first,
                                metadata_params={})
@@ -476,3 +488,24 @@ class DeepseekV3ForCausalLM(nn.Module):
             (row, P(*L, "model", None)),
             (r"lm_head/kernel", P(None, "model")),
         ]
+
+
+def remat_offers(cfg, x, applications: int):
+    """What an expert ``DeepseekV3Block`` names, as ``DeepseekV3Model``
+    offers it to ``layers.resolve_remat_policy`` for a stream ``x [B, T,
+    hidden]`` through its ``applications`` scanned layers, costliest replay a
+    byte first (kimi 8k, ms of replay a step for a GB kept: the attention's
+    output projection 2.2 for 0.17, the shared experts' gate and up products
+    in ``_SwiGLU`` 5.0 for 0.46, q, k, v as the flash kernel takes them --
+    every head's key holds the shared rotary columns -- 5.6 for 0.67, the
+    held experts' gate and up products 2.7 for 0.35, their sorted rows 2.0
+    for 0.25)."""
+    per_column = x.shape[0] * x.shape[1] * x.dtype.itemsize * applications
+    heads = cfg.num_attention_heads * (2 * cfg.qk_head_dim + cfg.v_head_dim)
+    shared = cfg.n_shared_experts * cfg.moe_intermediate_size
+    return ((REMAT_ATTN_OUT, cfg.hidden_size * per_column),
+            (REMAT_MLP, 2 * shared * per_column),
+            (REMAT_QKV, heads * per_column),
+            *expert_offers(x, cfg.num_experts_per_tok,
+                           cfg.moe_intermediate_size, cfg.n_routed_experts,
+                           cfg.router_width, applications))

@@ -178,20 +178,20 @@ class LlamaAttention(nn.Module):
         # holds score-softmax-value only — the Pallas kernels here, their
         # XLA counterparts in layers.py — so both are measured alike
         with jax.named_scope("ds.attn_proj"):
-            norm = (lambda t, name: RMSNorm(eps=cfg.rms_norm_eps,
-                                            name=name)(t)) \
-                if cfg.qk_norm else (lambda t, name: t)
-            q = norm(dense(H * D, "q_proj", qb)(x),
-                     "q_norm").reshape(B, T, H, D)
-            k = norm(dense(Hkv * D, "k_proj", qb)(x),
-                     "k_norm").reshape(B, T, Hkv, D)
+            # a replay keeps q, k ahead of a norm: its backward reads its input
+            kept = lambda t, on=True: name_if_kept(t, REMAT_QKV) if on else t
+            norm = lambda t, name, on: RMSNorm(
+                eps=cfg.rms_norm_eps, name=name)(kept(t)) if on else t
+            per_head = getattr(cfg, "qk_norm_per_head", False)  # scales [D]
+            bare = not (cfg.qk_norm or per_head)    # named after RoPE
+            q = norm(dense(H * D, "q_proj", qb)(x), "q_norm",
+                     cfg.qk_norm).reshape(B, T, H, D)
+            k = norm(dense(Hkv * D, "k_proj", qb)(x), "k_norm",
+                     cfg.qk_norm).reshape(B, T, Hkv, D)
             v = dense(Hkv * D, "v_proj", qb)(x).reshape(B, T, Hkv, D)
-            if getattr(cfg, "qk_norm_per_head", False):
-                # an RMSNorm over each head's D columns (scales [D])
-                q = RMSNorm(eps=cfg.rms_norm_eps, name="q_norm")(q)
-                k = RMSNorm(eps=cfg.rms_norm_eps, name="k_norm")(k)
+            q, k = norm(q, "q_norm", per_head), norm(k, "k_norm", per_head)
             q, k = (apply_rotary(t, cos, sin) for t in (q, k))
-            q, k, v = (name_if_kept(t, REMAT_QKV) for t in (q, k, v))
+            q, k, v = kept(q, bare), kept(k, bare), kept(v)
         if getattr(cfg, "sa_config", None) is not None:
             # a learned indexer chooses each query's keys (training only):
             # no cache, and a third value: what the loss needs of this layer

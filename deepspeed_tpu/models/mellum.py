@@ -33,7 +33,8 @@ from .layers import (RMSNorm, cross_entropy_loss, head_scope, lm_head_output,
                      resolve_remat_policy, rotary_embedding, shift_labels,
                      yarn_rotary_embedding)
 from .mixtral import (MixtralBlock, MixtralConfig, MixtralForCausalLM,
-                      _add_stats, _extra_stats, _share_loss_and_gauges)
+                      _add_stats, _extra_stats, _share_loss_and_gauges,
+                      remat_offers)
 
 WINDOW, FULL = "window", "full"
 #: the outer scope of a block of each kind (every inner name stays what
@@ -156,7 +157,9 @@ class _Period(nn.Module):
         cfg = self.config
         x, frac_sum, prob_sum, extra_sum = carry
         block_cls = nn.remat(MixtralBlock, prevent_cse=self.lone,
-                             policy=resolve_remat_policy(cfg.remat_policy)) \
+                             policy=resolve_remat_policy(
+                                 cfg.remat_policy,
+                                 remat_offers(cfg, x, cfg.num_hidden_layers))) \
             if cfg.remat else MixtralBlock
         for i, kind in enumerate(self.kinds):
             with jax.named_scope(KIND_SCOPES[kind]):

@@ -46,7 +46,7 @@ from ..ops.pallas import grouped_matmul
 from ..parallel.topology import BATCH_AXES, get_mesh, tokens_replicated
 from ..utils.logging import log_dist
 from .layers import (RMSNorm, cross_entropy_loss, head_scope, init_kv_cache,
-                     lm_head_output,
+                     lm_head_output, name_if_kept, remat_room,
                      resolve_remat_policy, rotary_embedding, shift_labels)
 from .indexed_attention import SparseAttentionConfig
 from .llama import LlamaAttention, LlamaConfig
@@ -377,7 +377,7 @@ def _sorted_experts_fwd(x, w1, w3, w2, topk_w, order, inv, group_sizes):
     with jax.named_scope("moe_combine"):
         y = y[inv].reshape(K, N, -1).astype(jnp.float32)
         out = jnp.sum(y * topk_w[:, :, None], axis=0).astype(x.dtype)
-    return out, (xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3)
+    return out, _named(xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3)
 
 
 def _sorted_experts_bwd(res, g):
@@ -551,7 +551,7 @@ class MixtralBlock(nn.Module):
         # under sa_config a third value: the selection's statistics
         extra = dict(out[2]) if cfg.sa_config is not None else {}
         with jax.named_scope("ds.residual"):
-            x = x + attn
+            x = x + name_if_kept(attn, REMAT_ATTN_OUT)
         with jax.named_scope("ds.norm"):
             h = RMSNorm(eps=cfg.rms_norm_eps,
                         name="post_attention_layernorm")(x)
@@ -613,7 +613,8 @@ class MixtralModel(nn.Module):
         extra_sum = dict.fromkeys(
             _extra_stats(cfg, B * T * cfg.num_experts_per_tok),
             jnp.float32(0))
-        remat_policy = resolve_remat_policy(cfg.remat_policy)
+        remat_policy = resolve_remat_policy(
+            cfg.remat_policy, remat_offers(cfg, x, cfg.num_hidden_layers))
         # ds.layer_stack: what the loop over the layers costs beyond what
         # the layers' own scopes name (models/llama.py LlamaModel)
         with jax.named_scope("ds.layer_stack"):
@@ -850,10 +851,17 @@ def _compact_index(C, order, inv):
     return order[:C], jnp.minimum(inv, C - 1)
 
 
+def _in_a_branch(*args):
+    """``_sorted_experts_fwd`` as a ``cond``'s branch runs it: nothing is
+    named in there (what the rule keeps is named once, after the ``cond``)."""
+    with remat_room(0):
+        return _sorted_experts_fwd(*args)
+
+
 def _compact_experts_fwd(C, x, w1, w3, w2, topk_w, order, inv, group_sizes):
     def run(order, inv):
-        out, res = _sorted_experts_fwd(x, w1, w3, w2, topk_w, order, inv,
-                                       group_sizes)
+        out, res = _in_a_branch(x, w1, w3, w2, topk_w, order, inv,
+                                group_sizes)
         return out, res[0], res[-2], res[-1]         # xs, h1, h3
 
     def full():     # its backward pass reads none of the three
@@ -863,7 +871,8 @@ def _compact_experts_fwd(C, x, w1, w3, w2, topk_w, order, inv, group_sizes):
     out, xs, h1, h3 = jax.lax.cond(
         _fits(group_sizes, C),
         lambda: run(*_compact_index(C, order, inv)), full)
-    return out, (x, xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3)
+    return out, (x, *_named(xs, w1, w3, w2, topk_w, order, inv, group_sizes,
+                            h1, h3))
 
 
 def _compact_experts_bwd(C, res, g):
@@ -875,7 +884,7 @@ def _compact_experts_bwd(C, res, g):
              group_sizes, h1, h3), g)[:5]
 
     def full():
-        return _sorted_experts_bwd(_sorted_experts_fwd(
+        return _sorted_experts_bwd(_in_a_branch(
             x, w1, w3, w2, topk_w, order, inv, group_sizes)[1], g)[:5]
 
     return (*jax.lax.cond(_fits(group_sizes, C), compact, full),
@@ -941,3 +950,59 @@ def _share_loss_and_gauges(cfg, loss, frac_sum, extra, tokens):
     if "compact_hit" in extra:
         named["moe_compact_hit_share"] = extra["compact_hit"] / L
     return loss, named
+
+
+# -- what a remat'ed block offers its policy ---------------------------------
+# (``layers.resolve_remat_policy``: kept where the engine's budget has room;
+# the names' import stands here with them, as ``layers.py``'s do: a line
+# added above moves the frames of the grouped kernels' call sites)
+
+from ..ops.pallas import (REMAT_ATTN_OUT, REMAT_MOE_ROWS,  # noqa: E402
+                          REMAT_MOE_UP)
+from .llama import remat_offers as _llama_offers  # noqa: E402
+
+
+def _named(xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3):
+    """The residuals of ``_sorted_experts_fwd`` (``_compact_experts_fwd``:
+    the compact-shaped ones, after its ``cond``) under the two names
+    ``expert_offers`` counts, each where the rule kept it: with the gate and
+    up products kept the replay runs no grouped product, with the sorted
+    rows and the sort's index vectors no ``argsort``, scatter or gather --
+    the down product and the combine are no residuals and never replayed."""
+    xs, topk_w, order, inv, group_sizes = (
+        name_if_kept(t, REMAT_MOE_ROWS)
+        for t in (xs, topk_w, order, inv, group_sizes))
+    h1, h3 = (name_if_kept(t, REMAT_MOE_UP) for t in (h1, h3))
+    return xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3
+
+
+def expert_offers(x, K, I, held, experts, applications: int):
+    """What ``_named`` names, as a block wrapper offers it: ``[(name, bytes
+    over ``applications`` expert layers)]`` for a stream ``x [B, T, hidden]``
+    routed to ``K`` experts of width ``I``, ``held`` of the router's
+    ``experts`` here -- counted over the rows the layer sorts onto: the
+    compact buffer's where it has one, every pair's where it has none. The
+    index vectors (``order``, ``inv``, ``topk_w``: a word a pair each, and
+    ``group_sizes``) go with the rows."""
+    B, T, H = x.shape
+    pairs, item = B * T * K, x.dtype.itemsize
+    rows = _compact_rows(pairs, held, experts) or pairs
+    return ((REMAT_MOE_UP, applications * 2 * rows * I * item),
+            (REMAT_MOE_ROWS,
+             applications * (rows * H * item + 4 * (3 * pairs + held))))
+
+
+def remat_offers(cfg, x, applications: int):
+    """What a ``MixtralBlock`` names, as ``MixtralModel`` and
+    ``mellum._Period`` offer it to ``layers.resolve_remat_policy`` for a
+    stream ``x [B, T, hidden]`` through ``applications`` blocks, costliest
+    replay a byte first (mellum2 8k, ms of replay a step for a GB kept: the
+    attention's output projection 4.0 for 0.15; q, k, v as ``LlamaAttention``
+    names them and ``llama.remat_offers`` counts them 6.9 for 0.34; the
+    experts' gate and up products 2.0 for 0.23, their sorted rows 1.9 for
+    0.30)."""
+    _, qkv = _llama_offers(cfg, x, applications)
+    return ((REMAT_ATTN_OUT, applications * x.size * x.dtype.itemsize), qkv,
+            *expert_offers(x, cfg.num_experts_per_tok, cfg.expert_width,
+                           cfg.num_local_experts, cfg.router_experts,
+                           applications))

@@ -68,9 +68,16 @@ GDN_GATE_BWD = "ds_gdn_gate_bwd"
 # the engine's budget for the trace has room for, and none without one
 # (``resolve_remat_policy``); elsewhere they are the identity. The MLP's
 # gate and up products, and q, k, v after RoPE (the key/value heads before
-# ``repeat_kv``) -- ``models/llama.py``; ``models/ouro.py`` offers the same
+# ``repeat_kv``; q and k AHEAD of their norm where they have one, whose
+# backward reads its input: the replay then runs the norm and RoPE, not the
+# projections) -- ``models/llama.py``; ``models/ouro.py`` offers the same
 REMAT_MLP = "ds_mlp_gate_up"
 REMAT_QKV = "ds_attn_qkv"
+# the attention's output projection, as it joins the residual stream: the
+# replay of what follows it in the block (the second norm, the router, an
+# expert layer's input) then runs no ``o_proj`` -- ``models/mixtral.py``'s
+# and ``models/deepseek_v3.py``'s blocks
+REMAT_ATTN_OUT = "ds_attn_o_proj"
 # a delta-rule layer's (``models/qwen3_next.py``): the rule's output,
 # boundary states and chunk inverse (the replay then runs neither
 # ``ds_gdn_rule_fwd`` nor the triangular solve), ``in_proj_qkvz``'s output,
@@ -78,3 +85,9 @@ REMAT_QKV = "ds_attn_qkv"
 REMAT_GDN_RULE = "ds_gdn_rule_kept"
 REMAT_GDN_QKVZ = "ds_gdn_qkvz"
 REMAT_GDN_MIX = "ds_gdn_mix_out"
+# an expert layer's (``models/mixtral.py``, named inside the forward rules of
+# ``_sorted_experts`` / ``_compact_experts``): the sorted rows' gate and up
+# products (the replay then runs no grouped product), and the sorted rows
+# with the index vectors the sort made (no ``argsort``, scatter or gather)
+REMAT_MOE_UP = "ds_moe_gate_up"
+REMAT_MOE_ROWS = "ds_moe_rows"

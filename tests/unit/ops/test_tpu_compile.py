@@ -891,3 +891,65 @@ def test_kept_names_cost_train_8ks_step_no_more_than_the_rule_counts(
     rise = named.memory_analysis().temp_size_in_bytes \
         - plain.memory_analysis().temp_size_in_bytes
     assert 0 < rise <= REMAT_FACTOR * sum(kept.values())
+
+
+def test_kept_names_cost_kimi_8ks_step_no_more_than_the_rule_counts(
+        chip, on_v5e, monkeypatch):
+    """One dense and two scanned expert layers at kimi 8k's widths (hidden
+    2048, 16 heads of 192 / 128 over a 512 latent, 8 of 64 experts of 1408
+    held on a compact buffer of 12,288 rows, two shared experts, 8,192
+    tokens, flash and grouped kernels in) -- the cell nearest the margin:
+    its gradient compiled for one v5e with all five offered names kept
+    calls ``ds_moe_gmm`` less often (the replay's gate and up products, in
+    either branch of its ``cond``) and holds no shared-expert gate or up
+    product in the replay, and ``memory_analysis()``'s temp stands above the
+    plain step's by no more than ``REMAT_FACTOR`` times the bytes the rule
+    counted. The unrolled dense layer offers nothing (XLA merges its replay
+    with its forward pass): the offer counts the scanned layers alone."""
+    import deepspeed_tpu.ops.pallas.flash_attention as fa
+    from deepspeed_tpu.models import deepseek_v3 as dsv3
+    from deepspeed_tpu.models.layers import REMAT_FACTOR, remat_room
+
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=False))
+    T, layers = 8192, 2
+    ids = jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=chip)
+
+    def compiled(budget):
+        # a model, and so a function, of its own: jax keeps a trace
+        model = dsv3.DeepseekV3ForCausalLM(dsv3.DeepseekV3Config.kimi_vl_a3b(
+            vocab_size=4096, num_hidden_layers=1 + layers,
+            n_routed_experts=8, router_experts=64, max_position_embeddings=T,
+            attention_impl="flash", loss_chunk=1024))
+        params = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, BF16, sharding=chip),
+            jax.eval_shape(lambda: model.init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"])
+        loss = lambda params, ids: model.apply({"params": params}, ids,
+                                               labels=ids)
+        with remat_room(budget) as kept:
+            program = jax.jit(jax.grad(loss)).lower(params, ids).compile()
+        return dict(kept), program
+
+    kept, named = compiled(4 * 10 ** 9)
+    nothing, plain = compiled(0)
+    assert nothing == {}
+    column = layers * T * 2                     # bf16 bytes a column kept
+    assert kept == {
+        "ds_attn_o_proj": 2048 * column,
+        "ds_mlp_gate_up": 2 * 2 * 1408 * column,
+        "ds_attn_qkv": 16 * (192 + 192 + 128) * column,
+        "ds_moe_gate_up": layers * 2 * 12288 * 1408 * 2,
+        "ds_moe_rows": layers * (12288 * 2048 * 2 + 4 * (3 * T * 6 + 8))}
+    plain_hlo, named_hlo = plain.as_text(), named.as_text()
+    gate_up = lambda hlo: sum(
+        kernel == "ds_moe_gmm" and dims.endswith(",1408")
+        for kernel, _, dims in _grouped_products(hlo))
+    assert gate_up(named_hlo) > 0
+    assert gate_up(plain_hlo) - gate_up(named_hlo) == 4
+    shared = lambda hlo: len(re.findall(
+        r"= bf16\[8192,2816\]\S* (?:convolution|dot)\(", hlo))
+    assert shared(plain_hlo) - shared(named_hlo) == 2
+    rise = named.memory_analysis().temp_size_in_bytes \
+        - plain.memory_analysis().temp_size_in_bytes
+    assert 0 < rise <= REMAT_FACTOR * sum(kept.values())

@@ -1,8 +1,10 @@
 """What a remat'ed block keeps beyond its policy (PR 57): the rule alone
 (``models/layers.py keep_for_room``), the names under each policy (a tiny
-Llama and a tiny Qwen3-Next with the delta rule's kernels interpreted), and
-the engine's side -- the budget it states, the compile ahead of the first
-call, the check on the compiled step and the fallback."""
+Llama, a tiny Qwen3-Next with the delta rule's kernels interpreted, and --
+PR 59 -- the expert blocks: Mixtral with and without a compact row buffer,
+Mellum's period, a DeepSeek-V3 share), and the engine's side -- the budget
+it states, the compile ahead of the first call, the check on the compiled
+step and the fallback."""
 
 import collections
 import logging
@@ -13,7 +15,9 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models import layers
+from deepspeed_tpu.models import deepseek_v3 as dsv3
+from deepspeed_tpu.models import layers, mellum
+from deepspeed_tpu.models import mixtral as mx
 from deepspeed_tpu.models import qwen3_next as qn
 from deepspeed_tpu.models.layers import (REMAT_FACTOR, keep_for_room,
                                          remat_room, resolve_remat_policy)
@@ -21,12 +25,14 @@ from deepspeed_tpu.models.llama import (LlamaConfig, LlamaForCausalLM,
                                         remat_offers)
 from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
 from deepspeed_tpu.models.ouro import OuroConfig, OuroForCausalLM
+from deepspeed_tpu.models.sdar import SdarConfig, SdarForCausalLM
 from deepspeed_tpu.ops.pallas import (GDN_GATE_BWD, GDN_GATE_FWD,
                                       GDN_PREMIX_BWD, GDN_PREMIX_FWD,
                                       GDN_RULE_BWD, GDN_RULE_FWD,
-                                      REMAT_GDN_MIX, REMAT_GDN_QKVZ,
-                                      REMAT_GDN_RULE, REMAT_MLP, REMAT_QKV,
-                                      gdn_mix, gdn_rule)
+                                      REMAT_ATTN_OUT, REMAT_GDN_MIX,
+                                      REMAT_GDN_QKVZ, REMAT_GDN_RULE,
+                                      REMAT_MLP, REMAT_MOE_ROWS, REMAT_MOE_UP,
+                                      REMAT_QKV, gdn_mix, gdn_rule)
 from deepspeed_tpu.parallel.topology import build_mesh
 from deepspeed_tpu.runtime import engine as engine_module
 from deepspeed_tpu.utils.logging import logger
@@ -90,13 +96,85 @@ def test_with_nothing_kept_the_policy_answers_as_before(policy):
                                                           name="other"))
 
 
-def test_llama_offers_its_two_names_with_their_bytes():
+def _llama_offer():
     cfg = LlamaConfig.tiny()                     # 4 heads, 2 kv heads of 16
     x = jax.ShapeDtypeStruct((2, 32, cfg.hidden_size), jnp.bfloat16)
     tokens = 2 * 32 * 2                          # bf16 bytes a column
-    assert remat_offers(cfg, x, 3) == (
+    return remat_offers(cfg, x, 3), (
         (REMAT_MLP, 3 * 2 * cfg.intermediate_size * tokens),
         (REMAT_QKV, 3 * (4 + 2 + 2) * cfg.head_dim * tokens))
+
+
+def _mixtral_offer():
+    """No compact buffer: every one of the 128 pairs has a sorted row (the
+    index vectors: three words a pair and one an expert)."""
+    cfg = MixtralConfig.tiny()      # hidden 32, 4 / 2 heads of 8, 4 experts
+    x = jax.ShapeDtypeStruct((2, 32, 32), jnp.bfloat16)     # of 64, top-2
+    return mx.remat_offers(cfg, x, 3), (
+        (REMAT_ATTN_OUT, 3 * 32 * 2 * 32 * 2),
+        (REMAT_QKV, 3 * (4 + 2 + 2) * 8 * 2 * 32 * 2),
+        (REMAT_MOE_UP, 3 * 2 * 128 * 64 * 2),
+        (REMAT_MOE_ROWS, 3 * (128 * 32 * 2 + 4 * (3 * 128 + 4))))
+
+
+def _compact_offer():
+    """2 of 16 experts held: the 1,024 pairs sort onto ``_compact_rows``'
+    512 rows, and the offer counts those (the index vectors stay a pair's)."""
+    cfg = MixtralConfig.tiny(num_local_experts=2, router_experts=16)
+    x = jax.ShapeDtypeStruct((1, 512, 32), jnp.float32)
+    assert mx._compact_rows(1024, 2, 16) == 512
+    return mx.remat_offers(cfg, x, 2), (
+        (REMAT_ATTN_OUT, 2 * 32 * 512 * 4),
+        (REMAT_QKV, 2 * (4 + 2 + 2) * 8 * 512 * 4),
+        (REMAT_MOE_UP, 2 * 2 * 512 * 64 * 4),
+        (REMAT_MOE_ROWS, 2 * (512 * 32 * 4 + 4 * (3 * 1024 + 2))))
+
+
+def _mellum_offer():
+    """``_Period`` offers ``MixtralBlock``'s names over every block of every
+    period (the tiny model: two periods of four)."""
+    cfg = mellum.MellumConfig.tiny(remat=True)
+    model = mellum.MellumForCausalLM(cfg)
+    ids = jnp.zeros((1, 16), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
+    with remat_room(10 ** 9) as kept:
+        jax.make_jaxpr(jax.grad(
+            lambda p: model.apply({"params": p}, ids, labels=ids)))(params)
+    x = jax.ShapeDtypeStruct((1, 16, cfg.hidden_size), jnp.float32)
+    assert cfg.num_hidden_layers == 8
+    return tuple(kept.items()), mx.remat_offers(cfg, x, 8)
+
+
+def _deepseek_offer():
+    """One unrolled dense layer and two scanned expert layers (8 experts of
+    16, top-3, two shared experts of 16; 4 heads of 8 + 4 and 8): the model
+    offers what the scanned layers name, over those two alone -- XLA merges
+    an unrolled layer's replay with its forward pass."""
+    cfg = dsv3.DeepseekV3Config.tiny(remat=True)
+    model = dsv3.DeepseekV3ForCausalLM(cfg)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
+    with remat_room(10 ** 9) as kept:
+        jax.make_jaxpr(jax.grad(
+            lambda p: model.apply({"params": p}, ids, labels=ids)))(params)
+    tokens, pairs = 2 * 16 * 4, 2 * 16 * 3      # float32 bytes a column
+    return tuple(kept.items()), (
+        (REMAT_ATTN_OUT, 2 * 32 * tokens),
+        (REMAT_MLP, 2 * 2 * 2 * 16 * tokens),
+        (REMAT_QKV, 2 * 4 * (12 + 12 + 8) * tokens),
+        (REMAT_MOE_UP, 2 * 2 * pairs * 16 * 4),
+        (REMAT_MOE_ROWS, 2 * (pairs * 32 * 4 + 4 * (3 * pairs + 8))))
+
+
+@pytest.mark.parametrize("offer", [
+    _llama_offer, _mixtral_offer, _compact_offer, _mellum_offer,
+    _deepseek_offer], ids=["llama", "mixtral", "mixtral_compact",
+                           "mellum_period", "deepseek_v3"])
+def test_a_block_offers_its_names_with_their_bytes(offer):
+    got, want = offer()
+    assert got == want
 
 
 # -- the names under each policy: a tiny Llama -------------------------------
@@ -179,25 +257,205 @@ def test_a_budget_between_the_two_keeps_the_first_alone():
     assert _products_into(named.jaxpr, INTER) == 3
 
 
-@pytest.mark.parametrize("build", [
-    lambda: MixtralForCausalLM(MixtralConfig.tiny(remat=True)),
-    lambda: OuroForCausalLM(OuroConfig.tiny(remat=True)),
-], ids=["mixtral", "ouro_refused"])
-def test_a_model_that_offers_nothing_or_is_refused_holds_no_name(build):
-    """``MixtralBlock`` runs ``LlamaAttention`` and offers nothing; ouro
-    offers ``llama.py``'s two names and a small budget refuses both: neither
-    gradient holds a ``name`` equation of them, whatever the room."""
+# -- the names under each policy: the expert blocks (PR 59) ------------------
+
+def _expert_model(family):
+    """``(model, ids)`` of a tiny remat'ed expert model: Mixtral whole (no
+    compact buffer), Mixtral holding 2 of 16 experts (a compact buffer of
+    512 rows for 1,024 pairs), Mellum's two periods, a DeepSeek-V3 share
+    with one dense layer ahead of its scanned expert layers."""
+    rng = np.random.RandomState(0)
+    if family == "mixtral":
+        return MixtralForCausalLM(MixtralConfig.tiny(remat=True)), \
+            rng.randint(0, 128, (2, 32))
+    if family == "mixtral_compact":
+        return MixtralForCausalLM(MixtralConfig.tiny(
+            remat=True, num_local_experts=2, router_experts=16)), \
+            rng.randint(0, 128, (1, 512))
+    if family == "mellum":      # as published: an RMSNorm a head on q and k
+        return mellum.MellumForCausalLM(mellum.MellumConfig.tiny(
+            remat=True, qk_norm_per_head=True)), rng.randint(0, 128, (2, 16))
+    return dsv3.DeepseekV3ForCausalLM(dsv3.DeepseekV3Config.tiny(
+        remat=True)), rng.randint(0, 128, (2, 16))
+
+
+def _expert_grad(family):
+    model, ids = _expert_model(family)
+    ids = jnp.asarray(ids)
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    loss = lambda p: model.apply({"params": p}, ids, labels=ids)
+    return jax.value_and_grad(loss), params
+
+
+def _primitives(jaxpr, found=None):
+    """``{primitive: equations}`` of a jaxpr, the sub-jaxprs among them."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        found[eqn.primitive.name] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, found)
+    return found
+
+
+#: ``(remat'ed layer bodies that offer, projections a body's replay loses,
+#: whether the layer has a compact buffer)``: q, k, v and the attention's
+#: output -- of a DeepSeek-V3 expert layer q, the keys' and values' expansion,
+#: the output and the shared experts' gate and up (the latent's own projection
+#: stays: the expansion's weight gradient reads its norm). Mellum's q and k
+#: have a norm a head, whose backward reads its input: they are named ahead
+#: of it, or the replay would keep both projections
+EXPERT_FAMILIES = {"mixtral": (1, 4, False), "mixtral_compact": (1, 4, True),
+                   "mellum": (4, 4, False), "deepseek_v3": (1, 5, False)}
+
+
+@pytest.mark.parametrize("family", sorted(EXPERT_FAMILIES))
+def test_kept_names_take_the_expert_layer_out_of_the_replay(family):
+    """With the expert layer's two names kept a layer body's replay holds no
+    grouped product (on this CPU ``ragged_dot``; ``ds_moe_gmm`` on the chip:
+    ``test_tpu_compile.py``), no ``argsort`` and no scatter of its inverse,
+    and where the layer has a compact buffer no ``cond`` -- the forward's
+    three products and the backward's six stand, one sort and one scatter
+    for the forward's; q, k, v and the dense products beside them the
+    same. Loss and every gradient are, bit for bit, those of
+    the step that keeps nothing."""
+    expert_bodies, projections, compact = EXPERT_FAMILIES[family]
+    fn, params = _expert_grad(family)
+    plain = _primitives(jax.make_jaxpr(fn)(params).jaxpr)
+    want = jax.jit(fn)(params)
+    fn, _ = _expert_grad(family)        # jax keeps a function's trace
+    with remat_room(10 ** 9) as kept:
+        named = _primitives(jax.make_jaxpr(fn)(params).jaxpr)
+        got = jax.jit(fn)(params)
+    assert {REMAT_ATTN_OUT, REMAT_QKV, REMAT_MOE_UP,
+            REMAT_MOE_ROWS} <= set(kept)
+    assert (REMAT_MLP in kept) == (family == "deepseek_v3")
+    # the replay's gate and up products (in each branch of its ``cond``)
+    assert plain["ragged_dot_general"] - named["ragged_dot_general"] == \
+        (4 if compact else 2) * expert_bodies
+    if not compact:     # the forward's three and the backward's six stand
+        assert named["ragged_dot_general"] == 9 * expert_bodies
+    for moved in ("sort", "scatter"):   # the argsort and its inverse
+        assert plain[moved] == 2 * expert_bodies
+        assert named[moved] == expert_bodies
+    assert (plain["cond"], named["cond"]) == ((3, 2) if compact else (0, 0))
+    assert plain["dot_general"] - named["dot_general"] == \
+        expert_bodies * projections
+    assert plain["name"] == 0
+    # (Mellum's replay runs the heads' norm and RoPE from the kept q and k,
+    # which XLA:CPU fuses otherwise than beside the projections: ulps)
+    tight = dict(rtol=0, atol=0) if family != "mellum" else \
+        dict(rtol=2e-5, atol=1e-7)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tight)
+
+
+@pytest.mark.parametrize("family", ["mixtral", "deepseek_v3"])
+def test_a_budget_between_two_expert_names_keeps_the_first_ones(family):
+    """A budget that ends between the expert layer's two names keeps the
+    gate and up products and every name ahead of them, and not the sorted
+    rows: the replay sorts and scatters as before and runs no grouped
+    product."""
+    model, ids = _expert_model(family)
+    x = jax.ShapeDtypeStruct((*ids.shape, model.config.hidden_size),
+                             jnp.float32)
+    offered = mx.remat_offers(model.config, x, 2) if family == "mixtral" \
+        else dsv3.remat_offers(model.config, x, 2)
+    assert [n for n, _ in offered[-2:]] == [REMAT_MOE_UP, REMAT_MOE_ROWS]
+    fn, params = _expert_grad(family)
+    with remat_room(REMAT_FACTOR * sum(b for _, b in offered[:-1])) as kept:
+        named = jax.make_jaxpr(fn)(params)
+    assert list(kept) == [n for n, _ in offered[:-1]]
+    assert f"name={REMAT_MOE_ROWS}" not in str(named)
+    found = _primitives(named.jaxpr)
+    assert found["ragged_dot_general"] == 9 and found["sort"] == 2
+
+
+N, K, E, G, H, I = 1024, 3, 32, 4, 16, 24   # test_moe_compact.py's layer
+
+
+@pytest.mark.parametrize("held_pairs", [None, 1023, 1024, N * K],
+                         ids=["level", "at_capacity", "overflow_by_one",
+                              "all_held"])
+def test_an_overflowing_step_trains_alike_with_the_names_kept(held_pairs):
+    """Two scanned, remat'ed expert layers over a compact buffer of 1,024
+    rows: whether the held pairs fit it or not (then the backward computes
+    its own forward again over every row: the compact-shaped values the rule
+    kept are not read), the gradients with both names kept are those of the
+    layer that keeps nothing, bit for bit."""
+    assert mx._compact_rows(N * K, G, E) == 1024
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    x = jax.random.normal(ks[0], (N, H))
+    w1, w3 = (jax.random.normal(k, (2, G, H, I)) / H ** 0.5 for k in ks[1:3])
+    w2 = jax.random.normal(ks[3], (2, G, I, H)) / I ** 0.5
+    topk_w = jax.random.uniform(ks[4], (N, K), minval=0.1, maxval=1.0)
+
+    def routing(layer):
+        if held_pairs is None:
+            return jax.lax.top_k(jax.random.uniform(
+                jax.random.PRNGKey(7 + layer), (N, E)), K)[1].astype(jnp.int32)
+        slot = jnp.arange(N * K).reshape(N, K)
+        n, k = slot // K, slot % K
+        return jnp.where(slot < held_pairs, (n + k + layer) % G,
+                         G + (n * K + k + layer) % (E - G)).astype(jnp.int32)
+
+    idx = jnp.stack([routing(0), routing(1)])
+    stream = jax.ShapeDtypeStruct((1, N, H), x.dtype)
+
+    def grads(budget):
+        def loss(x, w1, w2, w3, topk_w):
+            def body(x, layer):
+                w1, w2, w3, idx = layer
+                out, _ = mx._routed_experts(x, w1, w2, w3, topk_w, idx, 0, E)
+                return x + out, None
+
+            with remat_room(budget) as kept:
+                policy = resolve_remat_policy(
+                    "nothing", mx.expert_offers(stream, K, I, G, E, 2))
+                y, _ = jax.lax.scan(jax.checkpoint(
+                    body, prevent_cse=False, policy=policy), x,
+                    (w1, w2, w3, idx))
+                assert len(kept) == (2 if budget else 0)
+            return jnp.mean(y ** 2)
+
+        fn = jax.value_and_grad(loss, argnums=tuple(range(5)))
+        with remat_room(budget):        # the trace of the gradient
+            found = _primitives(jax.make_jaxpr(fn)(
+                x, w1, w2, w3, topk_w).jaxpr)
+            return found, jax.jit(fn)(x, w1, w2, w3, topk_w)
+
+    (plain, want), (named, got) = grads(0), grads(10 ** 9)
+    assert (plain["cond"], named["cond"]) == (3, 2)
+    assert plain["sort"] == 2 and named["sort"] == 1
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("build,budget,names", [
+    (lambda: MixtralForCausalLM(MixtralConfig.tiny(remat=True)), 10 ** 9,
+     {REMAT_ATTN_OUT, REMAT_QKV, REMAT_MOE_UP, REMAT_MOE_ROWS}),
+    (lambda: MixtralForCausalLM(MixtralConfig.tiny(remat=True)), 0, set()),
+    (lambda: OuroForCausalLM(OuroConfig.tiny(remat=True)), 64, set()),
+], ids=["mixtral", "mixtral_no_room", "ouro_refused"])
+def test_a_gradient_holds_the_names_its_room_kept_and_no_other(build, budget,
+                                                               names):
+    """``MixtralBlock`` offers its attention's output, the q, k, v that
+    ``LlamaAttention`` names and its expert layer's two names: under a room
+    its gradient holds all four, under none not one; ouro offers ``llama.py``'s two names and a
+    small budget refuses both."""
     model = build()
     ids = jnp.zeros((1, 16), jnp.int32)
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
     loss = lambda p: model.apply({"params": p}, ids, labels=ids)
     loss_of = lambda p: loss(p)[0] if isinstance(loss(p), tuple) else loss(p)
-    budget = 10 ** 9 if isinstance(model, MixtralForCausalLM) else 64
     with remat_room(budget) as kept:
         text = str(jax.make_jaxpr(jax.grad(loss_of))(params))
-    assert kept == {}
-    assert REMAT_MLP not in text and REMAT_QKV not in text
+    assert set(kept) == names
+    for name in (REMAT_MLP, REMAT_QKV, REMAT_ATTN_OUT, REMAT_MOE_UP,
+                 REMAT_MOE_ROWS):
+        assert (f"name={name}" in text) == (name in names)
 
 
 def test_ouro_counts_every_layer_of_every_pass():
@@ -265,7 +523,8 @@ def test_kept_names_take_the_rule_out_of_qwen3_nexts_replay(
         named = jax.make_jaxpr(named_fn)(params)
         if policy == "nothing":
             got = jax.jit(named_fn)(params)
-    assert list(kept) == [REMAT_GDN_RULE, REMAT_GDN_QKVZ, REMAT_GDN_MIX]
+    assert list(kept) == [REMAT_GDN_RULE, REMAT_GDN_QKVZ, REMAT_GDN_MIX,
+                          REMAT_MOE_UP, REMAT_MOE_ROWS]
     forward = {GDN_RULE_FWD, GDN_PREMIX_FWD, GDN_GATE_FWD}
     backward = {GDN_RULE_BWD, GDN_PREMIX_BWD, GDN_GATE_BWD}
     # a layer: the forward and the replay (the backward applies the
@@ -288,7 +547,12 @@ def test_qwen3_next_keeps_the_rule_first_and_the_mixer_last(
     x = jax.ShapeDtypeStruct((1, 16, cfg.hidden_size), jnp.float32)
     offered = qn.remat_offers(cfg, x, qn.period_kinds(cfg))
     assert [n for n, _ in offered] == [REMAT_GDN_RULE, REMAT_GDN_QKVZ,
-                                       REMAT_GDN_MIX]
+                                       REMAT_GDN_MIX, REMAT_MOE_UP,
+                                       REMAT_MOE_ROWS]
+    # after its own three, what the expert layer names over all four layers
+    assert offered[3:] == mx.expert_offers(
+        x, cfg.num_experts_per_tok, cfg.expert_width, cfg.num_local_experts,
+        cfg.router_experts, 4)
     # three layers' o [T, 4 heads of 8], boundary states [4, 2 chunks, 8, 8]
     # and inverse [4, 2, 8, 8] in float32
     assert offered[0][1] == 3 * (16 * 32 * 4 + 2 * 4 * 2 * 8 * 8 * 4)
@@ -300,9 +564,11 @@ def test_qwen3_next_keeps_the_rule_first_and_the_mixer_last(
 
 
 def test_off_the_kernels_a_delta_rule_layer_offers_nothing():
+    """Nothing of the rule's: the expert layer's two names stand."""
     cfg = qn.Qwen3NextConfig.tiny()
     x = jax.ShapeDtypeStruct((1, 32, cfg.hidden_size), jnp.float32)
-    assert qn.remat_offers(cfg, x, qn.period_kinds(cfg)) == ()
+    assert [n for n, _ in qn.remat_offers(cfg, x, qn.period_kinds(cfg))] == \
+        [REMAT_MOE_UP, REMAT_MOE_ROWS]
 
 
 # -- the engine --------------------------------------------------------------
@@ -340,6 +606,11 @@ def _lowered_text(engine):
 TINY = {
     "llama": lambda: LlamaForCausalLM(LlamaConfig.tiny(remat=True)),
     "mixtral": lambda: MixtralForCausalLM(MixtralConfig.tiny(remat=True)),
+    "mellum": lambda: mellum.MellumForCausalLM(
+        mellum.MellumConfig.tiny(remat=True)),
+    "deepseek_v3": lambda: dsv3.DeepseekV3ForCausalLM(
+        dsv3.DeepseekV3Config.tiny(remat=True)),
+    "sdar": lambda: SdarForCausalLM(SdarConfig.tiny(remat=True)),
     "ouro": lambda: OuroForCausalLM(OuroConfig.tiny(remat=True)),
     "qwen3_next": lambda: qn.Qwen3NextForCausalLM(
         qn.Qwen3NextConfig.tiny(num_hidden_layers=4, remat=True)),
@@ -357,7 +628,7 @@ def test_on_a_cpu_the_lowered_step_names_nothing(family, monkeypatch):
     text = _lowered_text(engine)
     from deepspeed_tpu.models import llama
 
-    for module in (llama, qn):
+    for module in (llama, qn, mx, dsv3):
         monkeypatch.setattr(module, "name_if_kept", lambda x, name: x)
     assert _lowered_text(_engine(TINY[family]())) == text
     if family != "llama":       # one family's step runs: the record is the

@@ -558,6 +558,17 @@ def _offering_llama():
         params
 
 
+def _offering_mixtral():
+    from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
+
+    model = MixtralForCausalLM(MixtralConfig.tiny(remat=True))
+    ids = jnp.zeros((1, 16), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
+    return jax.grad(lambda p: model.apply({"params": p}, ids, labels=ids)), \
+        params
+
+
 def _offering_qwen3_next():
     """The delta rule's kernels as on the chip (the choosers answered with a
     tiling, the kernels lowered, not interpreted: nothing runs)."""
@@ -586,6 +597,9 @@ def _offering_qwen3_next():
 OFFERED_NAMES = {
     names.REMAT_MLP: ("ds_mlp_gate_up", _offering_llama),
     names.REMAT_QKV: ("ds_attn_qkv", _offering_llama),
+    names.REMAT_ATTN_OUT: ("ds_attn_o_proj", _offering_mixtral),
+    names.REMAT_MOE_UP: ("ds_moe_gate_up", _offering_mixtral),
+    names.REMAT_MOE_ROWS: ("ds_moe_rows", _offering_mixtral),
     names.REMAT_GDN_RULE: ("ds_gdn_rule_kept", _offering_qwen3_next),
     names.REMAT_GDN_QKVZ: ("ds_gdn_qkvz", _offering_qwen3_next),
     names.REMAT_GDN_MIX: ("ds_gdn_mix_out", _offering_qwen3_next),
