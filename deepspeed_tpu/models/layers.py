@@ -11,13 +11,16 @@ dtype (bf16) before apply. Shapes are static; batch/heads stay multiples of
 the lane layout so XLA tiles cleanly onto the 128x128 MXU.
 """
 
+import contextlib
 import functools
+import threading
 from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 
 def resolve_remat_policy(name: str, offered=()):
@@ -32,9 +35,9 @@ def resolve_remat_policy(name: str, offered=()):
     loss's row statistics (``ds_sa_kl_rows``) the same. Everything else gets
     the named policy's answer -- but for what the model file OFFERS:
     ``offered`` is its ordered ``[(checkpoint_name, bytes over every layer
-    application)]``, costliest replay first, and ``keep_for_room`` (this
-    file's end) keeps as many as the budget the ENGINE states for the trace
-    has room for (``remat_room``: the device's free memory before the step
+    application)]``, costliest replay first, and ``keep_for_room`` keeps as
+    many as the budget the ENGINE states for the trace has room for
+    (``remat_room``: the device's free memory before the step
     is built; the engine checks the compiled step and takes the choice back
     where it was wrong). Nothing is set by hand. With no budget -- a bare
     ``model.apply``, a CPU, a mesh of several devices -- nothing offered is
@@ -1053,11 +1056,6 @@ def lm_head_output(parent, cfg, hidden, labels, cache, head_bias=False):
                                             chunk=cfg.loss_chunk)
 
 
-# New helpers go BELOW this line: a Mosaic call's payload holds the line numbers
-# of the frames that call it, so code moved above ``dot_product_attention``
-# changes every flash cell's lowered step and compile-cache key (PERF.md
-# section 6, PR 35).
-
 def apply_rotary_interleaved(x, cos, sin):
     """GPT-J-style rotate_every_two: pairs are (x[2i], x[2i+1]), not the
     rotate-half (x[i], x[i+D/2]) convention."""
@@ -1150,13 +1148,6 @@ def yarn_rotary_embedding(positions: jnp.ndarray, head_dim: int, theta: float,
 
 
 # -- what a remat'ed block keeps beyond its policy ---------------------------
-# at the file's end, its two imports too: a line added above moves the frames
-# of every flash kernel's call sites, which its compile-cache key holds
-
-import contextlib  # noqa: E402
-import threading  # noqa: E402
-
-from jax.ad_checkpoint import checkpoint_name  # noqa: E402
 
 #: the rule's constants (PERF.md section 3 has the readings they were fixed
 #: on; they are not options). A named byte costs the compiled step up to

@@ -145,15 +145,6 @@ class LlamaConfig:
             rope_theta=10000.0, sliding_window=4096), **over})
 
     @staticmethod
-    def llama_400m(**over):
-        """A ~400M preset of no published model, named by the README's
-        example alone (a line removed here moves every frame below it)."""
-        return LlamaConfig(**{**dict(
-            vocab_size=32000, hidden_size=1024, intermediate_size=2816,
-            num_hidden_layers=24, num_attention_heads=16,
-            num_key_value_heads=16, max_position_embeddings=1024), **over})
-
-    @staticmethod
     def tiny(**over):
         return LlamaConfig(**{**dict(
             vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -597,8 +588,7 @@ def remat_offers(cfg, x, applications: int):
     it to ``layers.resolve_remat_policy``: ``[(name, bytes over
     ``applications`` layer applications)]`` for a stream ``x [B, T, hidden]``,
     costliest replay a byte first (train.8k: the gate and up products replay
-    in 21.3 ms a step for 0.94 GB, q / k / v with RoPE in 4.7 for 0.20). At
-    the file's end: the frames of the kernels' call sites keep their lines."""
+    in 21.3 ms a step for 0.94 GB, q / k / v with RoPE in 4.7 for 0.20)."""
     per_value = x.shape[0] * x.shape[1] * x.dtype.itemsize * applications
     heads = cfg.num_attention_heads + 2 * cfg.num_key_value_heads
     return ((REMAT_MLP, 2 * cfg.intermediate_size * per_value),

@@ -42,7 +42,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.pallas import grouped_matmul
+from ..ops.pallas import (REMAT_ATTN_OUT, REMAT_MOE_ROWS, REMAT_MOE_UP,
+                          grouped_matmul)
 from ..parallel.topology import BATCH_AXES, get_mesh, tokens_replicated
 from ..utils.logging import log_dist
 from .layers import (RMSNorm, cross_entropy_loss, head_scope, init_kv_cache,
@@ -50,6 +51,7 @@ from .layers import (RMSNorm, cross_entropy_loss, head_scope, init_kv_cache,
                      resolve_remat_policy, rotary_embedding, shift_labels)
 from .indexed_attention import SparseAttentionConfig
 from .llama import LlamaAttention, LlamaConfig
+from .llama import remat_offers as _llama_offers
 
 
 def _expert_axis_active() -> bool:
@@ -756,8 +758,7 @@ class MixtralForCausalLM(nn.Module):
 # -- one chip's share of a wider router's experts ---------------------------
 # ``deepseek_v3.py`` and ``zaya.py`` hold ``held`` experts, ``first ..`` of
 # the ``experts`` the router scores, and call ``_routed_experts`` with that
-# ``first``. (At the file's end: a Mosaic call's payload holds the line
-# numbers of the frames above it, PERF.md section 6, PR 35.)
+# ``first``.
 
 def _check_held_share(first, held, experts):
     if not 0 <= first <= experts - held:
@@ -953,14 +954,7 @@ def _share_loss_and_gauges(cfg, loss, frac_sum, extra, tokens):
 
 
 # -- what a remat'ed block offers its policy ---------------------------------
-# (``layers.resolve_remat_policy``: kept where the engine's budget has room;
-# the names' import stands here with them, as ``layers.py``'s do: a line
-# added above moves the frames of the grouped kernels' call sites)
-
-from ..ops.pallas import (REMAT_ATTN_OUT, REMAT_MOE_ROWS,  # noqa: E402
-                          REMAT_MOE_UP)
-from .llama import remat_offers as _llama_offers  # noqa: E402
-
+# (``layers.resolve_remat_policy``: kept where the engine's budget has room)
 
 def _named(xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3):
     """The residuals of ``_sorted_experts_fwd`` (``_compact_experts_fwd``:

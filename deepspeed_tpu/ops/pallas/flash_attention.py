@@ -25,7 +25,7 @@ A mask can also be DATA: ``flash_attention(..., mask=[B, Tq, Tk] int8)``, one
 set of visible keys a query shared by the heads (a learned selection,
 ``models/indexed_attention.py``). The table is then DATA too: the rule's
 entries that hold a selected pair (``mask_tiles``), compacted on the device
-(``_mask_tile_table``, at the file's end) and prefetched like the constant,
+(``_mask_tile_table``) and prefetched like the constant,
 the grid's last dimension their traced count; a kept tile reads its block of
 the mask in place of the iota rule. That path differentiates (its own
 ``custom_vjp``, the mask one more operand) and hands out the log-sum-exp too.
@@ -86,7 +86,7 @@ def _tile_table(tq, tk, block_q, block_k, causal, window, by_kv=False,
     over its REAL rows and columns; the mask keeps ``d >= 0`` (causal) and
     ``d < window``, so a tile holds a visible entry iff the two ranges meet
     and is wholly visible iff one lies in the other (and it has no padded
-    tail). A ``window`` that is a ``BlockDiffusion`` (file's end) is a RULE
+    tail). A ``window`` that is a ``BlockDiffusion`` is a RULE
     that stands for both: it says which tiles it keeps and which whole.
     Entries are ordered by q row, keys ascending (``by_kv``: by kv row,
     queries ascending); ``_FIRST`` / ``_LAST`` mark a row's ends. Every row
@@ -649,7 +649,7 @@ def _reference_attention(q, k, v, causal, sm_scale, window=None,
         logits = jnp.where(tril[None, None], logits, NEG_INF)
     if window is not None:
         # a sliding window's width, or a rule that stands for causality
-        # too (``BlockDiffusion``, at the file's end)
+        # too (``BlockDiffusion``)
         wmask = window_mask(window, Tq, Tk)
         logits = jnp.where(wmask[None, None], logits, NEG_INF)
     if key_mask is not None:
@@ -724,8 +724,7 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = No
 
 
 # ---------------------------------------------------------------------------
-# the tile table under a mask that is data (below the rest of the file: a
-# kernel's cache key holds the line numbers of the frames that call it)
+# the tile table under a mask that is data
 # ---------------------------------------------------------------------------
 
 

@@ -1576,8 +1576,7 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         find it (the benchmark's ``setup.*`` readers read it there), and a
         ring event. Once after the first step, which ends set-up (the record
         stands from there on), and on the first step of every profiler
-        session. At the class's end: the frames of the step's call sites
-        keep their lines."""
+        session."""
         if first:
             self.setup.close()
         record = self.setup.record(step)

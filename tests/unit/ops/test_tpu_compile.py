@@ -351,6 +351,61 @@ def test_kernel_compiles_for_v5e(chip, name):
         "the compiled program holds no Mosaic kernel"
 
 
+#: one three-line caller of ``flash_attention``, as it stands and with blank
+#: lines and indentation above and inside the call
+_CALLER = """\
+import jax.numpy as jnp
+from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+def loss(q, k, v):
+    out = flash_attention(q, k, v, causal=True, interpret=False)
+    return out.astype(jnp.float32).sum()
+"""
+_CALLER_MOVED = """\
+import jax.numpy as jnp
+from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+
+def loss(q, k, v):
+    out = (
+
+           flash_attention(q, k, v, causal=True, interpret=False))
+    return out.astype(jnp.float32).sum()
+"""
+
+
+def _lowered_from(path, source, chip):
+    """The lowered text, Mosaic payloads in, of the tiny flash gradient
+    called from ``source`` as the module at ``path``."""
+    path.write_text(source)
+    module = {}
+    exec(compile(source, str(path), "exec"), module)
+    leaf = jax.ShapeDtypeStruct((1, 1024, 2, D), BF16, sharding=chip)
+    return jax.jit(jax.grad(module["loss"], argnums=(0, 1, 2))).lower(
+        leaf, leaf, leaf).as_text()
+
+
+def test_a_moved_caller_line_keeps_the_lowered_kernel(chip, tmp_path):
+    """Where the compile cache is configured, a kernel's Mosaic payload --
+    part of the cache's key -- holds no frame of the code that calls it: one
+    caller at other lines and columns of ONE path lowers to the same text,
+    so a refactor's byte-for-byte proof survives a moved line. Under jax's
+    own default the two differ, which is what the setting is for: should
+    that half fail, jax changed what the setting means."""
+    from deepspeed_tpu.utils.jax_compat import configure_compile_cache
+
+    configure_compile_cache()
+    path = tmp_path / "caller.py"
+    text = _lowered_from(path, _CALLER, chip)
+    assert "tpu_custom_call" in text
+    assert _lowered_from(path, _CALLER_MOVED, chip) == text
+    jax.config.update("jax_traceback_in_locations_limit", 10)  # the default
+    try:
+        assert _lowered_from(path, _CALLER, chip) \
+            != _lowered_from(path, _CALLER_MOVED, chip)
+    finally:
+        configure_compile_cache()
+
+
 def test_delta_rule_layer_is_two_kernels_and_no_loop_on_one_v5e(chip, on_v5e):
     """``models/qwen3_next.py``'s delta-rule mixer at the published widths
     (hidden 2,048, 16 key and 32 value heads of 128, chunks of 64) over

@@ -337,6 +337,50 @@ def test_unrolled_periods_are_the_scanned_ones():
         model.apply({"params": params}, IDS), rtol=1e-5, atol=1e-6)
 
 
+#: (leaves; sha256[:16] of every leaf's "path shape dtype"; the sum of every
+#: |weight|) and the second period's first router weights of ``block_1``, of
+#: the tiny presets as two periods of two kinds at ``PRNGKey(11)``: recorded
+#: on PR 61's first commit, when each file still scanned a ``_Period`` of
+#: its own
+TREES = {
+    "mellum": (23, "5f7577acaaf180af", 12481.355006518836),
+    "qwen3_next": (36, "46cd00115af43436", 12821.14223604188),
+}
+GATE = [-0.2554759979248047, 0.21863120794296265, 0.042051441967487335,
+        0.08249568194150925]
+
+
+@pytest.mark.parametrize("family", sorted(TREES))
+def test_the_shared_scan_keeps_each_models_parameter_tree(family):
+    """``layers.scan_periods`` lays the two stacks' parameters where their
+    own scans did (``periods/block_<i>/...``, the periods stacked on axis 0:
+    partition rules, frozen parameters, checkpoints and the cells' seeded
+    weights go by these paths) and draws them from the same keys."""
+    import hashlib
+
+    from deepspeed_tpu.models import qwen3_next
+
+    if family == "mellum":
+        model = MellumForCausalLM(MellumConfig.tiny(
+            embed_init_std=1.0, num_hidden_layers=4, full_attention_period=2))
+    else:
+        model = qwen3_next.Qwen3NextForCausalLM(qwen3_next.Qwen3NextConfig.tiny(
+            embed_init_std=1.0, num_hidden_layers=4,
+            full_attention_interval=2))
+    params = jax.jit(model.init)(jax.random.PRNGKey(11),
+                                 jnp.zeros((1, 16), jnp.int32))["params"]
+    leaves = jax.tree_util.tree_flatten_with_path(params)[0]
+    lines = [f"{jax.tree_util.keystr(path)} {leaf.shape} {leaf.dtype}"
+             for path, leaf in leaves]
+    count, digest, total = TREES[family]
+    assert len(lines) == count
+    assert hashlib.sha256("\n".join(lines).encode()).hexdigest()[:16] == digest
+    assert sum(float(np.abs(np.asarray(leaf, np.float64)).sum())
+               for _, leaf in leaves) == pytest.approx(total, rel=1e-6)
+    gate = params["model"]["periods"]["block_1"]["block_sparse_moe"]["gate"]
+    np.testing.assert_allclose(gate["kernel"][1, 0], GATE, rtol=1e-6)
+
+
 @pytest.mark.parametrize("layers_,barriers", [(4, True), (8, False)])
 def test_a_lone_period_keeps_its_replay(layers_, barriers):
     """A scan of one trip is no loop once XLA is done with it, and the
