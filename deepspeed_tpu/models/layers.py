@@ -1147,6 +1147,77 @@ def yarn_rotary_embedding(positions: jnp.ndarray, head_dim: int, theta: float,
             (jnp.sin(freqs) * attention_factor).astype(dtype))
 
 
+# -- a stack of layer kinds: the scan over its periods -----------------------
+
+class _Period(nn.Module):
+    """One period, its blocks unrolled: a scan's body. Block ``i`` is
+    ``block(kinds[i], "block_<i>")`` under its kind's outer scope, remat'ed
+    by itself as a one-kind stack's layers are. ``lone``: the scan has this
+    one trip. XLA then removes the loop, and without ``prevent_cse`` it
+    merges each block's replay with its forward pass: the step keeps every
+    activation ``remat`` was asked to drop."""
+
+    config: Any
+    kinds: tuple
+    block: Callable
+    call: Callable
+    fold: Callable
+    scopes: Any
+    offers: Callable
+    lone: bool = False
+
+    @nn.compact
+    def __call__(self, carry, *inputs):
+        cfg = self.config
+        x, stats = carry
+        apply = lambda block, *args: block(*args)
+        if cfg.remat:
+            apply = nn.remat(apply, prevent_cse=self.lone,
+                             policy=resolve_remat_policy(
+                                 cfg.remat_policy, self.offers(x)))
+        for i, kind in enumerate(self.kinds):
+            block = self.block(kind, f"block_{i}")
+            with jax.named_scope(self.scopes[kind]):
+                x, got = self.call(functools.partial(apply, block), kind, x,
+                                   *inputs)
+            stats = self.fold(stats, got)
+        return (x, stats), None
+
+
+def scan_periods(cfg, kinds, x, stats, inputs, *, block, call, fold, scopes,
+                 offers):
+    """``(x, stats)`` after ``cfg.num_hidden_layers`` layers, whole periods
+    of ``kinds``: ONE scan over the periods (``periods``; with
+    ``scan_layers`` off, unrolled as ``periods_<p>``) whose body unrolls a
+    period's blocks, each remat'ed by itself under
+    ``resolve_remat_policy(cfg.remat_policy, offers(x))``. What a model file
+    says of its stack is data: ``block(kind, name)`` makes a layer,
+    ``call(block, kind, x, *inputs)`` applies it to the stream and the
+    scan's broadcast ``inputs`` and returns ``(x, its statistics)``,
+    ``scopes[kind]`` is a kind's outer scope, and ``fold(stats, a block's
+    statistics)`` carries what the layers report: a pytree that rides the
+    scan's carry behind ``x`` and is not looked into here."""
+    periods = cfg.num_hidden_layers // len(kinds)
+    fields = (cfg, kinds, block, call, fold, scopes, offers)
+    carry = (x, stats)
+    # ds.layer_stack: what the loop over the periods costs beyond what the
+    # layers' own scopes name (models/llama.py LlamaModel)
+    with jax.named_scope("ds.layer_stack"):
+        if cfg.scan_layers:
+            scan = nn.scan(
+                _Period, variable_axes={"params": 0, "intermediates": 0},
+                split_rngs={"params": True, "dropout": True},
+                in_axes=(nn.broadcast,) * len(inputs), length=periods,
+                metadata_params={})
+            carry, _ = scan(*fields, periods == 1, name="periods")(
+                carry, *inputs)
+        else:
+            for p in range(periods):
+                carry, _ = _Period(*fields, name=f"periods_{p}")(
+                    carry, *inputs)
+    return carry
+
+
 # -- what a remat'ed block keeps beyond its policy ---------------------------
 
 #: the rule's constants (PERF.md section 3 has the readings they were fixed

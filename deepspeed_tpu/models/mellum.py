@@ -11,9 +11,9 @@ three ``sliding_attention`` and one ``full_attention`` a period,
 Every block is ``mixtral.MixtralBlock`` as it is, under a config whose
 ``sliding_window`` is its kind's: attention, router, expert layer, the held
 share and its compact buffer are that file's. What is here is the pattern as
-config data, the period (a scan's body, its blocks unrolled inside, each
-under an outer ``ds.layer_window`` / ``ds.layer_full`` scope), the scan over
-periods with the kinds' ``(cos, sin)`` as broadcast inputs, and the causal-LM
+config data, what ``layers.scan_periods`` is told of a period (its blocks,
+each under an outer ``ds.layer_window`` / ``ds.layer_full`` scope, the
+kinds' ``(cos, sin)`` as the scan's broadcast inputs), and the causal-LM
 wrapper. Training only: a serving cache would hold a ring of
 ``sliding_window`` keys for the window layers beside the full layers' pages
 (ROADMAP R2).
@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from .layers import (RMSNorm, cross_entropy_loss, head_scope, lm_head_output,
-                     resolve_remat_policy, rotary_embedding, shift_labels,
+                     rotary_embedding, scan_periods, shift_labels,
                      yarn_rotary_embedding)
 from .mixtral import (MixtralBlock, MixtralConfig, MixtralForCausalLM,
                       _add_stats, _extra_stats, _share_loss_and_gauges,
@@ -140,36 +140,19 @@ def _check(cfg):
             "router_experts (MixtralForCausalLM reports a whole layer's)")
 
 
-class _Period(nn.Module):
-    """One period, its blocks unrolled: a scan's body. ``kinds`` is data:
-    block ``i`` is a ``MixtralBlock`` of ``kinds[i]`` (its window, its rotary
-    table of ``tables``), remat'ed by itself as ``MixtralModel``'s are.
-    ``lone``: the scan has this one trip. XLA then removes the loop, and
-    without ``prevent_cse`` it merges each block's replay with its forward
-    pass: the step keeps every activation ``remat`` was asked to drop."""
+def _call(block, kind, x, tables, mask, tok_mask, deterministic):
+    """A block over the stream with its kind's rotary table: ``(x, (each
+    expert's token fraction, the mean router probability, the layer's other
+    statistics))``."""
+    x, _, frac, prob, extra = block(x, *tables[kind], mask, tok_mask, None,
+                                    None, deterministic)
+    return x, (frac, prob, extra)
 
-    config: MellumConfig
-    kinds: tuple
-    lone: bool = False
 
-    @nn.compact
-    def __call__(self, carry, tables, mask, tok_mask, deterministic):
-        cfg = self.config
-        x, frac_sum, prob_sum, extra_sum = carry
-        block_cls = nn.remat(MixtralBlock, prevent_cse=self.lone,
-                             policy=resolve_remat_policy(
-                                 cfg.remat_policy,
-                                 remat_offers(cfg, x, cfg.num_hidden_layers))) \
-            if cfg.remat else MixtralBlock
-        for i, kind in enumerate(self.kinds):
-            with jax.named_scope(KIND_SCOPES[kind]):
-                x, _, frac, prob, extra = block_cls(
-                    kind_config(cfg, kind), name=f"block_{i}")(
-                    x, *tables[kind], mask, tok_mask, None, None,
-                    deterministic)
-            frac_sum, prob_sum = frac_sum + frac, prob_sum + prob
-            extra_sum = _add_stats(extra_sum, extra)
-        return (x, frac_sum, prob_sum, extra_sum), None
+def _fold(sums, stats):
+    frac_sum, prob_sum, extra_sum = sums
+    frac, prob, extra = stats
+    return frac_sum + frac, prob_sum + prob, _add_stats(extra_sum, extra)
 
 
 class MellumModel(nn.Module):
@@ -185,7 +168,6 @@ class MellumModel(nn.Module):
         _check(cfg)
         B, T = input_ids.shape
         kinds = period_kinds(cfg)
-        periods = cfg.num_hidden_layers // len(kinds)
         with jax.named_scope("ds.embed"):
             seeded = {} if cfg.embed_init_std is None else {
                 "embedding_init": nn.initializers.normal(cfg.embed_init_std)}
@@ -199,26 +181,17 @@ class MellumModel(nn.Module):
                 jnp.float32)
         E = cfg.router_width
         zero_e = jnp.zeros((E,), jnp.float32)
-        carry = (x, zero_e, zero_e, dict.fromkeys(
+        sums = (zero_e, zero_e, dict.fromkeys(
             _extra_stats(cfg, B * T * cfg.num_experts_per_tok),
             jnp.float32(0)))
-        inputs = (tables, mask, attention_mask, deterministic)
-        # ds.layer_stack: what the loop over the periods costs beyond what
-        # the layers' own scopes name (models/llama.py LlamaModel)
-        with jax.named_scope("ds.layer_stack"):
-            if cfg.scan_layers:
-                scan = nn.scan(
-                    _Period, variable_axes={"params": 0, "intermediates": 0},
-                    split_rngs={"params": True, "dropout": True},
-                    in_axes=(nn.broadcast,) * len(inputs), length=periods,
-                    metadata_params={})
-                carry, _ = scan(cfg, kinds, periods == 1, name="periods")(
-                    carry, *inputs)
-            else:
-                for p in range(periods):
-                    carry, _ = _Period(cfg, kinds, name=f"periods_{p}")(
-                        carry, *inputs)
-        x, frac_sum, prob_sum, extra_sum = carry
+        # every block is a ``MixtralBlock`` under its kind's window,
+        # remat'ed by itself as ``MixtralModel``'s are
+        x, (frac_sum, prob_sum, extra_sum) = scan_periods(
+            cfg, kinds, x, sums, (tables, mask, attention_mask, deterministic),
+            block=lambda kind, name: MixtralBlock(kind_config(cfg, kind),
+                                                  name=name),
+            call=_call, fold=_fold, scopes=KIND_SCOPES,
+            offers=lambda x: remat_offers(cfg, x, cfg.num_hidden_layers))
         with jax.named_scope(head_scope(None)):
             x = RMSNorm(eps=cfg.rms_norm_eps, name="norm")(x)
         L = cfg.num_hidden_layers

@@ -988,7 +988,7 @@ def expert_offers(x, K, I, held, experts, applications: int):
 
 def remat_offers(cfg, x, applications: int):
     """What a ``MixtralBlock`` names, as ``MixtralModel`` and
-    ``mellum._Period`` offer it to ``layers.resolve_remat_policy`` for a
+    ``mellum.MellumModel`` offer it to ``layers.resolve_remat_policy`` for a
     stream ``x [B, T, hidden]`` through ``applications`` blocks, costliest
     replay a byte first (mellum2 8k, ms of replay a step for a GB kept: the
     attention's output projection 4.0 for 0.15; q, k, v as ``LlamaAttention``

@@ -75,8 +75,8 @@ from ..ops.pallas import (REMAT_GDN_MIX, REMAT_GDN_QKVZ, REMAT_GDN_RULE,
 from ..parallel.topology import get_mesh
 from .layers import (apply_rotary_partial, causal_conv, cross_entropy_loss,
                      dot_product_attention, head_scope, model_dense,
-                     name_if_kept, repeat_kv, resolve_remat_policy,
-                     rotary_embedding, shift_labels)
+                     name_if_kept, repeat_kv, rotary_embedding, scan_periods,
+                     shift_labels)
 from .mixtral import (MixtralConfig, MixtralForCausalLM, MixtralSparseMoeBlock,
                       _add_stats, _compact_rows, _extra_stats, _fits,
                       _share_loss_and_gauges, expert_offers)
@@ -536,32 +536,16 @@ def _check(cfg):
             "router_experts")
 
 
-class _Period(nn.Module):
-    """One period, its blocks unrolled: a scan's body, each block remat'ed
-    by itself under its kind's outer scope. ``lone``: the scan has this one
-    trip, and each replay is fenced (``models/mellum.py _Period`` has why)."""
+def _call(block, kind, x, cos, sin):
+    x, frac, prob, extra, decay = block(x, cos, sin)
+    return x, (frac, prob, extra, decay)
 
-    config: Qwen3NextConfig
-    kinds: tuple
-    lone: bool = False
 
-    @nn.compact
-    def __call__(self, carry, cos, sin):
-        cfg = self.config
-        x, frac_sum, prob_sum, extra_sum, decay_max = carry
-        block_cls = nn.remat(Qwen3NextBlock, prevent_cse=self.lone,
-                             policy=resolve_remat_policy(
-                                 cfg.remat_policy,
-                                 remat_offers(cfg, x, self.kinds))) \
-            if cfg.remat else Qwen3NextBlock
-        for i, kind in enumerate(self.kinds):
-            with jax.named_scope(KIND_SCOPES[kind]):
-                x, frac, prob, extra, decay = block_cls(
-                    cfg, kind, name=f"block_{i}")(x, cos, sin)
-            frac_sum, prob_sum = frac_sum + frac, prob_sum + prob
-            extra_sum = _add_stats(extra_sum, extra)
-            decay_max = jnp.maximum(decay_max, decay)
-        return (x, frac_sum, prob_sum, extra_sum, decay_max), None
+def _fold(sums, stats):
+    frac_sum, prob_sum, extra_sum, decay_max = sums
+    frac, prob, extra, decay = stats
+    return (frac_sum + frac, prob_sum + prob, _add_stats(extra_sum, extra),
+            jnp.maximum(decay_max, decay))
 
 
 class Qwen3NextModel(nn.Module):
@@ -576,7 +560,6 @@ class Qwen3NextModel(nn.Module):
         _check(cfg)
         B, T = input_ids.shape
         kinds = period_kinds(cfg)
-        periods = cfg.num_hidden_layers // len(kinds)
         with jax.named_scope("ds.embed"):
             seeded = {} if cfg.embed_init_std is None else {
                 "embedding_init": nn.initializers.normal(cfg.embed_init_std)}
@@ -587,23 +570,14 @@ class Qwen3NextModel(nn.Module):
         cos, sin = rotary_embedding(positions, cfg.rotary_dim, cfg.rope_theta,
                                     dtype=x.dtype)
         zero_e = jnp.zeros((cfg.router_width,), jnp.float32)
-        carry = (x, zero_e, zero_e, dict.fromkeys(
+        sums = (zero_e, zero_e, dict.fromkeys(
             _extra_stats(cfg, B * T * cfg.num_experts_per_tok),
             jnp.float32(0)), jnp.zeros((), jnp.float32))
-        with jax.named_scope("ds.layer_stack"):
-            if cfg.scan_layers:
-                scan = nn.scan(
-                    _Period, variable_axes={"params": 0, "intermediates": 0},
-                    split_rngs={"params": True, "dropout": True},
-                    in_axes=(nn.broadcast, nn.broadcast), length=periods,
-                    metadata_params={})
-                carry, _ = scan(cfg, kinds, periods == 1, name="periods")(
-                    carry, cos, sin)
-            else:
-                for p in range(periods):
-                    carry, _ = _Period(cfg, kinds, name=f"periods_{p}")(
-                        carry, cos, sin)
-        x, frac_sum, _, extra_sum, decay_max = carry
+        x, (frac_sum, _, extra_sum, decay_max) = scan_periods(
+            cfg, kinds, x, sums, (cos, sin),
+            block=lambda kind, name: Qwen3NextBlock(cfg, kind, name=name),
+            call=_call, fold=_fold, scopes=KIND_SCOPES,
+            offers=lambda x: remat_offers(cfg, x, kinds))
         with jax.named_scope(head_scope(None)):
             x = ZeroCentredRMSNorm(cfg.rms_norm_eps, name="norm")(x)
         return x, (frac_sum, extra_sum, decay_max)
@@ -657,7 +631,7 @@ class Qwen3NextForCausalLM(nn.Module):
 
 
 def remat_offers(cfg, x, kinds):
-    """What a delta-rule layer names, as ``_Period`` offers it to
+    """What a delta-rule layer names, as ``Qwen3NextModel`` offers it to
     ``layers.resolve_remat_policy``: ``[(name, bytes over the stack's
     delta-rule layers)]`` for a stream ``x [B, T, hidden]``, costliest replay
     a byte first -- the rule's output, boundary states and chunk inverse

@@ -131,8 +131,8 @@ def _compact_offer():
 
 
 def _mellum_offer():
-    """``_Period`` offers ``MixtralBlock``'s names over every block of every
-    period (the tiny model: two periods of four)."""
+    """``MellumModel`` offers ``MixtralBlock``'s names over every block of
+    every period (the tiny model: two periods of four)."""
     cfg = mellum.MellumConfig.tiny(remat=True)
     model = mellum.MellumForCausalLM(cfg)
     ids = jnp.zeros((1, 16), jnp.int32)
