@@ -58,7 +58,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.pallas import REMAT_ATTN_OUT, REMAT_MLP, REMAT_QKV
-from .layers import (RMSNorm, apply_rotary, cross_entropy_loss,
+from .layers import (RMSNorm, apply_rotary, cross_entropy_loss, device_part,
                      dot_product_attention, head_scope, lm_head_output,
                      model_dense, name_if_kept, resolve_remat_policy,
                      rotary_embedding, shift_labels)
@@ -500,7 +500,8 @@ def remat_offers(cfg, x, applications: int):
     every head's key holds the shared rotary columns -- 5.6 for 0.67, the
     held experts' gate and up products 2.7 for 0.35, their sorted rows 2.0
     for 0.25)."""
-    per_column = x.shape[0] * x.shape[1] * x.dtype.itemsize * applications
+    per_column = device_part(x.shape[0]) * x.shape[1] * x.dtype.itemsize * \
+        applications
     heads = cfg.num_attention_heads * (2 * cfg.qk_head_dim + cfg.v_head_dim)
     shared = cfg.n_shared_experts * cfg.moe_intermediate_size
     return ((REMAT_ATTN_OUT, cfg.hidden_size * per_column),

@@ -13,6 +13,7 @@ the lane layout so XLA tiles cleanly onto the 128x128 MXU.
 
 import contextlib
 import functools
+import math
 import threading
 from typing import Any, Callable, Optional, Tuple
 
@@ -21,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
+
+from ..parallel.topology import BATCH_AXES, get_mesh, tokens_replicated
 
 
 def resolve_remat_policy(name: str, offered=()):
@@ -39,9 +42,10 @@ def resolve_remat_policy(name: str, offered=()):
     many as the budget the ENGINE states for the trace has room for
     (``remat_room``: the device's free memory before the step
     is built; the engine checks the compiled step and takes the choice back
-    where it was wrong). Nothing is set by hand. With no budget -- a bare
-    ``model.apply``, a CPU, a mesh of several devices -- nothing offered is
-    kept, the names are the identity and the policy is the plain one.
+    where it was wrong); budget and bytes are ONE device's whatever the
+    mesh (``device_part``). Nothing is set by hand. With no budget -- a bare
+    ``model.apply``, a CPU -- nothing offered is kept, the names are the
+    identity and the policy is the plain one.
 
     ``offload_dots_no_batch`` is the CPU-activation-checkpointing analog:
     its ``dots_no_batch`` residuals go to PINNED HOST memory, not HBM."""
@@ -1272,6 +1276,35 @@ def keep_for_room(offered) -> Tuple[str, ...]:
             total += nbytes
             _room.kept[name] = nbytes
     return tuple(kept)
+
+
+def batch_axes(batch: int) -> Tuple[str, ...]:
+    """The mesh axes a batch of ``batch`` samples is sharded over: the
+    engine's batch layout (``("data", "expert")``, ``("data",)`` under
+    ``moe.replicate_tokens``), the axes of several devices, as far along as
+    their product divides ``batch``. ``()`` with no mesh and on one device."""
+    mesh = get_mesh()
+    size = {} if mesh is None else mesh.shape
+    axes = ("data",) if tokens_replicated() else BATCH_AXES
+    axes = tuple(a for a in axes if size.get(a, 1) > 1)
+    while axes and batch % math.prod(size[a] for a in axes):
+        axes = axes[:-1]
+    return axes
+
+
+def device_part(batch: int, but=()) -> int:
+    """What ONE device holds of a batch of ``batch`` samples: ``batch`` over
+    the devices of ``batch_axes(batch)``, less the axes ``but`` (the ones a
+    value has been gathered over where it is named). A model file's offer
+    counts a device's part of each value through here, as the budget it is
+    held against is a device's (``remat_room``); on one device that is the
+    whole batch. Only the batch is divided: whether heads or columns are
+    sharded over ``model`` is the caller's ``partition_rules`` to say, which
+    no model file sees, so they count whole -- a count too high keeps less,
+    one too low plans what does not fit."""
+    mesh = get_mesh()
+    return batch // math.prod(
+        mesh.shape[a] for a in batch_axes(batch) if a not in but)
 
 
 def name_if_kept(x, name: str):

@@ -23,7 +23,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.pallas import REMAT_MLP, REMAT_QKV
 from .layers import (RMSNorm, apply_rotary, cached_attention_xla,
-                     cross_entropy_loss, dot_product_attention,
+                     cross_entropy_loss, device_part, dot_product_attention,
                      flash_prefill_from_empty, head_scope, init_kv_cache,
                      init_paged_kv_cache, is_paged_index, key_mask_to_bias,
                      lm_head_output, model_dense, name_if_kept,
@@ -589,7 +589,8 @@ def remat_offers(cfg, x, applications: int):
     ``applications`` layer applications)]`` for a stream ``x [B, T, hidden]``,
     costliest replay a byte first (train.8k: the gate and up products replay
     in 21.3 ms a step for 0.94 GB, q / k / v with RoPE in 4.7 for 0.20)."""
-    per_value = x.shape[0] * x.shape[1] * x.dtype.itemsize * applications
+    per_value = device_part(x.shape[0]) * x.shape[1] * x.dtype.itemsize * \
+        applications
     heads = cfg.num_attention_heads + 2 * cfg.num_key_value_heads
     return ((REMAT_MLP, 2 * cfg.intermediate_size * per_value),
             (REMAT_QKV, heads * cfg.head_dim * per_value))

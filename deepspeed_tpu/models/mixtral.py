@@ -34,7 +34,6 @@ Attention/rotary/cache machinery is shared with ``models/llama.py``.
 
 import dataclasses
 import functools
-import math
 from typing import Optional
 
 import flax.linen as nn
@@ -44,11 +43,12 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.pallas import (REMAT_ATTN_OUT, REMAT_MOE_ROWS, REMAT_MOE_UP,
                           grouped_matmul)
-from ..parallel.topology import BATCH_AXES, get_mesh, tokens_replicated
+from ..parallel.topology import get_mesh
 from ..utils.logging import log_dist
-from .layers import (RMSNorm, cross_entropy_loss, head_scope, init_kv_cache,
-                     lm_head_output, name_if_kept, remat_room,
-                     resolve_remat_policy, rotary_embedding, shift_labels)
+from .layers import (RMSNorm, batch_axes, cross_entropy_loss, device_part,
+                     head_scope, init_kv_cache, lm_head_output, name_if_kept,
+                     remat_room, resolve_remat_policy, rotary_embedding,
+                     shift_labels)
 from .indexed_attention import SparseAttentionConfig
 from .llama import LlamaAttention, LlamaConfig
 from .llama import remat_offers as _llama_offers
@@ -440,17 +440,6 @@ def _routed_experts(x, w1, w2, w3, topk_w, topk_idx, first, experts=None):
     return out, group_sizes
 
 
-def _token_axes(size, batch):
-    """The mesh axes the ``[B, T, H]`` tokens shard ``B`` over inside the
-    expert layer: the engine's batch layout (``("data", "expert")``,
-    ``("data",)`` under ``moe.replicate_tokens``) as far as it divides."""
-    axes = ("data",) if tokens_replicated() else BATCH_AXES
-    axes = tuple(a for a in axes if size.get(a, 1) > 1)
-    while axes and batch % math.prod(size[a] for a in axes):
-        axes = axes[:-1]
-    return axes
-
-
 @jax.named_scope("ds.moe_experts")
 def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx):
     """The stacked expert SwiGLU and the weighted combine: ``(out [B, T, H],
@@ -486,9 +475,7 @@ def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx):
         return out.reshape(x.shape), rows
 
     mesh = get_mesh()
-    size = {} if mesh is None else dict(zip(mesh.axis_names,
-                                            mesh.devices.shape))
-    ep = size.get("expert", 1)
+    ep = _expert_axis_size(mesh)
     if ep == 1:
         return experts(x, w1, w2, w3, topk_w, topk_idx)
 
@@ -500,7 +487,8 @@ def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx):
     layout = expert_layout(E, w1.shape[2], ep)
     _log_expert_layout(layout, E, w1.shape[2], ep)
     columns = layout == "columns"
-    batch = _token_axes(size, B)
+    # the engine's batch layout, as far as it divides B
+    batch = batch_axes(B)
     gathered = "expert" in batch
     others = tuple(a for a in batch if a != "expert")
 
@@ -977,8 +965,20 @@ def expert_offers(x, K, I, held, experts, applications: int):
     ``experts`` here -- counted over the rows the layer sorts onto: the
     compact buffer's where it has one, every pair's where it has none. The
     index vectors (``order``, ``inv``, ``topk_w``: a word a pair each, and
-    ``group_sizes``) go with the rows."""
+    ``group_sizes``) go with the rows. Under an ``expert`` mesh axis what
+    ONE device names inside ``_expert_mlp``'s ``shard_map``: the rows of
+    the batch's other axes' part, whole on ``expert`` (all-gathered, or
+    never divided by it), at ``expert_layout``'s columns or experts a
+    chip; without that axis the layer sorts the whole batch's pairs under
+    the partitioner, and the offer counts them all."""
     B, T, H = x.shape
+    ep = _expert_axis_size(get_mesh())
+    if ep > 1:
+        B = device_part(B, but=("expert",))
+        if expert_layout(held, I, ep) == "columns":
+            I //= ep
+        else:
+            held //= ep
     pairs, item = B * T * K, x.dtype.itemsize
     rows = _compact_rows(pairs, held, experts) or pairs
     return ((REMAT_MOE_UP, applications * 2 * rows * I * item),
@@ -996,7 +996,9 @@ def remat_offers(cfg, x, applications: int):
     experts' gate and up products 2.0 for 0.23, their sorted rows 1.9 for
     0.30)."""
     _, qkv = _llama_offers(cfg, x, applications)
-    return ((REMAT_ATTN_OUT, applications * x.size * x.dtype.itemsize), qkv,
+    B, T, H = x.shape
+    return ((REMAT_ATTN_OUT,
+             applications * device_part(B) * T * H * x.dtype.itemsize), qkv,
             *expert_offers(x, cfg.num_experts_per_tok, cfg.expert_width,
                            cfg.num_local_experts, cfg.router_experts,
                            applications))

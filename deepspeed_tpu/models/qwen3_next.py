@@ -74,9 +74,9 @@ from ..ops.pallas import (REMAT_GDN_MIX, REMAT_GDN_QKVZ, REMAT_GDN_RULE,
                           gdn_mix, gdn_rule, grouped_matmul)
 from ..parallel.topology import get_mesh
 from .layers import (apply_rotary_partial, causal_conv, cross_entropy_loss,
-                     dot_product_attention, head_scope, model_dense,
-                     name_if_kept, repeat_kv, rotary_embedding, scan_periods,
-                     shift_labels)
+                     device_part, dot_product_attention, head_scope,
+                     model_dense, name_if_kept, repeat_kv, rotary_embedding,
+                     scan_periods, shift_labels)
 from .mixtral import (MixtralConfig, MixtralForCausalLM, MixtralSparseMoeBlock,
                       _add_stats, _compact_rows, _extra_stats, _fits,
                       _share_loss_and_gauges, expert_offers)
@@ -653,7 +653,7 @@ def remat_offers(cfg, x, kinds):
     if _rule_tiling(dk, dv, C, x.dtype) is None:
         return experts
     layers = cfg.num_hidden_layers // len(kinds) * kinds.count(GDN)
-    item, n = x.dtype.itemsize, -(-T // C)
+    B, item, n = device_part(B), x.dtype.itemsize, -(-T // C)
     return ((REMAT_GDN_RULE, layers * B * Hv * (
                 n * (C * C + dk * dv) * 4 + T * dv * item)),
             (REMAT_GDN_QKVZ,

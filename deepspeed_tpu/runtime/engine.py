@@ -1586,18 +1586,18 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             pass
 
     def _remat_budget(self) -> Tuple[int, Optional[Tuple[int, int]]]:
-        """``(the bytes the remat rule may plan with, the device's (limit, in
-        use) or None)``: ``layers.REMAT_SHARE`` of the one device's memory
-        less what is in use now, the state resident -- and 0 where that
-        cannot be read (a CPU), under a mesh of several devices (a device's
-        part of a kept value is not what the model file counted), on the
+        """``(the bytes the remat rule may plan with, a device's (limit, in
+        use) or None)``: ``layers.REMAT_SHARE`` of ONE device's memory less
+        what is in use there now, the state resident -- whatever the mesh:
+        the model files count a device's part of what they offer
+        (``layers.device_part``), and ``memory_analysis()`` behind them is a
+        device's too. 0 where the numbers cannot be read (a CPU), on the
         one-bit, overlap and sparse lanes (their steps differentiate by their
         own rules), and once a choice was taken back."""
         from ..models import layers
 
-        memory = _device_memory(self.mesh.devices.flat[0])
-        if memory is None or self.mesh.devices.size != 1 or \
-                self._remat_fallbacks or self._onebit_wire or \
+        memory = _device_memory(self.mesh.local_devices[0])
+        if memory is None or self._remat_fallbacks or self._onebit_wire or \
                 self._overlap_lane or self._config.sparse_gradients_enabled:
             return 0, memory
         limit, in_use = memory

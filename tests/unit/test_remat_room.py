@@ -4,7 +4,9 @@ Llama, a tiny Qwen3-Next with the delta rule's kernels interpreted, and --
 PR 59 -- the expert blocks: Mixtral with and without a compact row buffer,
 Mellum's period, a DeepSeek-V3 share), and the engine's side -- the budget
 it states, the compile ahead of the first call, the check on the compiled
-step and the fallback."""
+step and the fallback. PR 62: budget and bytes are ONE device's whatever the
+mesh -- ``layers.device_part``, the offers under ``expert`` and ``data``
+axes, the expert layer's names inside its ``shard_map``."""
 
 import collections
 import logging
@@ -33,6 +35,7 @@ from deepspeed_tpu.ops.pallas import (GDN_GATE_BWD, GDN_GATE_FWD,
                                       REMAT_GDN_QKVZ, REMAT_GDN_RULE,
                                       REMAT_MLP, REMAT_MOE_ROWS, REMAT_MOE_UP,
                                       REMAT_QKV, gdn_mix, gdn_rule)
+from deepspeed_tpu.parallel import topology
 from deepspeed_tpu.parallel.topology import build_mesh
 from deepspeed_tpu.runtime import engine as engine_module
 from deepspeed_tpu.utils.logging import logger
@@ -130,17 +133,28 @@ def _compact_offer():
         (REMAT_MOE_ROWS, 2 * (512 * 32 * 4 + 4 * (3 * 1024 + 2))))
 
 
+def _kept_under_a_room(model, shape):
+    """``{name: bytes}`` the rule keeps of what ``model`` offers for ids of
+    ``shape`` under a room with space for everything: its gradient traced
+    from shapes alone."""
+    ids = jnp.zeros(shape, jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
+
+    def loss(p):
+        out = model.apply({"params": p}, ids, labels=ids)
+        return out[0] if isinstance(out, tuple) else out
+
+    with remat_room(10 ** 9) as kept:
+        jax.make_jaxpr(jax.grad(loss))(params)
+    return dict(kept)
+
+
 def _mellum_offer():
     """``MellumModel`` offers ``MixtralBlock``'s names over every block of
     every period (the tiny model: two periods of four)."""
     cfg = mellum.MellumConfig.tiny(remat=True)
-    model = mellum.MellumForCausalLM(cfg)
-    ids = jnp.zeros((1, 16), jnp.int32)
-    params = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
-    with remat_room(10 ** 9) as kept:
-        jax.make_jaxpr(jax.grad(
-            lambda p: model.apply({"params": p}, ids, labels=ids)))(params)
+    kept = _kept_under_a_room(mellum.MellumForCausalLM(cfg), (1, 16))
     x = jax.ShapeDtypeStruct((1, 16, cfg.hidden_size), jnp.float32)
     assert cfg.num_hidden_layers == 8
     return tuple(kept.items()), mx.remat_offers(cfg, x, 8)
@@ -151,14 +165,8 @@ def _deepseek_offer():
     16, top-3, two shared experts of 16; 4 heads of 8 + 4 and 8): the model
     offers what the scanned layers name, over those two alone -- XLA merges
     an unrolled layer's replay with its forward pass."""
-    cfg = dsv3.DeepseekV3Config.tiny(remat=True)
-    model = dsv3.DeepseekV3ForCausalLM(cfg)
-    ids = jnp.zeros((2, 16), jnp.int32)
-    params = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
-    with remat_room(10 ** 9) as kept:
-        jax.make_jaxpr(jax.grad(
-            lambda p: model.apply({"params": p}, ids, labels=ids)))(params)
+    kept = _kept_under_a_room(dsv3.DeepseekV3ForCausalLM(
+        dsv3.DeepseekV3Config.tiny(remat=True)), (2, 16))
     tokens, pairs = 2 * 16 * 4, 2 * 16 * 3      # float32 bytes a column
     return tuple(kept.items()), (
         (REMAT_ATTN_OUT, 2 * 32 * tokens),
@@ -460,13 +468,7 @@ def test_a_gradient_holds_the_names_its_room_kept_and_no_other(build, budget,
 
 def test_ouro_counts_every_layer_of_every_pass():
     cfg = OuroConfig.tiny(remat=True)
-    model = OuroForCausalLM(cfg)
-    ids = jnp.zeros((1, 16), jnp.int32)
-    params = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
-    with remat_room(10 ** 9) as kept:
-        jax.make_jaxpr(jax.grad(
-            lambda p: model.apply({"params": p}, ids, labels=ids)))(params)
+    kept = _kept_under_a_room(OuroForCausalLM(cfg), (1, 16))
     x = jax.ShapeDtypeStruct((1, 16, cfg.hidden_size), jnp.float32)
     assert kept == dict(remat_offers(
         cfg, x, cfg.num_hidden_layers * cfg.total_ut_steps))
@@ -586,21 +588,24 @@ def _engine(model):
     return engine
 
 
-def _train(engine, steps=3):
+def _train(engine, steps=3, batch=2):
     rng = np.random.default_rng(0)
     losses = []
     for _ in range(steps):
-        ids = rng.integers(0, 128, (2, 32)).astype(np.int32)
+        ids = rng.integers(0, 128, (batch, 32)).astype(np.int32)
         losses.append(float(engine.train_batch(
             batch={"input_ids": ids, "labels": ids})))
     return losses
 
 
-def _lowered_text(engine):
+def _lowered_text(engine, budget=0):
+    """The engine's step as it lowers under a stated ``budget`` (a fresh
+    engine a call: jax keeps a function's trace)."""
     ids = np.zeros((2, 32), np.int32)
     batch = engine._shape_batch({"input_ids": ids, "labels": ids})
-    return engine._train_step.lower(
-        engine.state, batch, jax.random.PRNGKey(0)).as_text()
+    with remat_room(budget):
+        return engine._train_step.lower(
+            engine.state, batch, jax.random.PRNGKey(0)).as_text()
 
 
 TINY = {
@@ -717,11 +722,221 @@ def test_a_step_over_the_margin_is_built_again_with_nothing_kept(
     assert engine._remat_budget()[0] == 0      # and stays there
 
 
-def test_no_budget_under_a_mesh_of_several_devices(device_memory):
-    device_memory(10 ** 9)
+def test_a_mesh_of_several_devices_states_one_devices_budget(device_memory):
+    device_memory(10 ** 9, 10 ** 8)
     engine, *_ = ds.initialize(
         model=TINY["llama"](), config={**CONFIG, "train_batch_size": 4},
         mesh=build_mesh(devices=jax.devices()[:2]),
         example_batch={"input_ids": np.zeros((4, 32), np.int32),
                        "labels": np.zeros((4, 32), np.int32)})
-    assert engine._remat_budget() == (0, (10 ** 9, 0))
+    assert engine._remat_budget() == (
+        int(layers.REMAT_SHARE * 10 ** 9) - 10 ** 8, (10 ** 9, 10 ** 8))
+
+
+# -- a device's part under a mesh (PR 62) ------------------------------------
+
+#: ``{case: (devices, mesh axes, moe.replicate_tokens)}``
+MESHES = {"one_device": (1, {}, False),
+          "data2": (2, {}, False),
+          "expert4_gathered": (4, {"expert": 4}, False),
+          "expert4_replicated": (4, {"expert": 4}, True),
+          "data2_expert2": (4, {"expert": 2}, False),
+          "data2_expert2_replicated": (4, {"expert": 2}, True)}
+
+
+def _mesh(case):
+    """The mesh of ``MESHES[case]`` set as the engine sets it (``data``
+    takes the devices the other axes leave)."""
+    devices, axes, replicate = MESHES[case]
+    mesh = build_mesh(devices=jax.devices()[:devices], **axes)
+    topology.set_mesh(mesh)
+    topology.set_token_replication(replicate)
+    return mesh
+
+
+@pytest.mark.parametrize("case,batch,axes,part,gathered_part", [
+    ("one_device", 4, (), 4, 4),
+    ("data2", 4, ("data",), 2, 2),
+    ("data2", 3, (), 3, 3),
+    ("expert4_gathered", 8, ("expert",), 2, 8),
+    ("expert4_gathered", 6, (), 6, 6),
+    ("expert4_replicated", 8, (), 8, 8),
+    ("data2_expert2", 4, ("data", "expert"), 1, 2),
+    ("data2_expert2", 2, ("data",), 1, 1),
+    ("data2_expert2", 3, (), 3, 3),
+    ("data2_expert2_replicated", 4, ("data",), 2, 2),
+])
+def test_a_devices_part_of_a_batch(case, batch, axes, part, gathered_part):
+    """The batch's axes as far as they divide it, a device's samples, and
+    its samples where the value was gathered over ``expert``."""
+    _mesh(case)
+    assert layers.batch_axes(batch) == axes
+    assert layers.device_part(batch) == part
+    assert layers.device_part(batch, but=("expert",)) == gathered_part
+
+
+def test_with_no_mesh_a_device_holds_the_whole_batch():
+    assert topology.get_mesh() is None
+    assert layers.batch_axes(8) == () and layers.device_part(8) == 8
+
+
+def _named_bytes(jaxpr, stream, times=1, inside=False, found=None):
+    """``{name: bytes ONE device holds of the values named so}`` of a forward
+    pass's jaxpr: inside a ``shard_map`` the named equation's own aval, outside
+    it ``stream.shard_shape`` of a value that leads with the batch (``[B, T,
+    ...]``) and the whole of any other (the expert layer's rows without an
+    ``expert`` axis: the partitioner sorts the whole batch's pairs on every
+    device); a scan's body counts ``length`` times."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            aval = eqn.outvars[0].aval
+            shape = aval.shape if inside or aval.ndim < 3 else \
+                stream.shard_shape(aval.shape)
+            found[eqn.params["name"]] += \
+                times * int(np.prod(shape)) * aval.dtype.itemsize
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _named_bytes(
+                sub, stream, times * eqn.params.get("length", 1)
+                if eqn.primitive.name == "scan" else times,
+                inside or eqn.primitive.name == "shard_map", found)
+    return dict(found)
+
+
+@pytest.mark.parametrize("columns", [False, True], ids=["experts", "columns"])
+@pytest.mark.parametrize("case", sorted(MESHES))
+def test_every_offer_counts_what_one_device_holds_of_the_named_value(
+        case, columns):
+    """The tiny Mixtral's forward pass traced with everything kept: each
+    offer's bytes are the bytes ONE device holds of the values that carry
+    its name -- the stream's over the batch's axes, the expert layer's as its
+    ``shard_map`` sees them (rows whole on ``expert``, whole experts a chip
+    or, with 1,024 columns a chip, every expert's columns)."""
+    mesh = _mesh(case)
+    cfg = MixtralConfig.tiny(remat=True, **(
+        {"intermediate_size": 1024 * mesh.shape["expert"]} if columns else {}))
+    assert (mx.expert_layout(4, cfg.expert_width, mesh.shape["expert"])
+            == "columns") == (columns and mesh.shape["expert"] > 1)
+    model = MixtralForCausalLM(cfg)
+    ids = jnp.zeros((4, 32), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
+    with remat_room(10 ** 12) as kept:
+        jaxpr = jax.make_jaxpr(
+            lambda p: model.apply({"params": p}, ids, labels=ids))(params)
+    assert list(kept) == [REMAT_ATTN_OUT, REMAT_QKV, REMAT_MOE_UP,
+                          REMAT_MOE_ROWS]
+    stream = jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec(layers.batch_axes(4)))
+    assert _named_bytes(jaxpr.jaxpr, stream) == dict(kept)
+
+
+#: what each family's tiny model offered on one device before PR 62 (the
+#: global count, which one device's part is there): ``{family: (ids' shape,
+#: {name: bytes})}``
+OFFERED_ON_ONE_DEVICE = {
+    "llama": ((2, 32), {REMAT_MLP: 131072, REMAT_QKV: 65536}),
+    "mixtral": ((2, 32), {REMAT_ATTN_OUT: 16384, REMAT_QKV: 32768,
+                          REMAT_MOE_UP: 131072, REMAT_MOE_ROWS: 35872}),
+    "mellum": ((2, 16), {REMAT_ATTN_OUT: 32768, REMAT_QKV: 65536,
+                         REMAT_MOE_UP: 262144, REMAT_MOE_ROWS: 71808}),
+    "deepseek_v3": ((2, 16), {REMAT_ATTN_OUT: 8192, REMAT_MLP: 16384,
+                              REMAT_QKV: 32768, REMAT_MOE_UP: 24576,
+                              REMAT_MOE_ROWS: 26944}),
+    "sdar": ((2, 32), {REMAT_ATTN_OUT: 32768, REMAT_QKV: 65536,
+                       REMAT_MOE_UP: 262144, REMAT_MOE_ROWS: 71712}),
+    "ouro": ((2, 32), {REMAT_MLP: 262144, REMAT_QKV: 196608}),
+    "qwen3_next": ((1, 16), {REMAT_GDN_RULE: 18432, REMAT_GDN_QKVZ: 18432,
+                             REMAT_GDN_MIX: 24576, REMAT_MOE_UP: 16384,
+                             REMAT_MOE_ROWS: 17984}),
+}
+
+
+@pytest.mark.parametrize("family", sorted(OFFERED_ON_ONE_DEVICE))
+def test_on_one_device_every_family_offers_what_it_offered(
+        family, delta_rule_kernels):
+    shape, offered = OFFERED_ON_ONE_DEVICE[family]
+    _mesh("one_device")
+    assert _kept_under_a_room(TINY[family](), shape) == offered
+
+
+@pytest.mark.parametrize("family", ["llama", "mixtral", "deepseek_v3",
+                                    "qwen3_next"])
+def test_on_one_device_a_stated_budget_lowers_the_step_it_lowered(
+        family, monkeypatch):
+    """With a budget stated the one-device engine's step lowers, to the
+    character, to the text it lowers to with ``device_part`` made the
+    identity -- the offers' global count, what the rule planned with before
+    a device's part was counted: the same names kept, no ``name`` equation
+    moved."""
+    from deepspeed_tpu.models import llama
+
+    text = _lowered_text(_engine(TINY[family]()), 10 ** 9)
+    # (a name lowers to nothing: what it keeps shows in the scans' carries)
+    assert text != _lowered_text(_engine(TINY[family]()))
+    for module in (llama, qn, mx, dsv3):
+        monkeypatch.setattr(module, "device_part", lambda batch, but=(): batch)
+    assert _lowered_text(_engine(TINY[family]()), 10 ** 9) == text
+
+
+def _expert4_engine(replicate):
+    """The tiny remat'ed Mixtral under ``expert=4`` (whole experts a chip),
+    its tokens all-gathered over the axis or replicated on it."""
+    config = {**CONFIG, "train_batch_size": 4,
+              **({"moe": {"replicate_tokens": True}} if replicate else {})}
+    model = TINY["mixtral"]()
+    engine, *_ = ds.initialize(
+        model=model, config=config,
+        mesh=build_mesh(devices=jax.devices()[:4], expert=4),
+        partition_rules=MixtralForCausalLM.partition_rules(model.config),
+        example_batch={"input_ids": np.zeros((4, 32), np.int32),
+                       "labels": np.zeros((4, 32), np.int32)})
+    return engine
+
+
+@pytest.mark.parametrize("replicate", [False, True],
+                         ids=["gathered", "replicated"])
+def test_under_an_expert_axis_the_kept_products_leave_the_replay(
+        replicate, device_memory):
+    """``expert=4``: the step's gradient holds 11 grouped products a layer
+    body with nothing kept -- three forward, the replay's gate and up, six
+    backward, counted through the ``shard_map`` -- 9 with the names up to
+    ``ds_moe_gate_up`` kept (the replay still sorts), and with the sorted
+    rows kept too one sort, one scatter and, the tokens all-gathered, three
+    all-gathers fewer. An engine that reads room on its device keeps all
+    four under the mesh and trains to the very losses of one that keeps
+    nothing."""
+    def step(budget):
+        engine = _expert4_engine(replicate)
+        ids = np.zeros((4, 32), np.int32)
+        batch = engine._shape_batch({"input_ids": ids, "labels": ids})
+        with remat_room(budget) as kept:
+            found = _primitives(jax.make_jaxpr(engine._train_step)(
+                engine.state, batch, jax.random.PRNGKey(0)).jaxpr)
+        grouped = found["ragged_dot"] + found["ragged_dot_general"]
+        return dict(kept), grouped, found
+
+    kept, grouped, plain = step(0)
+    assert (kept, grouped) == ({}, 11) and plain["name"] == 0
+    x = jax.ShapeDtypeStruct((4, 32, 32), jnp.float32)
+    offered = mx.remat_offers(MixtralConfig.tiny(), x, 2)   # on that mesh
+    assert [n for n, _ in offered[2:]] == [REMAT_MOE_UP, REMAT_MOE_ROWS]
+    kept, grouped, up = step(REMAT_FACTOR * sum(b for _, b in offered[:3]))
+    assert list(kept) == [n for n, _ in offered[:3]] and grouped == 9
+    assert up["sort"] == plain["sort"] == 2
+    kept, grouped, rows = step(10 ** 9)
+    assert kept == dict(offered) and grouped == 9
+    assert (rows["sort"], rows["scatter"]) == (1, 1)
+    assert plain["all_gather"] - rows["all_gather"] == (0 if replicate else 3)
+
+    engine = _expert4_engine(replicate)
+    want, record = _train(engine, batch=4), engine.setup.record(0)
+    assert record["remat_kept_names"] == record["remat_room_bytes"] == 0
+    device_memory(10 ** 8, 10 ** 6)
+    engine = _expert4_engine(replicate)
+    assert _train(engine, batch=4) == want
+    record = engine.setup.record(0)
+    assert record["remat_kept_names"] == 4 and record["remat_fallbacks"] == 0
+    assert record["remat_kept_bytes"] == sum(b for _, b in offered)
+    assert record["remat_room_bytes"] == \
+        int(layers.REMAT_SHARE * 10 ** 8) - 10 ** 6
