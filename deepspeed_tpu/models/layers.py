@@ -1060,6 +1060,28 @@ def lm_head_output(parent, cfg, hidden, labels, cache, head_bias=False):
                                             chunk=cfg.loss_chunk)
 
 
+def seeded_embed_tokens(cfg, input_ids):
+    """The input table ``embed_tokens`` of a model whose configuration may
+    state the scale its rows are SEEDED at (``embed_init_std``; None: flax's
+    ``1 / sqrt(hidden_size)``), looked up. Called from the parent module's
+    compact ``__call__`` frame, as ``lm_head_output`` is."""
+    seeded = {} if cfg.embed_init_std is None else {
+        "embedding_init": nn.initializers.normal(cfg.embed_init_std)}
+    return nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens",
+                    param_dtype=jnp.float32, **seeded)(input_ids)
+
+
+def seeded_lm_head(cfg, hidden):
+    """Whole logits through an untied ``lm_head`` whose configuration may
+    state the scale its rows are SEEDED at (``head_init_std``; None: flax's
+    ``1 / sqrt(hidden_size)``). Called from the parent module's compact
+    ``__call__`` frame."""
+    seeded = {} if cfg.head_init_std is None else {
+        "kernel_init": nn.initializers.normal(cfg.head_init_std)}
+    return nn.Dense(cfg.vocab_size, use_bias=False, name="lm_head",
+                    param_dtype=jnp.float32, **seeded)(hidden)
+
+
 def apply_rotary_interleaved(x, cos, sin):
     """GPT-J-style rotate_every_two: pairs are (x[2i], x[2i+1]), not the
     rotate-half (x[i], x[i+D/2]) convention."""
@@ -1189,24 +1211,30 @@ class _Period(nn.Module):
 
 
 def scan_periods(cfg, kinds, x, stats, inputs, *, block, call, fold, scopes,
-                 offers):
-    """``(x, stats)`` after ``cfg.num_hidden_layers`` layers, whole periods
-    of ``kinds``: ONE scan over the periods (``periods``; with
-    ``scan_layers`` off, unrolled as ``periods_<p>``) whose body unrolls a
-    period's blocks, each remat'ed by itself under
-    ``resolve_remat_policy(cfg.remat_policy, offers(x))``. What a model file
-    says of its stack is data: ``block(kind, name)`` makes a layer,
-    ``call(block, kind, x, *inputs)`` applies it to the stream and the
-    scan's broadcast ``inputs`` and returns ``(x, its statistics)``,
+                 offers, leading=()):
+    """``(x, stats)`` after ``cfg.num_hidden_layers`` layers: the layers of
+    the kinds ``leading`` (a stack's dense layers in front of its pattern),
+    unrolled as ``leading/block_<i>``, then whole periods of ``kinds``: ONE
+    scan over the periods (``periods``; with ``scan_layers`` off, unrolled
+    as ``periods_<p>``) whose body unrolls a period's blocks, each remat'ed
+    by itself under ``resolve_remat_policy(cfg.remat_policy, offers(x))``.
+    What a model file says of its stack is data: ``block(kind, name)`` makes
+    a layer, ``call(block, kind, x, *inputs)`` applies it to the stream and
+    the scan's broadcast ``inputs`` and returns ``(x, its statistics)``,
     ``scopes[kind]`` is a kind's outer scope, and ``fold(stats, a block's
     statistics)`` carries what the layers report: a pytree that rides the
     scan's carry behind ``x`` and is not looked into here."""
-    periods = cfg.num_hidden_layers // len(kinds)
+    periods = (cfg.num_hidden_layers - len(leading)) // len(kinds)
     fields = (cfg, kinds, block, call, fold, scopes, offers)
     carry = (x, stats)
     # ds.layer_stack: what the loop over the periods costs beyond what the
     # layers' own scopes name (models/llama.py LlamaModel)
     with jax.named_scope("ds.layer_stack"):
+        if leading:
+            # no loop to remove: each block's replay is fenced as a lone
+            # period's is
+            carry, _ = _Period(cfg, leading, *fields[2:], True,
+                               name="leading")(carry, *inputs)
         if cfg.scan_layers:
             scan = nn.scan(
                 _Period, variable_axes={"params": 0, "intermediates": 0},

@@ -22,12 +22,12 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.pallas import REMAT_MLP, REMAT_QKV
-from .layers import (RMSNorm, apply_rotary, cached_attention_xla,
-                     cross_entropy_loss, device_part, dot_product_attention,
-                     flash_prefill_from_empty, head_scope, init_kv_cache,
-                     init_paged_kv_cache, is_paged_index, key_mask_to_bias,
-                     lm_head_output, model_dense, name_if_kept,
-                     paged_attention_reference,
+from .layers import (RMSNorm, apply_rotary, apply_rotary_partial,
+                     cached_attention_xla, cross_entropy_loss, device_part,
+                     dot_product_attention, flash_prefill_from_empty,
+                     head_scope, init_kv_cache, init_paged_kv_cache,
+                     is_paged_index, key_mask_to_bias, lm_head_output,
+                     model_dense, name_if_kept, paged_attention_reference,
                      paged_prefill_attention_reference,
                      ragged_mixed_attention_reference, repeat_kv,
                      resolve_remat_policy, rotary_embedding, shift_labels,
@@ -175,14 +175,24 @@ class LlamaAttention(nn.Module):
                 eps=cfg.rms_norm_eps, name=name)(kept(t)) if on else t
             per_head = getattr(cfg, "qk_norm_per_head", False)  # scales [D]
             bare = not (cfg.qk_norm or per_head)    # named after RoPE
+            # a head's first ``rotary_dim`` columns rotate (cos and sin are
+            # that wide), the others pass; None: all of them
+            rot = getattr(cfg, "rotary_dim", None)
+            rotate = apply_rotary if rot is None else (
+                lambda t, cos, sin: apply_rotary_partial(t, cos, sin, rot))
             q = norm(dense(H * D, "q_proj", qb)(x), "q_norm",
                      cfg.qk_norm).reshape(B, T, H, D)
             k = norm(dense(Hkv * D, "k_proj", qb)(x), "k_norm",
                      cfg.qk_norm).reshape(B, T, Hkv, D)
             v = dense(Hkv * D, "v_proj", qb)(x).reshape(B, T, Hkv, D)
             q, k = norm(q, "q_norm", per_head), norm(k, "k_norm", per_head)
-            q, k = (apply_rotary(t, cos, sin) for t in (q, k))
+            q, k = (rotate(t, cos, sin) for t in (q, k))
             q, k, v = kept(q, bare), kept(k, bare), kept(v)
+            # a learned gate a head on the core's output, from the layer's
+            # normed input through a projection of its own
+            gated = getattr(cfg, "attn_head_gate", False)
+            if gated:
+                gate = _head_gate(dense(H, "g_proj")(x))        # [B, T, H]
         if getattr(cfg, "sa_config", None) is not None:
             # a learned indexer chooses each query's keys (training only):
             # no cache, and a third value: what the loss needs of this layer
@@ -333,11 +343,18 @@ class LlamaAttention(nn.Module):
                                         flash_block_q=cfg.flash_block_q,
                                         flash_block_k=cfg.flash_block_k,
                                         window=_window_of(cfg, T))
+        if gated:
+            with jax.named_scope("ds.attn_gate"):
+                out = out * gate[..., None].astype(out.dtype)
         with jax.named_scope("ds.attn_proj"):
             out = dense(cfg.hidden_size, "o_proj", row=True)(
                 out.reshape(B, T, H * D))
+        # a third value where the layer has statistics to hand up: the
+        # selection's, the mean of a head gate
         if getattr(cfg, "sa_config", None) is not None:
             return out, layer_cache, sa_stats
+        if gated:
+            return out, layer_cache, {"attn_gate": jnp.mean(gate)}
         return out, layer_cache
 
 
@@ -594,6 +611,11 @@ def remat_offers(cfg, x, applications: int):
     heads = cfg.num_attention_heads + 2 * cfg.num_key_value_heads
     return ((REMAT_MLP, 2 * cfg.intermediate_size * per_value),
             (REMAT_QKV, heads * cfg.head_dim * per_value))
+
+
+def _head_gate(logits):
+    """``sigmoid`` of a head gate's logits, in float32."""
+    return jax.nn.sigmoid(logits.astype(jnp.float32))
 
 
 def _window_of(cfg, T: int):

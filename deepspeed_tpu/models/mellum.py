@@ -30,8 +30,8 @@ import jax
 import jax.numpy as jnp
 
 from .layers import (RMSNorm, cross_entropy_loss, head_scope, lm_head_output,
-                     rotary_embedding, scan_periods, shift_labels,
-                     yarn_rotary_embedding)
+                     rotary_embedding, scan_periods, seeded_embed_tokens,
+                     seeded_lm_head, shift_labels, yarn_rotary_embedding)
 from .mixtral import (MixtralBlock, MixtralConfig, MixtralForCausalLM,
                       _add_stats, _extra_stats, _share_loss_and_gauges,
                       remat_offers)
@@ -169,10 +169,7 @@ class MellumModel(nn.Module):
         B, T = input_ids.shape
         kinds = period_kinds(cfg)
         with jax.named_scope("ds.embed"):
-            seeded = {} if cfg.embed_init_std is None else {
-                "embedding_init": nn.initializers.normal(cfg.embed_init_std)}
-            x = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens",
-                         param_dtype=jnp.float32, **seeded)(input_ids)
+            x = seeded_embed_tokens(cfg, input_ids)
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
         tables = rope_tables(cfg, positions, x.dtype)
@@ -222,11 +219,7 @@ class MellumForCausalLM(nn.Module):
             if cfg.head_init_std is None:
                 logits, lm = lm_head_output(self, cfg, hidden, labels, None)
             else:
-                logits, lm = nn.Dense(
-                    cfg.vocab_size, use_bias=False, name="lm_head",
-                    param_dtype=jnp.float32,
-                    kernel_init=nn.initializers.normal(cfg.head_init_std))(
-                        hidden), None
+                logits, lm = seeded_lm_head(cfg, hidden), None
             if labels is None:
                 return logits
             if lm is None:

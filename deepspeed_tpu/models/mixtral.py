@@ -263,11 +263,11 @@ class MixtralSparseMoeBlock(nn.Module):
         with jax.named_scope("ds.moe_router"):
             router_logits = nn.Dense(E, use_bias=False, name="gate",
                                      param_dtype=jnp.float32)(x)  # [B, T, E]
-            probs = jax.nn.softmax(router_logits.astype(jnp.float32),
-                                   axis=-1)
+            probs = _router_scores(cfg, router_logits.astype(jnp.float32))
             topk_w, topk_idx = jax.lax.top_k(probs, K)
             if cfg.norm_topk_prob:
                 topk_w = topk_w / jnp.sum(topk_w, axis=-1, keepdims=True)
+            topk_w = _routed_scale(cfg, topk_w)
             # one-hot routing (also feeds the aux-loss stats below)
             onehot = jax.nn.one_hot(topk_idx, E,
                                     dtype=topk_w.dtype)  # [B,T,K,E]
@@ -438,6 +438,30 @@ def _routed_experts(x, w1, w2, w3, topk_w, topk_idx, first, experts=None):
         x, w1.astype(dt), w3.astype(dt), w2.astype(dt),
         topk_w.T.astype(jnp.float32), order, inv, group_sizes)
     return out, group_sizes
+
+
+# -- how a router scores and weighs its experts ------------------------------
+# (here, below ``_routed_experts``: the grouped kernels' compiled payload
+# holds the line of that function's call of ``_sorted_experts_for``, so a
+# line added above it re-keys the kernels of every cell that runs them --
+# PERF.md section 7)
+
+def _router_scores(cfg, logits):
+    """Each expert's score ``[..., E]`` from the router's float32 logits: a
+    softmax over all of them, or under a config whose ``router_scoring`` is
+    ``"sigmoid"`` (``models/laguna.py``) each expert's own sigmoid; the
+    top-k is of the scores either way."""
+    if getattr(cfg, "router_scoring", "softmax") == "sigmoid":
+        return jax.nn.sigmoid(logits)
+    return jax.nn.softmax(logits, axis=-1)
+
+
+def _routed_scale(cfg, topk_w):
+    """The chosen experts' weights times the config's
+    ``routed_scaling_factor`` (after their normalisation), where it has one
+    that is not 1."""
+    scale = getattr(cfg, "routed_scaling_factor", 1.0)
+    return topk_w if scale == 1.0 else topk_w * scale
 
 
 @jax.named_scope("ds.moe_experts")

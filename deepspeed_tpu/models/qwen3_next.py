@@ -76,7 +76,8 @@ from ..parallel.topology import get_mesh
 from .layers import (apply_rotary_partial, causal_conv, cross_entropy_loss,
                      device_part, dot_product_attention, head_scope,
                      model_dense, name_if_kept, repeat_kv, rotary_embedding,
-                     scan_periods, shift_labels)
+                     scan_periods, seeded_embed_tokens, seeded_lm_head,
+                     shift_labels)
 from .mixtral import (MixtralConfig, MixtralForCausalLM, MixtralSparseMoeBlock,
                       _add_stats, _compact_rows, _extra_stats, _fits,
                       _share_loss_and_gauges, expert_offers)
@@ -561,10 +562,7 @@ class Qwen3NextModel(nn.Module):
         B, T = input_ids.shape
         kinds = period_kinds(cfg)
         with jax.named_scope("ds.embed"):
-            seeded = {} if cfg.embed_init_std is None else {
-                "embedding_init": nn.initializers.normal(cfg.embed_init_std)}
-            x = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens",
-                         param_dtype=jnp.float32, **seeded)(input_ids)
+            x = seeded_embed_tokens(cfg, input_ids)
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
         cos, sin = rotary_embedding(positions, cfg.rotary_dim, cfg.rope_theta,
@@ -608,10 +606,7 @@ class Qwen3NextForCausalLM(nn.Module):
         hidden, (load, extra, decay) = Qwen3NextModel(cfg, name="model")(
             input_ids, positions)
         with jax.named_scope(head_scope(None)):
-            init = {} if cfg.head_init_std is None else {
-                "kernel_init": nn.initializers.normal(cfg.head_init_std)}
-            logits = nn.Dense(cfg.vocab_size, use_bias=False, name="lm_head",
-                              param_dtype=jnp.float32, **init)(hidden)
+            logits = seeded_lm_head(cfg, hidden)
             if labels is None:
                 return logits
             loss = cross_entropy_loss(logits, shift_labels(labels))

@@ -25,6 +25,7 @@ import deepspeed_tpu as ds
 from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3Config,
                                               DeepseekV3ForCausalLM)
+from deepspeed_tpu.models.laguna import LagunaConfig, LagunaForCausalLM
 from deepspeed_tpu.models.mellum import MellumConfig, MellumForCausalLM
 from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
 from deepspeed_tpu.models.ouro import OuroConfig, OuroForCausalLM
@@ -69,6 +70,14 @@ TRAIN_SCOPES = {
                "ds.rope_tables", "ds.layer_window", "ds.layer_full",
                "ds.attn_proj", "ds.attention", "ds.moe_router",
                "ds.moe_experts", "ds.lm_head_loss"],
+    # a pattern of layer kinds of different head counts behind a dense
+    # layer: ds.layer_dense is the leading block (its feed-forward ds.mlp),
+    # ds.attn_gate the head gates' product, ds.moe_shared the shared expert
+    "laguna": ["ds.loss_and_grad", "ds.optimizer", "ds.embed",
+               "ds.rope_tables", "ds.layer_dense", "ds.layer_window",
+               "ds.layer_full", "ds.attn_proj", "ds.attention",
+               "ds.attn_gate", "ds.mlp", "ds.moe_router", "ds.moe_experts",
+               "ds.moe_shared", "ds.lm_head_loss"],
     # two mixers a period over Mixtral's expert layer: each block under its
     # kind's outer scope; ds.gdn_rule is the chunked delta rule alone,
     # ds.gdn_mix the rest of a delta-rule mixer, ds.attn_gate the full
@@ -115,6 +124,7 @@ def train_text():
                                topk=8)))),
             ("sambay", SambaYForCausalLM(SambaYConfig.tiny(remat=True))),
             ("mellum", MellumForCausalLM(MellumConfig.tiny(remat=True))),
+            ("laguna", LagunaForCausalLM(LagunaConfig.tiny(remat=True))),
             ("qwen3_next", Qwen3NextForCausalLM(Qwen3NextConfig.tiny(
                 remat=True))),
             ("ouro", OuroForCausalLM(OuroConfig.tiny(remat=True,
@@ -180,8 +190,9 @@ def trace_names():
 #: reader of that time asked for; PR 56 added ``ds.loop_stack`` and
 #: ``ds.exit_gate``, which stand only in ``models/ouro.py``'s step; PR 58
 #: added ``ds.bd_noise`` and ``ds.bd_gather``, which stand only in
-#: ``models/sdar.py``'s step)
-NAMES_PIN = (3, "6988b08f961235e5")
+#: ``models/sdar.py``'s step; PR 63 added ``ds.layer_dense``, which stands
+#: only in ``models/laguna.py``'s step, whose ``ds.attn_gate`` is PR 52's name)
+NAMES_PIN = (3, "d72adb445a335316")
 
 
 def test_names_version_is_raised_with_the_names():
@@ -195,7 +206,8 @@ def test_names_version_is_raised_with_the_names():
             "ds.ssm_mix", "ds.gmu", "ds.da_mix", "ds.layer_window",
             "ds.layer_full", "ds.rope_tables", "ds.layer_gdn", "ds.gdn_mix",
             "ds.gdn_rule", "ds.attn_gate", "ds.loop_stack",
-            "ds.exit_gate", "ds.bd_noise", "ds.bd_gather"} <= set(scopes) \
+            "ds.exit_gate", "ds.bd_noise", "ds.bd_gather",
+            "ds.layer_dense"} <= set(scopes) \
         and {"counters", "init", "init_shapes", "init_params",
              "init_opt_state", "init_step", "cost_capture",
              "setup"} <= set(spans)
@@ -848,15 +860,18 @@ def test_no_step_without_the_flash_indexer_holds_its_kernels(train_text):
 
 
 def test_no_other_familys_step_holds_the_delta_rules_names(train_text):
-    """``ds.layer_gdn``, ``ds.gdn_mix``, ``ds.gdn_rule`` and ``ds.attn_gate``
-    stand in ``models/qwen3_next.py``'s step alone: the other families'
-    programs are what they were, and ``NAMES_VERSION`` stays."""
+    """``ds.layer_gdn``, ``ds.gdn_mix`` and ``ds.gdn_rule`` stand in
+    ``models/qwen3_next.py``'s step alone, ``ds.attn_gate`` there and in
+    ``models/laguna.py``'s (a gate a head for a gate a column),
+    ``ds.layer_dense`` in the latter alone: the other families' programs
+    are what they were, and ``NAMES_VERSION`` stays."""
+    own = {"qwen3_next": {"ds.layer_gdn", "ds.gdn_mix", "ds.gdn_rule",
+                          "ds.attn_gate"},
+           "laguna": {"ds.attn_gate", "ds.layer_dense"}}
     for family, text in train_text.items():
-        found = set(re.findall(r"ds\.(?:layer_gdn|gdn_[a-z]+|attn_gate)\b",
-                               text))
-        assert found == ({"ds.layer_gdn", "ds.gdn_mix", "ds.gdn_rule",
-                          "ds.attn_gate"} if family == "qwen3_next"
-                         else set()), family
+        found = set(re.findall(
+            r"ds\.(?:layer_gdn|gdn_[a-z]+|attn_gate|layer_dense)\b", text))
+        assert found == own.get(family, set()), family
 
 
 def test_no_other_familys_step_holds_the_loops_names(train_text):
