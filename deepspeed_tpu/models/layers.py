@@ -934,16 +934,46 @@ def cache_attention_bias(q_len: int, cache_len: int, cache_index,
     return bias.astype(jnp.float32)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray,
                        ignore_index: int = -100) -> jnp.ndarray:
-    """Token-mean cross entropy with ignore mask; stable in fp32."""
-    logits = logits.astype(jnp.float32)
+    """Token-mean cross entropy with ignore mask; stable in fp32.
+
+    A ``custom_vjp``: its backward rule hands the head ONE cotangent, made
+    in one pass in the logits' own dtype (``_cross_entropy_bwd``)."""
+    return _cross_entropy_fwd(logits, labels, ignore_index)[0]
+
+
+def _cross_entropy_fwd(logits, labels, ignore_index):
     mask = (labels != ignore_index).astype(jnp.float32)
     safe_labels = jnp.where(labels == ignore_index, 0, labels)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, safe_labels[..., None], axis=-1).squeeze(-1)
+    logz = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+    # gathered BEFORE the cast: a gather from the float32 cast makes the
+    # head's product write a second, float32 copy of the logits for it
+    gold = jnp.take_along_axis(logits, safe_labels[..., None], axis=-1)
+    gold = gold.squeeze(-1).astype(jnp.float32)
     nll = (logz - gold) * mask
-    return nll.sum() / jnp.maximum(mask.sum(), 1.0)
+    count = jnp.maximum(mask.sum(), 1.0)
+    return nll.sum() / count, (logits, labels, logz, count)
+
+
+def _cross_entropy_bwd(ignore_index, residuals, g):
+    """``(softmax - onehot) * mask * g / count`` in float32, rounded ONCE to
+    the logits' dtype -- where plain autodiff rounds it, at the transpose
+    of the forward's cast -- and held as one array. Left to autodiff, the
+    element-wise float32 graph ahead of that cast is either written out as
+    a float32 ``[tokens, vocab]`` and converted in a second pass, or cloned
+    into both of the head's backward products as their operand's producer
+    (PERF.md section 5, PR 64); behind the barrier both read one array."""
+    logits, labels, logz, count = residuals
+    scale = jnp.where(labels != ignore_index, g / count, 0.0)
+    probs = jnp.exp(logits.astype(jnp.float32) - logz[..., None])
+    onehot = labels[..., None] == jnp.arange(logits.shape[-1])
+    cotangent = ((probs - onehot) * scale[..., None]).astype(logits.dtype)
+    return jax.lax.optimization_barrier(cotangent), None
+
+
+cross_entropy_loss.defvjp(_cross_entropy_fwd, _cross_entropy_bwd)
 
 
 def chunked_cross_entropy_loss(hidden: jnp.ndarray, w_out: jnp.ndarray,
@@ -953,10 +983,12 @@ def chunked_cross_entropy_loss(hidden: jnp.ndarray, w_out: jnp.ndarray,
                                chunk: int = 2048) -> jnp.ndarray:
     """Token-mean cross entropy WITHOUT materializing ``[tokens, vocab]``.
 
-    The plain path computes bf16 logits ``[B,T,V]`` and casts them to fp32 —
-    at the bench shapes (B32, T1024, V32k) that is a 2 GB + 4 GB temp and
-    the backward touches it all again: the loss layer becomes an HBM-
-    bandwidth sink. Here the head projection + logsumexp run inside a
+    The plain path (``cross_entropy_loss`` over ``lm_head_output``'s whole
+    logits) holds two ``[B,T,V]`` arrays in the logits' dtype -- the logits
+    and, in the backward, their cotangent, made in one pass -- and nothing
+    of that shape in float32: at 8,192 tokens x 32,000 words 0.52 GB each,
+    the cotangent read by the head's two backward products as it stands.
+    Here the head projection + logsumexp run inside a
     ``lax.scan`` over token chunks with a rematerialized body, so peak
     memory is ``O(chunk * vocab)`` and the full logits never exist; the
     backward recomputes each chunk's logits (≈ +1/3 of the lm-head FLOPs,

@@ -392,8 +392,9 @@ def test_a_lone_period_keeps_its_replay(layers_, barriers):
     model, params = seeded(cfg)
     loss = lambda m: lambda p: m.apply({"params": p}, IDS, labels=IDS)[0]
     grad = jax.jit(jax.grad(loss(model)))
-    assert ("optimization_barrier" in grad.lower(params).as_text()) \
-        == barriers
+    # one barrier is the loss's own: its rule holds the logits' cotangent
+    fences = grad.lower(params).as_text().count("optimization_barrier") - 1
+    assert (fences > 0) == barriers and fences >= 0
     plain = MellumForCausalLM(dataclasses.replace(cfg, remat=False))
     want = jax.grad(loss(plain))(params)
     got = grad(params)

@@ -281,6 +281,35 @@ def test_moe_load_gauges_are_published_by_name(family, over, gauges):
         assert found[gauges[1]] <= 1.0 <= found[gauges[0]]
 
 
+#: the families whose step takes the chunked per-token loss
+#: (``ouro.token_nll``), which the head's whole-logits loss is no part of
+CHUNKED_LOSS = ("ouro", "sdar")
+
+
+@pytest.mark.parametrize("family", list(TRAIN_SCOPES))
+def test_head_loss_backward_rule_stands_under_its_scope(train_text, family):
+    """``layers.cross_entropy_loss`` is a ``custom_vjp``: the operations of
+    its backward rule -- the one pass that makes the logits' cotangent and
+    the barrier that holds it -- carry the CALLER's ``ds.lm_head_loss`` and
+    the backward pass's mark, so ``train.head_loss_share`` reads the whole
+    head and ``train.unnamed_share`` nothing of it."""
+    text = train_text[family]
+    held = re.findall(r'"([^"]*)/optimization_barrier"', text)
+    of_the_loss = [n for n in held if "ds.lm_head_loss" in n]
+    if family in CHUNKED_LOSS:
+        assert not of_the_loss
+        return
+    assert len(set(of_the_loss)) == 1
+    path = of_the_loss[0]
+    # what scope_reduce.scope_of and phase_of read: the innermost ds.* name,
+    # and the marks of the backward pass with none of a replay
+    assert re.findall(r"ds\.[a-z_0-9]+", path)[-1] == "ds.lm_head_loss"
+    assert "ds.loss_and_grad" in path and "transpose(" in path
+    assert "rematted_computation" not in path
+    for op in ("exp", "sub", "mul", "convert_element_type"):
+        assert f'"{path}/{op}"' in text
+
+
 def test_backward_and_recompute_leave_their_marks(train_text):
     """What ``scope_reduce.phase_of`` tells the phases apart by."""
     text = train_text["mixtral"]
