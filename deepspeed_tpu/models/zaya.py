@@ -42,6 +42,10 @@ the whole router to the deployment that sees every expert.
 *Residual* of either sublayer with output ``y``: ``x <- (x + b_r) * a_r +
 (y + b_y) * a_y``, four learned vectors a sublayer.
 
+*What a remat'ed layer keeps* is the rule's (``layers.keep_for_room``): the
+block names its costliest residuals, each where the backward of its consumer
+reads it, and ``remat_offers`` counts them for ``resolve_remat_policy``.
+
 Training only: no cache holds the one token of ``h``, of the convolutions'
 input and of the first convolution's output that CCA's decode would need.
 """
@@ -54,13 +58,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops.pallas import (REMAT_CCA_MIX, REMAT_MOE_OUT, REMAT_QKV,
+                          REMAT_ROUTER)
 from .layers import (RMSNorm, apply_rotary_partial, causal_conv,
-                     cross_entropy_loss, dot_product_attention, head_scope,
-                     lm_head_output, model_dense, repeat_kv,
-                     resolve_remat_policy, rotary_embedding, shift_labels, shift_tokens)
+                     cross_entropy_loss, device_part, dot_product_attention,
+                     head_scope, lm_head_output, model_dense, name_if_kept,
+                     repeat_kv, resolve_remat_policy, rotary_embedding,
+                     shift_labels, shift_tokens)
 from .llama import LlamaConfig
 from .mixtral import (_balancing_delta, _check_held_share, _held_load_gauges,
-                      _routed_experts)
+                      _routed_experts, expert_offers)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -202,11 +209,16 @@ class ZayaAttention(nn.Module):
         C, G = (Hq + Hkv) * D, Hq + Hkv
         dense = lambda feats, name, row=False: model_dense(
             cfg, feats, name, row_parallel=row)
+        # offered as the projections write them (remat_offers): the
+        # convolutions, the mean and the unit length read them from there
+        proj = lambda feats, name: name_if_kept(dense(feats, name)(x),
+                                                REMAT_QKV)
+        mixed = lambda t: name_if_kept(t, REMAT_CCA_MIX)
         with jax.named_scope("ds.attn_proj"):
-            q0 = dense(Hq * D, "q_proj")(x)
-            k0 = dense(Hkv * D, "k_proj")(x)
-            v1 = dense(Hkv * D // 2, "v1_proj")(x)
-            v2 = dense(Hkv * D // 2, "v2_proj")(x)
+            q0 = proj(Hq * D, "q_proj")
+            k0 = proj(Hkv * D, "k_proj")
+            v1 = proj(Hkv * D // 2, "v1_proj")
+            v2 = proj(Hkv * D // 2, "v2_proj")
         # what CCA adds ahead of the kernels, beside the projections
         with jax.named_scope("ds.cca_mix"):
             wa = self.param("conv_a_weight", _about(0.0, cfg.cca_time0 ** -0.5),
@@ -222,12 +234,15 @@ class ZayaAttention(nn.Module):
                              jnp.float32)
             dt = x.dtype
             c = jnp.concatenate([q0, k0], axis=-1)
-            c = causal_conv(c, wa.astype(dt), ba.astype(dt))
+            # offered where this scope's own backward reads them: the
+            # first convolution's output (the grouped one's weight
+            # gradient), q and k ahead of their unit length
+            c = mixed(causal_conv(c, wa.astype(dt), ba.astype(dt)))
             c = causal_conv(c, wb.astype(dt), bb.astype(dt))
             mq, mk = _qk_mean(q0.reshape(B, T, Hq, D),
                               k0.reshape(B, T, Hkv, D))
-            q = c[..., :Hq * D].reshape(B, T, Hq, D) + mq
-            k = c[..., Hq * D:].reshape(B, T, Hkv, D) + mk
+            q = mixed(c[..., :Hq * D].reshape(B, T, Hq, D) + mq)
+            k = mixed(c[..., Hq * D:].reshape(B, T, Hkv, D) + mk)
             q = _unit_length(q).astype(dt)
             k = _temperature(_unit_length(k), tau).astype(dt)
             v = _shifted_value(v1, v2).reshape(B, T, Hkv, D)
@@ -258,7 +273,10 @@ def route(cfg, logits, bias):
     choice = p if bias is None else \
         p + jax.lax.stop_gradient(bias.astype(jnp.float32))
     _, idx = jax.lax.top_k(choice, cfg.num_experts_per_tok)
-    return jnp.take_along_axis(p, idx, axis=-1), idx
+    # offered (remat_offers) ahead of the gather, whose backward reads it
+    idx = name_if_kept(idx, REMAT_ROUTER)
+    return name_if_kept(jnp.take_along_axis(p, idx, axis=-1),
+                        REMAT_ROUTER), idx
 
 
 class ZayaRouter(nn.Module):
@@ -283,19 +301,24 @@ class ZayaRouter(nn.Module):
         r = jnp.einsum("bth,hr->btr", h, down.astype(h.dtype),
                        preferred_element_type=f32) \
             + param("down_bias", small, R)
-        state = _carry_state(r, param("state_scale", _about(1.0, 0.1), R),
-                             state)
+        # offered (remat_offers), each where the backward reads it: the
+        # state under its norm, the norm's output under fc1's weight
+        # gradient, a GELU's input, the logits under the softmax (route names
+        # the choice and its weight)
+        kept = lambda t: name_if_kept(t, REMAT_ROUTER)
+        state = kept(_carry_state(
+            r, param("state_scale", _about(1.0, 0.1), R), state))
         # RMSNorm, its scale seeded about one (at one it only rescales)
-        z = state * jax.lax.rsqrt(
+        z = kept(state * jax.lax.rsqrt(
             jnp.mean(state * state, axis=-1, keepdims=True)
-            + cfg.rms_norm_eps) * param("norm_scale", _about(1.0, 0.1), R)
+            + cfg.rms_norm_eps) * param("norm_scale", _about(1.0, 0.1), R))
         dot = lambda a, w: jnp.dot(a, w, precision=jax.lax.Precision.HIGHEST)
         gelu = lambda a: nn.gelu(a, approximate=False)
-        z = gelu(dot(z, param("fc1_kernel", lecun, R, R))
-                 + param("fc1_bias", small, R))
-        z = gelu(dot(z, param("fc2_kernel", lecun, R, R))
-                 + param("fc2_bias", small, R))
-        logits = dot(z, param("fc3_kernel", lecun, R, E))
+        z = gelu(kept(dot(z, param("fc1_kernel", lecun, R, R))
+                      + param("fc1_bias", small, R)))
+        z = gelu(kept(dot(z, param("fc2_kernel", lecun, R, R))
+                      + param("fc2_bias", small, R)))
+        logits = kept(dot(z, param("fc3_kernel", lecun, R, E)))
         bias = param(BIAS, nn.initializers.normal(cfg.router_bias_init), E)
         w, idx = route(cfg, logits, bias)
         delta = _balancing_delta(idx, E, cfg.router_bias_update_rate) \
@@ -376,6 +399,9 @@ class ZayaBlock(nn.Module):
             h = RMSNorm(eps=cfg.rms_norm_eps,
                         name="post_attention_layernorm")(x)
         out, state, rows, skipped, delta = ZayaMoE(cfg, name="mlp")(h, state)
+        # offered (remat_offers): the residual's output scale reads it, so
+        # without it the replay runs the whole expert sublayer for that alone
+        out = name_if_kept(out, REMAT_MOE_OUT)
         with jax.named_scope("ds.residual"):
             x = ScaledResidual(name="mlp_residual")(x, out)
         return x, state, rows, skipped, delta
@@ -417,7 +443,8 @@ class ZayaModel(nn.Module):
             attention_mask[:, None, None, :] > 0, 0.0, -1e9).astype(
                 jnp.float32)
 
-        policy = resolve_remat_policy(cfg.remat_policy)
+        policy = resolve_remat_policy(
+            cfg.remat_policy, remat_offers(cfg, x, cfg.num_hidden_layers))
         remat = lambda cls: nn.remat(cls, prevent_cse=False, policy=policy) \
             if cfg.remat else cls
         state = jnp.zeros((B, T, cfg.router_hidden_size), jnp.float32)
@@ -501,3 +528,39 @@ class ZayaForCausalLM(nn.Module):
             (r"q_proj/kernel", P(*L, None, "model")),
             (r"o_proj/kernel", P(*L, "model", None)),
         ]
+
+
+def remat_offers(cfg, x, applications: int):
+    """What a ``ZayaBlock`` names, as ``ZayaModel`` offers it to
+    ``layers.resolve_remat_policy`` for a stream ``x [B, T, hidden]`` through
+    ``applications`` blocks, the name worth most a byte first (zaya 8k, ms a
+    step the six-layer scan runs longer WITHOUT the name -- its replay less
+    what its stack costs to write and read -- for a GB kept): the expert
+    sublayer's output, without which the replay runs the whole expert
+    forward, the down product too, for the residual's output scale, 6.4 for
+    0.20; the four projections of ``h`` ahead of the convolutions 3.1 for
+    0.15; the held experts' gate and up products (``mixtral.expert_offers``:
+    every pair has a row, 8 of 17 is no compact share; worth nothing without
+    the first name) 4.7 for 0.40; the router's float32 values (four
+    ``[.., R]``, the logits, a word each for a choice and its weight) 2.2
+    for 0.20; what ``ds.cca_mix`` hands on, the first convolution's output
+    and q, k ahead of their unit length, 1.7 for 0.25. Two values are NOT
+    offered, by the same measurement (PERF.md section 5): the attention's
+    output projection as it enters its residual (the step ran 0.65 ms
+    SHORTER without it: 1.1 ms of replay for 1.2 ms of stack) and the
+    experts' sorted rows, which ``mixtral._named`` names all the same
+    (1.3 ms shorter: 0.8 ms of sort, scatter and gather for 2.1)."""
+    per_column = device_part(x.shape[0]) * x.shape[1] * applications
+    item = x.dtype.itemsize
+    latent = (cfg.num_attention_heads + cfg.num_key_value_heads) * cfg.head_dim
+    values = cfg.num_key_value_heads * cfg.head_dim
+    router = 4 * (4 * cfg.router_hidden_size + cfg.router_width
+                  + 2 * cfg.num_experts_per_tok)
+    gate_up, _ = expert_offers(
+        x, cfg.num_experts_per_tok, cfg.moe_intermediate_size,
+        cfg.n_routed_experts, cfg.router_width, applications)
+    return ((REMAT_MOE_OUT, cfg.hidden_size * item * per_column),
+            (REMAT_QKV, (latent + values) * item * per_column),
+            gate_up,
+            (REMAT_ROUTER, router * per_column),
+            (REMAT_CCA_MIX, 2 * latent * item * per_column))

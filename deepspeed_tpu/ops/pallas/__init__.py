@@ -91,3 +91,18 @@ REMAT_GDN_MIX = "ds_gdn_mix_out"
 # with the index vectors the sort made (no ``argsort``, scatter or gather)
 REMAT_MOE_UP = "ds_moe_gate_up"
 REMAT_MOE_ROWS = "ds_moe_rows"
+# a compressed-convolutional-attention layer's (``models/zaya.py``): what
+# ``ds.cca_mix`` hands on that its own backward reads -- the first
+# convolution's output (the grouped one's weight gradient reads it) and the
+# queries and keys ahead of their unit length: the replay then runs neither
+# convolution nor the query-key mean
+REMAT_CCA_MIX = "ds_cca_mix_out"
+# its MLP router's float32 values -- the carried state, its norm, the two
+# products ahead of a GELU, the logits, the choice and its weight: the
+# replay then runs none of the router's products at the highest precision,
+# no GELU's input and no ``top_k``
+REMAT_ROUTER = "ds_moe_router_kept"
+# and its expert sublayer's output as it joins the scaled residual stream,
+# whose output scale's gradient reads it: the replay then runs no forward of
+# the expert layer (the down product and the combine) for that sum alone
+REMAT_MOE_OUT = "ds_moe_out"

@@ -6,7 +6,10 @@ Mellum's period, a DeepSeek-V3 share), and the engine's side -- the budget
 it states, the compile ahead of the first call, the check on the compiled
 step and the fallback. PR 62: budget and bytes are ONE device's whatever the
 mesh -- ``layers.device_part``, the offers under ``expert`` and ``data``
-axes, the expert layer's names inside its ``shard_map``."""
+axes, the expert layer's names inside its ``shard_map``. PR 65: ZAYA's block
+-- the expert sublayer's output under its scaled residual, the projections
+ahead of the convolutions, what the mixer hands on, the MLP router's float32
+values."""
 
 import collections
 import logging
@@ -21,6 +24,7 @@ from deepspeed_tpu.models import deepseek_v3 as dsv3
 from deepspeed_tpu.models import layers, mellum
 from deepspeed_tpu.models import mixtral as mx
 from deepspeed_tpu.models import qwen3_next as qn
+from deepspeed_tpu.models import zaya as zy
 from deepspeed_tpu.models.layers import (REMAT_FACTOR, keep_for_room,
                                          remat_room, resolve_remat_policy)
 from deepspeed_tpu.models.llama import (LlamaConfig, LlamaForCausalLM,
@@ -31,10 +35,12 @@ from deepspeed_tpu.models.sdar import SdarConfig, SdarForCausalLM
 from deepspeed_tpu.ops.pallas import (GDN_GATE_BWD, GDN_GATE_FWD,
                                       GDN_PREMIX_BWD, GDN_PREMIX_FWD,
                                       GDN_RULE_BWD, GDN_RULE_FWD,
-                                      REMAT_ATTN_OUT, REMAT_GDN_MIX,
-                                      REMAT_GDN_QKVZ, REMAT_GDN_RULE,
-                                      REMAT_MLP, REMAT_MOE_ROWS, REMAT_MOE_UP,
-                                      REMAT_QKV, gdn_mix, gdn_rule)
+                                      REMAT_ATTN_OUT, REMAT_CCA_MIX,
+                                      REMAT_GDN_MIX, REMAT_GDN_QKVZ,
+                                      REMAT_GDN_RULE, REMAT_MLP,
+                                      REMAT_MOE_OUT, REMAT_MOE_ROWS,
+                                      REMAT_MOE_UP, REMAT_QKV, REMAT_ROUTER,
+                                      gdn_mix, gdn_rule)
 from deepspeed_tpu.parallel import topology
 from deepspeed_tpu.parallel.topology import build_mesh
 from deepspeed_tpu.runtime import engine as engine_module
@@ -176,10 +182,44 @@ def _deepseek_offer():
         (REMAT_MOE_ROWS, 2 * (pairs * 32 * 4 + 4 * (3 * pairs + 8))))
 
 
+def _zaya_offer():
+    """Three scanned layers of 4 / 2 heads of 8 over hidden 32, a router of
+    16 columns' width scoring 8 experts and the skip expert, top-1, experts
+    of 16 (8 of 9 held: no compact buffer), the name worth most a byte
+    first: the expert sublayer's output; q, k and the value's two halves as
+    the projections write them; the experts' gate and up products; the
+    router's four ``[.., 16]`` float32 values, its 9 logits and a word each
+    for the choice and its weight; the first convolution's output with q and
+    k ahead of their unit length (48 columns each) -- and neither the
+    attention's output nor the experts' sorted rows, which cost more to keep
+    than to replay (``zaya.remat_offers``). ``ZayaModel`` offers exactly
+    that, and each offer's bytes are the bytes of the values its forward
+    pass names so."""
+    cfg = zy.ZayaConfig.tiny(remat=True)
+    model = zy.ZayaForCausalLM(cfg)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
+    with remat_room(10 ** 9) as kept:
+        forward = jax.make_jaxpr(
+            lambda p: model.apply({"params": p}, ids, labels=ids))(params)
+    assert _named_bytes(forward.jaxpr, None, inside=True) == dict(kept)
+    tokens, pairs = 2 * 16 * 4, 2 * 16 * 1       # float32 bytes a column
+    x = jax.ShapeDtypeStruct((2, 16, cfg.hidden_size), jnp.float32)
+    assert tuple(kept.items()) == zy.remat_offers(cfg, x, 3)
+    return tuple(kept.items()), (
+        (REMAT_MOE_OUT, 3 * 32 * tokens),
+        (REMAT_QKV, 3 * (4 + 2 + 1 + 1) * 8 * tokens),
+        (REMAT_MOE_UP, 3 * 2 * pairs * 16 * 4),
+        (REMAT_ROUTER, 3 * (4 * 16 + 9 + 1 + 1) * tokens),
+        (REMAT_CCA_MIX, 3 * 2 * (4 + 2) * 8 * tokens))
+
+
 @pytest.mark.parametrize("offer", [
     _llama_offer, _mixtral_offer, _compact_offer, _mellum_offer,
-    _deepseek_offer], ids=["llama", "mixtral", "mixtral_compact",
-                           "mellum_period", "deepseek_v3"])
+    _deepseek_offer, _zaya_offer], ids=["llama", "mixtral", "mixtral_compact",
+                                        "mellum_period", "deepseek_v3",
+                                        "zaya"])
 def test_a_block_offers_its_names_with_their_bytes(offer):
     got, want = offer()
     assert got == want
@@ -271,7 +311,8 @@ def _expert_model(family):
     """``(model, ids)`` of a tiny remat'ed expert model: Mixtral whole (no
     compact buffer), Mixtral holding 2 of 16 experts (a compact buffer of
     512 rows for 1,024 pairs), Mellum's two periods, a DeepSeek-V3 share
-    with one dense layer ahead of its scanned expert layers."""
+    with one dense layer ahead of its scanned expert layers, ZAYA's three
+    layers scanned or unrolled."""
     rng = np.random.RandomState(0)
     if family == "mixtral":
         return MixtralForCausalLM(MixtralConfig.tiny(remat=True)), \
@@ -280,6 +321,10 @@ def _expert_model(family):
         return MixtralForCausalLM(MixtralConfig.tiny(
             remat=True, num_local_experts=2, router_experts=16)), \
             rng.randint(0, 128, (1, 512))
+    if family.startswith("zaya"):
+        return zy.ZayaForCausalLM(zy.ZayaConfig.tiny(
+            remat=True, scan_layers=family == "zaya")), \
+            rng.randint(0, 128, (2, 32))
     if family == "mellum":      # as published: an RMSNorm a head on q and k
         return mellum.MellumForCausalLM(mellum.MellumConfig.tiny(
             remat=True, qk_norm_per_head=True)), rng.randint(0, 128, (2, 16))
@@ -377,6 +422,77 @@ def test_a_budget_between_two_expert_names_keeps_the_first_ones(family):
     assert f"name={REMAT_MOE_ROWS}" not in str(named)
     found = _primitives(named.jaxpr)
     assert found["ragged_dot_general"] == 9 and found["sort"] == 2
+
+
+# -- the names under each policy: ZAYA's block (PR 65) ------------------------
+
+@pytest.mark.parametrize("family", ["zaya", "zaya_unrolled"])
+def test_kept_names_take_the_projections_the_router_and_the_experts_out_of_zayas_replay(
+        family):
+    """With everything offered kept a layer body's replay holds ONE
+    product, ``o_proj``: not q, k, v1, v2, not the grouped convolution's two
+    taps, not the router's down-projection nor its three at the highest
+    precision (ten ``dot_general`` a body fewer), no grouped product (with
+    nothing kept the replay runs all THREE -- the down product too, for the
+    scaled residual's output scale -- beside the forward's three and the
+    backward's six) and no ``top_k``. What it still runs besides: the
+    experts' ``argsort``, its scatter and the rows' gather (neither
+    ``o_proj``'s output nor the sorted rows are offered:
+    ``zaya.remat_offers``), and element-wise the norms, the residuals, the
+    unit length, RoPE, the router's norm, GELUs and softmax from the kept
+    state, products and logits. Loss and gradients are those of the step
+    that keeps nothing."""
+    bodies = 1 if family == "zaya" else 3
+    fn, params = _expert_grad(family)
+    plain = _primitives(jax.make_jaxpr(fn)(params).jaxpr)
+    want = jax.jit(fn)(params)
+    fn, _ = _expert_grad(family)        # jax keeps a function's trace
+    with remat_room(10 ** 9) as kept:
+        named = _primitives(jax.make_jaxpr(fn)(params).jaxpr)
+        got = jax.jit(fn)(params)
+    assert list(kept) == [REMAT_MOE_OUT, REMAT_QKV, REMAT_MOE_UP,
+                          REMAT_ROUTER, REMAT_CCA_MIX]
+    assert plain["dot_general"] - named["dot_general"] == 10 * bodies
+    assert (plain["ragged_dot_general"], named["ragged_dot_general"]) == \
+        (12 * bodies, 9 * bodies)
+    assert (plain["top_k"], named["top_k"]) == (2 * bodies, bodies)
+    for stays in ("sort", "scatter"):
+        assert plain[stays] == named[stays] == 2 * bodies
+    assert plain["name"] == 0
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=1e-7)
+
+
+#: ``{names kept, in the offer's order: (products, grouped products,
+#: top_ks) a scanned body's replay still runs}``: 11 / 3 / 1 with nothing
+#: kept, 1 / 0 / 0 (``o_proj``) with all five
+ZAYA_ROOMS = {
+    1: (11, 2, 1),      # the sublayer's output: no down product
+    2: (7, 2, 1),       # + q, k, v1, v2
+    3: (7, 0, 1),       # + gate and up: no grouped product
+    4: (3, 0, 0),       # + the router: its four products and the top_k
+}                       # (the mixer's values, the last: the grouped taps)
+
+
+@pytest.mark.parametrize("names", sorted(ZAYA_ROOMS))
+def test_a_room_between_two_of_zayas_names_keeps_the_first_ones(names):
+    fn, params = _expert_grad("zaya")
+    plain = _primitives(jax.make_jaxpr(fn)(params).jaxpr)
+    cfg = zy.ZayaConfig.tiny()
+    x = jax.ShapeDtypeStruct((2, 32, cfg.hidden_size), jnp.float32)
+    offered = zy.remat_offers(cfg, x, cfg.num_hidden_layers)
+    fn, _ = _expert_grad("zaya")
+    with remat_room(REMAT_FACTOR * sum(b for _, b in offered[:names])) as kept:
+        text = jax.make_jaxpr(fn)(params)
+    assert list(kept) == [n for n, _ in offered[:names]]
+    assert f"name={offered[names][0]}" not in str(text)
+    named = _primitives(text.jaxpr)
+    products, grouped, top_ks = ZAYA_ROOMS[names]
+    assert plain["dot_general"] - named["dot_general"] == 11 - products
+    assert named["ragged_dot_general"] == 9 + grouped
+    assert named["sort"] == 2 and named["top_k"] == 1 + top_ks
 
 
 N, K, E, G, H, I = 1024, 3, 32, 4, 16, 24   # test_moe_compact.py's layer
@@ -619,6 +735,7 @@ TINY = {
     "ouro": lambda: OuroForCausalLM(OuroConfig.tiny(remat=True)),
     "qwen3_next": lambda: qn.Qwen3NextForCausalLM(
         qn.Qwen3NextConfig.tiny(num_hidden_layers=4, remat=True)),
+    "zaya": lambda: zy.ZayaForCausalLM(zy.ZayaConfig.tiny(remat=True)),
 }
 
 
@@ -633,7 +750,7 @@ def test_on_a_cpu_the_lowered_step_names_nothing(family, monkeypatch):
     text = _lowered_text(engine)
     from deepspeed_tpu.models import llama
 
-    for module in (llama, qn, mx, dsv3):
+    for module in (llama, qn, mx, dsv3, zy):
         monkeypatch.setattr(module, "name_if_kept", lambda x, name: x)
     assert _lowered_text(_engine(TINY[family]())) == text
     if family != "llama":       # one family's step runs: the record is the
@@ -849,6 +966,10 @@ OFFERED_ON_ONE_DEVICE = {
     "qwen3_next": ((1, 16), {REMAT_GDN_RULE: 18432, REMAT_GDN_QKVZ: 18432,
                              REMAT_GDN_MIX: 24576, REMAT_MOE_UP: 16384,
                              REMAT_MOE_ROWS: 17984}),
+    # (PR 65: what it offers since it offers)
+    "zaya": ((2, 32), {REMAT_MOE_OUT: 24576, REMAT_QKV: 49152,
+                       REMAT_MOE_UP: 24576, REMAT_ROUTER: 57600,
+                       REMAT_CCA_MIX: 73728}),
 }
 
 
@@ -861,7 +982,7 @@ def test_on_one_device_every_family_offers_what_it_offered(
 
 
 @pytest.mark.parametrize("family", ["llama", "mixtral", "deepseek_v3",
-                                    "qwen3_next"])
+                                    "qwen3_next", "zaya"])
 def test_on_one_device_a_stated_budget_lowers_the_step_it_lowered(
         family, monkeypatch):
     """With a budget stated the one-device engine's step lowers, to the
@@ -874,7 +995,7 @@ def test_on_one_device_a_stated_budget_lowers_the_step_it_lowered(
     text = _lowered_text(_engine(TINY[family]()), 10 ** 9)
     # (a name lowers to nothing: what it keeps shows in the scans' carries)
     assert text != _lowered_text(_engine(TINY[family]()))
-    for module in (llama, qn, mx, dsv3):
+    for module in (llama, qn, mx, dsv3, zy):
         monkeypatch.setattr(module, "device_part", lambda batch, but=(): batch)
     assert _lowered_text(_engine(TINY[family]()), 10 ** 9) == text
 

@@ -588,26 +588,25 @@ CHECKPOINT_NAMES = {names.FLASH_OUT: ("ds_flash_out", lambda: _flash(True)),
                                        lambda: _sa_probs(True))}
 
 
-def _offering_llama():
-    from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-
-    model = LlamaForCausalLM(LlamaConfig.tiny(remat=True))
+def _offering(model):
+    """``(gradient of the model's loss, its parameters' shapes)``."""
     ids = jnp.zeros((1, 16), jnp.int32)
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
     return jax.grad(lambda p: model.apply({"params": p}, ids, labels=ids)), \
         params
+
+
+def _offering_llama():
+    return _offering(LlamaForCausalLM(LlamaConfig.tiny(remat=True)))
 
 
 def _offering_mixtral():
-    from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
+    return _offering(MixtralForCausalLM(MixtralConfig.tiny(remat=True)))
 
-    model = MixtralForCausalLM(MixtralConfig.tiny(remat=True))
-    ids = jnp.zeros((1, 16), jnp.int32)
-    params = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
-    return jax.grad(lambda p: model.apply({"params": p}, ids, labels=ids)), \
-        params
+
+def _offering_zaya():
+    return _offering(ZayaForCausalLM(ZayaConfig.tiny(remat=True)))
 
 
 def _offering_qwen3_next():
@@ -644,6 +643,9 @@ OFFERED_NAMES = {
     names.REMAT_GDN_RULE: ("ds_gdn_rule_kept", _offering_qwen3_next),
     names.REMAT_GDN_QKVZ: ("ds_gdn_qkvz", _offering_qwen3_next),
     names.REMAT_GDN_MIX: ("ds_gdn_mix_out", _offering_qwen3_next),
+    names.REMAT_CCA_MIX: ("ds_cca_mix_out", _offering_zaya),
+    names.REMAT_ROUTER: ("ds_moe_router_kept", _offering_zaya),
+    names.REMAT_MOE_OUT: ("ds_moe_out", _offering_zaya),
 }
 
 
@@ -674,6 +676,74 @@ def test_offered_name_stands_where_it_is_kept_and_nowhere_else(constant):
     with remat_room(10 ** 9) as kept:
         text = str(jax.make_jaxpr(grad)(params))
     assert constant in kept and named(text) > 0
+
+
+@pytest.fixture(scope="module")
+def zaya_replay():
+    """``{budget: {scope: primitives}}`` of what the tiny remat'ed ZAYA's
+    gradient replays (``rematted_computation`` in the operation's path, what
+    ``scope_reduce.phase_of`` calls recompute), each under its innermost
+    ``ds.*`` scope as ``scope_reduce.scope_of`` reads it: with no budget
+    stated and under one with room for every offered value."""
+    from deepspeed_tpu.models.layers import remat_room
+
+    out = {}
+    for budget in (0, 10 ** 9):
+        grad, params = _offering_zaya()
+        with remat_room(budget):
+            text = jax.jit(grad).trace(params).lower().as_text(
+                debug_info=True)
+        found = {}
+        for path in re.findall(r'loc\("([^"]*rematted_computation[^"]*)"',
+                               text):
+            scope = re.findall(r"ds\.[a-z_0-9]+", path)[-1]
+            found.setdefault(scope, set()).add(
+                "/".join(path.rsplit("/", 2)[-2:]))
+        out[budget] = found
+    return out
+
+
+#: ``{scope: what its replay runs with nothing kept and no longer runs under
+#: a stated budget}`` (the operation under the module or scope that made
+#: it); None: the scope's replay is what it was (``repeat_kv`` and the
+#: kernels' transposes, the norms, the residual scalings stay)
+ZAYA_REPLAY = {
+    # o_proj stays (its output is not offered: zaya.remat_offers), and RoPE
+    "ds.attn_proj": {f"{p}_proj/dot_general" for p in ("q", "k", "v1", "v2")},
+    "ds.cca_mix": {"btgi,gio->btgo/dot_general"},   # the unit length stays
+    # the down-projection, the three products, the choice; the state's norm,
+    # the GELUs of the kept products and the logits' softmax stay
+    "ds.moe_router": {"bth,hr->btr/dot_general", "router/dot_general",
+                      "router/top_k"},
+    # all three grouped products and the combine; the sort, its scatter and
+    # the rows' gather stay (the sorted rows are not offered)
+    "ds.moe_experts": {"moe_gmm/ragged_dot_general", "moe_combine/gather"},
+    "ds.attention": None, "ds.norm": None, "ds.residual": None,
+}
+
+
+@pytest.mark.parametrize("scope", sorted(ZAYA_REPLAY))
+def test_under_a_budget_zayas_replay_stands_under_its_scopes(zaya_replay,
+                                                             scope):
+    """With every offered value kept the replay's operations still carry
+    their layer's scope (a traced run's ``train.recompute_share`` splits by
+    it): ``ds.attention``, ``ds.norm`` and ``ds.residual`` as before; under
+    ``ds.attn_proj`` one product, ``o_proj``; under ``ds.cca_mix`` and
+    ``ds.moe_router`` no product and no ``top_k`` -- what a name cannot
+    reach there is element-wise (a softmax's and a norm's backward read
+    their own raw values: PERF.md section 7); under ``ds.moe_experts`` the
+    sort and the rows' gather, no grouped product."""
+    plain, kept = zaya_replay[0], zaya_replay[10 ** 9]
+    assert set(kept) == set(plain) == set(ZAYA_REPLAY) | {"ds.moe_skip"}
+    gone = ZAYA_REPLAY[scope]
+    if gone is None:
+        assert kept[scope] == plain[scope]
+    else:
+        assert gone <= plain[scope] and not gone & kept[scope]
+        assert kept[scope] < plain[scope]
+    if scope != "ds.attention":     # (this CPU's XLA core is two products)
+        assert {op for op in kept[scope] if op.endswith("dot_general")} == \
+            ({"o_proj/dot_general"} if scope == "ds.attn_proj" else set())
 
 
 @pytest.mark.parametrize("constant", sorted(CHECKPOINT_NAMES))

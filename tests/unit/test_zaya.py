@@ -383,6 +383,63 @@ def test_engine_moves_what_frozen_parameters_leaves(trainable):
     assert len(still) == (1 if trainable else 10)
 
 
+# -- what the replay keeps (PR 65) -------------------------------------------
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "unrolled"])
+def test_kept_names_change_no_loss_and_no_gradient(scan):
+    """The remat'ed share with every offered value kept (``remat_offers``
+    under a room: the expert sublayer's output, the projections, the experts'
+    gate and up products, the router's float32 values, the mixer's) against
+    the same model keeping nothing: one loss, and every parameter's gradient
+    to float32 rounding -- a kept value is the value the replay computed."""
+    from deepspeed_tpu.models.layers import remat_room
+
+    cfg = ZayaConfig.tiny(num_hidden_layers=L, remat=True, scan_layers=scan,
+                          **SHARE)
+    model, params = _seeded(cfg, 3, IDS)
+    grad = lambda: jax.value_and_grad(
+        lambda p: model.apply({"params": p}, IDS, labels=IDS))
+    want_loss, want = jax.jit(grad())(params)
+    with remat_room(10 ** 9) as kept:      # a fresh function: a fresh trace
+        got_loss, got = jax.jit(grad())(params)
+    assert len(kept) == len(zaya.remat_offers(
+        cfg, jax.ShapeDtypeStruct((*IDS.shape, cfg.hidden_size), jnp.float32),
+        L)) == 5
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-6)
+    want, got = _flat(want), _flat(got)
+    assert set(got) == set(want)
+    assert len(got) == (len(PATHS) if scan else L * (len(PATHS) - 2) + 2)
+    for path in want:
+        scale = np.abs(want[path]).max()
+        np.testing.assert_allclose(got[path], want[path], rtol=2e-5,
+                                   atol=2e-6 * scale, err_msg=path)
+
+
+def test_a_frozen_router_stays_frozen_with_its_values_kept(monkeypatch):
+    """An engine that reads room on its device keeps all five of the
+    remat'ed block's names, the router's float32 values among them; with
+    ``router_trainable`` off the router subtree still receives no update,
+    and the losses are those of the engine that kept nothing."""
+    from deepspeed_tpu.runtime import engine as engine_module
+
+    cfg = ZayaConfig.tiny(router_trainable=False, remat=True, **SHARE)
+    engine, batch = _engine(cfg)
+    want = [float(engine.train_batch(batch=batch)) for _ in range(3)]
+    assert engine.setup.record(0)["remat_kept_names"] == 0
+    monkeypatch.setattr(engine_module, "_device_memory",
+                        lambda device: (10 ** 8, 10 ** 6))
+    engine, batch = _engine(cfg)
+    before = _flat(engine.state.params)
+    got = [float(engine.train_batch(batch=batch)) for _ in range(3)]
+    record = engine.setup.record(0)
+    assert record["remat_kept_names"] == 5 and record["remat_fallbacks"] == 0
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    after = _flat(engine.state.params)
+    still = {k for k in before if not np.abs(after[k] - before[k]).max()}
+    assert still == {k for k in before if "['router']" in k}
+    assert len(still) == 10
+
+
 def test_balancing_rule_moves_the_bias_against_the_load():
     """``router_bias_update_rate``: beside its loss the training call names
     what the sign rule adds to each layer's bias — minus the rate for a
