@@ -176,10 +176,12 @@ class LlamaAttention(nn.Module):
             per_head = getattr(cfg, "qk_norm_per_head", False)  # scales [D]
             bare = not (cfg.qk_norm or per_head)    # named after RoPE
             # a head's first ``rotary_dim`` columns rotate (cos and sin are
-            # that wide), the others pass; None: all of them
+            # that wide), the others pass; None: all of them; 0: none, and
+            # no table is read (a stack whose recurrences carry position)
             rot = getattr(cfg, "rotary_dim", None)
             rotate = apply_rotary if rot is None else (
-                lambda t, cos, sin: apply_rotary_partial(t, cos, sin, rot))
+                lambda t, cos, sin: apply_rotary_partial(t, cos, sin, rot)
+                if rot else t)
             q = norm(dense(H * D, "q_proj", qb)(x), "q_norm",
                      cfg.qk_norm).reshape(B, T, H, D)
             k = norm(dense(Hkv * D, "k_proj", qb)(x), "k_norm",

@@ -34,7 +34,7 @@ Attention/rotary/cache machinery is shared with ``models/llama.py``.
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -272,12 +272,13 @@ class MixtralSparseMoeBlock(nn.Module):
             onehot = jax.nn.one_hot(topk_idx, E,
                                     dtype=topk_w.dtype)  # [B,T,K,E]
 
-        # stacked expert SwiGLU: [E, H, I] / [E, I, H], sharded over "expert"
-        # (expert_layout)
+        # stacked expert weights: [E, H, I] / [E, I, H], sharded over "expert"
+        # (expert_layout); no w3 where the experts' activation has no gate
         init = nn.initializers.lecun_normal(
             batch_axis=(0,) if cfg.per_expert_init else ())
         w1 = self.param("w1", init, (G, H, I), jnp.float32)  # gate
-        w3 = self.param("w3", init, (G, H, I), jnp.float32)  # up
+        w3 = self.param("w3", init, (G, H, I), jnp.float32) \
+            if _activation(cfg).gated else None              # up
         w2 = self.param("w2", init, (G, I, H), jnp.float32)  # down
         out, rows = _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx)
         if rows is not None:
@@ -347,42 +348,42 @@ def _grouped_outer(lhs, rhs, group_sizes):
     return jax.lax.ragged_dot_general(lhs, rhs, group_sizes, dims)
 
 
-@jax.custom_vjp
-def _sorted_experts(x, w1, w3, w2, topk_w, order, inv, group_sizes):
-    """The grouped SwiGLU over sorted rows and the weighted combine.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _sorted_experts(act, x, w1, w3, w2, topk_w, order, inv, group_sizes):
+    """The grouped expert MLP over sorted rows and the weighted combine.
 
-    ``x [N, H]`` tokens; ``w1``/``w3 [G, H, I]``, ``w2 [G, I, H]`` in the
-    compute dtype; ``topk_w [K, N]`` float32. Sorted row ``r`` holds pair
-    ``order[r]`` (pair ``k * N + n`` is token ``n``'s ``k``-th choice),
-    the ``sum(group_sizes)`` pairs of these experts first; ``inv [K * N]``
-    is each pair's row. Returns ``[N, H]``: each token's K rows times their
-    routing weights, summed in float32; pairs of no group add zero.
+    ``act`` is the experts' ``Activation`` (below: ``SWIGLU``, or ``RELU2``
+    with no ``w3``); ``x [N, H]`` tokens; ``w1``/``w3 [G, H, I]``, ``w2 [G,
+    I, H]`` in the compute dtype; ``topk_w [K, N]`` float32. Sorted row ``r``
+    holds pair ``order[r]`` (pair ``k * N + n`` is token ``n``'s ``k``-th
+    choice), the ``sum(group_sizes)`` pairs of these experts first; ``inv [K
+    * N]`` is each pair's row. Returns ``[N, H]``: each token's K rows times
+    their routing weights, summed in float32; pairs of no group add zero.
 
     The backward pass is written out. Both permutations stay gathers (by
     ``order`` and by ``inv``) where autodiff would scatter-add ``[M, H]``
-    rows. And the routing weights' gradient ``<y_row, g_row>`` is taken as
-    ``<h_row, (g @ w2^T)_row>``, a by-product of ``dh``: the down
-    projection is no residual, so ``jax.checkpoint`` replays two products,
-    not three (11 a step, 9 without it)."""
-    return _sorted_experts_fwd(x, w1, w3, w2, topk_w, order, inv,
+    rows. The routing weights' gradient ``<y_row, g_row>`` is taken as
+    ``<h_row, (g @ w2^T)_row>``, a by-product of ``dh``: the down projection
+    is no residual, so ``jax.checkpoint`` replays the first products alone."""
+    return _sorted_experts_fwd(act, x, w1, w3, w2, topk_w, order, inv,
                                group_sizes)[0]
 
 
-def _sorted_experts_fwd(x, w1, w3, w2, topk_w, order, inv, group_sizes):
+def _sorted_experts_fwd(act, x, w1, w3, w2, topk_w, order, inv, group_sizes):
     K, N = topk_w.shape
     with jax.named_scope("moe_dispatch"):
         xs = x[order % N]
     with jax.named_scope("moe_gmm"):
         h1 = _grouped_dot(xs, w1, group_sizes)
-        h3 = _grouped_dot(xs, w3, group_sizes)
-        y = _grouped_dot(nn.silu(h1) * h3, w2, group_sizes)
+        h3 = None if w3 is None else _grouped_dot(xs, w3, group_sizes)
+        y = _grouped_dot(act.forward(h1, h3), w2, group_sizes)
     with jax.named_scope("moe_combine"):
         y = y[inv].reshape(K, N, -1).astype(jnp.float32)
         out = jnp.sum(y * topk_w[:, :, None], axis=0).astype(x.dtype)
     return out, _named(xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3)
 
 
-def _sorted_experts_bwd(res, g):
+def _sorted_experts_bwd(act, res, g):
     xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3 = res
     K, N = topk_w.shape
     dt, f32 = xs.dtype, jnp.float32
@@ -392,17 +393,15 @@ def _sorted_experts_bwd(res, g):
     with jax.named_scope("moe_gmm"):
         t = _grouped_dot(g_row, w2, group_sizes,
                          transposed=True).astype(f32)    # g @ w2^T
-        a1, a3 = h1.astype(f32), h3.astype(f32)
-        sig = jax.nn.sigmoid(a1)
-        h = a1 * sig * a3
+        h, pull = act.rule(h1.astype(f32),
+                           None if h3 is None else h3.astype(f32))
         d_w_row = jnp.sum(h * t, axis=-1)
-        dh = t * w_row
-        dh1 = (dh * a3 * sig * (1 + a1 * (1 - sig))).astype(dt)
-        dh3 = (dh * a1 * sig).astype(dt)
-        dxs = _grouped_dot(dh1, w1, group_sizes, transposed=True) + \
-            _grouped_dot(dh3, w3, group_sizes, transposed=True)
+        dh1, dh3 = pull(t * w_row, dt)
+        dxs = _grouped_dot(dh1, w1, group_sizes, transposed=True)
+        if w3 is not None:
+            dxs = dxs + _grouped_dot(dh3, w3, group_sizes, transposed=True)
         dw1 = _grouped_outer(xs, dh1, group_sizes)
-        dw3 = _grouped_outer(xs, dh3, group_sizes)
+        dw3 = None if w3 is None else _grouped_outer(xs, dh3, group_sizes)
         dw2 = _grouped_outer((h * w_row).astype(dt), g_row, group_sizes)
     with jax.named_scope("moe_dispatch"):
         dx = dxs[inv].reshape(K, N, -1).sum(axis=0)
@@ -413,11 +412,11 @@ def _sorted_experts_bwd(res, g):
 _sorted_experts.defvjp(_sorted_experts_fwd, _sorted_experts_bwd)
 
 
-def _routed_experts(x, w1, w2, w3, topk_w, topk_idx, first, experts=None):
-    """Dropless grouped SwiGLU of tokens ``x [N, H]`` through the experts
-    ``first .. first + G`` whose weights are ``w1``/``w3 [G, H, I]`` and
-    ``w2 [G, I, H]``: ``(out [N, H], group_sizes [G])``. Pairs routed to
-    other experts contribute zero (another shard computes them).
+def _routed_experts(x, w1, w2, w3, topk_w, topk_idx, first, experts=None,
+                    act=None):
+    """Dropless grouped MLP (``act``; None: ``SWIGLU``) of tokens ``x [N,
+    H]`` through the experts ``first .. first + G`` of weights ``w1``/``w3
+    [G, H, I]`` and ``w2 [G, I, H]``: ``(out [N, H], group_sizes [G])``.
 
     The sorted row buffer is the static worst case, ``N*K`` rows (every
     pair here), or what ``_sorted_experts_for`` makes of ``G`` of a router's
@@ -434,8 +433,9 @@ def _routed_experts(x, w1, w2, w3, topk_w, topk_idx, first, experts=None):
             jnp.arange(M, dtype=jnp.int32), unique_indices=True)  # pair -> row
         group_sizes = jnp.sum(key[:, None] == jnp.arange(G)[None, :],
                               axis=0, dtype=jnp.int32)
+    ws = (w if w is None else w.astype(dt) for w in (w1, w3, w2))
     out = _sorted_experts_for(M, G, experts)(
-        x, w1.astype(dt), w3.astype(dt), w2.astype(dt),
+        act or SWIGLU, x, *ws,
         topk_w.T.astype(jnp.float32), order, inv, group_sizes)
     return out, group_sizes
 
@@ -464,14 +464,58 @@ def _routed_scale(cfg, topk_w):
     return topk_w if scale == 1.0 else topk_w * scale
 
 
+class Activation(NamedTuple):
+    """An expert's activation as ``_sorted_experts`` takes it: whether a
+    second first product ``h3 = xs w3`` gates it (no ``w3`` exists where
+    not); ``forward(h1, h3)``, the hidden rows in the compute dtype; and
+    ``rule(a1, a3)`` on the float32 first products, the hidden rows and
+    the function from their cotangent and a dtype to ``(d a1, d a3)`` in
+    that dtype -- the derivative written out beside the value it shares its
+    terms with."""
+
+    gated: bool
+    forward: Callable
+    rule: Callable
+
+
+def _swiglu_rule(a1, a3):
+    sig = jax.nn.sigmoid(a1)
+    return a1 * sig * a3, lambda dh, dt: (
+        (dh * a3 * sig * (1 + a1 * (1 - sig))).astype(dt),
+        (dh * a1 * sig).astype(dt))
+
+
+def _relu2_rule(a1, _):
+    r = jnp.maximum(a1, 0)
+    return r * r, lambda dh, dt: ((dh * 2 * r).astype(dt), None)
+
+
+#: ``silu(x w1) * (x w3)``: every Mixtral-family layer's
+SWIGLU = Activation(True, lambda h1, h3: nn.silu(h1) * h3, _swiglu_rule)
+#: ``relu(x w1) ** 2``, ungated (``models/nemotron_h.py``)
+RELU2 = Activation(False, lambda h1, _: jnp.square(nn.relu(h1)), _relu2_rule)
+_ACTIVATIONS = {"swiglu": SWIGLU, "relu2": RELU2}
+
+
+def _activation(cfg) -> Activation:
+    """The experts' activation: the config's ``expert_activation`` where it
+    has that field (a key of ``_ACTIVATIONS``), else ``SWIGLU``."""
+    return _ACTIVATIONS[getattr(cfg, "expert_activation", "swiglu")]
+
+
 @jax.named_scope("ds.moe_experts")
 def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx):
-    """The stacked expert SwiGLU and the weighted combine: ``(out [B, T, H],
+    """The stacked expert MLP and the weighted combine: ``(out [B, T, H],
     rows)``, ``rows [E]`` the (token, expert) pairs each expert computed
     (None on the decode path, which sorts nothing)."""
     B, T, H = x.shape
     dt = x.dtype
     E, K = cfg.num_local_experts, cfg.num_experts_per_tok
+    act = _activation(cfg)
+    if not act.gated and (T == 1 or _expert_axis_active()):
+        raise NotImplementedError(
+            "ungated experts are built for one device's training step: the "
+            "decode path and the expert axis' shard_map read w3")
     if T == 1 and E > K and cfg.router_experts is None \
             and not _expert_axis_active():
         # decode fast path (replicated experts): GATHER only the K
@@ -495,7 +539,7 @@ def _expert_mlp(cfg, x, w1, w2, w3, topk_w, topk_idx):
     def experts(x, w1, w2, w3, topk_w, topk_idx, first=cfg.first_expert):
         out, rows = _routed_experts(
             x.reshape(-1, H), w1, w2, w3, topk_w.reshape(-1, K),
-            topk_idx.reshape(-1, K), first, cfg.router_experts)
+            topk_idx.reshape(-1, K), first, cfg.router_experts, act)
         return out.reshape(x.shape), rows
 
     mesh = get_mesh()
@@ -844,8 +888,8 @@ def _sorted_experts_for(pairs, held, experts):
         functools.partial(_compact_experts, C)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _compact_experts(C, x, w1, w3, w2, topk_w, order, inv, group_sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _compact_experts(C, act, x, w1, w3, w2, topk_w, order, inv, group_sizes):
     """``_sorted_experts`` over the first ``C`` sorted rows where the held
     pairs fit them, over all ``K * N`` rows where they do not: one
     ``lax.cond`` in the forward pass and one in the backward pass, both
@@ -853,7 +897,7 @@ def _compact_experts(C, x, w1, w3, w2, topk_w, order, inv, group_sizes):
     ``lax.switch`` differentiated through under ``jax.checkpoint`` returned
     zero ``dx`` rows on XLA:TPU, PR 26). The residuals have the compact
     shape; an overflowing step's backward pass computes its own again."""
-    return _compact_experts_fwd(C, x, w1, w3, w2, topk_w, order, inv,
+    return _compact_experts_fwd(C, act, x, w1, w3, w2, topk_w, order, inv,
                                 group_sizes)[0]
 
 
@@ -871,15 +915,16 @@ def _in_a_branch(*args):
         return _sorted_experts_fwd(*args)
 
 
-def _compact_experts_fwd(C, x, w1, w3, w2, topk_w, order, inv, group_sizes):
+def _compact_experts_fwd(C, act, x, w1, w3, w2, topk_w, order, inv,
+                         group_sizes):
     def run(order, inv):
-        out, res = _in_a_branch(x, w1, w3, w2, topk_w, order, inv,
+        out, res = _in_a_branch(act, x, w1, w3, w2, topk_w, order, inv,
                                 group_sizes)
         return out, res[0], res[-2], res[-1]         # xs, h1, h3
 
     def full():     # its backward pass reads none of the three
-        out, xs, h1, h3 = run(order, inv)
-        return out, xs[:C], h1[:C], h3[:C]
+        out, *firsts = run(order, inv)
+        return out, *(t if t is None else t[:C] for t in firsts)
 
     out, xs, h1, h3 = jax.lax.cond(
         _fits(group_sizes, C),
@@ -888,17 +933,17 @@ def _compact_experts_fwd(C, x, w1, w3, w2, topk_w, order, inv, group_sizes):
                             h1, h3))
 
 
-def _compact_experts_bwd(C, res, g):
+def _compact_experts_bwd(C, act, res, g):
     x, xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3 = res
 
     def compact():
         return _sorted_experts_bwd(
-            (xs, w1, w3, w2, topk_w, *_compact_index(C, order, inv),
-             group_sizes, h1, h3), g)[:5]
+            act, (xs, w1, w3, w2, topk_w, *_compact_index(C, order, inv),
+                  group_sizes, h1, h3), g)[:5]
 
     def full():
-        return _sorted_experts_bwd(_in_a_branch(
-            x, w1, w3, w2, topk_w, order, inv, group_sizes)[1], g)[:5]
+        return _sorted_experts_bwd(act, _in_a_branch(
+            act, x, w1, w3, w2, topk_w, order, inv, group_sizes)[1], g)[:5]
 
     return (*jax.lax.cond(_fits(group_sizes, C), compact, full),
             None, None, None)
@@ -982,13 +1027,14 @@ def _named(xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3):
     return xs, w1, w3, w2, topk_w, order, inv, group_sizes, h1, h3
 
 
-def expert_offers(x, K, I, held, experts, applications: int):
+def expert_offers(x, K, I, held, experts, applications: int, firsts=2):
     """What ``_named`` names, as a block wrapper offers it: ``[(name, bytes
     over ``applications`` expert layers)]`` for a stream ``x [B, T, hidden]``
-    routed to ``K`` experts of width ``I``, ``held`` of the router's
-    ``experts`` here -- counted over the rows the layer sorts onto: the
-    compact buffer's where it has one, every pair's where it has none. The
-    index vectors (``order``, ``inv``, ``topk_w``: a word a pair each, and
+    routed to ``K`` experts of width ``I`` with ``firsts`` first products a
+    row (gate and up; 1 for an ungated ``Activation``), ``held`` of the
+    router's ``experts`` here -- counted over the rows the layer sorts onto:
+    the compact buffer's where it has one, every pair's where it has none.
+    The index vectors (``order``, ``inv``, ``topk_w``: a word a pair each, and
     ``group_sizes``) go with the rows. Under an ``expert`` mesh axis what
     ONE device names inside ``_expert_mlp``'s ``shard_map``: the rows of
     the batch's other axes' part, whole on ``expert`` (all-gathered, or
@@ -1005,7 +1051,7 @@ def expert_offers(x, K, I, held, experts, applications: int):
             held //= ep
     pairs, item = B * T * K, x.dtype.itemsize
     rows = _compact_rows(pairs, held, experts) or pairs
-    return ((REMAT_MOE_UP, applications * 2 * rows * I * item),
+    return ((REMAT_MOE_UP, applications * firsts * rows * I * item),
             (REMAT_MOE_ROWS,
              applications * (rows * H * item + 4 * (3 * pairs + held))))
 

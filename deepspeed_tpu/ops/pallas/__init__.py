@@ -106,3 +106,7 @@ REMAT_ROUTER = "ds_moe_router_kept"
 # whose output scale's gradient reads it: the replay then runs no forward of
 # the expert layer (the down product and the combine) for that sum alone
 REMAT_MOE_OUT = "ds_moe_out"
+# a scalar-decay state-space layer's (``models/nemotron_h.py``): its input
+# projection's output ``[z ; xBC ; dt]``, the widest product of the stack:
+# the replay then runs the convolution and the recurrence, not ``in_proj``
+REMAT_SSM_IN = "ds_ssm_in_proj"

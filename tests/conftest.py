@@ -14,7 +14,20 @@ import sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
+    _flags = (_flags + " --xla_force_host_platform_device_count=8").strip()
+# One device's program runs its thunks in program order. XLA:CPU's default
+# scheduler starts whatever is ready, so under six busy workers the eight
+# devices of one step could enter two independent collectives in different
+# orders (five threads in the gradients' all-reduce, three in an all-gather,
+# each waiting for the other's), and after 40 s the rendezvous ended the
+# process: the worker that was lost to ``test_deepseek_v3.py
+# test_router_weights_stay_where_they_were_when_not_trainable`` in the
+# driver's runs of PRs 63-65 and, with 64 busy processes beside it, in every
+# run of that test alone (PR 66). In order, every device meets the
+# collectives alike; warm, two multi-device files took the same 39 s.
+if "xla_cpu_enable_concurrency_optimized_scheduler" not in _flags:
+    _flags += " --xla_cpu_enable_concurrency_optimized_scheduler=false"
+os.environ["XLA_FLAGS"] = _flags
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -95,6 +108,25 @@ OVERTAKEN_BY_A_LATER_CELL = {
            "moe.expert_share", "train.host_gap_ms_per_step",
            "train.attn_proj_share", "moe.compact_hit_share",
            "moe.rows_max_over_mean", "moe.held_rows_over_expected")},
+    # the thirteen ``.trajectory`` twins' cases of PR 58's own test of them:
+    # ONE of its assertions ended with PR 66's cell (the cells whose files
+    # state the ``.trajectory`` rate are keye 16k and sdar 8k ALONE; nemotron
+    # 8k states it too, by the same rule: two sets of six runs spread over
+    # 0.5%); the others stay on, for all three cells, in
+    # ``tests/benchmark/test_benchmark_nemotron_h.py``
+    # (``test_a_twin_is_the_shared_reader_for_the_three_cells_that_state_its_rate``)
+    **{"tests/benchmark/test_benchmark_sdar.py::"
+       "test_a_twin_is_the_shared_reader_for_the_cells_that_state_its_rate"
+       f"[{twin}.trajectory]": "asserts keye 16k and sdar 8k alone state the "
+       "trajectory rate"
+       for twin in (
+           "train.step_ms_p50", "device.idle_share.train",
+           "train.attention_share", "train.head_loss_share",
+           "train.optimizer_share", "train.recompute_share",
+           "moe.expert_share", "train.host_gap_ms_per_step",
+           "moe.grouped_matmul_share", "train.attn_proj_share",
+           "moe.compact_hit_share", "moe.rows_max_over_mean",
+           "moe.held_rows_over_expected")},
 }
 
 

@@ -28,6 +28,8 @@ from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3Config,
 from deepspeed_tpu.models.laguna import LagunaConfig, LagunaForCausalLM
 from deepspeed_tpu.models.mellum import MellumConfig, MellumForCausalLM
 from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
+from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,
+                                             NemotronHForCausalLM)
 from deepspeed_tpu.models.ouro import OuroConfig, OuroForCausalLM
 from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
                                              Qwen3NextForCausalLM)
@@ -78,6 +80,15 @@ TRAIN_SCOPES = {
                "ds.layer_full", "ds.attn_proj", "ds.attention",
                "ds.attn_gate", "ds.mlp", "ds.moe_router", "ds.moe_experts",
                "ds.moe_shared", "ds.lm_head_loss"],
+    # every layer ONE branch under its kind's outer scope: ds.layer_mamba a
+    # scalar-decay state-space mixer (ds.ssm_scan its recurrence in the
+    # duality form, ds.ssm_mix the rest), ds.layer_full an attention layer
+    # without rotation, ds.layer_moe the experts beside the shared one
+    "nemotron_h": ["ds.loss_and_grad", "ds.optimizer", "ds.embed",
+                   "ds.layer_mamba", "ds.layer_full", "ds.layer_moe",
+                   "ds.ssm_mix", "ds.ssm_scan", "ds.attn_proj",
+                   "ds.attention", "ds.moe_router", "ds.moe_experts",
+                   "ds.moe_shared", "ds.lm_head_loss"],
     # two mixers a period over Mixtral's expert layer: each block under its
     # kind's outer scope; ds.gdn_rule is the chunked delta rule alone,
     # ds.gdn_mix the rest of a delta-rule mixer, ds.attn_gate the full
@@ -125,6 +136,8 @@ def train_text():
             ("sambay", SambaYForCausalLM(SambaYConfig.tiny(remat=True))),
             ("mellum", MellumForCausalLM(MellumConfig.tiny(remat=True))),
             ("laguna", LagunaForCausalLM(LagunaConfig.tiny(remat=True))),
+            ("nemotron_h", NemotronHForCausalLM(NemotronHConfig.tiny(
+                remat=True))),
             ("qwen3_next", Qwen3NextForCausalLM(Qwen3NextConfig.tiny(
                 remat=True))),
             ("ouro", OuroForCausalLM(OuroConfig.tiny(remat=True,
@@ -191,8 +204,11 @@ def trace_names():
 #: ``ds.exit_gate``, which stand only in ``models/ouro.py``'s step; PR 58
 #: added ``ds.bd_noise`` and ``ds.bd_gather``, which stand only in
 #: ``models/sdar.py``'s step; PR 63 added ``ds.layer_dense``, which stands
-#: only in ``models/laguna.py``'s step, whose ``ds.attn_gate`` is PR 52's name)
-NAMES_PIN = (3, "d72adb445a335316")
+#: only in ``models/laguna.py``'s step, whose ``ds.attn_gate`` is PR 52's
+#: name; PR 66 added ``ds.layer_mamba`` and ``ds.layer_moe``, which stand only
+#: in ``models/nemotron_h.py``'s step, whose ``ds.ssm_scan`` and ``ds.ssm_mix``
+#: are PR 41's names and ``ds.layer_full`` PR 49's)
+NAMES_PIN = (3, "b07853096644c0e0")
 
 
 def test_names_version_is_raised_with_the_names():
@@ -207,7 +223,8 @@ def test_names_version_is_raised_with_the_names():
             "ds.layer_full", "ds.rope_tables", "ds.layer_gdn", "ds.gdn_mix",
             "ds.gdn_rule", "ds.attn_gate", "ds.loop_stack",
             "ds.exit_gate", "ds.bd_noise", "ds.bd_gather",
-            "ds.layer_dense"} <= set(scopes) \
+            "ds.layer_dense", "ds.layer_mamba",
+            "ds.layer_moe"} <= set(scopes) \
         and {"counters", "init", "init_shapes", "init_params",
              "init_opt_state", "init_step", "cost_capture",
              "setup"} <= set(spans)
@@ -609,6 +626,10 @@ def _offering_zaya():
     return _offering(ZayaForCausalLM(ZayaConfig.tiny(remat=True)))
 
 
+def _offering_nemotron_h():
+    return _offering(NemotronHForCausalLM(NemotronHConfig.tiny(remat=True)))
+
+
 def _offering_qwen3_next():
     """The delta rule's kernels as on the chip (the choosers answered with a
     tiling, the kernels lowered, not interpreted: nothing runs)."""
@@ -646,6 +667,7 @@ OFFERED_NAMES = {
     names.REMAT_CCA_MIX: ("ds_cca_mix_out", _offering_zaya),
     names.REMAT_ROUTER: ("ds_moe_router_kept", _offering_zaya),
     names.REMAT_MOE_OUT: ("ds_moe_out", _offering_zaya),
+    names.REMAT_SSM_IN: ("ds_ssm_in_proj", _offering_nemotron_h),
 }
 
 
@@ -971,6 +993,20 @@ def test_no_other_familys_step_holds_the_delta_rules_names(train_text):
         found = set(re.findall(
             r"ds\.(?:layer_gdn|gdn_[a-z]+|attn_gate|layer_dense)\b", text))
         assert found == own.get(family, set()), family
+
+
+def test_no_other_familys_step_holds_the_single_branch_layers_names(
+        train_text):
+    """``ds.layer_mamba`` and ``ds.layer_moe`` stand in
+    ``models/nemotron_h.py``'s step alone (``ds.ssm_scan`` and ``ds.ssm_mix``
+    there and in ``models/sambay.py``'s, whose names they are): the other
+    families' programs are what they were, and ``NAMES_VERSION`` stays."""
+    for family, text in train_text.items():
+        found = set(re.findall(r"ds\.(?:layer_mamba|layer_moe)\b", text))
+        assert found == ({"ds.layer_mamba", "ds.layer_moe"}
+                         if family == "nemotron_h" else set()), family
+        assert bool(re.search(r"ds\.ssm_(?:scan|mix)\b", text)) == (
+            family in ("nemotron_h", "sambay")), family
 
 
 def test_no_other_familys_step_holds_the_loops_names(train_text):
