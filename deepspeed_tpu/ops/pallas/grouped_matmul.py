@@ -22,9 +22,9 @@ group come back exactly zero (their tiles are visited to be zeroed: a store,
 no product). The arithmetic is ``ragged_dot``'s: operands as they come,
 float32 accumulation, one rounding to the operands' dtype.
 
-``plan`` is the rule that says where the kernels run and with which tiles, a
-pure function of what the call site can see: backend, devices under the
-mesh, ``(M, A, B, G)``, the device kind. No option selects any of it.
+``plan`` is the rule that says where the kernels run and with which tiles (a
+width of no whole lanes WHOLE, counted as Mosaic holds it), a pure function
+of what the call site sees: backend, mesh, shape, device kind. No option.
 """
 
 import functools
@@ -78,12 +78,18 @@ def device_kind() -> str:
     return jax.devices()[0].device_kind
 
 
+def _lanes(n):      # n as Mosaic holds it along the lanes: 1856 -> 1920
+    return -(-n // 128) * 128
+
+
 def _cols(A, B, bytes_per, budget):
-    """``B`` whole, or its halves of whole lanes, where the resident block
-    of ``A`` rows fits ``budget``."""
+    """``B`` whole, or its halves of whole lanes (a ``B`` of no whole lanes
+    has none), where the resident block of ``A`` rows fits ``budget`` as
+    Mosaic holds it: either width may lie along the lanes."""
     for parts in (1, 2):
         bn = B // parts
-        if B % parts == 0 and bn % 128 == 0 and A * bn * bytes_per <= budget:
+        if B % parts == 0 and (bn % 128 == 0 or parts == 1) \
+                and _lanes(A) * _lanes(bn) * bytes_per <= budget:
             return bn
     return None
 
@@ -97,14 +103,19 @@ def plan(platform: str, mesh_devices: int, M: int, A: int, B: int, G: int,
     devices (a Mosaic call is not partitioned; ep4's products run inside a
     ``shard_map`` and read 61-67% of the peak on XLA's kernel), operands
     that are not two bytes wide, rows that are no whole tiles or fewer than
-    a block a group, widths that are no whole lanes (the tiny test sizes,
-    ``T == 1``), and a group's weight that does not fit the
-    weight-stationary budget (ep4's 4096 x 3584 a chip, Mixtral's whole
-    4096 x 14336)."""
+    a block a group (``T == 1``), a group's weight that does not fit the
+    weight-stationary budget (ep4's 4096 x 3584 a chip, Mixtral's whole 4096
+    x 14336), and widths never timed: no whole 16-row sublane tiles (900
+    columns, the tiny test sizes), or two of no whole lanes. ONE may be no
+    whole lanes (Nemotron-3's 2688 x 1856, 14.5; 1808 and 1872 timed beside
+    it) and is taken WHOLE, as columns and as contraction: a block may be the
+    dimension itself; Mosaic holds it at the next whole lane, which the budget
+    and the kernels count (``ds_moe_gmm_t``: 2688 x 1920 x 8 B of 41.9 MB)."""
     if platform != "tpu" or mesh_devices > 1 or itemsize != 2 \
             or device_kind not in _VMEM_BYTES:
         return None
-    if M % _ROWS or A % 128 or B % 128 or M // G < _BLOCK:
+    if M % _ROWS or M // G < _BLOCK or A % 16 or B % 16 \
+            or (A % 128 and B % 128):
         return None
     budget = _VMEM_BYTES[device_kind] * 5 // 16
     cols = _cols(A, B, 2 * itemsize, budget)
@@ -112,17 +123,6 @@ def plan(platform: str, mesh_devices: int, M: int, A: int, B: int, G: int,
     if cols is None or cols_t is None:
         return None
     return Tiles(_ROWS, cols, cols_t)
-
-
-@functools.lru_cache(maxsize=None)
-def log_plan(M, A, B, G, tiles) -> None:
-    """Once per shape, at trace time, beside ``moe expert layout: ...``."""
-    log_dist(
-        f"moe grouped products: [{M}, {A}] x [{G}, {A}, {B}] -> "
-        + ("jax.lax.ragged_dot" if tiles is None else
-           f"{GMM} (rows {tiles.rows}, cols {tiles.cols}), "
-           f"{GMM_T} (rows {tiles.rows}, cols {tiles.cols_t})"),
-        ranks=[0])
 
 
 # -- the visits --------------------------------------------------------------
@@ -261,8 +261,8 @@ def _gmm(group_of, tile_of, read_of, flags, offsets, count, lhs, rhs, *, rows,
     else:
         rhs_spec = pl.BlockSpec((1, A, cols), lambda j, t, g, *_: (g[t], 0, j))
     size = jnp.dtype(lhs.dtype).itemsize
-    resident = 2 * size * (A * cols + rows * A + rows * cols) \
-        + 4 * rows * cols
+    a, c = _lanes(A), _lanes(cols)              # as Mosaic holds them
+    resident = 2 * size * (a * c + rows * a + rows * c) + 4 * rows * c
     return pl.pallas_call(
         functools.partial(_gmm_kernel, tm=rows, sub=sub,
                           transpose_rhs=transpose_rhs),
@@ -343,8 +343,8 @@ def _tgmm(group_of, tile_of, read_of, flags, offsets, count, lhs, rhs, *,
     B = rhs.shape[1]
     groups = offsets.shape[0] - 1
     size = jnp.dtype(lhs.dtype).itemsize
-    resident = (4 + 2 * size) * A * cols + 2 * size * rows * (A + cols) \
-        + 4 * A * cols
+    a, c = _lanes(A), _lanes(cols)              # as Mosaic holds them
+    resident = (8 + 2 * size) * a * c + 2 * size * rows * (a + c)
     return pl.pallas_call(
         functools.partial(_tgmm_kernel, tm=rows, sub=sub),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -367,3 +367,14 @@ def _tgmm(group_of, tile_of, read_of, flags, offsets, count, lhs, rhs, *,
         interpret=interpret,
         name=GMM_T,
     )(group_of, tile_of, read_of, flags, offsets, lhs, rhs)
+
+
+@functools.lru_cache(maxsize=None)
+def log_plan(M, A, B, G, tiles) -> None:
+    """Once per shape, at trace time, beside ``moe expert layout: ...``."""
+    log_dist(
+        f"moe grouped products: [{M}, {A}] x [{G}, {A}, {B}] -> "
+        + ("jax.lax.ragged_dot" if tiles is None else
+           f"{GMM} (rows {tiles.rows}, cols {tiles.cols}), "
+           f"{GMM_T} (rows {tiles.rows}, cols {tiles.cols_t})"),
+        ranks=[0])

@@ -67,8 +67,9 @@ def pytest_report_header(config):
     return f"jax {jax.__version__} | devices: {jax.device_count()} ({jax.devices()[0].platform})"
 
 
-#: Standing tests that assert a state of the benchmark's files which a later
-#: cell ended, each with what it asserts: mellum2's entries LAST and
+#: Standing tests that assert a state of the benchmark's files (the last
+#: entry: of the program) which a later PR ended, each with what it asserts:
+#: mellum2's entries LAST and
 #: ``train.full_layer_share`` listing mellum2 alone (PR 52 appended a cell);
 #: keye 16k the ONE workload file that states ``weight_seed`` and
 #: ``rate_metric`` (PR 58's cell states both, by keye's rules and for its
@@ -127,6 +128,16 @@ OVERTAKEN_BY_A_LATER_CELL = {
            "moe.grouped_matmul_share", "train.attn_proj_share",
            "moe.compact_hit_share", "moe.rows_max_over_mean",
            "moe.held_rows_over_expected")},
+    # ONE assertion ended with PR 67's rule: ``grouped_matmul.plan("tpu", 1,
+    # 6144, 2688, 1856, 8) is None`` -- a width of whole half lanes is taken
+    # whole, so the held experts' products run in ``ds_moe_gmm*`` under
+    # ``ds.moe_experts``. The recording's half of the test still holds of the
+    # recording (PR 66's program: ``ragged-dot-none`` and ``conditional``
+    # first of the unscoped 21.1%); a ``benchmark`` PR records the cell anew
+    # or splits the test
+    "tests/benchmark/test_benchmark_nemotron_h.py::"
+    "test_the_recorded_experts_products_carry_no_scope":
+    "asserts the rule leaves 1856 columns to ragged_dot",
 }
 
 

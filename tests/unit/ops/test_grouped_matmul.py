@@ -109,6 +109,52 @@ def test_a_stacked_weights_gradient(cell, load):
         assert not np.asarray(out, np.float32)[empty].any()
 
 
+#: Nemotron-3's expert ``(hidden, intermediate)`` over 16: 168 = 1.3 lanes and
+#: 116 = 0.9, as 2688 x 1856 one width of whole lanes' worth of sublanes and
+#: no whole lanes -- here both are none, which interpret mode does not mind
+HID, INTER = 168, 116
+PRODUCTS = {
+    # name: (kernel, contraction or rows' width, output columns, transposed)
+    "up_columns": ("gmm", HID, INTER, False),
+    "down_contraction": ("gmm", INTER, HID, False),
+    "dt_columns_transposed": ("gmm", HID, INTER, True),
+    "dx_contraction_transposed": ("gmm", INTER, HID, True),
+    "dw1_columns": ("tgmm", HID, INTER, None),
+    "dw2_rows": ("tgmm", INTER, HID, None),
+}
+
+
+@pytest.mark.parametrize("load", ["empty_groups", "compact_buffer_half_full",
+                                  "fallback_length"])
+@pytest.mark.parametrize("product", sorted(PRODUCTS))
+def test_a_width_of_no_whole_lanes_is_one_whole_block(product, load):
+    """Nemotron 8k's six products at a small width of no whole lanes, as
+    output columns and as contraction, the block being the whole dimension:
+    ``ragged_dot`` / ``ragged_dot_general`` to bf16's rounding, rows of no
+    group exactly zero, an empty group's block zero."""
+    kernel, A, B, transposed = PRODUCTS[product]
+    M, sizes = LOADS[load]
+    lhs, w, rhs = operands(M, A, B, seed=1)
+    total = sum(sizes)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    kw = dict(rows=ROWS, cols=B, sub=BLOCK, interpret=True)
+    if kernel == "gmm":
+        want = ragged_dot(lhs, w, sizes)
+        out = gm.gmm(lhs, jnp.swapaxes(w, 1, 2) if transposed else w, sizes,
+                     transpose_rhs=transposed, **kw)
+        assert out.dtype == BF16 and out.shape == (M, B)
+        assert not np.asarray(out[total:], np.float32).any()
+    else:
+        lhs = lhs.at[total:].set(jnp.nan)
+        rhs = rhs.at[total:].set(jnp.inf)
+        want = ragged_outer(lhs[:total], rhs[:total], sizes)
+        out = gm.tgmm(lhs, rhs, sizes, **kw)
+        assert out.dtype == BF16 and out.shape == (G, A, B)
+        empty = np.asarray(sizes) == 0
+        assert not np.asarray(out, np.float32)[empty].any()
+    assert rel(out, want) < 4e-3
+
+
 @pytest.mark.parametrize("every_group", [False, True])
 @pytest.mark.parametrize("load", sorted(LOADS))
 def test_the_visits_follow_the_real_rows(load, every_group):
@@ -188,7 +234,7 @@ def test_expert_layer_gradient_through_the_kernels(monkeypatch, held,
 
 # -- the rule -----------------------------------------------------------------
 
-#: the six cells' layers: ``(devices under the mesh, rows of the buffer(s),
+#: the seven cells' layers: ``(devices under the mesh, rows of the buffer(s),
 #: hidden, intermediate, groups held)``
 SHAPES = {
     "olmoe-1b-7b.train.4k": (1, (65536,), 2048, 1024, 64),
@@ -197,6 +243,7 @@ SHAPES = {
     "keye-vl2-30b-a3b.train.16k": (1, (32768, 131072), 2048, 768, 16),
     "mellum2-12b-a2.5b.train.8k": (1, (16384, 65536), 2304, 896, 8),
     "mixtral-8x7b.train.ep4": (4, (32768,), 4096, 3584, 8),
+    "nemotron-3-nano-30b-a3b.train.8k": (1, (6144, 49152), 2688, 1856, 8),
 }
 
 
@@ -224,7 +271,10 @@ def test_the_rule_takes_the_one_chip_cells_and_leaves_ep4(cell):
     (8, 2048, 1024, 64, "a decode step's rows"),
     (4096, 2048, 1024, 64, "fewer rows than a block a group"),
     (16384, 4096, 14336, 8, "Mixtral's whole expert: the weight never fits"),
-    (16384, 2304, 900, 8, "columns that are no whole lanes"),
+    (16384, 2304, 900, 8, "columns that are no whole sublane tile either"),
+    (16384, 2304, 1864, 8, "whole 8-row tiles, no whole 16-row ones"),
+    (16384, 1856, 1856, 8, "two widths of no whole lanes"),
+    (16384, 4096, 1856, 8, "no whole lanes, and too wide to hold whole"),
 ])
 def test_the_rule_leaves_what_it_cannot_tile(M, A, B, groups, why):
     assert gm.plan("tpu", 1, M, A, B, groups) is None, why
@@ -241,6 +291,26 @@ def test_wide_weights_are_held_in_column_halves():
     assert gm.plan("tpu", 1, 16384, 4096, 2048, 8) == \
         gm.Tiles(512, 2048, 1024)
     assert gm.plan("tpu", 1, 16384, 4096, 3072, 8) is None
+
+
+@pytest.mark.parametrize("A,B", [(2688, 1856), (1856, 2688), (2688, 1808),
+                                 (2688, 1872), (1024, 1856), (3008, 1024)])
+def test_a_width_of_no_whole_lanes_is_never_split(A, B):
+    """Half of 1856 is 928, no lanes either: such a width is one block, as
+    columns and as contraction, or the shape stays on ``ragged_dot``."""
+    assert gm.plan("tpu", 1, 6144, A, B, 8) == gm.Tiles(512, B, B)
+
+
+def test_the_budget_counts_a_width_as_mosaic_holds_it():
+    """At the next whole lane: 2688 x 1920 x 8 B fit the accumulator's 5/16
+    of VMEM whole, 2688 x 2048 x 8 B of a shape 128 columns wider do not and
+    split; 3712 columns are whole lanes whose halves are not, and do not fit
+    whole: no tiles."""
+    budget = (128 << 20) * 5 // 16
+    assert gm._lanes(1856) == 1920 and gm._lanes(2048) == 2048
+    assert 2688 * 1920 * 8 <= budget < 2688 * 2048 * 8
+    assert gm.plan("tpu", 1, 6144, 2688, 2048, 8) == gm.Tiles(512, 2048, 1024)
+    assert gm._cols(2688, 2 * 1856, 8, budget) is None
 
 
 def test_the_layer_asks_the_rule_what_it_sees(monkeypatch):

@@ -522,52 +522,73 @@ def test_olmoe_expert_layer_reaches_the_grouped_kernel_on_one_v5e(
 
 
 #: a held share's layer at its cell's shapes: ``(tokens, top-k, hidden,
-#: intermediate, experts held, experts the router scores, compact rows)``
+#: intermediate, experts held, experts the router scores, compact rows, the
+#: experts' activation)``
 HELD_SHARES = {
-    "zaya_8k": (8192, 1, 2048, 2048, 8, 17, None),      # 8 of 17: no compact
-    "mellum2_8k": (8192, 8, 2304, 896, 8, 64, 16384),
-    "keye_16k": (16384, 8, 2048, 768, 16, 128, 32768),
+    "zaya_8k": (8192, 1, 2048, 2048, 8, 17, None, "swiglu"),   # no compact
+    "mellum2_8k": (8192, 8, 2304, 896, 8, 64, 16384, "swiglu"),
+    "keye_16k": (16384, 8, 2048, 768, 16, 128, 32768, "swiglu"),
+    # 1856 columns, 14.5 lanes: one block, the whole dimension (PR 67)
+    "nemotron_8k": (8192, 6, 2688, 1856, 8, 128, 6144, "relu2"),
 }
 
 
 @pytest.mark.parametrize("cell", sorted(HELD_SHARES))
 def test_held_share_reaches_the_small_group_kernels_on_one_v5e(
         chip, on_v5e, cell):
-    """``_routed_experts`` at ZAYA1-8B's, Mellum2's and Keye-VL2's one-chip
-    shares, forward and backward, compiled for one v5e: every grouped
-    product is ``ds_moe_gmm`` / ``ds_moe_gmm_t`` -- nine over the one buffer
-    (ZAYA), or nine over the compact buffer and eleven over the fallback's
-    rows (an overflowing step's backward computes ``h1`` and ``h3`` again),
-    each pair behind its ``conditional``."""
+    """``_routed_experts`` at ZAYA1-8B's, Mellum2's, Keye-VL2's and
+    Nemotron-3's one-chip shares, forward and backward, compiled for one
+    v5e: every grouped product is ``ds_moe_gmm`` / ``ds_moe_gmm_t`` -- nine
+    over the one buffer (ZAYA), or nine over the compact buffer and eleven
+    over the fallback's rows (an overflowing step's backward computes ``h1``
+    and ``h3`` again); an ungated expert (Nemotron-3: no ``w3``) has six and
+    seven -- each pair behind its ``conditional``, and each call under the
+    layer's ``ds.moe_experts`` scope."""
     import deepspeed_tpu.models.mixtral as mx
 
-    N, K, HID, INTER, G, experts, C = HELD_SHARES[cell]
+    N, K, HID, INTER, G, experts, C, act = HELD_SHARES[cell]
+    act = mx._ACTIVATIONS[act]
     assert mx._compact_rows(N * K, G, experts) == C
 
     def struct(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
+    w3 = struct((G, HID, INTER), BF16) if act.gated else None
     args = (struct((N, HID), BF16), struct((G, HID, INTER), BF16),
-            struct((G, INTER, HID), BF16), struct((G, HID, INTER), BF16),
+            struct((G, INTER, HID), BF16), w3,
             struct((N, K), jnp.float32), struct((N, K), jnp.int32))
 
     def loss(x, w1, w2, w3, topk_w, topk_idx):
-        out, rows = mx._routed_experts(x, w1, w2, w3, topk_w, topk_idx, 0,
-                                       experts)
+        with jax.named_scope("ds.moe_experts"):
+            out, rows = mx._routed_experts(x, w1, w2, w3, topk_w, topk_idx,
+                                           0, experts, act)
         return jnp.sum(out.astype(jnp.float32) ** 2), rows
 
     hlo = jax.jit(jax.value_and_grad(
-        loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(
-            *args).compile().as_text()
+        loss, argnums=(0, 1, 2, 3, 4) if act.gated else (0, 1, 2, 4),
+        has_aux=True)).lower(*args).compile().as_text()
     products = _grouped_products(hlo)
     rows = sorted(int(dims.split(",")[0]) for kernel, _, dims in products
                   if kernel == "ds_moe_gmm")
     outer = [dims for kernel, _, dims in products if kernel == "ds_moe_gmm_t"]
     assert set(outer) == {f"{G},{HID},{INTER}", f"{G},{INTER},{HID}"}
+    firsts = 2 if act.gated else 1            # first products: w1, and w3
+    site = 2 * firsts + 2                     # forward, their dx, down, g w2^T
     if C is None:
-        assert rows == 6 * [N * K] and len(outer) == 3
+        assert rows == site * [N * K] and len(outer) == firsts + 1
     else:
-        assert rows == 6 * [C] + 8 * [N * K] and len(outer) == 6
+        assert rows == site * [C] + (site + firsts) * [N * K]
+        assert len(outer) == 2 * (firsts + 1)
+        comps = _computations(hlo)
+        branches = {name for pair in re.findall(
+            r"branch_computations=\{([^}]*)\}", hlo)
+            for name in re.findall(r"%([\w.\-]+)", pair)}
+        assert all(_GMM.search(comps[name]) for name in branches)
+        assert not _GMM.search(next(
+            text for text in comps.values() if text.startswith("ENTRY")))
+    calls = [line for line in hlo.splitlines() if _GMM.search(line)]
+    assert calls and all("ds.moe_experts" in re.search(
+        r'op_name="([^"]*)"', line).group(1) for line in calls)
 
 
 def test_a_step_lowers_each_distinct_grouped_kernel_once(on_v5e):
