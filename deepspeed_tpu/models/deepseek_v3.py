@@ -81,6 +81,11 @@ class DeepseekV3Config(LlamaConfig):
     #: de-interleaved (a fixed permutation) before rotate-half RoPE, as the
     #: published class does by default
     rope_interleave: bool = True
+    #: no column rotates (the published key of ``kimi_linear``: the model's
+    #: recurrent layers carry position): the ``qk_rope_head_dim`` columns of
+    #: the queries and the one shared key join the product as projected, and
+    #: ``cos`` / ``sin`` are never read
+    mla_use_nope: bool = False
     # -- layers of two kinds ---------------------------------------------
     #: leading layers whose feed-forward is a dense SwiGLU of
     #: ``intermediate_size``; every later layer is an expert layer
@@ -219,11 +224,14 @@ class DeepseekV3Attention(nn.Module):
             kv = dense(H * (dn + dv), "kv_b_proj")(
                 _kv_norm(cfg, latent)).reshape(B, T, H, dn + dv)
             k_nope, v = jnp.split(kv, [dn], axis=-1)
-            q_nope, q_rot = jnp.split(q, [dn], axis=-1)
-            q_rot = _rotate(q_rot, cos, sin, cfg.rope_interleave)
-            k_rot = _rotate(k_rot[:, :, None, :], cos, sin,
-                            cfg.rope_interleave)
-            q = jnp.concatenate([q_nope, q_rot], axis=-1)
+            if cfg.mla_use_nope:
+                k_rot = k_rot[:, :, None, :]
+            else:
+                q_nope, q_rot = jnp.split(q, [dn], axis=-1)
+                q_rot = _rotate(q_rot, cos, sin, cfg.rope_interleave)
+                k_rot = _rotate(k_rot[:, :, None, :], cos, sin,
+                                cfg.rope_interleave)
+                q = jnp.concatenate([q_nope, q_rot], axis=-1)
             k = jnp.concatenate(
                 [k_nope, jnp.broadcast_to(k_rot, (B, T, H, dr))], axis=-1)
             # as the flash kernel takes them: the replay then runs neither

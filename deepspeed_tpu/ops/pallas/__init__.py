@@ -110,3 +110,7 @@ REMAT_MOE_OUT = "ds_moe_out"
 # projection's output ``[z ; xBC ; dt]``, the widest product of the stack:
 # the replay then runs the convolution and the recurrence, not ``in_proj``
 REMAT_SSM_IN = "ds_ssm_in_proj"
+# a vector-decay delta-rule layer's (``models/kimi_linear.py``): the rule's
+# output (each pass of the rule is rematerialised by itself: a replay that
+# holds the output runs no rule)
+REMAT_KDA_RULE = "ds_kda_rule_out"

@@ -30,6 +30,8 @@ from deepspeed_tpu.models.mellum import MellumConfig, MellumForCausalLM
 from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
 from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,
                                              NemotronHForCausalLM)
+from deepspeed_tpu.models.kimi_linear import (KimiLinearConfig,
+                                              KimiLinearForCausalLM)
 from deepspeed_tpu.models.ouro import OuroConfig, OuroForCausalLM
 from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
                                              Qwen3NextForCausalLM)
@@ -89,6 +91,18 @@ TRAIN_SCOPES = {
                    "ds.ssm_mix", "ds.ssm_scan", "ds.attn_proj",
                    "ds.attention", "ds.moe_router", "ds.moe_experts",
                    "ds.moe_shared", "ds.lm_head_loss"],
+    # two mixers by LIST over deepseek_v3.py's feed-forward parts: each block
+    # under its mixer's outer scope (ds.layer_kda / ds.layer_mla) and, inside
+    # it, the name other stacks give its kind (ds.layer_dense the leading
+    # block, ds.layer_full a latent-attention one); ds.kda_rule is the
+    # chunked delta rule under a decay a channel alone, ds.kda_mix the rest
+    # of a KDA mixer but its projections
+    "kimi_linear": ["ds.loss_and_grad", "ds.optimizer", "ds.embed",
+                    "ds.layer_kda", "ds.layer_mla", "ds.layer_dense",
+                    "ds.layer_full", "ds.attn_proj", "ds.kda_mix",
+                    "ds.kda_rule", "ds.attention", "ds.mlp",
+                    "ds.moe_router", "ds.moe_experts", "ds.moe_shared",
+                    "ds.lm_head_loss"],
     # two mixers a period over Mixtral's expert layer: each block under its
     # kind's outer scope; ds.gdn_rule is the chunked delta rule alone,
     # ds.gdn_mix the rest of a delta-rule mixer, ds.attn_gate the full
@@ -140,6 +154,8 @@ def train_text():
                 remat=True))),
             ("qwen3_next", Qwen3NextForCausalLM(Qwen3NextConfig.tiny(
                 remat=True))),
+            ("kimi_linear", KimiLinearForCausalLM(KimiLinearConfig.tiny(
+                num_hidden_layers=5, remat=True))),
             ("ouro", OuroForCausalLM(OuroConfig.tiny(remat=True,
                                                      loss_chunk=64))),
             ("sdar", SdarForCausalLM(SdarConfig.tiny(remat=True,
@@ -207,8 +223,11 @@ def trace_names():
 #: only in ``models/laguna.py``'s step, whose ``ds.attn_gate`` is PR 52's
 #: name; PR 66 added ``ds.layer_mamba`` and ``ds.layer_moe``, which stand only
 #: in ``models/nemotron_h.py``'s step, whose ``ds.ssm_scan`` and ``ds.ssm_mix``
-#: are PR 41's names and ``ds.layer_full`` PR 49's)
-NAMES_PIN = (3, "b07853096644c0e0")
+#: are PR 41's names and ``ds.layer_full`` PR 49's; PR 68 added
+#: ``ds.layer_kda``, ``ds.layer_mla``, ``ds.kda_mix`` and ``ds.kda_rule``,
+#: which stand only in ``models/kimi_linear.py``'s step, whose
+#: ``ds.layer_dense`` is PR 63's name and ``ds.layer_full`` PR 49's)
+NAMES_PIN = (3, "004a54239cfaf933")
 
 
 def test_names_version_is_raised_with_the_names():
@@ -223,8 +242,9 @@ def test_names_version_is_raised_with_the_names():
             "ds.layer_full", "ds.rope_tables", "ds.layer_gdn", "ds.gdn_mix",
             "ds.gdn_rule", "ds.attn_gate", "ds.loop_stack",
             "ds.exit_gate", "ds.bd_noise", "ds.bd_gather",
-            "ds.layer_dense", "ds.layer_mamba",
-            "ds.layer_moe"} <= set(scopes) \
+            "ds.layer_dense", "ds.layer_mamba", "ds.layer_moe",
+            "ds.layer_kda", "ds.layer_mla", "ds.kda_mix",
+            "ds.kda_rule"} <= set(scopes) \
         and {"counters", "init", "init_shapes", "init_params",
              "init_opt_state", "init_step", "cost_capture",
              "setup"} <= set(spans)
@@ -630,6 +650,11 @@ def _offering_nemotron_h():
     return _offering(NemotronHForCausalLM(NemotronHConfig.tiny(remat=True)))
 
 
+def _offering_kimi_linear():
+    return _offering(KimiLinearForCausalLM(KimiLinearConfig.tiny(
+        num_hidden_layers=5, remat=True)))
+
+
 def _offering_qwen3_next():
     """The delta rule's kernels as on the chip (the choosers answered with a
     tiling, the kernels lowered, not interpreted: nothing runs)."""
@@ -668,6 +693,7 @@ OFFERED_NAMES = {
     names.REMAT_ROUTER: ("ds_moe_router_kept", _offering_zaya),
     names.REMAT_MOE_OUT: ("ds_moe_out", _offering_zaya),
     names.REMAT_SSM_IN: ("ds_ssm_in_proj", _offering_nemotron_h),
+    names.REMAT_KDA_RULE: ("ds_kda_rule_out", _offering_kimi_linear),
 }
 
 
@@ -988,7 +1014,8 @@ def test_no_other_familys_step_holds_the_delta_rules_names(train_text):
     are what they were, and ``NAMES_VERSION`` stays."""
     own = {"qwen3_next": {"ds.layer_gdn", "ds.gdn_mix", "ds.gdn_rule",
                           "ds.attn_gate"},
-           "laguna": {"ds.attn_gate", "ds.layer_dense"}}
+           "laguna": {"ds.attn_gate", "ds.layer_dense"},
+           "kimi_linear": {"ds.layer_dense"}}
     for family, text in train_text.items():
         found = set(re.findall(
             r"ds\.(?:layer_gdn|gdn_[a-z]+|attn_gate|layer_dense)\b", text))
@@ -1007,6 +1034,19 @@ def test_no_other_familys_step_holds_the_single_branch_layers_names(
                          if family == "nemotron_h" else set()), family
         assert bool(re.search(r"ds\.ssm_(?:scan|mix)\b", text)) == (
             family in ("nemotron_h", "sambay")), family
+
+
+def test_no_other_familys_step_holds_the_vector_decay_rules_names(
+        train_text):
+    """``ds.layer_kda``, ``ds.layer_mla``, ``ds.kda_mix`` and ``ds.kda_rule``
+    stand in ``models/kimi_linear.py``'s step alone: the other families'
+    programs are what they were, and ``NAMES_VERSION`` stays."""
+    for family, text in train_text.items():
+        found = set(re.findall(r"ds\.(?:layer_kda|layer_mla|kda_[a-z]+)\b",
+                               text))
+        assert found == ({"ds.layer_kda", "ds.layer_mla", "ds.kda_mix",
+                          "ds.kda_rule"}
+                         if family == "kimi_linear" else set()), family
 
 
 def test_no_other_familys_step_holds_the_loops_names(train_text):
