@@ -55,8 +55,14 @@ def grads(rule, x, w):
     return jax.grad(lambda *a: jnp.sum(w * rule(*a)), argnums=range(5))(*x)
 
 
-@pytest.mark.parametrize("T,chunk", [(32, 8), (37, 8), (5, 8), (64, 64),
-                                     (100, 64)])
+@pytest.mark.parametrize("T,chunk", [
+    (37, 8), (64, 64),      # a ragged tail of small chunks; one whole chunk
+    # 28-35 s each cold (PR 69): whole small chunks, a sequence under one
+    # chunk and a ragged tail of the published chunk ask the same of the two
+    # kept above
+    pytest.param(32, 8, marks=pytest.mark.slow),
+    pytest.param(5, 8, marks=pytest.mark.slow),
+    pytest.param(100, 64, marks=pytest.mark.slow)])
 def test_kernels_are_the_xla_path_and_the_recurrence(kernels, T, chunk):
     x = rule_inputs(T, B=2)
     w = jax.random.normal(jax.random.PRNGKey(9), x[2].shape)

@@ -147,7 +147,7 @@ SHARE = dict(n_routed_experts=4, router_experts=16, first_expert=8,
 
 def _seeded(cfg, seed, ids):
     model = DeepseekV3ForCausalLM(cfg)
-    params = model.init(jax.random.PRNGKey(seed), ids)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed), ids)["params"]
     # norms start at one: make their gradients and their values count
     keys = iter(jax.random.split(jax.random.PRNGKey(seed + 100), 64))
     params = jax.tree_util.tree_map_with_path(
@@ -164,10 +164,13 @@ def share_grads():
     ids = jnp.asarray(np.random.RandomState(5).randint(0, 128, (2, 24)))
     model, params = _seeded(cfg, 3, ids)
     sizes = sizes_of(cfg)
-    sys_loss, sys_g = jax.value_and_grad(lambda p: model.apply(
-        {"params": p}, ids, labels=ids))(params)
-    ref_loss, ref_g = jax.value_and_grad(
-        lambda p: REF.loss(p, sizes, ids))(params)
+    # one program a side (run operation by operation a gradient was some
+    # thousand one-operation programs, compiled by every worker that drew a
+    # case of this file)
+    sys_loss, sys_g = jax.jit(jax.value_and_grad(lambda p: model.apply(
+        {"params": p}, ids, labels=ids)))(params)
+    ref_loss, ref_g = jax.jit(jax.value_and_grad(
+        lambda p: REF.loss(p, sizes, ids)))(params)
     hidden, rows = REF.hidden_states(params, sizes, ids[0])
     return {"sys_g": sys_g, "ref_g": ref_g, "rows": rows,
             "sys_logits": model.apply({"params": params}, ids)[0],
@@ -511,9 +514,10 @@ def test_unbuilt_paths_say_so():
                       ({"first_expert": 6, "router_experts": 8},
                        ValueError)):
         with pytest.raises(err):
-            DeepseekV3ForCausalLM(DeepseekV3Config.tiny(**over)).init(
+            jax.eval_shape(
+                DeepseekV3ForCausalLM(DeepseekV3Config.tiny(**over)).init,
                 jax.random.PRNGKey(0), ids)
     model = DeepseekV3ForCausalLM(DeepseekV3Config.tiny())
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"]
     with pytest.raises(NotImplementedError):
         model.apply({"params": params}, ids, cache={})

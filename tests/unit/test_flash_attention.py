@@ -482,23 +482,10 @@ def test_forward_orientation_output_lse_and_gradients(case, what):
     key mask as a column over un-repeated kv heads, a row of tiles with
     nothing to run), and the three gradients the UNCHANGED backward kernels
     make of the new forward's ``out`` / ``lse``."""
-    from deepspeed_tpu.ops.pallas.flash_attention import _flash_fwd
-
     tq, tk, h, hkv, d, dv, bq, bk, causal, window, pad = _ORIENTATION[case]
-    ks = jax.random.split(jax.random.PRNGKey(38), 4)
-    q = jax.random.normal(ks[0], (2, tq, h, d))
-    k = jax.random.normal(ks[1], (2, tk, hkv, d))
-    v = jax.random.normal(ks[2], (2, tk, hkv, dv))
-    key_mask = None
-    if case == "key_mask_gqa":
-        key_mask = jnp.ones((2, tk), jnp.int32).at[0, :pad].set(0)
-    scale = d ** -0.5
-    want_out, want_lse, seen = _plain_attention(q, k, v, scale, causal,
-                                                window, key_mask)
     if what in ("out", "lse"):
+        out, lse, want_out, want_lse, seen = _orientation_forward(case)
         bhtd = lambda x: jnp.transpose(x, (0, 2, 1, 3))
-        out, lse = _flash_fwd(bhtd(q), bhtd(k), bhtd(v), scale, causal, bq,
-                              bk, True, window, key_mask)
         assert out.shape == (2, h, tq, dv) and lse.shape == (2, h, tq)
         assert lse.dtype == jnp.float32
         got, want = ((bhtd(out), want_out) if what == "out" else
@@ -511,17 +498,61 @@ def test_forward_orientation_output_lse_and_gradients(case, what):
             assert np.asarray(seen)[:, 64:].all()
             assert not np.asarray(bhtd(out))[:, :64].any()
         return
-    w = jax.random.normal(ks[3], (2, tq, h, dv)) * seen[:, :, None, None]
+    got, want = _orientation_gradients(case)
+    i = "qkv".index(what[1])
+    np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want[i]),
+                               atol=5e-4, rtol=5e-4)
+
+
+def _orientation_operands(case):
+    tq, tk, h, hkv, d, dv, bq, bk, causal, window, pad = _ORIENTATION[case]
+    ks = jax.random.split(jax.random.PRNGKey(38), 4)
+    q = jax.random.normal(ks[0], (2, tq, h, d))
+    k = jax.random.normal(ks[1], (2, tk, hkv, d))
+    v = jax.random.normal(ks[2], (2, tk, hkv, dv))
+    key_mask = None
+    if case == "key_mask_gqa":
+        key_mask = jnp.ones((2, tk), jnp.int32).at[0, :pad].set(0)
+    return q, k, v, key_mask, jax.random.normal(ks[3], (2, tq, h, dv))
+
+
+@functools.lru_cache(maxsize=None)
+def _orientation_forward(case):
+    """``(out, lse)`` of the forward kernel and ``(out, lse, seen)`` of plain
+    attention: one program each, run once a process for the two cases that
+    read them (numpy COPIES, as ``_unremat_results`` below says why)."""
+    from deepspeed_tpu.ops.pallas.flash_attention import _flash_fwd
+
+    tq, tk, h, hkv, d, dv, bq, bk, causal, window, pad = _ORIENTATION[case]
+    q, k, v, key_mask, _ = _orientation_operands(case)
+    scale = d ** -0.5
+    bhtd = lambda x: jnp.transpose(x, (0, 2, 1, 3))
+    out, lse = jax.jit(lambda q, k, v: _flash_fwd(
+        bhtd(q), bhtd(k), bhtd(v), scale, causal, bq, bk, True, window,
+        key_mask))(q, k, v)
+    return tuple(map(np.array, (out, lse) + jax.jit(
+        lambda q, k, v: _plain_attention(
+            q, k, v, scale, causal, window, key_mask))(q, k, v)))
+
+
+@functools.lru_cache(maxsize=None)
+def _orientation_gradients(case):
+    """``((dq, dk, dv) through the kernels, (dq, dk, dv) of plain
+    attention)``: one program each, run once a process for the three cases
+    that read them (numpy COPIES)."""
+    tq, tk, h, hkv, d, dv, bq, bk, causal, window, pad = _ORIENTATION[case]
+    q, k, v, _, w = _orientation_operands(case)
+    scale = d ** -0.5
+    seen = _plain_attention(q, k, v, scale, causal, window, None)[2]
+    w = w * seen[:, :, None, None]
     flash = lambda q, k, v: flash_attention(
         q, k, v, causal=causal, sm_scale=scale, block_q=bq, block_k=bk,
         interpret=True, force_pallas=True, window=window)
     plain = lambda q, k, v: _plain_attention(q, k, v, scale, causal, window,
                                              None)[0]
-    i = "qkv".index(what[1])
-    got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * w), argnums=i)(q, k, v)
-                 for f in (flash, plain))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-4,
-                               rtol=5e-4)
+    return tuple(tuple(map(np.array, jax.jit(jax.grad(
+        lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2)))(q, k, v)))
+        for f in (flash, plain))
 
 
 # -- what jax.checkpoint keeps of the kernel ----------------------------------

@@ -62,7 +62,7 @@ def seeded(cfg, seed=3, ids=IDS):
     """(model, params): the model's own init with the norms' scales and
     biases moved off their defaults, so that leaving one out shows."""
     model = MixtralForCausalLM(cfg)
-    params = model.init(jax.random.PRNGKey(seed), ids)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed), ids)["params"]
     keys = iter(jax.random.split(jax.random.PRNGKey(seed + 100), 64))
     return model, jax.tree_util.tree_map_with_path(
         lambda kp, p: p + 0.3 * jax.random.normal(next(keys), p.shape)
@@ -89,11 +89,15 @@ def share():
     cfg = tiny()
     model, params = seeded(cfg)
     sizes = sizes_of(cfg)
+    # (operation by operation: ``test_each_loss_term_reaches_its_own_
+    # parameters`` holds these gradients BIT FOR BIT to those of a step
+    # without the KL term, which two fused programs are not)
     (loss, named), grads = jax.value_and_grad(
         lambda p: model.apply({"params": p}, IDS, labels=IDS),
         has_aux=True)(params)
-    term = lambda i: jax.grad(
-        lambda p: REF.loss_terms(p, sizes, np.asarray(IDS))[i])(params)
+    # the reference's two gradients: one program each
+    term = lambda i: jax.jit(jax.grad(
+        lambda p: REF.loss_terms(p, sizes, np.asarray(IDS))[i]))(params)
     return dict(cfg=cfg, model=model, params=params, sizes=sizes, loss=loss,
                 named=named, grads=grads, ref_lm_grads=term(0),
                 ref_kl_grads=term(1))

@@ -109,8 +109,8 @@ def test_heads_go_a_few_at_a_time_where_all_do_not_fit(monkeypatch):
     assert kl._heads_a_pass(1, 16384, 32, 128) == 2
     assert kl._heads_a_pass(2, 64, 4, 8) == 4
     args = operands(3, 24, H=4)
-    grad = lambda: jax.grad(lambda *a: jnp.sum(
-        kl.kda_rule(*a, chunk=8, block=4)[0] ** 2), argnums=(0, 3))(*args)
+    grad = lambda: jax.jit(jax.grad(lambda *a: jnp.sum(
+        kl.kda_rule(*a, chunk=8, block=4)[0] ** 2), argnums=(0, 3)))(*args)
     with HIGHEST:
         whole, whole_grads = kl.kda_rule(*args, chunk=8, block=4), grad()
         monkeypatch.setattr(kl, "_PASS_BYTES", 4 * 2 * 24 * 8 * 2)
@@ -139,7 +139,7 @@ def seeded():
     model = kl.KimiLinearForCausalLM(cfg)
     ids = jax.random.randint(jax.random.PRNGKey(0), (1, 21), 0,
                              cfg.vocab_size)
-    params = model.init(jax.random.PRNGKey(1), ids)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), ids)["params"]
     # a trained model's decays and biases, not the seeds' alone
     return cfg, model, params, ids
 
@@ -155,12 +155,17 @@ def test_model_matches_the_reference_logits_loss_and_every_gradient(seeded):
             lambda p: model.apply({"params": p}, ids, labels=ids))(p)
         return model.apply({"params": p}, ids), loss, grads
 
+    @jax.jit        # one program: run operation by operation the
+    def reference(p):   # reference was some thousand of them
+        def loss(p):
+            hidden, rows = ref.hidden_states(p, sizes, ids[0])
+            return ref.loss(p, sizes, np.asarray(ids)), (
+                ref.logits(p, hidden), rows)
+        return jax.value_and_grad(loss, has_aux=True)(p)
+
     with HIGHEST:
         logits, loss, grads = system(params)
-        want_loss, want_grads = jax.value_and_grad(
-            lambda p: ref.loss(p, sizes, np.asarray(ids)))(params)
-        hidden, rows = ref.hidden_states(params, sizes, ids[0])
-        want_logits = ref.logits(params, hidden)
+        (want_loss, (want_logits, rows)), want_grads = reference(params)
     assert common.rel_l2(logits[0], want_logits) < 1e-5
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
     assert float(rows.sum()) > 0          # the held range computed pairs
@@ -214,7 +219,7 @@ def test_the_held_ranges_add_up_to_the_uncut_layer():
                                      num_experts_per_tok=4)
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 12, whole.hidden_size))
     layer = deepseek_v3.DeepseekV3MoE(whole)
-    params = layer.init(jax.random.PRNGKey(4), x)["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(4), x)["params"]
     shared_only = dataclasses.replace(whole, num_experts_per_tok=1,
                                       routed_scaling_factor=0.0)
     with HIGHEST:

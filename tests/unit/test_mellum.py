@@ -88,11 +88,14 @@ def share():
     cfg = tiny()
     model, params = seeded(cfg)
     sizes = sizes_of(cfg)
-    (loss, named), grads = jax.value_and_grad(
+    # one program a side (run operation by operation a gradient was some
+    # thousand one-operation programs, compiled by every worker that drew a
+    # case of this file)
+    (loss, named), grads = jax.jit(jax.value_and_grad(
         lambda p: model.apply({"params": p}, IDS, labels=IDS),
-        has_aux=True)(params)
-    ref_grads = jax.grad(
-        lambda p: REF.loss(p, sizes, np.asarray(IDS)))(params)
+        has_aux=True))(params)
+    ref_grads = jax.jit(jax.grad(
+        lambda p: REF.loss(p, sizes, np.asarray(IDS))))(params)
     return dict(cfg=cfg, model=model, params=params, sizes=sizes, loss=loss,
                 named=named, grads=grads, ref_grads=ref_grads)
 
@@ -500,12 +503,14 @@ def test_partition_rules_and_frozen_parameters_reach_the_periods():
 
 # -- what is not built -------------------------------------------------------
 
-def test_what_is_not_built_raises(share):
-    model, params = share["model"], share["params"]
+def test_what_is_not_built_raises():
+    """(Each raised before any arithmetic: parameters by shape.)"""
+    model = MellumForCausalLM(tiny())
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), IDS)["params"]
     with pytest.raises(NotImplementedError, match="training"):
         model.apply({"params": params}, IDS, cache={}, cache_index=0)
-    init = lambda cfg: MellumForCausalLM(cfg).init(jax.random.PRNGKey(0),
-                                                   IDS)
+    init = lambda cfg: jax.eval_shape(MellumForCausalLM(cfg).init,
+                                      jax.random.PRNGKey(0), IDS)
     with pytest.raises(ValueError, match="whole periods"):
         init(tiny(full_attention_period=3))
     with pytest.raises(NotImplementedError, match="selection"):

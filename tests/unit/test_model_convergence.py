@@ -94,13 +94,15 @@ def remat_loss_and_grads(monkeypatch, model_of, ids):
 
     monkeypatch.setattr(fa, "flash_attention", functools.partial(
         fa.flash_attention, force_pallas=True))
-    params = model_of(False).init(jax.random.PRNGKey(0), ids)["params"]
+    params = jax.jit(model_of(False).init)(jax.random.PRNGKey(0),
+                                           ids)["params"]
 
-    def run(remat):
-        fn = jax.value_and_grad(lambda params: model_of(remat).apply(
-            {"params": params}, ids, labels=ids))
-        assert "name=ds_flash_fwd" in str(jax.make_jaxpr(fn)(params))
-        return jax.jit(fn)(params)
+    def run(remat):     # ONE trace: the jaxpr that is read is the one run
+        traced = jax.jit(jax.value_and_grad(
+            lambda params: model_of(remat).apply(
+                {"params": params}, ids, labels=ids))).trace(params)
+        assert "name=ds_flash_fwd" in str(traced.jaxpr)
+        return traced.lower().compile()(params)
 
     return run(True), run(False)
 

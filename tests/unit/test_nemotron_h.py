@@ -59,12 +59,18 @@ def seeded(cfg, seed=3, ids=IDS):
     """(model, params): the model's own init with the norms' scales and
     ``D`` moved off one, so that leaving one out shows."""
     model = NemotronHForCausalLM(cfg)
-    params = jax.jit(model.init)(jax.random.PRNGKey(seed), ids)["params"]
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 100), 128))
-    return model, jax.tree_util.tree_map_with_path(
-        lambda kp, p: p + 0.3 * jax.random.normal(next(keys), p.shape)
-        if str(getattr(kp[-1], "key", "")) in ("scale", "norm_scale", "D")
-        else p, params)
+
+    @jax.jit        # one program, not an operation a leaf
+    def make(key, jitter_key):
+        params = model.init(key, ids)["params"]
+        keys = iter(jax.random.split(jitter_key, 128))
+        return jax.tree_util.tree_map_with_path(
+            lambda kp, p: p + 0.3 * jax.random.normal(next(keys), p.shape)
+            if str(getattr(kp[-1], "key", "")) in ("scale", "norm_scale", "D")
+            else p, params)
+
+    return model, make(jax.random.PRNGKey(seed),
+                       jax.random.PRNGKey(seed + 100))
 
 
 def paths(tree, prefix=""):
@@ -82,9 +88,13 @@ def leaf(tree, path):
 
 
 def loss_and_grads(model, params):
-    return jax.jit(jax.value_and_grad(
-        lambda p: model.apply({"params": p}, IDS, labels=IDS),
-        has_aux=True))(params)
+    """``((loss, (named scalars, logits)), gradients)``: ONE jitted
+    ``value_and_grad``, not a forward, a loss and a gradient program."""
+    def loss(p):
+        loss, named = model.apply({"params": p}, IDS, labels=IDS)
+        return loss, (named, model.apply({"params": p}, IDS))
+
+    return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
 
 
 @pytest.fixture(scope="module")
@@ -92,31 +102,34 @@ def share():
     cfg = tiny()
     model, params = seeded(cfg)
     sizes = sizes_of(cfg)
-    (loss, named), grads = loss_and_grads(model, params)
-    ref_grads = jax.grad(
-        lambda p: REF.loss(p, sizes, np.asarray(IDS)))(params)
+    (loss, (named, logits)), grads = loss_and_grads(model, params)
+
+    def reference(p):
+        hidden, rows = zip(*(REF.hidden_states(p, sizes, IDS[b])
+                             for b in range(IDS.shape[0])))
+        return REF.loss(p, sizes, np.asarray(IDS)), (
+            [REF.logits(p, h) for h in hidden], sum(rows))
+
+    # the reference's side of every comparison: ONE program (run operation
+    # by operation it was some thousand, compiled by every worker that drew
+    # a case of this file)
+    (ref_loss, (ref_logits, ref_rows)), ref_grads = jax.jit(
+        jax.value_and_grad(reference, has_aux=True))(params)
     return dict(cfg=cfg, model=model, params=params, sizes=sizes, loss=loss,
-                named=named, grads=grads, ref_grads=ref_grads)
+                named=named, logits=logits, grads=grads, ref_loss=ref_loss,
+                ref_logits=ref_logits, ref_rows=ref_rows, ref_grads=ref_grads)
 
 
 # -- the system against the reference ----------------------------------------
 
 def test_logits_match_the_reference(share):
-    got = jax.jit(lambda p: share["model"].apply({"params": p}, IDS))(
-        share["params"])
-    for b in range(IDS.shape[0]):
-        hidden, _ = REF.hidden_states(share["params"], share["sizes"], IDS[b])
-        np.testing.assert_allclose(
-            got[b], REF.logits(share["params"], hidden), rtol=2e-5,
-            atol=2e-5)
+    for got, want in zip(share["logits"], share["ref_logits"]):
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
 def test_loss_and_gauges_match_the_reference(share):
-    np.testing.assert_allclose(
-        share["loss"], REF.loss(share["params"], share["sizes"],
-                                np.asarray(IDS)), rtol=1e-5)
-    rows = sum(REF.hidden_states(share["params"], share["sizes"], IDS[b])[1]
-               for b in range(IDS.shape[0]))
+    np.testing.assert_allclose(share["loss"], share["ref_loss"], rtol=1e-5)
+    rows = share["ref_rows"]
     # pairs routed to the held experts / tokens x top-k x held / routed,
     # summed over the 4 EXPERT layers (the other six route nothing)
     named = share["named"]
@@ -528,14 +541,16 @@ def test_partition_rules_and_frozen_parameters_cover_the_new_names():
 
 # -- what is not built -------------------------------------------------------
 
-def test_what_is_not_built_raises(share):
-    model, params = share["model"], share["params"]
+def test_what_is_not_built_raises():
+    """(Each raised before any arithmetic: parameters by shape.)"""
+    model = NemotronHForCausalLM(tiny())
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), IDS)["params"]
     with pytest.raises(NotImplementedError, match="training"):
         model.apply({"params": params}, IDS, cache={}, cache_index=0)
     with pytest.raises(NotImplementedError, match="packed"):
         model.apply({"params": params}, IDS, attention_mask=jnp.ones_like(IDS))
-    init = lambda cfg: NemotronHForCausalLM(cfg).init(jax.random.PRNGKey(0),
-                                                      IDS)
+    init = lambda cfg: jax.eval_shape(NemotronHForCausalLM(cfg).init,
+                                      jax.random.PRNGKey(0), IDS)
     with pytest.raises(ValueError, match="pattern"):
         init(tiny(first_layer=50))
     with pytest.raises(ValueError, match="one of"):

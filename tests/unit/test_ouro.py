@@ -35,7 +35,7 @@ def seeded(model, ids, seed=0, jitter=0.2):
     """The model's own init with every vector (the norms' scales seeded at
     1, the gate's bias at 0) moved off its seed, so that a test tells a
     scale from none and a bias from none."""
-    params = model.init(jax.random.PRNGKey(seed), ids)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed), ids)["params"]
     leaves, tree = jax.tree_util.tree_flatten_with_path(params)
     keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
     return jax.tree_util.tree_unflatten(tree, [
@@ -122,9 +122,10 @@ def test_every_parameters_gradient_is_the_references(case):
     own unrolled loop."""
     cfg, model, ids, params = case
     with HIGHEST:
-        got = jax.grad(lambda p: model.apply({"params": p}, ids,
-                                             labels=ids)[0])(params)
-        want = ref.grads(params, sizes_of(cfg), ids)
+        # one program a side, not an operation at a time
+        got = jax.jit(jax.grad(lambda p: model.apply(
+            {"params": p}, ids, labels=ids)[0]))(params)
+        want = jax.jit(lambda p: ref.grads(p, sizes_of(cfg), ids))(params)
     assert jax.tree_util.tree_structure(got) == \
         jax.tree_util.tree_structure(want)
     assert min(float(jnp.abs(g).max())
@@ -134,9 +135,9 @@ def test_every_parameters_gradient_is_the_references(case):
 
 def test_remat_and_the_plain_loss_change_nothing(case):
     cfg, model, ids, params = case
-    value = lambda **over: jax.value_and_grad(
+    value = lambda **over: jax.jit(jax.value_and_grad(
         lambda p: OuroForCausalLM(dataclasses.replace(cfg, **over)).apply(
-            {"params": p}, ids, labels=ids)[0])(params)
+            {"params": p}, ids, labels=ids)[0]))(params)
     with HIGHEST:
         loss, grads = value()
         for over in (dict(remat=True), dict(loss_chunk=0),

@@ -8,6 +8,7 @@ against a masked softmax with 64 of 256 columns rotated; the sixteen shares of
 an expert layer against the uncut layer; and the interface the engine sees."""
 
 import dataclasses
+import functools
 import re
 
 import jax
@@ -35,12 +36,16 @@ def seeded(model, ids, seed=0, jitter=0.2):
     """The model's own init with every vector (norm weights seeded at 0 or
     1, ``A_log``, ``dt_bias``) moved off its seed, so that a test tells
     ``(1 + w)`` from ``w`` and a scale from none."""
-    params = model.init(jax.random.PRNGKey(seed), ids)["params"]
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
-    return jax.tree_util.tree_unflatten(tree, [
-        x + jitter * jax.random.normal(k, x.shape) if x.ndim <= 2 else x
-        for x, k in zip(leaves, keys)])
+    @jax.jit        # one program, not an operation a leaf
+    def make(key, jitter_key):
+        params = model.init(key, ids)["params"]
+        leaves, tree = jax.tree_util.tree_flatten(params)
+        keys = jax.random.split(jitter_key, len(leaves))
+        return jax.tree_util.tree_unflatten(tree, [
+            x + jitter * jax.random.normal(k, x.shape) if x.ndim <= 2 else x
+            for x, k in zip(leaves, keys)])
+
+    return make(jax.random.PRNGKey(seed), jax.random.PRNGKey(seed + 1))
 
 
 @pytest.fixture(scope="module")
@@ -49,7 +54,39 @@ def case():
                                report_expert_load=True)
     model = Qwen3NextForCausalLM(cfg)
     ids = jnp.asarray(np.random.RandomState(0).randint(0, 128, (2, 37)))
-    return cfg, model, ids, seeded(model, ids)
+    params = seeded(model, ids)
+    sizes = sizes_of(cfg)
+
+    @jax.jit        # the reference's side of both comparisons: ONE program,
+    def reference(p):   # whichever path the mixer takes
+        def loss(p):
+            logits = [ref.logits(p, ref.hidden_states(p, sizes, ids[b])[0])
+                      for b in range(ids.shape[0])]
+            return ref.loss(p, sizes, np.asarray(ids)), logits
+
+        with HIGHEST:
+            return jax.value_and_grad(loss, has_aux=True)(p)
+
+    (loss, logits), grads = reference(params)
+    return cfg, model, ids, params, dict(loss=loss, logits=logits,
+                                         grads=grads, system={})
+
+
+def loss_logits_and_gradients(case, mixer_path):
+    """The system's side: ONE jitted ``value_and_grad`` with the logits
+    beside the loss, not a forward, a loss and a gradient program, and run
+    once a path for the two cases that read it."""
+    _, model, ids, params, memo = case
+
+    def loss(p):
+        loss, named = model.apply({"params": p}, ids, labels=ids)
+        return loss, (named, model.apply({"params": p}, ids))
+
+    if mixer_path not in memo["system"]:
+        with HIGHEST:
+            memo["system"][mixer_path] = jax.jit(jax.value_and_grad(
+                loss, has_aux=True))(params)
+    return memo["system"][mixer_path]
 
 
 # -- the model against the reference ------------------------------------------
@@ -67,18 +104,12 @@ def mixer_path(request, monkeypatch):
 
 
 def test_logits_and_loss_are_the_references(case, mixer_path):
-    cfg, model, ids, params = case
-    with HIGHEST:
-        logits = model.apply({"params": params}, ids)
-        loss, named = model.apply({"params": params}, ids, labels=ids)
-    sizes = sizes_of(cfg)
+    cfg, model, ids, params, want = case
+    (loss, (named, logits)), _ = loss_logits_and_gradients(case, mixer_path)
     for b in range(ids.shape[0]):
-        hidden, _ = ref.hidden_states(params, sizes, ids[b])
-        want = ref.logits(params, hidden)
-        assert np.abs(np.asarray(logits[b] - want)).max() < 2e-4 * float(
-            jnp.abs(want).max())
-    assert float(loss) == pytest.approx(
-        float(ref.loss(params, sizes, np.asarray(ids))), rel=2e-6)
+        assert np.abs(np.asarray(logits[b] - want["logits"][b])).max() \
+            < 2e-4 * float(jnp.abs(want["logits"][b]).max())
+    assert float(loss) == pytest.approx(float(want["loss"]), rel=2e-6)
     assert sorted(named) == ["gdn_chunk_decay_max",
                              "moe_held_rows_over_expected",
                              "moe_rows_max_over_mean"]
@@ -89,12 +120,9 @@ def test_every_parameters_gradient_is_the_references(case, mixer_path):
     gates (the full layer's inside ``q_proj``, the shared expert's), the
     zero-centred weights, the delta rule's plain output scale, the frozen
     router (its gradient exists; the optimizer never applies it)."""
-    cfg, model, ids, params = case
-    sizes = sizes_of(cfg)
-    with HIGHEST:
-        got = jax.grad(lambda p: model.apply({"params": p}, ids,
-                                             labels=ids)[0])(params)
-        want = jax.grad(lambda p: ref.loss(p, sizes, np.asarray(ids)))(params)
+    cfg, model, ids, params, want = case
+    _, got = loss_logits_and_gradients(case, mixer_path)
+    want = want["grads"]
     flat = lambda t: {jax.tree_util.keystr(k): np.asarray(v) for k, v in
                       jax.tree_util.tree_flatten_with_path(t)[0]}
     got, want = flat(got), flat(want)
@@ -132,8 +160,9 @@ def recurrence(q, k, v, g, beta):
 def test_chunked_rule_is_the_recurrence(T, chunk):
     x = rule_inputs(T)
     with HIGHEST:
-        got, decay = qn.gated_delta_rule(*x, chunk=chunk)
-        want = recurrence(*x)
+        got, decay = jax.jit(functools.partial(qn.gated_delta_rule,
+                                               chunk=chunk))(*x)
+        want = jax.jit(recurrence)(*x)
     assert got.shape == want.shape
     assert np.abs(np.asarray(got - want)).max() < 1e-5
     g = np.pad(np.asarray(x[3]), ((0, 0), (0, (-T) % chunk), (0, 0)))
@@ -146,11 +175,11 @@ def test_chunked_rule_has_the_recurrences_gradients(T, chunk):
     x = rule_inputs(T, seed=1)
     w = jax.random.normal(jax.random.PRNGKey(9), (1, T, 3, 8))
     with HIGHEST:
-        got = jax.grad(lambda *a: jnp.sum(
+        got = jax.jit(jax.grad(lambda *a: jnp.sum(
             w * qn.gated_delta_rule(*a, chunk=chunk)[0]),
-            argnums=range(5))(*x)
-        want = jax.grad(lambda *a: jnp.sum(w * recurrence(*a)),
-                        argnums=range(5))(*x)
+            argnums=range(5)))(*x)
+        want = jax.jit(jax.grad(lambda *a: jnp.sum(w * recurrence(*a)),
+                                argnums=range(5)))(*x)
     for a, b in zip(got, want):
         assert np.abs(np.asarray(a - b)).max() < 1e-4 * max(
             1.0, float(jnp.abs(b).max()))
@@ -165,13 +194,14 @@ def test_twenty_nats_a_token_stay_finite_and_equal(T, chunk):
     q, k, v, g, beta = rule_inputs(T, decay=20.0, seed=2)
     g = jnp.minimum(g, -15.0 * (jnp.arange(3) > 0))     # head 0 decays little
     w = jax.random.normal(jax.random.PRNGKey(3), v.shape)
-    f = lambda rule: lambda *a: jnp.sum(w * rule(*a))
-    chunked = lambda *a: qn.gated_delta_rule(*a, chunk=chunk)[0]
     with HIGHEST:
-        got, decay = qn.gated_delta_rule(q, k, v, g, beta, chunk=chunk)
-        want = recurrence(q, k, v, g, beta)
-        dgot = jax.grad(f(chunked), argnums=range(5))(q, k, v, g, beta)
-        dwant = jax.grad(f(recurrence), argnums=range(5))(q, k, v, g, beta)
+        (_, (got, decay)), dgot = jax.jit(jax.value_and_grad(
+            lambda *a: (lambda o, d: (jnp.sum(w * o), (o, d)))(
+                *qn.gated_delta_rule(*a, chunk=chunk)),
+            argnums=range(5), has_aux=True))(q, k, v, g, beta)
+        (_, want), dwant = jax.jit(jax.value_and_grad(
+            lambda *a: (lambda o: (jnp.sum(w * o), o))(recurrence(*a)),
+            argnums=range(5), has_aux=True))(q, k, v, g, beta)
     assert float(decay) > 88 * 2
     assert np.isfinite(np.asarray(got)).all()
     assert np.abs(np.asarray(got - want)).max() < 1e-5
@@ -265,8 +295,11 @@ def test_sixteen_shares_and_the_shared_expert_once_make_the_whole_layer():
 
 # -- the interface the engine sees -------------------------------------------
 
-def test_a_cache_or_a_padding_mask_raises(case):
-    cfg, model, ids, params = case
+def test_a_cache_or_a_padding_mask_raises():
+    """(Raised before any arithmetic: parameters by shape, no fixture.)"""
+    model = Qwen3NextForCausalLM(Qwen3NextConfig.tiny())
+    ids = jnp.zeros((2, 37), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"]
     with pytest.raises(NotImplementedError, match="training only"):
         model.apply({"params": params}, ids, cache={})
     with pytest.raises(NotImplementedError, match="packed sequences"):
@@ -277,7 +310,8 @@ def test_a_cache_or_a_padding_mask_raises(case):
 def test_layers_are_whole_periods():
     model = Qwen3NextForCausalLM(Qwen3NextConfig.tiny(num_hidden_layers=6))
     with pytest.raises(ValueError, match="whole"):
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8), jnp.int32))
     assert qn.period_kinds(Qwen3NextConfig.tiny()) == (
         qn.GDN, qn.GDN, qn.GDN, qn.FULL)
 

@@ -12,6 +12,7 @@ ahead of the convolutions, what the mixer hands on, the MLP router's float32
 values."""
 
 import collections
+import functools
 import logging
 
 import jax
@@ -141,19 +142,42 @@ def _compact_offer():
 
 def _kept_under_a_room(model, shape):
     """``{name: bytes}`` the rule keeps of what ``model`` offers for ids of
-    ``shape`` under a room with space for everything: its gradient traced
-    from shapes alone."""
+    ``shape`` under a room with space for everything: its forward pass
+    traced from shapes alone (a block makes its offer where it builds its
+    policy, which is the forward pass: the gradient's trace, three times as
+    long, asks the rule nothing more)."""
     ids = jnp.zeros(shape, jnp.int32)
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
-
-    def loss(p):
-        out = model.apply({"params": p}, ids, labels=ids)
-        return out[0] if isinstance(out, tuple) else out
-
     with remat_room(10 ** 9) as kept:
-        jax.make_jaxpr(jax.grad(loss))(params)
+        jax.eval_shape(
+            lambda p: model.apply({"params": p}, ids, labels=ids), params)
     return dict(kept)
+
+
+def _trace_and_run(fn, *args, run=True):
+    """``(jaxpr, outputs)`` of ONE trace of ``fn``: the program that is read
+    is the program that runs (``make_jaxpr`` and then ``jit`` traced every
+    model twice). A trace reads the room it stands in."""
+    traced = jax.jit(fn).trace(*args)
+    return traced.jaxpr, traced.lower().compile()(*args) if run else None
+
+
+@functools.lru_cache(maxsize=None)
+def _seeded(family):
+    """``(ids, params)`` of a tiny model of ``family``: ONE jitted ``init``
+    a family a PROCESS (run operation by operation an ``init`` is some
+    hundreds of one-operation programs, each compiled cold by whichever
+    worker draws the case; a process-wide memo serves a worker whichever
+    cases ``--dist load`` hands it, which a fixture's scope does not
+    promise). Numpy COPIES: a cached device array would stay in
+    ``jax.live_arrays()`` for the worker's later files, and
+    ``test_engine.py`` counts them."""
+    build = {"llama": _llama_model,
+             "qwen3_next": _qwen3_next_model}.get(family)
+    model, ids = build("nothing") if build else _expert_model(family)
+    return np.asarray(ids), jax.tree_util.tree_map(np.array, jax.jit(
+        model.init)(jax.random.PRNGKey(0), jnp.asarray(ids))["params"])
 
 
 def _mellum_offer():
@@ -230,14 +254,30 @@ def test_a_block_offers_its_names_with_their_bytes(offer):
 INTER = (2, 32, 128)    # [B, T, LlamaConfig.tiny's intermediate size]
 
 
-def _llama(policy, remat=True):
+def _llama_model(policy, remat=True):
     cfg = LlamaConfig.tiny(remat=remat, remat_policy=policy)
     assert cfg.intermediate_size == INTER[-1] and cfg.scan_layers
-    model = LlamaForCausalLM(cfg)
-    ids = jnp.asarray(np.random.RandomState(0).randint(0, 256, (2, 32)))
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    return LlamaForCausalLM(cfg), \
+        np.random.RandomState(0).randint(0, 256, (2, 32))
+
+
+def _llama(policy, remat=True):
+    """A function of its own a call (jax keeps a function's trace, and a
+    room is no part of that cache's key: the engine states one for the FIRST
+    trace of a step it has just built) over the family's one set of
+    parameters: the policy and the remat change no shape."""
+    model, _ = _llama_model(policy, remat)
+    ids, params = _seeded("llama")
     loss = lambda p: model.apply({"params": p}, ids, labels=ids)
     return jax.value_and_grad(loss), params
+
+
+@functools.lru_cache(maxsize=None)
+def _llama_unremated():
+    """Loss and gradients of the un-remat'ed tiny Llama, which no policy
+    changes: computed once a process."""
+    fn, params = _llama("nothing", remat=False)
+    return jax.tree_util.tree_map(np.array, jax.jit(fn)(params))
 
 
 def _products_into(jaxpr, shape):
@@ -264,15 +304,10 @@ def test_kept_names_take_the_products_out_of_llamas_replay(policy):
     have computed), and the un-remat'ed function's to the ulps by which
     XLA:CPU's other fusion of that backward already differs from both."""
     fn, params = _llama(policy)
-    plain = jax.make_jaxpr(fn)(params)
-    want = jax.jit(fn)(params)
-    # a function of its own: jax keeps a function's trace, and a room is
-    # no part of that cache's key (the engine states one for the FIRST
-    # trace of a step it has just built)
+    plain, want = _trace_and_run(fn, params)
     fn, _ = _llama(policy)
     with remat_room(10 ** 9) as kept:
-        named = jax.make_jaxpr(fn)(params)
-        got = jax.jit(fn)(params)
+        named, got = _trace_and_run(fn, params)
     assert set(kept) == {REMAT_MLP, REMAT_QKV}
     assert str(named).count(f"name={REMAT_MLP}") >= 2
     assert REMAT_MLP not in str(plain) and REMAT_QKV not in str(plain)
@@ -282,7 +317,7 @@ def test_kept_names_take_the_products_out_of_llamas_replay(policy):
     total = lambda jaxpr: str(jaxpr).count("dot_general")
     if policy == "nothing":      # gate, up, q, k, v: five fewer
         assert total(plain) - total(named) == 5
-    _same(got, want, jax.jit(_llama(policy, remat=False)[0])(params))
+    _same(got, want, _llama_unremated())
 
 
 def _same(got, want, unremated):
@@ -333,11 +368,21 @@ def _expert_model(family):
 
 
 def _expert_grad(family):
-    model, ids = _expert_model(family)
-    ids = jnp.asarray(ids)
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    """A function of its own a call over the family's one set of
+    parameters (``_seeded``)."""
+    model, _ = _expert_model(family)
+    ids, params = _seeded(family)
     loss = lambda p: model.apply({"params": p}, ids, labels=ids)
     return jax.value_and_grad(loss), params
+
+
+@functools.lru_cache(maxsize=None)
+def _plain(family):
+    """The family's gradient traced with no room stated, once a process:
+    ``(its primitives, the trace)`` -- a case that compares values runs the
+    trace it has, a case that counts equations reads it."""
+    traced = jax.jit(_expert_grad(family)[0]).trace(_seeded(family)[1])
+    return _primitives(traced.jaxpr.jaxpr), traced
 
 
 def _primitives(jaxpr, found=None):
@@ -372,13 +417,12 @@ def test_kept_names_take_the_expert_layer_out_of_the_replay(family):
     same. Loss and every gradient are, bit for bit, those of
     the step that keeps nothing."""
     expert_bodies, projections, compact = EXPERT_FAMILIES[family]
-    fn, params = _expert_grad(family)
-    plain = _primitives(jax.make_jaxpr(fn)(params).jaxpr)
-    want = jax.jit(fn)(params)
-    fn, _ = _expert_grad(family)        # jax keeps a function's trace
+    plain, traced = _plain(family)
+    fn, params = _expert_grad(family)   # jax keeps a function's trace
+    want = traced.lower().compile()(params)
     with remat_room(10 ** 9) as kept:
-        named = _primitives(jax.make_jaxpr(fn)(params).jaxpr)
-        got = jax.jit(fn)(params)
+        named, got = _trace_and_run(fn, params)
+    named = _primitives(named.jaxpr)
     assert {REMAT_ATTN_OUT, REMAT_QKV, REMAT_MOE_UP,
             REMAT_MOE_ROWS} <= set(kept)
     assert (REMAT_MLP in kept) == (family == "deepseek_v3")
@@ -417,7 +461,7 @@ def test_a_budget_between_two_expert_names_keeps_the_first_ones(family):
     assert [n for n, _ in offered[-2:]] == [REMAT_MOE_UP, REMAT_MOE_ROWS]
     fn, params = _expert_grad(family)
     with remat_room(REMAT_FACTOR * sum(b for _, b in offered[:-1])) as kept:
-        named = jax.make_jaxpr(fn)(params)
+        named, _ = _trace_and_run(fn, params, run=False)
     assert list(kept) == [n for n, _ in offered[:-1]]
     assert f"name={REMAT_MOE_ROWS}" not in str(named)
     found = _primitives(named.jaxpr)
@@ -426,7 +470,9 @@ def test_a_budget_between_two_expert_names_keeps_the_first_ones(family):
 
 # -- the names under each policy: ZAYA's block (PR 65) ------------------------
 
-@pytest.mark.parametrize("family", ["zaya", "zaya_unrolled"])
+@pytest.mark.parametrize("family", [
+    "zaya",     # unrolled: 27 s cold (PR 69); the scanned stack asks the same
+    pytest.param("zaya_unrolled", marks=pytest.mark.slow)])
 def test_kept_names_take_the_projections_the_router_and_the_experts_out_of_zayas_replay(
         family):
     """With everything offered kept a layer body's replay holds ONE
@@ -443,13 +489,12 @@ def test_kept_names_take_the_projections_the_router_and_the_experts_out_of_zayas
     state, products and logits. Loss and gradients are those of the step
     that keeps nothing."""
     bodies = 1 if family == "zaya" else 3
-    fn, params = _expert_grad(family)
-    plain = _primitives(jax.make_jaxpr(fn)(params).jaxpr)
-    want = jax.jit(fn)(params)
-    fn, _ = _expert_grad(family)        # jax keeps a function's trace
+    plain, traced = _plain(family)
+    fn, params = _expert_grad(family)   # jax keeps a function's trace
+    want = traced.lower().compile()(params)
     with remat_room(10 ** 9) as kept:
-        named = _primitives(jax.make_jaxpr(fn)(params).jaxpr)
-        got = jax.jit(fn)(params)
+        named, got = _trace_and_run(fn, params)
+    named = _primitives(named.jaxpr)
     assert list(kept) == [REMAT_MOE_OUT, REMAT_QKV, REMAT_MOE_UP,
                           REMAT_ROUTER, REMAT_CCA_MIX]
     assert plain["dot_general"] - named["dot_general"] == 10 * bodies
@@ -478,14 +523,13 @@ ZAYA_ROOMS = {
 
 @pytest.mark.parametrize("names", sorted(ZAYA_ROOMS))
 def test_a_room_between_two_of_zayas_names_keeps_the_first_ones(names):
-    fn, params = _expert_grad("zaya")
-    plain = _primitives(jax.make_jaxpr(fn)(params).jaxpr)
+    plain, _ = _plain("zaya")
     cfg = zy.ZayaConfig.tiny()
     x = jax.ShapeDtypeStruct((2, 32, cfg.hidden_size), jnp.float32)
     offered = zy.remat_offers(cfg, x, cfg.num_hidden_layers)
-    fn, _ = _expert_grad("zaya")
+    fn, params = _expert_grad("zaya")
     with remat_room(REMAT_FACTOR * sum(b for _, b in offered[:names])) as kept:
-        text = jax.make_jaxpr(fn)(params)
+        text, _ = _trace_and_run(fn, params, run=False)
     assert list(kept) == [n for n, _ in offered[:names]]
     assert f"name={offered[names][0]}" not in str(text)
     named = _primitives(text.jaxpr)
@@ -544,9 +588,8 @@ def test_an_overflowing_step_trains_alike_with_the_names_kept(held_pairs):
 
         fn = jax.value_and_grad(loss, argnums=tuple(range(5)))
         with remat_room(budget):        # the trace of the gradient
-            found = _primitives(jax.make_jaxpr(fn)(
-                x, w1, w2, w3, topk_w).jaxpr)
-            return found, jax.jit(fn)(x, w1, w2, w3, topk_w)
+            jaxpr, out = _trace_and_run(fn, x, w1, w2, w3, topk_w)
+            return _primitives(jaxpr.jaxpr), out
 
     (plain, want), (named, got) = grads(0), grads(10 ** 9)
     assert (plain["cond"], named["cond"]) == (3, 2)
@@ -614,12 +657,17 @@ def _calls(jaxpr, found=None):
     return dict(found)
 
 
-def _qwen3_next(policy, remat=True):
-    cfg = qn.Qwen3NextConfig.tiny(num_hidden_layers=4, remat=remat,
-                                  remat_policy=policy)
-    model = qn.Qwen3NextForCausalLM(cfg)
-    ids = jnp.asarray(np.random.RandomState(0).randint(0, 128, (1, 16)))
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+def _qwen3_next_model(policy):
+    return qn.Qwen3NextForCausalLM(qn.Qwen3NextConfig.tiny(
+        num_hidden_layers=4, remat=True, remat_policy=policy)), \
+        np.random.RandomState(0).randint(0, 128, (1, 16))
+
+
+def _qwen3_next(policy):
+    """A function of its own a call over the family's one set of
+    parameters (``_seeded``: the policy changes no shape)."""
+    model, _ = _qwen3_next_model(policy)
+    ids, params = _seeded("qwen3_next")
     loss = lambda p: model.apply({"params": p}, ids, labels=ids)
     return jax.value_and_grad(loss), params
 
@@ -635,12 +683,11 @@ def test_kept_names_take_the_rule_out_of_qwen3_nexts_replay(
     every gradient are those of the step that keeps nothing, bit for bit
     (``test_qwen3_next.py`` holds that step to the reference)."""
     fn, params = _qwen3_next(policy)
-    plain = jax.make_jaxpr(fn)(params)
+    plain, want = _trace_and_run(fn, params, run=policy == "nothing")
     named_fn, _ = _qwen3_next(policy)
     with remat_room(10 ** 9) as kept:
-        named = jax.make_jaxpr(named_fn)(params)
-        if policy == "nothing":
-            got = jax.jit(named_fn)(params)
+        named, got = _trace_and_run(named_fn, params,
+                                    run=policy == "nothing")
     assert list(kept) == [REMAT_GDN_RULE, REMAT_GDN_QKVZ, REMAT_GDN_MIX,
                           REMAT_MOE_UP, REMAT_MOE_ROWS]
     forward = {GDN_RULE_FWD, GDN_PREMIX_FWD, GDN_GATE_FWD}
@@ -654,7 +701,7 @@ def test_kept_names_take_the_rule_out_of_qwen3_nexts_replay(
                                    "triangular_solve": 3}
     if policy == "nothing":
         for a, b in zip(jax.tree_util.tree_leaves(got),
-                        jax.tree_util.tree_leaves(jax.jit(fn)(params))):
+                        jax.tree_util.tree_leaves(want)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -676,7 +723,7 @@ def test_qwen3_next_keeps_the_rule_first_and_the_mixer_last(
     assert offered[0][1] == 3 * (16 * 32 * 4 + 2 * 4 * 2 * 8 * 8 * 4)
     two = sum(b for _, b in offered[:2])
     with remat_room(REMAT_FACTOR * two) as kept:
-        named = jax.make_jaxpr(fn)(params)
+        named, _ = _trace_and_run(fn, params, run=False)
     assert list(kept) == [REMAT_GDN_RULE, REMAT_GDN_QKVZ]
     assert REMAT_GDN_MIX not in str(named)
 
@@ -695,9 +742,9 @@ CONFIG = {"train_batch_size": 2,
           "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}}
 
 
-def _engine(model):
+def _engine(model, params=None):
     engine, *_ = ds.initialize(
-        model=model, config=CONFIG,
+        model=model, config=CONFIG, model_parameters=params,
         mesh=build_mesh(devices=jax.devices()[:1]),
         example_batch={"input_ids": np.zeros((2, 32), np.int32),
                        "labels": np.zeros((2, 32), np.int32)})
@@ -715,12 +762,16 @@ def _train(engine, steps=3, batch=2):
 
 
 def _lowered_text(engine, budget=0):
-    """The engine's step as it lowers under a stated ``budget`` (a fresh
-    engine a call: jax keeps a function's trace)."""
+    """The engine's step as it lowers under a stated ``budget``: a step of
+    its own a call (jax keeps a function's trace), built as the engine
+    itself builds its step again (``_fit_train_step``) -- the engine's state
+    and set-up are no part of the question, so one engine a family serves
+    every lowering (``_family_engine``)."""
     ids = np.zeros((2, 32), np.int32)
+    topology.set_mesh(engine.mesh)      # the autouse fixture took it away
     batch = engine._shape_batch({"input_ids": ids, "labels": ids})
     with remat_room(budget):
-        return engine._train_step.lower(
+        return engine._compile_train_step().lower(
             engine.state, batch, jax.random.PRNGKey(0)).as_text()
 
 
@@ -739,22 +790,49 @@ TINY = {
 }
 
 
+@functools.lru_cache(maxsize=None)
+def _family_engine(family):
+    """The family's one-device engine for the cases that lower its step and
+    never run it: once a process, its parameters zeros of the shapes
+    ``init`` gives (no ``init`` program is compiled: half an engine's
+    cost) -- and, once built, its state the shapes alone: a device array
+    this memo kept would be alive for the worker's later files."""
+    model = TINY[family]()
+    ids = jnp.zeros((2, 32), jnp.int32)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids,
+                            labels=ids)["params"]
+    engine = _engine(model, jax.tree_util.tree_map(
+        lambda s: np.zeros(s.shape, s.dtype), shapes))
+    engine.state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+        engine.state)
+    return engine
+
+
+@functools.lru_cache(maxsize=None)
+def _llama_losses():
+    """Three steps' losses of the tiny Llama's engine that keeps nothing."""
+    return _train(_engine(TINY["llama"]()))
+
+
 @pytest.mark.parametrize("family", sorted(TINY))
 def test_on_a_cpu_the_lowered_step_names_nothing(family, monkeypatch):
     """A CPU keeps no allocator numbers: the budget is 0, nothing is kept,
     and the engine's lowered step holds no ``name`` equation -- it is, to
     the character, the text it lowers to with the naming helper made the
     identity (what the step was before a name was offered)."""
-    engine = _engine(TINY[family]())
+    engine = _family_engine(family)
     assert engine_module._device_memory(jax.devices()[0]) is None
     text = _lowered_text(engine)
     from deepspeed_tpu.models import llama
 
     for module in (llama, qn, mx, dsv3, zy):
         monkeypatch.setattr(module, "name_if_kept", lambda x, name: x)
-    assert _lowered_text(_engine(TINY[family]())) == text
+    assert _lowered_text(engine) == text
     if family != "llama":       # one family's step runs: the record is the
         return                  # engine's, not the model's
+    monkeypatch.undo()
+    engine = _engine(TINY[family]())    # an engine of its own: it steps
     _train(engine, 1)
     record = engine.setup.record(0)
     assert record["remat_kept_bytes"] == record["remat_room_bytes"] == 0
@@ -777,7 +855,7 @@ def test_engine_states_the_budget_and_compiles_the_step_once(device_memory,
     compiled ahead of the first call and the call finds it (one trace, one
     lowering, one backend compile of ``train_step``, then the cost capture's
     cached lowering); the losses are those of an engine that kept nothing."""
-    want = _train(_engine(TINY["llama"]()))
+    want = _llama_losses()
     device_memory(10 ** 8, 10 ** 7)
     engine = _engine(TINY["llama"]())
     before = dict(engine.setup.ledger.snapshot()["by_fun"].get(
@@ -818,7 +896,7 @@ def test_a_step_over_the_margin_is_built_again_with_nothing_kept(
     """A limit the rule finds room under and the compiled step does not fit:
     the engine builds the step once more with budget 0, says so in one
     line, counts the fallback, and trains to the same losses."""
-    want = _train(_engine(TINY["llama"]()))
+    want = _llama_losses()
     device_memory(10 ** 6)      # budget 750 kB; the step's arguments 1.3 MB
     engine = _engine(TINY["llama"]())
     heard = []
@@ -992,12 +1070,13 @@ def test_on_one_device_a_stated_budget_lowers_the_step_it_lowered(
     moved."""
     from deepspeed_tpu.models import llama
 
-    text = _lowered_text(_engine(TINY[family]()), 10 ** 9)
+    engine = _family_engine(family)
+    text = _lowered_text(engine, 10 ** 9)
     # (a name lowers to nothing: what it keeps shows in the scans' carries)
-    assert text != _lowered_text(_engine(TINY[family]()))
+    assert text != _lowered_text(engine)
     for module in (llama, qn, mx, dsv3, zy):
         monkeypatch.setattr(module, "device_part", lambda batch, but=(): batch)
-    assert _lowered_text(_engine(TINY[family]()), 10 ** 9) == text
+    assert _lowered_text(engine, 10 ** 9) == text
 
 
 def _expert4_engine(replicate):
@@ -1027,12 +1106,14 @@ def test_under_an_expert_axis_the_kept_products_leave_the_replay(
     all-gathers fewer. An engine that reads room on its device keeps all
     four under the mesh and trains to the very losses of one that keeps
     nothing."""
+    traced = _expert4_engine(replicate)     # one engine, a step a trace
+
     def step(budget):
-        engine = _expert4_engine(replicate)
+        engine = traced
         ids = np.zeros((4, 32), np.int32)
         batch = engine._shape_batch({"input_ids": ids, "labels": ids})
         with remat_room(budget) as kept:
-            found = _primitives(jax.make_jaxpr(engine._train_step)(
+            found = _primitives(jax.make_jaxpr(engine._compile_train_step())(
                 engine.state, batch, jax.random.PRNGKey(0)).jaxpr)
         grouped = found["ragged_dot"] + found["ragged_dot_general"]
         return dict(kept), grouped, found

@@ -33,7 +33,7 @@ def seeded(cfg, seed=0, spread=0.05):
     seeded normal, so that no bias is zero and no scale one (at exactly
     those the forward pass would not notice them)."""
     model = SambaYForCausalLM(cfg)
-    params = model.init(jax.random.PRNGKey(seed), IDS)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed), IDS)["params"]
     leaves, tree = jax.tree_util.tree_flatten(params)
     keys = jax.random.split(jax.random.PRNGKey(seed + 100), len(leaves))
     return model, jax.tree_util.tree_unflatten(tree, [
@@ -75,9 +75,11 @@ def test_gradients_match_the_reference(case):
     and what crosses layers (the memory, the handed-on keys and values)
     among them."""
     cfg, model, params = case
-    got = jax.grad(lambda p: model.apply({"params": p}, IDS, labels=IDS))(
-        params)
-    want = jax.grad(lambda p: REF.loss(p, sizes_of(cfg), IDS))(params)
+    # one program a side, not an operation at a time
+    got = jax.jit(jax.grad(
+        lambda p: model.apply({"params": p}, IDS, labels=IDS)))(params)
+    want = jax.jit(jax.grad(
+        lambda p: REF.loss(p, sizes_of(cfg), IDS)))(params)
     worst = jax.tree_util.tree_map(
         lambda g, w: float(jnp.linalg.norm(g - w)
                            / (jnp.linalg.norm(w) + 1e-12)), got, want)

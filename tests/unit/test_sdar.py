@@ -49,7 +49,8 @@ def seeded(cfg, seed=3):
     """(model, params): the model's own init with the norms' scales moved
     off one, so that leaving a norm out shows."""
     model = SdarForCausalLM(cfg)
-    params = model.init(jax.random.PRNGKey(seed), IDS, labels=IDS)["params"]
+    params = jax.jit(lambda key: model.init(key, IDS, labels=IDS))(
+        jax.random.PRNGKey(seed))["params"]
     keys = iter(jax.random.split(jax.random.PRNGKey(seed + 100), 64))
     return model, jax.tree_util.tree_map_with_path(
         lambda kp, p: p + 0.3 * jax.random.normal(next(keys), p.shape)
@@ -85,8 +86,8 @@ def test_loss_gradients_and_logits_are_the_references(case, impl,
         cfg = dataclasses.replace(cfg, attention_impl="flash")
         interpreted(monkeypatch)
     model, params = SdarForCausalLM(cfg), case["params"]
-    loss, grads = jax.value_and_grad(
-        lambda p: model.apply({"params": p}, IDS, labels=IDS))(params)
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: model.apply({"params": p}, IDS, labels=IDS)))(params)
     np.testing.assert_allclose(loss, case["ref_loss"], rtol=1e-5)
     flat = lambda tree: dict(jax.tree_util.tree_leaves_with_path(tree))
     want = flat(case["ref_grads"])
@@ -158,9 +159,10 @@ def test_a_call_that_is_not_deterministic_noises_a_sequence_anew(case):
 def test_checksums_of_model_and_reference_agree():
     rs = np.random.RandomState(1)
     seen = set()
+    checksum = jax.jit(sdar.checksum)   # a length is a program, not ten
     for n in range(100):
         ids = rs.randint(0, 151936, rs.randint(1, 9000))
-        got = int(sdar.checksum(jnp.asarray(ids, jnp.int32)))
+        got = int(checksum(jnp.asarray(ids, jnp.int32)))
         assert got == REF.checksum(ids) and got >= 0
         seen.add(got)
     assert len(seen) == 100

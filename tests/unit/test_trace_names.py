@@ -133,61 +133,81 @@ SERVE_SCOPES = ["ds.mixed_step", "ds.embed", "ds.attn_proj", "ds.kv_append",
                 "ds.attention", "ds.mlp", "ds.lm_head", "ds.sample"]
 
 
-@pytest.fixture(scope="module")
-def train_text():
-    out = {}
-    for family, model in (
-            ("llama", LlamaForCausalLM(LlamaConfig.tiny(sliding_window=16))),
-            ("mixtral", MixtralForCausalLM(MixtralConfig.tiny(remat=True))),
-            ("deepseek_v3", DeepseekV3ForCausalLM(
-                DeepseekV3Config.tiny(remat=True))),
-            ("zaya", ZayaForCausalLM(ZayaConfig.tiny(remat=True))),
-            ("keye", MixtralForCausalLM(MixtralConfig.tiny(
-                remat=True, router_experts=16, first_expert=4,
-                sa_config=dict(indexer_head_dim=8, indexer_num_heads=2,
-                               q_chunk_size=16, kv_chunk_size=16,
-                               topk=8)))),
-            ("sambay", SambaYForCausalLM(SambaYConfig.tiny(remat=True))),
-            ("mellum", MellumForCausalLM(MellumConfig.tiny(remat=True))),
-            ("laguna", LagunaForCausalLM(LagunaConfig.tiny(remat=True))),
-            ("nemotron_h", NemotronHForCausalLM(NemotronHConfig.tiny(
-                remat=True))),
-            ("qwen3_next", Qwen3NextForCausalLM(Qwen3NextConfig.tiny(
-                remat=True))),
-            ("kimi_linear", KimiLinearForCausalLM(KimiLinearConfig.tiny(
-                num_hidden_layers=5, remat=True))),
-            ("ouro", OuroForCausalLM(OuroConfig.tiny(remat=True,
-                                                     loss_chunk=64))),
-            ("sdar", SdarForCausalLM(SdarConfig.tiny(remat=True,
-                                                     loss_chunk=16)))):
-        batch = {"input_ids": np.zeros((8, 32), np.int32),
-                 "labels": np.zeros((8, 32), np.int32)}
-        engine, *_ = ds.initialize(
-            model=model, example_batch={k: v[:1] for k, v in batch.items()},
-            config={"train_batch_size": 8, "bf16": {"enabled": True},
-                    "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})
-        out[family] = engine._train_step.lower(
-            engine.state, engine._shape_batch(batch),
-            jax.random.PRNGKey(0)).as_text(debug_info=True)
-    return out
+#: a tiny remat'ed model of each family, as its step names its scopes
+TRAIN_MODELS = {
+    "llama": lambda: LlamaForCausalLM(LlamaConfig.tiny(sliding_window=16)),
+    "mixtral": lambda: MixtralForCausalLM(MixtralConfig.tiny(remat=True)),
+    "deepseek_v3": lambda: DeepseekV3ForCausalLM(
+        DeepseekV3Config.tiny(remat=True)),
+    "zaya": lambda: ZayaForCausalLM(ZayaConfig.tiny(remat=True)),
+    "keye": lambda: MixtralForCausalLM(MixtralConfig.tiny(
+        remat=True, router_experts=16, first_expert=4,
+        sa_config=dict(indexer_head_dim=8, indexer_num_heads=2,
+                       q_chunk_size=16, kv_chunk_size=16, topk=8))),
+    "sambay": lambda: SambaYForCausalLM(SambaYConfig.tiny(remat=True)),
+    "mellum": lambda: MellumForCausalLM(MellumConfig.tiny(remat=True)),
+    "laguna": lambda: LagunaForCausalLM(LagunaConfig.tiny(remat=True)),
+    "nemotron_h": lambda: NemotronHForCausalLM(NemotronHConfig.tiny(
+        remat=True)),
+    "qwen3_next": lambda: Qwen3NextForCausalLM(Qwen3NextConfig.tiny(
+        remat=True)),
+    "kimi_linear": lambda: KimiLinearForCausalLM(KimiLinearConfig.tiny(
+        num_hidden_layers=5, remat=True)),
+    "ouro": lambda: OuroForCausalLM(OuroConfig.tiny(remat=True,
+                                                    loss_chunk=64)),
+    "sdar": lambda: SdarForCausalLM(SdarConfig.tiny(remat=True,
+                                                    loss_chunk=16)),
+}
+FAMILIES = list(TRAIN_SCOPES)
+assert set(FAMILIES) == set(TRAIN_MODELS)
+
+
+@functools.lru_cache(maxsize=None)
+def train_text(family):
+    """The family's engine's ``train_step``, lowered. One engine a family a
+    PROCESS and only for the families a worker is asked about: ``--dist
+    load`` deals a file's cases out across the workers, and a module's
+    fixture that built all thirteen engines was built in every one of them
+    (PR 69: 717 of this file's 890 cold test-seconds). Nothing runs, so the
+    parameters are zeros of the shapes ``init`` gives: the engine compiles
+    no ``init`` program (half an engine's cost), and the step lowers to the
+    same text, character for character, as over the engine's own."""
+    batch = {"input_ids": np.zeros((8, 32), np.int32),
+             "labels": np.zeros((8, 32), np.int32)}
+    example = {k: v[:1] for k, v in batch.items()}
+    model = TRAIN_MODELS[family]()
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            **example)["params"]
+    engine, *_ = ds.initialize(
+        model=model, example_batch=example,
+        model_parameters=jax.tree_util.tree_map(
+            lambda s: np.zeros(s.shape, s.dtype), shapes),
+        config={"train_batch_size": 8, "bf16": {"enabled": True},
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})
+    return engine._train_step.lower(
+        engine.state, engine._shape_batch(batch),
+        jax.random.PRNGKey(0)).as_text(debug_info=True)
 
 
 @pytest.mark.parametrize("family,scope", [
     (f, s) for f, scopes in TRAIN_SCOPES.items() for s in scopes])
-def test_train_step_names_its_scopes(train_text, family, scope):
-    assert re.search(re.escape(scope) + r"\b", train_text[family])
+def test_train_step_names_its_scopes(family, scope):
+    assert re.search(re.escape(scope) + r"\b", train_text(family))
 
 
-def test_jitted_steps_are_named_like_the_kernels(train_text, mixed_text):
+@pytest.mark.parametrize("family", FAMILIES)
+def test_jitted_steps_are_named_like_the_kernels(family):
     """The module's name is in the compile cache's key; the scopes inside
     are metadata and are not. Were the steps still ``train_step`` and
     ``mixed_step``, an executable cached before the scopes existed would be
     reused, and a trace of it would show none of them; the names' version
     in the module's name does the same for every later name."""
     n = tracing.NAMES_VERSION
-    for text in train_text.values():
-        assert f"module @jit_ds_train_step_n{n} " in text
-    assert f"module @jit_ds_mixed_step_n{n} " in mixed_text
+    assert f"module @jit_ds_train_step_n{n} " in train_text(family)
+
+
+def test_the_mixed_step_is_named_like_the_kernels(mixed_text):
+    assert f"module @jit_ds_mixed_step_n{tracing.NAMES_VERSION} " in mixed_text
 
 
 def trace_names():
@@ -308,8 +328,8 @@ def test_moe_load_gauges_are_published_by_name(family, over, gauges):
             assert 0 <= found["moe_skip_share"] < 1
         assert found["moe_rows_max_over_mean"] >= 1.0
     elif gauges:
-        _, sown = model.apply({"params": params}, **batch,
-                              mutable=["intermediates"])
+        _, sown = jax.jit(lambda p: model.apply(
+            {"params": p}, **batch, mutable=["intermediates"]))(params)
         rows = np.sum([np.asarray(v).reshape(-1, 8).sum(0) for v in
                        jax.tree_util.tree_leaves(sown)], 0)  # [E]
         assert rows.sum() == 2 * ids.size * 2    # layers x tokens x top-2
@@ -323,14 +343,14 @@ def test_moe_load_gauges_are_published_by_name(family, over, gauges):
 CHUNKED_LOSS = ("ouro", "sdar")
 
 
-@pytest.mark.parametrize("family", list(TRAIN_SCOPES))
-def test_head_loss_backward_rule_stands_under_its_scope(train_text, family):
+@pytest.mark.parametrize("family", FAMILIES)
+def test_head_loss_backward_rule_stands_under_its_scope(family):
     """``layers.cross_entropy_loss`` is a ``custom_vjp``: the operations of
     its backward rule -- the one pass that makes the logits' cotangent and
     the barrier that holds it -- carry the CALLER's ``ds.lm_head_loss`` and
     the backward pass's mark, so ``train.head_loss_share`` reads the whole
     head and ``train.unnamed_share`` nothing of it."""
-    text = train_text[family]
+    text = train_text(family)
     held = re.findall(r'"([^"]*)/optimization_barrier"', text)
     of_the_loss = [n for n in held if "ds.lm_head_loss" in n]
     if family in CHUNKED_LOSS:
@@ -347,9 +367,9 @@ def test_head_loss_backward_rule_stands_under_its_scope(train_text, family):
         assert f'"{path}/{op}"' in text
 
 
-def test_backward_and_recompute_leave_their_marks(train_text):
+def test_backward_and_recompute_leave_their_marks():
     """What ``scope_reduce.phase_of`` tells the phases apart by."""
-    text = train_text["mixtral"]
+    text = train_text("mixtral")
     assert "transpose(jvp(" in text
     assert "rematted_computation" in text
 
@@ -357,8 +377,8 @@ def test_backward_and_recompute_leave_their_marks(train_text):
 @pytest.fixture(scope="module")
 def mixed_text():
     model = LlamaForCausalLM(LlamaConfig.tiny())
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))["params"]
     srv = ds.init_serving(model, params=params,
                           config={"dtype": "fp32"},
                           serving_config=ds.ServingConfig(
@@ -717,13 +737,24 @@ def test_offered_name_stands_where_it_is_kept_and_nowhere_else(constant):
     spelled, case = OFFERED_NAMES[constant]
     assert constant == spelled
     named = lambda text: text.count(f"name[name={constant}]")
+    without, kept, text = _offering_texts(case)
+    assert (named(without) == 0) == (constant != names.REMAT_GDN_RULE)
+    assert constant in kept and named(text) > 0
+
+
+@functools.lru_cache(maxsize=None)
+def _offering_texts(case):
+    """``(the gradient's jaxpr with no room stated, the names kept under a
+    room for everything, the jaxpr under that room)`` of an offering model:
+    traced once a model a process, whichever of its names is asked about."""
+    from deepspeed_tpu.models.layers import remat_room
+
     grad, params = case()
-    without = named(str(jax.make_jaxpr(grad)(params)))
-    assert (without == 0) == (constant != names.REMAT_GDN_RULE)
+    without = str(jax.make_jaxpr(grad)(params))
     grad, params = case()       # jax keeps a function's trace
     with remat_room(10 ** 9) as kept:
         text = str(jax.make_jaxpr(grad)(params))
-    assert constant in kept and named(text) > 0
+    return without, dict(kept), text
 
 
 @pytest.fixture(scope="module")
@@ -996,17 +1027,19 @@ def test_delta_rule_kernels_stand_alone_under_their_scope(monkeypatch):
                   + 2 * ["ds_gdn_premix_fwd", "ds_gdn_gate_fwd"])
 
 
-def test_no_step_without_the_flash_indexer_holds_its_kernels(train_text):
+# one case a family: it reads that family's step alone (``train_text``)
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_no_step_without_the_flash_indexer_holds_its_kernels(family):
     """The other families' steps, and a ``sa_config`` step on the XLA path,
     carry none of the indexer's five kernels: their programs are what they
     were."""
-    assert set(train_text) == set(TRAIN_SCOPES)
-    for family, text in train_text.items():
-        assert "ds_sa_index" not in text, family
-        assert "ds_sa_probs" not in text, family
+    text = train_text(family)
+    assert "ds_sa_index" not in text and "ds_sa_probs" not in text
 
 
-def test_no_other_familys_step_holds_the_delta_rules_names(train_text):
+@pytest.mark.parametrize("family", FAMILIES)
+def test_no_other_familys_step_holds_the_delta_rules_names(family):
     """``ds.layer_gdn``, ``ds.gdn_mix`` and ``ds.gdn_rule`` stand in
     ``models/qwen3_next.py``'s step alone, ``ds.attn_gate`` there and in
     ``models/laguna.py``'s (a gate a head for a gate a column),
@@ -1016,57 +1049,56 @@ def test_no_other_familys_step_holds_the_delta_rules_names(train_text):
                           "ds.attn_gate"},
            "laguna": {"ds.attn_gate", "ds.layer_dense"},
            "kimi_linear": {"ds.layer_dense"}}
-    for family, text in train_text.items():
-        found = set(re.findall(
-            r"ds\.(?:layer_gdn|gdn_[a-z]+|attn_gate|layer_dense)\b", text))
-        assert found == own.get(family, set()), family
+    found = set(re.findall(
+        r"ds\.(?:layer_gdn|gdn_[a-z]+|attn_gate|layer_dense)\b",
+        train_text(family)))
+    assert found == own.get(family, set())
 
 
-def test_no_other_familys_step_holds_the_single_branch_layers_names(
-        train_text):
+@pytest.mark.parametrize("family", FAMILIES)
+def test_no_other_familys_step_holds_the_single_branch_layers_names(family):
     """``ds.layer_mamba`` and ``ds.layer_moe`` stand in
     ``models/nemotron_h.py``'s step alone (``ds.ssm_scan`` and ``ds.ssm_mix``
     there and in ``models/sambay.py``'s, whose names they are): the other
     families' programs are what they were, and ``NAMES_VERSION`` stays."""
-    for family, text in train_text.items():
-        found = set(re.findall(r"ds\.(?:layer_mamba|layer_moe)\b", text))
-        assert found == ({"ds.layer_mamba", "ds.layer_moe"}
-                         if family == "nemotron_h" else set()), family
-        assert bool(re.search(r"ds\.ssm_(?:scan|mix)\b", text)) == (
-            family in ("nemotron_h", "sambay")), family
+    text = train_text(family)
+    found = set(re.findall(r"ds\.(?:layer_mamba|layer_moe)\b", text))
+    assert found == ({"ds.layer_mamba", "ds.layer_moe"}
+                     if family == "nemotron_h" else set())
+    assert bool(re.search(r"ds\.ssm_(?:scan|mix)\b", text)) == (
+        family in ("nemotron_h", "sambay"))
 
 
-def test_no_other_familys_step_holds_the_vector_decay_rules_names(
-        train_text):
+@pytest.mark.parametrize("family", FAMILIES)
+def test_no_other_familys_step_holds_the_vector_decay_rules_names(family):
     """``ds.layer_kda``, ``ds.layer_mla``, ``ds.kda_mix`` and ``ds.kda_rule``
     stand in ``models/kimi_linear.py``'s step alone: the other families'
     programs are what they were, and ``NAMES_VERSION`` stays."""
-    for family, text in train_text.items():
-        found = set(re.findall(r"ds\.(?:layer_kda|layer_mla|kda_[a-z]+)\b",
-                               text))
-        assert found == ({"ds.layer_kda", "ds.layer_mla", "ds.kda_mix",
-                          "ds.kda_rule"}
-                         if family == "kimi_linear" else set()), family
+    found = set(re.findall(r"ds\.(?:layer_kda|layer_mla|kda_[a-z]+)\b",
+                           train_text(family)))
+    assert found == ({"ds.layer_kda", "ds.layer_mla", "ds.kda_mix",
+                      "ds.kda_rule"} if family == "kimi_linear" else set())
 
 
-def test_no_other_familys_step_holds_the_loops_names(train_text):
+@pytest.mark.parametrize("family", FAMILIES)
+def test_no_other_familys_step_holds_the_loops_names(family):
     """``ds.loop_stack`` and ``ds.exit_gate`` stand in ``models/ouro.py``'s
     step alone: the other families' programs are what they were, and
     ``NAMES_VERSION`` stays."""
-    for family, text in train_text.items():
-        found = set(re.findall(r"ds\.(?:loop_stack|exit_gate)\b", text))
-        assert found == ({"ds.loop_stack", "ds.exit_gate"}
-                         if family == "ouro" else set()), family
+    found = set(re.findall(r"ds\.(?:loop_stack|exit_gate)\b",
+                           train_text(family)))
+    assert found == ({"ds.loop_stack", "ds.exit_gate"}
+                     if family == "ouro" else set())
 
 
-def test_no_other_familys_step_holds_block_diffusions_names(train_text):
+@pytest.mark.parametrize("family", FAMILIES)
+def test_no_other_familys_step_holds_block_diffusions_names(family):
     """``ds.bd_noise`` and ``ds.bd_gather`` stand in ``models/sdar.py``'s
     step alone: the other families' programs are what they were, and
     ``NAMES_VERSION`` stays."""
-    for family, text in train_text.items():
-        found = set(re.findall(r"ds\.bd_[a-z]+\b", text))
-        assert found == ({"ds.bd_noise", "ds.bd_gather"}
-                         if family == "sdar" else set()), family
+    found = set(re.findall(r"ds\.bd_[a-z]+\b", train_text(family)))
+    assert found == ({"ds.bd_noise", "ds.bd_gather"}
+                     if family == "sdar" else set())
 
 
 @pytest.fixture

@@ -57,7 +57,7 @@ def _seeded(cfg, seed, ids):
     one and the skip column's bias raised so that the tiny router takes every
     kind of choice (held, absent, skip)."""
     model = ZayaForCausalLM(cfg)
-    params = model.init(jax.random.PRNGKey(seed), ids)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed), ids)["params"]
     keys = iter(jax.random.split(jax.random.PRNGKey(seed + 100), 64))
     params = jax.tree_util.tree_map_with_path(
         lambda kp, p: p * (1 + 0.3 * jax.random.normal(next(keys), p.shape))
@@ -78,17 +78,26 @@ def share():
     cfg = ZayaConfig.tiny(**SHARE)
     model, params = _seeded(cfg, 3, IDS)
     sizes = sizes_of(cfg)
-    sys_loss, sys_g = jax.value_and_grad(lambda p: model.apply(
-        {"params": p}, IDS, labels=IDS))(params)
-    ref_loss, ref_g = jax.value_and_grad(
-        lambda p: REF.loss(p, sizes, IDS))(params)
-    hidden, rows, skipped = zip(*(REF.hidden_states(params, sizes, ids)
-                                  for ids in IDS))
+    # one compiled program a side (run operation by operation, each side
+    # was some hundreds of one-operation programs, compiled in every worker
+    # that drew a case of this file)
+    (sys_loss, sys_logits), sys_g = jax.jit(jax.value_and_grad(
+        lambda p: (model.apply({"params": p}, IDS, labels=IDS),
+                   model.apply({"params": p}, IDS)), has_aux=True))(params)
+
+    def reference(p):
+        hidden, rows, skipped = zip(*(REF.hidden_states(p, sizes, ids)
+                                      for ids in IDS))
+        return REF.loss(p, sizes, IDS), (
+            jnp.stack([REF.logits(p, h) for h in hidden]), sum(rows),
+            sum(skipped))
+
+    (ref_loss, (ref_logits, rows, skipped)), ref_g = jax.jit(
+        jax.value_and_grad(reference, has_aux=True))(params)
     return {"sys_g": sys_g, "ref_g": ref_g, "params": params, "cfg": cfg,
-            "rows": sum(rows), "skipped": sum(skipped),
-            "sys_logits": model.apply({"params": params}, IDS),
-            "ref_logits": jnp.stack([REF.logits(params, h) for h in hidden]),
-            "sys_loss": sys_loss, "ref_loss": ref_loss}
+            "rows": rows, "skipped": skipped, "sys_logits": sys_logits,
+            "ref_logits": ref_logits, "sys_loss": sys_loss,
+            "ref_loss": ref_loss}
 
 
 def test_share_logits_and_loss_match_the_reference(share):
@@ -151,11 +160,12 @@ def test_a_prefix_sees_nothing_after_it(share, t):
     change at ``t + 1`` moves nothing at or before ``t``."""
     model = ZayaForCausalLM(share["cfg"])
     full = np.asarray(share["sys_logits"])
-    alone = model.apply({"params": share["params"]}, IDS[:, :t + 1])
+    apply = jax.jit(lambda p, ids: model.apply({"params": p}, ids))
+    alone = apply(share["params"], IDS[:, :t + 1])
     np.testing.assert_allclose(np.asarray(alone), full[:, :t + 1],
                                rtol=1e-4, atol=1e-5)
     changed = IDS.at[:, t + 1].set((IDS[:, t + 1] + 1) % 128)
-    moved = np.asarray(model.apply({"params": share["params"]}, changed))
+    moved = np.asarray(apply(share["params"], changed))
     np.testing.assert_allclose(moved[:, :t + 1], full[:, :t + 1], rtol=1e-4,
                                atol=1e-5)
     assert np.abs(moved[:, t + 1] - full[:, t + 1]).max() > 1e-3
@@ -210,12 +220,12 @@ def test_scanned_layers_carry_the_router_state_as_an_unrolled_loop_does(
            for i in range(L)}}}
     loop = ZayaForCausalLM(dataclasses.replace(cfg, scan_layers=False))
     np.testing.assert_allclose(
-        np.asarray(loop.apply({"params": unrolled}, IDS)),
+        np.asarray(jax.jit(loop.apply)({"params": unrolled}, IDS)),
         np.asarray(share["sys_logits"]), rtol=1e-5, atol=1e-6)
     saved = zaya._carry_state
     try:
         zaya._carry_state = lambda r, gamma, state: r
-        forgot = ZayaForCausalLM(cfg).apply({"params": params}, IDS)
+        forgot = jax.jit(ZayaForCausalLM(cfg).apply)({"params": params}, IDS)
     finally:
         zaya._carry_state = saved
     assert np.abs(np.asarray(forgot)
@@ -235,9 +245,15 @@ def _levelled(p, x, state, sizes, rate=0.004, steps=60):
         p["attn_residual"])
     h = REF.dense.rms_norm(mid, p["post_attention_layernorm"]["scale"], eps)
     router = dict(p["mlp"]["router"])
-    for _ in range(steps):
+
+    @jax.jit
+    def step(router):
         load = (REF.route(h, state, router, sizes)[0] > 0).sum(0)
-        router[BIAS] = router[BIAS] + rate * jnp.sign(load.mean() - load)
+        return {**router, BIAS: router[BIAS]
+                + rate * jnp.sign(load.mean() - load)}, load
+
+    for _ in range(steps):
+        router, load = step(router)
     assert int((load > 0).sum()) >= 12
     return {**p, "mlp": {**p["mlp"], "router": router}}
 
@@ -254,12 +270,12 @@ def test_the_two_shares_add_up_to_the_uncut_layer():
                                     (1, T, full.router_hidden_size))
     cos, sin = rotary_embedding(jnp.arange(T)[None], full.rotary_dim,
                                 full.rope_theta)
-    p = ZayaBlock(full).init(jax.random.PRNGKey(2), x, state, cos, sin,
-                             None)["params"]
+    p = jax.jit(ZayaBlock(full).init)(jax.random.PRNGKey(2), x, state, cos,
+                                      sin, None)["params"]
     sizes = sizes_of(full)
     p = _levelled(p, x[0], state[0], sizes)
-    want, _, rows, skipped = REF._layer(x[0], state[0], p,
-                                        REF.dense._static(sizes))
+    want, _, rows, skipped = jax.jit(lambda p: REF._layer(
+        x[0], state[0], p, REF.dense._static(sizes)))(p)
     assert int(rows.sum()) + int(skipped) == T and int(skipped) > 0
     assert min((np.asarray(rows[:8]) > 0).sum(),
                (np.asarray(rows[8:]) > 0).sum()) >= 4
@@ -270,7 +286,7 @@ def test_the_two_shares_add_up_to_the_uncut_layer():
                                   router_experts=16, first_expert=first)
         mine = {**p, "mlp": {**p["mlp"], **{
             w: p["mlp"][w][first:first + 8] for w in ("w1", "w2", "w3")}}}
-        out, _, share_rows, share_skipped, _ = ZayaBlock(cfg).apply(
+        out, _, share_rows, share_skipped, _ = jax.jit(ZayaBlock(cfg).apply)(
             {"params": mine}, x, state, cos, sin, None)
         np.testing.assert_array_equal(np.asarray(share_rows),
                                       np.asarray(rows[first:first + 8]))
@@ -278,12 +294,18 @@ def test_the_two_shares_add_up_to_the_uncut_layer():
         total = total + out[0]
     # what both chips computed alike, from the reference's parts
     eps = sizes["rms_norm_eps"]
-    mid = REF.residual(x[0], REF.attention(REF.dense.rms_norm(
-        x[0], p["input_layernorm"]["scale"], eps), p["self_attn"], sizes),
-        p["attn_residual"])
-    h = REF.dense.rms_norm(mid, p["post_attention_layernorm"]["scale"], eps)
-    _, skip, *_ = REF.moe_parts(h, state[0], p["mlp"], sizes)
-    alike = REF.residual(mid, skip, p["mlp_residual"])
+
+    @jax.jit
+    def computed_alike(p):
+        mid = REF.residual(x[0], REF.attention(REF.dense.rms_norm(
+            x[0], p["input_layernorm"]["scale"], eps), p["self_attn"],
+            sizes), p["attn_residual"])
+        h = REF.dense.rms_norm(mid, p["post_attention_layernorm"]["scale"],
+                               eps)
+        _, skip, *_ = REF.moe_parts(h, state[0], p["mlp"], sizes)
+        return REF.residual(mid, skip, p["mlp_residual"])
+
+    alike = computed_alike(p)
     np.testing.assert_allclose(np.asarray(total - alike), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
     assert float(jnp.abs(want - alike).max()) > 1e-2     # experts do add
@@ -309,10 +331,11 @@ def test_flash_path_equals_the_xla_path_and_runs_the_forward_once_a_layer(
     (loss, grads), (loss0, grads0) = remat_loss_and_grads(
         monkeypatch, model_of, ids)
     assert_same_loss_and_grads(loss, grads, loss0, grads0)
-    params = model_of(False).init(jax.random.PRNGKey(0), ids)["params"]
+    params = jax.jit(model_of(False).init)(jax.random.PRNGKey(0),
+                                           ids)["params"]
     xla = ZayaForCausalLM(ZayaConfig.tiny())
-    loss_x, grads_x = jax.value_and_grad(lambda p: xla.apply(
-        {"params": p}, ids, labels=ids))(params)
+    loss_x, grads_x = jax.jit(jax.value_and_grad(lambda p: xla.apply(
+        {"params": p}, ids, labels=ids)))(params)
     assert float(loss0) == pytest.approx(float(loss_x), rel=1e-5)
     jax.tree_util.tree_map(lambda a, b: np.testing.assert_allclose(
         a, b, rtol=1e-3, atol=1e-5), grads0, grads_x)
@@ -325,7 +348,8 @@ def test_flash_path_equals_the_xla_path_and_runs_the_forward_once_a_layer(
                  "ds_flash_bwd_dkv")]
     assert count(model_of(True), params) == [1, 0, 1, 1]
     loop = model_of(True, scan_layers=False)
-    loop_params = loop.init(jax.random.PRNGKey(0), ids)["params"]
+    loop_params = jax.eval_shape(loop.init, jax.random.PRNGKey(0),
+                                 ids)["params"]       # only traced
     assert count(loop, loop_params) == [L, 0, L, L]
     # on the chip a head's dQ stays in VMEM (``fa.fused_backward`` answers
     # for the device kind, the CPU's here): one backward kernel a flash call
@@ -368,8 +392,8 @@ def test_engine_moves_what_frozen_parameters_leaves(trainable):
         [BIAS] if trainable else [r"mlp/router/"])
     engine, batch = _engine(cfg)
     before = _flat(engine.state.params)
-    grads = jax.grad(lambda p: ZayaForCausalLM(cfg).apply(
-        {"params": p}, **batch))(engine.state.params)
+    grads = jax.jit(jax.grad(lambda p: ZayaForCausalLM(cfg).apply(
+        {"params": p}, **batch)))(engine.state.params)
     router = {k: np.asarray(v) for k, v in _flat(grads).items()
               if "['router']" in k and BIAS not in k}
     assert len(router) == 9 and all(np.abs(g).max() > 0
@@ -385,7 +409,9 @@ def test_engine_moves_what_frozen_parameters_leaves(trainable):
 
 # -- what the replay keeps (PR 65) -------------------------------------------
 
-@pytest.mark.parametrize("scan", [True, False], ids=["scan", "unrolled"])
+@pytest.mark.parametrize("scan", [
+    True,       # unrolled: 34 s cold (PR 69); the scanned stack asks the same
+    pytest.param(False, marks=pytest.mark.slow)], ids=["scan", "unrolled"])
 def test_kept_names_change_no_loss_and_no_gradient(scan):
     """The remat'ed share with every offered value kept (``remat_offers``
     under a room: the expert sublayer's output, the projections, the experts'
@@ -450,7 +476,8 @@ def test_balancing_rule_moves_the_bias_against_the_load():
     cfg = ZayaConfig.tiny(router_bias_update_rate=rate, **SHARE)
     engine, batch = _engine(cfg)
     params = jax.tree_util.tree_map(np.asarray, engine.state.params)
-    loss, named = ZayaForCausalLM(cfg).apply({"params": params}, **batch)
+    loss, named = jax.jit(lambda p: ZayaForCausalLM(cfg).apply(
+        {"params": p}, **batch))(params)
     delta = np.asarray(named["param_deltas"][BIAS_PATH])      # [L, E + 1]
     assert set(named) == {"param_deltas"} and delta.shape == (L, 9)
     assert np.all(np.isclose(np.abs(delta), rate) | (delta == 0))
@@ -458,9 +485,9 @@ def test_balancing_rule_moves_the_bias_against_the_load():
     sizes = sizes_of(cfg)
     first = REF.dense.f32(jax.tree_util.tree_map(
         lambda a: a[0], params["model"]["layers"]["block"]))
-    load = 0
-    with jax.default_matmul_precision("highest"):
-        for ids in batch["input_ids"]:
+    @jax.jit
+    def load_of(ids):
+        with jax.default_matmul_precision("highest"):
             x = jnp.asarray(params["model"]["embed_tokens"]["embedding"])[ids]
             mid = REF.residual(x, REF.attention(REF.dense.rms_norm(
                 x, first["input_layernorm"]["scale"], 1e-5),
@@ -469,7 +496,9 @@ def test_balancing_rule_moves_the_bias_against_the_load():
                 mid, first["post_attention_layernorm"]["scale"], 1e-5)
             combine, _ = REF.route(h, jnp.zeros((16, 16)),
                                    first["mlp"]["router"], sizes)
-            load = load + np.asarray((combine > 0).sum(0))
+            return (combine > 0).sum(0)
+
+    load = sum(np.asarray(load_of(ids)) for ids in batch["input_ids"])
     assert load.sum() == batch["input_ids"].size and load.shape == (9,)
     np.testing.assert_allclose(delta[0], rate * np.sign(load.mean() - load),
                                atol=1e-7)
@@ -491,8 +520,9 @@ def test_engine_publishes_the_three_gauges():
                               "moe_rows_max_over_mean", "moe_skip_share"]
     sizes = sizes_of(cfg)
     rows, skipped = 0, 0
+    counts = jax.jit(lambda p, ids: REF.hidden_states(p, sizes, ids)[1:])
     for ids in batch["input_ids"]:
-        _, r, s = REF.hidden_states(params, sizes, jnp.asarray(ids))
+        r, s = counts(params, ids)
         rows, skipped = rows + np.asarray(r), skipped + int(s)
     choices = L * batch["input_ids"].size
     assert gauges["moe_skip_share"] == pytest.approx(skipped / choices)
@@ -508,10 +538,10 @@ def test_unbuilt_paths_say_so():
                   "n_routed_experts": 4},
                  {"num_key_value_heads": 1, "num_attention_heads": 4}):
         with pytest.raises(ValueError):
-            ZayaForCausalLM(ZayaConfig.tiny(**over)).init(
-                jax.random.PRNGKey(0), ids)
+            jax.eval_shape(ZayaForCausalLM(ZayaConfig.tiny(**over)).init,
+                           jax.random.PRNGKey(0), ids)
     model = ZayaForCausalLM(ZayaConfig.tiny())
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"]
     with pytest.raises(NotImplementedError):
         model.apply({"params": params}, ids, cache={})
 
