@@ -1,0 +1,12 @@
+"""Share of the MXU's bf16 peak that ``ds.mlp`` reaches (the dense
+feed-forward products): the matrix operations the program counts under the
+scope a step (``matmul_flops_mlp - replayed_flops_mlp`` of ``ds.step_cost``:
+forward and backward, the replays left out) x the traced window's steps, over
+the device seconds under that scope less its replays' x the peak x the
+devices (benchmark/step_cost.py)."""
+
+from benchmark import step_cost
+
+
+def read(run):
+    return step_cost.mxu_share(run, "ds.mlp")

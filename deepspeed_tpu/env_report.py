@@ -207,7 +207,8 @@ def dslint_report() -> None:
 def perf_report() -> None:
     """Performance-accounting status (``monitor/perf.py``): per-device
     memory stats and the resident compiled-program table (name,
-    fingerprint hash, compile/recompile counts, cost-model FLOPs).
+    fingerprint hash, compile/recompile counts, FLOPs a call and their
+    source; under the train step's row its matrix work by scope).
 
     The program table is per-process — a fresh ``ds_report`` CLI run has
     no engines, so it reports none; call this from inside a serving or
@@ -232,7 +233,7 @@ def perf_report() -> None:
         print("compiled programs: none resident in this process")
         return
     from deepspeed_tpu.monitor.export import (LEDGER_HEADER, ledger_columns,
-                                              memory_line)
+                                              memory_line, step_cost_line)
 
     print(f"{'program':<34}{'fingerprint':<13}{'compiles':>9}"
           f"{'recompiles':>11}{'calls':>7}{LEDGER_HEADER}  flops/call")
@@ -241,8 +242,7 @@ def perf_report() -> None:
         print(f"{r['name']:<34}{str(r['fingerprint']):<13}"
               f"{r['compiles']:>9}{r['recompiles']:>11}{r['calls']:>7}"
               f"{ledger_columns(r)}  {flops} ({r['cost_source'] or '-'})")
-        line = memory_line(r)
-        if line:
+        for line in filter(None, (memory_line(r), step_cost_line(r))):
             print(line)
 
 

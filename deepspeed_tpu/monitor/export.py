@@ -474,6 +474,23 @@ def memory_line(row) -> Optional[str]:
             f"peak {gb('step_peak_bytes')} GB")
 
 
+def step_cost_line(row) -> Optional[str]:
+    """One line under the TRAIN step's row (``monitor/perf.py StepCost``):
+    the matrix operations a step runs and how many of them are replays,
+    the largest scopes, and what the count leaves out by name. None for a
+    row without the record."""
+    c = row.get("step_cost")
+    if not c:
+        return None
+    top = sorted(c["scopes"].items(), key=lambda kv: -sum(kv[1].values()))
+    scopes = ", ".join(f"{k} {sum(v.values()) / 1e12:.3f}" for k, v in top[:6])
+    left = ", ".join(f"{k} x{n}" for k, n in c["uncounted"].items()) or "none"
+    return (f"  {row['name']}: {c['matmul_flops'] / 1e12:.3f} T matrix "
+            f"operations a step ({c['replayed_flops'] / 1e12:.3f} replayed; "
+            f"cond spread {c['cond_spread_flops'] / 1e12:.3f}); by scope: "
+            f"{scopes}; uncounted: {left}; walked in {c['walk_s']:.3f} s")
+
+
 def serving_statusz(srv) -> str:
     """The human-readable /statusz page of a serving engine: resident
     compiled-program table, recompile counts, HBM watermarks, and the
@@ -495,8 +512,7 @@ def serving_statusz(srv) -> str:
         lines.append(f"{row['name']:<28}{str(row['fingerprint']):<13}"
                      f"{row['compiles']:>9}{row['recompiles']:>11}"
                      f"{row['calls']:>7}{ledger_columns(row)}")
-        line = memory_line(row)
-        if line:
+        for line in filter(None, (memory_line(row), step_cost_line(row))):
             lines.append(line)
     lines.append("")
     lines.append(f"compile_counts: {json.dumps(perf.get('compile_counts'))}")

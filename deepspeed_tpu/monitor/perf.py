@@ -15,12 +15,18 @@ layer makes *performance claims* measurable and defensible:
   and how it changed. The serving layer's "ONE decode compile" invariant
   stops being a test-only assertion and becomes something a production
   run screams about.
-- **Cost-model accounting** — ``jitfn.lower(*args).cost_analysis()``
-  captured once per program (the lowering is cached by jax, so this pays
-  no second trace and no XLA compile), with a hand-rolled transformer
-  FLOPs estimate as the fallback where the backend has no cost model.
-  Combined with step wall times this yields **MFU** (training / prefill:
-  compute-bound) and **MBU + tokens/sec/chip** (decode: bandwidth-bound).
+- **Cost accounting** — the TRAIN step counts its own matrix work:
+  :class:`StepCost` walks the jaxpr the step's lowering was made from (no
+  second trace), a scan's body times its length, by ``ds.*`` scope, the
+  replays apart; ``train_mfu`` divides it by the published step time. The
+  serving programs and dense ``generate`` keep
+  ``jitfn.lower(*args).cost_analysis()`` captured once per program (the
+  lowering is cached by jax: no second trace, no XLA compile), with a
+  hand-rolled transformer FLOPs estimate as the fallback where the backend
+  has no cost model -- XLA counts a ``while`` body ONCE and a Pallas call 0,
+  which is why the train step left it. With step wall times this yields
+  **MFU** (training / prefill: compute-bound) and **MBU + tokens/sec/chip**
+  (decode: bandwidth-bound).
 - **Device memory watermarks** — ``device.memory_stats()`` live/peak HBM
   bytes, graceful no-op on backends (CPU) that expose none.
 - **Compile ledger + set-up record** — one set of ``jax.monitoring``
@@ -155,7 +161,7 @@ class CompiledProgram:
 
     __slots__ = ("name", "fingerprint", "compiles", "calls", "recompiles",
                  "flops", "bytes_accessed", "cost_source", "cost_attempted",
-                 "memory")
+                 "memory", "step_cost")
 
     def __init__(self, name: str):
         self.name = name
@@ -165,7 +171,8 @@ class CompiledProgram:
         self.recompiles = 0    # sentinel alarms: fingerprint changed
         self.flops: Optional[float] = None           # per call
         self.bytes_accessed: Optional[float] = None  # per call
-        self.cost_source: Optional[str] = None  # "cost_model" | "estimate"
+        # "cost_model" | "estimate" | "jaxpr" (the train step: StepCost)
+        self.cost_source: Optional[str] = None
         #: capture tried (even unsuccessfully): a backend with no cost
         #: model AND no fallback must pay the lowering walk once, not on
         #: every hot-path dispatch
@@ -174,6 +181,8 @@ class CompiledProgram:
         #: call: what its remat policy keeps and what the compiled program
         #: occupies (``export.memory_line`` prints it under the row)
         self.memory: Optional[Dict[str, int]] = None
+        #: the train step's :class:`StepCost` (``export.step_cost_line``)
+        self.step_cost: Optional["StepCost"] = None
 
     @property
     def cost_pending(self) -> bool:
@@ -192,7 +201,8 @@ class CompiledProgram:
                 "compiles": self.compiles, "recompiles": self.recompiles,
                 "calls": self.calls, "flops": self.flops,
                 "bytes_accessed": self.bytes_accessed,
-                "cost_source": self.cost_source, "memory": self.memory}
+                "cost_source": self.cost_source, "memory": self.memory,
+                "step_cost": self.step_cost.row() if self.step_cost else None}
 
 
 #: every live ProgramRegistry in the process, for ``ds_report``'s resident
@@ -676,6 +686,75 @@ class SetupRecord:
         return {**numbers, "steps_before": steps_before}
 
 
+class StepCost:
+    """The matrix operations ONE train step runs, counted by the program
+    from the jaxpr its own lowering came from
+    (``profiling/flops_profiler.walk_jaxpr``, walked once in set-up inside
+    ``cost_capture``): ``2 x`` the multiply-accumulates of every plain,
+    grouped and convolutional product and of the Pallas kernels in
+    ``ops/pallas MATMUL_FLOPS``, a scan's body times its length, GLOBAL (over
+    every device), by the innermost ``ds.`` scope of the name stack -- the
+    scope ``benchmark/scope_reduce`` gives a device operation's time to --
+    and apart for the forward pass, the backward pass and what
+    ``jax.checkpoint`` REPLAYS. XLA's ``cost_analysis()`` cannot stand here:
+    it counts a ``while`` body once, so a scanned stack of any depth reads as
+    one layer, and a Pallas call reads 0. Published like the set-up record:
+    the train step's registry row and the ``ds.step_cost`` host event."""
+
+    def __init__(self, walk, walk_s: float):
+        self.scopes: Dict[str, Dict[str, int]] = {
+            scope: dict(row) for scope, row in sorted(walk.scopes.items())}
+        self.cond_spread_flops = int(walk.cond_spread_flops)
+        #: what counted 0, by name, with its calls a step: a Pallas kernel
+        #: without an entry in ``MATMUL_FLOPS`` (or with a traced grid), a
+        #: ``while``
+        self.uncounted: Dict[str, int] = dict(sorted(walk.uncounted.items()))
+        self.walk_s = walk_s
+
+    @property
+    def matmul_flops(self) -> int:
+        return sum(sum(row.values()) for row in self.scopes.values())
+
+    @property
+    def replayed_flops(self) -> int:
+        return sum(row["replayed"] for row in self.scopes.values())
+
+    @property
+    def model_flops(self) -> int:
+        """What the model asks for: the replays left out (``train_mfu``)."""
+        return self.matmul_flops - self.replayed_flops
+
+    @staticmethod
+    def stat(scope: str) -> str:
+        """``ds.mlp`` -> ``mlp``, ``(unscoped)`` -> ``unscoped``: a scope as
+        a stat's (and a gauge's) suffix."""
+        return re.sub(r"^ds\.|[()]", "", scope)
+
+    def record(self) -> Dict[str, float]:
+        """One stat a number (docs/observability.md has each): a step's
+        matrix operations a scope (forward + backward + replayed), the
+        replayed part of them, the totals, the spread a ``cond``'s larger
+        branch would add, the calls of kernels the count leaves out (in all
+        and a kernel), and the seconds the walk took."""
+        out: Dict[str, float] = {}
+        for scope, row in self.scopes.items():
+            out[f"matmul_flops_{self.stat(scope)}"] = sum(row.values())
+            out[f"replayed_flops_{self.stat(scope)}"] = row["replayed"]
+        out.update(matmul_flops=self.matmul_flops,
+                   replayed_flops=self.replayed_flops,
+                   cond_spread_flops=self.cond_spread_flops,
+                   uncounted_kernel_calls=sum(
+                       n for k, n in self.uncounted.items() if k != "while"),
+                   walk_s=self.walk_s)
+        for name, calls in self.uncounted.items():
+            out[f"uncounted_{name}"] = calls
+        return out
+
+    def row(self) -> Dict[str, Any]:
+        return {**self.record(), "scopes": self.scopes,
+                "uncounted": self.uncounted}
+
+
 # ---------------------------------------------------------------------------
 # PerfAccounting: the engine-side bundle
 # ---------------------------------------------------------------------------
@@ -755,6 +834,33 @@ class PerfAccounting:
             return
         self.programs.set_cost(name, cost.get("flops"),
                                cost.get("bytes_accessed"), source)
+
+    def capture_step_cost(self, name: str, jaxpr) -> Optional[StepCost]:
+        """The TRAIN step's cost, once: a walk of the jaxpr its lowering
+        was made from (no second trace, no executable; ``cost_analysis()``
+        is not consulted -- :class:`StepCost` says why). ``flops`` of the
+        row are the operations the model asks for, ``cost_source``
+        ``"jaxpr"``; the step publishes no bytes. Latched and never raising,
+        like :meth:`capture_cost`."""
+        prog = self.programs.program(name)
+        if prog.cost_attempted:
+            return prog.step_cost
+        prog.cost_attempted = True      # with no jaxpr too: the step is warm
+        if jaxpr is None:
+            return None
+        try:
+            from ..profiling.flops_profiler.profiler import walk_jaxpr
+
+            t0 = time.perf_counter()
+            walk = walk_jaxpr(jaxpr)
+            prog.step_cost = StepCost(walk, time.perf_counter() - t0)
+        except Exception as e:
+            logger.warning(f"perf: the walk of {name}'s jaxpr failed: "
+                           f"{type(e).__name__}: {e}")
+            return None
+        self.programs.set_cost(name, float(prog.step_cost.model_flops), None,
+                               "jaxpr")
+        return prog.step_cost
 
     # -- utilization ----------------------------------------------------
 

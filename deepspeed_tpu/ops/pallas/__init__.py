@@ -114,3 +114,49 @@ REMAT_SSM_IN = "ds_ssm_in_proj"
 # output (each pass of the rule is rematerialised by itself: a replay that
 # holds the output runs no rule)
 REMAT_KDA_RULE = "ds_kda_rule_out"
+
+
+# -- the matrix operations a call of a kernel RUNS ---------------------------
+# Data for ``profiling/flops_profiler``'s walk of a jaxpr (the train step's
+# ``StepCost``), which never enters a kernel's body; it reaches no lowering
+# (no ``cost_estimate=`` on a call: that goes into the custom call). An entry
+# is ``f(operands, outputs, grid, blocks) -> operations or None``: the shapes
+# of the call's operands after its scalar-prefetch arguments and of its
+# outputs, the grid, and the block shape of every operand and output in
+# order. A kernel without an entry counts 0 and the walk lists it by name
+# under ``uncounted``: its products run in VMEM beside element-wise work the
+# kernel's own cost file under ``benchmark/`` knows (``ds_ssm_scan_*``,
+# ``ds_gdn_*``, ``ds_sa_*``).
+
+def _grouped_product(operands, outputs, grid, blocks):
+    # lhs [M, A] x rhs [G, A, B] (or [G, B, A]) -> [M, B], and the weights'
+    # gradient lhs [M, A], rhs [M, B] -> [G, A, B]: a row meets one group
+    (m, a), out = operands[0], outputs[0]
+    return 2 * m * a * out[-1]
+
+
+def _flash_products(per_tile):
+    """``per_tile(D, Dv)``: the contraction widths of the products ONE grid
+    step runs on its ``[bq, bk]`` tile. A cut tile is computed whole; a grid
+    whose length is data (a mask's tile table) is not counted."""
+    def count(operands, outputs, grid, blocks):
+        if not all(isinstance(g, int) for g in grid):
+            return None
+        (_, _, bq, d), (_, _, bk, _), (_, _, _, dv) = blocks[:3]
+        steps = 1
+        for g in grid:
+            steps *= g
+        return 2 * steps * bq * bk * per_tile(d, dv)
+    return count
+
+
+MATMUL_FLOPS = {
+    MOE_GMM: _grouped_product,
+    MOE_GMM_T: _grouped_product,
+    # s = q k^T (D) and p v (Dv)
+    FLASH_FWD: _flash_products(lambda d, dv: d + dv),
+    # s, dk = ds^T q and dq = ds k (D); dv = p^T do and dp = do v^T (Dv)
+    FLASH_BWD: _flash_products(lambda d, dv: 3 * d + 2 * dv),
+    FLASH_BWD_DQ: _flash_products(lambda d, dv: 2 * d + dv),
+    FLASH_BWD_DKV: _flash_products(lambda d, dv: 2 * d + 2 * dv),
+}

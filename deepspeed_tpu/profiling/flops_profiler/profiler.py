@@ -10,11 +10,18 @@ JAX name stack (the same metadata XLA shows in HLO). ``lax.scan`` bodies are
 counted once and multiplied by trip count, so a scanned N-layer model costs
 one layer's analysis.
 
+The same walk (``walk_jaxpr``) is the train step's own count of its matrix
+work (``monitor/perf.py StepCost``): products alone, by the innermost ``ds.``
+scope and by phase, a ``shard_map`` body times its devices, a ``cond``'s
+cheapest branch, a Pallas call by the table ``ops/pallas MATMUL_FLOPS``.
+
 No execution, no monkey-patching, exact shapes — and it works on anything
 jittable, not just ``nn.Module``s.
 """
 
+import collections
 import dataclasses
+import re
 import sys
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -51,15 +58,22 @@ def _size(aval) -> int:
         return 0
 
 
-def _dot_general_flops(eqn) -> Tuple[int, int]:
-    """(flops, macs) from dimension numbers: 2 * batch * M * N * K."""
-    (lc, rc), (lb, rb) = eqn.params["dimension_numbers"]
-    lhs, rhs = eqn.invars[0].aval, eqn.invars[1].aval
+def _product_macs(lhs, rhs, dimension_numbers, rhs_group=()) -> int:
+    """batch x M x N x K of a product from its dimension numbers; ``rhs``'s
+    ``rhs_group`` dimensions (a grouped product's) are no part of N."""
+    (lc, rc), (lb, rb) = dimension_numbers
+    size = lambda shape, skip: int(np.prod(
+        [s for i, s in enumerate(shape) if i not in skip]) or 1)
     batch = int(np.prod([lhs.shape[i] for i in lb])) if lb else 1
     contract = int(np.prod([lhs.shape[i] for i in lc])) if lc else 1
-    m = int(np.prod([s for i, s in enumerate(lhs.shape) if i not in lc + lb]) or 1)
-    n = int(np.prod([s for i, s in enumerate(rhs.shape) if i not in rc + rb]) or 1)
-    macs = batch * m * n * contract
+    return batch * contract * size(lhs.shape, tuple(lc) + tuple(lb)) \
+        * size(rhs.shape, tuple(rc) + tuple(rb) + tuple(rhs_group))
+
+
+def _dot_general_flops(eqn) -> Tuple[int, int]:
+    """(flops, macs) from dimension numbers: 2 * batch * M * N * K."""
+    macs = _product_macs(eqn.invars[0].aval, eqn.invars[1].aval,
+                         eqn.params["dimension_numbers"])
     return 2 * macs, macs
 
 
@@ -78,24 +92,35 @@ def _conv_flops(eqn) -> Tuple[int, int]:
     return 2 * macs, macs
 
 
-def _sub_jaxprs(eqn) -> List[Tuple[Any, int]]:
-    """(inner jaxpr, trip multiplier) pairs for a higher-order primitive."""
-    name = eqn.primitive.name
-    if name == "scan":
-        return [(eqn.params["jaxpr"].jaxpr, int(eqn.params["length"]))]
-    if name == "while":
-        # trip count is data-dependent; count ONE iteration (documented)
-        return [(eqn.params["body_jaxpr"].jaxpr, 1)]
-    if name == "cond":
-        # count the most expensive branch
-        return [(max((b.jaxpr for b in eqn.params["branches"]),
-                     key=lambda j: len(j.eqns)), 1)]
-    out = []
-    for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
-        sub = eqn.params.get(key)
-        if sub is not None:
-            out.append((sub.jaxpr if hasattr(sub, "jaxpr") else sub, 1))
-    return out
+def _ragged_dot_flops(eqn) -> Tuple[int, int]:
+    """A grouped product as the dense one over its operands: ``lhs [M, K] x
+    rhs [G, K, N]`` is ``2 M K N`` (a row meets ONE group's weight), and so
+    is the transposed form ``lhs [M, K] x rhs [M, N] -> [G, K, N]`` (ragged
+    along the contraction) -- read off the dimension numbers, ``rhs``'s
+    group dimensions left out."""
+    numbers = eqn.params.get("ragged_dot_dimension_numbers")
+    dims, group = ((((1,), (1,)), ((), ())), (0,)) if numbers is None else \
+        (numbers.dot_dimension_numbers, numbers.rhs_group_dimensions)
+    macs = _product_macs(eqn.invars[0].aval, eqn.invars[1].aval, dims, group)
+    return 2 * macs, macs
+
+
+#: the innermost ``ds.`` scope of a name-stack path: the rule
+#: ``benchmark/scope_reduce.py SCOPE`` applies to a device operation's
+#: ``op_name`` (the same expression; a test holds the two together)
+SCOPE = re.compile(r"ds\.[a-z_0-9]+")
+UNSCOPED = "(unscoped)"
+PHASES = ("forward", "backward", "replayed")
+
+
+def phase_of(path: str) -> str:
+    """forward / backward / replayed from the marks the transformations leave
+    in a name stack, as ``scope_reduce.phase_of`` reads them off ``op_name``:
+    what ``jax.checkpoint``'s backward runs again stands under
+    ``rematted_computation``, the backward pass under ``transpose(``."""
+    if "rematted_computation" in path:
+        return "replayed"
+    return "backward" if "transpose(" in path else "forward"
 
 
 @dataclasses.dataclass
@@ -118,38 +143,156 @@ class ModuleProfile:
     def total_macs(self) -> int:
         return self.macs + sum(c.total_macs() for c in self.children.values())
 
+    def add(self, other: "ModuleProfile") -> None:
+        self.flops += other.flops
+        self.macs += other.macs
+        for name, node in other.children.items():
+            self.child(name).add(node)
 
-def _walk(jaxpr, root: ModuleProfile, mult: int, prefix: Tuple[str, ...]):
+
+@dataclasses.dataclass
+class Walk:
+    """What one walk of a jaxpr counted. ``tree``: every counted operation
+    under its name stack (the printed profile). ``scopes``: the MATRIX
+    operations alone (``2 x`` the multiply-accumulates of ``dot_general``,
+    convolutions, grouped products and the Pallas kernels of
+    ``ops/pallas MATMUL_FLOPS``), by the innermost ``ds.`` scope of the path
+    and by phase. Every count is GLOBAL: a ``shard_map`` body counts once a
+    device of its mesh axes. A ``cond`` counts its branch of the FEWEST
+    matrix operations (a lower bound) and keeps the distance to its largest
+    as ``cond_spread_flops``; what counts nothing -- a ``while``, whose trip
+    count is data, a Pallas kernel without an entry or with a traced grid --
+    stands in ``uncounted`` by name with its calls."""
+
+    tree: ModuleProfile = dataclasses.field(
+        default_factory=lambda: ModuleProfile("total"))
+    scopes: Dict[str, Dict[str, int]] = dataclasses.field(default_factory=dict)
+    cond_spread_flops: int = 0
+    uncounted: Dict[str, int] = dataclasses.field(
+        default_factory=collections.Counter)
+
+    def _row(self, scope: str) -> Dict[str, int]:
+        return self.scopes.setdefault(scope, dict.fromkeys(PHASES, 0))
+
+    def matmul_flops(self, *phases: str) -> int:
+        return sum(v for row in self.scopes.values() for k, v in row.items()
+                   if not phases or k in phases)
+
+    def count(self, stack: Tuple[str, ...], flops: int, macs: int) -> None:
+        node = self.tree
+        for part in stack:
+            node = node.child(part)
+        node.flops += flops
+        node.macs += macs
+        if macs:
+            path = "/".join(stack)
+            found = SCOPE.findall(path)
+            self._row(found[-1] if found else UNSCOPED)[
+                phase_of(path)] += 2 * macs
+
+    def add(self, other: "Walk") -> None:
+        self.tree.add(other.tree)
+        for scope, row in other.scopes.items():
+            mine = self._row(scope)
+            for phase, flops in row.items():
+                mine[phase] += flops
+        self.cond_spread_flops += other.cond_spread_flops
+        self.uncounted.update(other.uncounted)       # a Counter: adds
+
+
+def _shard_map_devices(eqn) -> int:
+    """The devices a ``shard_map`` body runs on: the product of the mesh
+    axes it is manual over (all of them where it names none)."""
+    mesh = eqn.params["mesh"]
+    axes = eqn.params.get("manual_axes") or mesh.axis_names
+    return int(np.prod([mesh.shape[a] for a in axes]))
+
+
+def _pallas_flops(eqn) -> Optional[int]:
+    """The matrix operations a ``pallas_call`` RUNS, from the table beside
+    the kernels' names -- the kernel's body is never entered: its jaxpr is
+    ONE grid step's, over blocks. None: no entry, or one that cannot count
+    this call (a grid with a traced bound)."""
+    from ...ops.pallas import MATMUL_FLOPS
+
+    count = MATMUL_FLOPS.get(eqn.params.get("name"))
+    if count is None:
+        return None
+    mapping = eqn.params["grid_mapping"]
+    blocks = [tuple(getattr(b, "block_size", b) for b in m.block_shape)
+              for m in mapping.block_mappings]
+    # behind the grid's traced bounds and the scalar-prefetch arguments
+    operands = [v.aval.shape for v in eqn.invars[-mapping.num_inputs:]]
+    return count(operands, [v.aval.shape for v in eqn.outvars],
+                 tuple(mapping.grid), blocks)
+
+
+def _sub_jaxprs(eqn) -> List[Tuple[Any, int]]:
+    """(inner jaxpr, multiplier) pairs of an equation that holds others: a
+    ``scan``'s body times its length, a ``shard_map``'s times its devices."""
+    name = eqn.primitive.name
+    if name == "scan":
+        return [(eqn.params["jaxpr"].jaxpr, int(eqn.params["length"]))]
+    mult = _shard_map_devices(eqn) if name == "shard_map" else 1
+    out = []
+    for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
+        sub = eqn.params.get(key)
+        if sub is not None:
+            out.append((sub.jaxpr if hasattr(sub, "jaxpr") else sub, mult))
+    return out
+
+
+def _walk(jaxpr, walk: Walk, mult: int, prefix: Tuple[str, ...]) -> None:
     for eqn in jaxpr.eqns:
         stack = prefix + tuple(
             s for s in str(eqn.source_info.name_stack).split("/") if s)
         name = eqn.primitive.name
+        if name == "while":
+            walk.uncounted[name] += mult
+            continue
+        if name == "pallas_call":
+            flops = _pallas_flops(eqn)
+            if flops is None:
+                walk.uncounted[eqn.params.get("name") or name] += mult
+            else:
+                walk.count(stack, flops * mult, flops // 2 * mult)
+            continue
+        if name == "cond":
+            branches = []
+            for branch in eqn.params["branches"]:
+                branches.append(Walk())
+                _walk(branch.jaxpr, branches[-1], mult, stack)
+            branches.sort(key=lambda b: b.matmul_flops())
+            walk.add(branches[0])
+            walk.cond_spread_flops += branches[-1].matmul_flops() \
+                - branches[0].matmul_flops()
+            continue
         subs = _sub_jaxprs(eqn)
-        if subs and name not in ("custom_jvp_call", "custom_vjp_call"):
+        if subs:
             for sub, m in subs:
-                _walk(sub, root, mult * m, stack)
+                _walk(sub, walk, mult * m, stack)
             continue
         if name == "dot_general":
             flops, macs = _dot_general_flops(eqn)
+        elif name in ("ragged_dot", "ragged_dot_general"):
+            flops, macs = _ragged_dot_flops(eqn)
         elif name == "conv_general_dilated":
             flops, macs = _conv_flops(eqn)
         elif name in _ELEMENTWISE:
             flops, macs = sum(_size(v.aval) for v in eqn.outvars), 0
         elif name in _REDUCTIONS:
             flops, macs = sum(_size(v.aval) for v in eqn.invars), 0
-        elif name in _ZERO:
-            continue
-        elif subs:  # custom_jvp/vjp wrappers
-            for sub, m in subs:
-                _walk(sub, root, mult * m, stack)
-            continue
         else:
             continue
-        node = root
-        for part in stack:
-            node = node.child(part)
-        node.flops += flops * mult
-        node.macs += macs * mult
+        walk.count(stack, flops * mult, macs * mult)
+
+
+def walk_jaxpr(jaxpr) -> Walk:
+    """Walk a (closed) jaxpr: the per-module tree and the matrix operations
+    by scope. Needs no executable and runs nothing."""
+    walk = Walk()
+    _walk(getattr(jaxpr, "jaxpr", jaxpr), walk, 1, ())
+    return walk
 
 
 def profile_fn(fn: Callable, *args, **kwargs) -> ModuleProfile:
@@ -157,10 +300,7 @@ def profile_fn(fn: Callable, *args, **kwargs) -> ModuleProfile:
 
     Works on any jittable callable; module attribution follows the JAX name
     stack (flax modules populate it automatically)."""
-    jaxpr = jax.make_jaxpr(fn)(*args, **kwargs)
-    root = ModuleProfile("total")
-    _walk(jaxpr.jaxpr, root, 1, ())
-    return root
+    return walk_jaxpr(jax.make_jaxpr(fn)(*args, **kwargs)).tree
 
 
 def params_count(params) -> int:

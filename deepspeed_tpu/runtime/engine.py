@@ -251,8 +251,8 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         #: performance accounting (monitor/perf.py): the compiled train
         #: step registers an argument fingerprint (recompile sentinel —
         #: curriculum/data shape drift shows up as a NAMED alarm, not a
-        #: mystery stall) and captures cost-model FLOPs once, yielding the
-        #: train_mfu / train_tflops_per_chip gauges in the registry.
+        #: mystery stall) and counts its matrix work once (``StepCost``),
+        #: yielding the train_mfu / train_tflops_per_chip gauges.
         self.perf = PerfAccounting(
             tracer=self.tracer, metrics=self.registry, scope="train",
             n_devices=int(np.prod(self.mesh.devices.shape)))
@@ -298,6 +298,9 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         #: choices of the remat rule the compiled step made it take back
         #: (``_fit_train_step``); from the first on the budget is 0
         self._remat_fallbacks = 0
+        #: the train step's jaxpr between ``_fit_train_step``'s trace and
+        #: ``cost_capture``'s walk of it, None before and after
+        self._step_jaxpr = None
         with self.setup.span(self.tracer.span("init", cat="setup")):
             self._construct(model, model_parameters, example_batch,
                             partition_rules, rng)
@@ -867,7 +870,7 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             )
             return new_state, (loss, grad_norm, named), overflow
 
-        # raw Python step kept for the flops profiler's jaxpr walk
+        # raw Python step kept for the flops profiler's printed tree
         self._train_step_fn = ds_train_step
         return jax.jit(
             ds_train_step,
@@ -1139,15 +1142,14 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             if first:
                 setup.seconds["first_step"] = time.perf_counter() - t_batch0
             if not warm:
-                # once, after the compile-carrying first call: the cached
-                # lowering yields the cost model without a second trace;
-                # the jaxpr-walk flops profiler is the fallback
+                # once, after the compile-carrying first call: the matrix
+                # work of the step by scope, walked off the jaxpr its
+                # lowering was made from (``_fit_train_step`` kept it)
                 with setup.span(tr.span("cost_capture", cat="host",
                                         args={"step": step})):
-                    self.perf.capture_cost(
-                        "train_step", self._train_step,
-                        (self.state, batch, step_rng),
-                        fallback=self._train_flops_estimate(batch, step_rng))
+                    self.perf.capture_step_cost("train_step",
+                                                self._step_jaxpr)
+                    self._step_jaxpr = None
             if profiling:
                 float(loss)  # device fence so the measured latency is real
                 self._print_flops_profile(batch, step_rng,
@@ -1203,19 +1205,6 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         from ..checkpoint.manifest import prune_checkpoints
 
         prune_checkpoints(self._elastic_ckpt_dir, keep=keep)
-
-    def _train_flops_estimate(self, shaped_batch, rng):
-        """Fallback FLOPs source for backends without an XLA cost model: a
-        jaxpr walk of the raw train step (the flops profiler's graph
-        accounting — counts every dot/conv/elementwise primitive)."""
-        def estimate():
-            from ..profiling.flops_profiler.profiler import profile_fn
-
-            prof = profile_fn(self._train_step_fn, self.state, shaped_batch,
-                              rng)
-            return {"flops": float(prof.total_flops())}
-
-        return estimate
 
     def _print_flops_profile(self, shaped_batch, rng, step_time_s):
         """Flops-profiler hook (reference ``engine.py:1615,1634``: start at
@@ -1389,8 +1378,9 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         self._publish_step_rate(time.perf_counter(), done[-1][0])
 
     def _publish_step_rate(self, now: float, step: int):
-        """``train_mfu`` / ``train_tflops_per_chip`` from the cost model
-        over the time a step took: steps published since the last
+        """``train_mfu`` / ``train_tflops_per_chip``: the matrix operations
+        the step runs, replays left out (``StepCost.model_flops``), over the
+        time a step took: steps published since the last
         publication ÷ the host time between the two. A publication follows
         the device (it waits, or finds finished what a fence finished), so
         the interval is device-paced however far ahead the host dispatches;
@@ -1576,7 +1566,8 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
         find it (the benchmark's ``setup.*`` readers read it there), and a
         ring event. Once after the first step, which ends set-up (the record
         stands from there on), and on the first step of every profiler
-        session."""
+        session. Beside it, the same way, the train step's matrix work
+        (``monitor/perf.py StepCost``) as one ``step_cost`` span."""
         if first:
             self.setup.close()
         record = self.setup.record(step)
@@ -1584,6 +1575,11 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
             self.registry.gauge(f"setup_{name}").set(value)
         with self.tracer.span("setup", cat="setup", args=record):
             pass
+        cost = self.perf.programs.program("train_step").step_cost
+        if cost is not None:
+            with self.tracer.span("step_cost", cat="setup",
+                                  args=cost.record()):
+                pass
 
     def _remat_budget(self) -> Tuple[int, Optional[Tuple[int, int]]]:
         """``(the bytes the remat rule may plan with, a device's (limit, in
@@ -1620,7 +1616,11 @@ class DeepSpeedEngine(_EngineCheckpointMixin):
 
         def build(budget):
             with layers.remat_room(budget) as kept:
-                lowered = self._train_step.lower(self.state, batch, rng)
+                traced = self._train_step.trace(self.state, batch, rng)
+                lowered = traced.lower()
+            if self.perf.programs.program("train_step").cost_pending:
+                # the one trace: ``cost_capture`` walks it after the step
+                self._step_jaxpr = traced.jaxpr
             try:
                 return dict(kept), lowered.compile().memory_analysis()
             except jax.errors.JaxRuntimeError as e:

@@ -233,12 +233,20 @@ def test_training_engine_registers_train_step_with_cost_and_gauges():
     assert prog.recompiles == 0
     assert prog.calls == 3
     assert prog.flops and prog.flops > 0
-    # the train step is matmul-dominated: the cost model must sit within
-    # 15% of the classic 6·N·B matmul count (elementwise + Adam ops are
-    # the small honest remainder the 6NB shorthand ignores)
-    n_params = sum(int(np.prod(p.shape)) for p in
-                   jax.tree_util.tree_leaves(engine.state.params))
-    assert prog.flops == pytest.approx(6 * n_params * 16, rel=0.15)
+    # the step's own count of its matrix work (``StepCost``, walked off the
+    # jaxpr the lowering came from), exact: 6 x weights x batch -- forward,
+    # weight gradient, input gradient -- less the first layer's input
+    # gradient, which nobody asks for; biases and Adam are no matrix work
+    assert prog.cost_source == "jaxpr" and prog.bytes_accessed is None
+    kernels = [p for p in jax.tree_util.tree_leaves(engine.state.params)
+               if p.ndim == 2]
+    first = max(kernels, key=lambda p: p.shape[0] * p.shape[1])
+    assert prog.flops == 16 * (6 * sum(int(np.prod(p.shape)) for p in kernels)
+                               - 2 * int(np.prod(first.shape)))
+    row = next(r for r in engine.perf.programs.table()
+               if r["name"] == "train/train_step")
+    assert row["step_cost"]["matmul_flops"] == prog.flops
+    assert row["step_cost"]["replayed_flops"] == 0
     snap = engine.registry.snapshot()
     assert snap.get("train_tflops_per_chip", 0) > 0
     # CPU has no known peak: the MFU gauge must be absent, not garbage

@@ -246,8 +246,10 @@ def trace_names():
 #: are PR 41's names and ``ds.layer_full`` PR 49's; PR 68 added
 #: ``ds.layer_kda``, ``ds.layer_mla``, ``ds.kda_mix`` and ``ds.kda_rule``,
 #: which stand only in ``models/kimi_linear.py``'s step, whose
-#: ``ds.layer_dense`` is PR 63's name and ``ds.layer_full`` PR 49's)
-NAMES_PIN = (3, "004a54239cfaf933")
+#: ``ds.layer_dense`` is PR 63's name and ``ds.layer_full`` PR 49's; PR 70
+#: added the host span ``step_cost``, published beside ``setup``: a host
+#: span stands in no lowered step, as PR 54's)
+NAMES_PIN = (3, "4e27b79c87fe5e07")
 
 
 def test_names_version_is_raised_with_the_names():
@@ -267,7 +269,7 @@ def test_names_version_is_raised_with_the_names():
             "ds.kda_rule"} <= set(scopes) \
         and {"counters", "init", "init_shapes", "init_params",
              "init_opt_state", "init_step", "cost_capture",
-             "setup"} <= set(spans)
+             "setup", "step_cost"} <= set(spans)
     digest = hashlib.sha256("\n".join(scopes + spans).encode()).hexdigest()
     assert (tracing.NAMES_VERSION, digest[:16]) == NAMES_PIN
 
